@@ -1,0 +1,996 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the main path once, through the entry points an operator uses:
+
+    serve      `python -m comfyui_distributed_tpu --port P` as its own
+               process; the two BASELINE workflows queued over HTTP as
+               committed — workflows/distributed-upscale.json (SDXL at
+               registry width, 1024 -> 2048, 16 tiles, 20 steps) and
+               workflows/distributed-txt2img.json (SD1.5, 512^2, 20
+               steps) — POST /distributed/queue, poll /history/<id>,
+               read the saved PNG. One cold request of each as
+               committed, then warm ones with only the seed changed (a
+               re-queued identical graph is answered from the node
+               cache and would run nothing on the device). Weights are
+               seeded random, the input image comes from a seed.
+    restart    the server is stopped (SIGTERM, wait, port dead) and
+               started again; both committed requests are repeated and
+               must hit the persistent compile cache and give the same
+               bytes as before the restart.
+    attention  one child that holds the chip runs the attention
+               dispatcher, compiled, at the shapes the two workflows
+               produce, against a float32 reference, and prints which
+               route each shape took.
+    multichip  only where the server reports two or more chips: the
+               serve leg has then already run on every chip through
+               the in-process mesh; this leg checks that, and runs the
+               process-per-chip tier (master pinned to chip 0, a
+               managed worker on chip 1, USDU through the elastic tile
+               queue).
+
+This process never initialises a JAX backend: a chip belongs to one
+process, so it talks HTTP to a server child and stops that child before
+the next process needs the chip. Every child writes under one output
+directory (chiprun_out/chip_smoke by default) — config, data, logs, the
+native build — so nothing it finds in the checkout is reused.
+
+A leg that fails is a failure: the remaining independent legs still run
+(a chip call is expensive and should report everything it can), then
+the exit code is 1 and no result line is printed. The last line of a
+passing run is one JSON object, {"ok": true, "device": {...}}, with the
+device as JAX reported it to the server.
+
+`--rehearsal [N]` is for debugging this command in a sandbox with no
+chip, and for the integration test: tiny-unet at 64 px on `--platform
+cpu` (N virtual devices, default 1; the Pallas kernel interpreted). It
+is chosen by the caller, says so in its output, and is never entered
+because no chip was found. Without it, anything but platform "tpu"
+fails.
+"""
+
+from __future__ import annotations
+
+import argparse
+import copy
+import io
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.error
+import urllib.request
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+PACKAGE = "comfyui_distributed_tpu"
+LEGS = ("serve", "restart", "attention", "multichip")
+
+# tests/ops/test_upscale.py pins mesh == single-device USDU at
+# atol=2e-2 on the unit range: 5.1 of a PNG's 255 levels, plus one for
+# the two independent roundings to uint8.
+CANVAS_TOLERANCE_LEVELS = 6
+
+# Attention tolerance. Operands are bfloat16 and the output is rounded
+# to bfloat16 (2^-8 relative steps); the XLA route also rounds the
+# softmax weights to bfloat16 before the PV product. Against a float32
+# reference at `highest` matmul precision on the SAME bfloat16 operands
+# that bounds the error near 1e-2 of the output scale. Queries are
+# scaled so logits have a std of ~2 — a peaked softmax with O(1)
+# outputs, as in a trained model — so that a wrong scale, a missed key
+# block or stale scratch (errors of 0.1-1) cannot hide under it.
+ATTENTION_TOLERANCE = 2e-2
+
+# SDXL as the registry builds it (UNet + VAE + CLIP-L + CLIP-G) is
+# 3,468,837,867 parameters, stored on a TPU in bfloat16. A device that
+# took part in the USDU job peaks above this; one that only holds the
+# replicated weights does not.
+SDXL_WEIGHT_BYTES = 2 * 3_468_837_867
+
+
+class Failure(Exception):
+    """A leg did not do what it must."""
+
+
+_STARTED = time.monotonic()
+
+
+def say(message: str) -> None:
+    print(f"[chip_smoke +{time.monotonic() - _STARTED:6.1f}s] {message}", flush=True)
+
+
+# --- HTTP ------------------------------------------------------------------
+
+
+def http(method: str, url: str, body=None, timeout: float = 60.0):
+    data = None if body is None else json.dumps(body).encode()
+    request = urllib.request.Request(
+        url, data=data, method=method,
+        headers={"Content-Type": "application/json"},
+    )
+    try:
+        with urllib.request.urlopen(request, timeout=timeout) as response:
+            raw = response.read()
+    except urllib.error.HTTPError as exc:
+        detail = exc.read().decode(errors="replace")[:2000]
+        raise Failure(f"{method} {url} -> HTTP {exc.code}: {detail}") from exc
+    text = raw.decode()
+    try:
+        return json.loads(text)
+    except json.JSONDecodeError:
+        return text
+
+
+def port_is_dead(port: int) -> bool:
+    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as sock:
+        sock.settimeout(1.0)
+        return sock.connect_ex(("127.0.0.1", port)) != 0
+
+
+def tail(path: str, lines: int = 40) -> str:
+    try:
+        with open(path, "r", encoding="utf-8", errors="replace") as fh:
+            return "".join(fh.readlines()[-lines:])
+    except OSError as exc:
+        return f"<no log: {exc}>"
+
+
+# --- server child ----------------------------------------------------------
+
+
+class Server:
+    """One `python -m comfyui_distributed_tpu` child and its HTTP face."""
+
+    def __init__(self, run: "Run", name: str, port: int, devices=None):
+        self.run = run
+        self.name = name
+        self.port = port
+        self.base = f"http://127.0.0.1:{port}"
+        self.log_path = os.path.join(run.out, f"{name}.log")
+        self.devices = devices  # rehearsal only: virtual CPU devices
+        self.device: dict = {}  # as the server reports it, once up
+        self.proc: subprocess.Popen | None = None
+
+    def start(self, local_devices=None) -> dict:
+        if not port_is_dead(self.port):
+            raise Failure(f"port {self.port} is already taken")
+        cmd = [sys.executable, "-m", PACKAGE, "--port", str(self.port)]
+        cmd += self.run.platform_args
+        env = self.run.child_env(self.devices)
+        say(f"{self.name}: starting {' '.join(cmd[1:])} (log {self.log_path})")
+        started = time.monotonic()
+        with open(self.log_path, "ab") as log:
+            self.proc = subprocess.Popen(
+                cmd, cwd=HERE, env=env, stdout=log, stderr=subprocess.STDOUT,
+                start_new_session=True,
+            )
+        self.run.children.append(self.proc)
+        info = wait_for_server(self.base, self.proc, self.log_path, self.name)
+        say(f"{self.name}: up in {time.monotonic() - started:.1f}s")
+        self.device = check_system_info(
+            self.run, self.name, info, local_devices
+        )
+        return info
+
+    def stop(self) -> None:
+        """SIGTERM, wait, and require the port dead: the next process
+        that needs the chip must find it free."""
+        proc = self.proc
+        if proc is None:
+            return
+        proc.send_signal(signal.SIGTERM)
+        try:
+            code = proc.wait(timeout=90)
+        except subprocess.TimeoutExpired:
+            os.killpg(proc.pid, signal.SIGKILL)
+            proc.wait()
+            raise Failure(f"{self.name}: still running 90s after SIGTERM")
+        self.proc = None
+        if not port_is_dead(self.port):
+            raise Failure(f"{self.name}: exited but port {self.port} answers")
+        say(f"{self.name}: stopped (exit {code}), port {self.port} dead")
+
+    def get(self, path: str, **kw):
+        return http("GET", self.base + path, **kw)
+
+    def post(self, path: str, body, **kw):
+        return http("POST", self.base + path, body, **kw)
+
+
+def wait_for_server(base: str, proc, log_path: str, name: str) -> dict:
+    """Poll /distributed/system_info until it answers. The server only
+    listens once its backend is up, so an answer is a started server;
+    a child that exits first is a failed start, with its log's end."""
+    deadline = time.monotonic() + 600
+    while time.monotonic() < deadline:
+        if proc is not None and proc.poll() is not None:
+            raise Failure(
+                f"{name}: exited with code {proc.returncode} during "
+                f"start-up; end of {log_path}:\n{tail(log_path)}"
+            )
+        try:
+            return http("GET", base + "/distributed/system_info", timeout=10)
+        except (urllib.error.URLError, OSError, Failure):
+            time.sleep(0.5)
+    raise Failure(f"{name}: no answer on {base} after 600s")
+
+
+def wait_idle(base: str, name: str) -> None:
+    """Until the server's prompt queue is empty (GET /prompt, the
+    probe the master itself uses)."""
+    deadline = time.monotonic() + 900
+    while time.monotonic() < deadline:
+        info = http("GET", base + "/prompt")
+        if info["exec_info"]["queue_remaining"] == 0:
+            return
+        time.sleep(0.5)
+    raise Failure(f"{name}: prompt queue still busy after 900s")
+
+
+def read_metrics(base: str) -> dict:
+    """The runtime gauges of /distributed/metrics (telemetry/runtime.py)."""
+    text = http("GET", base + "/distributed/metrics")
+    out = {
+        "compiles": 0.0, "compile_s": 0.0, "cache_hits": 0.0,
+        "cache_misses": 0.0, "peak_bytes_in_use": {}, "bytes_in_use": {},
+        "tiles": {},
+    }
+    plain = {
+        "cdt_jax_compiles": "compiles",
+        "cdt_jax_compile_time_seconds": "compile_s",
+        "cdt_jax_cache_hits": "cache_hits",
+        "cdt_jax_cache_misses": "cache_misses",
+    }
+    for line in text.splitlines():
+        if line.startswith("#") or not line.strip():
+            continue
+        head, _, value = line.rpartition(" ")
+        name, _, labels = head.partition("{")
+        labels = dict(
+            part.split("=", 1) for part in labels.rstrip("}").split(",") if part
+        )
+        labels = {k: v.strip('"') for k, v in labels.items()}
+        if name in plain:
+            out[plain[name]] = float(value)
+        elif name == "cdt_device_memory_bytes":
+            stat = labels.get("stat")
+            if stat in ("peak_bytes_in_use", "bytes_in_use"):
+                out[stat][labels.get("device", "?")] = int(float(value))
+        elif name == "cdt_tiles_processed_total":
+            out["tiles"][labels.get("role", "?")] = int(float(value))
+    return out
+
+
+def describe_metrics(before: dict, after: dict) -> dict:
+    return {
+        "compiles": int(after["compiles"] - before["compiles"]),
+        "compile_s": round(after["compile_s"] - before["compile_s"], 2),
+        "cache_hits": int(after["cache_hits"] - before["cache_hits"]),
+        "cache_misses": int(after["cache_misses"] - before["cache_misses"]),
+        "peak_bytes_in_use": after["peak_bytes_in_use"],
+        "bytes_in_use": after["bytes_in_use"],
+    }
+
+
+# --- the run ---------------------------------------------------------------
+
+
+class Run:
+    def __init__(self, args):
+        self.rehearsal: int = args.rehearsal or 0
+        self.out = os.path.abspath(args.out)
+        self.port = args.port
+        self.children: list[subprocess.Popen] = []
+        self.failures: list[str] = []
+        self.device: dict | None = None
+        self.results: dict[str, dict] = {}
+        self.platform_args = ["--platform", "cpu"] if self.rehearsal else []
+        self.expect_platform = "cpu" if self.rehearsal else "tpu"
+
+    # every child of the smoke resolves state under the output
+    # directory; the compile cache alone stays where the program puts
+    # it (JAX_COMPILATION_CACHE_DIR, else the fixed in-checkout path)
+    def child_env(self, devices: int | None = None) -> dict:
+        env = dict(os.environ)
+        env.update(
+            CDT_CONFIG_PATH=os.path.join(self.out, "tpu_config.json"),
+            CDT_DATA_DIR=os.path.join(self.out, "data"),
+            CDT_LOG_DIR=os.path.join(self.out, "logs"),
+            CDT_NATIVE_BUILD_DIR=os.path.join(self.out, "native_build"),
+            PYTHONPATH=HERE + os.pathsep + env.get("PYTHONPATH", ""),
+            PYTHONUNBUFFERED="1",
+        )
+        if self.rehearsal:
+            n = self.rehearsal if devices is None else devices
+            env["JAX_PLATFORMS"] = "cpu"
+            env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+            # a CPU mesh is opt-in (parallel/mesh.worker_mesh)
+            env.pop("CDT_MESH_SHAPE", None)
+            if n > 1:
+                env["CDT_MESH_SHAPE"] = f"{n},1"
+        return env
+
+    def workflow(self, name: str) -> dict:
+        with open(os.path.join(HERE, "workflows", name), encoding="utf-8") as fh:
+            prompt = json.load(fh)
+        if self.rehearsal:
+            # the one place the committed graphs are edited, and only
+            # on the caller's explicit request: same nodes, toy sizes
+            for node in prompt.values():
+                inputs = node["inputs"]
+                if node["class_type"] == "CheckpointLoaderSimple":
+                    inputs["ckpt_name"] = "tiny-unet"
+                if node["class_type"] == "EmptyLatentImage":
+                    inputs["width"] = inputs["height"] = 64
+                if node["class_type"] in (
+                    "KSampler", "UltimateSDUpscaleDistributed"
+                ):
+                    inputs["steps"] = 2
+                if node["class_type"] == "UltimateSDUpscaleDistributed":
+                    inputs["tile_width"] = inputs["tile_height"] = 64
+                    inputs["tile_padding"] = 16
+        return prompt
+
+    @property
+    def input_px(self) -> int:
+        return 64 if self.rehearsal else 1024
+
+    @property
+    def tile_px(self) -> int:
+        return 64 if self.rehearsal else 512
+
+    @property
+    def txt2img_px(self) -> int:
+        return 64 if self.rehearsal else 512
+
+    def workloads(self) -> tuple:
+        """(tag, workflow file, output px, block px for the flatness
+        check, images expected): the seed node fans txt2img out to one
+        image per mesh participant."""
+        return (
+            ("usdu", "distributed-upscale.json", 2 * self.input_px,
+             self.tile_px, 1),
+            ("txt2img", "distributed-txt2img.json", self.txt2img_px,
+             self.txt2img_px, self.device["count"]),
+        )
+
+    def attempt(self, leg: str, fn) -> bool:
+        say(f"=== leg {leg} ===")
+        started = time.monotonic()
+        try:
+            fn()
+        except Failure as exc:
+            self.failures.append(f"{leg}: {exc}")
+            say(f"leg {leg} FAILED after {time.monotonic() - started:.1f}s: {exc}")
+            return False
+        say(f"leg {leg} passed in {time.monotonic() - started:.1f}s")
+        return True
+
+    def stop_children(self) -> None:
+        for proc in self.children:
+            if proc.poll() is None:
+                try:
+                    os.killpg(proc.pid, signal.SIGKILL)
+                except ProcessLookupError:
+                    pass
+                proc.wait()
+
+
+def write_input_image(run: Run) -> None:
+    """LoadImage's "input.png", from a seed: smooth colour fields plus
+    fine noise, so every tile has content and no tile is constant."""
+    import numpy as np
+    from PIL import Image
+
+    n = run.input_px
+    rng = np.random.default_rng(20260926)
+    yy, xx = np.mgrid[0:n, 0:n].astype(np.float32) / n
+    phase = rng.uniform(0, 2 * np.pi, size=(3, 2))
+    planes = [
+        0.5 + 0.35 * np.sin(2 * np.pi * (3 + c) * xx + phase[c, 0])
+        * np.cos(2 * np.pi * (2 + c) * yy + phase[c, 1])
+        for c in range(3)
+    ]
+    image = np.stack(planes, axis=-1) + rng.normal(0, 0.04, size=(n, n, 3))
+    pixels = (np.clip(image, 0, 1) * 255 + 0.5).astype(np.uint8)
+    path = os.path.join(run.out, "data", "input", "input.png")
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    Image.fromarray(pixels).save(path)
+    say(f"input image {n}x{n} from seed 20260926 -> {path}")
+
+
+def check_system_info(run: "Run", name: str, info: dict, local_devices=None) -> dict:
+    """Print what the server says it runs on; fail unless it is the
+    platform this run is for."""
+    topology = info.get("topology") or {}
+    if "error" in topology:
+        raise Failure(f"{name}: system_info topology error: {topology['error']}")
+    if "error" in (topology.get("mesh") or {}):
+        raise Failure(f"{name}: mesh error: {topology['mesh']['error']}")
+    device = {
+        "platform": topology.get("platform"),
+        "kind": topology.get("device_kind"),
+        "count": topology.get("device_count"),
+    }
+    say(
+        f"{name}: platform={device['platform']} device_kind={device['kind']} "
+        f"devices={device['count']} local={topology.get('local_device_count')} "
+        f"visible_chips={topology.get('visible_chips')} "
+        f"mesh={topology.get('mesh')} versions={topology.get('versions')} "
+        f"data_plane={info.get('data_plane')}"
+    )
+    if device["platform"] != run.expect_platform:
+        raise Failure(
+            f"{name}: serves on platform {device['platform']!r}, "
+            f"this run needs {run.expect_platform!r}"
+        )
+    if local_devices is not None and topology.get("local_device_count") != local_devices:
+        raise Failure(
+            f"{name}: {topology.get('local_device_count')} local device(s), "
+            f"expected {local_devices}"
+        )
+    return device
+
+
+def load_png(path: str):
+    import numpy as np
+    from PIL import Image
+
+    with open(path, "rb") as fh:
+        raw = fh.read()
+    return raw, np.asarray(Image.open(io.BytesIO(raw)).convert("RGB"))
+
+
+def check_image(label: str, pixels, size: int, block: int) -> None:
+    """Right shape, finite, and no constant block: a NaN tile leaves
+    the encoder as one flat colour, so flatness is how it shows."""
+    import numpy as np
+
+    if pixels.shape != (size, size, 3):
+        raise Failure(f"{label}: shape {pixels.shape}, expected {(size, size, 3)}")
+    as_float = pixels.astype(np.float32)
+    if not np.isfinite(as_float).all():
+        raise Failure(f"{label}: non-finite pixels")
+    for y in range(0, size, block):
+        for x in range(0, size, block):
+            if as_float[y:y + block, x:x + block].std() == 0.0:
+                raise Failure(f"{label}: constant {block}px block at ({y},{x})")
+
+
+def request(
+    run: Run, server: Server, label: str, prompt: dict, *, images: int,
+    size: int, block: int, warm: bool, workers=(),
+) -> dict:
+    """Queue one prompt as a client would and hold it to the contract."""
+    before = read_metrics(server.base)
+    started = time.monotonic()
+    queued = server.post(
+        "/distributed/queue",
+        {"prompt": prompt, "client_id": "chip_smoke", "workers": list(workers)},
+        timeout=300,
+    )
+    prompt_id = queued.get("prompt_id")
+    if not prompt_id:
+        raise Failure(f"{label}: queue answered {queued}")
+    if sorted(queued.get("workers", [])) != sorted(workers):
+        raise Failure(
+            f"{label}: asked for workers {list(workers)}, "
+            f"dispatched to {queued.get('workers')}"
+        )
+    deadline = started + 1500
+    while True:
+        history = server.get(f"/history/{prompt_id}")
+        if history.get("done"):
+            break
+        if server.proc is not None and server.proc.poll() is not None:
+            raise Failure(
+                f"{label}: server died mid-request; end of log:\n"
+                f"{tail(server.log_path)}"
+            )
+        if time.monotonic() > deadline:
+            raise Failure(f"{label}: not done after 1500s")
+        time.sleep(0.25)
+    wall = time.monotonic() - started
+    if history.get("error"):
+        raise Failure(
+            f"{label}: ended in error: {history['error']}; end of log:\n"
+            f"{tail(server.log_path, 25)}"
+        )
+    names = [
+        name
+        for entry in (history.get("outputs") or {}).values()
+        for name in entry.get("images", [])
+    ]
+    if len(names) != images:
+        raise Failure(f"{label}: saved {names}, expected {images} image(s)")
+    outputs = []
+    for name in names:
+        path = os.path.join(run.out, "data", "output", name)
+        raw, pixels = load_png(path)
+        check_image(f"{label} {name}", pixels, size, block)
+        outputs.append({"name": name, "bytes": raw, "pixels": pixels})
+        # held in memory from here; what the chip tool brings back is
+        # capped, and a 2048^2 PNG of this input is ~10 MB
+        os.remove(path)
+    delta = describe_metrics(before, read_metrics(server.base))
+    say(
+        f"{label}: ok in {wall:.2f}s wall on {server.device['platform']}/"
+        f"{server.device['kind']} x{server.device['count']} | "
+        f"compiles={delta['compiles']} compile_s={delta['compile_s']} "
+        f"cache_hits={delta['cache_hits']} cache_misses={delta['cache_misses']} "
+        f"peak_bytes_in_use={delta['peak_bytes_in_use']} "
+        f"bytes_in_use={delta['bytes_in_use']} | "
+        f"node timings {history.get('timings')}"
+    )
+    if warm and delta["compiles"]:
+        raise Failure(
+            f"{label}: a warm request built {delta['compiles']} program(s) "
+            f"({delta['compile_s']}s)"
+        )
+    return {"wall_s": wall, "outputs": outputs, "metrics": delta}
+
+
+def with_seed(prompt: dict, seed: int) -> dict:
+    """The committed graph with only its seed changed."""
+    prompt = copy.deepcopy(prompt)
+    for node in prompt.values():
+        if node["class_type"] in ("UltimateSDUpscaleDistributed", "DistributedSeed"):
+            node["inputs"]["seed"] = seed
+    return prompt
+
+
+def committed_seed(prompt: dict) -> int:
+    for node in prompt.values():
+        if node["class_type"] in ("UltimateSDUpscaleDistributed", "DistributedSeed"):
+            return int(node["inputs"]["seed"])
+    raise Failure("workflow has no seed node")
+
+
+def require_distinct(label: str, blobs: list[bytes]) -> None:
+    if len(set(blobs)) != len(blobs):
+        raise Failure(f"{label}: outputs that must differ are identical")
+
+
+# --- legs ------------------------------------------------------------------
+
+
+def leg_serve(run: Run) -> None:
+    server = Server(run, "server1", run.port)
+    problems: list[str] = []
+    try:
+        server.start()
+        run.device = server.device
+        results = run.results.setdefault("serve", {})
+        for tag, name, size, block, images in run.workloads():
+            prompt = run.workflow(name)
+            seed = committed_seed(prompt)
+            runs = results.setdefault(tag, [])
+            try:
+                runs.append(request(
+                    run, server, f"{tag} cold (seed {seed}, as committed)",
+                    prompt, images=images, size=size, block=block, warm=False,
+                ))
+                for i in (1, 2):
+                    runs.append(request(
+                        run, server, f"{tag} warm {i} (seed {seed + i})",
+                        with_seed(prompt, seed + i), images=images, size=size,
+                        block=block, warm=True,
+                    ))
+                require_distinct(
+                    f"{tag} across seeds",
+                    [o["bytes"] for r in runs for o in r["outputs"]],
+                )
+            except Failure as exc:
+                # the other workflow is independent of this one
+                problems.append(str(exc))
+                say(f"{tag} FAILED: {exc}")
+                if not runs:
+                    del results[tag]
+    finally:
+        server.stop()
+    if problems:
+        raise Failure("; ".join(problems))
+
+
+def leg_restart(run: Run) -> None:
+    first = run.results.get("serve") or {}
+    if not ("usdu" in first and "txt2img" in first):
+        raise Failure("needs the serve leg's cold outputs")
+    server = Server(run, "server2", run.port)
+    try:
+        server.start()
+        for tag, name, size, block, images in run.workloads():
+            prompt = run.workflow(name)
+            again = request(
+                run, server,
+                f"{tag} after restart (seed {committed_seed(prompt)}, as committed)",
+                prompt, images=images, size=size, block=block, warm=False,
+            )
+            metrics = again["metrics"]
+            if metrics["cache_hits"] == 0 or metrics["cache_misses"]:
+                raise Failure(
+                    f"{tag} after restart: persistent compile cache "
+                    f"hits={metrics['cache_hits']} misses={metrics['cache_misses']}"
+                    " (a restarted server must load every program it needs)"
+                )
+            before = [o["bytes"] for o in first[tag][0]["outputs"]]
+            if [o["bytes"] for o in again["outputs"]] != before:
+                raise Failure(
+                    f"{tag} after restart: same seed, different bytes"
+                )
+            say(f"{tag} after restart: bytes identical to the first server's")
+    finally:
+        server.stop()
+
+
+def leg_attention(run: Run) -> None:
+    """The dispatcher at the served shapes, in one child that holds
+    the chip (the servers are down by now)."""
+    cmd = [sys.executable, os.path.abspath(__file__), "--attention-child"]
+    if run.rehearsal:
+        cmd += ["--rehearsal", "1"]
+    log_path = os.path.join(run.out, "attention.log")
+    with open(log_path, "wb") as log:
+        proc = subprocess.Popen(
+            cmd, cwd=HERE, env=run.child_env(devices=1),
+            stdout=subprocess.PIPE, stderr=log, start_new_session=True,
+        )
+        run.children.append(proc)
+        try:
+            stdout, _ = proc.communicate(timeout=900)
+        except subprocess.TimeoutExpired:
+            os.killpg(proc.pid, signal.SIGKILL)
+            proc.wait()
+            raise Failure("attention child still running after 900s")
+    rows = [
+        json.loads(line) for line in stdout.decode().splitlines()
+        if line.startswith("{")
+    ]
+    for row in rows:
+        say(f"attention: {json.dumps(row)}")
+        if "devices" in row and run.device is None:
+            run.device = {
+                "platform": row["platform"], "kind": row["device_kind"],
+                "count": row["devices"],
+            }
+    if proc.returncode != 0:
+        raise Failure(
+            f"attention child exited {proc.returncode}; end of log:\n{tail(log_path)}"
+        )
+    bad = [r for r in rows if "shape" in r and not r["ok"]]
+    if bad or not any("shape" in r for r in rows):
+        raise Failure(f"attention: {len(bad)} shape(s) out of tolerance")
+
+
+def leg_multichip(run: Run) -> None:
+    """(a) what the serve leg did on every chip through the in-process
+    mesh, and (b) the process-per-chip tier. A check that fails is kept
+    and the leg goes on — a four-chip call should say everything it can
+    — then the leg fails with all of them."""
+    import numpy as np
+
+    chips = run.device["count"]
+    serve = run.results.get("serve") or {}
+    problems: list[str] = []
+
+    def check(name: str, fn) -> None:
+        try:
+            fn()
+        except Failure as exc:
+            problems.append(f"{name}: {exc}")
+            say(f"{name} FAILED: {exc}")
+
+    def every_device_worked():
+        if "usdu" not in serve:
+            raise Failure("the serve leg gave no USDU result")
+        peak = serve["usdu"][0]["metrics"]["peak_bytes_in_use"]
+        say(f"(a) USDU on the mesh: peak bytes per device {peak}")
+        if run.rehearsal:  # the CPU backend reports no memory stats
+            return
+        if len(peak) != chips or min(peak.values()) <= SDXL_WEIGHT_BYTES:
+            raise Failure(
+                f"expected every one of {chips} devices to peak above the "
+                f"{SDXL_WEIGHT_BYTES} bytes of weights"
+            )
+
+    def one_image_per_chip():
+        if "txt2img" not in serve:
+            raise Failure("the serve leg gave no txt2img result")
+        images = [o["bytes"] for o in serve["txt2img"][0]["outputs"]]
+        if len(images) != chips:
+            raise Failure(f"{len(images)} image(s) on {chips} chips")
+        require_distinct("txt2img participants", images)
+        say(f"(a) txt2img: {chips} distinct images, one per chip")
+
+    check("(a) every device worked", every_device_worked)
+    check("(a) one image per chip", one_image_per_chip)
+
+    # (b) process per chip: the operator's config edit, then the API
+    with open(os.path.join(run.out, "tpu_config.json"), "w", encoding="utf-8") as fh:
+        json.dump({"master": {"tpu_chips": [0]}}, fh)
+    worker_port = run.port + 1
+    worker_base = f"http://127.0.0.1:{worker_port}"
+    usdu = run.workflow("distributed-upscale.json")
+    size, block = 2 * run.input_px, run.tile_px
+    # in rehearsal "one chip" is one virtual CPU device
+    master = Server(run, "master_chip0", run.port, devices=1)
+
+    def launch_worker() -> None:
+        launched = master.post("/distributed/launch_worker", {"worker_id": "w1"})
+        say(f"(b) launched worker w1: {launched}")
+        log_path = launched.get("log", "")
+        try:
+            info = wait_for_server(worker_base, None, log_path, "worker_chip1")
+        except Failure as exc:
+            raise Failure(f"{exc}; end of {log_path}:\n{tail(log_path)}")
+        check_system_info(run, "worker_chip1", info, local_devices=1)
+
+    def stop_worker() -> None:
+        stopped = master.post("/distributed/stop_worker", {"worker_id": "w1"})
+        if not stopped.get("stopped") or not port_is_dead(worker_port):
+            raise Failure(
+                f"stop_worker answered {stopped}; port dead: "
+                f"{port_is_dead(worker_port)}"
+            )
+        say("(b) worker stopped, its port is dead")
+
+    def canvas_matches_one_chip():
+        solo = request(
+            run, master, "(b) usdu on chip 0 alone (as committed)", usdu,
+            images=1, size=size, block=block, warm=False,
+        )
+        if "usdu" not in serve:
+            raise Failure("no mesh canvas to compare with")
+        diff = np.abs(
+            solo["outputs"][0]["pixels"].astype(np.int16)
+            - serve["usdu"][0]["outputs"][0]["pixels"].astype(np.int16)
+        )
+        say(
+            f"(a) {chips}-chip mesh canvas vs one-chip canvas: max |diff| "
+            f"{int(diff.max())} of 255 levels, mean {float(diff.mean()):.4f}, "
+            f"{float((diff > CANVAS_TOLERANCE_LEVELS).mean()):.6f} of pixels "
+            f"over {CANVAS_TOLERANCE_LEVELS}"
+        )
+        if diff.max() > CANVAS_TOLERANCE_LEVELS:
+            raise Failure(
+                f"mesh canvas differs from the one-chip canvas by "
+                f"{int(diff.max())} levels (> {CANVAS_TOLERANCE_LEVELS})"
+            )
+
+    def both_processes_contribute():
+        # the first elastic job finds the worker still loading its
+        # model (the master, already warm, may finish every tile
+        # alone); the split is judged on the second, once the worker
+        # has drained its queue and holds model and programs
+        for label, seed in (("cold", 7), ("warm", 8)):
+            before_m = read_metrics(master.base)["tiles"].get("master", 0)
+            before_w = read_metrics(worker_base)["tiles"].get("worker", 0)
+            request(
+                run, master,
+                f"(b) elastic usdu {label} (seed {seed}, workers [w1])",
+                with_seed(usdu, seed), images=1, size=size, block=block,
+                warm=label == "warm", workers=("w1",),
+            )
+            tiles_m = read_metrics(master.base)["tiles"].get("master", 0) - before_m
+            tiles_w = read_metrics(worker_base)["tiles"].get("worker", 0) - before_w
+            say(f"(b) {label}: tiles master={tiles_m} worker={tiles_w}")
+            wait_idle(worker_base, "worker_chip1")
+            totals = read_metrics(worker_base)
+            totals.pop("tiles")
+            say(f"(b) {label}: worker process so far {totals}")
+        if not (tiles_m > 0 and tiles_w > 0):
+            raise Failure(
+                f"warm elastic job: master {tiles_m} tile(s), worker "
+                f"{tiles_w} — both must contribute"
+            )
+
+    try:
+        master.start(local_devices=1)
+        check("(a) mesh canvas equals one-chip canvas", canvas_matches_one_chip)
+        master.post("/distributed/config/worker", {
+            "id": "w1", "name": "w1", "type": "local", "host": "127.0.0.1",
+            "port": worker_port, "tpu_chips": [1], "enabled": True,
+            "extra_args": " ".join(run.platform_args),
+        })
+        launch_worker()
+        check("(b) both processes contribute", both_processes_contribute)
+        stop_worker()
+        # the chip is free again only if a new process can take it
+        launch_worker()
+        say("(b) chip 1 was free again: a second worker took it")
+        stop_worker()
+    finally:
+        master.stop()
+    if problems:
+        raise Failure("; ".join(problems))
+
+
+# --- the attention child (the only code here that imports jax) --------------
+
+# (label, [B, N, H, D] of q, key tokens M). Self-attention has M = N.
+# SD1.5 at 512^2 is a 64x64 latent with CFG batch 2: 8 heads of
+# 40/80/160 at 4,096/1,024/256 tokens, 64 tokens in the middle block,
+# 77 text keys in cross-attention; its VAE middle block is one
+# 512-wide head over 4,096 tokens. A 512 px USDU tile with 32 px of
+# padding is 576 px, a 72x72 latent: SDXL attends at 1,296 tokens
+# (10 heads of 64) and 324 tokens (20 heads of 64) with tile batch 8
+# under CFG (batch 16), and its VAE middle block sees 5,184 tokens.
+SERVED_SHAPES = (
+    ("sd15 self 64x64", (2, 4096, 8, 40), 4096),
+    ("sd15 self 32x32", (2, 1024, 8, 80), 1024),
+    ("sd15 self 16x16", (2, 256, 8, 160), 256),
+    ("sd15 self 8x8 mid", (2, 64, 8, 160), 64),
+    ("sd15 cross 64x64", (2, 4096, 8, 40), 77),
+    ("sd15 vae mid 64x64", (1, 4096, 1, 512), 4096),
+    ("sdxl tile self 36x36", (16, 1296, 10, 64), 1296),
+    ("sdxl tile self 18x18", (16, 324, 20, 64), 324),
+    ("sdxl tile cross 36x36", (16, 1296, 10, 64), 77),
+    ("sdxl tile vae mid 72x72", (8, 5184, 1, 512), 5184),
+)
+# the same routes at sizes the Pallas interpreter finishes in seconds
+REHEARSAL_SHAPES = (
+    ("toy self aligned", (1, 256, 2, 40), 256),
+    ("toy self ragged", (1, 81, 2, 64), 81),
+    ("toy cross", (1, 256, 2, 40), 77),
+)
+
+
+def attention_child(rehearsal: bool) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if rehearsal:
+        jax.config.update("jax_platforms", "cpu")
+    from comfyui_distributed_tpu.ops import attention
+    from comfyui_distributed_tpu.workers.startup import configure_compile_cache
+
+    configure_compile_cache()
+    device = jax.devices()[0]
+    header = {
+        "platform": device.platform, "device_kind": device.device_kind,
+        "devices": len(jax.devices()), "rehearsal": rehearsal,
+        "tolerance": ATTENTION_TOLERANCE,
+    }
+    print(json.dumps(header), flush=True)
+    if device.platform != ("cpu" if rehearsal else "tpu"):
+        print(f"attention child needs a TPU, found {device.platform}", file=sys.stderr)
+        return 1
+    @jax.jit
+    def errors(out, q, k, v):
+        with jax.default_matmul_precision("highest"):
+            ref = jax.nn.dot_product_attention(
+                q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32)
+            )
+        return (
+            jnp.max(jnp.abs(out.astype(jnp.float32) - ref)), jnp.max(jnp.abs(ref))
+        )
+
+    failed = 0
+    for label, q_shape, m in REHEARSAL_SHAPES if rehearsal else SERVED_SHAPES:
+        b, n, h, d = q_shape
+
+        @jax.jit
+        def operands(key, q_shape=q_shape, kv_shape=(b, m, h, d)):
+            kq, kk, kv = jax.random.split(key, 3)
+            return (
+                (2.0 * jax.random.normal(kq, q_shape)).astype(jnp.bfloat16),
+                jax.random.normal(kk, kv_shape).astype(jnp.bfloat16),
+                jax.random.normal(kv, kv_shape).astype(jnp.bfloat16),
+            )
+
+        q, k, v = operands(jax.random.key(n * 131 + m * 7 + d))
+        route = attention.attention_route(q, k)
+        if rehearsal:
+            # the CPU never routes to the kernel by itself: ask for it
+            # where the chip would, interpreted
+            flash = n % attention.BLOCK_Q == 0 and m % attention.BLOCK_K == 0
+            route = "flash (interpreted)" if flash else route
+            fn = jax.jit(
+                lambda q, k, v, flash=flash: attention.dot_product_attention(
+                    q, k, v, force_flash=flash, interpret=flash
+                )
+            )
+        else:
+            fn = jax.jit(attention.dot_product_attention)
+        started = time.perf_counter()
+        out = jax.block_until_ready(fn(q, k, v))
+        first_s = time.perf_counter() - started
+        started = time.perf_counter()
+        jax.block_until_ready(fn(q, k, v))
+        again_ms = 1e3 * (time.perf_counter() - started)
+        err, ref_max = (float(x) for x in errors(out, q, k, v))
+        scale = max(1.0, ref_max)
+        ok = bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
+        failed += not ok
+        print(json.dumps({
+            "shape": label, "q": list(q_shape), "keys": m, "dtype": "bfloat16",
+            "route": route, "max_abs_err": round(err, 5),
+            "ref_max_abs": round(scale, 3), "ok": ok,
+            "first_call_s": round(first_s, 2), "second_call_ms": round(again_ms, 2),
+        }), flush=True)
+    return 1 if failed else 0
+
+
+# --- entry -----------------------------------------------------------------
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument(
+        "--rehearsal", type=int, nargs="?", const=1, default=None, metavar="N",
+        help="debug run without a chip: tiny-unet, 64 px, --platform cpu on "
+             "N virtual devices (default 1), Pallas interpreted",
+    )
+    parser.add_argument(
+        "--legs", default=",".join(LEGS),
+        help=f"comma list of legs to run (default: all of {','.join(LEGS)}; "
+             "multichip runs only where there are two or more chips)",
+    )
+    parser.add_argument(
+        "--out", default=os.path.join(HERE, "chiprun_out", "chip_smoke"),
+        help="output directory; emptied first",
+    )
+    parser.add_argument("--port", type=int, default=18188)
+    parser.add_argument("--attention-child", action="store_true", help=argparse.SUPPRESS)
+    args = parser.parse_args(argv)
+
+    if not os.path.isdir(os.path.join(HERE, PACKAGE)):
+        print(
+            f"chip_smoke: {PACKAGE}/ is not next to this script; it checks "
+            "the program and cannot run without it", file=sys.stderr,
+        )
+        return 2
+    if args.attention_child:
+        return attention_child(bool(args.rehearsal))
+
+    legs = [leg.strip() for leg in args.legs.split(",") if leg.strip()]
+    unknown = sorted(set(legs) - set(LEGS))
+    if unknown:
+        parser.error(f"unknown leg(s) {unknown}")
+    run = Run(args)
+    shutil.rmtree(run.out, ignore_errors=True)
+    os.makedirs(run.out)
+    started = time.monotonic()
+    if run.rehearsal:
+        say(
+            f"REHEARSAL on {run.rehearsal} virtual CPU device(s): tiny-unet, "
+            "64 px, Pallas interpreted — this checks the command, not the chip"
+        )
+    say(
+        f"output under {run.out}; JAX_COMPILATION_CACHE_DIR="
+        f"{os.environ.get('JAX_COMPILATION_CACHE_DIR') or '<unset: in-checkout default>'}"
+    )
+    try:
+        write_input_image(run)
+        if "serve" in legs:
+            run.attempt("serve", lambda: leg_serve(run))
+        if "restart" in legs:
+            run.attempt("restart", lambda: leg_restart(run))
+        if "attention" in legs:
+            run.attempt("attention", lambda: leg_attention(run))
+        if "multichip" in legs and run.device and run.device["count"] >= 2:
+            run.attempt("multichip", lambda: leg_multichip(run))
+    finally:
+        run.stop_children()
+    say(f"finished in {time.monotonic() - started:.1f}s")
+    if run.device is None:
+        run.failures.append("no server reported a device")
+    if run.failures:
+        # each was printed in full where it happened; the last lines
+        # carry one line apiece
+        for failure in run.failures:
+            say(f"FAILED {failure.splitlines()[0]}")
+        return 1
+    result = {"ok": True, "device": run.device}
+    if run.rehearsal:
+        result["rehearsal"] = True
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -40,29 +40,36 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--config", type=str, default=None)
     parser.add_argument(
         "--platform", type=str, default=None,
-        help="force a jax platform (e.g. cpu for smoke tests)",
+        help="force a jax platform. Without it the server refuses to "
+             "serve on the CPU (pass cpu to do that on purpose)",
     )
     args = parser.parse_args(argv)
 
     if args.worker:
         os.environ.setdefault("CDT_IS_WORKER", "1")
-    if args.platform:
-        import jax
 
-        jax.config.update("jax_platforms", args.platform)
+    from .utils.logging import log
+    from .workers.startup import (
+        apply_master_chips,
+        configure_compile_cache,
+        init_backend,
+    )
 
-    # persistent XLA compilation cache: every process after the first
-    # skips its first compiles (master and workers share the dir)
-    from .workers.startup import configure_compile_cache
+    # Order matters: the chip set before anything can initialise a
+    # backend (libtpu takes every chip it can see), the cache before
+    # the first compile, the backend before the listener so no request
+    # handler is ever the first to touch it.
+    apply_master_chips(args.config)
+    try:
+        configure_compile_cache()
+        init_backend(args.platform)
+    except RuntimeError as exc:
+        log(f"backend start-up failed: {exc}")
+        return 1
 
-    configure_compile_cache()
-
-    # join the pod's shared JAX runtime when configured (no-op otherwise)
-    from .parallel.multihost import maybe_init_multihost
-
-    maybe_init_multihost()
-
+    from . import native
     from .api.server import DistributedServer
+    from .parallel.mesh import mesh_summary, note_serving_mesh, worker_mesh
     from .workers.monitor import start_master_watchdog
     from .workers.startup import (
         auto_populate_workers,
@@ -71,12 +78,19 @@ def main(argv: list[str] | None = None) -> int:
         register_worker_drain,
     )
 
+    # every local chip serves: the same mesh rule the elastic tile tier
+    # uses (None on one chip, and on the CPU unless CDT_MESH_SHAPE opts
+    # in), handed to every node through the execution context
+    mesh = worker_mesh()
+    note_serving_mesh(mesh)
+    log(f"serving mesh {mesh_summary(mesh)}; data plane: {native.backend()}")
+
     server = DistributedServer(
-        port=args.port, is_worker=args.worker, config_path=args.config,
-        host=args.host, standby_of=args.standby,
+        port=args.port, is_worker=args.worker, mesh=mesh,
+        config_path=args.config, host=args.host, standby_of=args.standby,
     )
 
-    async def run():
+    async def start():
         await server.start()
         register_signals(asyncio.get_running_loop(), args.config)
         if not server.is_worker:
@@ -88,13 +102,16 @@ def main(argv: list[str] | None = None) -> int:
             # in-flight batch, flush encoded tiles, hand the remainder
             # back via return_tiles, then deregister and stop
             register_worker_drain(asyncio.get_running_loop(), server)
-        # run until the loop is stopped by a signal handler
-        await asyncio.Event().wait()
 
-    try:
-        asyncio.run(run())
-    except (KeyboardInterrupt, RuntimeError):
-        pass
+    # The signal handlers end the process with loop.stop(), which
+    # run_forever() returns from cleanly — so any exception that does
+    # get out of here is a failure and exits non-zero as one.
+    with asyncio.Runner() as runner:
+        runner.run(start())
+        try:
+            runner.get_loop().run_forever()
+        except KeyboardInterrupt:
+            pass
     return 0
 
 

@@ -336,6 +336,10 @@ class JobRoutes:
         """Drop pipeline caches and device buffers (the TPU analog of
         the reference's unload-models + cuda empty_cache)."""
         self.server.execution_context.pipelines.clear()
+        # the elastic tier's per-signature processors hold their bundle
+        from ..graph.usdu_elastic import _tile_processor
+
+        _tile_processor.cache_clear()
         import gc
 
         gc.collect()

@@ -22,6 +22,7 @@ from typing import Any
 from aiohttp import WSMsgType, web
 
 from ..utils.async_helpers import run_blocking
+from ..utils.exceptions import MeshError
 from ..utils.logging import log
 
 
@@ -295,20 +296,28 @@ class WorkerRoutes:
         incidents = getattr(self.server, "incidents", None)
         if incidents is not None:
             info["status"]["incidents"] = incidents.status()
-        try:
-            from ..parallel.mesh import describe_topology, serving_mesh_summary
+        # Device enumeration off the loop: the CLI brings the backend up
+        # before it listens, but an embedded server may not have, and a
+        # first jax.devices() blocks for seconds. The error stays in the
+        # answer — chip_smoke.py and the panel both read it as a failure.
+        from ..parallel.mesh import describe_topology, serving_mesh_summary
 
-            info["topology"] = describe_topology()
-            # the mesh this process serves tile grants with (recorded
-            # by the elastic loop; knob-only resolution before one has
-            # run); a mesh-knob failure degrades only this key, never
-            # the device enumeration above
-            try:
-                info["topology"]["mesh"] = serving_mesh_summary()
-            except Exception as exc:  # noqa: BLE001 - best effort
-                info["topology"]["mesh"] = {"error": str(exc)}
-        except Exception as exc:  # noqa: BLE001 - best effort
+        try:
+            info["topology"] = await run_blocking(describe_topology)
+        except RuntimeError as exc:
             info["topology"] = {"error": str(exc)}
+        else:
+            # the mesh this process serves with (recorded at start-up /
+            # by the elastic loop; knob-only resolution before either)
+            try:
+                info["topology"]["mesh"] = await run_blocking(
+                    serving_mesh_summary
+                )
+            except (RuntimeError, MeshError) as exc:
+                info["topology"]["mesh"] = {"error": str(exc)}
+        from .. import native
+
+        info["data_plane"] = await run_blocking(native.backend)
         # Tokenizer fidelity: with the committed prose-trained stand-in
         # vocab, real SD/SDXL checkpoints get wrong token ids. The
         # reference inherits the exact tokenizer from ComfyUI's bundled
@@ -339,24 +348,6 @@ class WorkerRoutes:
         except Exception as exc:  # noqa: BLE001 - best effort
             info["t5_vocab_canonical"] = None
             info["t5_vocab_error"] = str(exc)
-        # Last bench accelerator-probe report (scripts/bench_probe via
-        # bench.py writes CDT_PROBE_REPORT): backend/stage/versions so
-        # operators see WHY accelerators fell back to CPU without
-        # digging through BENCH notes. Absent file = key omitted.
-        try:
-            from ..utils.constants import probe_report_path
-
-            probe_path = probe_report_path()
-            if probe_path is not None and os.path.exists(probe_path):
-                import json as json_mod
-
-                def _read_probe() -> Any:
-                    with open(probe_path, "r", encoding="utf-8") as fh:
-                        return json_mod.load(fh)
-
-                info["probe"] = await run_blocking(_read_probe)
-        except Exception as exc:  # noqa: BLE001 - best effort
-            info["probe"] = {"error": str(exc)}
         return web.json_response(info)
 
 

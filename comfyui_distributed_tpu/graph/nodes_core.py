@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from functools import partial
 from typing import Any
 
 import jax
@@ -28,7 +29,12 @@ import numpy as np
 
 from ..models import pipeline as pl
 from ..ops import samplers as smp
-from ..parallel.mesh import DATA_AXIS, data_axis_size, shard_map_compat
+from ..parallel.mesh import (
+    DATA_AXIS,
+    data_axis_size,
+    replicated,
+    shard_map_compat,
+)
 from ..utils import image as img_utils
 from ..utils.logging import log
 from .registry import register_node
@@ -650,19 +656,45 @@ def _sample_mesh(
     n = data_axis_size(mesh)
     keys = participant_keys(jax.random.key(spec.base_seed), n)
     keys = jax.device_put(keys, NamedSharding(mesh, P(DATA_AXIS)))
-    params = jax.device_put(bundle.params, NamedSharding(mesh, P()))
-    pos = jax.device_put(positive, NamedSharding(mesh, P()))
-    neg = jax.device_put(negative, NamedSharding(mesh, P()))
-    base = jax.device_put(latents, NamedSharding(mesh, P()))
-    mask = (
-        jax.device_put(
-            jnp.clip(noise_mask.astype(jnp.float32), 0.0, 1.0),
-            NamedSharding(mesh, P()),
+    everywhere = replicated(mesh)
+    params = jax.device_put(bundle.params, everywhere)
+    pos = jax.device_put(positive, everywhere)
+    neg = jax.device_put(negative, everywhere)
+    base = jax.device_put(latents, everywhere)
+    extra = ()
+    if noise_mask is not None:
+        extra = (
+            jax.device_put(
+                jnp.clip(noise_mask.astype(jnp.float32), 0.0, 1.0), everywhere
+            ),
         )
-        if noise_mask is not None
-        else None
+    out = _sample_mesh_jit(
+        pl._Static(bundle), pl._Static(mesh),
+        tuple(float(s) for s in np.asarray(sigmas)), float(cfg), sampler_name,
+        keys, params, pos, neg, base, *extra,
     )
+    return {"samples": out, "participant_major": True}
 
+
+@partial(
+    jax.jit,
+    static_argnames=(
+        "bundle_static", "mesh_static", "sigmas_t", "cfg", "sampler_name",
+    ),
+)
+def _sample_mesh_jit(
+    bundle_static, mesh_static, sigmas_t: tuple, cfg: float,
+    sampler_name: str, keys, params, pos, neg, base, *maybe_mask,
+):
+    """The compiled half of _sample_mesh, keyed on (bundle, mesh, sigma
+    grid, cfg, sampler) so a second request with another seed reuses
+    the program instead of tracing and building it again. sigmas_t is
+    a static tuple for the same reason as in pipeline._custom_sigmas_jit:
+    multistep samplers precompute numpy coefficients from the grid."""
+    from jax.sharding import PartitionSpec as P
+
+    bundle = bundle_static.value
+    sigmas = jnp.asarray(sigmas_t, jnp.float32)
     param, _shift = pl.model_schedule_info(bundle)
 
     def per_chip(keys_shard, params, pos, neg, base, *maybe_mask):
@@ -671,7 +703,7 @@ def _sample_mesh(
         noise_key, anc_key = jax.random.split(key)
         noise = jax.random.normal(noise_key, base.shape)
         x = smp.noise_latents(param, base, noise, sigmas[0])
-        model_fn = pl.guided_model(bundle, params, float(cfg))
+        model_fn = pl.guided_model(bundle, params, cfg)
         if mask_arr is not None:
             model_fn = smp.masked_inpaint_model(
                 model_fn, param, base, noise, mask_arr
@@ -685,20 +717,14 @@ def _sample_mesh(
             out = out * mask_arr + base * (1.0 - mask_arr)
         return out
 
-    extra = () if mask is None else (mask,)
-    in_specs = [P(DATA_AXIS), P(), P(), P(), P()] + (
-        [P()] if mask is not None else []
-    )
-    out = jax.jit(
-        shard_map_compat(
-            per_chip,
-            mesh=mesh,
-            in_specs=tuple(in_specs),
-            out_specs=P(DATA_AXIS),
-            check=False,
-        )
-    )(keys, params, pos, neg, base, *extra)
-    return {"samples": out, "participant_major": True}
+    in_specs = (P(DATA_AXIS),) + (P(),) * (4 + len(maybe_mask))
+    return shard_map_compat(
+        per_chip,
+        mesh=mesh_static.value,
+        in_specs=in_specs,
+        out_specs=P(DATA_AXIS),
+        check=False,
+    )(keys, params, pos, neg, base, *maybe_mask)
 
 
 @register_node
@@ -843,8 +869,40 @@ class VAEDecode:
     FUNCTION = "decode"
 
     def decode(self, samples: dict, vae: pl.PipelineBundle, context=None):
+        mesh = getattr(context, "mesh", None) if context is not None else None
+        if (
+            samples.get("participant_major")
+            and mesh is not None
+            and data_axis_size(mesh) > 1
+        ):
+            return (_decode_mesh(vae, mesh, samples["samples"]),)
         imgs = vae.vae.apply(vae.params["vae"], samples["samples"], method="decode")
         return (imgs,)
+
+
+def _decode_mesh(vae, mesh, latents) -> jax.Array:
+    """Decode a participant-major batch where it lies: each chip
+    decodes its own images under shard_map and the result stays
+    sharded over the data axis for the collector. Decoding the sharded
+    batch with plain ops instead asks XLA to partition the VAE
+    mid-block's Pallas kernel, which it cannot ("Mosaic kernels cannot
+    be automatically partitioned" — the first four-chip txt2img run)."""
+    params = jax.device_put(vae.params["vae"], replicated(mesh))
+    return _decode_mesh_jit(pl._Static(vae), pl._Static(mesh), params, latents)
+
+
+@partial(jax.jit, static_argnames=("vae_static", "mesh_static"))
+def _decode_mesh_jit(vae_static, mesh_static, params, latents):
+    from jax.sharding import PartitionSpec as P
+
+    vae = vae_static.value
+    return shard_map_compat(
+        lambda p, z: vae.vae.apply(p, z, method="decode"),
+        mesh=mesh_static.value,
+        in_specs=(P(), P(DATA_AXIS)),
+        out_specs=P(DATA_AXIS),
+        check=False,
+    )(params, latents)
 
 
 @register_node

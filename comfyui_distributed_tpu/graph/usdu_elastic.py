@@ -28,6 +28,7 @@ pattern, reference tests/test_static_mode.py).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Any, Optional
@@ -339,6 +340,14 @@ class HTTPWorkClient:
                         "/distributed/request_image", payload, op="pull"
                     ),
                     work_pull_policy(),
+                    # patient with an unreachable or failing master; a
+                    # 4xx is its verdict. "No such job" comes after the
+                    # master's own init grace, so it means the job is
+                    # over — a worker that arrives late (still loading
+                    # its model while a warm master finished alone) must
+                    # leave at once, not hold its prompt queue for ten
+                    # backed-off retries while the next job goes by
+                    retryable=self._retryable_failures(),
                     label=f"request_tile:{self.worker_id}",
                 )
             except Exception as exc:  # noqa: BLE001 - exhausted retries
@@ -362,10 +371,10 @@ class HTTPWorkClient:
             return None
         return out
 
-    # Submits retry transport failures and 5xx answers only — a 4xx is
-    # the master's verdict (bad job id, malformed entry) and re-sending
-    # the same payload can't change it.
-    def _submit_retryable(self):
+    # Pulls and submits retry transport failures and 5xx answers only —
+    # a 4xx is the master's verdict (bad job id, malformed entry) and
+    # re-sending the same payload can't change it.
+    def _retryable_failures(self):
         return transport_errors() + (TransientServerError,)
 
     def submit_tiles(self, entries: list[dict], is_final: bool) -> None:
@@ -382,7 +391,7 @@ class HTTPWorkClient:
                     op="submit",
                 ),
                 http_policy(),
-                retryable=self._submit_retryable(),
+                retryable=self._retryable_failures(),
                 label=f"submit_tiles:{self.worker_id}",
             )
 
@@ -405,7 +414,7 @@ class HTTPWorkClient:
                     op="submit",
                 ),
                 http_policy(),
-                retryable=self._submit_retryable(),
+                retryable=self._retryable_failures(),
                 label=f"submit_image:{self.worker_id}",
             )
 
@@ -755,8 +764,8 @@ def run_worker_loop(
 
     # Warm the tile-processor compile while the ready poll waits on the
     # master: with the persistent compilation cache hot this turns the
-    # 14-40 s first compile (BENCH_NOTES r5) into a cache load that
-    # finishes before the first grant arrives.
+    # first compile into a cache load that finishes before the first
+    # grant arrives.
     warm = None
     if WARM_COMPILE:
         warm = threading.Thread(
@@ -938,10 +947,22 @@ def _jit_tile_processor(bundle, grid, steps, sampler, scheduler, cfg, denoise,
                         tiled_decode=False):
     """fn(params, tile, key, pos, neg, yx): pos/neg must be prepped via
     ops.upscale.prep_cond_for_tiles (per-tile hint/mask windows are
-    sliced at yx inside)."""
+    sliced at yx inside). One compiled function per signature, kept
+    across jobs like the scan tier's: a new jax.jit per job re-traces
+    and re-fetches its program in every process for every job."""
+    return _tile_processor(
+        pl._Static(bundle), grid, int(steps), str(sampler), str(scheduler),
+        float(cfg), float(denoise), bool(tiled_decode),
+    )
+
+
+@functools.lru_cache(maxsize=8)
+def _tile_processor(bundle_static, grid, steps, sampler, scheduler, cfg,
+                    denoise, tiled_decode):
+    bundle = bundle_static.value
     param, shift = pl.model_schedule_info(bundle)
     sigmas = smp.get_model_sigmas(
-        param, scheduler, int(steps), denoise=float(denoise), flow_shift=shift
+        param, scheduler, steps, denoise=denoise, flow_shift=shift
     )
 
     @jax.jit
@@ -953,7 +974,7 @@ def _jit_tile_processor(bundle, grid, steps, sampler, scheduler, cfg, denoise,
         x = smp.noise_latents(
             param, z, jax.random.normal(noise_key, z.shape), sigmas[0]
         )
-        model_fn = pl.guided_model(bundle, params, float(cfg))
+        model_fn = pl.guided_model(bundle, params, cfg)
         z_out = smp.sample(
             model_fn, x, sigmas, (pos_t, neg_t), sampler, anc_key,
             flow=(param == "flow"),

@@ -22,23 +22,33 @@ from .registry import create_model, get_config
 from .text_encoder import Tokenizer
 
 
+def params_storage_dtype():
+    """The dtype floating-point weights are STORED in (the models
+    already compute in bfloat16 either way). CDT_PARAMS_DTYPE names it
+    outright; unset, the platform decides: bfloat16 on an accelerator —
+    SDXL's ~3.5 B weights are ~13.9 GB in float32, which does not leave
+    a 16 GB chip room to run — and float32 on the CPU, where the
+    committed goldens pin float32 weights. Returns None for "leave the
+    weights as initialised" (float32)."""
+    want = os.environ.get("CDT_PARAMS_DTYPE", "")
+    if want:
+        return jnp.dtype(want)
+    return None if jax.default_backend() == "cpu" else jnp.dtype(jnp.bfloat16)
+
+
 def maybe_cast_params(tree):
-    """CDT_PARAMS_DTYPE=bfloat16 stores floating-point weights in bf16
-    (halves HBM — the big lever for real checkpoints on 16G chips; the
-    models already COMPUTE in bf16, so only the storage precision
-    changes). Unset keeps float32: CPU golden numerics are pinned at
-    f32 weights. Applied by every model/VAE/TE/ControlNet/upscaler
-    loader at bundle-build time.
+    """Cast floating-point weights to params_storage_dtype(). Applied
+    by every model/VAE/TE/ControlNet/upscaler loader at bundle-build
+    time.
 
     Takes OWNERSHIP of the tree: each source buffer is freed as soon
     as its cast completes, so the transient peak stays at the f32
     footprint instead of f32+bf16 — the difference between fitting
     and OOMing an SDXL load on a 16G chip. Callers must not reuse the
     input tree afterwards (every loader discards it immediately)."""
-    want = os.environ.get("CDT_PARAMS_DTYPE", "")
-    if not want:
+    dt = params_storage_dtype()
+    if dt is None:
         return tree
-    dt = jnp.dtype(want)
 
     def cast(x):
         if (
@@ -48,15 +58,28 @@ def maybe_cast_params(tree):
         ):
             y = x.astype(dt)
             if isinstance(x, jax.Array):
-                try:
-                    y.block_until_ready()
-                    x.delete()
-                except Exception:
-                    pass
+                y.block_until_ready()
+                x.delete()
             return y
         return x
 
     return jax.tree_util.tree_map(cast, tree)
+
+
+def init_params(module, key, *args, settle: bool = True, **kwargs):
+    """Seeded random parameters for `module`: flax's `lazy_init`, which
+    runs the parameter initializers and only shape-evaluates the
+    forward pass. The values are bit-identical to `module.init` on the
+    same dummy inputs; what goes is the forward pass itself, which on
+    an accelerator was ~70% of the ~1,000 small programs a model load
+    compiled one by one. Each component is settled into its storage
+    dtype as soon as it exists (`settle=False` keeps float32 for a
+    caller about to map a checkpoint onto the tree)."""
+    abstract_args, abstract_kwargs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (args, kwargs)
+    )
+    params = module.lazy_init(key, *abstract_args, **abstract_kwargs)
+    return maybe_cast_params(params) if settle else params
 
 
 @dataclasses.dataclass
@@ -278,6 +301,11 @@ def load_pipeline(
     root = jax.random.key(seed)
     k_unet, k_vae, k_te = jax.random.split(root, 3)
 
+    # Seeded-random bundles settle each component into its storage
+    # dtype as it is built; with a checkpoint to map onto them the
+    # float32 templates stay as they are until the final cast.
+    settle = not (checkpoint or os.environ.get("CDT_CHECKPOINT_DIR"))
+
     # Init with minimal dummy shapes; flax params are shape-polymorphic
     # across batch/spatial dims for these architectures.
     lat = jnp.zeros((1, 16, 16, vae_cfg.latent_channels))
@@ -285,19 +313,21 @@ def load_pipeline(
     ts = jnp.zeros((1,))
     if family == "dit":  # video DiT
         lat5 = jnp.zeros((1, 4, 16, 16, unet_cfg.in_channels))
-        unet_params = unet.init(k_unet, lat5, ts, ctx)
+        unet_params = init_params(unet, k_unet, lat5, ts, ctx, settle=settle)
     elif family in ("mmdit", "sd3"):
-        unet_params = unet.init(
-            k_unet, lat, ts, ctx, y=jnp.zeros((1, unet_cfg.adm_in_channels))
+        unet_params = init_params(
+            unet, k_unet, lat, ts, ctx, settle=settle,
+            y=jnp.zeros((1, unet_cfg.adm_in_channels)),
         )
     else:
-        unet_params = unet.init(
-            k_unet, _unet_init_latents(unet_cfg, lat.shape[-1]), ts, ctx
+        unet_params = init_params(
+            unet, k_unet, _unet_init_latents(unet_cfg, lat.shape[-1]), ts,
+            ctx, settle=settle,
         )
     img = jnp.zeros((1, 32, 32, 3))
-    vae_params = vae.init(k_vae, img)
+    vae_params = init_params(vae, k_vae, img, settle=settle)
     tokens = jnp.zeros((1, te_cfg.max_length), jnp.int32)
-    te_params = te.init(k_te, tokens)
+    te_params = init_params(te, k_te, tokens, settle=settle)
 
     te2 = None
     te2_params = None
@@ -305,14 +335,18 @@ def load_pipeline(
         te2 = create_model(te2_name)
         te2_cfg = get_config(te2_name)
         tokens2 = jnp.zeros((1, te2_cfg.max_length), jnp.int32)
-        te2_params = te2.init(jax.random.fold_in(k_te, 2), tokens2)
+        te2_params = init_params(
+            te2, jax.random.fold_in(k_te, 2), tokens2, settle=settle
+        )
     te3 = None
     te3_params = None
     if te3_name:
         te3 = create_model(te3_name)
         te3_cfg = get_config(te3_name)
         tokens3 = jnp.zeros((1, te3_cfg.max_length), jnp.int32)
-        te3_params = te3.init(jax.random.fold_in(k_te, 3), tokens3)
+        te3_params = init_params(
+            te3, jax.random.fold_in(k_te, 3), tokens3, settle=settle
+        )
 
     from . import sd_checkpoint as sdc
 

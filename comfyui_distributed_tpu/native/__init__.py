@@ -18,7 +18,7 @@ from typing import Optional
 
 import numpy as np
 
-from ..utils.logging import debug_log
+from ..utils.logging import log
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -44,25 +44,54 @@ def _build_dir() -> str:
 _CXX_FLAGS = ("-O3", "-march=native", "-ffp-contract=off", "-shared", "-fPIC")
 
 
+def _host_tag() -> bytes:
+    """What -march=native resolves to on THIS host, in the compiler's
+    own words. Part of the artefact key, so a build directory copied to
+    another machine (the chip tool copies the tree as it stands) is
+    rebuilt there instead of loaded. No compiler, no tag — and no build
+    either: _compile reports that."""
+    try:
+        return subprocess.run(
+            ["g++", "-march=native", "-Q", "--help=target"],
+            check=True, capture_output=True, timeout=30,
+        ).stdout
+    except (OSError, subprocess.SubprocessError):
+        return b""
+
+
 def _compile() -> Optional[str]:
     src = _source_path()
     out_dir = _build_dir()
     os.makedirs(out_dir, exist_ok=True)
-    # cache key: source + flags digest, so edits OR flag changes rebuild
+    # cache key: source + flags + host digest, so edits, flag changes
+    # OR a different CPU rebuild
     with open(src, "rb") as fh:
         hasher = hashlib.sha256(fh.read())
     hasher.update(" ".join(_CXX_FLAGS).encode())
+    hasher.update(_host_tag())
     digest = hasher.hexdigest()[:16]
     so_path = os.path.join(out_dir, f"blendlib_{digest}.so")
     if os.path.isfile(so_path):
         return so_path
-    cmd = ["g++", *_CXX_FLAGS, src, "-o", so_path]
+    # build to a private name and rename: a co-hosted worker starting
+    # at the same moment must never dlopen a half-written file
+    tmp_path = f"{so_path}.{os.getpid()}.tmp"
+    cmd = ["g++", *_CXX_FLAGS, src, "-o", tmp_path]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp_path, so_path)
         return so_path
     except (OSError, subprocess.SubprocessError) as exc:
-        debug_log(f"native build failed ({exc}); using numpy fallback")
+        log(f"native build failed ({exc}); using the numpy data plane")
         return None
+
+
+def backend() -> str:
+    """Which data plane this process runs: "native" (the compiled C++
+    library) or "numpy" (its drop-in twin — no toolchain, or the build
+    failed; the reason is logged once by the build). The server logs
+    this at start-up and reports it in /distributed/system_info."""
+    return "native" if get_lib() is not None else "numpy"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

@@ -219,8 +219,8 @@ def blend_tiles(tiles: jax.Array, grid: TileGrid) -> jax.Array:
     canvas updates (default), and a single segment-sum scatter-add
     with static indices (CDT_BLEND=segment). Measured at a 4K grid
     (256 tiles, CPU): scan 81ms vs segment 323ms — XLA scatter loses
-    to the serialized windowed adds there; the knob exists so the
-    same A/B can be re-run on real TPU hardware (BENCH_NOTES.md).
+    to the serialized windowed adds there; on a TPU the two have not
+    been compared (ROADMAP Queue 1 item 10).
     """
     import os
 

@@ -87,22 +87,11 @@ def build_mesh(
 
 
 def shard_map_compat(fn, *, mesh: Mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions. jax >= 0.5 exposes it at
-    the top level with ``check_vma``; 0.4.x has only
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``. Every
-    mesh-tier call site routes through here — without the shim the
-    whole sharded path raises AttributeError on 0.4.x runtimes the
-    moment a multi-device mesh exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with this codebase's argument names; every
+    mesh-tier call site routes through here."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check,
+        check_vma=check,
     )
 
 
@@ -117,17 +106,19 @@ def model_axis_size(mesh: Mesh) -> int:
 def _parse_mesh_shape(raw: str | None) -> dict[str, int] | None:
     """``CDT_MESH_SHAPE`` grammar: ``"<data>,<model>"`` (e.g. ``"4,1"``,
     ``"-1,2"``; -1 infers the remainder) or a single ``"<data>"``.
-    Malformed values fall back to None (auto layout) rather than
-    refusing to serve."""
+    Unset is None (auto layout); a value that does not parse raises —
+    an operator who set it did not ask for the auto layout."""
     if not raw:
         return None
     parts = [p.strip() for p in raw.split(",") if p.strip()]
     try:
         sizes = [int(p) for p in parts]
     except ValueError:
-        return None
+        sizes = []
     if not sizes or len(sizes) > 2:
-        return None
+        raise MeshError(
+            f"CDT_MESH_SHAPE={raw!r}: expected '<data>' or '<data>,<model>'"
+        )
     if len(sizes) == 1:
         sizes.append(1)
     return {DATA_AXIS: sizes[0], MODEL_AXIS: sizes[1]}
@@ -151,15 +142,13 @@ def worker_mesh(
 
     Returns None when the resolved mesh would be a single participant
     with no model sharding — callers then take the unsharded path.
+    A backend that cannot start, or knobs that do not fit the host,
+    raise: one silent participant on a four-chip host is a quarter of
+    the machine nobody knows is missing.
     """
     if devices is None:
-        try:
-            devices = jax.local_devices()
-        except Exception:  # noqa: BLE001 - backend not available
-            return None
+        devices = jax.local_devices()
     devices = list(devices)
-    if not devices:
-        return None
     n = len(devices)
     shape = _parse_mesh_shape(os.environ.get("CDT_MESH_SHAPE"))
     try:
@@ -186,13 +175,7 @@ def worker_mesh(
     explicit = math.prod(s for s in shape.values() if s != -1)
     if all(s != -1 for s in shape.values()) and 0 < explicit < n:
         devices = devices[:explicit]
-    try:
-        mesh = build_mesh(shape, devices)
-    except MeshError as exc:
-        # mesh knobs are advisory, like capacity: a non-divisible
-        # combination must not kill the worker before its first pull
-        debug_log(f"worker_mesh: {shape} over {len(devices)} devices: {exc}")
-        return None
+    mesh = build_mesh(shape, devices)
     if data_axis_size(mesh) <= 1 and model_axis_size(mesh) <= 1:
         return None
     return mesh
@@ -296,18 +279,30 @@ def describe_topology() -> dict[str, Any]:
     """Enumerate local accelerator topology for the control plane.
 
     The TPU replacement for the reference's `/distributed/system_info`
-    CUDA enumeration (api/worker_routes.py:237-274): chip ids, platform,
-    coords, process index, and any chip-visibility pinning.
+    CUDA enumeration (api/worker_routes.py:237-274): platform, chip
+    ids, kind, coords, memory, process index, any chip-visibility
+    pinning, and the versions of the packages that make up the
+    backend.
     """
+    import importlib.metadata as metadata
+
     devices = jax.devices()
     local = jax.local_devices()
+    versions = {}
+    for dist in ("jax", "jaxlib", "libtpu"):
+        try:
+            versions[dist] = metadata.version(dist)
+        except metadata.PackageNotFoundError:
+            versions[dist] = None
     info: dict[str, Any] = {
-        "platform": devices[0].platform if devices else "none",
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
         "device_count": len(devices),
         "local_device_count": len(local),
         "process_index": jax.process_index(),
         "process_count": jax.process_count(),
         "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        "versions": versions,
         "devices": [],
     }
     for dev in local:
@@ -315,14 +310,14 @@ def describe_topology() -> dict[str, Any]:
             "id": dev.id,
             "platform": dev.platform,
             "process_index": dev.process_index,
+            "device_kind": dev.device_kind,
         }
-        for attr in ("coords", "core_on_chip", "device_kind", "memory_stats"):
-            try:
-                value = getattr(dev, attr, None)
-                value = value() if callable(value) else value
-            except Exception:
-                value = None
+        for attr in ("coords", "core_on_chip"):
+            value = getattr(dev, attr, None)
             if value is not None:
                 entry[attr] = value
+        stats = dev.memory_stats()
+        if stats is not None:
+            entry["memory_stats"] = stats
         info["devices"].append(entry)
     return info

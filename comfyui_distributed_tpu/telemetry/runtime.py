@@ -7,7 +7,7 @@ cache, or running the chip's HBM to the edge. The same snapshot is
 stamped into `bench.py` output so every BENCH round carries its
 profiling context.
 
-Three sources, all optional and all failure-isolated:
+Three sources:
 
 - **jax.monitoring** — `install_jax_monitoring()` registers listeners
   for the backend-compile duration event and the compilation-cache
@@ -16,9 +16,9 @@ Three sources, all optional and all failure-isolated:
   the first program.
 - **device.memory_stats()** — per-device HBM gauges
   (`bytes_in_use`, `peak_bytes_in_use`, `bytes_limit`, ...). Only
-  consulted when jax is ALREADY imported: a metrics scrape must never
-  be the thing that triggers backend init on a dark chip
-  (docs/operator-runbook.md §4b). `CDT_RUNTIME_DEVICE_STATS=0`
+  consulted when jax is ALREADY imported AND its backend is already
+  up: a metrics scrape runs on the event loop and must never be the
+  thing that initialises a backend. `CDT_RUNTIME_DEVICE_STATS=0`
   disables device enumeration at scrape entirely.
 - **psutil** — host RSS of this process.
 
@@ -58,16 +58,13 @@ _bound_registry: MetricsRegistry | None = None
 _bind_lock = threading.Lock()
 
 
-def install_jax_monitoring() -> bool:
+def install_jax_monitoring() -> None:
     """Register jax.monitoring listeners for compile + cache events;
-    idempotent; returns False when the API is unavailable."""
+    idempotent."""
     global _monitoring_installed
     if _monitoring_installed:
-        return True
-    try:
-        from jax import monitoring
-    except Exception:  # noqa: BLE001 - jax absent or too old
-        return False
+        return
+    from jax import monitoring
 
     def on_event(event: str, **kwargs: Any) -> None:
         with _tallies_lock:
@@ -82,13 +79,9 @@ def install_jax_monitoring() -> bool:
                 _tallies["compiles"] += 1
                 _tallies["compile_time_s"] += float(duration)
 
-    try:
-        monitoring.register_event_listener(on_event)
-        monitoring.register_event_duration_secs_listener(on_duration)
-    except Exception:  # noqa: BLE001 - listener API drift
-        return False
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
     _monitoring_installed = True
-    return True
 
 
 def _host_rss_bytes() -> int | None:
@@ -108,23 +101,16 @@ def _host_rss_bytes() -> int | None:
 
 
 def _device_memory() -> list[dict[str, Any]]:
-    """Per-device memory stats, ONLY if jax is already initialized in
+    """Per-device memory stats, ONLY if jax's backend is already up in
     this process (never trigger backend init from a scrape)."""
     if os.environ.get("CDT_RUNTIME_DEVICE_STATS", "1") == "0":
         return []
     jax = sys.modules.get("jax")
-    if jax is None:
-        return []
-    try:
-        devices = jax.devices()
-    except Exception:  # noqa: BLE001 - backend not ready
+    if jax is None or not backend_is_up():
         return []
     out = []
-    for device in devices:
-        try:
-            stats = device.memory_stats() or {}
-        except Exception:  # noqa: BLE001 - CPU devices often raise
-            stats = {}
+    for device in jax.local_devices():
+        stats = device.memory_stats() or {}
         out.append(
             {
                 "id": f"{device.platform}:{getattr(device, 'id', '?')}",
@@ -134,6 +120,16 @@ def _device_memory() -> list[dict[str, Any]]:
             }
         )
     return out
+
+
+def backend_is_up() -> bool:
+    """Whether a JAX backend has been initialised in this process. jax
+    has no public query for it, and asking for devices is the very call
+    that initialises one — this is the one place the private call
+    lives."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
 def collect_runtime_gauges() -> None:
@@ -149,7 +145,7 @@ def collect_runtime_gauges() -> None:
     if rss is not None:
         instruments.host_rss_bytes().set(rss)
     gauge = instruments.device_memory_bytes()
-    gauge.clear()  # devices can disappear (tunnel drop); don't freeze stale series
+    gauge.clear()  # stats keys vary by backend; don't freeze stale series
     for device in _device_memory():
         for stat, value in device["memory"].items():
             gauge.set(value, device=device["id"], stat=stat)
@@ -177,10 +173,7 @@ def runtime_snapshot() -> dict[str, Any]:
     out["compile_time_s"] = round(out["compile_time_s"], 3)
     jax = sys.modules.get("jax")
     if jax is not None:
-        try:
-            cache_dir = jax.config.jax_compilation_cache_dir
-        except Exception:  # noqa: BLE001 - config name drift
-            cache_dir = None
+        cache_dir = jax.config.jax_compilation_cache_dir
         if cache_dir:
             # the hit/miss tallies above say whether it actually helped
             out["compile_cache_dir"] = cache_dir

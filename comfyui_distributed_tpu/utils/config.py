@@ -28,8 +28,11 @@ CONFIG_FILENAME = "tpu_config.json"
 DEFAULT_CONFIG: dict[str, Any] = {
     "master": {
         "host": "",
-        # Which local chips the master's own compute participant uses.
-        "tpu_chips": [0],
+        # Chips the master process pins itself to before its backend
+        # starts (process-per-chip mode: the rest are left for managed
+        # workers). Empty = every local chip, driven as one in-process
+        # mesh — the TPU-native path.
+        "tpu_chips": [],
     },
     "mesh": {
         # Logical axis names for the local slice mesh. "data" is the

@@ -51,27 +51,18 @@ MAX_TILE_BATCH = _env_int("CDT_MAX_BATCH", 20)
 # VAE programs; MXU utilization knob). 1 = reference numerics
 # (bit-identical to the committed goldens); >1 is allclose.
 # CDT_TILE_BATCH overrides; unset defaults by platform at first use:
-# CPU stays 1 (golden-exact, r1-r5 trendline comparability),
-# accelerators get 8 (measured best on v5e — BENCH_NOTES r5 A/B:
-# K=8 is +4.0% tiles/s over K=1).
+# CPU stays 1 (golden-exact), accelerators get 8 (batch-1 convs leave
+# most of the MXU idle).
 def tile_scan_batch() -> int:
-    """Platform-aware CDT_TILE_BATCH resolution. Never triggers backend
-    init: the platform is only consulted when jax is already imported
-    (the callers are compute paths where it always is); otherwise the
-    conservative CPU default applies."""
+    """Platform-aware CDT_TILE_BATCH resolution. The callers are
+    compute paths, so the backend is already up; a backend that cannot
+    answer raises here rather than quietly taking the CPU value."""
     explicit = _env_int("CDT_TILE_BATCH", 0)
     if explicit > 0:
         return explicit
-    import sys
+    import jax
 
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return 1
-    try:
-        platform = jax.default_backend()
-    except Exception:  # noqa: BLE001 - backend not ready
-        return 1
-    return 1 if platform == "cpu" else 8
+    return 1 if jax.default_backend() == "cpu" else 8
 MAX_AUDIO_PAYLOAD_BYTES = _env_int("CDT_MAX_AUDIO_PAYLOAD_BYTES", 256 * 1024 * 1024)
 
 # --- orchestration concurrency ------------------------------------------
@@ -248,36 +239,34 @@ SHED_COOLDOWN_SECONDS = _env_float("CDT_SHED_COOLDOWN", 5.0)
 PIPELINE_ENABLED = os.environ.get("CDT_PIPELINE", "1") != "0"
 # In-flight device batches the sampler may run ahead of the I/O stage
 # (queue bound). 1 keeps at most two batches materialized (one in
-# readback, one dispatched) — the bf16 HBM margin from the r5 OOM
-# finding; raise only on chips with headroom.
+# readback, one dispatched) beside SDXL's weights on a 16 GB chip;
+# raise only on chips with headroom.
 PIPELINE_DEPTH = _env_int("CDT_PIPELINE_DEPTH", 1)
 # Pull prefetch: claim the next grant while the device samples the
 # current one (bounded to ONE grant ahead so a crash never orphans a
 # deep claim). 0 pulls synchronously between batches.
 PIPELINE_PREFETCH = os.environ.get("CDT_PIPELINE_PREFETCH", "1") != "0"
 # Warm the tile-processor compile during the worker's ready-poll
-# window so the first pull doesn't eat the (14-40 s on TPU, r5) first
-# compile. With the persistent compilation cache hot this is a cache
+# window so the first pull doesn't eat the first compile. With the persistent compilation cache hot this is a cache
 # load, not a compile.
 WARM_COMPILE = os.environ.get("CDT_WARM_COMPILE", "1") != "0"
 
 # --- persistent XLA compilation cache -------------------------------------
-# First compiles dominate a chip session's budget (BENCH_NOTES r5:
-# 14-40 s with the flash kernel); the persistent cache makes every
-# process after the first skip them. CDT_COMPILE_CACHE_DIR overrides
-# the location; "0"/"off" disables. The default lives under the worker
-# base dir (cwd) so co-hosted master+workers share one cache.
-COMPILE_CACHE_DISABLED_VALUES = ("0", "off", "none")
+# First compiles dominate a cold start; the persistent cache makes every
+# process after the first skip them. JAX_COMPILATION_CACHE_DIR places
+# the cache from outside (jax reads it itself — the program then sets
+# no directory in code). Unset, the cache lives at ONE fixed path under
+# the checkout, derived from the package location: the path is part of
+# the cache key, so a directory that follows the working directory
+# never hits.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
-def compile_cache_dir() -> str | None:
-    """Resolved persistent-compilation-cache directory (None = off)."""
-    raw = os.environ.get("CDT_COMPILE_CACHE_DIR")
-    if raw is not None:
-        if raw.strip().lower() in COMPILE_CACHE_DISABLED_VALUES or not raw.strip():
-            return None
-        return raw
-    return os.path.join(os.getcwd(), ".cdt", "compile_cache")
+def default_compile_cache_dir() -> str:
+    """The in-checkout cache location used when COMPILE_CACHE_ENV is
+    unset: <checkout>/.cdt/compile_cache, independent of cwd."""
+    package_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_dir), ".cdt", "compile_cache")
 
 
 # --- high availability: lease, standby, failover, push grants -------------
@@ -407,16 +396,6 @@ def profile_dir_from_env() -> str | None:
     incident-dir idiom."""
     raw = os.environ.get("CDT_PROFILE_DIR", "").strip()
     return raw or None
-
-
-def probe_report_path() -> str | None:
-    """Where bench.py persists its last accelerator-probe report (and
-    GET /distributed/system_info reads it back). Resolved at call time;
-    empty/"0"/"off"/"none" disables the handoff."""
-    raw = os.environ.get("CDT_PROBE_REPORT", ".cdt/bench_probe.json").strip()
-    if not raw or raw.lower() in CACHE_DIR_DISABLED_VALUES:
-        return None
-    return raw
 
 
 # --- content-addressed tile result cache (cache/) -------------------------

@@ -196,8 +196,6 @@ KNOBS: tuple[Knob, ...] = (
     Knob("CDT_WARM_COMPILE", "1", "pipeline",
          "`0` skips AOT-compiling the steady-state tile bucket during the "
          "worker's ready-poll window."),
-    Knob("CDT_COMPILE_CACHE_DIR", "./.cdt/compile_cache", "pipeline",
-         "Persistent XLA compilation cache directory; `0`/`off`/`none` disables."),
     # --- durability ------------------------------------------------------
     Knob("CDT_JOURNAL_DIR", "unset", "durability",
          "Directory for the control-plane write-ahead journal + snapshots; "
@@ -304,10 +302,6 @@ KNOBS: tuple[Knob, ...] = (
     Knob("CDT_FLEET_TTL", "120.0", "telemetry",
          "Seconds without a snapshot before a worker is evicted from the "
          "fleet view (all its retained series drop)."),
-    Knob("CDT_PROBE_REPORT", "./.cdt/bench_probe.json", "telemetry",
-         "Path bench.py persists its backend probe report (backend, stage, "
-         "library versions) to; `GET /distributed/system_info` serves it "
-         "under `probe`. `0`/`off`/`none` disables persistence."),
     Knob("CDT_PROFILE_AUTO", "0", "telemetry",
          "`1` makes every incident bundle capture a short device trace "
          "(requires CDT_PROFILE_DIR; the bundle records the capture ids)."),
@@ -457,8 +451,9 @@ KNOBS: tuple[Knob, ...] = (
          "the committed fallback vocab."),
     Knob("CDT_LORA_DIR", "empty", "models",
          "Root directory for LoRA adapter files."),
-    Knob("CDT_PARAMS_DTYPE", "empty", "models",
-         "`bfloat16` stores floating-point weights in bf16 (half HBM footprint)."),
+    Knob("CDT_PARAMS_DTYPE", "platform-aware (CPU float32, accelerators bfloat16)", "models",
+         "Storage dtype of floating-point weights (the models compute in "
+         "bfloat16 either way); SDXL in float32 does not fit a 16 GB chip."),
     # --- ops -------------------------------------------------------------
     Knob("CDT_FLASH", "unset", "ops",
          "`0` force-disables the Pallas flash-attention kernel."),

@@ -5,9 +5,9 @@ workers/process_manager.py + workers/process/*): build a launch
 command, spawn with per-worker env (chip pinning, role flag, master
 pid), log to per-worker files, persist PIDs into config
 managed_processes for restore-on-restart, and stop via process-tree
-kill. TPU adaptations: chip pinning via TPU_VISIBLE_CHIPS instead of
-CUDA_VISIBLE_DEVICES; workers run `python -m comfyui_distributed_tpu
---port N --worker`.
+kill. TPU adaptations: chip pinning via the libtpu sub-host process
+environment (chip_environment) instead of CUDA_VISIBLE_DEVICES; workers
+run `python -m comfyui_distributed_tpu --port N --worker`.
 """
 
 from __future__ import annotations
@@ -65,6 +65,44 @@ def sanitize_extra_args(extra: str) -> list[str]:
     return shlex.split(extra)
 
 
+def chip_environment(chips: list[int]) -> dict[str, str]:
+    """The environment libtpu needs to run as a sub-host process on
+    `chips`: visibility alone leaves it assuming the whole host's
+    topology (and the host-wide lock), so the process bounds, a private
+    controller port, a metrics port of its own and the multi-load
+    permit travel with it. Each chip set is its own one-process slice —
+    the processes talk HTTP, not ICI. A TPU host image exports the
+    whole-host values under libtpu's older names
+    (TPU_CHIPS_PER_HOST_BOUNDS=2,2,1 ...), so both generations of each
+    name are set: whichever libtpu reads, it reads this process's.
+    Empty = the whole host, which needs nothing."""
+    if not chips:
+        return {}
+    bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}.get(len(chips))
+    if bounds is None:
+        raise ProcessError(
+            f"tpu_chips {chips}: a process takes 1, 2, 4 or 8 chips"
+        )
+    visible = ",".join(str(c) for c in chips)
+    port = str(8476 + min(chips))
+    return {
+        TPU_VISIBLE_CHIPS_ENV: visible,
+        "TPU_VISIBLE_DEVICES": visible,
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+        "TPU_CHIPS_PER_HOST_BOUNDS": bounds,
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_HOST_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": port,
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+        "TPU_MESH_CONTROLLER_PORT": port,
+        "TPU_RUNTIME_METRICS_PORTS": ",".join(str(8431 + c) for c in chips),
+        "CLOUD_TPU_TASK_ID": "0",
+        "TPU_WORKER_ID": "0",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+
+
 class WorkerProcessManager:
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -99,9 +137,20 @@ class WorkerProcessManager:
             env = dict(os.environ)
             env[WORKER_ENV_FLAG] = "1"
             env[MASTER_PID_ENV] = str(os.getpid())
-            chips = worker.get("tpu_chips") or []
-            if chips:
-                env[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(c) for c in chips)
+            chips = [int(c) for c in worker.get("tpu_chips") or []]
+            held = os.environ.get(TPU_VISIBLE_CHIPS_ENV)
+            if chips and (
+                not held or set(chips) & {int(c) for c in held.split(",")}
+            ):
+                # libtpu gives a chip to one process; a launch that
+                # cannot get its chip would die in its log, not here
+                raise ProcessError(
+                    f"worker {worker_id} wants chips {chips} but this "
+                    f"master holds {held or 'every local chip'}: set "
+                    "master.tpu_chips to the master's own chips and "
+                    "restart it (process-per-chip mode)"
+                )
+            env.update(chip_environment(chips))
             cmd = self.build_launch_command(worker)
 
             os.makedirs(logs_dir(), exist_ok=True)

@@ -19,11 +19,13 @@ from typing import Any
 from ..utils import config as config_mod
 from ..utils.constants import (
     AUTO_LAUNCH_DELAY_SECONDS,
+    COMPILE_CACHE_ENV,
+    TPU_VISIBLE_CHIPS_ENV,
     WORKER_ENV_FLAG,
-    compile_cache_dir,
+    default_compile_cache_dir,
 )
-from ..utils.logging import debug_log, log
-from .process_manager import get_worker_manager
+from ..utils.logging import log
+from .process_manager import chip_environment, get_worker_manager
 
 _cleanup_done = threading.Event()
 
@@ -32,54 +34,122 @@ def is_worker_process() -> bool:
     return os.environ.get(WORKER_ENV_FLAG) == "1"
 
 
-def configure_compile_cache() -> str | None:
-    """Point JAX's persistent compilation cache at the shared on-disk
-    directory (CDT_COMPILE_CACHE_DIR; see utils/constants) so every
-    process after the first skips its first compiles — 14-40 s each on
-    TPU with the flash kernel (BENCH_NOTES r5), previously re-paid by
-    EVERY worker process. Must run before the first jit compile; safe
-    any time before backend-heavy work. Returns the cache dir in use,
-    or None when disabled/unavailable.
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache so every process
+    after the first skips its first compiles. Where
+    JAX_COMPILATION_CACHE_DIR is set jax has already read it and no
+    directory is set in code; otherwise the cache goes to the fixed
+    in-checkout path (utils/constants.default_compile_cache_dir).
+    Master, managed workers, bench.py and chip_smoke.py all come
+    through here. Must run before the first jit compile. Returns the
+    directory in use.
 
     Thresholds are zeroed so even small/fast programs cache — the
-    elastic tier compiles one tile-processor per shape bucket and every
-    one of them is worth persisting. jax.monitoring cache hit/miss
-    events land in cdt_jax_cache_hits/misses on /distributed/metrics
-    (telemetry/runtime.py)."""
-    cache_dir = compile_cache_dir()
-    if cache_dir is None:
-        return None
-    try:
-        import jax
+    elastic tier compiles one tile-processor per shape bucket, a model
+    load dispatches ~1,000 small eager programs, and every one of them
+    is worth persisting. jax.monitoring cache hit/miss events land in
+    cdt_jax_cache_hits/misses on /distributed/metrics
+    (telemetry/runtime.py) — installed here, the earliest
+    backend-adjacent moment every process passes through, so the
+    tallies count from the FIRST program."""
+    import jax
 
+    from ..telemetry.runtime import install_jax_monitoring
+
+    if not os.environ.get(COMPILE_CACHE_ENV):
+        cache_dir = default_compile_cache_dir()
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:  # noqa: BLE001 - knob absent on older jax
-            pass
-    except Exception as exc:  # noqa: BLE001 - cache is an optimization
-        debug_log(f"compile cache setup failed ({cache_dir}): {exc}")
-        return None
-    debug_log(f"persistent compilation cache at {cache_dir}")
-    # Compile/cache tallies must count from the FIRST program: the
-    # fleet snapshot a worker piggybacks onto its pulls (and the bench
-    # runtime stamp) both read these jax.monitoring listeners, so
-    # install them alongside the cache — the earliest backend-adjacent
-    # moment every process passes through.
-    try:
-        from ..telemetry.runtime import install_jax_monitoring
-
-        install_jax_monitoring()
-    except Exception as exc:  # noqa: BLE001 - telemetry is best effort
-        debug_log(f"jax monitoring install failed: {exc}")
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    install_jax_monitoring()
+    cache_dir = jax.config.jax_compilation_cache_dir
+    log(f"persistent compilation cache at {cache_dir}")
     return cache_dir
 
 
+def host_tpu_chips(dev_root: str = "/dev") -> list[int]:
+    """Indices of the TPU chips this host lets a process open, counted
+    from their device nodes so no JAX backend is initialised (a backend
+    that enumerates chips also takes them, and the master must leave
+    its workers' chips free): /dev/accelN on the older generations,
+    the numbered IOMMU groups under /dev/vfio from v5e on. The PCI bus
+    is no guide — a host that passes one chip of four through still
+    lists all four there."""
+    import re
+
+    def numbered(directory: str, pattern: str) -> int:
+        try:
+            names = os.listdir(directory)
+        except OSError:
+            return 0
+        return sum(1 for name in names if re.fullmatch(pattern, name))
+
+    chips = numbered(dev_root, r"accel\d+") or numbered(
+        os.path.join(dev_root, "vfio"), r"\d+"
+    )
+    return list(range(chips))
+
+
+def apply_master_chips(config_path: str | None = None) -> list[int]:
+    """Pin THIS process to config master.tpu_chips (process-per-chip
+    mode) — before any backend initialises, or libtpu has already
+    taken every chip on the host and no managed worker can start.
+    Empty (the default) leaves the master every local chip: the
+    in-process mesh is the TPU-native path. A worker process comes
+    pre-pinned by its launcher and is left alone."""
+    if is_worker_process():
+        return []
+    chips = master_chips(config_path)
+    os.environ.update(chip_environment(chips))
+    return chips
+
+
+def master_chips(config_path: str | None = None) -> list[int]:
+    raw = config_mod.load_config(config_path).get("master", {}).get("tpu_chips")
+    return [int(c) for c in raw or []]
+
+
+def init_backend(platform_flag: str | None) -> Any:
+    """Initialise the JAX backend once, on purpose, at start-up — so no
+    request handler ever does it on the event loop — and say what it
+    is. Refuses the CPU unless it was asked for by name: with
+    JAX_PLATFORMS unset jax falls back to the CPU when libtpu cannot
+    take the chip, and a server that then serves SDXL at CPU speed
+    looks like a hang. Raises RuntimeError (jax's own, or the refusal)
+    for the CLI to turn into a non-zero exit."""
+    import jax
+
+    from ..parallel.multihost import maybe_init_multihost
+
+    if platform_flag:
+        jax.config.update("jax_platforms", platform_flag)
+    # join the pod's shared JAX runtime when configured (no-op
+    # otherwise); it has to precede the first device query
+    maybe_init_multihost()
+    devices = jax.local_devices()
+    platform = devices[0].platform
+    log(
+        f"serving on platform={platform} device_kind={devices[0].device_kind} "
+        f"local_devices={len(devices)} "
+        f"visible_chips={os.environ.get(TPU_VISIBLE_CHIPS_ENV) or 'all'} "
+        f"host_chips={len(host_tpu_chips())}"
+    )
+    if platform == "cpu" and "cpu" not in (platform_flag or "").split(","):
+        raise RuntimeError(
+            "refusing to serve on platform 'cpu': no accelerator backend "
+            "initialised (JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS', '')!r}). Pass --platform cpu "
+            "to serve on the CPU on purpose."
+        )
+    return devices
+
+
 def auto_populate_workers(config_path: str | None = None) -> list[dict[str, Any]]:
-    """First-run convenience: create one local worker entry per spare
-    local chip (everything but the master's chip 0), ports 8189+.
+    """First-run convenience for process-per-chip mode: create one
+    local worker entry per chip of this host the master is not pinned
+    to, ports 8189+. With the master unpinned (the default) its mesh
+    already drives every chip and there is nothing to populate.
 
     The reference does this from the browser (reference
     web/masterDetection.js auto-populate, flag
@@ -88,18 +158,14 @@ def auto_populate_workers(config_path: str | None = None) -> list[dict[str, Any]
     """
     if is_worker_process():
         return []
+    pinned = set(master_chips(config_path))
+    if not pinned:
+        return []
     created: list[dict[str, Any]] = []
     config = config_mod.load_config(config_path)
     if config.get("settings", {}).get("has_auto_populated_workers"):
         return []
-    try:
-        import jax
-
-        chips = [d.id for d in jax.local_devices()]
-    except Exception:
-        chips = []
-    master_chips = set(config.get("master", {}).get("tpu_chips", [0]))
-    spare = [c for c in chips if c not in master_chips]
+    spare = [c for c in host_tpu_chips() if c not in pinned]
     port = 8189
     for chip in spare:
         created.append(

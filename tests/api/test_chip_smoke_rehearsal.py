@@ -1,0 +1,38 @@
+"""chip_smoke.py end to end in its rehearsal mode (integration tier):
+the same legs, requests and checks as on the chip — a CLI server child,
+both workflows over HTTP cold and warm, a restart that must hit the
+compile cache and reproduce the bytes, the attention child — at toy
+size on the CPU, because the caller said so."""
+
+import json
+import os
+import socket
+import subprocess
+import sys
+
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+
+
+def test_chip_smoke_rehearsal_passes(tmp_path):
+    with socket.socket() as sock:
+        sock.bind(("127.0.0.1", 0))
+        port = sock.getsockname()[1]
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO_ROOT, "chip_smoke.py"),
+         "--rehearsal", "--out", str(tmp_path / "out"), "--port", str(port)],
+        capture_output=True, text=True, timeout=1500,
+    )
+    assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-2000:]
+    lines = proc.stdout.strip().splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+        "rehearsal": True,
+    }
+    assert "REHEARSAL" in lines[0]
+    for leg in ("serve", "restart", "attention"):
+        assert any(f"leg {leg} passed" in line for line in lines), leg
+    assert any("bytes identical to the first server's" in line for line in lines)
+    assert any('"route": "flash (interpreted)"' in line for line in lines)

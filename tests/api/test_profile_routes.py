@@ -180,16 +180,12 @@ def test_disabled_without_profile_dir(
         _stop_server(srv, loop_thread)
 
 
-def test_system_info_serves_probe_report(
-    tmp_config_path, tmp_path, monkeypatch, clean_profiling
+def test_system_info_names_the_device_and_versions(
+    tmp_config_path, clean_profiling
 ):
-    probe_path = tmp_path / "bench_probe.json"
-    probe = {
-        "backend": "cpu", "stage": "generate",
-        "versions": {"jax": "0.4"}, "written_at": 123.0,
-    }
-    probe_path.write_text(json.dumps(probe))
-    monkeypatch.setenv("CDT_PROBE_REPORT", str(probe_path))
+    """What chip_smoke.py and the panel read to know what the server
+    runs on: platform, device kind, counts, package versions, the
+    serving mesh and which data plane loaded."""
     port = _free_port()
     srv, loop_thread = _start_server(port)
     try:
@@ -197,15 +193,30 @@ def test_system_info_serves_probe_report(
             f"http://127.0.0.1:{port}/distributed/system_info"
         )
         assert status == 200
-        assert info["probe"] == probe
+        topology = info["topology"]
+        assert topology["platform"] == "cpu"
+        assert topology["device_kind"] == "cpu"
+        assert topology["device_count"] == topology["local_device_count"] >= 1
+        assert set(topology["versions"]) == {"jax", "jaxlib", "libtpu"}
+        assert topology["versions"]["jax"]
+        assert topology["mesh"]["devices"] >= 1
+        assert info["data_plane"] in ("native", "numpy")
+        assert "probe" not in info
     finally:
         _stop_server(srv, loop_thread)
 
 
-def test_system_info_omits_probe_when_unset(
-    tmp_config_path, tmp_path, monkeypatch, clean_profiling
+def test_system_info_reports_a_dead_backend_as_an_error(
+    tmp_config_path, monkeypatch, clean_profiling
 ):
-    monkeypatch.setenv("CDT_PROBE_REPORT", "off")
+    """A backend that cannot start is an `error` in the answer (which
+    chip_smoke.py fails on), not an empty device list."""
+    from comfyui_distributed_tpu.parallel import mesh as mesh_mod
+
+    def boom():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(mesh_mod, "describe_topology", boom)
     port = _free_port()
     srv, loop_thread = _start_server(port)
     try:
@@ -213,6 +224,8 @@ def test_system_info_omits_probe_when_unset(
             f"http://127.0.0.1:{port}/distributed/system_info"
         )
         assert status == 200
-        assert "probe" not in info
+        assert info["topology"] == {
+            "error": "Unable to initialize backend 'tpu'"
+        }
     finally:
         _stop_server(srv, loop_thread)

@@ -46,8 +46,8 @@ def test_bfloat16_casts_floats_only(monkeypatch):
 
 def test_all_loaders_route_through_cast():
     """Every bundle-building loader must apply maybe_cast_params —
-    an unrouted loader resurrects the 18.5G/15.75G SDXL HBM OOM this
-    knob exists to fix (BENCH_NOTES.md round 5)."""
+    an unrouted loader keeps float32 weights on the chip, and SDXL in
+    float32 does not leave a 16 GB chip room to run."""
     import inspect
 
     from comfyui_distributed_tpu.models import (

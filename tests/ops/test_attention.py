@@ -55,7 +55,9 @@ def test_flash_pads_unaligned_head_dim():
         q = jax.random.normal(jax.random.key(0), (1, 128, 2, d))
         k = jax.random.normal(jax.random.key(1), (1, 128, 2, d))
         v = jax.random.normal(jax.random.key(2), (1, 128, 2, d))
-        flash = dot_product_attention(q, k, v, force_flash=True)
+        flash = dot_product_attention(
+            q, k, v, force_flash=True, interpret=True
+        )
         ref = jax.nn.dot_product_attention(q, k, v)
         np.testing.assert_allclose(
             np.asarray(flash), np.asarray(ref), atol=2e-5

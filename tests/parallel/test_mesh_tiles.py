@@ -26,6 +26,7 @@ from comfyui_distributed_tpu.parallel.mesh import (
     mesh_summary,
     worker_mesh,
 )
+from comfyui_distributed_tpu.utils.exceptions import MeshError
 from comfyui_distributed_tpu.parallel.sharding import (
     maybe_shard_params,
     params_byte_size,
@@ -156,9 +157,10 @@ def test_worker_mesh_tp_knob_and_inference():
     assert mesh_summary(inferred) == summary
 
 
-def test_worker_mesh_malformed_shape_falls_back():
+def test_worker_mesh_malformed_shape_raises():
     with mock.patch.dict(os.environ, {"CDT_MESH_SHAPE": "banana"}):
-        assert worker_mesh() is None  # CPU default: no mesh
+        with pytest.raises(MeshError, match="CDT_MESH_SHAPE"):
+            worker_mesh()
 
 
 def test_worker_mesh_tp_keeps_explicit_data_pin():
@@ -224,14 +226,16 @@ def test_serving_mesh_summary_reports_recorded_mesh():
         mesh_mod._serving_mesh_summary = saved
 
 
-def test_worker_mesh_non_divisible_knob_falls_back_not_crash():
-    """Mesh knobs are advisory: a tp that doesn't divide the host must
-    fall back to the single-device path (with a log line), never kill
-    run_worker_loop before its first pull."""
+def test_worker_mesh_non_divisible_knob_raises():
+    """A tp that doesn't divide the host is a misconfiguration, not a
+    request for one silent participant: it raises where the mesh is
+    built (server start-up) instead of serving on a fraction of the
+    host."""
     if jax.local_device_count() % 3 == 0:
         pytest.skip("tp=3 divides this host; not the non-divisible case")
     with mock.patch.dict(os.environ, {"CDT_TP_SIZE": "3"}):
-        assert worker_mesh() is None
+        with pytest.raises(MeshError):
+            worker_mesh()
 
 
 # --- tensor-parallel tier (HBM budget rule + param sharding) ---------------

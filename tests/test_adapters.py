@@ -162,7 +162,8 @@ def _tiny_sd(seed=0, rank=2, dim=4, name="lora_unet_foo"):
         f"{name}.lora_up.weight": rng.normal(size=(dim, rank)).astype(
             np.float32
         ),
-        f"{name}.alpha": np.float32(rank),
+        # a 0-d ndarray: safetensors' save_file takes arrays, not numpy scalars
+        f"{name}.alpha": np.asarray(rank, dtype=np.float32),
     }
 
 

@@ -1,0 +1,113 @@
+"""chip_smoke.py's contract where no chip is needed to check it: it
+fails, with no result line, when there is no accelerator or no program
+beside it; and its own checks reject what they must."""
+
+import importlib.util
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO_ROOT, "chip_smoke.py")
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke_mod", SMOKE)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _result_lines(stdout: str) -> list[dict]:
+    out = []
+    for line in stdout.splitlines():
+        if line.startswith("{"):
+            out.append(json.loads(line))
+    return out
+
+
+def test_fails_without_an_accelerator_and_prints_no_result(tmp_path):
+    """The sandbox case: JAX finds no TPU, the server refuses the CPU,
+    and the smoke exits non-zero with the reason on its last lines —
+    it never falls into the rehearsal by itself."""
+    proc = subprocess.run(
+        [sys.executable, SMOKE, "--out", str(tmp_path / "out")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert not any("ok" in row for row in _result_lines(proc.stdout))
+    assert "REHEARSAL" not in proc.stdout
+    last = proc.stdout.strip().splitlines()[-3:]
+    assert any("FAILED" in line for line in last), last
+    assert "refusing to serve on platform 'cpu'" in proc.stdout
+
+
+def test_fails_alone_in_a_directory(tmp_path):
+    """The script is a check of the program, not a stand-in for it."""
+    shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=str(tmp_path),
+        capture_output=True, text=True, timeout=60,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "comfyui_distributed_tpu" in proc.stderr
+
+
+def test_metrics_parse_reads_runtime_gauges(smoke, monkeypatch):
+    text = "\n".join([
+        "# HELP cdt_jax_compiles programs",
+        "cdt_jax_compiles 12",
+        "cdt_jax_compile_time_seconds 3.5",
+        "cdt_jax_cache_hits 10",
+        "cdt_jax_cache_misses 2",
+        'cdt_device_memory_bytes{device="tpu:0",stat="peak_bytes_in_use"} 9.5e9',
+        'cdt_device_memory_bytes{device="tpu:1",stat="peak_bytes_in_use"} 8e9',
+        'cdt_device_memory_bytes{device="tpu:0",stat="bytes_in_use"} 7e9',
+        'cdt_tiles_processed_total{role="master"} 9',
+    ])
+    monkeypatch.setattr(smoke, "http", lambda *a, **k: text)
+    metrics = smoke.read_metrics("http://x")
+    assert metrics["compiles"] == 12 and metrics["cache_misses"] == 2
+    assert metrics["peak_bytes_in_use"] == {
+        "tpu:0": 9_500_000_000, "tpu:1": 8_000_000_000,
+    }
+    assert metrics["tiles"] == {"master": 9}
+    delta = smoke.describe_metrics(
+        dict(metrics, compiles=10.0, compile_s=1.0, cache_hits=10.0), metrics
+    )
+    assert delta["compiles"] == 2 and delta["cache_hits"] == 0
+
+
+def test_image_check_rejects_flat_blocks_and_wrong_shapes(smoke):
+    rng = np.random.default_rng(0)
+    good = rng.integers(0, 255, size=(128, 128, 3), dtype=np.uint8)
+    smoke.check_image("good", good, 128, 64)
+    flat_tile = good.copy()
+    flat_tile[64:, :64] = 0  # what a NaN tile looks like after encoding
+    with pytest.raises(smoke.Failure, match=r"constant 64px block at \(64,0\)"):
+        smoke.check_image("nan tile", flat_tile, 128, 64)
+    with pytest.raises(smoke.Failure, match="shape"):
+        smoke.check_image("small", good[:64], 128, 64)
+
+
+def test_warm_requests_change_only_the_seed(smoke):
+    with open(os.path.join(REPO_ROOT, "workflows", "distributed-txt2img.json")) as fh:
+        prompt = json.load(fh)
+    assert smoke.committed_seed(prompt) == 42
+    warm = smoke.with_seed(prompt, 43)
+    assert smoke.committed_seed(prompt) == 42  # the committed graph is untouched
+    changed = [
+        (node_id, key)
+        for node_id, node in prompt.items()
+        for key, value in node["inputs"].items()
+        if warm[node_id]["inputs"][key] != value
+    ]
+    assert changed == [("5", "seed")]

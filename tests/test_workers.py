@@ -111,9 +111,34 @@ def test_clear_launching_marker(tmp_config_path):
     assert manager.clear_launching("missing") is False
 
 
-def test_auto_populate_once(tmp_config_path):
+def test_host_tpu_chips_counts_device_nodes_without_a_backend(tmp_path):
+    """Chips are counted from the device nodes a process could open —
+    numbered vfio groups (v5e on) or /dev/accelN — never through jax,
+    whose enumeration takes the chips it lists."""
+    v5e = tmp_path / "v5e"
+    (v5e / "vfio").mkdir(parents=True)
+    for name in ("0", "1", "2", "3", "vfio"):
+        (v5e / "vfio" / name).touch()
+    assert startup.host_tpu_chips(str(v5e)) == [0, 1, 2, 3]
+    older = tmp_path / "older"
+    older.mkdir()
+    for name in ("accel0", "accel1", "accelerometer", "null"):
+        (older / name).touch()
+    assert startup.host_tpu_chips(str(older)) == [0, 1]
+    assert startup.host_tpu_chips(str(tmp_path / "missing")) == []
+
+
+def test_auto_populate_once(tmp_config_path, monkeypatch):
+    monkeypatch.setattr(startup, "host_tpu_chips", lambda: list(range(8)))
+    # master unpinned (the default): its mesh drives every chip, there
+    # is nothing to populate and the first-run flag stays unspent
+    assert startup.auto_populate_workers() == []
+    assert "has_auto_populated_workers" not in cfg_mod.load_config()["settings"]
+    # process-per-chip mode: one disabled entry per chip the master left
+    cfg = cfg_mod.load_config()
+    cfg["master"]["tpu_chips"] = [0]
+    cfg_mod.save_config(cfg)
     created = startup.auto_populate_workers()
-    # 8 virtual chips, chip 0 reserved for the master
     assert [w["tpu_chips"] for w in created] == [[c] for c in range(1, 8)]
     assert all(not w["enabled"] for w in created)
     cfg = cfg_mod.load_config()
@@ -122,6 +147,117 @@ def test_auto_populate_once(tmp_config_path):
     # second call is a no-op
     assert startup.auto_populate_workers() == []
     assert len(cfg_mod.load_config()["workers"]) == 7
+
+
+def test_chip_environment_is_a_complete_sub_host_process():
+    """TPU_VISIBLE_CHIPS alone leaves libtpu assuming the host's whole
+    topology and its host-wide lock; a sub-host process also needs its
+    bounds, a controller port of its own and the multi-load permit."""
+    assert pm.chip_environment([]) == {}
+    env = pm.chip_environment([1])
+    assert env == {
+        "TPU_VISIBLE_CHIPS": "1",
+        "TPU_VISIBLE_DEVICES": "1",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_HOST_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": "localhost:8477",
+        "TPU_PROCESS_PORT": "8477",
+        "TPU_MESH_CONTROLLER_ADDRESS": "localhost:8477",
+        "TPU_MESH_CONTROLLER_PORT": "8477",
+        "TPU_RUNTIME_METRICS_PORTS": "8432",
+        "CLOUD_TPU_TASK_ID": "0",
+        "TPU_WORKER_ID": "0",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+    # two processes on one host never share a controller port
+    assert pm.chip_environment([0])["TPU_PROCESS_PORT"] == "8476"
+    assert pm.chip_environment([2, 3])["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    with pytest.raises(ProcessError):
+        pm.chip_environment([0, 1, 2])
+
+
+def _capture_launch(manager, monkeypatch):
+    launched = {}
+
+    class _Proc:
+        pid = 424242
+
+    def fake_popen(cmd, **kwargs):
+        launched["cmd"] = cmd
+        launched["env"] = kwargs["env"]
+        return _Proc()
+
+    monkeypatch.setattr(pm.subprocess, "Popen", fake_popen)
+    return launched
+
+
+def test_master_on_chip_0_launches_worker_on_chip_1(
+    tmp_config_path, tmp_path, monkeypatch
+):
+    """Process per chip: the master pins ITSELF (before any backend
+    starts) and hands the worker a complete environment for its own
+    chip — not the master's, and not visibility alone."""
+    monkeypatch.setenv("CDT_LOG_DIR", str(tmp_path / "logs"))
+    for key in pm.chip_environment([0]):
+        monkeypatch.delenv(key, raising=False)
+    monkeypatch.delenv("CDT_IS_WORKER", raising=False)
+    cfg = cfg_mod.load_config()
+    cfg["master"]["tpu_chips"] = [0]
+    cfg_mod.save_config(cfg)
+
+    assert startup.apply_master_chips() == [0]
+    for key, value in pm.chip_environment([0]).items():
+        assert os.environ[key] == value
+        monkeypatch.setenv(key, value)  # restored after the test
+
+    manager = pm.WorkerProcessManager()
+    launched = _capture_launch(manager, monkeypatch)
+    manager.launch_worker(
+        {"id": "w1", "name": "w1", "port": 8190, "tpu_chips": [1]}
+    )
+    env = launched["env"]
+    for key, value in pm.chip_environment([1]).items():
+        assert env[key] == value
+    assert env["CDT_IS_WORKER"] == "1"
+    assert env["CDT_MASTER_PID"] == str(os.getpid())
+    # the chip the master holds is refused, loudly, at launch
+    with pytest.raises(ProcessError, match="holds 0"):
+        manager.launch_worker(
+            {"id": "w0", "name": "w0", "port": 8191, "tpu_chips": [0]}
+        )
+
+
+def test_unpinned_master_refuses_a_chip_pinned_worker(
+    tmp_config_path, tmp_path, monkeypatch
+):
+    """The default master takes every local chip; a worker that needs
+    one would die in its own log, so the launch says so instead."""
+    monkeypatch.setenv("CDT_LOG_DIR", str(tmp_path / "logs"))
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    monkeypatch.delenv("CDT_IS_WORKER", raising=False)
+    assert startup.apply_master_chips() == []
+    assert "TPU_VISIBLE_CHIPS" not in os.environ
+    manager = pm.WorkerProcessManager()
+    _capture_launch(manager, monkeypatch)
+    with pytest.raises(ProcessError, match="master.tpu_chips"):
+        manager.launch_worker(
+            {"id": "w1", "name": "w1", "port": 8190, "tpu_chips": [1]}
+        )
+    # a worker with no chip set (CPU hosts, remote-style local workers)
+    # launches as before
+    manager.launch_worker({"id": "w2", "name": "w2", "port": 8191})
+
+
+def test_worker_process_never_repins_itself(tmp_config_path, monkeypatch):
+    monkeypatch.setenv("CDT_IS_WORKER", "1")
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    cfg = cfg_mod.load_config()
+    cfg["master"]["tpu_chips"] = [0]
+    cfg_mod.save_config(cfg)
+    assert startup.apply_master_chips() == []
+    assert "TPU_VISIBLE_CHIPS" not in os.environ
 
 
 def test_detection_helpers():

@@ -18,6 +18,7 @@ DEFAULT_SCAN_PATHS = (
     "comfyui_distributed_tpu",
     "scripts",
     "bench.py",
+    "chip_smoke.py",
     "__graft_entry__.py",
 )
 
