@@ -53,6 +53,9 @@ class PromptJob:
         self.outputs: dict[str, Any] | None = None
         self.error: str | None = None
         self.timings: dict[str, float] = {}
+        # the prompt_queue.wait span: opened at enqueue, closed by the
+        # executor thread when it takes the job
+        self.queue_span: Any = None
 
 
 class DistributedServer:
@@ -454,6 +457,13 @@ class DistributedServer:
         validate_prompt(prompt)
         job = PromptJob(prompt_id, prompt, extra, trace_id=trace_id)
         self._history[prompt_id] = job
+        from ..telemetry import get_tracer
+
+        job.queue_span = get_tracer().start_span(
+            "prompt_queue.wait",
+            trace_id=job.trace_id,
+            attrs={"depth": self._prompt_queue.qsize()},
+        )
         self._prompt_queue.put(job)
         return job
 
@@ -468,6 +478,10 @@ class DistributedServer:
             job = self._prompt_queue.get()
             if job is None:
                 return
+            from ..telemetry import get_tracer
+
+            tracer = get_tracer()
+            tracer.end_span(job.queue_span)
             self._executing.set()
             self._interrupt.clear()
             ctx = ExecutionContext(
@@ -478,9 +492,6 @@ class DistributedServer:
                 pipelines=self.execution_context.pipelines,
                 extras=self.execution_context.extras,  # node cache persists
             )
-            from ..telemetry import get_tracer
-
-            tracer = get_tracer()
             # The compute thread joins the prompt's trace so every span
             # opened during execution (tile pulls, sampler stages)
             # attaches to the distributed execution's tree.
@@ -491,10 +502,12 @@ class DistributedServer:
                     "execute_prompt",
                     prompt_id=job.prompt_id,
                     role="worker" if self.is_worker else "master",
-                ):
+                ) as span:
                     executor = GraphExecutor(ctx)
                     job.outputs = executor.execute(job.prompt)
                     job.timings = executor.last_timings
+                    span.attrs["nodes_run"] = executor.nodes_run
+                    span.attrs["nodes_cached"] = executor.nodes_cached
             except Exception as exc:  # noqa: BLE001 - reported to client
                 job.error = f"{type(exc).__name__}: {exc}"
                 log(f"prompt {job.prompt_id} failed: {job.error}")

@@ -123,6 +123,29 @@ def _toposort(prompt: Prompt) -> list[str]:
     return order
 
 
+# telemetry/runtime tally -> the node span attribute its delta goes by
+_TALLY_ATTRS = {
+    "compiles": "compiles",
+    "compile_time_s": "compile_s",
+    "cache_hits": "cache_hits",
+    "cache_misses": "cache_misses",
+    "trace_time_s": "trace_s",
+    "lower_time_s": "lower_s",
+    "cache_retrieval_s": "cache_fetch_s",
+}
+
+
+def _program_work(before: dict[str, Any], after: dict[str, Any]) -> dict[str, Any]:
+    """What JAX traced, lowered, built or fetched between two
+    `runtime.tallies()` snapshots, under the span attribute names;
+    quantities that did not move are left out."""
+    return {
+        attr: after[key] - before[key]
+        for key, attr in _TALLY_ATTRS.items()
+        if after[key] != before[key]
+    }
+
+
 class GraphExecutor:
     """Execute a validated prompt graph."""
 
@@ -131,6 +154,10 @@ class GraphExecutor:
         # per-node wall times of the last execution (observability the
         # reference lacks — SURVEY §5 "no timing/profiler integration")
         self.last_timings: dict[str, float] = {}
+        # of the last execution: nodes whose function ran, and nodes
+        # answered from the cache (a node that ran can round to 0.0 s)
+        self.nodes_run = 0
+        self.nodes_cached = 0
 
     def execute(self, prompt: Prompt) -> dict[str, Any]:
         """Run the graph; returns {node_id: output} for OUTPUT_NODE nodes.
@@ -144,11 +171,16 @@ class GraphExecutor:
         import json
         import time
 
+        from ..telemetry import get_tracer
+        from ..telemetry import runtime
+
+        tracer = get_tracer()
         validate_prompt(prompt)
         order = _toposort(prompt)
         results: dict[str, tuple] = {}
         outputs: dict[str, Any] = {}
         self.last_timings = {}
+        self.nodes_run = self.nodes_cached = 0
         cache: dict[str, tuple[str, tuple]] = self.context.extras.setdefault(
             "node_cache", {}
         )
@@ -183,6 +215,7 @@ class GraphExecutor:
             if cached is not None and cached[0] == content_keys[node_id]:
                 results[node_id] = cached[1]
                 self.last_timings[node_id] = 0.0
+                self.nodes_cached += 1
                 continue
 
             # defaults first, then literal/link inputs
@@ -201,9 +234,19 @@ class GraphExecutor:
             fn = getattr(instance, cls.FUNCTION)
             if "context" in inspect.signature(fn).parameters:
                 kwargs["context"] = self.context
-            started = time.perf_counter()
-            result = fn(**kwargs)
-            self.last_timings[node_id] = round(time.perf_counter() - started, 4)
+            # host time in the node: dispatch is asynchronous, so the
+            # device's time shows where the thread waits (device.wait)
+            with tracer.span(
+                f"node.{node_def['class_type']}", node_id=node_id
+            ) as span:
+                before = runtime.tallies()
+                started = time.perf_counter()
+                result = fn(**kwargs)
+                self.last_timings[node_id] = round(
+                    time.perf_counter() - started, 4
+                )
+                span.attrs.update(_program_work(before, runtime.tallies()))
+            self.nodes_run += 1
             if result is None:
                 result = ()
             if not isinstance(result, tuple):

@@ -1271,14 +1271,25 @@ class SaveImage:
         # each other (ComfyUI counter-scan behavior)
         from .io_dirs import next_counter
 
+        from ..telemetry import get_tracer
+
+        tracer = get_tracer()
         start = next_counter(out_dir, filename_prefix, "png")
         saved = []
-        arr = img_utils.ensure_numpy(images)
+        # the executor thread parks here until the device has finished
+        # everything the images depend on
+        with tracer.span("device.wait") as wait:
+            arr = img_utils.ensure_numpy(images)
+            wait.attrs["bytes"] = int(arr.nbytes)
         for i in range(arr.shape[0]):
             name = f"{filename_prefix}_{start + i:05d}.png"
             path = os.path.join(out_dir, name)
-            with open(path, "wb") as fh:
-                fh.write(img_utils.encode_png(arr[i], compress_level=4))
+            with tracer.span("png.encode") as encode:
+                png = img_utils.encode_png(arr[i], compress_level=4)
+                encode.attrs["bytes"] = len(png)
+            with tracer.span("file.write", bytes=len(png)):
+                with open(path, "wb") as fh:
+                    fh.write(png)
             saved.append(name)
         return ({"ui": {"images": saved}, "images": images},)
 

@@ -346,9 +346,13 @@ class DistributedCollector:
 
         # Mesh tier: the sharded participant-major array IS the collected
         # batch — just materialise it.
-        mesh_collected = host_collect(images) if isinstance(images, jax.Array) else (
-            img_utils.ensure_numpy(images)
-        )
+        from ..telemetry import get_tracer
+
+        with get_tracer().span("device.wait") as wait:
+            mesh_collected = host_collect(images) if isinstance(images, jax.Array) else (
+                img_utils.ensure_numpy(images)
+            )
+            wait.attrs["bytes"] = int(mesh_collected.nbytes)
 
         if not enabled_worker_ids or server is None:
             combined_audio = audio

@@ -163,62 +163,71 @@ class UNet(nn.Module):
         skips = [h]
 
         # --- down path ---
+        # Block-level scopes (down_N / mid / up_N, attn inside) land in
+        # every operation's metadata, above the module names flax adds,
+        # so a device trace can be grouped by block.
         for level, mult in enumerate(cfg.channel_mult):
             out_ch = ch * mult
-            for i in range(cfg.num_res_blocks):
-                h = ResBlock(out_ch, dt, name=f"down_{level}_res_{i}")(h, emb)
-                if cfg.transformer_depth[level] > 0:
-                    heads, hdim = head_split(out_ch)
-                    h = SpatialT(
-                        heads,
-                        hdim,
-                        cfg.transformer_depth[level],
-                        dt,
-                        name=f"down_{level}_attn_{i}",
-                    )(h, context)
-                skips.append(h)
-            if level != len(cfg.channel_mult) - 1:
-                h = Downsample(dt, name=f"down_{level}_ds")(h)
-                skips.append(h)
+            with jax.named_scope(f"down_{level}"):
+                for i in range(cfg.num_res_blocks):
+                    h = ResBlock(out_ch, dt, name=f"down_{level}_res_{i}")(h, emb)
+                    if cfg.transformer_depth[level] > 0:
+                        heads, hdim = head_split(out_ch)
+                        with jax.named_scope("attn"):
+                            h = SpatialT(
+                                heads,
+                                hdim,
+                                cfg.transformer_depth[level],
+                                dt,
+                                name=f"down_{level}_attn_{i}",
+                            )(h, context)
+                    skips.append(h)
+                if level != len(cfg.channel_mult) - 1:
+                    h = Downsample(dt, name=f"down_{level}_ds")(h)
+                    skips.append(h)
 
         # --- middle ---
         mid_ch = ch * cfg.channel_mult[-1]
         mid_depth = max(cfg.transformer_depth[-1], 1)
-        h = ResBlock(mid_ch, dt, name="mid_res_0")(h, emb)
         mid_heads, mid_hdim = head_split(mid_ch)
         # capture bypasses remat for the mid block only: sown
         # intermediates don't survive nn.remat, and the mid block's
         # activations are 1/64 of the latent tokens anyway
         MidT = SpatialTransformer if sag_capture else SpatialT
-        h = MidT(
-            mid_heads, mid_hdim, mid_depth, dt, pag=pag,
-            sow_attn=sag_capture, name="mid_attn",
-        )(h, context)
-        h = ResBlock(mid_ch, dt, name="mid_res_1")(h, emb)
+        with jax.named_scope("mid"):
+            h = ResBlock(mid_ch, dt, name="mid_res_0")(h, emb)
+            with jax.named_scope("attn"):
+                h = MidT(
+                    mid_heads, mid_hdim, mid_depth, dt, pag=pag,
+                    sow_attn=sag_capture, name="mid_attn",
+                )(h, context)
+            h = ResBlock(mid_ch, dt, name="mid_res_1")(h, emb)
 
         # --- up path ---
         for level, mult in reversed(list(enumerate(cfg.channel_mult))):
             out_ch = ch * mult
-            for i in range(cfg.num_res_blocks + 1):
-                skip = skips.pop()
-                if cfg.freeu is not None:
-                    h, skip = _apply_freeu(cfg, ch, h, skip)
-                h = jnp.concatenate([h, skip], axis=-1)
-                h = ResBlock(out_ch, dt, name=f"up_{level}_res_{i}")(h, emb)
-                if cfg.transformer_depth[level] > 0:
-                    heads, hdim = head_split(out_ch)
-                    h = SpatialT(
-                        heads,
-                        hdim,
-                        cfg.transformer_depth[level],
-                        dt,
-                        name=f"up_{level}_attn_{i}",
-                    )(h, context)
-            if level != 0:
-                # land exactly on the next skip's spatial dims (small /
-                # odd latents don't round-trip through stride-2 convs)
-                target = skips[-1].shape[1:3]
-                h = Upsample(dt, name=f"up_{level}_us")(h, target)
+            with jax.named_scope(f"up_{level}"):
+                for i in range(cfg.num_res_blocks + 1):
+                    skip = skips.pop()
+                    if cfg.freeu is not None:
+                        h, skip = _apply_freeu(cfg, ch, h, skip)
+                    h = jnp.concatenate([h, skip], axis=-1)
+                    h = ResBlock(out_ch, dt, name=f"up_{level}_res_{i}")(h, emb)
+                    if cfg.transformer_depth[level] > 0:
+                        heads, hdim = head_split(out_ch)
+                        with jax.named_scope("attn"):
+                            h = SpatialT(
+                                heads,
+                                hdim,
+                                cfg.transformer_depth[level],
+                                dt,
+                                name=f"up_{level}_attn_{i}",
+                            )(h, context)
+                if level != 0:
+                    # land exactly on the next skip's spatial dims (small /
+                    # odd latents don't round-trip through stride-2 convs)
+                    target = skips[-1].shape[1:3]
+                    h = Upsample(dt, name=f"up_{level}_us")(h, target)
 
         h = GroupNorm32(name="out_norm")(h)
         h = nn.silu(h)

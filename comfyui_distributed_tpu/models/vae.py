@@ -160,22 +160,24 @@ class VAE(nn.Module):
     def encode(self, x: jax.Array, rng: jax.Array | None = None) -> jax.Array:
         """[B,H,W,3] in [0,1] → [B,H/8,W/8,C] scaled latents (mean; pass
         rng to sample from the posterior instead)."""
-        moments = self.encoder(x * 2.0 - 1.0)
-        if self.config.use_quant_conv:
-            moments = self.quant_conv(moments)
-        mean, logvar = jnp.split(moments, 2, axis=-1)
-        if rng is not None:
-            std = jnp.exp(0.5 * jnp.clip(logvar, -30.0, 20.0))
-            mean = mean + std * jax.random.normal(rng, mean.shape)
-        return (mean - self.config.shift_factor) * self.config.scaling_factor
+        with jax.named_scope("vae_encode"):
+            moments = self.encoder(x * 2.0 - 1.0)
+            if self.config.use_quant_conv:
+                moments = self.quant_conv(moments)
+            mean, logvar = jnp.split(moments, 2, axis=-1)
+            if rng is not None:
+                std = jnp.exp(0.5 * jnp.clip(logvar, -30.0, 20.0))
+                mean = mean + std * jax.random.normal(rng, mean.shape)
+            return (mean - self.config.shift_factor) * self.config.scaling_factor
 
     def decode(self, z: jax.Array) -> jax.Array:
         """[B,h,w,C] scaled latents → [B,H,W,3] images in [0,1]."""
-        z = z / self.config.scaling_factor + self.config.shift_factor
-        if self.config.use_quant_conv:
-            z = self.post_quant_conv(z)
-        x = self.decoder(z)
-        return jnp.clip((x + 1.0) / 2.0, 0.0, 1.0)
+        with jax.named_scope("vae_decode"):
+            z = z / self.config.scaling_factor + self.config.shift_factor
+            if self.config.use_quant_conv:
+                z = self.post_quant_conv(z)
+            x = self.decoder(z)
+            return jnp.clip((x + 1.0) / 2.0, 0.0, 1.0)
 
     def __call__(self, x: jax.Array) -> jax.Array:
         return self.decode(self.encode(x))

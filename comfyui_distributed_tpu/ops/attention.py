@@ -158,6 +158,7 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",  # the kernel's name in a device trace
     )(qf, kf, vf)
 
     return out.reshape(b, h, n, d).transpose(0, 2, 1, 3)

@@ -493,7 +493,8 @@ def upscale_single(
     processed = _scan_tiles(
         one, extracted, keys, grid.positions_array(), tile_batch
     )
-    return tile_ops.blend_tiles(processed, grid)
+    with jax.named_scope("tile_blend"):
+        return tile_ops.blend_tiles(processed, grid)
 
 
 @partial(
@@ -565,7 +566,8 @@ def upscale_mesh(
         out_specs=P(),
         check=False,
     )(extracted, global_idx, positions, params, pos, neg)
-    return tile_ops.blend_tiles(gathered[:t], grid)
+    with jax.named_scope("tile_blend"):
+        return tile_ops.blend_tiles(gathered[:t], grid)
 
 
 def run_upscale(

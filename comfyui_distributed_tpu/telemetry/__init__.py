@@ -7,7 +7,9 @@ The observability subsystem the ROADMAP's perf work hangs off:
   by `/distributed/metrics`;
 - `tracing`: span trees keyed by the existing ``exec_*`` trace ids,
   propagated master→worker via the ``X-CDT-Trace-Id`` header and
-  served by `/distributed/trace/{trace_id}`; JSONL export feeds
+  served by `/distributed/trace/{trace_id}`: a served request's queue
+  wait, nodes, device waits and PNG save are one tree, mirrored into
+  an open profiler capture; JSONL export feeds
   `scripts/perf_report.py`;
 - `instruments`: every metric name/label vocabulary in one place,
   plus `bind_server_collectors` for live-state gauges;
@@ -16,8 +18,8 @@ The observability subsystem the ROADMAP's perf work hangs off:
   `GET /distributed/events` WebSocket;
 - `watchdog`: straggler & stall detector feeding breaker suspect
   transitions and speculative tail-tile re-dispatch;
-- `runtime`: JAX compile/cache/HBM/host-RSS collectors on the scrape,
-  stamped into bench output via `runtime_snapshot`;
+- `runtime`: JAX trace/lower/compile/cache/HBM/host-RSS collectors on
+  the scrape, stamped into bench output via `runtime_snapshot`;
 - `timeseries`: bounded two-tier ring-buffer retention (10 s raw /
   5 min rollup) for the fleet plane's windowed history;
 - `fleet`: worker snapshot production + the master's `FleetRegistry`

@@ -389,6 +389,28 @@ def jax_compile_time_seconds() -> Gauge:
     )
 
 
+def jax_trace_time_seconds() -> Gauge:
+    return get_metrics_registry().gauge(
+        "cdt_jax_trace_time_seconds",
+        "Cumulative jaxpr tracing time since process start",
+    )
+
+
+def jax_lower_time_seconds() -> Gauge:
+    return get_metrics_registry().gauge(
+        "cdt_jax_lower_time_seconds",
+        "Cumulative jaxpr-to-MLIR lowering time since process start",
+    )
+
+
+def jax_cache_retrieval_seconds() -> Gauge:
+    return get_metrics_registry().gauge(
+        "cdt_jax_cache_retrieval_seconds",
+        "Cumulative compilation-cache retrieval time since process start "
+        "(part of cdt_jax_compile_time_seconds)",
+    )
+
+
 def jax_cache_hits() -> Gauge:
     return get_metrics_registry().gauge(
         "cdt_jax_cache_hits",

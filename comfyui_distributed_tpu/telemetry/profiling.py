@@ -33,7 +33,12 @@ Two pieces:
   total, prune-oldest but never the newest). Served by
   ``POST /distributed/profile/start|stop`` + the index route
   (api/profile_routes.py); the incident manager auto-captures a short
-  trace alongside a debug bundle when ``CDT_PROFILE_AUTO=1``.
+  trace alongside a debug bundle when ``CDT_PROFILE_AUTO=1``. The
+  Python tracer is off (it slowed the server and made stop outlast the
+  slice); while a capture is open the tracer's context-managed spans
+  are written into it as ``TraceAnnotation``s, and the start answer
+  carries the tracer's clock and the wall clock read beside
+  ``start_trace``.
 
 Determinism contract (cdt-lint CDT004 covers this file): all clocks are
 injectable and used only for durations, capture ids derive from a
@@ -53,6 +58,7 @@ from typing import Any, Callable, Optional
 
 from ..utils import constants
 from ..utils.logging import debug_log
+from .tracing import get_tracer, set_span_annotator
 
 _NS = 1_000_000_000
 
@@ -74,6 +80,25 @@ STAGE_HOST_BUCKETS = {
     "decode": "encode",
     "submit": "ship",
 }
+
+
+# Span attributes of these types go into the mirrored annotation.
+_ANNOTATION_TYPES = (str, int, float, bool)
+
+
+def _annotate_span(span: Any) -> Any:
+    """The span mirror a capture installs (tracing.set_span_annotator):
+    a TraceAnnotation of the span's name and the plain attributes it
+    was opened with, entered by the tracer on the span's own thread."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(
+        span.name,
+        **{
+            k: v for k, v in span.attrs.items()
+            if k != "name" and isinstance(v, _ANNOTATION_TYPES)
+        },
+    )
 
 
 def _to_ns(seconds: float) -> int:
@@ -373,7 +398,13 @@ class ProfilerCapture:
                 os.makedirs(path, exist_ok=True)
                 import jax
 
-                jax.profiler.start_trace(path)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(path, profiler_options=options)
+                # the two clocks a reader needs to place spans fetched
+                # over /distributed/trace/<id> on the capture's axis
+                tracer_clock_s, unix_ns = get_tracer().now(), time.time_ns()
+                set_span_annotator(_annotate_span)
             except Exception as exc:  # noqa: BLE001 - degrade, never 500
                 self.counters["errors"] += 1
                 with contextlib.suppress(OSError):
@@ -396,6 +427,8 @@ class ProfilerCapture:
                 "id": capture_id,
                 "path": path,
                 "duration_s": duration,
+                "tracer_clock_s": tracer_clock_s,
+                "unix_ns": unix_ns,
             }
 
     def stop(self) -> dict[str, Any]:
@@ -410,6 +443,7 @@ class ProfilerCapture:
         if active is None:
             return {"stopped": False, "reason": "not_running"}
         elapsed = self.clock() - active["started_at"]
+        set_span_annotator(None)
         try:
             import jax
 

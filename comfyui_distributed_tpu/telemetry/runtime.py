@@ -10,10 +10,13 @@ profiling context.
 Three sources:
 
 - **jax.monitoring** — `install_jax_monitoring()` registers listeners
-  for the backend-compile duration event and the compilation-cache
-  hit/miss events. Installed once per process (idempotent), as early
-  as possible (server start, bench init) so compiles are counted from
-  the first program.
+  for the duration events of a program's way to the device (jaxpr
+  tracing, lowering to MLIR, backend compile — which holds the
+  compilation-cache retrieval, also tallied alone) and the
+  compilation-cache hit/miss events. Installed once per process
+  (idempotent), as early as possible (server start, bench init) so
+  compiles are counted from the first program. `tallies()` is the raw
+  snapshot the graph executor diffs around each node.
 - **device.memory_stats()** — per-device HBM gauges
   (`bytes_in_use`, `peak_bytes_in_use`, `bytes_limit`, ...). Only
   consulted when jax is ALREADY imported AND its backend is already
@@ -47,9 +50,21 @@ _tallies = {
     "compile_time_s": 0.0,
     "cache_hits": 0,
     "cache_misses": 0,
+    "trace_time_s": 0.0,
+    "lower_time_s": 0.0,
+    "cache_retrieval_s": 0.0,
 }
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# duration event -> the tally it adds to; nested jits are traced and
+# lowered inside their caller's events, so these two can count a
+# stretch of time twice
+_DURATION_TALLIES = {
+    _BACKEND_COMPILE_EVENT: "compile_time_s",
+    "/jax/core/compile/jaxpr_trace_duration": "trace_time_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_time_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+}
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -74,10 +89,12 @@ def install_jax_monitoring() -> None:
                 _tallies["cache_misses"] += 1
 
     def on_duration(event: str, duration: float, **kwargs: Any) -> None:
-        if event == _BACKEND_COMPILE_EVENT:
+        key = _DURATION_TALLIES.get(event)
+        if key is not None:
             with _tallies_lock:
-                _tallies["compiles"] += 1
-                _tallies["compile_time_s"] += float(duration)
+                _tallies[key] += float(duration)
+                if event == _BACKEND_COMPILE_EVENT:
+                    _tallies["compiles"] += 1
 
     monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)
@@ -132,13 +149,22 @@ def backend_is_up() -> bool:
     return xla_bridge.backends_are_initialized()
 
 
+def tallies() -> dict[str, Any]:
+    """The monitoring tallies as they stand (monotonic since process
+    start)."""
+    with _tallies_lock:
+        return dict(_tallies)
+
+
 def collect_runtime_gauges() -> None:
     """Scrape-time collector body: refresh the cdt_jax_* / host gauges
     from the monitoring tallies and live device state."""
-    with _tallies_lock:
-        snap = dict(_tallies)
+    snap = tallies()
     instruments.jax_compiles().set(snap["compiles"])
     instruments.jax_compile_time_seconds().set(snap["compile_time_s"])
+    instruments.jax_trace_time_seconds().set(snap["trace_time_s"])
+    instruments.jax_lower_time_seconds().set(snap["lower_time_s"])
+    instruments.jax_cache_retrieval_seconds().set(snap["cache_retrieval_s"])
     instruments.jax_cache_hits().set(snap["cache_hits"])
     instruments.jax_cache_misses().set(snap["cache_misses"])
     rss = _host_rss_bytes()
@@ -168,9 +194,9 @@ def ensure_runtime_collectors() -> None:
 def runtime_snapshot() -> dict[str, Any]:
     """The same runtime health numbers as a plain dict — stamped into
     bench.py's JSON datum so BENCH rounds carry profiling context."""
-    with _tallies_lock:
-        out: dict[str, Any] = dict(_tallies)
-    out["compile_time_s"] = round(out["compile_time_s"], 3)
+    out = tallies()
+    for key in _DURATION_TALLIES.values():
+        out[key] = round(out[key], 3)
     jax = sys.modules.get("jax")
     if jax is not None:
         cache_dir = jax.config.jax_compilation_cache_dir
@@ -189,5 +215,5 @@ def runtime_snapshot() -> dict[str, Any]:
 def reset_runtime_tallies() -> None:
     """Zero the monitoring tallies (tests)."""
     with _tallies_lock:
-        for key in _tallies:
-            _tallies[key] = 0 if key != "compile_time_s" else 0.0
+        for key, value in _tallies.items():
+            _tallies[key] = type(value)()

@@ -1,10 +1,14 @@
 """Span-based tracing keyed by the existing ``exec_*`` trace ids.
 
 Subsumes the grep-oriented `utils/trace_logger.py`: instead of log
-lines, one execution produces a TREE of spans (queue → dispatch → tile
-pull → sampler → blend) that `/distributed/trace/{trace_id}` serves as
-JSON and `scripts/perf_report.py` turns into a per-stage latency
-breakdown.
+lines, one execution produces a TREE of spans that
+`/distributed/trace/{trace_id}` serves as JSON. On the served path a
+request's tree is `sched.wait`, `queue_orchestration`,
+`prompt_queue.wait`, `execute_prompt` and under it one
+`node.<class_type>` per node that ran, with `device.wait`,
+`png.encode` and `file.write` where the executor thread blocks or
+saves; the elastic tile tier adds `dispatch` and `tile.<stage>`
+(docs/observability.md has the whole vocabulary).
 
 Design:
 
@@ -25,7 +29,12 @@ Design:
   the orchestration root without shipping span ids over the wire;
 - storage is bounded: at most `max_traces` traces (oldest evicted) of
   at most `max_spans_per_trace` spans each;
-- `write_jsonl` exports one span per line for offline analysis.
+- `write_jsonl` exports one span per line for offline analysis;
+- while a profiler capture is open (telemetry/profiling.py installs
+  `set_span_annotator`), every context-managed span is mirrored into
+  the capture on the thread that runs it, so the program's spans and
+  the device's lines share the profiler's clock. This module imports
+  no jax; with no capture open a span pays one global read.
 """
 
 from __future__ import annotations
@@ -57,6 +66,34 @@ def set_span_listener(fn: Optional[Callable[[str, "Span"], None]]) -> None:
     "close"); None uninstalls. Errors are swallowed."""
     global _span_listener
     _span_listener = fn
+
+
+# Span mirror: telemetry/profiling.ProfilerCapture installs one while a
+# capture is open. Given a span just opened by `Tracer.span`, it returns
+# a context manager to hold for the span's life on the same thread (a
+# jax.profiler.TraceAnnotation), or None.
+_span_annotator: Optional[Callable[["Span"], Any]] = None
+
+
+def set_span_annotator(fn: Optional[Callable[["Span"], Any]]) -> None:
+    """Install the mirror for context-managed spans; None uninstalls.
+    Manual start_span/end_span pairs belong to no thread and are never
+    mirrored."""
+    global _span_annotator
+    _span_annotator = fn
+
+
+def _open_mirror(span: "Span") -> Any:
+    """The entered mirror of a span just opened, or None."""
+    annotator = _span_annotator
+    if annotator is None:
+        return None
+    try:
+        mirror = annotator(span)
+        mirror.__enter__()
+        return mirror
+    except Exception:  # noqa: BLE001 - telemetry must not break tracing
+        return None
 
 
 def _notify_span(phase: str, span: "Span") -> None:
@@ -165,6 +202,10 @@ class Tracer:
         with self._lock:
             return self._roots.get(trace_id)
 
+    def now(self) -> float:
+        """The clock every span of this tracer is stamped with."""
+        return self._clock()
+
     # --- context ----------------------------------------------------------
 
     def activate(self, trace_id: str) -> contextvars.Token:
@@ -241,6 +282,7 @@ class Tracer:
         nesting; exceptions mark the span status 'error' and re-raise."""
         span = self.start_span(name, trace_id, parent_id, attrs)
         token = _current.set((span.trace_id, span.span_id))
+        mirror = _open_mirror(span)
         try:
             yield span
         except BaseException as exc:
@@ -250,6 +292,8 @@ class Tracer:
         else:
             self.end_span(span)
         finally:
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
             _current.reset(token)
 
     def event(self, name: str, **attrs: Any) -> None:

@@ -47,7 +47,7 @@ class FakeProfiler:
         self.started = []
         self.stopped = 0
 
-    def start_trace(self, path):
+    def start_trace(self, path, profiler_options=None):
         self.started.append(path)
 
     def stop_trace(self):

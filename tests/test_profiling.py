@@ -229,10 +229,11 @@ class FakeProfiler:
         self.fail_stop: Exception | None = None
         self._dir: str | None = None
 
-    def start_trace(self, path):
+    def start_trace(self, path, profiler_options=None):
         if self.fail_start is not None:
             raise self.fail_start
         self.started.append(path)
+        self.options = profiler_options
         self._dir = path
 
     def stop_trace(self):

@@ -1,0 +1,281 @@
+"""The served path's span tree: queue wait, nodes, device waits and the
+PNG save in the request's one trace; the profiler mirror; the set-up
+tallies. All on a tracer whose clock ticks once per reading, so every
+duration is a pure function of the span sequence."""
+
+import time
+
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.api.server import DistributedServer
+from comfyui_distributed_tpu.graph.executor import ExecutionContext, GraphExecutor
+from comfyui_distributed_tpu.graph.registry import NODE_REGISTRY
+from comfyui_distributed_tpu.resilience.chaos import FakeClock
+from comfyui_distributed_tpu.telemetry import Tracer, get_tracer, set_tracer
+from comfyui_distributed_tpu.telemetry import profiling, runtime, tracing
+from comfyui_distributed_tpu.telemetry.metrics import get_metrics_registry
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class SpanTestImage:
+    """A cacheable source node: a host image, so nothing is compiled."""
+
+    @classmethod
+    def INPUT_TYPES(cls):
+        return {"required": {"value": ("FLOAT", {"default": 0.5})}}
+
+    RETURN_TYPES = ("IMAGE",)
+    FUNCTION = "make"
+
+    def make(self, value):
+        import jax
+
+        # what a node that builds a program makes JAX report
+        jax.monitoring.record_event_duration_secs(TRACE_EVENT, 0.25)
+        jax.monitoring.record_event_duration_secs(FETCH_EVENT, 0.5)
+        return (np.full((1, 8, 8, 3), float(value), np.float32),)
+
+
+def graph(value=0.5):
+    return {
+        "1": {"class_type": "SpanTestImage", "inputs": {"value": value}},
+        "2": {"class_type": "SaveImage",
+              "inputs": {"images": ["1", 0], "filename_prefix": "spans"}},
+    }
+
+
+@pytest.fixture()
+def tracer():
+    ticking = Tracer(clock=FakeClock(step=1.0))
+    set_tracer(ticking)
+    return ticking
+
+
+@pytest.fixture()
+def server(tmp_config_path, tmp_path, monkeypatch, tracer):
+    monkeypatch.setitem(NODE_REGISTRY, "SpanTestImage", SpanTestImage)
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path / "out"))
+    runtime.install_jax_monitoring()
+    return DistributedServer(port=0, is_worker=True)
+
+
+def run_queued(server):
+    """The executor thread's loop, in this thread, until the queue is empty."""
+    server._prompt_queue.put(None)
+    server._executor_loop()
+
+
+def by_name(tracer, trace_id):
+    out = {}
+    for span in tracer.spans(trace_id):
+        out.setdefault(span["name"], []).append(span)
+    return out
+
+
+def test_a_request_is_one_tree_from_queue_wait_to_nodes(server, tracer):
+    job = server.queue_prompt(graph(), "p1")
+    run_queued(server)
+    assert job.error is None
+    (root,) = tracer.tree("p1")
+    assert root["name"] == "prompt_queue.wait" and root["attrs"] == {"depth": 0}
+    (execute,) = root["children"]
+    assert execute["name"] == "execute_prompt"
+    assert execute["attrs"]["nodes_run"] == 2 and execute["attrs"]["nodes_cached"] == 0
+    assert [(c["name"], c["attrs"]["node_id"]) for c in execute["children"]] == [
+        ("node.SpanTestImage", "1"), ("node.SaveImage", "2")]
+    assert root["end"] < execute["start"]
+    assert all(s["end"] is not None for s in tracer.spans("p1"))
+
+
+def test_a_cached_node_opens_no_span_and_still_reports_zero(server, tracer):
+    server.queue_prompt(graph(), "p1")
+    again = server.queue_prompt(graph(), "p2")
+    run_queued(server)
+    spans = by_name(tracer, "p2")
+    assert "node.SpanTestImage" not in spans and len(spans["node.SaveImage"]) == 1
+    assert again.timings["1"] == 0.0 and set(again.timings) == {"1", "2"}
+    assert spans["execute_prompt"][0]["attrs"]["nodes_run"] == 1
+    assert spans["execute_prompt"][0]["attrs"]["nodes_cached"] == 1
+
+
+def test_save_image_yields_device_wait_encode_and_write_with_bytes(server, tracer):
+    server.queue_prompt(graph(), "p1")
+    run_queued(server)
+    spans = by_name(tracer, "p1")
+    save = spans["node.SaveImage"][0]
+    parts = [spans[name][0] for name in ("device.wait", "png.encode", "file.write")]
+    assert all(p["parent_id"] == save["span_id"] for p in parts)
+    assert [p["start"] for p in parts] == sorted(p["start"] for p in parts)
+    wait, encode, write = parts
+    assert wait["attrs"]["bytes"] == 8 * 8 * 3 * 4
+    assert encode["attrs"]["bytes"] == write["attrs"]["bytes"] > 0
+
+
+def test_the_second_queued_prompt_waits_out_the_firsts_execution(server, tracer):
+    server.queue_prompt(graph(0.25), "p1")
+    server.queue_prompt(graph(0.75), "p2")
+    run_queued(server)
+    first, second = by_name(tracer, "p1"), by_name(tracer, "p2")
+    waited = second["prompt_queue.wait"][0]
+    assert waited["attrs"]["depth"] == 1
+    assert waited["duration"] >= first["execute_prompt"][0]["duration"]
+    assert waited["end"] >= first["execute_prompt"][0]["end"]
+
+
+def test_node_spans_carry_the_program_work_done_in_them(server, tracer):
+    server.queue_prompt(graph(), "p1")
+    run_queued(server)
+    spans = by_name(tracer, "p1")
+    source = spans["node.SpanTestImage"][0]["attrs"]
+    assert source == {"node_id": "1", "trace_s": 0.25, "cache_fetch_s": 0.5}
+    assert spans["node.SaveImage"][0]["attrs"] == {"node_id": "2"}
+
+
+def test_the_collector_read_back_is_a_device_wait(tracer):
+    from comfyui_distributed_tpu.graph.nodes_distributed import DistributedCollector
+
+    images = np.zeros((2, 4, 4, 3), np.float32)
+    with tracer.span("node.DistributedCollector", trace_id="t"):
+        DistributedCollector().run(images)
+    (wait,) = by_name(tracer, "t")["device.wait"]
+    assert wait["attrs"]["bytes"] == images.nbytes
+
+
+def test_last_timings_and_counts_without_a_server(tmp_path, monkeypatch, tracer):
+    monkeypatch.setitem(NODE_REGISTRY, "SpanTestImage", SpanTestImage)
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
+    context = ExecutionContext()
+    first, second = GraphExecutor(context), GraphExecutor(context)
+    first.execute(graph())
+    second.execute(graph())
+    assert (first.nodes_run, first.nodes_cached) == (2, 0)
+    assert (second.nodes_run, second.nodes_cached) == (1, 1)
+    assert second.last_timings["1"] == 0.0
+
+
+# --- the profiler mirror ----------------------------------------------------
+
+
+class FakeJaxProfiler:
+    def __init__(self, monkeypatch):
+        import jax
+
+        self.started, self.annotations, self.exited = [], [], 0
+        monkeypatch.setattr(jax.profiler, "start_trace", self.start_trace)
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", self.annotation)
+
+    def start_trace(self, path, profiler_options=None):
+        self.started.append(profiler_options)
+
+    def annotation(self, name, **attrs):
+        fake = self
+
+        class Annotation:
+            def __enter__(self):
+                fake.annotations.append((name, attrs))
+
+            def __exit__(self, *exc):
+                fake.exited += 1
+
+        return Annotation()
+
+
+@pytest.fixture()
+def fake_jax_profiler(monkeypatch):
+    yield FakeJaxProfiler(monkeypatch)
+    tracing.set_span_annotator(None)
+
+
+def test_capture_start_turns_the_python_tracer_off(tmp_path, fake_jax_profiler, tracer):
+    capture = profiling.ProfilerCapture(str(tmp_path))
+    answer = capture.start(5, "t")
+    (options,) = fake_jax_profiler.started
+    assert options.python_tracer_level == 0
+    assert options.host_tracer_level == type(options)().host_tracer_level
+    assert answer["started"] and answer["tracer_clock_s"] == 1.0
+    assert abs(answer["unix_ns"] / 1e9 - time.time()) < 60
+    capture.stop()
+
+
+def test_spans_are_mirrored_once_each_while_a_capture_is_open(
+    tmp_path, fake_jax_profiler, tracer
+):
+    capture = profiling.ProfilerCapture(str(tmp_path))
+    with tracer.span("before", trace_id="t"):
+        pass
+    capture.start(5, "t")
+    with tracer.span("execute_prompt", trace_id="t", prompt_id="p", skipped=[1]):
+        with tracer.span("node.SaveImage", node_id="2"):
+            pass
+        tracer.end_span(tracer.start_span("prompt_queue.wait", trace_id="t"))
+    assert fake_jax_profiler.annotations == [
+        ("execute_prompt", {"prompt_id": "p"}), ("node.SaveImage", {"node_id": "2"})]
+    assert fake_jax_profiler.exited == 2
+    capture.stop()
+    with tracer.span("after", trace_id="t"):
+        pass
+    assert len(fake_jax_profiler.annotations) == 2
+    assert tracing._span_annotator is None
+
+
+def test_a_failed_capture_start_installs_no_mirror(tmp_path, monkeypatch, tracer):
+    import jax
+
+    def refuse(path, profiler_options=None):
+        raise RuntimeError("profiler busy")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    assert profiling.ProfilerCapture(str(tmp_path)).start(5, "t")["started"] is False
+    assert tracing._span_annotator is None
+
+
+def test_a_mirror_that_raises_does_not_break_the_span(tracer):
+    def broken(span):
+        raise RuntimeError("no profiler")
+
+    tracing.set_span_annotator(broken)
+    try:
+        with tracer.span("execute_prompt", trace_id="t") as span:
+            pass
+    finally:
+        tracing.set_span_annotator(None)
+    assert span.end is not None and span.status == "ok"
+
+
+# --- set-up, split ------------------------------------------------------------
+
+
+def test_the_new_tallies_fill_from_monitoring_events_and_reach_the_scrape():
+    import jax
+
+    runtime.install_jax_monitoring()
+    before = runtime.tallies()
+    jax.monitoring.record_event_duration_secs(TRACE_EVENT, 1.5)
+    jax.monitoring.record_event_duration_secs(LOWER_EVENT, 0.75)
+    jax.monitoring.record_event_duration_secs(FETCH_EVENT, 0.25)
+    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 2.0)
+    jax.monitoring.record_event_duration_secs("/jax/some/other_duration", 9.0)
+    after = runtime.tallies()
+    moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
+    assert moved == {"trace_time_s": 1.5, "lower_time_s": 0.75,
+                     "cache_retrieval_s": 0.25, "compile_time_s": 2.0, "compiles": 1}
+    runtime.collect_runtime_gauges()
+    text = get_metrics_registry().render()
+    for name, key in (("cdt_jax_trace_time_seconds", "trace_time_s"),
+                      ("cdt_jax_lower_time_seconds", "lower_time_s"),
+                      ("cdt_jax_cache_retrieval_seconds", "cache_retrieval_s")):
+        assert f"{name} {after[key]}" in text or f"{name} {after[key]:g}" in text
+
+
+def test_reset_zeroes_every_tally_with_its_type():
+    runtime.reset_runtime_tallies()
+    zero = runtime.tallies()
+    assert all(v == 0 for v in zero.values())
+    assert isinstance(zero["compiles"], int) and isinstance(zero["trace_time_s"], float)
+    assert set(runtime.runtime_snapshot()) >= set(zero)
