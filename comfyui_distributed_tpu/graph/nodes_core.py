@@ -29,6 +29,7 @@ import numpy as np
 
 from ..models import pipeline as pl
 from ..ops import samplers as smp
+from ..ops.tiled_vae import vae_apply
 from ..parallel.mesh import (
     DATA_AXIS,
     data_axis_size,
@@ -876,8 +877,18 @@ class VAEDecode:
             and data_axis_size(mesh) > 1
         ):
             return (_decode_mesh(vae, mesh, samples["samples"]),)
-        imgs = vae.vae.apply(vae.params["vae"], samples["samples"], method="decode")
-        return (imgs,)
+        return (_vae_pass(vae, samples["samples"], "decode"),)
+
+
+def _vae_pass(vae, x, method: str) -> jax.Array:
+    """A whole decode or encode of `x` through the bundle's VAE as one
+    dispatched program (ops.tiled_vae.vae_apply). The `node.*` span it
+    runs under says which path the request took: `programs` 1 here,
+    `mesh_programs` 1 in `_decode_mesh`."""
+    from ..telemetry import get_tracer
+
+    get_tracer().annotate(programs=1)
+    return vae_apply(vae.vae, vae.params["vae"], x, method=method)
 
 
 def _decode_mesh(vae, mesh, latents) -> jax.Array:
@@ -887,6 +898,9 @@ def _decode_mesh(vae, mesh, latents) -> jax.Array:
     batch with plain ops instead asks XLA to partition the VAE
     mid-block's Pallas kernel, which it cannot ("Mosaic kernels cannot
     be automatically partitioned" — the first four-chip txt2img run)."""
+    from ..telemetry import get_tracer
+
+    get_tracer().annotate(mesh_programs=1)
     params = jax.device_put(vae.params["vae"], replicated(mesh))
     return _decode_mesh_jit(pl._Static(vae), pl._Static(mesh), params, latents)
 
@@ -915,8 +929,7 @@ class VAEEncode:
     FUNCTION = "encode"
 
     def encode(self, pixels, vae: pl.PipelineBundle, context=None):
-        z = vae.vae.apply(vae.params["vae"], pixels, method="encode")
-        return ({"samples": z},)
+        return ({"samples": _vae_pass(vae, pixels, "encode")},)
 
 
 def _mask_to_latent(mask, lh: int, lw: int) -> jax.Array:
@@ -982,7 +995,7 @@ class VAEEncodeForInpaint:
                 ((0, 0), (lo, hi), (lo, hi)),
             )
         neutral = pixels * (1.0 - hard[..., None]) + 0.5 * hard[..., None]
-        z = vae.vae.apply(vae.params["vae"], neutral, method="encode")
+        z = _vae_pass(vae, neutral, "encode")
         return (
             {
                 "samples": z,
@@ -1580,8 +1593,8 @@ class InpaintModelConditioning:
         hard = (m > 0.5).astype(jnp.float32)
         # reference pixel neutralization: (p - 0.5) * keep + 0.5
         neutral = (pixels - 0.5) * (1.0 - hard[..., None]) + 0.5
-        z_orig = vae.vae.apply(vae.params["vae"], pixels, method="encode")
-        z_masked = vae.vae.apply(vae.params["vae"], neutral, method="encode")
+        z_orig = _vae_pass(vae, pixels, "encode")
+        z_masked = _vae_pass(vae, neutral, "encode")
         mask_lat = _mask_to_latent(m, z_orig.shape[1], z_orig.shape[2])
         concat = jnp.concatenate([mask_lat, z_masked], axis=-1)
 

@@ -21,6 +21,19 @@ import jax
 from . import tiles as tile_ops
 
 
+@partial(jax.jit, static_argnames=("module", "method"))
+def vae_apply(module, params, x: jax.Array, method: str) -> jax.Array:
+    """One whole pass of a VAE (`method` "decode" or "encode") as one
+    program, for callers outside any jit (the graph's VAE nodes).
+    Called eagerly the same pass is ~800 one-operation programs with
+    the device idle between them. The flax module is the static key
+    (it hashes by its config, so bundles rebuilt or patched around the
+    same VAE share a program per input shape and dtype); the weights
+    are an argument, never constants of the executable, and nothing is
+    donated (the executor caches the input)."""
+    return module.apply(params, x, method=method)
+
+
 @partial(jax.jit, static_argnames=("vae_static", "tile", "overlap"))
 def decode_tiled(
     vae_static, params, latents: jax.Array, tile: int = 64, overlap: int = 8

@@ -296,20 +296,33 @@ class Tracer:
                 mirror.__exit__(None, None, None)
             _current.reset(token)
 
-    def event(self, name: str, **attrs: Any) -> None:
-        """Attach a point-in-time event to the active span, falling
-        back to the active trace's root span; no-op outside a trace."""
+    def _active_span(self) -> Optional[Span]:
+        """The active span, falling back to the active trace's root
+        span; None outside a trace."""
         state = _current.get()
         if state is None:
-            return
+            return None
         trace_id, span_id = state
         target = span_id or self.root_span_id(trace_id)
         if target is None:
-            return
+            return None
         with self._lock:
-            span = self._by_id.get(trace_id, {}).get(target)
+            return self._by_id.get(trace_id, {}).get(target)
+
+    def event(self, name: str, **attrs: Any) -> None:
+        """Attach a point-in-time event to the active span, falling
+        back to the active trace's root span; no-op outside a trace."""
+        span = self._active_span()
         if span is not None and len(span.events) < 1000:
             span.events.append({"name": name, "ts": self._clock(), "attrs": attrs})
+
+    def annotate(self, **attrs: Any) -> None:
+        """Set attributes on the active span from code that did not
+        open it (a node inside the executor's `node.*` span); no-op
+        outside a trace."""
+        span = self._active_span()
+        if span is not None:
+            span.attrs.update(attrs)
 
     # --- export -----------------------------------------------------------
 

@@ -81,6 +81,17 @@ def test_events_attach_to_active_span():
     assert span["events"][0]["attrs"]["message"] == "hello"
 
 
+def test_annotate_sets_attributes_on_the_innermost_active_span():
+    tracer = Tracer()
+    tracer.annotate(programs=1)  # outside a trace: nothing to set, nothing raised
+    with tracer.span("outer", trace_id="t1", node_id="7"):
+        with tracer.span("inner"):
+            tracer.annotate(bytes=3)
+        tracer.annotate(programs=1)
+    attrs = {s["name"]: s["attrs"] for s in tracer.spans("t1")}
+    assert attrs == {"outer": {"node_id": "7", "programs": 1}, "inner": {"bytes": 3}}
+
+
 def test_trace_eviction_bound():
     tracer = Tracer(max_traces=3)
     for i in range(5):
