@@ -29,6 +29,7 @@ import numpy as np
 
 from ..models import pipeline as pl
 from ..ops import samplers as smp
+from ..ops.attention import route_log as attention_route_log
 from ..ops.tiled_vae import vae_apply
 from ..parallel.mesh import (
     DATA_AXIS,
@@ -85,7 +86,32 @@ class CheckpointLoaderSimple:
         # strip file extensions so ComfyUI workflow values map to registry names
         name = os.path.splitext(str(ckpt_name))[0]
         bundle = _get_bundle(context, name)
+        _annotate_load(bundle)
         return (bundle, bundle, bundle)
+
+
+def _annotate_load(bundle) -> None:
+    """What the bundle holds, on the `node.CheckpointLoaderSimple`
+    span: per component (`unet`, `vae`, `te`, `te2`, ...) its parameter
+    count and its bytes as stored, and the fullest device's
+    `peak_bytes` after the load where the backend reports one."""
+    from ..parallel.sharding import params_byte_size
+    from ..telemetry import get_tracer
+
+    attrs = {}
+    for part, tree in bundle.params.items():
+        attrs[f"{part}_params"] = sum(
+            int(np.prod(np.shape(leaf)))
+            for leaf in jax.tree_util.tree_leaves(tree)
+        )
+        attrs[f"{part}_bytes"] = params_byte_size(tree)
+    peaks = [
+        (device.memory_stats() or {}).get("peak_bytes_in_use")
+        for device in jax.local_devices()
+    ]
+    if any(peaks):
+        attrs["peak_bytes"] = int(max(p for p in peaks if p))
+    get_tracer().annotate(**attrs)
 
 
 @register_node
@@ -556,37 +582,80 @@ class KSampler:
         bundle = model
         latents, noise_mask, extras = _prep_latents(bundle, latent_image)
         fixed = bool(latent_image.get("batch_index_fixed", False))
+        _annotate_sampling(
+            bundle, latents, positive, int(steps), float(cfg), sampler_name
+        )
 
         mesh = getattr(context, "mesh", None) if context is not None else None
-        if spec.per_participant and mesh is not None and data_axis_size(mesh) > 1:
-            _reject_fixed_on_mesh(fixed)
-            param, shift = pl.model_schedule_info(bundle)
-            sigmas = smp.get_model_sigmas(
-                param, scheduler, int(steps), denoise=float(denoise),
-                flow_shift=shift,
-            )
-            result = _sample_mesh(
-                bundle, mesh, spec, sigmas, cfg, sampler_name,
-                positive, negative, latents, noise_mask,
-            )
-            return ({**extras, **result},)
+        with attention_route_log() as routes:
+            if (
+                spec.per_participant
+                and mesh is not None
+                and data_axis_size(mesh) > 1
+            ):
+                _reject_fixed_on_mesh(fixed)
+                param, shift = pl.model_schedule_info(bundle)
+                sigmas = smp.get_model_sigmas(
+                    param, scheduler, int(steps), denoise=float(denoise),
+                    flow_shift=shift,
+                )
+                result = _sample_mesh(
+                    bundle, mesh, spec, sigmas, cfg, sampler_name,
+                    positive, negative, latents, noise_mask,
+                )
+            else:
+                result = {
+                    "samples": pl.img2img_latents(
+                        bundle,
+                        latents,
+                        positive,
+                        negative,
+                        steps=int(steps),
+                        sampler=sampler_name,
+                        scheduler=scheduler,
+                        cfg_scale=float(cfg),
+                        denoise=float(denoise),
+                        seed=int(spec.effective_seed()),
+                        noise_mask=noise_mask,
+                        batch_fixed_noise=fixed,
+                    )
+                }
+        if routes:
+            # only the request that traced the program gets here with
+            # anything: which implementation its attention took
+            from ..telemetry import get_tracer
 
-        effective_seed = spec.effective_seed()
-        out = pl.img2img_latents(
-            bundle,
-            latents,
-            positive,
-            negative,
-            steps=int(steps),
-            sampler=sampler_name,
-            scheduler=scheduler,
-            cfg_scale=float(cfg),
-            denoise=float(denoise),
-            seed=int(effective_seed),
-            noise_mask=noise_mask,
-            batch_fixed_noise=fixed,
-        )
-        return ({**extras, "samples": out},)
+            get_tracer().annotate(attention=", ".join(sorted(
+                {f"{route} {n}x{m}x{d}" for route, n, m, d in routes}
+            )))
+        return ({**extras, **result},)
+
+
+def _annotate_sampling(
+    bundle, latents, positive, steps: int, cfg: float, sampler_name: str
+) -> None:
+    """What the request asked of the denoiser, on the `node.KSampler`
+    span: the model `family`, `tokens` (the longest sequence its
+    attention sees: the latent's patches, plus the text tokens where
+    the family attends to both jointly) and `evals` (model evaluations:
+    sigma pairs x the sampler's evaluations a pair x two where a
+    negative is evaluated beside the positive)."""
+    from ..models import get_config
+    from ..models.registry import model_family
+    from ..ops.conditioning import as_conditioning
+    from ..telemetry import get_tracer
+
+    family = model_family(bundle.model_name)
+    patch = getattr(get_config(bundle.model_name), "patch_size", 1)
+    per_token = patch ** 2 if isinstance(patch, int) else int(np.prod(patch))
+    tokens = int(np.prod(latents.shape[1:-1])) // per_token
+    if family in ("mmdit", "sd3"):
+        first = positive[0] if isinstance(positive, (list, tuple)) else positive
+        tokens += as_conditioning(first).context.shape[1]
+    evals = smp.model_evals_per_scan(sampler_name, steps) * (
+        1 if cfg == 1.0 else 2
+    )
+    get_tracer().annotate(family=family, tokens=int(tokens), evals=int(evals))
 
 
 def _prep_latents(bundle, latent_image: dict):

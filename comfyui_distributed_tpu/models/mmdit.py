@@ -213,7 +213,8 @@ class _DoubleBlock(nn.Module):
         q = apply_rope(jnp.concatenate([tq, iq], axis=1), freqs)
         k = apply_rope(jnp.concatenate([tk, ik], axis=1), freqs)
         v = jnp.concatenate([tv, iv], axis=1)
-        attn = dot_product_attention(q, k, v).reshape(b, nt + ni, dim)
+        with jax.named_scope("joint_attn"):
+            attn = dot_product_attention(q, k, v).reshape(b, nt + ni, dim)
         t_attn, i_attn = attn[:, :nt], attn[:, nt:]
 
         def stream(x, a, sh2, sc2, g1, g2, name):
@@ -270,7 +271,8 @@ class _SingleBlock(nn.Module):
         q, k = _qk_norm(q, k, "")  # single_blocks.N.norm.{query,key}_norm
         q = apply_rope(q.astype(self.dtype), freqs)
         k = apply_rope(k.astype(self.dtype), freqs)
-        attn = dot_product_attention(q, k, v).reshape(b, n, dim)
+        with jax.named_scope("joint_attn"):
+            attn = dot_product_attention(q, k, v).reshape(b, n, dim)
         out = nn.Dense(dim, dtype=self.dtype, name="linear2")(
             jnp.concatenate([attn, nn.gelu(mlp, approximate=True)], axis=-1)
         )
@@ -379,26 +381,31 @@ class MMDiT(nn.Module):
         single_cls = (
             nn.remat(_SingleBlock, static_argnums=()) if cfg.remat else _SingleBlock
         )
+        # block scopes in the operations' metadata, as the UNet's
+        # down_N / mid / up_N: a device trace groups time by them
         for i in range(cfg.double_depth):
-            img, txt = double_cls(
-                cfg.heads, cfg.mlp_width, dt, name=f"double_blocks_{i}"
-            )(img, txt, vec, freqs)
+            with jax.named_scope(f"double_{i}"):
+                img, txt = double_cls(
+                    cfg.heads, cfg.mlp_width, dt, name=f"double_blocks_{i}"
+                )(img, txt, vec, freqs)
         stream = jnp.concatenate([txt, img], axis=1)
         for i in range(cfg.single_depth):
-            stream = single_cls(
-                cfg.heads, cfg.mlp_width, dt, name=f"single_blocks_{i}"
-            )(stream, vec, freqs)
+            with jax.named_scope(f"single_{i}"):
+                stream = single_cls(
+                    cfg.heads, cfg.mlp_width, dt, name=f"single_blocks_{i}"
+                )(stream, vec, freqs)
         img = stream[:, nt:nt + ni]  # reference tokens are dropped
 
         # final layer: adaLN (shift, scale) then linear to patch pixels
-        sh, sc = _modulation(vec, 2, cfg.hidden_dim, "final_layer_adaLN")
-        h = nn.LayerNorm(
-            use_bias=False, use_scale=False, dtype=jnp.float32
-        )(img.astype(jnp.float32))
-        h = h * (1 + sc) + sh
-        out = nn.Dense(
-            c * p * p, dtype=jnp.float32, name="final_layer_linear"
-        )(h)
+        with jax.named_scope("final"):
+            sh, sc = _modulation(vec, 2, cfg.hidden_dim, "final_layer_adaLN")
+            h = nn.LayerNorm(
+                use_bias=False, use_scale=False, dtype=jnp.float32
+            )(img.astype(jnp.float32))
+            h = h * (1 + sc) + sh
+            out = nn.Dense(
+                c * p * p, dtype=jnp.float32, name="final_layer_linear"
+            )(h)
         out = out.reshape(b, gh, gw, c, p, p)
         out = out.transpose(0, 1, 4, 2, 5, 3).reshape(b, hh, ww, c)
         return out

@@ -9,6 +9,7 @@ calls these; the distributed layers shard their inputs.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 from functools import partial
@@ -36,6 +37,16 @@ def params_storage_dtype():
     return None if jax.default_backend() == "cpu" else jnp.dtype(jnp.bfloat16)
 
 
+def _needs_cast(x, dt):
+    """Whether a leaf is a floating-point weight in another dtype than
+    the storage dtype `dt`."""
+    return (
+        hasattr(x, "dtype")
+        and jnp.issubdtype(x.dtype, jnp.floating)
+        and x.dtype != dt
+    )
+
+
 def maybe_cast_params(tree):
     """Cast floating-point weights to params_storage_dtype(). Applied
     by every model/VAE/TE/ControlNet/upscaler loader at bundle-build
@@ -51,11 +62,7 @@ def maybe_cast_params(tree):
         return tree
 
     def cast(x):
-        if (
-            hasattr(x, "dtype")
-            and jnp.issubdtype(x.dtype, jnp.floating)
-            and x.dtype != dt
-        ):
+        if _needs_cast(x, dt):
             y = x.astype(dt)
             if isinstance(x, jax.Array):
                 y.block_until_ready()
@@ -66,20 +73,85 @@ def maybe_cast_params(tree):
     return jax.tree_util.tree_map(cast, tree)
 
 
+def _run_storing(closed, args, dtype):
+    """Run a traced function operation by operation, as eager code
+    runs it, and return its outputs stored in `dtype`: each output is
+    cast the moment the operation that makes it has run, and every
+    value is dropped after its last use. The operations are the ones
+    the eager function would dispatch, so their small programs are
+    shared between models and with the compile cache; what differs is
+    that an output's float32 value lives only until its cast."""
+    from jax.extend.core import Literal
+
+    jaxpr = closed.jaxpr
+    outputs = {v for v in jaxpr.outvars if not isinstance(v, Literal)}
+    # only what an output depends on: `lazy_init` also leaves behind the
+    # forward pass's operations on the weights alone (a kernel cast to
+    # the compute dtype, a reshaped bias), whose results nothing reads
+    needed, eqns = set(outputs), []
+    for eqn in reversed(jaxpr.eqns):
+        if needed.intersection(eqn.outvars):
+            eqns.append(eqn)
+            needed.update(v for v in eqn.invars if not isinstance(v, Literal))
+    eqns.reverse()
+    uses = collections.Counter(
+        v for eqn in eqns for v in eqn.invars if not isinstance(v, Literal)
+    )
+    env = dict(zip(jaxpr.constvars, closed.consts))
+    env.update(zip(jaxpr.invars, args))
+
+    def read(v):
+        return v.val if isinstance(v, Literal) else env[v]
+
+    def stored(x):
+        if not _needs_cast(x, dtype):
+            return x
+        y = x.astype(dtype)
+        if not isinstance(y, jax.core.Tracer):
+            # dispatch runs ahead of the device: wait, so that the
+            # float32 buffers of many weights are never queued at once
+            y.block_until_ready()
+        return y
+
+    for eqn in eqns:
+        subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+        out = eqn.primitive.bind(*subfuns, *map(read, eqn.invars), **params)
+        env.update(zip(eqn.outvars, out if eqn.primitive.multiple_results else [out]))
+        for v in eqn.invars:
+            if not isinstance(v, Literal):
+                uses[v] -= 1
+                if not uses[v] and v not in outputs:
+                    env.pop(v, None)
+        for v in eqn.outvars:
+            if v in outputs and not uses[v]:
+                env[v] = stored(env[v])
+    return [stored(read(v)) for v in jaxpr.outvars]
+
+
 def init_params(module, key, *args, settle: bool = True, **kwargs):
     """Seeded random parameters for `module`: flax's `lazy_init`, which
     runs the parameter initializers and only shape-evaluates the
-    forward pass. The values are bit-identical to `module.init` on the
-    same dummy inputs; what goes is the forward pass itself, which on
-    an accelerator was ~70% of the ~1,000 small programs a model load
-    compiled one by one. Each component is settled into its storage
-    dtype as soon as it exists (`settle=False` keeps float32 for a
-    caller about to map a checkpoint onto the tree)."""
+    forward pass; the values are bit-identical to `module.init` on the
+    same dummy inputs. Where the weights are stored in another dtype
+    than float32 they are built in it, weight by weight
+    (`_run_storing`): a load never holds a float32 copy of a component
+    (a 3.2 B-parameter denoiser is 12.7 GB in float32, on a 16 GB chip
+    that also holds its text encoders). `settle=False` keeps float32
+    for a caller about to map a checkpoint onto the tree."""
     abstract_args, abstract_kwargs = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (args, kwargs)
     )
-    params = module.lazy_init(key, *abstract_args, **abstract_kwargs)
-    return maybe_cast_params(params) if settle else params
+
+    def build(k):
+        return module.lazy_init(k, *abstract_args, **abstract_kwargs)
+
+    dtype = params_storage_dtype() if settle else None
+    if dtype is None:
+        return build(key)
+    closed, shape = jax.make_jaxpr(build, return_shape=True)(key)
+    return jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(shape), _run_storing(closed, [key], dtype)
+    )
 
 
 @dataclasses.dataclass

@@ -158,6 +158,14 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             guidance_embed=False, flow_shift=1.0, remat=True
         ),
     },
+    # flux-dev at a quarter of its depth, the 1 : 2 ratio of block
+    # kinds kept and every width as published: what one 16 GB chip
+    # holds of the model beside its text encoders (the benchmark's
+    # flux.1-dev configuration says what the cut stands for)
+    "flux-dev-5x10": {
+        "family": "mmdit",
+        "config": MMDiTConfig(double_depth=5, single_depth=10, remat=True),
+    },
     "tiny-flux": {
         "family": "mmdit",
         "config": MMDiTConfig(
@@ -388,6 +396,15 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             heads=64, d_kv=64, per_layer_rel_bias=False,
         ),
     },
+    # the same encoder at 6 of its 24 layers, widths unchanged: the
+    # text side of flux-dev-5x10
+    "t5-xxl-6l": {
+        "family": "t5_encoder",
+        "config": T5EncoderConfig(
+            vocab_size=32128, d_model=4096, d_ff=10240, layers=6,
+            heads=64, d_kv=64, per_layer_rel_bias=False,
+        ),
+    },
     # SD3's T5 slot: same weights, 77-token padding (the reference
     # stack pads T5 to 77 for SD3; Flux uses the long padding)
     "t5-xxl-sd3": {
@@ -455,6 +472,7 @@ DEFAULT_TEXT_ENCODERS: dict[str, str] = {
 HIDDEN_POOLED_ENCODERS: dict[str, tuple[str, str]] = {
     "flux-dev": ("t5-xxl", "clip-l"),
     "flux-schnell": ("t5-xxl", "clip-l"),
+    "flux-dev-5x10": ("t5-xxl-6l", "clip-l"),
     "tiny-flux": ("tiny-t5-shared", "tiny-te"),
 }
 

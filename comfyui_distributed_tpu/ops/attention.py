@@ -12,6 +12,8 @@ it); this is new TPU-native surface.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
 import os
@@ -24,6 +26,26 @@ import jax.numpy as jnp
 # so the block sweep can re-run on real hardware without edits.
 BLOCK_Q = int(os.environ.get("CDT_FLASH_BQ", 128))
 BLOCK_K = int(os.environ.get("CDT_FLASH_BK", 128))
+
+
+_ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
+    "attention_route_log", default=None
+)
+
+
+@contextlib.contextmanager
+def route_log():
+    """Collect ("flash" | "xla", n, m, d) for every
+    `dot_product_attention` call made inside the block. Calls happen
+    while a program is traced, so a block around a jitted call fills
+    only on the request that builds the program; the graph's sampler
+    node reads it into its span."""
+    routes: list[tuple[str, int, int, int]] = []
+    token = _ROUTE_LOG.set(routes)
+    try:
+        yield routes
+    finally:
+        _ROUTE_LOG.reset(token)
 
 
 def dot_product_attention(
@@ -45,6 +67,11 @@ def dot_product_attention(
     use_flash = (
         attention_route(q, k) == "flash" if force_flash is None else force_flash
     )
+    log = _ROUTE_LOG.get()
+    if log is not None:
+        log.append(
+            ("flash" if use_flash else "xla", q.shape[1], k.shape[1], q.shape[3])
+        )
     if use_flash:
         d = q.shape[3]
         if d % 128 != 0:
