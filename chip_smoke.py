@@ -818,6 +818,9 @@ def leg_multichip(run: Run) -> None:
 # padding is 576 px, a 72x72 latent: SDXL attends at 1,296 tokens
 # (10 heads of 64) and 324 tokens (20 heads of 64) with tile batch 8
 # under CFG (batch 16), and its VAE middle block sees 5,184 tokens.
+# FLUX.1-dev at 1024^2 attends jointly over 512 text + 4,096 image
+# tokens with 24 heads of 128 (no CFG batch), and its VAE middle block
+# sees the 128x128 latent: 16,384 tokens.
 SERVED_SHAPES = (
     ("sd15 self 64x64", (2, 4096, 8, 40), 4096),
     ("sd15 self 32x32", (2, 1024, 8, 80), 1024),
@@ -829,6 +832,8 @@ SERVED_SHAPES = (
     ("sdxl tile self 18x18", (16, 324, 20, 64), 324),
     ("sdxl tile cross 36x36", (16, 1296, 10, 64), 77),
     ("sdxl tile vae mid 72x72", (8, 5184, 1, 512), 5184),
+    ("flux joint 4608", (1, 4608, 24, 128), 4608),
+    ("flux vae mid 128x128", (1, 16384, 1, 512), 16384),
 )
 # the same routes at sizes the Pallas interpreter finishes in seconds
 REHEARSAL_SHAPES = (
@@ -887,7 +892,8 @@ def attention_child(rehearsal: bool) -> int:
         if rehearsal:
             # the CPU never routes to the kernel by itself: ask for it
             # where the chip would, interpreted
-            flash = n % attention.BLOCK_Q == 0 and m % attention.BLOCK_K == 0
+            step = attention.ROUTE_MULTIPLE
+            flash = n % step == 0 and m % step == 0
             route = "flash (interpreted)" if flash else route
             fn = jax.jit(
                 lambda q, k, v, flash=flash: attention.dot_product_attention(

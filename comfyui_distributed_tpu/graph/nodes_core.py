@@ -625,9 +625,7 @@ class KSampler:
             # anything: which implementation its attention took
             from ..telemetry import get_tracer
 
-            get_tracer().annotate(attention=", ".join(sorted(
-                {f"{route} {n}x{m}x{d}" for route, n, m, d in routes}
-            )))
+            get_tracer().annotate(attention=", ".join(sorted(set(routes))))
         return ({**extras, **result},)
 
 

@@ -21,11 +21,21 @@ import os
 import jax
 import jax.numpy as jnp
 
-# Flash kernel tiling. Block sizes keep the (Bq x D) @ (D x Bk) matmuls on
-# MXU-friendly 128 boundaries. Env-tunable (CDT_FLASH_BQ / CDT_FLASH_BK)
-# so the block sweep can re-run on real hardware without edits.
-BLOCK_Q = int(os.environ.get("CDT_FLASH_BQ", 128))
-BLOCK_K = int(os.environ.get("CDT_FLASH_BK", 128))
+# Sequence lengths that are whole multiples of this route to the kernel,
+# and every block the kernel uses is a multiple of it (the MXU's edge and
+# the lane width).
+ROUTE_MULTIPLE = 128
+
+# Block caps, from the sweep on a v5e (PERF.md §6, PR 28): a grid step has
+# a fixed cost of a few tenths of a microsecond and an online-softmax
+# update costs per q row whatever the number of k columns, so a step
+# should cover as much of the problem as VMEM takes. Wider than this the
+# kernel stopped gaining (block_k 2304, 2048) or lost.
+MAX_BLOCK_Q = 512
+MAX_BLOCK_K = 1536
+# What one grid step may hold in VMEM by `flash_vmem_bytes`' count. The
+# compiler's scoped limit is 16 MiB, and it keeps temporaries of its own.
+VMEM_BUDGET = 12 * 2**20
 
 
 _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
@@ -35,17 +45,21 @@ _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
 
 @contextlib.contextmanager
 def route_log():
-    """Collect ("flash" | "xla", n, m, d) for every
-    `dot_product_attention` call made inside the block. Calls happen
-    while a program is traced, so a block around a jitted call fills
-    only on the request that builds the program; the graph's sampler
-    node reads it into its span."""
-    routes: list[tuple[str, int, int, int]] = []
+    """Collect one entry for every `dot_product_attention` call made
+    inside the block: `xla NxMxD`, or `flash NxMxD bq<block_q>
+    bk<block_k> <operand dtype>` with what the kernel chose for the
+    shape. Calls happen while a program is traced, so a block around a
+    jitted call fills only on the request that builds the program; the
+    graph's sampler node reads it into its span."""
+    routes: list[str] = []
     token = _ROUTE_LOG.set(routes)
     try:
         yield routes
     finally:
         _ROUTE_LOG.reset(token)
+
+
+_DTYPE_NAMES = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
 
 
 def dot_product_attention(
@@ -67,37 +81,90 @@ def dot_product_attention(
     use_flash = (
         attention_route(q, k) == "flash" if force_flash is None else force_flash
     )
+    n, m, d = q.shape[1], k.shape[1], q.shape[3]
+    pad = -d % ROUTE_MULTIPLE
     log = _ROUTE_LOG.get()
     if log is not None:
-        log.append(
-            ("flash" if use_flash else "xla", q.shape[1], k.shape[1], q.shape[3])
+        entry = f"{'flash' if use_flash else 'xla'} {n}x{m}x{d}"
+        if use_flash:
+            block_q, block_k = flash_blocks(n, m, d + pad, q.dtype.itemsize)
+            name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
+            entry += f" bq{block_q} bk{block_k} {name}"
+        log.append(entry)
+    if not use_flash:
+        return jax.nn.dot_product_attention(q, k, v)
+    if pad:
+        widths = ((0, 0), (0, 0), (0, 0), (0, pad))
+        out = flash_attention(
+            jnp.pad(q, widths), jnp.pad(k, widths), jnp.pad(v, widths),
+            scale=1.0 / math.sqrt(d), interpret=interpret,
         )
-    if use_flash:
-        d = q.shape[3]
-        if d % 128 != 0:
-            pad = -d % 128
-            widths = ((0, 0), (0, 0), (0, 0), (0, pad))
-            out = flash_attention(
-                jnp.pad(q, widths), jnp.pad(k, widths), jnp.pad(v, widths),
-                scale=1.0 / math.sqrt(d), interpret=interpret,
-            )
-            return out[..., :d]
-        return flash_attention(q, k, v, interpret=interpret)
-    return jax.nn.dot_product_attention(q, k, v)
+        return out[..., :d]
+    return flash_attention(q, k, v, interpret=interpret)
 
 
 def attention_route(q: jax.Array, k: jax.Array) -> str:
     """The implementation `dot_product_attention` gives these operands:
     "flash" (the Pallas kernel: a TPU backend and both sequence lengths
-    whole multiples of the block) or "xla"."""
+    whole multiples of `ROUTE_MULTIPLE`) or "xla"."""
     if os.environ.get("CDT_FLASH") == "0":  # kill switch
         return "xla"
     if jax.default_backend() != "tpu":
         return "xla"
     n, m = q.shape[1], k.shape[1]
-    if n % BLOCK_Q == 0 and m % BLOCK_K == 0 and n >= BLOCK_Q:
+    if n % ROUTE_MULTIPLE == 0 and m % ROUTE_MULTIPLE == 0 and n > 0:
         return "flash"
     return "xla"
+
+
+def _largest_block(length: int, cap: int) -> int:
+    """The largest multiple of `ROUTE_MULTIPLE` that divides `length`
+    and is at most `cap` (`length` is such a multiple itself)."""
+    for block in range(min(cap, length), 0, -ROUTE_MULTIPLE):
+        if length % block == 0:
+            return block
+    raise ValueError(f"{length} is not a multiple of {ROUTE_MULTIPLE}")
+
+
+def flash_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
+    """VMEM one grid step of the kernel holds: the q, k, v and output
+    blocks (double-buffered by the pipeline), the float32 accumulator,
+    the running max and sum (a lane tile wide each), and the step's
+    float32 scores, their `exp`, and `p` in the operands' dtype."""
+    blocks = 2 * (2 * block_q + 2 * block_k) * d * itemsize
+    carried = block_q * (d + 2 * ROUTE_MULTIPLE) * 4
+    scores = block_q * block_k * (4 + 4 + itemsize)
+    return blocks + carried + scores
+
+
+def flash_blocks(n: int, m: int, d: int, itemsize: int) -> tuple[int, int]:
+    """(block_q, block_k) for q of n rows, k/v of m rows, heads d wide
+    (as padded) and operands of `itemsize` bytes: the largest multiples
+    of `ROUTE_MULTIPLE` that divide n and m, up to the caps the sweep
+    found, shrunk (k first: it is only streamed) until a step fits
+    `VMEM_BUDGET`. Depends on nothing else, so VMEM never grows with m."""
+    if n % ROUTE_MULTIPLE or m % ROUTE_MULTIPLE or n <= 0 or m <= 0:
+        # fail loudly: a zero-length inner grid would silently return
+        # an UNWRITTEN output buffer (the finalize step never fires)
+        raise ValueError(
+            f"flash_attention needs N and M to be multiples of "
+            f"{ROUTE_MULTIPLE}, got N={n}, M={m}; route via "
+            "dot_product_attention instead"
+        )
+    cap_q, cap_k = MAX_BLOCK_Q, MAX_BLOCK_K
+    while True:
+        block_q, block_k = _largest_block(n, cap_q), _largest_block(m, cap_k)
+        if flash_vmem_bytes(block_q, block_k, d, itemsize) <= VMEM_BUDGET:
+            return block_q, block_k
+        if block_k > ROUTE_MULTIPLE:
+            cap_k = block_k - ROUTE_MULTIPLE
+        elif block_q > ROUTE_MULTIPLE:
+            cap_q = block_q - ROUTE_MULTIPLE
+        else:
+            raise ValueError(
+                f"no flash_attention block of head width {d} x {itemsize} "
+                f"bytes fits {VMEM_BUDGET} bytes of VMEM"
+            )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "scale"))
@@ -107,26 +174,26 @@ def flash_attention(
 ) -> jax.Array:
     """Tiled online-softmax attention (Pallas).
 
-    Grid: (batch*heads, N/BLOCK_Q, M/BLOCK_K) with K/V STREAMED one
-    (BLOCK_K, D) block per grid step — VMEM holds one K and one V block
-    at a time regardless of sequence length (long-video sequences
-    would blow VMEM if the whole K/V were block-resident). The online
-    max/denominator/accumulator live in VMEM scratch carried across
-    the innermost (sequential, "arbitrary") grid dimension; the output
-    block is written on the last K step.
+    Grid: (batch*heads, N/block_q, M/block_k), the blocks chosen from
+    the shape by `flash_blocks`, with K/V STREAMED one (block_k, D)
+    block per grid step — VMEM holds one K and one V block at a time
+    regardless of sequence length (long-video sequences would blow VMEM
+    if the whole K/V were block-resident). The online max/denominator/
+    accumulator live in VMEM scratch carried across the innermost
+    (sequential, "arbitrary") grid dimension; the output block is
+    written on the last K step.
+
+    Both dots take their operands in the dtype they arrive in (bfloat16
+    on every served path, whose products are exact in float32) and
+    accumulate in float32; `p` is rounded to v's dtype for the second.
+    Scale, max, `exp`, sum and correction stay float32.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, n, h, d = q.shape
     m = k.shape[1]
-    if n % BLOCK_Q != 0 or m % BLOCK_K != 0:
-        # fail loudly: a zero-length inner grid would silently return
-        # an UNWRITTEN output buffer (the finalize step never fires)
-        raise ValueError(
-            f"flash_attention needs N%{BLOCK_Q}==0 and M%{BLOCK_K}==0, "
-            f"got N={n}, M={m}; route via dot_product_attention instead"
-        )
+    block_q, block_k = flash_blocks(n, m, d, q.dtype.itemsize)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
 
@@ -135,7 +202,8 @@ def flash_attention(
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, m, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b * h, m, d)
 
-    num_k_blocks = m // BLOCK_K
+    num_k_blocks = m // block_k
+    contract_last = (((1,), (1,)), ((), ()))  # q @ k.T without the transpose
 
     def kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, max_ref, sum_ref):
         ki = pl.program_id(2)
@@ -146,16 +214,17 @@ def flash_attention(
             max_ref[...] = jnp.full_like(max_ref, -jnp.inf)
             sum_ref[...] = jnp.zeros_like(sum_ref)
 
-        qb = q_ref[0].astype(jnp.float32) * scale   # [BLOCK_Q, D]
-        kb = k_ref[0].astype(jnp.float32)           # [BLOCK_K, D]
-        vb = v_ref[0].astype(jnp.float32)
-        scores = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32)
+        vb = v_ref[0]                                # [block_k, D]
+        scores = scale * jax.lax.dot_general(        # [block_q, block_k]
+            q_ref[0], k_ref[0], contract_last,
+            preferred_element_type=jnp.float32,
+        )
         row_max = max_ref[...]
         new_max = jnp.maximum(row_max, scores.max(axis=-1, keepdims=True))
         correction = jnp.exp(row_max - new_max)
         p = jnp.exp(scores - new_max)
         acc_ref[...] = acc_ref[...] * correction + jnp.dot(
-            p, vb, preferred_element_type=jnp.float32
+            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32
         )
         sum_ref[...] = sum_ref[...] * correction + p.sum(
             axis=-1, keepdims=True
@@ -168,18 +237,18 @@ def flash_attention(
 
     out = pl.pallas_call(
         kernel,
-        grid=(b * h, n // BLOCK_Q, num_k_blocks),
+        grid=(b * h, n // block_q, num_k_blocks),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_Q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, BLOCK_K, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, BLOCK_Q, d), lambda bh, qi, ki: (bh, qi, 0)),
+        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((BLOCK_Q, d), jnp.float32),  # acc
-            pltpu.VMEM((BLOCK_Q, 1), jnp.float32),  # running max
-            pltpu.VMEM((BLOCK_Q, 1), jnp.float32),  # running sum
+            pltpu.VMEM((block_q, d), jnp.float32),  # acc
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running sum
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),

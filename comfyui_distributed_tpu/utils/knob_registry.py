@@ -457,10 +457,6 @@ KNOBS: tuple[Knob, ...] = (
     # --- ops -------------------------------------------------------------
     Knob("CDT_FLASH", "unset", "ops",
          "`0` force-disables the Pallas flash-attention kernel."),
-    Knob("CDT_FLASH_BQ", "128", "ops",
-         "Flash-attention query block size (MXU-aligned)."),
-    Knob("CDT_FLASH_BK", "128", "ops",
-         "Flash-attention key block size (MXU-aligned)."),
     Knob("CDT_BLEND", "unset", "ops",
          "`segment` selects segment-sum canvas blending for large grids."),
     Knob("CDT_DEVICE_CANVAS", "0", "ops",

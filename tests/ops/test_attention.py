@@ -1,9 +1,11 @@
 """Flash-attention kernel numerics vs the reference implementation
 (Pallas interpret mode on CPU)."""
 
+import chip_smoke
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from comfyui_distributed_tpu.ops import attention as attn
 
@@ -62,3 +64,82 @@ def test_flash_pads_unaligned_head_dim():
         np.testing.assert_allclose(
             np.asarray(flash), np.asarray(ref), atol=2e-5
         )
+
+
+# --- bfloat16 operands at small versions of every served case --------------
+
+# (label, [B, N, H, D] of q, keys M, the (block_q, block_k) it must take):
+# the blocks are named so that a case keeps exercising what its label says
+# (several q blocks, several k steps of the online softmax, one block)
+# when the caps move.
+BF16_CASES = [
+    ("square, two q blocks", (2, 1024, 2, 128), 1024, (512, 1024)),
+    ("n != m, two k steps", (1, 512, 2, 128), 3072, (512, 1536)),
+    ("odd multiple 384, one block", (1, 384, 2, 128), 384, (384, 384)),
+    ("odd multiple 1152, three q blocks", (1, 1152, 1, 128), 1152, (384, 1152)),
+    ("a single block of 256", (2, 256, 2, 128), 256, (256, 256)),
+    ("d=40 padded, two k steps", (2, 256, 2, 40), 2048, (256, 1024)),
+    ("d=80 padded", (2, 256, 2, 80), 256, (256, 256)),
+    ("d=160 padded to 256", (2, 256, 2, 160), 256, (256, 256)),
+    ("d=512 at one head, 2 x 2 blocks", (1, 1024, 1, 512), 1024, (512, 512)),
+]
+
+
+@pytest.mark.parametrize(
+    "q_shape,m,blocks", [c[1:] for c in BF16_CASES], ids=[c[0] for c in BF16_CASES]
+)
+def test_flash_bf16_operands_match_f32_reference(q_shape, m, blocks):
+    b, n, h, d = q_shape
+    assert attn.flash_blocks(n, m, d + -d % 128, 2) == blocks
+    kq, kk, kv = jax.random.split(jax.random.key(n * 131 + m * 7 + d), 3)
+    q = (2.0 * jax.random.normal(kq, q_shape)).astype(jnp.bfloat16)
+    k = jax.random.normal(kk, (b, m, h, d)).astype(jnp.bfloat16)
+    v = jax.random.normal(kv, (b, m, h, d)).astype(jnp.bfloat16)
+    out = attn.dot_product_attention(q, k, v, force_flash=True, interpret=True)
+    assert out.dtype == jnp.bfloat16 and out.shape == q_shape
+    with jax.default_matmul_precision("highest"):
+        ref = jax.nn.dot_product_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32)
+        )
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
+    scale = max(1.0, float(jnp.max(jnp.abs(ref))))
+    assert err <= chip_smoke.ATTENTION_TOLERANCE * scale, (err, scale)
+
+
+def test_flash_blocks_divide_align_and_fit():
+    shapes = [
+        (q_shape[1], m, q_shape[3]) for _, q_shape, m in chip_smoke.SERVED_SHAPES
+    ]
+    assert (4608, 4608, 128) in shapes  # FLUX's joint attention
+    # plus lengths with awkward divisors and a video-length key sequence
+    shapes += [(128 * 37, 128 * 37, 64), (256, 128 * 257, 128), (128, 32768, 128)]
+    reached = 0
+    for n, m, d in shapes:
+        if n % attn.ROUTE_MULTIPLE or m % attn.ROUTE_MULTIPLE:
+            continue  # routed to XLA (every SDXL tile shape, cross-attention)
+        reached += 1
+        padded = d + -d % 128
+        for itemsize in (2, 4):
+            block_q, block_k = attn.flash_blocks(n, m, padded, itemsize)
+            assert n % block_q == 0 and m % block_k == 0
+            assert block_q % 128 == 0 and block_k % 128 == 0
+            assert block_q <= attn.MAX_BLOCK_Q and block_k <= attn.MAX_BLOCK_K
+            assert (
+                attn.flash_vmem_bytes(block_q, block_k, padded, itemsize)
+                <= attn.VMEM_BUDGET
+            )
+    assert reached >= 9
+    # what the sweep chose for the two shapes that carry the benchmark
+    assert attn.flash_blocks(4608, 4608, 128, 2) == (512, 1536)
+    assert attn.flash_blocks(4096, 4096, 128, 2) == (512, 1024)
+
+
+@pytest.mark.parametrize("n,m", [(1296, 1296), (256, 77), (100, 128), (0, 128)])
+def test_flash_refuses_lengths_off_the_routing_multiple(n, m):
+    with pytest.raises(ValueError, match="multiples of 128"):
+        attn.flash_blocks(n, m, 128, 2)
+    if n:
+        q = jnp.ones((1, n, 1, 128), jnp.bfloat16)
+        k = jnp.ones((1, m, 1, 128), jnp.bfloat16)
+        with pytest.raises(ValueError, match="multiples of 128"):
+            attn.flash_attention(q, k, k, interpret=True)
