@@ -53,12 +53,12 @@ from __future__ import annotations
 
 import argparse
 import copy
+import importlib.util
 import io
 import json
 import os
 import shutil
 import signal
-import socket
 import subprocess
 import sys
 import time
@@ -68,6 +68,34 @@ import urllib.request
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = "comfyui_distributed_tpu"
 LEGS = ("serve", "restart", "attention", "multichip")
+
+if not os.path.isdir(os.path.join(HERE, PACKAGE)):
+    # a check of the program, not a stand-in for it: without the
+    # program nothing below, the benchmark's client included, is loaded
+    raise SystemExit(
+        f"chip_smoke: {PACKAGE}/ is not next to this script; it checks "
+        "the program and cannot run without it"
+    )
+
+
+def _load_client():
+    """benchmark/client.py by path, under a name of its own: what it
+    and this script do the same way (`Failure`, `port_is_dead`, `tail`,
+    the metrics-text parser, the seeded input image) lives there once.
+    `Server`, `request` and the legs stay here: the restart and
+    multichip legs need what the benchmark's do not have."""
+    spec = importlib.util.spec_from_file_location(
+        "cdt_benchmark_client", os.path.join(HERE, "benchmark", "client.py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+_client = _load_client()
+Failure = _client.Failure
+port_is_dead = _client.port_is_dead
+tail = _client.tail
 
 # tests/ops/test_upscale.py pins mesh == single-device USDU at
 # atol=2e-2 on the unit range: 5.1 of a PNG's 255 levels, plus one for
@@ -89,10 +117,6 @@ ATTENTION_TOLERANCE = 2e-2
 # took part in the USDU job peaks above this; one that only holds the
 # replicated weights does not.
 SDXL_WEIGHT_BYTES = 2 * 3_468_837_867
-
-
-class Failure(Exception):
-    """A leg did not do what it must."""
 
 
 _STARTED = time.monotonic()
@@ -122,20 +146,6 @@ def http(method: str, url: str, body=None, timeout: float = 60.0):
         return json.loads(text)
     except json.JSONDecodeError:
         return text
-
-
-def port_is_dead(port: int) -> bool:
-    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as sock:
-        sock.settimeout(1.0)
-        return sock.connect_ex(("127.0.0.1", port)) != 0
-
-
-def tail(path: str, lines: int = 40) -> str:
-    try:
-        with open(path, "r", encoding="utf-8", errors="replace") as fh:
-            return "".join(fh.readlines()[-lines:])
-    except OSError as exc:
-        return f"<no log: {exc}>"
 
 
 # --- server child ----------------------------------------------------------
@@ -209,7 +219,7 @@ def wait_for_server(base: str, proc, log_path: str, name: str) -> dict:
         if proc is not None and proc.poll() is not None:
             raise Failure(
                 f"{name}: exited with code {proc.returncode} during "
-                f"start-up; end of {log_path}:\n{tail(log_path)}"
+                f"start-up; end of {log_path}:\n{tail(log_path, 40)}"
             )
         try:
             return http("GET", base + "/distributed/system_info", timeout=10)
@@ -231,36 +241,17 @@ def wait_idle(base: str, name: str) -> None:
 
 
 def read_metrics(base: str) -> dict:
-    """The runtime gauges of /distributed/metrics (telemetry/runtime.py)."""
+    """The runtime gauges of /distributed/metrics (telemetry/runtime.py),
+    plus the tiles each role has processed (the multichip leg reads
+    who did the work)."""
     text = http("GET", base + "/distributed/metrics")
-    out = {
-        "compiles": 0.0, "compile_s": 0.0, "cache_hits": 0.0,
-        "cache_misses": 0.0, "peak_bytes_in_use": {}, "bytes_in_use": {},
-        "tiles": {},
-    }
-    plain = {
-        "cdt_jax_compiles": "compiles",
-        "cdt_jax_compile_time_seconds": "compile_s",
-        "cdt_jax_cache_hits": "cache_hits",
-        "cdt_jax_cache_misses": "cache_misses",
-    }
+    out = _client.parse_metrics(text)
+    out["tiles"] = {}
     for line in text.splitlines():
-        if line.startswith("#") or not line.strip():
-            continue
-        head, _, value = line.rpartition(" ")
-        name, _, labels = head.partition("{")
-        labels = dict(
-            part.split("=", 1) for part in labels.rstrip("}").split(",") if part
-        )
-        labels = {k: v.strip('"') for k, v in labels.items()}
-        if name in plain:
-            out[plain[name]] = float(value)
-        elif name == "cdt_device_memory_bytes":
-            stat = labels.get("stat")
-            if stat in ("peak_bytes_in_use", "bytes_in_use"):
-                out[stat][labels.get("device", "?")] = int(float(value))
-        elif name == "cdt_tiles_processed_total":
-            out["tiles"][labels.get("role", "?")] = int(float(value))
+        if line.startswith("cdt_tiles_processed_total{"):
+            labels, _, value = line.rpartition(" ")
+            role = labels.partition('role="')[2].partition('"')[0]
+            out["tiles"][role or "?"] = int(float(value))
     return out
 
 
@@ -380,26 +371,10 @@ class Run:
 
 
 def write_input_image(run: Run) -> None:
-    """LoadImage's "input.png", from a seed: smooth colour fields plus
-    fine noise, so every tile has content and no tile is constant."""
-    import numpy as np
-    from PIL import Image
-
-    n = run.input_px
-    rng = np.random.default_rng(20260926)
-    yy, xx = np.mgrid[0:n, 0:n].astype(np.float32) / n
-    phase = rng.uniform(0, 2 * np.pi, size=(3, 2))
-    planes = [
-        0.5 + 0.35 * np.sin(2 * np.pi * (3 + c) * xx + phase[c, 0])
-        * np.cos(2 * np.pi * (2 + c) * yy + phase[c, 1])
-        for c in range(3)
-    ]
-    image = np.stack(planes, axis=-1) + rng.normal(0, 0.04, size=(n, n, 3))
-    pixels = (np.clip(image, 0, 1) * 255 + 0.5).astype(np.uint8)
+    """LoadImage's "input.png", from a seed (the benchmark's generator)."""
     path = os.path.join(run.out, "data", "input", "input.png")
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    Image.fromarray(pixels).save(path)
-    say(f"input image {n}x{n} from seed 20260926 -> {path}")
+    _client.write_input_image(path, run.input_px, 20260926)
+    say(f"input image {run.input_px}x{run.input_px} from seed 20260926 -> {path}")
 
 
 def check_system_info(run: "Run", name: str, info: dict, local_devices=None) -> dict:
@@ -488,7 +463,7 @@ def request(
         if server.proc is not None and server.proc.poll() is not None:
             raise Failure(
                 f"{label}: server died mid-request; end of log:\n"
-                f"{tail(server.log_path)}"
+                f"{tail(server.log_path, 40)}"
             )
         if time.monotonic() > deadline:
             raise Failure(f"{label}: not done after 1500s")
@@ -658,7 +633,7 @@ def leg_attention(run: Run) -> None:
             }
     if proc.returncode != 0:
         raise Failure(
-            f"attention child exited {proc.returncode}; end of log:\n{tail(log_path)}"
+            f"attention child exited {proc.returncode}; end of log:\n{tail(log_path, 40)}"
         )
     bad = [r for r in rows if "shape" in r and not r["ok"]]
     if bad or not any("shape" in r for r in rows):
@@ -725,7 +700,7 @@ def leg_multichip(run: Run) -> None:
         try:
             info = wait_for_server(worker_base, None, log_path, "worker_chip1")
         except Failure as exc:
-            raise Failure(f"{exc}; end of {log_path}:\n{tail(log_path)}")
+            raise Failure(f"{exc}; end of {log_path}:\n{tail(log_path, 40)}")
         check_system_info(run, "worker_chip1", info, local_devices=1)
 
     def stop_worker() -> None:
@@ -944,12 +919,6 @@ def main(argv=None) -> int:
     parser.add_argument("--attention-child", action="store_true", help=argparse.SUPPRESS)
     args = parser.parse_args(argv)
 
-    if not os.path.isdir(os.path.join(HERE, PACKAGE)):
-        print(
-            f"chip_smoke: {PACKAGE}/ is not next to this script; it checks "
-            "the program and cannot run without it", file=sys.stderr,
-        )
-        return 2
     if args.attention_child:
         return attention_child(bool(args.rehearsal))
 

@@ -263,7 +263,7 @@ class JobRoutes:
             return web.json_response({"error": "no such job"}, status=404)
         accounting["status"] = "cancelled"
         # cancel-request → all tiles refunded: the reclaim-speed number
-        # the bench stamps as cancel_latency_ms
+        # scripts/lifecycle_soak.py reports
         accounting["cancel_latency_ms"] = round(
             (time_mod.monotonic() - started) * 1000.0, 3
         )

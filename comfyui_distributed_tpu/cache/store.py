@@ -290,7 +290,7 @@ def get_tile_cache() -> TileResultCache | None:
 
 
 def set_tile_cache(cache: TileResultCache | None) -> TileResultCache | None:
-    """Install a specific cache instance (chaos/bench harnesses); returns
+    """Install a specific cache instance (chaos harness, tests); returns
     the previous one so callers can restore it."""
     global _tile_cache
     with _cache_lock:

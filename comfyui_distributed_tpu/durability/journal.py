@@ -14,9 +14,9 @@ where payload is one UTF-8 JSON record carrying its log sequence
 number (``lsn``) plus the typed fields the job store emitted
 (docs/durability.md lists the record schema). Properties:
 
-- **rotation** — when a segment crosses ``CDT_JOURNAL_SEGMENT_BYTES``
-  it is fsync'd, closed, and a new segment is created with a directory
-  fsync, so segment boundaries are themselves durable;
+- **rotation** — when a segment crosses ``DEFAULT_SEGMENT_BYTES``
+  (4 MiB) it is fsync'd, closed, and a new segment is created with a
+  directory fsync, so segment boundaries are themselves durable;
 - **torn-tail truncation** — a crash mid-append leaves a final frame
   that is short or CRC-broken; replay truncates the LAST segment back
   to its last complete frame (the record was never acknowledged, so
@@ -213,9 +213,7 @@ class Journal:
     ) -> None:
         self.directory = directory
         self.segment_bytes = (
-            segment_bytes
-            if segment_bytes is not None
-            else _env_int("CDT_JOURNAL_SEGMENT_BYTES", DEFAULT_SEGMENT_BYTES)
+            segment_bytes if segment_bytes is not None else DEFAULT_SEGMENT_BYTES
         )
         self.fsync_every = (
             fsync_every if fsync_every is not None else _env_int("CDT_JOURNAL_FSYNC", 1)

@@ -213,7 +213,7 @@ class CrossJobExecutor:
     mesh data-axis width D), so every participant holds an equal
     slice — same rule as the mesh-aware GrantSampler.
     ``cross_job=False`` restricts every batch to a single job's items
-    (the per-job baseline the bench A/Bs against).
+    (the per-job baseline tests/test_chaos_xjob.py compares fill against).
     """
 
     def __init__(
@@ -288,7 +288,7 @@ class CrossJobExecutor:
 
             self._chips = max(1, _das(mesh))
         self._stop = threading.Event()
-        # --- accounting (read by bench + chaos assertions) ---------------
+        # --- accounting (read by chaos assertions) -----------------------
         self.dispatches = 0
         self.slots_real = 0
         self.slots_padded = 0

@@ -101,7 +101,7 @@ class HTTPWorkClient:
     High availability:
 
     - `master_url` may be a comma-separated address list (active first,
-      standbys after). `CDT_FAILOVER_AFTER` consecutive transport/5xx
+      standbys after). `FAILOVER_AFTER_ERRORS` (2) consecutive transport/5xx
       failures against the current address re-point to another — the
       re-pointed worker's next pull/heartbeat re-advertises its
       capacity, so the promoted master's placement policy re-learns the
@@ -179,7 +179,7 @@ class HTTPWorkClient:
         self._hb_suppressed_until = 0.0
         # Fleet telemetry piggyback: a compact versioned snapshot of
         # this process's metrics rides at most one pull/heartbeat per
-        # CDT_FLEET_SNAPSHOT_SECONDS (telemetry/fleet.local_snapshot).
+        # FLEET_SNAPSHOT_SECONDS (telemetry/fleet.local_snapshot).
         # <= 0 disables the piggyback entirely.
         self._telemetry_interval = FLEET_SNAPSHOT_SECONDS
         self._telemetry_last = 0.0
@@ -230,7 +230,7 @@ class HTTPWorkClient:
 
     def _count_error(self, op: str) -> None:
         """One master-RPC failure: counted per operation, and after
-        CDT_FAILOVER_AFTER consecutive failures against the current
+        FAILOVER_AFTER_ERRORS consecutive failures against the current
         address the rotation re-points (no-op with a single address).
         The failed address enters its per-URL backoff window, so the
         rotation won't land back on it while a healthy address exists."""

@@ -1360,57 +1360,6 @@ def _txt2img_jit(
     return bundle.vae.apply(params["vae"], latents, method="decode")
 
 
-def txt2img_flops(
-    bundle: PipelineBundle,
-    height: int = 512,
-    width: int = 512,
-    steps: int = 20,
-    sampler: str = "euler",
-    scheduler: str = "karras",
-    cfg_scale: float = 7.0,
-    batch: int = 1,
-) -> float | None:
-    """XLA-estimated FLOPs of ONE txt2img program (batch images) — the
-    txt2img MFU numerator. Composed scan-free (N guided model evals +
-    VAE decode; XLA cost analysis counts a lax.scan body once, see
-    ops/upscale._jitted_for_flops). Text encoding is excluded (a
-    one-time, sub-percent cost). Returns None when the backend exposes
-    no cost analysis."""
-    import logging
-
-    from ..ops.costs import xla_flops as _xla_flops
-
-    try:
-        param, shift = model_schedule_info(bundle)
-        sigmas = smp.get_model_sigmas(param, scheduler, steps, flow_shift=shift)
-        evals = smp.model_evals_per_scan(sampler, int(sigmas.shape[0]) - 1)
-        lh, lw = height // bundle.latent_scale, width // bundle.latent_scale
-        z = jnp.zeros((batch, lh, lw, bundle.latent_channels))
-        pos = encode_text_pooled(bundle, ["flops"] * batch)
-        neg = encode_text_pooled(bundle, [""] * batch)
-        params = bundle.params
-
-        def eval_fn(params, z, pos, neg):
-            model = guided_model(bundle, params, cfg_scale)
-            return model(
-                z, jnp.broadcast_to(sigmas[0], (z.shape[0],)), (pos, neg)
-            )
-
-        def dec_fn(params, z):
-            return bundle.vae.apply(params["vae"], z, method="decode")
-
-        ev = _xla_flops(eval_fn, params, z, pos, neg)
-        dec = _xla_flops(dec_fn, params, z)
-        if ev is None or dec is None:
-            return None
-        return evals * ev + dec
-    except Exception:
-        logging.getLogger("cdt.pipeline").warning(
-            "txt2img FLOPs estimate failed", exc_info=True
-        )
-        return None
-
-
 class _Static:
     """Wrap a python object as a hashable static jit argument."""
 

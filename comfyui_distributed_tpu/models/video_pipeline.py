@@ -208,62 +208,6 @@ def _video_model_fn(bundle: VideoPipelineBundle, params):
     return model_fn
 
 
-def t2v_flops(
-    bundle: "VideoPipelineBundle",
-    frames: int = 17,
-    height: int = 256,
-    width: int = 256,
-    steps: int = 20,
-    cfg_scale: float = 5.0,
-    batch: int = 1,
-) -> float | None:
-    """XLA-estimated FLOPs of ONE t2v program (batch clips) — the
-    video MFU numerator, composed scan-free (N guided DiT evals +
-    frame decode; XLA cost analysis counts a lax.scan body once, see
-    ops/upscale._jitted_for_flops). Text encoding excluded."""
-    import logging
-
-    from ..ops.costs import xla_flops as _xla_flops
-
-    try:
-        timesteps = smp.get_flow_timesteps(steps, bundle.flow_shift)
-        n_pairs = int(timesteps.shape[0]) - 1
-        lh, lw = height // bundle.latent_scale, width // bundle.latent_scale
-        lf = bundle.latent_frames(frames)
-        z = jnp.zeros((batch, lf, lh, lw, bundle.latent_channels))
-        pos = encode_video_text(bundle, ["flops"] * batch)
-        neg = encode_video_text(bundle, [""] * batch)
-        params = bundle.params
-
-        def eval_fn(params, z, pos, neg):
-            model = smp.cfg_flow_model(
-                _video_model_fn(bundle, params), cfg_scale
-            )
-            t = jnp.broadcast_to(timesteps[0] * 1000.0, (z.shape[0],))
-            return model(z, t, (pos, neg))
-
-        def dec_fn(params, zz):
-            # decode_frames with params as a TRACED argument — a
-            # closure over bundle.params would bake the VAE weights
-            # into the lowered HLO as constants
-            if bundle.temporal_scale != 1:
-                return bundle.vae.apply(params["vae"], zz, method="decode")
-            b, f = zz.shape[:2]
-            flat = zz.reshape((b * f,) + zz.shape[2:])
-            return bundle.vae.apply(params["vae"], flat, method="decode")
-
-        ev = _xla_flops(eval_fn, params, z, pos, neg)
-        dec = _xla_flops(dec_fn, params, z)
-        if ev is None or dec is None:
-            return None
-        return n_pairs * ev + dec
-    except Exception:
-        logging.getLogger("cdt.video_pipeline").warning(
-            "t2v FLOPs estimate failed", exc_info=True
-        )
-        return None
-
-
 @partial(
     jax.jit,
     static_argnames=(

@@ -934,9 +934,8 @@ _SECOND_ORDER = {
 
 def model_evals_per_scan(sampler: str, n_pairs: int) -> int:
     """CFG model evaluations sample() performs over n_pairs sigma pairs
-    — the step multiplier of the analytic FLOPs estimate in
-    ops/upscale._jitted_for_flops (XLA cost analysis counts a lax.scan
-    body once, so trip counts must be composed outside the HLO)."""
+    — the `evals` attribute of the `node.KSampler` span
+    (graph/nodes_core._annotate_sampling)."""
     return 2 * n_pairs - 1 if sampler in _SECOND_ORDER else n_pairs
 
 

@@ -113,8 +113,8 @@ def euler_ancestral_step(model_fn, x, sigma, sigma_next, cond, step_key):
 # BETWEEN steps (storage / checkpoint / transfer precision — halves
 # checkpoint and d2h bytes); the per-step model math still runs in the
 # model's parameter dtype via promotion, so the lane is a bounded
-# quality trade (bench stamps PSNR-vs-f32 into precision_ab), not an
-# unbounded one.
+# quality trade (its distance from the f32 trajectory on the chip: not
+# measured), not an unbounded one.
 PRECISION_LANES = ("f32", "bf16")
 
 

@@ -215,56 +215,9 @@ def blend_tiles(tiles: jax.Array, grid: TileGrid) -> jax.Array:
     the property the reference has to engineer with sorted sequential
     blending (upscale/modes/static.py:521-553).
 
-    Two formulations, equal by test: a sequential scan of windowed
-    canvas updates (default), and a single segment-sum scatter-add
-    with static indices (CDT_BLEND=segment). Measured at a 4K grid
-    (256 tiles, CPU): scan 81ms vs segment 323ms — XLA scatter loses
-    to the serialized windowed adds there; on a TPU the two have not
-    been compared (ROADMAP Queue 1 item 10).
+    A sequential scan of windowed canvas updates: one
+    dynamic_update_slice pair per tile, float32 accumulation.
     """
-    import os
-
-    if os.environ.get("CDT_BLEND") == "segment" and grid.num_tiles >= 2:
-        return _blend_tiles_segment(tiles, grid)
-    return _blend_tiles_scan(tiles, grid)
-
-
-def _blend_tiles_segment(tiles: jax.Array, grid: TileGrid) -> jax.Array:
-    batch, channels = int(tiles.shape[1]), int(tiles.shape[4])
-    p = grid.padding
-    ph, pw = grid.coverage_h + 2 * p, grid.coverage_w + 2 * p
-    th, tw = grid.padded_h, grid.padded_w
-    area = th * tw
-
-    # static flat canvas indices per tile cell (numpy, trace-time)
-    ii, jj = np.meshgrid(np.arange(th), np.arange(tw), indexing="ij")
-    idx_parts = [
-        ((y + ii) * pw + (x + jj)).reshape(-1) for y, x in grid.positions
-    ]
-    flat_idx = jnp.asarray(
-        np.concatenate(idx_parts).astype(np.int32)
-    )  # [T*area]
-
-    mask = feather_mask(grid, dtype=jnp.float32)  # [th, tw]
-    weighted = (
-        tiles.astype(jnp.float32) * mask[None, None, :, :, None]
-    )  # [T, B, th, tw, C]
-    # [T, th, tw, B, C] → [T*area, B*C]
-    updates = weighted.transpose(0, 2, 3, 1, 4).reshape(-1, batch * channels)
-
-    acc = jax.ops.segment_sum(updates, flat_idx, num_segments=ph * pw)
-    wsum = jax.ops.segment_sum(
-        jnp.tile(mask.reshape(-1), grid.num_tiles), flat_idx,
-        num_segments=ph * pw,
-    )
-    blended = acc / jnp.maximum(wsum, 1e-8)[:, None]
-    canvas = blended.reshape(ph, pw, batch, channels).transpose(2, 0, 1, 3)
-    return canvas[:, p : p + grid.image_h, p : p + grid.image_w, :].astype(
-        tiles.dtype
-    )
-
-
-def _blend_tiles_scan(tiles: jax.Array, grid: TileGrid) -> jax.Array:
     batch, channels = int(tiles.shape[1]), int(tiles.shape[4])
     p = grid.padding
     ph, pw = grid.coverage_h + 2 * p, grid.coverage_w + 2 * p

@@ -18,7 +18,6 @@ the property that makes elastic requeue safe.
 
 from __future__ import annotations
 
-import logging
 from functools import partial
 from typing import Any
 
@@ -31,9 +30,6 @@ from ..parallel.mesh import DATA_AXIS, data_axis_size, shard_map_compat
 from ..utils.constants import tile_scan_batch
 from . import samplers as smp
 from . import tiles as tile_ops
-from .costs import xla_flops as _xla_flops
-
-_log = logging.getLogger("cdt.upscale")
 
 
 # jax.image.resize method names for the user-facing upscale_method
@@ -623,101 +619,3 @@ def run_upscale(
         int(steps), sampler, scheduler, float(cfg), float(denoise),
         bool(tiled_decode), int(tile_batch),
     )
-
-
-def _jitted_for_flops(
-    bundle: pl.PipelineBundle,
-    image: jax.Array,
-    pos: jax.Array,
-    neg: jax.Array,
-    mesh: Any = None,
-    upscale_by: float = 2.0,
-    tile: int = 512,
-    padding: int = 32,
-    steps: int = 20,
-    sampler: str = "euler",
-    scheduler: str = "karras",
-    cfg: float = 7.0,
-    denoise: float = 0.35,
-    upscale_method: str = "bicubic",
-    tile_h: int | None = None,
-    tile_batch: int | None = None,
-    tiled_decode: bool = False,
-) -> float | None:
-    """XLA-estimated FLOPs of ONE full upscale program with these args
-    (whole mesh, all tiles) — the numerator of the bench's MFU.
-
-    XLA's cost_analysis counts a lax.scan body ONCE (the trip count is
-    not in the HLO metadata), and the timed program nests two scans
-    (tile groups x sampler steps) — costing it whole undercounts by
-    ~tiles*steps. The estimate is therefore composed from scan-free
-    components: VAE encode + N CFG model evals + VAE decode, costed on
-    one tile and multiplied by the tile count the program actually
-    executes (including the mesh tier's wrap-around padding). FLOPs
-    metadata is linear in batch, so tile_batch grouping cannot change
-    the total (the argument is accepted for run_upscale signature
-    parity); blend / resize / cond-prep are omitted (<1% of the work).
-    Returns None when the backend exposes no cost analysis."""
-    del tile_batch, upscale_method
-    try:
-        b, h, w, c = image.shape
-        _, _, grid = plan_grid(h, w, upscale_by, tile, padding, tile_h)
-        param, shift = pl.model_schedule_info(bundle)
-        sigmas = smp.get_model_sigmas(
-            param, scheduler, steps, denoise=denoise, flow_shift=shift
-        )
-        n_pairs = int(sigmas.shape[0]) - 1
-        evals = smp.model_evals_per_scan(sampler, n_pairs)
-        n_chips = data_axis_size(mesh) if mesh is not None else 1
-        t = grid.num_tiles
-        total_tiles = (-(-t // n_chips)) * n_chips
-
-        # shape-only: one padded tile as run_upscale's extract_tiles
-        # would produce it — no resize/extraction is materialized here
-        tiles1 = jnp.zeros(
-            (1, b, grid.padded_h, grid.padded_w, c), image.dtype
-        )
-        params = bundle.params
-        pos_p = prep_cond_for_tiles(pos, grid)
-        neg_p = prep_cond_for_tiles(neg, grid)
-
-        def enc_fn(params, tiles):
-            return jax.vmap(
-                lambda tl: bundle.vae.apply(params["vae"], tl, method="encode")
-            )(tiles)
-
-        z_spec = jax.eval_shape(enc_fn, params, tiles1)
-        z1 = jnp.zeros(z_spec.shape, z_spec.dtype)
-
-        def eval_fn(params, z, pos, neg):
-            model_fn = pl.guided_model(bundle, params, cfg)
-            pos_t = tile_cond(pos, jnp.int32(0), jnp.int32(0), grid)
-            neg_t = tile_cond(neg, jnp.int32(0), jnp.int32(0), grid)
-            return jax.vmap(
-                lambda zt: model_fn(
-                    zt,
-                    jnp.broadcast_to(sigmas[0], (zt.shape[0],)),
-                    (pos_t, neg_t),
-                )
-            )(z)
-
-        def dec_fn(params, z):
-            if tiled_decode:
-                from .tiled_vae import decode_tiled
-
-                return jax.vmap(
-                    lambda zt: decode_tiled(pl._Static(bundle), params["vae"], zt)
-                )(z)
-            return jax.vmap(
-                lambda zt: bundle.vae.apply(params["vae"], zt, method="decode")
-            )(z)
-
-        enc = _xla_flops(enc_fn, params, tiles1)
-        ev = _xla_flops(eval_fn, params, z1, pos_p, neg_p)
-        dec = _xla_flops(dec_fn, params, z1)
-        if enc is None or ev is None or dec is None:
-            return None
-        return float(total_tiles) * (enc + evals * ev + dec)
-    except Exception:
-        _log.warning("FLOPs estimate failed", exc_info=True)
-        return None

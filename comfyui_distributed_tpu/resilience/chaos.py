@@ -2259,8 +2259,8 @@ def run_chaos_xjob(
     must STILL be bit-identical.
 
     `cross_job=False` restricts every device batch to a single job's
-    items: the per-job baseline the fill-ratio A/B (bench
-    `mixed_small_jobs`) compares against.
+    items: the per-job baseline tests/test_chaos_xjob.py compares
+    the fill ratio against.
     """
     import jax
     import jax.numpy as jnp

@@ -20,9 +20,9 @@ States and transitions::
   binds one that requeues a quarantined worker's in-flight tiles
   (see `resilience.bind_quarantine_requeue`).
 
-Thresholds come from `CDT_CIRCUIT_SUSPECT_AFTER`,
-`CDT_CIRCUIT_FAILURES`, and `CDT_CIRCUIT_COOLDOWN` (see
-utils/constants.py); the clock is injectable for deterministic tests.
+Thresholds are the constants `CIRCUIT_SUSPECT_THRESHOLD` (2),
+`CIRCUIT_FAILURE_THRESHOLD` (5) and `CIRCUIT_COOLDOWN_SECONDS` (30) in
+utils/constants.py; the clock is injectable for deterministic tests.
 """
 
 from __future__ import annotations

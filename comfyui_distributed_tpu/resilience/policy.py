@@ -19,10 +19,10 @@ Design points:
   keep their existing exception taxonomy (`WorkerError`,
   `aiohttp.ClientError`, ...) instead of learning a new wrapper type.
 
-The default attempt counts / backoff bases come from the same env
-knobs the old loops used (`CDT_REQUEST_RETRIES`, `CDT_REQUEST_BACKOFF`,
-`CDT_WORK_PULL_RETRIES`, `CDT_WORK_PULL_RETRY_CAP`,
-`CDT_JOB_READY_POLLS`, `CDT_JOB_READY_POLL_INTERVAL`).
+The default attempt counts / backoff bases are the constants of
+utils/constants.py (`REQUEST_RETRY_COUNT`, `REQUEST_RETRY_BACKOFF`,
+`WORK_PULL_RETRY_COUNT`, `WORK_PULL_RETRY_CAP_SECONDS`,
+`JOB_READY_POLL_ATTEMPTS`, `JOB_READY_POLL_INTERVAL`).
 """
 
 from __future__ import annotations

@@ -13,14 +13,14 @@ the fact). This policy closes the loop *before* assignment:
   across models and tile sizes;
 - **size-aware batches** — ``batch_size`` scales a worker's pull batch
   with its relative speed (base x speed, clamped to
-  [1, CDT_SCHED_MAX_PULL_BATCH]), replacing the fixed per-pull split:
+  [1, SCHED_MAX_PULL_BATCH]), replacing the fixed per-pull split:
   fast workers amortize RPC overhead over more tiles, slow workers
   stay at 1 so a requeue never orphans a big batch. Analytic tile-FLOP
   estimates (ops/costs.py) convert heterogeneous tile sizes into one
   cost currency when a job carries per-task costs;
 - **tail trimming** — inside the last ``CDT_SCHED_TAIL_TILES`` pending
   tiles, workers that are SUSPECT/QUARANTINED in the health registry
-  or slower than ``CDT_SCHED_TRIM_RATIO`` x the mean speed are denied
+  or slower than ``SCHED_TRIM_RATIO`` x the mean speed are denied
   pulls (their pull reads as drained), steering the job's tail to fast
   healthy participants. Exempt ids (the master) are never denied —
   someone must always be able to finish the job.

@@ -66,11 +66,11 @@ class EndpointRotation:
     """Per-URL backoff + epoch tracking over one address list.
 
     The contract the old global cursor provided is preserved —
-    ``CDT_FAILOVER_AFTER`` consecutive failures against the current
+    ``FAILOVER_AFTER_ERRORS`` consecutive failures against the current
     address re-point to another — but failure history is now per
     address: a re-pointed-away-from address carries an exponential
-    backoff window (``CDT_ROUTER_BACKOFF_BASE`` · 2^bursts, capped at
-    ``CDT_ROUTER_BACKOFF_CAP``) so rotation never lands back on a
+    backoff window (``ROUTER_BACKOFF_BASE_SECONDS`` · 2^bursts, capped at
+    ``ROUTER_BACKOFF_CAP_SECONDS``: 0.5 s and 30 s) so rotation never lands back on a
     known-dead address while a healthy one exists, and any successful
     response resets that address's schedule. Selection prefers
     non-backed-off addresses reporting the highest fencing epoch (the

@@ -19,7 +19,8 @@ The observability subsystem the ROADMAP's perf work hangs off:
 - `watchdog`: straggler & stall detector feeding breaker suspect
   transitions and speculative tail-tile re-dispatch;
 - `runtime`: JAX trace/lower/compile/cache/HBM/host-RSS collectors on
-  the scrape, stamped into bench output via `runtime_snapshot`;
+  the scrape (read by `benchmark/client.py`) and, via
+  `runtime_snapshot`, in a worker's fleet snapshot;
 - `timeseries`: bounded two-tier ring-buffer retention (10 s raw /
   5 min rollup) for the fleet plane's windowed history;
 - `fleet`: worker snapshot production + the master's `FleetRegistry`

@@ -11,7 +11,7 @@ This module is the master-side signal plane:
   RSS from telemetry/runtime.py, mesh shape/device count) and
   piggyback it onto the heartbeat / `request_image` RPCs they already
   send (graph/usdu_elastic.HTTPWorkClient) — no new RPC, no new
-  socket, at most one snapshot per `CDT_FLEET_SNAPSHOT_SECONDS`;
+  socket, at most one snapshot per `FLEET_SNAPSHOT_SECONDS` (10 s);
 
 - the **`FleetRegistry`** on the master validates the snapshot version,
   merges per-worker state, derives tiles/sec rates from successive

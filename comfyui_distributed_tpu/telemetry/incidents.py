@@ -11,7 +11,7 @@ ONE atomically-written JSON bundle under ``CDT_INCIDENT_DIR``:
   window of history from BEFORE the trigger;
 - the implicated execution's trace spans (tracer retention);
 - the fleet registry's windowed history around the trigger
-  (``CDT_INCIDENT_WINDOW`` of `?since=`-style series, per worker);
+  (``INCIDENT_WINDOW_SECONDS``, 600, of `?since=`-style series, per worker);
 - the SLO engine's rule evaluations + transition history;
 - health-registry breaker states and placement weights/capacity;
 - the resolved ``CDT_*`` knob snapshot (utils/knob_registry);
@@ -25,7 +25,7 @@ Safety properties (the reason this is not just "dump some JSON"):
   can never stall an await point;
 - **trigger-keyed debounce + global rate limit**: a re-firing alert
   inside ``CDT_INCIDENT_DEBOUNCE`` captures nothing, and ANY two
-  automatic captures are at least ``CDT_INCIDENT_MIN_INTERVAL`` apart
+  automatic captures are at least ``INCIDENT_MIN_INTERVAL_SECONDS`` (10) apart
   (both windows are reserved at enqueue time, so a storm racing the
   writer cannot enqueue duplicates);
 - **bounded retention**: oldest bundles are pruned beyond
@@ -318,8 +318,7 @@ class IncidentManager:
         context: Optional[dict] = None,
     ) -> dict[str, Any]:
         """Synchronous capture on the CALLING thread (the manual-POST
-        route runs this via run_blocking; bench runs it inline on a
-        probe crash). Serialized with the writer thread through the
+        route runs this via run_blocking). Serialized with the writer thread through the
         capture lock; bypasses debounce/rate-limit but records into
         both windows."""
         now = self.clock()

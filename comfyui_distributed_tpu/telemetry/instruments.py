@@ -695,7 +695,7 @@ def event_subscriber_queue_depth() -> Gauge:
     return get_metrics_registry().gauge(
         "cdt_event_subscriber_queue_depth",
         "Events queued per event-bus subscriber at scrape time "
-        "(bounded by CDT_EVENT_QUEUE_SIZE)",
+        "(bounded by EVENT_QUEUE_SIZE, 512)",
         ("subscriber",),
     )
 

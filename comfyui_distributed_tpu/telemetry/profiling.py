@@ -244,8 +244,8 @@ class TransferLedger:
         return host / float(host + device)
 
     def snapshot(self, role: str = "worker") -> dict[str, Any]:
-        """The cumulative wire block (fleet snapshot v3 piggyback /
-        bench datum stamp). All integers except the derived ratio."""
+        """The cumulative wire block (fleet snapshot v3 piggyback).
+        All integers except the derived ratio."""
         with self._lock:
             return {
                 "role": role,
@@ -590,7 +590,7 @@ def peek_transfer_ledger() -> TransferLedger | None:
 def set_transfer_ledger(
     ledger: TransferLedger | None,
 ) -> TransferLedger | None:
-    """Install a specific ledger (chaos/bench harnesses); returns the
+    """Install a specific ledger (chaos harness, tests); returns the
     previous one so callers can restore it."""
     global _ledger
     with _ledger_lock:

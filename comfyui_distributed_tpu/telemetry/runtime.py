@@ -3,9 +3,9 @@
 Scrape-time collectors that put the *runtime* next to the *protocol*
 on `/distributed/metrics`: a latency regression means nothing without
 knowing whether the process was recompiling, missing the compilation
-cache, or running the chip's HBM to the edge. The same snapshot is
-stamped into `bench.py` output so every BENCH round carries its
-profiling context.
+cache, or running the chip's HBM to the edge. `benchmark/client.py`
+reads these gauges off the scrape (`compile_s`, program counts,
+`peak_hbm_gb`), and a worker's fleet snapshot carries the same numbers.
 
 Three sources:
 
@@ -14,7 +14,7 @@ Three sources:
   tracing, lowering to MLIR, backend compile — which holds the
   compilation-cache retrieval, also tallied alone) and the
   compilation-cache hit/miss events. Installed once per process
-  (idempotent), as early as possible (server start, bench init) so
+  (idempotent), as early as possible (server start) so
   compiles are counted from the first program. `tallies()` is the raw
   snapshot the graph executor diffs around each node.
 - **device.memory_stats()** — per-device HBM gauges
@@ -27,8 +27,8 @@ Three sources:
 
 `ensure_runtime_collectors()` binds the scrape collector to the
 CURRENT global registry (re-binding transparently after a test reset);
-`runtime_snapshot()` returns the same numbers as a plain dict for
-bench stamping.
+`runtime_snapshot()` returns the same numbers as a plain dict for the
+worker's fleet snapshot (telemetry/fleet.local_snapshot).
 """
 
 from __future__ import annotations
@@ -192,8 +192,8 @@ def ensure_runtime_collectors() -> None:
 
 
 def runtime_snapshot() -> dict[str, Any]:
-    """The same runtime health numbers as a plain dict — stamped into
-    bench.py's JSON datum so BENCH rounds carry profiling context."""
+    """The same runtime health numbers as a plain dict — what a
+    worker's fleet snapshot (telemetry/fleet.local_snapshot) carries."""
     out = tallies()
     for key in _DURATION_TALLIES.values():
         out[key] = round(out[key], 3)

@@ -56,7 +56,7 @@ This module is that signal plane:
 
 Memory is bounded: at most `MAX_TRACKED_KEYS` job entries per role and
 tenant entries per aggregator; idle entries (no activity within
-``CDT_USAGE_TTL``) are swept, folding their counters into per-tenant
+``USAGE_TTL_SECONDS``) are swept, folding their counters into per-tenant
 (then global) aggregates, and a departing tenant's retained series are
 evicted through the same `evict_label` seam the fleet plane uses —
 tenant-id churn cannot grow master memory (regression-tested).

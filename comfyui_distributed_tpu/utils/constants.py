@@ -1,8 +1,10 @@
 """Central timeouts, intervals, and env-var overrides.
 
-Parity with reference utils/constants.py (all knobs kept, names adapted
-to the TPU runtime). Every value can be overridden by an environment
-variable so deployments can tune without code changes.
+Parity with reference utils/constants.py (its own environment settings
+kept, names adapted to the TPU runtime). A value read through
+`_env_int`/`_env_float`/`os.environ` is a deployment setting listed in
+utils/knob_registry.py; a plain literal is the internal of one
+mechanism and has one value.
 """
 
 from __future__ import annotations
@@ -39,7 +41,7 @@ TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 HEARTBEAT_INTERVAL_SECONDS = _env_float("CDT_HEARTBEAT_INTERVAL", 5.0)
 HEARTBEAT_TIMEOUT_SECONDS = _env_float("CDT_HEARTBEAT_TIMEOUT", 60.0)
 # The collector waits in slices of timeout/20 so interrupts propagate fast.
-COLLECTOR_WAIT_SLICES = _env_int("CDT_COLLECTOR_WAIT_SLICES", 20)
+COLLECTOR_WAIT_SLICES = 20
 
 # --- payloads ------------------------------------------------------------
 # Reference upscale/job_store.py:12 (COMFYUI_MAX_PAYLOAD_SIZE 50MB) and
@@ -76,19 +78,19 @@ MEDIA_SYNC_TIMEOUT_SECONDS = _env_float("CDT_MEDIA_SYNC_TIMEOUT", 120.0)
 # --- probes / retries ----------------------------------------------------
 PROBE_TIMEOUT_SECONDS = _env_float("CDT_PROBE_TIMEOUT", 5.0)
 DISPATCH_TIMEOUT_SECONDS = _env_float("CDT_DISPATCH_TIMEOUT", 30.0)
-REQUEST_RETRY_COUNT = _env_int("CDT_REQUEST_RETRIES", 5)
-REQUEST_RETRY_BACKOFF = _env_float("CDT_REQUEST_BACKOFF", 0.5)
-WORK_PULL_RETRY_COUNT = _env_int("CDT_WORK_PULL_RETRIES", 10)
-WORK_PULL_RETRY_CAP_SECONDS = _env_float("CDT_WORK_PULL_RETRY_CAP", 30.0)
+REQUEST_RETRY_COUNT = 5
+REQUEST_RETRY_BACKOFF = 0.5
+WORK_PULL_RETRY_COUNT = 10
+WORK_PULL_RETRY_CAP_SECONDS = 30.0
 
 # --- circuit breaker (resilience/health.py) -------------------------------
 # A worker becomes SUSPECT after this many consecutive transport
 # failures, QUARANTINED (circuit open: no dispatch, tiles requeued)
 # at the failure threshold, and is probed again (half-open) once the
 # cooldown elapses.
-CIRCUIT_SUSPECT_THRESHOLD = _env_int("CDT_CIRCUIT_SUSPECT_AFTER", 2)
-CIRCUIT_FAILURE_THRESHOLD = _env_int("CDT_CIRCUIT_FAILURES", 5)
-CIRCUIT_COOLDOWN_SECONDS = _env_float("CDT_CIRCUIT_COOLDOWN", 30.0)
+CIRCUIT_SUSPECT_THRESHOLD = 2
+CIRCUIT_FAILURE_THRESHOLD = 5
+CIRCUIT_COOLDOWN_SECONDS = 30.0
 
 # --- watchdog (telemetry/watchdog.py) -------------------------------------
 # The straggler & stall detector: a worker whose rolling-median tile
@@ -97,11 +99,11 @@ CIRCUIT_COOLDOWN_SECONDS = _env_float("CDT_CIRCUIT_COOLDOWN", 30.0)
 # job with no completion progress for STALL seconds gets its in-flight
 # tail tiles speculatively re-enqueued. CDT_WATCHDOG=0 disables the
 # server's background monitor thread entirely.
-WATCHDOG_INTERVAL_SECONDS = _env_float("CDT_WATCHDOG_INTERVAL", 2.0)
+WATCHDOG_INTERVAL_SECONDS = 2.0
 WATCHDOG_STRAGGLER_FACTOR = _env_float("CDT_WATCHDOG_STRAGGLER_FACTOR", 4.0)
-WATCHDOG_MIN_SAMPLES = _env_int("CDT_WATCHDOG_MIN_SAMPLES", 3)
+WATCHDOG_MIN_SAMPLES = 3
 WATCHDOG_STALL_SECONDS = _env_float("CDT_WATCHDOG_STALL_SECONDS", 30.0)
-WATCHDOG_LATENCY_WINDOW = _env_int("CDT_WATCHDOG_LATENCY_WINDOW", 64)
+WATCHDOG_LATENCY_WINDOW = 64
 
 # --- scheduler control plane (scheduler/) ---------------------------------
 # Admission lanes in strict priority order as "name:depth" pairs; a
@@ -117,24 +119,24 @@ SCHED_MAX_ACTIVE = _env_int("CDT_SCHED_MAX_ACTIVE", 4)
 # DRR quantum in cost units added per tenant visit; a tenant's actual
 # replenishment is quantum x its weight (CDT_SCHED_TENANT_WEIGHTS,
 # "tenantA=3,tenantB=1"; unlisted tenants weigh 1).
-SCHED_QUANTUM = _env_float("CDT_SCHED_QUANTUM", 1.0)
+SCHED_QUANTUM = 1.0
 SCHED_TENANT_WEIGHTS = os.environ.get("CDT_SCHED_TENANT_WEIGHTS", "")
 # How long the queue route parks a request awaiting its grant before
 # answering 429 (the client should back off and retry).
-SCHED_GRANT_TIMEOUT_SECONDS = _env_float("CDT_SCHED_GRANT_TIMEOUT", 120.0)
+SCHED_GRANT_TIMEOUT_SECONDS = 120.0
 # Cost-aware placement (scheduler/placement.py): per-worker EWMA over
 # pull->submit tile latencies; a worker's pull batch scales with its
 # relative speed up to MAX_PULL_BATCH (BASE_PULL_BATCH at speed 1.0).
 # Inside the last TAIL_TILES of a job, suspect/slow workers are denied
 # pulls so the tail lands on fast healthy participants.
-SCHED_EWMA_ALPHA = _env_float("CDT_SCHED_EWMA_ALPHA", 0.25)
-SCHED_MIN_SAMPLES = _env_int("CDT_SCHED_MIN_SAMPLES", 2)
-SCHED_BASE_PULL_BATCH = _env_int("CDT_SCHED_BASE_PULL_BATCH", 2)
-SCHED_MAX_PULL_BATCH = _env_int("CDT_SCHED_MAX_PULL_BATCH", 8)
+SCHED_EWMA_ALPHA = 0.25
+SCHED_MIN_SAMPLES = 2
+SCHED_BASE_PULL_BATCH = 2
+SCHED_MAX_PULL_BATCH = 8
 SCHED_TAIL_TILES = _env_int("CDT_SCHED_TAIL_TILES", 2)
 # A worker slower than TRIM_RATIO x the fleet's mean speed is trimmed
 # from the tail (it may still pull while the queue is deep).
-SCHED_TRIM_RATIO = _env_float("CDT_SCHED_TRIM_RATIO", 0.5)
+SCHED_TRIM_RATIO = 0.5
 
 # --- cross-job continuous batching + step-level preemption ----------------
 # CDT_XJOB_BATCH=1 routes the elastic master/worker loops through the
@@ -229,7 +231,7 @@ JOB_DEADLINE_MAX_SECONDS = _env_float("CDT_JOB_DEADLINE_MAX", 0.0)
 # under half their thresholds.
 SHED_WAIT_P95_SECONDS = _env_float("CDT_SHED_WAIT_P95", 20.0)
 SHED_JOURNAL_P95_SECONDS = _env_float("CDT_SHED_JOURNAL_P95", 0.25)
-SHED_WINDOW_SAMPLES = _env_int("CDT_SHED_WINDOW", 64)
+SHED_WINDOW_SAMPLES = 64
 SHED_COOLDOWN_SECONDS = _env_float("CDT_SHED_COOLDOWN", 5.0)
 
 # --- elastic tile pipeline (graph/tile_pipeline.py) -----------------------
@@ -278,14 +280,14 @@ def default_compile_cache_dir() -> str:
 LEASE_TTL_SECONDS = _env_float("CDT_LEASE_TTL", 10.0)
 # Standby reconnect/lease-poll cadence while following the active
 # master's replication stream (api/standby.py).
-STANDBY_POLL_SECONDS = _env_float("CDT_STANDBY_POLL", 1.0)
+STANDBY_POLL_SECONDS = 1.0
 # Per-standby replication buffer (records). Overflow marks the stream
 # LOST (never drops interior records — a hole would silently desync the
 # replica) and the standby re-syncs from a fresh snapshot frame.
-STANDBY_BUFFER_RECORDS = _env_int("CDT_STANDBY_BUFFER", 4096)
+STANDBY_BUFFER_RECORDS = 4096
 # Consecutive transport/5xx failures against one master address before
 # the worker client rotates to the next address in its list.
-FAILOVER_AFTER_ERRORS = _env_int("CDT_FAILOVER_AFTER", 2)
+FAILOVER_AFTER_ERRORS = 2
 # Push-mode grants: workers hold the /distributed/events WebSocket and
 # wake on pushed grant_available frames instead of pull-polling; 0
 # restores the pure pull-poll protocol (the chaos-suite fallback).
@@ -293,7 +295,7 @@ PUSH_GRANTS_ENABLED = os.environ.get("CDT_PUSH_GRANTS", "1") != "0"
 # How long a push-mode worker parks on the grant signal after an empty
 # pull before concluding the queue is drained (one extra wait vs the
 # pull protocol's immediate exit).
-PUSH_WAIT_SECONDS = _env_float("CDT_PUSH_WAIT", 1.0)
+PUSH_WAIT_SECONDS = 1.0
 
 # --- region mode: quorum lease, sharded masters, autoscaler ---------------
 # Quorum lease peers (durability/quorum.py): a comma-separated list of
@@ -312,20 +314,20 @@ LEASE_PEERS = [
 SHARDS_SPEC = os.environ.get("CDT_SHARDS", "")
 # Virtual nodes per shard on the consistent-hash ring: more vnodes =
 # smoother job spread and smaller reshuffle when a shard joins/leaves.
-SHARD_VNODES = _env_int("CDT_SHARD_VNODES", 64)
+SHARD_VNODES = 64
 # Per-URL backoff for the worker client's master endpoints: after a
 # failure burst an address sits out base*2^k seconds (capped) so a
 # dead/lagging shard address can't throttle pulls against healthy
 # ones; any response resets its schedule.
-ROUTER_BACKOFF_BASE_SECONDS = _env_float("CDT_ROUTER_BACKOFF_BASE", 0.5)
-ROUTER_BACKOFF_CAP_SECONDS = _env_float("CDT_ROUTER_BACKOFF_CAP", 30.0)
+ROUTER_BACKOFF_BASE_SECONDS = 0.5
+ROUTER_BACKOFF_CAP_SECONDS = 30.0
 # Usage-driven autoscaler (scheduler/autoscale.py): 1 starts the
 # control loop on masters — SLO burn alerts + measured chip-second
 # demand drive launch/drain of managed local workers.
 AUTOSCALE_ENABLED = _env_int("CDT_AUTOSCALE", 0) == 1
 # Seconds between autoscaler evaluations (each evaluation emits one
 # decision record with measured chip-second cost/benefit).
-AUTOSCALE_INTERVAL_SECONDS = _env_float("CDT_AUTOSCALE_INTERVAL", 15.0)
+AUTOSCALE_INTERVAL_SECONDS = 15.0
 # Managed-worker count bounds the controller may scale between.
 AUTOSCALE_MIN_WORKERS = _env_int("CDT_AUTOSCALE_MIN", 1)
 AUTOSCALE_MAX_WORKERS = _env_int("CDT_AUTOSCALE_MAX", 8)
@@ -347,7 +349,7 @@ FLEET_ENABLED = os.environ.get("CDT_FLEET", "1") != "0"
 FLEET_INTERVAL_SECONDS = _env_float("CDT_FLEET_INTERVAL", 10.0)
 # Minimum seconds between a worker's piggybacked telemetry snapshots
 # (the snapshot rides heartbeat/request_image RPCs it already sends).
-FLEET_SNAPSHOT_SECONDS = _env_float("CDT_FLEET_SNAPSHOT_SECONDS", 10.0)
+FLEET_SNAPSHOT_SECONDS = 10.0
 # A worker that stops snapshotting for this long is evicted from the
 # fleet view (all its per-worker series drop).
 FLEET_TTL_SECONDS = _env_float("CDT_FLEET_TTL", 120.0)
@@ -369,7 +371,7 @@ USAGE_COST_ENABLED = _env_int("CDT_USAGE_COST", 0) == 1
 # Idle usage entries (jobs/tenants with no attribution activity for
 # this long) fold into retired aggregates and their retained series
 # evict — tenant-id churn must not grow master memory.
-USAGE_TTL_SECONDS = _env_float("CDT_USAGE_TTL", 3600.0)
+USAGE_TTL_SECONDS = 3600.0
 
 # --- device-time profiling plane (telemetry/profiling.py) -----------------
 # Master toggle for the transfer ledger: 0 disables the per-dispatch
@@ -385,9 +387,9 @@ PROFILE_MAX_CAPTURES = _env_int("CDT_PROFILE_MAX", 8)
 PROFILE_MAX_MB = _env_float("CDT_PROFILE_MAX_MB", 512.0)
 # Auto-capture: 1 lets an incident trigger (deadline / alert / poison)
 # grab a short device trace alongside the debug bundle; the capture
-# lasts CDT_PROFILE_AUTO_SECONDS and rides the incident writer thread.
+# lasts PROFILE_AUTO_SECONDS and rides the incident writer thread.
 PROFILE_AUTO_ENABLED = _env_int("CDT_PROFILE_AUTO", 0) == 1
-PROFILE_AUTO_SECONDS = _env_float("CDT_PROFILE_AUTO_SECONDS", 2.0)
+PROFILE_AUTO_SECONDS = 2.0
 
 
 def profile_dir_from_env() -> str | None:
@@ -483,7 +485,7 @@ def cheap_lane() -> str:
 # Per-subscriber bounded queue size for /distributed/events; a consumer
 # slower than the event rate loses its OLDEST events (drop-oldest) and
 # is told how many via the subscription's dropped count.
-EVENT_QUEUE_SIZE = _env_int("CDT_EVENT_QUEUE_SIZE", 512)
+EVENT_QUEUE_SIZE = 512
 
 # --- incident plane (telemetry/flight.py, telemetry/incidents.py) ---------
 # Always-on flight recorder: a synchronous bus tap keeps the last N
@@ -499,13 +501,13 @@ FLIGHT_SPAN_CAPACITY = _env_int("CDT_FLIGHT_SPANS", 2048)
 INCIDENT_DEBOUNCE_SECONDS = _env_float("CDT_INCIDENT_DEBOUNCE", 300.0)
 # Global floor between captures regardless of trigger key — an alert
 # storm across MANY distinct keys still cannot melt the disk.
-INCIDENT_MIN_INTERVAL_SECONDS = _env_float("CDT_INCIDENT_MIN_INTERVAL", 10.0)
+INCIDENT_MIN_INTERVAL_SECONDS = 10.0
 # Retention: prune-oldest beyond this many bundles or this many MB.
 INCIDENT_MAX_BUNDLES = _env_int("CDT_INCIDENT_MAX", 32)
 INCIDENT_MAX_MB = _env_float("CDT_INCIDENT_MAX_MB", 64.0)
 # Seconds of retained fleet history pulled into a bundle around the
 # trigger (the FleetRegistry ?since= window).
-INCIDENT_WINDOW_SECONDS = _env_float("CDT_INCIDENT_WINDOW", 600.0)
+INCIDENT_WINDOW_SECONDS = 600.0
 
 
 def incident_dir_from_env() -> str | None:
@@ -518,22 +520,22 @@ def incident_dir_from_env() -> str | None:
 # Grace period a result-submission endpoint waits for the master-side queue
 # to be created (reference api/job_routes.py:314-333), and the worker-side
 # job-ready poll (reference upscale/modes/static.py:33-47).
-JOB_INIT_GRACE_SECONDS = _env_float("CDT_JOB_INIT_GRACE", 10.0)
-JOB_READY_POLL_ATTEMPTS = _env_int("CDT_JOB_READY_POLLS", 20)
-JOB_READY_POLL_INTERVAL = _env_float("CDT_JOB_READY_POLL_INTERVAL", 1.0)
-QUEUE_POLL_INTERVAL_SECONDS = _env_float("CDT_QUEUE_POLL_INTERVAL", 0.1)
+JOB_INIT_GRACE_SECONDS = 10.0
+JOB_READY_POLL_ATTEMPTS = 20
+JOB_READY_POLL_INTERVAL = 1.0
+QUEUE_POLL_INTERVAL_SECONDS = 0.1
 
 # --- worker lifecycle ----------------------------------------------------
-AUTO_LAUNCH_DELAY_SECONDS = _env_float("CDT_AUTO_LAUNCH_DELAY", 2.0)
-MONITOR_POLL_INTERVAL_SECONDS = _env_float("CDT_MONITOR_POLL_INTERVAL", 2.0)
-WORKER_LAUNCH_GRACE_SECONDS = _env_float("CDT_LAUNCH_GRACE", 90.0)
-TUNNEL_START_TIMEOUT = _env_float("CDT_TUNNEL_START_TIMEOUT", 30.0)
+AUTO_LAUNCH_DELAY_SECONDS = 2.0
+MONITOR_POLL_INTERVAL_SECONDS = 2.0
+WORKER_LAUNCH_GRACE_SECONDS = 90.0
+TUNNEL_START_TIMEOUT = 30.0
 
 # --- network -------------------------------------------------------------
 DEFAULT_MASTER_PORT = _env_int("CDT_MASTER_PORT", 8188)
 FIRST_WORKER_PORT = _env_int("CDT_FIRST_WORKER_PORT", 8189)
-CONNECTION_POOL_LIMIT = _env_int("CDT_CONN_POOL_LIMIT", 100)
-CONNECTION_POOL_PER_HOST = _env_int("CDT_CONN_POOL_PER_HOST", 30)
+CONNECTION_POOL_LIMIT = 100
+CONNECTION_POOL_PER_HOST = 30
 
 # --- debug ---------------------------------------------------------------
 DEBUG_FLAG_TTL_SECONDS = 5.0

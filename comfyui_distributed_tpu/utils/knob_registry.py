@@ -43,8 +43,6 @@ KNOBS: tuple[Knob, ...] = (
          "Seconds between worker heartbeats to the master job store."),
     Knob("CDT_HEARTBEAT_TIMEOUT", "60.0", "liveness",
          "Seconds without a heartbeat before a worker's tiles are requeued."),
-    Knob("CDT_COLLECTOR_WAIT_SLICES", "20", "liveness",
-         "The result collector waits in timeout/N slices so interrupts propagate fast."),
     # --- payloads --------------------------------------------------------
     Knob("CDT_MAX_PAYLOAD_SIZE", "52428800", "payloads",
          "Maximum HTTP payload bytes accepted by the API (50 MB default)."),
@@ -68,21 +66,7 @@ KNOBS: tuple[Knob, ...] = (
          "Worker liveness probe timeout in seconds."),
     Knob("CDT_DISPATCH_TIMEOUT", "30.0", "orchestration",
          "Per-worker prompt dispatch timeout in seconds."),
-    Knob("CDT_REQUEST_RETRIES", "5", "orchestration",
-         "Retry attempts for idempotent master<->worker HTTP requests."),
-    Knob("CDT_REQUEST_BACKOFF", "0.5", "orchestration",
-         "Base seconds for exponential retry backoff (with jitter)."),
-    Knob("CDT_WORK_PULL_RETRIES", "10", "orchestration",
-         "Worker-side retry attempts for tile pull requests."),
-    Knob("CDT_WORK_PULL_RETRY_CAP", "30.0", "orchestration",
-         "Ceiling in seconds on the pull-retry backoff."),
     # --- resilience ------------------------------------------------------
-    Knob("CDT_CIRCUIT_SUSPECT_AFTER", "2", "resilience",
-         "Consecutive transport failures before a worker is marked suspect."),
-    Knob("CDT_CIRCUIT_FAILURES", "5", "resilience",
-         "Failure threshold that opens the circuit (quarantine + tile requeue)."),
-    Knob("CDT_CIRCUIT_COOLDOWN", "30.0", "resilience",
-         "Seconds a quarantined worker waits before a half-open probe."),
     Knob("CDT_FAULT_PLAN", "unset", "resilience",
          "Seeded fault-injection plan (e.g. `seed=3;latency(0.2)@request_image%0.5`) "
          "wrapping HTTP transport and the job store; unset = no injection."),
@@ -106,25 +90,17 @@ KNOBS: tuple[Knob, ...] = (
     Knob("CDT_SHED_WAIT_P95", "20.0", "lifecycle",
          "Queue-wait p95 seconds above which the brownout controller sheds "
          "one more lowest-priority lane (the premium lane never sheds)."),
-    Knob("CDT_SHED_WINDOW", "64", "lifecycle",
-         "Rolling sample window for the brownout controller's p95 signals."),
     Knob("CDT_TILE_MAX_ATTEMPTS", "3", "lifecycle",
          "Failed delivery attempts (crash/timeout requeues) a tile may "
          "accumulate before it is quarantined out of the pull set as poison."),
     # --- watchdog --------------------------------------------------------
     Knob("CDT_WATCHDOG", "1", "watchdog",
          "`0` disables the server's background straggler/stall monitor thread."),
-    Knob("CDT_WATCHDOG_INTERVAL", "2.0", "watchdog",
-         "Seconds between watchdog evaluation steps."),
     Knob("CDT_WATCHDOG_STRAGGLER_FACTOR", "4.0", "watchdog",
          "A worker whose rolling median tile latency exceeds this multiple of the "
          "global median is flagged suspect."),
-    Knob("CDT_WATCHDOG_MIN_SAMPLES", "3", "watchdog",
-         "Minimum completions in a worker's window before straggler verdicts."),
     Knob("CDT_WATCHDOG_STALL_SECONDS", "30.0", "watchdog",
          "A job quiet this long with tiles in flight triggers speculative re-dispatch."),
-    Knob("CDT_WATCHDOG_LATENCY_WINDOW", "64", "watchdog",
-         "Rolling latency window length per worker."),
     # --- scheduler -------------------------------------------------------
     Knob("CDT_SCHED_LANES", "interactive:64,batch:256,background:1024", "scheduler",
          "Admission lanes in strict priority order as name:depth pairs; a full "
@@ -133,25 +109,10 @@ KNOBS: tuple[Knob, ...] = (
          "Lane used when a queue request names none."),
     Knob("CDT_SCHED_MAX_ACTIVE", "4", "scheduler",
          "Orchestrations allowed to run concurrently; the rest wait in lanes."),
-    Knob("CDT_SCHED_QUANTUM", "1.0", "scheduler",
-         "Deficit-round-robin quantum (cost units) added per tenant visit."),
     Knob("CDT_SCHED_TENANT_WEIGHTS", "empty", "scheduler",
          "Per-tenant DRR weights as `tenantA=3,tenantB=1`; unlisted tenants weigh 1."),
-    Knob("CDT_SCHED_GRANT_TIMEOUT", "120.0", "scheduler",
-         "Seconds the queue route parks a request awaiting its grant before 429."),
-    Knob("CDT_SCHED_EWMA_ALPHA", "0.25", "scheduler",
-         "Smoothing factor for per-worker tile-latency speed EWMAs."),
-    Knob("CDT_SCHED_MIN_SAMPLES", "2", "scheduler",
-         "Samples required before a worker's speed EWMA influences placement."),
-    Knob("CDT_SCHED_BASE_PULL_BATCH", "2", "scheduler",
-         "Pull grant size for a speed-1.0 worker."),
-    Knob("CDT_SCHED_MAX_PULL_BATCH", "8", "scheduler",
-         "Ceiling on speed-scaled pull grant sizes."),
     Knob("CDT_SCHED_TAIL_TILES", "2", "scheduler",
          "Within this many remaining tiles, suspect/slow workers are denied pulls."),
-    Knob("CDT_SCHED_TRIM_RATIO", "0.5", "scheduler",
-         "Workers slower than this fraction of fleet mean speed are trimmed "
-         "from the job tail."),
     # --- cross-job batching + step-level preemption ----------------------
     Knob("CDT_PREEMPT", "1", "scheduler",
          "Step-level preemption: a premium-lane arrival flags running "
@@ -206,15 +167,10 @@ KNOBS: tuple[Knob, ...] = (
          "via a dedicated writer thread (the <5% overhead mode; a SIGKILL "
          "may lose the last in-flight records, which recovery then "
          "recomputes bit-identically)."),
-    Knob("CDT_JOURNAL_SEGMENT_BYTES", "4194304", "durability",
-         "Journal segment size before fsync'd rotation (4 MiB default)."),
     Knob("CDT_SNAPSHOT_EVERY", "256", "durability",
          "Journal appends between control-plane snapshots; each snapshot "
          "prunes the segments it supersedes."),
     # --- high availability (failover / push grants) ----------------------
-    Knob("CDT_FAILOVER_AFTER", "2", "ha",
-         "Consecutive transport/5xx failures against one master address before "
-         "the worker client rotates to the next address in its list."),
     Knob("CDT_LEASE_TTL", "10.0", "ha",
          "Master lease TTL in seconds (durability/lease.py): the standby "
          "promotes itself once the lease has been expired this long; also "
@@ -222,17 +178,9 @@ KNOBS: tuple[Knob, ...] = (
     Knob("CDT_PUSH_GRANTS", "1", "ha",
          "`0` disables push-mode grants: workers then pull-poll instead of "
          "waking on pushed grant_available events over /distributed/events."),
-    Knob("CDT_PUSH_WAIT", "1.0", "ha",
-         "Seconds a push-mode worker parks on the grant signal after an empty "
-         "pull before concluding the queue is drained."),
-    Knob("CDT_STANDBY_BUFFER", "4096", "ha",
-         "Per-standby replication buffer in records; overflow marks the "
-         "stream lost and the standby re-syncs from a fresh snapshot frame."),
     Knob("CDT_STANDBY_OF", "unset", "ha",
          "Comma-separated active-master URL list; set (or pass --standby) to "
          "run this master as a warm standby tailing the journal stream."),
-    Knob("CDT_STANDBY_POLL", "1.0", "ha",
-         "Standby reconnect/lease-poll cadence in seconds."),
     # --- region control plane (quorum lease / shards / autoscaler) -------
     Knob("CDT_AUTOSCALE", "0", "region",
          "`1` starts the usage-driven autoscaler loop on masters "
@@ -243,10 +191,6 @@ KNOBS: tuple[Knob, ...] = (
          "Seconds utilization must stay below half the target before a "
          "scale-down drains a worker; scale-up is immediate, scale-down "
          "is patient (thrash guard)."),
-    Knob("CDT_AUTOSCALE_INTERVAL", "15.0", "region",
-         "Seconds between autoscaler evaluations; each evaluation emits "
-         "one decision record and settles the previous decision's "
-         "measured capacity/demand deltas."),
     Knob("CDT_AUTOSCALE_MAX", "8", "region",
          "Upper bound on managed worker count; pressure at the bound "
          "holds with `reason=pressure at max_workers` instead of "
@@ -264,28 +208,15 @@ KNOBS: tuple[Knob, ...] = (
          "sidecar to majority agreement across these registers "
          "(durability/quorum.py) — epoch fencing and FencedOut "
          "semantics carry over unchanged."),
-    Knob("CDT_ROUTER_BACKOFF_BASE", "0.5", "region",
-         "Base of the per-URL exponential backoff window "
-         "(base*2^bursts seconds) a master address sits out after a "
-         "failure burst trips the rotation threshold."),
-    Knob("CDT_ROUTER_BACKOFF_CAP", "30.0", "region",
-         "Ceiling in seconds on the per-URL backoff window so a "
-         "long-dead address is still re-probed at a bounded cadence."),
     Knob("CDT_SHARDS", "empty", "region",
          "Region shard map: shards separated by `;`, each a "
          "comma-separated master address list (active first, standbys "
          "after). Non-empty enables consistent-hash job routing "
          "(scheduler/router.py); empty keeps the single-master "
          "topology."),
-    Knob("CDT_SHARD_VNODES", "64", "region",
-         "Virtual nodes per shard on the consistent-hash ring: more "
-         "vnodes = smoother job spread and smaller reshuffle when a "
-         "shard joins or leaves."),
     # --- telemetry -------------------------------------------------------
     Knob("CDT_METRIC_MAX_SERIES", "128", "telemetry",
          "Per-metric label-series cap; excess series collapse into `_overflow`."),
-    Knob("CDT_EVENT_QUEUE_SIZE", "512", "telemetry",
-         "Bounded per-subscriber queue for /distributed/events (drop-oldest)."),
     Knob("CDT_TRACE_EXPORT_DIR", "unset", "telemetry",
          "When set, each execution's span tree is exported as JSONL here."),
     Knob("CDT_RUNTIME_DEVICE_STATS", "1", "telemetry",
@@ -296,17 +227,12 @@ KNOBS: tuple[Knob, ...] = (
     Knob("CDT_FLEET_INTERVAL", "10.0", "telemetry",
          "Seconds between master-side fleet sampling passes "
          "(sweep + rollup + SLO burn-rate evaluation)."),
-    Knob("CDT_FLEET_SNAPSHOT_SECONDS", "10.0", "telemetry",
-         "Minimum seconds between a worker's piggybacked telemetry "
-         "snapshots on heartbeat/request_image; <=0 disables the piggyback."),
     Knob("CDT_FLEET_TTL", "120.0", "telemetry",
          "Seconds without a snapshot before a worker is evicted from the "
          "fleet view (all its retained series drop)."),
     Knob("CDT_PROFILE_AUTO", "0", "telemetry",
          "`1` makes every incident bundle capture a short device trace "
          "(requires CDT_PROFILE_DIR; the bundle records the capture ids)."),
-    Knob("CDT_PROFILE_AUTO_SECONDS", "2.0", "telemetry",
-         "Duration in seconds of the automatic incident-triggered trace."),
     Knob("CDT_PROFILE_DIR", "unset", "telemetry",
          "Directory retained jax.profiler traces are captured into; unset "
          "disables the /distributed/profile capture routes (the "
@@ -337,9 +263,6 @@ KNOBS: tuple[Knob, ...] = (
          "`1` multiplies DRR admission cost by the tenant's measured "
          "chip-seconds-per-tile ratio vs the fleet mean (clamped to "
          "[0.1, 10]), replacing the static estimated_tiles-only cost."),
-    Knob("CDT_USAGE_TTL", "3600.0", "telemetry",
-         "Seconds of inactivity before a job/tenant usage entry folds "
-         "into retired aggregates and its retained series evict."),
     # --- tile result cache -----------------------------------------------
     Knob("CDT_CACHE", "0", "cache",
          "`1` enables the master-side content-addressed tile result "
@@ -394,32 +317,11 @@ KNOBS: tuple[Knob, ...] = (
     Knob("CDT_INCIDENT_DEBOUNCE", "300.0", "incidents",
          "Seconds a trigger key (e.g. one SLO's alert) is debounced after "
          "a capture — a re-firing alert inside the window captures nothing."),
-    Knob("CDT_INCIDENT_MIN_INTERVAL", "10.0", "incidents",
-         "Global floor in seconds between ANY two automatic captures — an "
-         "alert storm across many keys still cannot melt the disk."),
     Knob("CDT_INCIDENT_MAX", "32", "incidents",
          "Retained bundle count; the oldest bundles are pruned beyond it."),
     Knob("CDT_INCIDENT_MAX_MB", "64.0", "incidents",
          "Total on-disk bundle budget in MB; oldest pruned beyond it."),
-    Knob("CDT_INCIDENT_WINDOW", "600.0", "incidents",
-         "Seconds of retained fleet history pulled into a bundle around "
-         "the trigger (the /distributed/fleet ?since= window)."),
-    # --- jobs ------------------------------------------------------------
-    Knob("CDT_JOB_INIT_GRACE", "10.0", "jobs",
-         "Seconds result submission waits for the master-side queue to appear."),
-    Knob("CDT_JOB_READY_POLLS", "20", "jobs",
-         "Worker-side job-ready poll attempts before giving up."),
-    Knob("CDT_JOB_READY_POLL_INTERVAL", "1.0", "jobs",
-         "Seconds between worker-side job-ready polls."),
-    Knob("CDT_QUEUE_POLL_INTERVAL", "0.1", "jobs",
-         "Master collection-loop poll interval in seconds."),
     # --- workers ---------------------------------------------------------
-    Knob("CDT_AUTO_LAUNCH_DELAY", "2.0", "workers",
-         "Delay before auto-launching configured local workers at startup."),
-    Knob("CDT_MONITOR_POLL_INTERVAL", "2.0", "workers",
-         "Master-liveness poll interval inside worker processes."),
-    Knob("CDT_LAUNCH_GRACE", "90.0", "workers",
-         "Seconds a launched worker gets to answer probes before being declared dead."),
     Knob("CDT_LOG_DIR", "./logs/workers", "workers",
          "Directory for per-worker stdout/stderr log files."),
     # --- network ---------------------------------------------------------
@@ -427,10 +329,6 @@ KNOBS: tuple[Knob, ...] = (
          "Default master HTTP port."),
     Knob("CDT_FIRST_WORKER_PORT", "8189", "network",
          "First port assigned to auto-launched local workers."),
-    Knob("CDT_CONN_POOL_LIMIT", "100", "network",
-         "aiohttp connection pool total limit."),
-    Knob("CDT_CONN_POOL_PER_HOST", "30", "network",
-         "aiohttp connection pool per-host limit."),
     Knob("CDT_CONFIG_PATH", "<package>/tpu_config.json", "network",
          "Overrides the JSON config file location."),
     # --- tunnel ----------------------------------------------------------
@@ -438,8 +336,6 @@ KNOBS: tuple[Knob, ...] = (
          "Path to the cloudflared binary for master tunnels."),
     Knob("CDT_TUNNEL_AUTODOWNLOAD", "unset", "tunnel",
          "`1` permits downloading cloudflared when no binary is found."),
-    Knob("CDT_TUNNEL_START_TIMEOUT", "30.0", "tunnel",
-         "Seconds to wait for the tunnel URL before giving up."),
     # --- models ----------------------------------------------------------
     Knob("CDT_CHECKPOINT_DIR", "unset", "models",
          "Root directory (or direct file path) for model checkpoints "
@@ -457,8 +353,6 @@ KNOBS: tuple[Knob, ...] = (
     # --- ops -------------------------------------------------------------
     Knob("CDT_FLASH", "unset", "ops",
          "`0` force-disables the Pallas flash-attention kernel."),
-    Knob("CDT_BLEND", "unset", "ops",
-         "`segment` selects segment-sum canvas blending for large grids."),
     Knob("CDT_DEVICE_CANVAS", "0", "ops",
          "`1` composites master-local tiles on-device (ops/tiles."
          "DeviceCanvas): one composited d2h per flush instead of a "

@@ -28,7 +28,7 @@ from .logging import debug_log
 def parse_master_urls(raw) -> list[str]:
     """One URL or a comma-separated failover list ('active,standby').
     Shared by the worker client (rotates on consecutive failures,
-    CDT_FAILOVER_AFTER) and the standby controller (rotates its
+    FAILOVER_AFTER_ERRORS) and the standby controller (rotates its
     replication stream) so both sides agree on list semantics."""
     if isinstance(raw, str):
         urls = [u.strip().rstrip("/") for u in raw.split(",")]

@@ -40,8 +40,8 @@ def configure_compile_cache() -> str:
     JAX_COMPILATION_CACHE_DIR is set jax has already read it and no
     directory is set in code; otherwise the cache goes to the fixed
     in-checkout path (utils/constants.default_compile_cache_dir).
-    Master, managed workers, bench.py and chip_smoke.py all come
-    through here. Must run before the first jit compile. Returns the
+    Master and managed workers (and so chip_smoke.py and benchmark/run.py,
+    which start them) all come through here. Must run before the first jit compile. Returns the
     directory in use.
 
     Thresholds are zeroed so even small/fast programs cache — the

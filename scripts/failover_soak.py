@@ -18,10 +18,10 @@ report as an artifact):
    the zombie's journal append raises, the promoted store rejects
    stale-epoch RPCs, and neither journals a single record.
 
-2. **grant A/B smoke** — bench's push-vs-poll grant dispatch
-   measurement over the real HTTP surface (wave-released grants): push
-   mode must land a lower mean grant RTT and fewer idle poll requests
-   than pull mode.
+2. **grant A/B smoke** — the push-vs-poll grant dispatch measurement
+   (`measure_grant_ab`) over the real HTTP surface (wave-released
+   grants): push mode must land a lower mean grant RTT and fewer idle
+   poll requests than pull mode.
 
     python scripts/failover_soak.py [--out failover_soak.json]
         [--cycles 6] [--skip-grant-ab]
@@ -133,12 +133,177 @@ def run_failover_cycles(cycles: int) -> dict:
     }
 
 
-def run_grant_ab() -> dict:
-    import bench
+def measure_grant_ab(
+    waves: int = 6,
+    wave_tiles: int = 2,
+    gap_s: float = 0.6,
+    poll_s: float = 0.1,
+) -> dict:
+    """Push-vs-poll grant dispatch A/B over the REAL HTTP surface
+    (needs no accelerator). One mode = one DistributedServer
+    on a loopback port with a tile job whose grants are released in
+    timed waves (the requeue/speculation shape that refills a pending
+    queue mid-job):
 
-    ab = bench._measure_grant_ab()
-    if ab is None:
-        return {"ok": False, "error": "grant A/B did not produce a result"}
+    - **pull** — the classic protocol: the client re-polls
+      request_image, each empty answer held QUEUE_POLL_INTERVAL
+      server-side then paced poll_s client-side, so a wave landing
+      between polls waits out the quantization;
+    - **push** — the client parks on the /distributed/events WebSocket
+      and pulls the instant a grant_available frame lands (push carries
+      availability, never assignment — the pull RPC still transfers the
+      grant, so placement sizing and fencing are identical).
+
+    Grant RTT = release instant → client holds the tile. Idle polls =
+    request_image answers that carried no work. Host-clock readings
+    over loopback on whatever platform runs the soak: a protocol
+    comparison, not a device metric."""
+    import asyncio
+    import math
+    import socket
+    import statistics
+
+    import aiohttp
+
+    from comfyui_distributed_tpu.api.server import DistributedServer
+
+    total = waves * wave_tiles
+    job_id = "grant-ab"
+
+    async def run_mode(push: bool) -> dict:
+        with socket.socket() as sock:
+            sock.bind(("127.0.0.1", 0))
+            port = sock.getsockname()[1]
+        server = DistributedServer(port=port, is_worker=False)
+        await server.start()
+        stats = {"rtts": [], "idle_polls": 0, "requests": 0}
+        try:
+            store = server.job_store
+            # the A/B flips the push publisher directly (start() wires
+            # it from CDT_PUSH_GRANTS; both arms must run in-process)
+            store.grant_notifier = (
+                server.scheduler.placement.notify_grants if push else None
+            )
+            await store.init_tile_job(job_id, list(range(total)))
+            claimed = []
+            for _ in range(total):
+                tid = await store.pull_task(job_id, "holder", timeout=0.05)
+                if tid is not None:
+                    claimed.append(tid)
+            release_at: dict[int, float] = {}
+
+            async def producer():
+                for wave in range(waves):
+                    await asyncio.sleep(gap_s)
+                    batch = claimed[wave * wave_tiles : (wave + 1) * wave_tiles]
+                    now = time.perf_counter()
+                    for tid in batch:
+                        release_at[tid] = now
+                    await store.release_tasks(job_id, "holder", batch)
+
+            url = f"http://127.0.0.1:{port}/distributed/request_image"
+
+            async def pull_once(session) -> int | None:
+                async with session.post(
+                    url, json={"job_id": job_id, "worker_id": "ab-worker"}
+                ) as resp:
+                    out = await resp.json()
+                stats["requests"] += 1
+                tid = out.get("tile_idx")
+                if tid is None:
+                    stats["idle_polls"] += 1
+                    return None
+                stats["rtts"].append(time.perf_counter() - release_at[int(tid)])
+                return int(tid)
+
+            async def pull_client(session):
+                got = 0
+                while got < total:
+                    tid = await pull_once(session)
+                    if tid is None:
+                        await asyncio.sleep(poll_s)
+                    else:
+                        got += 1
+
+            async def push_client(session):
+                got = 0
+                ws_url = (
+                    f"http://127.0.0.1:{port}/distributed/events"
+                    "?types=grant_available"
+                )
+                async with session.ws_connect(ws_url) as ws:
+                    while got < total:
+                        msg = await asyncio.wait_for(ws.receive(), timeout=15)
+                        if msg.type != aiohttp.WSMsgType.TEXT:
+                            break
+                        if json.loads(msg.data).get("type") != "grant_available":
+                            continue  # hello frame
+                        # drain everything the push announced, then
+                        # park on the socket again (ONE empty pull ends
+                        # the drain — that is push mode's whole idle
+                        # request budget)
+                        while got < total:
+                            tid = await pull_once(session)
+                            if tid is None:
+                                break
+                            got += 1
+
+            producer_task = asyncio.create_task(producer())
+            async with aiohttp.ClientSession() as session:
+                await asyncio.wait_for(
+                    (push_client if push else pull_client)(session),
+                    timeout=waves * gap_s + 30,
+                )
+            await producer_task
+            await store.cleanup_tile_job(job_id)
+        finally:
+            await server.stop()
+        rtts = stats["rtts"]
+        return {
+            "grant_rtt_ms_mean": round(1e3 * statistics.fmean(rtts), 2),
+            "grant_rtt_ms_p95": round(
+                1e3 * sorted(rtts)[max(0, math.ceil(len(rtts) * 0.95) - 1)], 2
+            ),
+            "grants": len(rtts),
+            "idle_polls": stats["idle_polls"],
+            "requests": stats["requests"],
+        }
+
+    async def run_both() -> dict:
+        pull = await run_mode(push=False)
+        push = await run_mode(push=True)
+        return {
+            "pull": pull,
+            "push": push,
+            "rtt_speedup": round(
+                pull["grant_rtt_ms_mean"] / max(push["grant_rtt_ms_mean"], 1e-6),
+                2,
+            ),
+            "idle_poll_ratio": round(
+                pull["idle_polls"] / max(push["idle_polls"], 1), 2
+            ),
+            "waves": waves,
+            "wave_tiles": wave_tiles,
+            "gap_s": gap_s,
+            "poll_s": poll_s,
+        }
+
+    previous_watchdog = os.environ.get("CDT_WATCHDOG")
+    os.environ["CDT_WATCHDOG"] = "0"  # no speculation over the held grants
+    try:
+        return asyncio.run(run_both())
+    finally:
+        if previous_watchdog is None:
+            os.environ.pop("CDT_WATCHDOG", None)
+        else:
+            os.environ["CDT_WATCHDOG"] = previous_watchdog
+
+
+def run_grant_ab() -> dict:
+    try:
+        ab = measure_grant_ab()
+    except Exception as exc:  # noqa: BLE001 - reported as a failed phase
+        return {"ok": False, "error": f"{type(exc).__name__}: {exc}"}
     ok = (
         ab["push"]["grant_rtt_ms_mean"] < ab["pull"]["grant_rtt_ms_mean"]
         and ab["push"]["idle_polls"] <= ab["pull"]["idle_polls"]

@@ -12,8 +12,8 @@ as an artifact):
    refund accounting — zero leaked in-flight assignments the instant
    the cancel returns, (c) round-trip the journal (terminal drained
    state at cancel time, replica parity, idempotent replay), and (d)
-   report the cancel→refund latency (the reclaim-speed number bench
-   stamps as `lifecycle.cancel_latency_ms`).
+   report the cancel→refund latency (`cancel_latency_ms`, the
+   reclaim-speed number).
 
 2. **poison tile** — one injected payload that crashes three
    consecutive workers (each crash opening that worker's breaker at

@@ -13,7 +13,7 @@ in span-clock order, and which tiles are missing stages).
 `--compare OLD.jsonl` turns the report into a regression gate: the
 per-stage p95 of the new trace is checked against the old one and the
 process exits 3 when any shared stage regressed by more than
-`--regress-pct` percent (default 25) — the bench/CI hook for "did this
+`--regress-pct` percent (default 25) — the CI hook for "did this
 PR make a stage slower".
 
 Stdlib only; importable (tests call `build_report` / `tile_lifecycle`

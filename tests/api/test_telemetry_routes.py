@@ -101,7 +101,7 @@ def test_metrics_endpoint_serves_prometheus_text(server):
     # per-worker pull→submit latency histogram (watchdog signal)
     assert 'cdt_worker_tile_seconds_count{worker_id="w1"} 1' in body
     # elastic tile-pipeline instruments are declared on the very first
-    # scrape (CI bench smoke asserts on them before any tile job runs)
+    # scrape (a dashboard reads them before any tile job runs)
     assert "# TYPE cdt_pipeline_batches_total counter" in body
     assert "# TYPE cdt_pipeline_inflight gauge" in body
     assert "# TYPE cdt_pipeline_padded_tiles_total counter" in body

@@ -42,7 +42,6 @@ def test_pipelines_match_goldens():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("CDT_TILE_BATCH", None)
-    env.pop("CDT_BLEND", None)
     proc = subprocess.run(
         [sys.executable, _SCRIPT, "--check"],
         capture_output=True, text=True, timeout=1800, cwd=_REPO, env=env,

@@ -25,7 +25,6 @@ def test_quick_goldens_match():
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("CDT_TILE_BATCH", None)
-    env.pop("CDT_BLEND", None)
     proc = subprocess.run(
         [sys.executable, _SCRIPT, "--check", "--quick"],
         capture_output=True, text=True, timeout=600, cwd=_REPO, env=env,

@@ -105,15 +105,3 @@ def test_v_parameterization_txt2img_runs():
     assert img.shape == (1, 32, 32, 3)
     assert np.isfinite(img).all()
     assert (img >= 0).all() and (img <= 1).all()
-
-
-def test_txt2img_flops_composition():
-    """txt2img MFU numerator: scan-free composition, step-monotonic,
-    heun costs its correction evals."""
-    bundle = _bundle()
-    f2 = pl.txt2img_flops(bundle, height=32, width=32, steps=2)
-    assert f2 is not None and f2 > 0
-    f4 = pl.txt2img_flops(bundle, height=32, width=32, steps=4)
-    assert f4 > f2
-    f2_heun = pl.txt2img_flops(bundle, height=32, width=32, steps=2, sampler="heun")
-    assert f2_heun > f2

@@ -78,14 +78,3 @@ def test_multihost_noop_without_config(monkeypatch):
         monkeypatch.delenv(var, raising=False)
     assert multihost.maybe_init_multihost() is False
     assert multihost.is_multihost() is False
-
-
-def test_t2v_flops_composition():
-    """video MFU numerator: scan-free composition, step-monotonic."""
-    from comfyui_distributed_tpu.models import video_pipeline as vp
-
-    bundle = vp.load_video_pipeline("tiny-dit", vae_name="tiny-video-vae-3d")
-    f2 = vp.t2v_flops(bundle, frames=5, height=32, width=32, steps=2)
-    assert f2 is not None and f2 > 0
-    f4 = vp.t2v_flops(bundle, frames=5, height=32, width=32, steps=4)
-    assert f4 > f2

@@ -146,23 +146,3 @@ def test_host_canvas_matches_jax_canvas():
     np.testing.assert_allclose(
         np.asarray(jc.result()), np.asarray(hc.result()), atol=1e-6
     )
-
-
-def test_blend_segment_matches_scan():
-    """The segment-sum (scatter) blend must equal the sequential-scan
-    blend on the same tiles."""
-    import jax
-    import numpy as np
-
-    from comfyui_distributed_tpu.ops import tiles as tile_ops
-
-    for hw, tile, pad in ((96, 48, 8), (80, 32, 8)):
-        grid = tile_ops.calculate_tiles(hw, hw, tile, pad)
-        assert grid.num_tiles >= 4
-        tiles = jax.random.uniform(
-            jax.random.key(3),
-            (grid.num_tiles, 2, grid.padded_h, grid.padded_w, 3),
-        )
-        a = tile_ops._blend_tiles_segment(tiles, grid)
-        b = tile_ops._blend_tiles_scan(tiles, grid)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)

@@ -69,31 +69,6 @@ def test_prep_ref_latents_alignment():
     )
 
 
-def test_flops_estimate_composition(bundle):
-    """MFU-numerator invariants. XLA cost analysis counts a lax.scan
-    body once, so the estimate must be composed from scan-free parts:
-    grouping-invariant, step-monotonic, and scaled by the mesh tier's
-    wrap-around tile padding."""
-    img = _image()
-    pos = pl.encode_text(bundle, ["p"])
-    neg = pl.encode_text(bundle, [""])
-    kwargs = dict(upscale_by=2.0, tile=64, padding=16, denoise=0.4)
-    f2 = up._jitted_for_flops(bundle, img, pos, neg, mesh=None, steps=2, **kwargs)
-    assert f2 is not None and f2 > 0
-    # tile_batch grouping changes dispatch, not work
-    f2_k3 = up._jitted_for_flops(
-        bundle, img, pos, neg, mesh=None, steps=2, tile_batch=3, **kwargs
-    )
-    assert f2_k3 == f2
-    # more sampler steps -> strictly more FLOPs
-    f4 = up._jitted_for_flops(bundle, img, pos, neg, mesh=None, steps=4, **kwargs)
-    assert f4 > f2
-    # 4 tiles wrap-padded onto 8 chips execute 8 tile programs
-    mesh = build_mesh({"data": 8})
-    f_mesh = up._jitted_for_flops(bundle, img, pos, neg, mesh=mesh, steps=2, **kwargs)
-    assert f_mesh == pytest.approx(2 * f2)
-
-
 def test_mesh_matches_single(bundle):
     """Tile sharding over 8 chips must be numerically equivalent to the
     local scan — same folded per-tile keys, same blend."""

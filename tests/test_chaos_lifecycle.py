@@ -58,7 +58,7 @@ def test_cancel_round_trips_journal_and_replica(tmp_path):
     assert result.journal_jobs_after == {}
     assert result.replica_jobs_after == {}
     assert result.idempotent_replay
-    # reclaim speed is measured (the bench stamps this number)
+    # reclaim speed is measured
     assert result.cancel_latency_ms > 0
 
 

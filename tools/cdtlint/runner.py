@@ -17,7 +17,6 @@ from .registry import all_checkers
 DEFAULT_SCAN_PATHS = (
     "comfyui_distributed_tpu",
     "scripts",
-    "bench.py",
     "chip_smoke.py",
     "__graft_entry__.py",
 )
