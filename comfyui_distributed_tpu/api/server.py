@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
 import os
 import queue as thread_queue
 import threading
@@ -56,6 +57,48 @@ class PromptJob:
         # the prompt_queue.wait span: opened at enqueue, closed by the
         # executor thread when it takes the job
         self.queue_span: Any = None
+        # the execute_prompt span: opened by the executor thread when it
+        # takes the job, ended with `done` by whichever thread finishes
+        # the job's last piece of work
+        self.execute_span: Any = None
+        # pieces of work `done` waits for: the graph walk, and each save
+        # handed to the saver thread
+        self.open_work = 0
+
+
+class SaveThread:
+    """Runs the work handed to it one piece at a time, in hand-off
+    order, on a thread of its own: the PNG encode and file write of
+    prompt N while the executor thread walks prompt N+1. At most one
+    piece waits beside the one running; `submit` blocks beyond that, so
+    a burst holds two images on the host, not a queue of them."""
+
+    def __init__(self) -> None:
+        self._queue: "thread_queue.Queue[Any]" = thread_queue.Queue(maxsize=1)
+        self._thread: Optional[threading.Thread] = None
+
+    def submit(self, work: Any) -> None:
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._loop, name="cdt-saver", daemon=True
+            )
+            self._thread.start()
+        self._queue.put(work)
+
+    def _loop(self) -> None:
+        while True:
+            work = self._queue.get()
+            if work is None:
+                return
+            work()
+
+    def join(self) -> None:
+        """Run what was submitted to its end and stop the thread. For
+        the thread that submits."""
+        if self._thread is not None:
+            self._queue.put(None)
+            self._thread.join()
+            self._thread = None
 
 
 class DistributedServer:
@@ -324,8 +367,18 @@ class DistributedServer:
         self._prompt_queue: "thread_queue.Queue[Optional[PromptJob]]" = (
             thread_queue.Queue()
         )
+        # set while a prompt is taken and not done (walking its graph, or
+        # its images not yet on disk)
         self._executing = threading.Event()
         self._executor_thread: Optional[threading.Thread] = None
+        self._saver = SaveThread()
+        # under _jobs_lock: prompts taken and not done; prompts ever
+        # taken (a save is overlapped when this moves before it ends);
+        # saves handed to the saver and not yet on disk
+        self._jobs_lock = threading.Lock()
+        self._unfinished = 0
+        self._taken = 0
+        self.saves_pending = 0
         self._history: dict[str, PromptJob] = {}
         self._interrupt = threading.Event()
         self.execution_context = ExecutionContext(mesh=mesh)
@@ -383,7 +436,9 @@ class DistributedServer:
 
     @property
     def queue_remaining(self) -> int:
-        return self._prompt_queue.qsize() + (1 if self._executing.is_set() else 0)
+        """Prompts queued, executing, or executed with an image not yet
+        on disk."""
+        return self._prompt_queue.qsize() + self._unfinished
 
     async def handle_get_prompt(self, request: web.Request) -> web.Response:
         # ComfyUI-compatible probe shape (reference utils/network.py:108-136
@@ -474,15 +529,25 @@ class DistributedServer:
     # --- executor thread --------------------------------------------------
 
     def _executor_loop(self) -> None:
+        """Walk one prompt's graph at a time on this thread. A job is
+        done when its walk and every save it handed off have ended.
+
+        The walk stays in this function's frame, with no closure in
+        it: the traced programs record the stack they were built
+        under, and a frame more between here and `execute` cost every
+        cell's loader 15-19 % (`lower_s` 15.3 -> 23.5 s on SD1.5;
+        PERF.md, PR 31)."""
+        from ..telemetry import get_tracer
+
         while True:
             job = self._prompt_queue.get()
             if job is None:
+                # no save outlives the loop
+                self._saver.join()
                 return
-            from ..telemetry import get_tracer
-
             tracer = get_tracer()
             tracer.end_span(job.queue_span)
-            self._executing.set()
+            self._job_taken(job)
             self._interrupt.clear()
             ctx = ExecutionContext(
                 mesh=self.mesh,
@@ -491,31 +556,94 @@ class DistributedServer:
                 interrupt_event=self._interrupt,
                 pipelines=self.execution_context.pipelines,
                 extras=self.execution_context.extras,  # node cache persists
+                defer=functools.partial(self._defer, job),
             )
-            # The compute thread joins the prompt's trace so every span
-            # opened during execution (tile pulls, sampler stages)
-            # attaches to the distributed execution's tree.
-            token = tracer.activate(job.trace_id)
+            debug_log(f"executing prompt {job.prompt_id}")
+            # A manual pair: the saver thread may be the one to end it.
+            # From pick-up to the job's last byte on disk.
+            span = job.execute_span = tracer.start_span(
+                "execute_prompt",
+                trace_id=job.trace_id,
+                attrs={
+                    "prompt_id": job.prompt_id,
+                    "role": "worker" if self.is_worker else "master",
+                },
+            )
+            # The compute thread joins the prompt's trace under that
+            # span, so every span opened during execution (nodes, tile
+            # pulls, sampler stages) attaches to the execution's tree.
+            token = tracer.activate(job.trace_id, span.span_id)
             try:
-                debug_log(f"executing prompt {job.prompt_id}")
-                with tracer.span(
-                    "execute_prompt",
-                    prompt_id=job.prompt_id,
-                    role="worker" if self.is_worker else "master",
-                ) as span:
-                    executor = GraphExecutor(ctx)
-                    job.outputs = executor.execute(job.prompt)
-                    job.timings = executor.last_timings
-                    span.attrs["nodes_run"] = executor.nodes_run
-                    span.attrs["nodes_cached"] = executor.nodes_cached
+                executor = GraphExecutor(ctx)
+                job.outputs = executor.execute(job.prompt)
+                job.timings = executor.last_timings
+                span.attrs["nodes_run"] = executor.nodes_run
+                span.attrs["nodes_cached"] = executor.nodes_cached
             except Exception as exc:  # noqa: BLE001 - reported to client
-                job.error = f"{type(exc).__name__}: {exc}"
-                log(f"prompt {job.prompt_id} failed: {job.error}")
+                self._fail(job, exc)
             finally:
                 tracer.deactivate(token)
-                self._export_trace(job.trace_id)
+                self._work_ended(job)
+
+    def _job_taken(self, job: PromptJob) -> None:
+        with self._jobs_lock:
+            job.open_work += 1
+            self._unfinished += 1
+            self._taken += 1
+            self._executing.set()
+
+    def _defer(self, job: PromptJob, work: Any) -> None:
+        """`ExecutionContext.defer` of a served prompt, called on the
+        executor thread from inside a node: run `work(overlapped)` on
+        the saver thread, in the job's trace under the span active
+        here. Blocks while a save runs and another waits."""
+        from ..telemetry import get_tracer
+
+        tracer = get_tracer()
+        parent_id = tracer.current_span_id()
+        with self._jobs_lock:
+            job.open_work += 1
+            self.saves_pending += 1
+            taken = self._taken
+
+        def run() -> None:
+            token = tracer.activate(job.trace_id, parent_id)
+            try:
+                work(lambda: self._taken > taken)
+            except Exception as exc:  # noqa: BLE001 - reported to client
+                self._fail(job, exc)
+            finally:
+                tracer.deactivate(token)
+                with self._jobs_lock:
+                    self.saves_pending -= 1
+                self._work_ended(job)
+
+        self._saver.submit(run)
+
+    def _fail(self, job: PromptJob, exc: Exception) -> None:
+        if job.error is None:
+            job.error = f"{type(exc).__name__}: {exc}"
+        log(f"prompt {job.prompt_id} failed: {type(exc).__name__}: {exc}")
+
+    def _work_ended(self, job: PromptJob) -> None:
+        """One piece of the job's work ended, on either thread; the last
+        one ends `execute_prompt` and sets `done`."""
+        from ..telemetry import get_tracer
+
+        with self._jobs_lock:
+            job.open_work -= 1
+            if job.open_work:
+                return
+        span = job.execute_span
+        if job.error is not None:
+            span.attrs.setdefault("error", job.error)
+        get_tracer().end_span(span, status="ok" if job.error is None else "error")
+        self._export_trace(job.trace_id)
+        with self._jobs_lock:
+            self._unfinished -= 1
+            if not self._unfinished:
                 self._executing.clear()
-                job.done.set()
+        job.done.set()
 
     def _export_trace(self, trace_id: str) -> None:
         """Write the trace's spans as JSONL when CDT_TRACE_EXPORT_DIR is

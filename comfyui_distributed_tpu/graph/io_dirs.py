@@ -8,6 +8,7 @@ sane defaults under the repo/package root, overridable by env.
 from __future__ import annotations
 
 import os
+import threading
 
 from ..utils.exceptions import DistributedError
 
@@ -58,4 +59,22 @@ def next_counter(out_dir: str, prefix: str, ext: str) -> int:
         stem = f[len(prefix) + 1 : -len(suffix)]
         if stem.isdigit():
             start = max(start, int(stem) + 1)
+    return start
+
+
+# (directory, prefix, ext) -> first counter not yet handed out in this
+# process. A file named by `reserve_counter` may be written later, on
+# another thread, so the directory scan alone would name it twice.
+_reserved: dict[tuple[str, str, str], int] = {}
+_reserved_lock = threading.Lock()
+
+
+def reserve_counter(out_dir: str, prefix: str, ext: str, count: int = 1) -> int:
+    """The first of `count` counters that no file on disk and no
+    earlier call in this process has: max(`next_counter`, last
+    reserved + 1)."""
+    key = (os.path.abspath(out_dir), prefix, ext)
+    with _reserved_lock:
+        start = max(next_counter(out_dir, prefix, ext), _reserved.get(key, 0))
+        _reserved[key] = start + count
     return start

@@ -1329,6 +1329,12 @@ class LoadImage:
 
 @register_node
 class SaveImage:
+    """PNG per image as <prefix>_NNNNN.png, the counter reserved when the
+    node runs; a served prompt is done when its files are written.
+
+    In a served prompt encode and write run on the server's saver
+    thread while the executor thread walks the next prompt."""
+
     @classmethod
     def INPUT_TYPES(cls):
         return {
@@ -1343,35 +1349,51 @@ class SaveImage:
     OUTPUT_NODE = True
 
     def save(self, images, filename_prefix="output", context=None):
-        from .io_dirs import get_output_dir
+        from .io_dirs import get_output_dir, reserve_counter
 
         out_dir = get_output_dir(context)
         os.makedirs(out_dir, exist_ok=True)
-        # resume numbering after existing files so runs never clobber
-        # each other (ComfyUI counter-scan behavior)
-        from .io_dirs import next_counter
-
         from ..telemetry import get_tracer
 
-        tracer = get_tracer()
-        start = next_counter(out_dir, filename_prefix, "png")
-        saved = []
+        # resume numbering after existing files so runs never clobber
+        # each other (ComfyUI counter-scan behavior); reserved, because
+        # an earlier prompt's file may not be written yet
+        start = reserve_counter(out_dir, filename_prefix, "png", len(images))
         # the executor thread parks here until the device has finished
         # everything the images depend on
-        with tracer.span("device.wait") as wait:
+        with get_tracer().span("device.wait") as wait:
             arr = img_utils.ensure_numpy(images)
             wait.attrs["bytes"] = int(arr.nbytes)
-        for i in range(arr.shape[0]):
-            name = f"{filename_prefix}_{start + i:05d}.png"
-            path = os.path.join(out_dir, name)
-            with tracer.span("png.encode") as encode:
-                png = img_utils.encode_png(arr[i], compress_level=4)
-                encode.attrs["bytes"] = len(png)
-            with tracer.span("file.write", bytes=len(png)):
-                with open(path, "wb") as fh:
-                    fh.write(png)
-            saved.append(name)
+        saved = [f"{filename_prefix}_{start + i:05d}.png" for i in range(arr.shape[0])]
+        write = partial(_write_pngs, arr, [os.path.join(out_dir, n) for n in saved])
+        # A served request hands encode and write to the server's saver
+        # thread, and the executor thread goes on to the next prompt;
+        # the prompt is done when they have run. Anywhere else: here.
+        defer = getattr(context, "defer", None)
+        if defer is None:
+            write()
+        else:
+            defer(write)
         return ({"ui": {"images": saved}, "images": images},)
+
+
+def _write_pngs(arr, paths, overlapped=lambda: False) -> None:
+    """Encode and write one PNG per image of `arr`. `overlapped()` says
+    whether the executor has taken another prompt since the hand-off."""
+    from ..telemetry import get_tracer
+    from ..telemetry.instruments import saves_total
+
+    tracer = get_tracer()
+    for image, path in zip(arr, paths):
+        with tracer.span("png.encode") as encode:
+            png = img_utils.encode_png(image, compress_level=4)
+            encode.attrs["bytes"] = len(png)
+        with tracer.span("file.write", bytes=len(png)):
+            with open(path, "wb") as fh:
+                fh.write(png)
+        hidden = int(overlapped())
+        encode.attrs["overlapped"] = hidden
+        saves_total().inc(overlapped=str(hidden))
 
 
 @register_node

@@ -182,7 +182,7 @@ class HTTPWorkClient:
         # FLEET_SNAPSHOT_SECONDS (telemetry/fleet.local_snapshot).
         # <= 0 disables the piggyback entirely.
         self._telemetry_interval = FLEET_SNAPSHOT_SECONDS
-        self._telemetry_last = 0.0
+        self._telemetry_last = float("-inf")  # the first RPC carries one
 
     @property
     def master_url(self) -> str:

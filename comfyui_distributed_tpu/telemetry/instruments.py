@@ -847,8 +847,25 @@ def pipeline_padded_tiles_total() -> Counter:
 def prompt_queue_depth() -> Gauge:
     return get_metrics_registry().gauge(
         "cdt_prompt_queue_depth",
-        "Prompts queued (including the one executing) per server",
+        "Prompts queued, executing or being saved, per server",
         ("server",),
+    )
+
+
+def saves_pending() -> Gauge:
+    return get_metrics_registry().gauge(
+        "cdt_saves_pending",
+        "Image saves handed to the saver thread and not yet on disk, per server",
+        ("server",),
+    )
+
+
+def saves_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_saves_total",
+        "Images saved; overlapped=1 when the executor had taken another "
+        "prompt before the file was written",
+        ("overlapped",),
     )
 
 
@@ -886,6 +903,7 @@ def collector_jobs_active() -> Gauge:
 
 _LIVE_GAUGES = (
     prompt_queue_depth,
+    saves_pending,
     tile_jobs_active,
     tile_queue_depth,
     tiles_in_flight,
@@ -991,6 +1009,7 @@ def bind_server_collectors(server) -> Callable[[], None]:
 
     def collect() -> None:
         prompt_queue_depth().set(server.queue_remaining, server=label)
+        saves_pending().set(getattr(server, "saves_pending", 0), server=label)
         stats = server.job_store.stats_unlocked()
         tile_jobs_active().set(stats["tile_jobs"], server=label)
         tile_queue_depth().set(stats["queue_depth"], server=label)

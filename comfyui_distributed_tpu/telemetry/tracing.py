@@ -6,8 +6,9 @@ lines, one execution produces a TREE of spans that
 request's tree is `sched.wait`, `queue_orchestration`,
 `prompt_queue.wait`, `execute_prompt` and under it one
 `node.<class_type>` per node that ran, with `device.wait`,
-`png.encode` and `file.write` where the executor thread blocks or
-saves; the elastic tile tier adds `dispatch` and `tile.<stage>`
+`png.encode` and `file.write` where the executor thread blocks and the
+saver thread saves; the elastic tile tier adds `dispatch` and
+`tile.<stage>`
 (docs/observability.md has the whole vocabulary).
 
 Design:
@@ -208,11 +209,14 @@ class Tracer:
 
     # --- context ----------------------------------------------------------
 
-    def activate(self, trace_id: str) -> contextvars.Token:
+    def activate(
+        self, trace_id: str, span_id: Optional[str] = None
+    ) -> contextvars.Token:
         """Join `trace_id` in the current context (thread); new spans
-        with no active parent attach to the trace's root. Returns a
-        token for `deactivate`."""
-        return _current.set((trace_id, None))
+        with no active parent attach to `span_id` (a span another
+        thread opened, or one started with `start_span`), else to the
+        trace's root. Returns a token for `deactivate`."""
+        return _current.set((trace_id, span_id))
 
     def deactivate(self, token: contextvars.Token) -> None:
         _current.reset(token)

@@ -1,0 +1,451 @@
+"""The saver thread: a served prompt's PNG encode and file write run
+while the executor thread walks the next prompt. `done` means on disk,
+names are reserved at hand-off, a saver-side failure is the job's error,
+at most one save waits beside the one running, and nothing that waits
+for the server (the loop's sentinel, `stop`, `drain_worker`) leaves a
+save unwritten. Encodes are held on events, never on sleeps."""
+
+import asyncio
+import os
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from comfyui_distributed_tpu.api.server import DistributedServer, SaveThread
+from comfyui_distributed_tpu.graph import io_dirs
+from comfyui_distributed_tpu.graph.executor import ExecutionContext, GraphExecutor
+from comfyui_distributed_tpu.graph.registry import NODE_REGISTRY
+from comfyui_distributed_tpu.resilience.chaos import FakeClock
+from comfyui_distributed_tpu.telemetry import Tracer, set_tracer
+from comfyui_distributed_tpu.telemetry.instruments import saves_total
+from comfyui_distributed_tpu.telemetry.metrics import get_metrics_registry
+from comfyui_distributed_tpu.utils import image as img_utils
+from comfyui_distributed_tpu.workers.startup import drain_worker
+
+encode_png = img_utils.encode_png
+
+
+class HostImage:
+    """A host image of one value, so nothing is compiled."""
+
+    @classmethod
+    def INPUT_TYPES(cls):
+        return {"required": {"value": ("FLOAT", {"default": 0.5})}}
+
+    RETURN_TYPES = ("IMAGE",)
+    FUNCTION = "make"
+
+    def make(self, value):
+        return (np.full((1, 8, 8, 3), float(value), np.float32),)
+
+
+def graph(value, prefix="saved"):
+    return {
+        "1": {"class_type": "HostImage", "inputs": {"value": value}},
+        "2": {"class_type": "SaveImage",
+              "inputs": {"images": ["1", 0], "filename_prefix": prefix}},
+    }
+
+
+def expected_png(value):
+    return encode_png(np.full((8, 8, 3), float(value), np.float32), compress_level=4)
+
+
+def wait_until(condition, what, seconds=10.0):
+    deadline = time.monotonic() + seconds
+    while not condition():
+        assert time.monotonic() < deadline, f"timed out waiting until {what}"
+        time.sleep(0.001)
+
+
+@pytest.fixture()
+def tracer():
+    ticking = Tracer(clock=FakeClock(step=1.0))
+    set_tracer(ticking)
+    return ticking
+
+
+@pytest.fixture()
+def out_dir(tmp_path, monkeypatch):
+    monkeypatch.setitem(NODE_REGISTRY, "HostImage", HostImage)
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path / "out"))
+    return tmp_path / "out"
+
+
+class Encoder:
+    """`encode_png` with a gate: while `hold` is clear an encode parks,
+    having said so on `entered`; `fail_next` makes one raise."""
+
+    def __init__(self):
+        self.hold = threading.Event()
+        self.hold.set()
+        self.entered = threading.Event()
+        self.fail_next = False
+        self.threads = []
+
+    def __call__(self, image, compress_level=0):
+        self.threads.append(threading.current_thread().name)
+        self.entered.set()
+        assert self.hold.wait(10), "the test never released the encode"
+        if self.fail_next:
+            self.fail_next = False
+            raise OSError("No space left on device")
+        return encode_png(image, compress_level)
+
+
+@pytest.fixture()
+def encoder(monkeypatch):
+    gate = Encoder()
+    monkeypatch.setattr(img_utils, "encode_png", gate)
+    return gate
+
+
+@pytest.fixture()
+def server(tmp_config_path, out_dir, tracer, encoder):
+    """A server whose executor loop runs on a thread, as `start` runs it."""
+    srv = DistributedServer(port=0, is_worker=True)
+    srv._executor_thread = threading.Thread(target=srv._executor_loop, daemon=True)
+    srv._executor_thread.start()
+    yield srv
+    encoder.hold.set()
+    if srv._executor_thread.is_alive():
+        srv._prompt_queue.put(None)
+        srv._executor_thread.join(10)
+
+
+def spans_named(tracer, trace_id, name):
+    return [s for s in tracer.spans(trace_id) if s["name"] == name]
+
+
+def walked(tracer, trace_id):
+    """The prompt's graph walk has handed its save off."""
+    done = spans_named(tracer, trace_id, "node.SaveImage")
+    return bool(done) and done[0]["end"] is not None
+
+
+@pytest.fixture()
+def two_in_flight(server, tracer, encoder, out_dir):
+    """p1's encode held on the saver thread, p2 walked behind it."""
+    encoder.hold.clear()
+    first = server.queue_prompt(graph(0.25), "p1")
+    second = server.queue_prompt(graph(0.75), "p2")
+    assert encoder.entered.wait(10)
+    wait_until(lambda: walked(tracer, "p2"), "p2's walk has ended")
+    return first, second
+
+
+def release(encoder, *jobs):
+    encoder.hold.set()
+    for job in jobs:
+        assert job.done.wait(10)
+
+
+def test_the_next_prompts_nodes_run_while_the_save_is_held(
+    two_in_flight, tracer, encoder, out_dir
+):
+    first, second = two_in_flight
+    assert spans_named(tracer, "p1", "png.encode")[0]["end"] is None
+    assert spans_named(tracer, "p2", "node.HostImage")[0]["end"] is not None
+    assert encoder.threads == ["cdt-saver"]
+    release(encoder, first, second)
+    encode = spans_named(tracer, "p1", "png.encode")[0]
+    assert all(
+        node["start"] < encode["end"]
+        for name in ("node.HostImage", "node.SaveImage")
+        for node in spans_named(tracer, "p2", name)
+    )
+    assert encoder.threads == ["cdt-saver", "cdt-saver"]
+
+
+def test_done_is_not_set_until_the_file_exists(two_in_flight, tracer, encoder, out_dir):
+    first, second = two_in_flight
+    assert not first.done.is_set() and not second.done.is_set()
+    assert spans_named(tracer, "p1", "execute_prompt")[0]["end"] is None
+    assert not os.path.exists(out_dir / "saved_00000.png")
+    # the walk's results are there already; only `done` waits for the disk
+    assert first.outputs is not None and set(first.timings) == {"1", "2"}
+    release(encoder, first, second)
+    assert os.path.exists(out_dir / "saved_00000.png")
+    execute = spans_named(tracer, "p1", "execute_prompt")[0]
+    assert execute["end"] >= spans_named(tracer, "p1", "file.write")[0]["end"]
+
+
+def test_two_in_flight_prompts_of_one_prefix_get_distinct_names(
+    two_in_flight, encoder, out_dir
+):
+    first, second = two_in_flight
+    names = [job.outputs["2"][0]["ui"]["images"] for job in (first, second)]
+    assert names == [["saved_00000.png"], ["saved_00001.png"]]
+    release(encoder, first, second)
+    assert (out_dir / "saved_00000.png").read_bytes() == expected_png(0.25)
+    assert (out_dir / "saved_00001.png").read_bytes() == expected_png(0.75)
+    assert sorted(os.listdir(out_dir)) == ["saved_00000.png", "saved_00001.png"]
+
+
+def test_queue_remaining_counts_a_prompt_whose_save_is_pending(
+    two_in_flight, server, encoder
+):
+    first, second = two_in_flight
+    assert server._prompt_queue.qsize() == 0
+    assert server.queue_remaining == 2 and server.saves_pending == 2
+    assert server._executing.is_set()
+    release(encoder, first, second)
+    wait_until(lambda: server.queue_remaining == 0, "both prompts are done")
+    assert server.saves_pending == 0 and not server._executing.is_set()
+
+
+def test_the_overlap_counter_and_attribute_read_one_and_zero(
+    two_in_flight, tracer, encoder
+):
+    first, second = two_in_flight
+    release(encoder, first, second)
+    # p2 was taken while p1's save was held; nothing was taken during p2's
+    assert spans_named(tracer, "p1", "png.encode")[0]["attrs"]["overlapped"] == 1
+    assert spans_named(tracer, "p2", "png.encode")[0]["attrs"]["overlapped"] == 0
+    assert saves_total().value(overlapped="1") == 1
+    assert saves_total().value(overlapped="0") == 1
+    text = get_metrics_registry().render()
+    assert 'cdt_saves_total{overlapped="1"} 1' in text
+    assert 'cdt_saves_total{overlapped="0"} 1' in text
+
+
+def test_the_pending_gauge_is_in_the_scrape(two_in_flight, server, encoder):
+    from comfyui_distributed_tpu.telemetry import bind_server_collectors
+
+    unbind = bind_server_collectors(server)
+    try:
+        assert 'cdt_saves_pending{server="worker:0"} 2' in get_metrics_registry().render()
+        release(encoder, *two_in_flight)
+        wait_until(lambda: server.saves_pending == 0, "the saves have ended")
+        assert 'cdt_saves_pending{server="worker:0"} 0' in get_metrics_registry().render()
+    finally:
+        unbind()
+
+
+def test_a_third_hand_off_blocks_until_the_running_save_ends(
+    two_in_flight, server, tracer, encoder
+):
+    first, second = two_in_flight
+    third = server.queue_prompt(graph(0.5), "p3")
+    fourth = server.queue_prompt(graph(0.6), "p4")
+    wait_until(lambda: spans_named(tracer, "p3", "device.wait")
+               and spans_named(tracer, "p3", "device.wait")[0]["end"] is not None,
+               "p3 has reached its hand-off")
+    time.sleep(0.005)
+    # one save runs (p1), one waits (p2): the executor thread is parked
+    # inside p3's SaveImage and has not taken p4
+    assert spans_named(tracer, "p3", "node.SaveImage")[0]["end"] is None
+    assert spans_named(tracer, "p4", "prompt_queue.wait")[0]["end"] is None
+    assert server.queue_remaining == 4
+    release(encoder, first, second, third, fourth)
+    ends = [spans_named(tracer, p, "file.write")[0]["end"] for p in ("p1", "p2", "p3", "p4")]
+    assert ends == sorted(ends)
+
+
+def test_a_failed_save_is_the_jobs_error_and_the_next_job_runs(
+    server, tracer, encoder, out_dir
+):
+    encoder.fail_next = True
+    failed = server.queue_prompt(graph(0.25), "p1")
+    fine = server.queue_prompt(graph(0.75), "p2")
+    assert failed.done.wait(10) and fine.done.wait(10)
+    assert failed.error == "OSError: No space left on device"
+    execute = spans_named(tracer, "p1", "execute_prompt")[0]
+    assert execute["status"] == "error" and execute["attrs"]["error"] == failed.error
+    assert spans_named(tracer, "p1", "png.encode")[0]["status"] == "error"
+    assert fine.error is None
+    # the failed job's reserved name is not reused
+    assert sorted(os.listdir(out_dir)) == ["saved_00001.png"]
+    assert (out_dir / "saved_00001.png").read_bytes() == expected_png(0.75)
+
+
+def test_a_failed_walk_still_ends_execute_prompt_with_the_error(server, tracer):
+    broken = {"1": {"class_type": "LoadImage", "inputs": {"image": "/nonexistent.png"}},
+              "2": {"class_type": "SaveImage", "inputs": {"images": ["1", 0]}}}
+    job = server.queue_prompt(broken, "p1")
+    assert job.done.wait(10)
+    assert job.error.startswith("FileNotFoundError")
+    execute = spans_named(tracer, "p1", "execute_prompt")[0]
+    assert execute["status"] == "error" and execute["end"] is not None
+    assert server.queue_remaining == 0
+
+
+def test_the_loop_returning_on_its_sentinel_leaves_no_save_unwritten(
+    server, tracer, encoder, out_dir
+):
+    encoder.hold.clear()
+    jobs = [server.queue_prompt(graph(0.1 * i), f"p{i}") for i in (1, 2)]
+    server._prompt_queue.put(None)
+    assert encoder.entered.wait(10)
+    wait_until(lambda: walked(tracer, "p2"), "p2's walk has ended")
+    assert server._executor_thread.is_alive()  # parked joining the saver
+    encoder.hold.set()
+    server._executor_thread.join(10)
+    assert not server._executor_thread.is_alive()
+    assert all(job.done.is_set() for job in jobs)
+    assert sorted(os.listdir(out_dir)) == ["saved_00000.png", "saved_00001.png"]
+    assert server._saver._thread is None
+
+
+def test_the_loop_run_in_this_thread_saves_before_it_returns(
+    tmp_config_path, out_dir, tracer
+):
+    server = DistributedServer(port=0, is_worker=True)
+    jobs = [server.queue_prompt(graph(0.1 * i), f"p{i}") for i in (1, 2, 3)]
+    server._prompt_queue.put(None)
+    server._executor_loop()
+    assert all(job.done.is_set() and job.error is None for job in jobs)
+    assert len(os.listdir(out_dir)) == 3
+    # and the loop can be entered again: the saver starts anew
+    again = server.queue_prompt(graph(0.9), "p4")
+    server._prompt_queue.put(None)
+    server._executor_loop()
+    assert again.done.is_set() and len(os.listdir(out_dir)) == 4
+
+
+def release_soon(encoder):
+    timer = threading.Timer(0.005, encoder.hold.set)
+    timer.start()
+    return timer
+
+
+def test_stop_waits_for_a_pending_save(two_in_flight, server, encoder, out_dir):
+    first, second = two_in_flight
+    timer = release_soon(encoder)
+    asyncio.run(server.stop())
+    timer.join()
+    assert first.done.is_set() and second.done.is_set()
+    assert not server._executor_thread.is_alive()
+    assert sorted(os.listdir(out_dir)) == ["saved_00000.png", "saved_00001.png"]
+
+
+def test_drain_worker_waits_for_a_pending_save(two_in_flight, server, encoder, out_dir):
+    first, second = two_in_flight
+    timer = release_soon(encoder)
+    assert asyncio.run(drain_worker(server, grace_seconds=10.0)) is True
+    timer.join()
+    assert first.done.is_set() and second.done.is_set()
+    assert first.error is None and second.error is None
+    assert sorted(os.listdir(out_dir)) == ["saved_00000.png", "saved_00001.png"]
+
+
+def test_without_a_server_the_node_saves_inline(out_dir, tracer, encoder):
+    context = ExecutionContext()
+    assert context.defer is None
+    GraphExecutor(context).execute(graph(0.25))
+    assert (out_dir / "saved_00000.png").read_bytes() == expected_png(0.25)
+    assert encoder.threads == [threading.current_thread().name]
+    (encode,) = [s for ids in tracer.trace_ids() for s in spans_named(tracer, ids, "png.encode")]
+    assert encode["attrs"]["overlapped"] == 0
+    assert saves_total().value(overlapped="0") == 1 and saves_total().value(overlapped="1") == 0
+
+
+def test_a_context_with_a_server_but_no_defer_saves_inline(out_dir, tracer, encoder):
+    """What selects the saver is the hand-off the served loop puts in
+    the context, not the presence of a server object."""
+    context = ExecutionContext(server=object())
+    GraphExecutor(context).execute(graph(0.25))
+    assert os.path.exists(out_dir / "saved_00000.png")
+    assert encoder.threads == [threading.current_thread().name]
+
+
+# --- the saver thread alone ---------------------------------------------------
+
+
+def test_save_thread_runs_in_order_and_join_stops_it():
+    saver, ran = SaveThread(), []
+    for i in range(5):
+        saver.submit(lambda i=i: ran.append((i, threading.current_thread().name)))
+    saver.join()
+    assert ran == [(i, "cdt-saver") for i in range(5)]
+    assert saver._thread is None
+    saver.join()  # nothing to join: a no-op
+    saver.submit(lambda: ran.append("again"))
+    saver.join()
+    assert ran[-1] == "again"
+
+
+def test_save_thread_holds_one_running_and_one_waiting():
+    saver, gate, started = SaveThread(), threading.Event(), threading.Event()
+    saver.submit(lambda: (started.set(), gate.wait(10)))
+    assert started.wait(10)
+    saver.submit(lambda: None)  # waits beside the running one
+    third = threading.Thread(target=saver.submit, args=(lambda: None,))
+    third.start()
+    third.join(0.005)
+    assert third.is_alive()  # blocked at the hand-off
+    gate.set()
+    third.join(10)
+    assert not third.is_alive()
+    saver.join()
+
+
+# --- names ---------------------------------------------------------------------
+
+
+def test_reserve_counter_starts_after_the_files_on_disk(tmp_path):
+    (tmp_path / "a_00006.png").write_bytes(b"")
+    assert io_dirs.reserve_counter(str(tmp_path), "a", "png") == 7
+
+
+def test_reserve_counter_never_hands_a_counter_out_twice(tmp_path):
+    first = io_dirs.reserve_counter(str(tmp_path), "a", "png", 3)
+    second = io_dirs.reserve_counter(str(tmp_path), "a", "png")
+    assert (first, second) == (0, 3)
+    assert os.listdir(tmp_path) == []  # reserved, not written
+
+
+def test_reserve_counter_follows_a_file_written_past_its_reservation(tmp_path):
+    assert io_dirs.reserve_counter(str(tmp_path), "a", "png") == 0
+    (tmp_path / "a_00041.png").write_bytes(b"")
+    assert io_dirs.reserve_counter(str(tmp_path), "a", "png") == 42
+
+
+@pytest.mark.parametrize("other", [("b", "png"), ("a", "webp")])
+def test_reservations_are_per_directory_prefix_and_extension(tmp_path, other):
+    assert io_dirs.reserve_counter(str(tmp_path), "a", "png", 5) == 0
+    assert io_dirs.reserve_counter(str(tmp_path), *other) == 0
+    assert io_dirs.reserve_counter(str(tmp_path / ".." / tmp_path.name), "a", "png") == 5
+
+
+def test_next_counter_still_only_scans(tmp_path):
+    """The animated savers' allocation: unchanged by a reservation."""
+    io_dirs.reserve_counter(str(tmp_path), "a", "webp", 4)
+    assert io_dirs.next_counter(str(tmp_path), "a", "webp") == 0
+    (tmp_path / "a_00002.webp").write_bytes(b"")
+    assert io_dirs.next_counter(str(tmp_path), "a", "webp") == 3
+
+
+# --- the tracer's part -----------------------------------------------------------
+
+
+def test_activate_with_a_span_id_parents_another_threads_spans(tracer):
+    parent = tracer.start_span("execute_prompt", trace_id="t")
+    found = {}
+
+    def other_thread():
+        token = tracer.activate("t", parent.span_id)
+        with tracer.span("png.encode") as child:
+            found["parent"] = child.parent_id
+            found["current"] = tracer.current_span_id()
+        found["after"] = tracer.current_span_id()
+        tracer.deactivate(token)
+
+    thread = threading.Thread(target=other_thread)
+    thread.start()
+    thread.join()
+    assert found["parent"] == parent.span_id == found["after"]
+    assert found["current"] != parent.span_id
+    assert tracer.current_span_id() is None  # this thread never joined
+
+
+def test_activate_without_a_span_id_still_falls_back_to_the_root(tracer):
+    root = tracer.start_span("prompt_queue.wait", trace_id="t")
+    tracer.start_span("execute_prompt", trace_id="t")
+    token = tracer.activate("t")
+    with tracer.span("late") as late:
+        pass
+    tracer.deactivate(token)
+    assert late.parent_id == root.span_id

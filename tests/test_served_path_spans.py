@@ -116,15 +116,54 @@ def test_save_image_yields_device_wait_encode_and_write_with_bytes(server, trace
     assert encode["attrs"]["bytes"] == write["attrs"]["bytes"] > 0
 
 
-def test_the_second_queued_prompt_waits_out_the_firsts_execution(server, tracer):
+def test_the_second_queued_prompt_waits_out_the_firsts_graph_walk(server, tracer):
     server.queue_prompt(graph(0.25), "p1")
     server.queue_prompt(graph(0.75), "p2")
     run_queued(server)
     first, second = by_name(tracer, "p1"), by_name(tracer, "p2")
     waited = second["prompt_queue.wait"][0]
     assert waited["attrs"]["depth"] == 1
-    assert waited["duration"] >= first["execute_prompt"][0]["duration"]
-    assert waited["end"] >= first["execute_prompt"][0]["end"]
+    # the executor takes p2 when p1's walk has handed its save off, not
+    # when p1's file is written: that belongs to p1's execute_prompt alone
+    walked = first["node.SaveImage"][0]
+    assert waited["end"] >= walked["end"]
+    assert waited["duration"] >= walked["end"] - first["execute_prompt"][0]["start"]
+    assert first["execute_prompt"][0]["end"] >= first["file.write"][0]["end"]
+
+
+def test_the_save_runs_on_the_saver_thread_below_execute_prompt(server, tracer):
+    """What the benchmark's readers need of the tree: `device.wait`,
+    `png.encode` and `file.write` descend from `execute_prompt`, which
+    lasts until the file is written, and both readers return numbers."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import spans as readers
+    finally:
+        sys.path.remove(bench)
+    server.queue_prompt(graph(), "p1")
+    run_queued(server)
+    flat = tracer.spans("p1")
+    (execute,) = [s for s in flat if s["name"] == "execute_prompt"]
+    parents = {s["span_id"]: s["parent_id"] for s in flat}
+
+    def descends(span):
+        at = span["parent_id"]
+        while at is not None and at != execute["span_id"]:
+            at = parents.get(at)
+        return at == execute["span_id"]
+
+    parts = {s["name"]: s for s in flat if s["name"] in ("device.wait", "png.encode", "file.write")}
+    assert set(parts) == {"device.wait", "png.encode", "file.write"}
+    assert all(descends(s) for s in parts.values())
+    assert execute["end"] >= parts["file.write"]["end"] and execute["status"] == "ok"
+    host = readers.host_seconds(flat)
+    assert host == execute["duration"] - parts["device.wait"]["duration"] > 0
+    assert readers.seconds(flat, "png.encode", "file.write") == (
+        parts["png.encode"]["duration"] + parts["file.write"]["duration"])
 
 
 def test_node_spans_carry_the_program_work_done_in_them(server, tracer):
