@@ -24,3 +24,4 @@ from . import nodes_mask  # noqa: F401,E402
 from . import nodes_custom_sampling  # noqa: F401,E402
 from . import nodes_loaders  # noqa: F401,E402
 from . import nodes_transform  # noqa: F401,E402
+from . import nodes_text  # noqa: F401,E402

@@ -73,6 +73,18 @@ def _get_bundle(context, model_name: str) -> pl.PipelineBundle:
     return context.pipelines[model_name]
 
 
+def _require_part(bundle, part: str, node: str) -> None:
+    """A bundle need not hold every part (a language-model checkpoint
+    has no denoiser, VAE or text encoder; UNETLoader's has no VAE): say
+    which is missing instead of failing inside the model code."""
+    if getattr(bundle, part, None) is None:
+        held = ", ".join(sorted(bundle.params)) or "nothing"
+        raise ValueError(
+            f"{node} needs a bundle with a {part} part; "
+            f"{bundle.model_name!r} holds {held}"
+        )
+
+
 @register_node
 class CheckpointLoaderSimple:
     @classmethod
@@ -222,6 +234,7 @@ class CLIPTextEncode:
         # Conditioning carrying the pooled vector: SDXL-class adm and
         # Flux-class vector_in models consume it; families without
         # pooled conditioning ignore the field (pipeline._make_model_fn)
+        _require_part(clip, "text_encoder", "CLIPTextEncode")
         return (pl.encode_text_pooled(clip, [str(text)]),)
 
 
@@ -580,6 +593,7 @@ class KSampler:
     ):
         spec = resolve_seed(seed)
         bundle = model
+        _require_part(bundle, "unet", "KSampler")
         latents, noise_mask, extras = _prep_latents(bundle, latent_image)
         fixed = bool(latent_image.get("batch_index_fixed", False))
         _annotate_sampling(
@@ -954,6 +968,7 @@ def _vae_pass(vae, x, method: str) -> jax.Array:
     `mesh_programs` 1 in `_decode_mesh`."""
     from ..telemetry import get_tracer
 
+    _require_part(vae, "vae", "VAEDecode" if method == "decode" else "VAEEncode")
     get_tracer().annotate(programs=1)
     return vae_apply(vae.vae, vae.params["vae"], x, method=method)
 

@@ -1,0 +1,578 @@
+"""DeepSeek-V2: multi-head latent attention and a mixture of experts
+with shared experts, as a language model that generates tokens.
+
+Pure functions over a parameter tree (no flax module: the same weights
+are read by two forms of one block, and the float32 reference in
+`reference/deepseek_v2.py` takes the tree as it is):
+
+- `prefill`: the whole prompt at once. MLA in its *expanded* form (keys
+  and values built from the latent, causal attention through
+  `ops.attention.dot_product_attention`), the latent cache written.
+- `decode`: N dependent steps in one program (`lax.fori_loop`), MLA in
+  its *absorbed* form (W_uk folded into the query, W_uv applied after
+  the weighted sum, attention over the 576-wide latent cache itself),
+  the next id sampled on the device from the seed.
+
+The expert layer is told which experts it holds (`parallel.sharding.
+expert_range` of `ep_rank` / `ep_size`), routes over all of them and
+computes its own experts' part of the result as one grouped product
+(`jax.lax.ragged_dot`) with no dropped token and no capacity factor;
+what absent experts would add is left out. The vocabulary may be a slice
+too (`vocab_shards`): embedding, logits and sampling are over the slice.
+
+Parameter layout, where it departs from the published checkpoint's (a
+fixed permutation or split of weight columns, nothing a forward pass can
+tell from the original): `w_uk` / `w_uv` are the two halves of
+`kv_b_proj`; gate and up projections are stored side by side as
+`w_gate_up`; rotary dimensions are half-split (`rotate_half`), where the
+checkpoint stores interleaved pairs that the published code permutes
+into this order before rotating.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+from typing import Any, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.attention import dot_product_attention
+from ..parallel.sharding import expert_range
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekV2Config:
+    """The published `config.json`'s shape keys under their own names,
+    and the chip's share of a deployment: `ep_size` chips share each
+    layer's experts and this one is `ep_rank`; the vocabulary is cut
+    `vocab_shards` ways and this chip holds the first slice."""
+
+    hidden_size: int = 5120
+    num_hidden_layers: int = 60
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 160
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    n_group: int = 8
+    topk_group: int = 3
+    routed_scaling_factor: float = 16.0
+    vocab_size: int = 102400
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.707
+    rope_mscale_all_dim: float = 0.707
+    rope_original_max_position_embeddings: int = 4096
+    ep_size: int = 1
+    ep_rank: int = 0
+    vocab_shards: int = 1
+
+    @property
+    def held_experts(self) -> range:
+        return expert_range(self.n_routed_experts, self.ep_rank, self.ep_size)
+
+    @property
+    def vocab_held(self) -> int:
+        return self.vocab_size // self.vocab_shards
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        m = _yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return self.qk_head_dim ** -0.5 * m * m
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace
+
+    def layer_name(self, layer: int) -> str:
+        return f"dense_{layer}" if self.is_dense(layer) else f"moe_{layer}"
+
+
+# --- rotary position embedding with YaRN ---------------------------------
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: DeepSeekV2Config) -> np.ndarray:
+    """The rotary frequencies: a blend of 1/theta^(2i/d) and the same
+    divided by the factor, by a linear ramp between the correction
+    dimensions of `beta_fast` and `beta_slow` rotations over the
+    original context."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    exponent = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extrapolated = 1.0 / base ** exponent
+    interpolated = extrapolated / cfg.rope_factor
+
+    def correction_dim(rotations: float) -> float:
+        return (
+            dim * math.log(cfg.rope_original_max_position_embeddings
+                           / (rotations * 2 * math.pi))
+        ) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    return (interpolated * ramp + extrapolated * (1 - ramp)).astype(np.float32)
+
+
+def rope_tables(cfg: DeepSeekV2Config, positions: jax.Array):
+    """cos and sin, [T, rope/2] float32; YaRN's factor on them is
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    angles = positions.astype(jnp.float32)[:, None] * jnp.asarray(yarn_inv_freq(cfg))
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / _yarn_mscale(
+        cfg.rope_factor, cfg.rope_mscale_all_dim
+    )
+    return jnp.cos(angles) * m, jnp.sin(angles) * m
+
+
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate [..., T, (heads,) rope] by its position, the two halves of
+    the last axis as the pair's members (`rotate_half`)."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    if x.ndim == cos.ndim + 1:  # a heads axis between T and rope
+        cos, sin = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+# --- parameters -----------------------------------------------------------
+
+
+def param_shapes(cfg: DeepSeekV2Config) -> dict[str, Any]:
+    """The tree's shapes with each weight's fan-in (None: a norm's
+    scale, initialised to one)."""
+    h, heads = cfg.hidden_size, cfg.num_attention_heads
+    held = len(cfg.held_experts)
+
+    def mlp(width: int) -> dict:
+        return {"w_gate_up": ((h, 2 * width), h), "w_down": ((width, h), width)}
+
+    layers = []
+    for layer in range(cfg.num_hidden_layers):
+        block: dict[str, Any] = {
+            "attn_norm": ((h,), None),
+            "attn": {
+                "w_dq": ((h, cfg.q_lora_rank), h),
+                "q_norm": ((cfg.q_lora_rank,), None),
+                "w_uq": ((cfg.q_lora_rank, heads * cfg.qk_head_dim), cfg.q_lora_rank),
+                "w_dkv": ((h, cfg.cache_width), h),
+                "kv_norm": ((cfg.kv_lora_rank,), None),
+                "w_uk": ((cfg.kv_lora_rank, heads, cfg.qk_nope_head_dim), cfg.kv_lora_rank),
+                "w_uv": ((cfg.kv_lora_rank, heads, cfg.v_head_dim), cfg.kv_lora_rank),
+                "w_o": ((heads * cfg.v_head_dim, h), heads * cfg.v_head_dim),
+            },
+            "ffn_norm": ((h,), None),
+        }
+        if cfg.is_dense(layer):
+            block["mlp"] = mlp(cfg.intermediate_size)
+        else:
+            width = cfg.moe_intermediate_size
+            block["moe"] = {
+                "w_g": ((h, cfg.n_routed_experts), h),
+                "experts": {
+                    "w_gate_up": ((held, h, 2 * width), h),
+                    "w_down": ((held, width, h), width),
+                },
+                "shared": mlp(width * cfg.n_shared_experts),
+            }
+        layers.append(block)
+    return {
+        "embed": ((cfg.vocab_held, h), 1),
+        "layers": layers,
+        "final_norm": ((h,), None),
+        "head": ((h, cfg.vocab_held), h),
+    }
+
+
+def _is_spec(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+
+
+def param_count(cfg: DeepSeekV2Config) -> int:
+    specs = jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_spec)
+    return sum(math.prod(shape) for shape, _ in specs)
+
+
+@partial(jax.jit, static_argnames=("shape", "std", "dtype"))
+def _normal(key, shape, std, dtype):
+    if len(shape) == 3 and math.prod(shape) >= 2**28:
+        # a stack of experts, one at a time: the float32 draw of a whole
+        # stack (2.5 GB at the published widths) is never alive at once
+        keys = jax.random.split(key, shape[0])
+        return jax.lax.map(
+            lambda k: (jax.random.normal(k, shape[1:], jnp.float32) * std).astype(dtype), keys
+        )
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def init_params(cfg: DeepSeekV2Config, key, dtype=jnp.float32) -> dict[str, Any]:
+    """Seeded random weights built in `dtype`, weight by weight: normal
+    with standard deviation fan_in^-1/2, so activations keep their scale
+    through the depth and the router's logits spread by about one."""
+    specs, treedef = jax.tree_util.tree_flatten(param_shapes(cfg), is_leaf=_is_spec)
+    dtype = jnp.dtype(dtype)
+    leaves = []
+    for index, (shape, fan_in) in enumerate(specs):
+        if fan_in is None:
+            leaves.append(jnp.ones(shape, dtype))
+        else:
+            leaves.append(
+                _normal(jax.random.fold_in(key, index), shape, float(fan_in) ** -0.5, dtype)
+            )
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+# --- blocks ---------------------------------------------------------------
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (normed * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def swiglu(x: jax.Array, p: dict) -> jax.Array:
+    gate, up = jnp.split(x @ p["w_gate_up"], 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ p["w_down"]
+
+
+def _queries(cfg, p, x, cos, sin):
+    """[T, heads, nope] and rotated [T, heads, rope] from x [T, hidden]."""
+    c_q = rms_norm(x @ p["w_dq"], p["q_norm"], cfg.rms_norm_eps)
+    q = (c_q @ p["w_uq"]).reshape(x.shape[0], cfg.num_attention_heads, cfg.qk_head_dim)
+    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
+    return q_nope, apply_rope(q_rope, cos, sin)
+
+
+def _latents(cfg, p, x, cos, sin):
+    """What the cache holds of x [T, hidden]: the normed latent and the
+    rotated shared rope key side by side, [T, kv_lora + rope]."""
+    down = x @ p["w_dkv"]
+    c_kv = rms_norm(down[:, : cfg.kv_lora_rank], p["kv_norm"], cfg.rms_norm_eps)
+    k_rope = apply_rope(down[:, cfg.kv_lora_rank:], cos, sin)
+    return jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def mla_expanded(cfg, p, x, rope):
+    """MLA over a whole sequence, keys and values built from the latent;
+    `rope` is `rope_tables` of its positions. Returns the block's output
+    [T, hidden] and the latents to cache."""
+    cos, sin = rope
+    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
+    latents = _latents(cfg, p, x, cos, sin)
+    c_kv, k_rope = latents[:, : cfg.kv_lora_rank], latents[:, cfg.kv_lora_rank:]
+    k_nope = jnp.einsum("tc,chd->thd", c_kv, p["w_uk"])
+    v = jnp.einsum("tc,chd->thd", c_kv, p["w_uv"])
+    k_rope = jnp.broadcast_to(k_rope[:, None, :], (*k_nope.shape[:2], cfg.qk_rope_head_dim))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, k_rope], axis=-1)
+    out = dot_product_attention(
+        q[None], k[None], v[None], causal=True, scale=cfg.softmax_scale
+    )[0]
+    return out.reshape(x.shape[0], -1) @ p["w_o"], latents
+
+
+def mla_absorbed(cfg, p, x, rope, cache, valid):
+    """MLA for new tokens x [T, hidden] over a latent cache [S, kv_lora +
+    rope] that already holds their own latents: W_uk folded into the
+    query, attention over the latent itself, W_uv after the weighted
+    sum. `valid` [T, S] says which cached positions each token sees."""
+    cos, sin = rope
+    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
+    q_lat = jnp.einsum("thd,chd->thc", q_nope, p["w_uk"])
+    q = jnp.concatenate([q_lat, q_rope], axis=-1)            # [T, heads, 576]
+    scores = cfg.softmax_scale * jnp.einsum(
+        "thc,sc->ths", q, cache, preferred_element_type=jnp.float32
+    )
+    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cache.dtype)
+    o_lat = jnp.einsum("ths,sc->thc", probs, cache[:, : cfg.kv_lora_rank])
+    out = jnp.einsum("thc,chd->thd", o_lat, p["w_uv"])
+    return out.reshape(x.shape[0], -1) @ p["w_o"]
+
+
+def route(cfg: DeepSeekV2Config, scores: jax.Array):
+    """`group_limited_greedy` over router scores [T, experts] (float32,
+    softmax already taken): a group's score is its best expert's, the
+    best `topk_group` groups stay, and the `num_experts_per_tok` largest
+    scores among their experts are chosen. Returns (ids, weights), the
+    weights `routed_scaling_factor` times the scores, not renormalised.
+    Ties go to the lower index, among groups and among experts."""
+    tokens = scores.shape[0]
+    per_group = cfg.n_routed_experts // cfg.n_group
+    group_scores = scores.reshape(tokens, cfg.n_group, per_group).max(axis=-1)
+    _, groups = jax.lax.top_k(group_scores, cfg.topk_group)
+    keep = jnp.zeros((tokens, cfg.n_group), bool).at[
+        jnp.arange(tokens)[:, None], groups
+    ].set(True)
+    masked = jnp.where(jnp.repeat(keep, per_group, axis=1), scores, 0.0)
+    weights, ids = jax.lax.top_k(masked, cfg.num_experts_per_tok)
+    return ids, weights * cfg.routed_scaling_factor
+
+
+def moe(cfg, p, x):
+    """The expert layer of this chip over x [T, hidden]: the shared
+    SwiGLU of every token, plus the weighted outputs of the chosen
+    experts that are held here. Returns (output, chosen ids [T, k],
+    pairs on each held expert [held])."""
+    with jax.named_scope("router"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), p["w_g"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        ids, weights = route(cfg, jax.nn.softmax(logits, axis=-1))
+    with jax.named_scope("experts"):
+        held = cfg.held_experts
+        tokens, k = ids.shape
+        local = ids.reshape(-1) - held.start
+        here = (local >= 0) & (local < len(held))
+        # sort the token-expert pairs by held expert, the pairs of absent
+        # experts last: each held expert's rows are then one segment
+        slot = jnp.where(here, local, len(held))
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.zeros((len(held),), jnp.int32).at[slot].add(1, mode="drop")
+        rows = x[order // k]
+        gate, up = jnp.split(
+            jax.lax.ragged_dot(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1
+        )
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
+        # rows past the last segment are absent experts' pairs: weight 0
+        out = jnp.where(here[order][:, None], out, 0).astype(jnp.float32)
+        out = out * weights.reshape(-1)[order][:, None]
+        routed = out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
+    with jax.named_scope("shared"):
+        shared = swiglu(x, p["shared"])
+    return shared + routed.astype(x.dtype), ids, sizes
+
+
+def _feed_forward(cfg, layer, block, x):
+    """(output, ids or None, pairs per held expert or None)"""
+    if cfg.is_dense(layer):
+        return swiglu(x, block["mlp"]), None, None
+    return moe(cfg, block["moe"], x)
+
+
+def block_expanded(cfg, layer: int, block: dict, h, rope):
+    """One pre-norm residual block over a whole sequence (the prefill's
+    form). Returns (h, latents, ids, pairs per held expert)."""
+    with jax.named_scope(cfg.layer_name(layer)):
+        with jax.named_scope("mla"):
+            attn, latents = mla_expanded(
+                cfg, block["attn"], rms_norm(h, block["attn_norm"], cfg.rms_norm_eps), rope
+            )
+        h = h + attn
+        out, ids, sizes = _feed_forward(
+            cfg, layer, block, rms_norm(h, block["ffn_norm"], cfg.rms_norm_eps)
+        )
+        return h + out, latents, ids, sizes
+
+
+def _head(cfg, params, h):
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+
+
+def _moe_layers(cfg) -> int:
+    return sum(not cfg.is_dense(layer) for layer in range(cfg.num_hidden_layers))
+
+
+# --- the two programs -----------------------------------------------------
+
+
+class Prefill(NamedTuple):
+    logits: jax.Array   # [vocab_held] float32, at the prompt's last position
+    cache: jax.Array    # [layers, cache_len, kv_lora + rope]
+    loads: jax.Array    # [moe layers, held] pairs on each held expert
+    chosen: jax.Array | None  # [moe layers, T, k] experts chosen; under `collect`
+
+
+class Decode(NamedTuple):
+    ids: jax.Array      # [steps]
+    loads: jax.Array    # [moe layers, held], summed over the steps
+    logits: jax.Array | None  # [steps, vocab_held] float32, after id i; under `collect`
+    chosen: jax.Array | None  # [steps, moe layers, k]; under `collect`
+
+
+@partial(jax.jit, static_argnames=("cfg", "cache_len", "collect"))
+def prefill(cfg: DeepSeekV2Config, params, ids, *, cache_len: int, collect: bool = False):
+    """The whole prompt `ids` [T] at once. Returns the logits at its last
+    position [vocab_held] (float32), the latent cache [layers, cache_len,
+    kv_lora + rope] with the first T positions written, the pairs that
+    fell on each held expert [moe layers, held] and, under `collect`
+    (the parity check's; a served request needs none of it), the experts
+    chosen [moe layers, T, k]."""
+    tokens = ids.shape[0]
+    rope = rope_tables(cfg, jnp.arange(tokens))
+    h = params["embed"][ids]
+    cache = jnp.zeros((cfg.num_hidden_layers, cache_len, cfg.cache_width), h.dtype)
+    chosen, loads = [], []
+    for layer, block in enumerate(params["layers"]):
+        h, latents, ids_l, sizes = block_expanded(cfg, layer, block, h, rope)
+        cache = cache.at[layer, :tokens].set(latents)
+        if ids_l is not None:
+            chosen.append(ids_l)
+            loads.append(sizes)
+    return Prefill(
+        _head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
+        jnp.stack(chosen) if collect else None,
+    )
+
+
+def decode_step(cfg, params, cache, token, position):
+    """One token through every layer over the latent cache (absorbed
+    MLA). Returns (logits [vocab_held], cache, ids [moe layers, k],
+    pairs per held expert [moe layers, held])."""
+    valid = (jnp.arange(cache.shape[1]) <= position)[None, :]
+    rope = rope_tables(cfg, position[None])
+    h = params["embed"][token][None]
+    chosen, loads = [], []
+    for layer, block in enumerate(params["layers"]):
+        with jax.named_scope(cfg.layer_name(layer)):
+            with jax.named_scope("mla"):
+                x = rms_norm(h, block["attn_norm"], cfg.rms_norm_eps)
+                latent = _latents(cfg, block["attn"], x, *rope)
+                cache = jax.lax.dynamic_update_slice(cache, latent[None], (layer, position, 0))
+                h = h + mla_absorbed(cfg, block["attn"], x, rope, cache[layer], valid)
+            out, ids_l, sizes = _feed_forward(
+                cfg, layer, block, rms_norm(h, block["ffn_norm"], cfg.rms_norm_eps)
+            )
+            h = h + out
+        if ids_l is not None:
+            chosen.append(ids_l[0])
+            loads.append(sizes)
+    return _head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
+
+
+def sample(logits, key, temperature):
+    """The next id from float32 logits: the largest at temperature 0,
+    else a draw from softmax(logits / temperature). `temperature` is a
+    traced scalar, so every value runs the one program."""
+    drawn = jax.random.categorical(key, logits / jnp.where(temperature > 0, temperature, 1.0))
+    return jnp.where(temperature > 0, drawn, jnp.argmax(logits)).astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("cfg", "steps", "collect"))
+def decode(cfg: DeepSeekV2Config, params, cache, logits, start, key, temperature, *,
+           steps: int, collect: bool = False):
+    """`steps` dependent decode steps in one program, from the prefill's
+    `logits` at position `start - 1`: draw id i from the logits, run it
+    through the model at position `start + i`. Always `steps` ids, no
+    early stop. Returns the ids [steps], the pairs on each held expert
+    summed over the steps [moe layers, held] and, under `collect`, every
+    step's logits [steps, vocab_held] (the logits after id i) and the
+    experts chosen [steps, moe layers, k]."""
+    moe_layers, k = _moe_layers(cfg), cfg.num_experts_per_tok
+
+    def body(i, carry):
+        cache, logits, ids, loads, kept = carry
+        token = sample(logits, jax.random.fold_in(key, i), temperature)
+        logits, cache, chosen_i, loads_i = decode_step(cfg, params, cache, token, start + i)
+        if collect:
+            kept = (kept[0].at[i].set(logits), kept[1].at[i].set(chosen_i))
+        return cache, logits, ids.at[i].set(token), loads + loads_i, kept
+
+    kept = (
+        jnp.zeros((steps, cfg.vocab_held), jnp.float32),
+        jnp.zeros((steps, moe_layers, k), jnp.int32),
+    ) if collect else (None, None)
+    carry = (
+        cache, logits, jnp.zeros((steps,), jnp.int32),
+        jnp.zeros((moe_layers, len(cfg.held_experts)), jnp.int32), kept,
+    )
+    _, _, ids, loads, kept = jax.lax.fori_loop(0, steps, body, carry)
+    return Decode(ids, loads, *kept)
+
+
+# --- the stand-in tokenizer -----------------------------------------------
+
+
+class ByteTokenizer:
+    """A stand-in for the published tokenizer, which is not in the
+    sandbox: deterministic and byte-level, into the first ids of the
+    vocabulary's slice. `encode`: id 0 (begin of sentence), then 1 + b
+    for each byte b of the text's UTF-8. `decode`: ids 1..256 give their
+    byte where it is printable ASCII and nothing otherwise; id 0 gives
+    nothing; every other id n gives a space and then n - 257 written in
+    base 26 with the letters a..z, least digit first. So any ids come
+    back as lower-case words that CLIP's BPE can tokenise."""
+
+    BOS = 0
+    BYTES = 256
+
+    def encode(self, text: str) -> list[int]:
+        return [self.BOS] + [1 + b for b in text.encode("utf-8")]
+
+    def decode(self, ids) -> str:
+        pieces = []
+        for n in map(int, ids):
+            if n == self.BOS:
+                continue
+            if n <= self.BYTES:
+                pieces.append(chr(n - 1) if 32 <= n - 1 < 127 else "")
+                continue
+            n -= self.BYTES + 1
+            word = chr(97 + n % 26)
+            while n >= 26:
+                n //= 26
+                word += chr(97 + n % 26)
+            pieces.append(" " + word)
+        return "".join(pieces).strip()
+
+
+class DeepSeekV2:
+    """What a bundle's `lm` part is: the configuration with the two
+    programs bound to it, and what a node reports of them."""
+
+    def __init__(self, cfg: DeepSeekV2Config):
+        self.cfg = cfg
+        self.tokenizer = ByteTokenizer()
+
+    def init(self, key, dtype=jnp.float32):
+        return init_params(self.cfg, key, dtype)
+
+    def prefill(self, params, ids, cache_len: int, collect: bool = False):
+        return prefill(self.cfg, params, ids, cache_len=cache_len, collect=collect)
+
+    def decode(self, params, cache, logits, start: int, key, steps: int, temperature: float,
+               collect: bool = False):
+        return decode(
+            self.cfg, params, cache, logits, jnp.int32(start), key, jnp.float32(temperature),
+            steps=steps, collect=collect,
+        )
+
+    def describe(self, cache_len: int, itemsize: int) -> dict[str, int]:
+        cfg = self.cfg
+        return {
+            "layers": cfg.num_hidden_layers,
+            "experts_held": len(cfg.held_experts),
+            "experts_total": cfg.n_routed_experts,
+            "cache_bytes": cfg.num_hidden_layers * cache_len * cfg.cache_width * itemsize,
+        }

@@ -15,6 +15,7 @@ from __future__ import annotations
 from typing import Any, Callable
 
 from .clip_vision import ClipVisionConfig, ClipVisionEncoder
+from .deepseek_v2 import DeepSeekV2, DeepSeekV2Config
 from .dit import DiTConfig, VideoDiT
 from .mmdit import MMDiT, MMDiTConfig
 from .sd3 import SD3Config, SD3MMDiT
@@ -451,6 +452,33 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             image_size=32, patch_size=8, width=48, layers=3, heads=2,
         ),
     },
+    # --- language models (a bundle with an `lm` part and no denoiser) ---
+    # DeepSeek-V2 as one chip's share of a four-chip host, every width as
+    # published: the leading dense layer and four expert layers of 60,
+    # experts 0-39 of 160 (rank 0 of 4), the first quarter of the
+    # vocabulary (the benchmark's deepseek-v2 configuration says what the
+    # cut stands for)
+    "deepseek-v2-ep4-5l": {
+        "family": "lm",
+        "config": DeepSeekV2Config(
+            num_hidden_layers=5, ep_size=4, ep_rank=0, vocab_shards=4,
+        ),
+    },
+    # every mechanism at a size for the CPU: a dense layer and two expert
+    # layers, 16 experts in 4 groups (2 groups and 3 experts a token, one
+    # shared), YaRN on, rank 0 of 4 holding experts 0-3
+    "tiny-deepseek-v2": {
+        "family": "lm",
+        "config": DeepSeekV2Config(
+            hidden_size=64, num_hidden_layers=3, num_attention_heads=4,
+            q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
+            moe_intermediate_size=32, n_routed_experts=16,
+            n_shared_experts=1, num_experts_per_tok=3, n_group=4,
+            topk_group=2, vocab_size=2048, ep_size=4, ep_rank=0,
+            vocab_shards=4,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -497,6 +525,7 @@ _CONSTRUCTORS: dict[str, Callable[[Any], Any]] = {
     "t5_encoder": lambda cfg: T5Encoder(cfg),
     "clip_vision": lambda cfg: ClipVisionEncoder(cfg),
     "video_vae": lambda cfg: VideoVAE(cfg),
+    "lm": lambda cfg: DeepSeekV2(cfg),
 }
 
 

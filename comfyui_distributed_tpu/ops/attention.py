@@ -65,8 +65,13 @@ _DTYPE_NAMES = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
 def dot_product_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     force_flash: bool | None = None, interpret: bool = False,
+    causal: bool = False, scale: float | None = None,
 ) -> jax.Array:
     """[B, N, H, D] attention; returns [B, N, H, D].
+
+    `causal` (query i sees keys 0..i + M - N) and a value width or a
+    `scale` of its own go to `causal_attention_blocked`, an XLA form;
+    `force_flash` and `interpret` do not apply there.
 
     `force_flash` overrides backend routing and `interpret` runs the
     Pallas kernel in the interpreter: both exist for tests that pin the
@@ -78,6 +83,12 @@ def dot_product_attention(
     those lanes anyway, so this costs nothing extra — with the softmax
     scale pinned to the ORIGINAL head dim and the output sliced back.
     """
+    if causal:
+        return causal_attention_blocked(q, k, v, scale=scale)
+    if scale is not None or v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "a scale or a value width of its own is implemented for causal attention only"
+        )
     use_flash = (
         attention_route(q, k) == "flash" if force_flash is None else force_flash
     )
@@ -101,6 +112,47 @@ def dot_product_attention(
         )
         return out[..., :d]
     return flash_attention(q, k, v, interpret=interpret)
+
+
+# Query rows a block of `causal_attention_blocked` takes: its float32
+# scores are heads x this x M at most (128 heads over 2,048 keys: 268 MB).
+CAUSAL_BLOCK_Q = 256
+
+
+def causal_attention_blocked(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float | None = None,
+) -> jax.Array:
+    """Causal attention, q/k [B, N|M, H, Dq] and v [B, M, H, Dv] with a
+    width of its own, as XLA operations over blocks of query rows: a
+    block of rows takes only the keys up to its last row (the blocks
+    above the diagonal are skipped, the block on it is masked), so the
+    score tensor is never whole in memory. Scores, softmax and both
+    accumulations are float32; the probabilities are rounded to v's
+    dtype for the second product, as the kernel does."""
+    n, m, d = q.shape[1], k.shape[1], q.shape[3]
+    if n > m:
+        raise ValueError(f"causal attention of {n} queries over {m} keys")
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    block = min(CAUSAL_BLOCK_Q, n)  # the last block is short where n is no multiple
+    log = _ROUTE_LOG.get()
+    if log is not None:
+        name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
+        log.append(f"xla-causal {n}x{m}x{d}/{v.shape[3]} bq{block} {name}")
+    outs = []
+    for start in range(0, n, block):
+        stop = min(start + block, n)
+        last = stop + m - n  # keys the block's last row sees
+        scores = scale * jnp.einsum(
+            "bqhd,bkhd->bhqk", q[:, start:stop], k[:, :last],
+            preferred_element_type=jnp.float32)
+        rows = jnp.arange(start, stop)[:, None] + (m - n)
+        scores = jnp.where(rows >= jnp.arange(last)[None, :], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        outs.append(jnp.einsum(
+            "bhqk,bkhd->bqhd", probs, v[:, :last], preferred_element_type=jnp.float32,
+        ).astype(v.dtype))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
 def attention_route(q: jax.Array, k: jax.Array) -> str:

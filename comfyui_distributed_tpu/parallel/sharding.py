@@ -59,6 +59,19 @@ def replicate(tree: Any, mesh: Mesh) -> Any:
     return jax.tree_util.tree_map(lambda leaf: jax.device_put(leaf, sharding), tree)
 
 
+def expert_range(n_experts: int, rank: int, size: int) -> range:
+    """The routed experts that chip `rank` of the `size` chips sharing a
+    layer holds: a contiguous run, so that with group-limited routing a
+    chip holds whole groups (160 experts in 8 groups over 4 chips: rank
+    0 holds experts 0-39, groups 0 and 1)."""
+    if size < 1 or not 0 <= rank < size or n_experts % size:
+        raise ValueError(
+            f"{n_experts} experts do not divide over {size} chips (rank {rank})"
+        )
+    per_chip = n_experts // size
+    return range(rank * per_chip, (rank + 1) * per_chip)
+
+
 def params_byte_size(params: Any) -> int:
     """Total parameter bytes (as stored) — the numerator of the
     CDT_MESH_HBM_GB auto-TP budget rule."""

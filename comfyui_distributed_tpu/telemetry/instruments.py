@@ -869,6 +869,15 @@ def saves_total() -> Counter:
     )
 
 
+def lm_tokens_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_lm_tokens_total",
+        "Tokens a language model took in (phase=prefill) or generated "
+        "(phase=decode)",
+        ("phase",),
+    )
+
+
 def tile_jobs_active() -> Gauge:
     return get_metrics_registry().gauge(
         "cdt_tile_jobs_active",

@@ -42,6 +42,7 @@ def render() -> str:
         "nodes_transform": "Image/latent transforms (flip, rotate, crop, blend)",
         "nodes_video": "Video (WAN class)",
         "nodes_audio": "Audio",
+        "nodes_text": "Text (a language model in the graph; STRING links)",
     }
 
     lines = [
