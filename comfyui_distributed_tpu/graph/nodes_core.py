@@ -18,6 +18,7 @@ collapse of the reference's prompt replication).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 from functools import partial
@@ -601,7 +602,7 @@ class KSampler:
         )
 
         mesh = getattr(context, "mesh", None) if context is not None else None
-        with attention_route_log() as routes:
+        with annotate_attention():
             if (
                 spec.per_participant
                 and mesh is not None
@@ -634,13 +635,21 @@ class KSampler:
                         batch_fixed_noise=fixed,
                     )
                 }
-        if routes:
-            # only the request that traced the program gets here with
-            # anything: which implementation its attention took
-            from ..telemetry import get_tracer
-
-            get_tracer().annotate(attention=", ".join(sorted(set(routes))))
         return ({**extras, **result},)
+
+
+@contextlib.contextmanager
+def annotate_attention():
+    """Around the call that may trace a node's program: the node's span
+    gets `attention`, every `dot_product_attention` call made inside
+    with the implementation it took (`ops/attention.route_log`). Only
+    the request that traces the program has any."""
+    from ..telemetry import get_tracer
+
+    with attention_route_log() as routes:
+        yield
+    if routes:
+        get_tracer().annotate(attention=", ".join(sorted(set(routes))))
 
 
 def _annotate_sampling(

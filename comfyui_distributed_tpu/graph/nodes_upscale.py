@@ -24,6 +24,7 @@ import jax
 from ..models import pipeline as pl
 from ..ops import upscale as upscale_ops
 from ..utils.logging import log
+from .nodes_core import annotate_attention
 from .registry import register_node
 
 
@@ -191,14 +192,15 @@ class UltimateSDUpscaleDistributed:
                 ),
             )
 
-        out = upscale_ops.run_upscale(
-            bundle=model, image=image, pos=positive, neg=negative, mesh=mesh,
-            upscale_by=float(upscale_by), tile=tile, tile_h=tile_h,
-            padding=int(tile_padding),
-            steps=int(steps), sampler=sampler_name, scheduler=scheduler,
-            cfg=float(cfg), denoise=float(denoise), seed=int(seed),
-            upscale_method=upscale_method,
-            mask_blur=int(mask_blur), tiled_decode=bool(tiled_decode),
-            uniform=bool(force_uniform_tiles),
-        )
+        with annotate_attention():
+            out = upscale_ops.run_upscale(
+                bundle=model, image=image, pos=positive, neg=negative, mesh=mesh,
+                upscale_by=float(upscale_by), tile=tile, tile_h=tile_h,
+                padding=int(tile_padding),
+                steps=int(steps), sampler=sampler_name, scheduler=scheduler,
+                cfg=float(cfg), denoise=float(denoise), seed=int(seed),
+                upscale_method=upscale_method,
+                mask_blur=int(mask_blur), tiled_decode=bool(tiled_decode),
+                uniform=bool(force_uniform_tiles),
+            )
         return (out,)

@@ -2,9 +2,11 @@
 
 `dot_product_attention(q, k, v)` with [B, N, H, D] layout routes to:
 - a Pallas flash-attention kernel on TPU (tiled online-softmax — the
-  memory-bound op worth hand-writing; everything else is left to XLA),
-- `jax.nn.dot_product_attention` elsewhere (other backends, tiny
-  shapes, and shapes that don't tile cleanly).
+  memory-bound op worth hand-writing; everything else is left to XLA);
+  lengths off the 128 multiple are padded to lengths the kernel tiles
+  and the padded keys masked inside it,
+- `jax.nn.dot_product_attention` elsewhere (other backends, and the
+  shapes `kernel_wins` leaves to XLA).
 
 The reference has no attention code at all (torch/ComfyUI provides
 it); this is new TPU-native surface.
@@ -21,9 +23,9 @@ import os
 import jax
 import jax.numpy as jnp
 
-# Sequence lengths that are whole multiples of this route to the kernel,
-# and every block the kernel uses is a multiple of it (the MXU's edge and
-# the lane width).
+# Every block the kernel uses is a multiple of this (the MXU's edge and
+# the lane width); a sequence length that is not is padded up to one
+# (`flash_plan`) inside `flash_attention`.
 ROUTE_MULTIPLE = 128
 
 # Block caps, from the sweep on a v5e (PERF.md §6, PR 28): a grid step has
@@ -36,6 +38,18 @@ MAX_BLOCK_K = 1536
 # What one grid step may hold in VMEM by `flash_vmem_bytes`' count. The
 # compiler's scoped limit is 16 MiB, and it keeps temporaries of its own.
 VMEM_BUDGET = 12 * 2**20
+# A q block of a length off `ROUTE_MULTIPLE` is a multiple of this, the
+# sublane tile of 16-bit operands (two of 32-bit): q rows only stream
+# through the MXU, so 1,296 rows go as 3 x 432 with no padded row at all
+# (1.74 ms against 2.04 as 3 x 512 of 1,536; PERF.md §6, PR 33).
+ROW_MULTIPLE = 16
+# Off the multiple, the kernel takes a call from this many keys on: XLA's
+# cost is the float32 scores, 12 bytes a key for every q row, the kernel's
+# the lane padding and the [B,N,H,D] <-> [BH,N,D] copies, about the same
+# for every row. On a v5e at 64-wide heads (batch 16, PR 33) XLA wins at
+# 324 keys (0.60 ms against 0.76), 400 and 484 are ties, and the kernel
+# wins from 500 (1.25 against 1.47) and 576 (0.66 against 0.81) on.
+MIN_RAGGED_KEYS = 512
 
 
 _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
@@ -46,11 +60,13 @@ _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
 @contextlib.contextmanager
 def route_log():
     """Collect one entry for every `dot_product_attention` call made
-    inside the block: `xla NxMxD`, or `flash NxMxD bq<block_q>
-    bk<block_k> <operand dtype>` with what the kernel chose for the
-    shape. Calls happen while a program is traced, so a block around a
-    jitted call fills only on the request that builds the program; the
-    graph's sampler node reads it into its span."""
+    inside the block: `xla NxMxD`, or `flash NxMxD [pad<N'>x<M'>]
+    bq<block_q> bk<block_k> <operand dtype>` with what the kernel chose
+    for the shape (`pad` only where a length was padded: `flash
+    1296x1296x64 pad1296x1408 bq432 bk1408 bf16`). Calls happen while a
+    program is traced, so a block around a jitted call fills only on
+    the request that builds the program; the graph's sampler and
+    upscale nodes read it into their spans."""
     routes: list[str] = []
     token = _ROUTE_LOG.set(routes)
     try:
@@ -98,8 +114,10 @@ def dot_product_attention(
     if log is not None:
         entry = f"{'flash' if use_flash else 'xla'} {n}x{m}x{d}"
         if use_flash:
-            block_q, block_k = flash_blocks(n, m, d + pad, q.dtype.itemsize)
+            n_pad, m_pad, block_q, block_k = flash_plan(n, m, d + pad, q.dtype.itemsize)
             name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
+            if (n_pad, m_pad) != (n, m):
+                entry += f" pad{n_pad}x{m_pad}"
             entry += f" bq{block_q} bk{block_k} {name}"
         log.append(entry)
     if not use_flash:
@@ -157,16 +175,23 @@ def causal_attention_blocked(
 
 def attention_route(q: jax.Array, k: jax.Array) -> str:
     """The implementation `dot_product_attention` gives these operands:
-    "flash" (the Pallas kernel: a TPU backend and both sequence lengths
-    whole multiples of `ROUTE_MULTIPLE`) or "xla"."""
+    "flash" (the Pallas kernel: a TPU backend and a shape `kernel_wins`
+    names) or "xla"."""
     if os.environ.get("CDT_FLASH") == "0":  # kill switch
         return "xla"
     if jax.default_backend() != "tpu":
         return "xla"
-    n, m = q.shape[1], k.shape[1]
-    if n % ROUTE_MULTIPLE == 0 and m % ROUTE_MULTIPLE == 0 and n > 0:
-        return "flash"
-    return "xla"
+    return "flash" if kernel_wins(q.shape[1], k.shape[1]) else "xla"
+
+
+def kernel_wins(n: int, m: int) -> bool:
+    """Whether q of n rows over m keys goes to the kernel on a TPU: both
+    lengths whole multiples of `ROUTE_MULTIPLE` (as since PR 28: nothing
+    is padded), or `MIN_RAGGED_KEYS` keys or more. The 77-key
+    cross-attentions and SDXL's 324-token blocks stay on XLA by this."""
+    if n <= 0 or m <= 0:
+        return False
+    return (n % ROUTE_MULTIPLE == 0 and m % ROUTE_MULTIPLE == 0) or m >= MIN_RAGGED_KEYS
 
 
 def _largest_block(length: int, cap: int) -> int:
@@ -189,25 +214,37 @@ def flash_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
     return blocks + carried + scores
 
 
-def flash_blocks(n: int, m: int, d: int, itemsize: int) -> tuple[int, int]:
-    """(block_q, block_k) for q of n rows, k/v of m rows, heads d wide
-    (as padded) and operands of `itemsize` bytes: the largest multiples
-    of `ROUTE_MULTIPLE` that divide n and m, up to the caps the sweep
-    found, shrunk (k first: it is only streamed) until a step fits
-    `VMEM_BUDGET`. Depends on nothing else, so VMEM never grows with m."""
-    if n % ROUTE_MULTIPLE or m % ROUTE_MULTIPLE or n <= 0 or m <= 0:
+def _tile(length: int, cap: int, step: int) -> tuple[int, int]:
+    """(padded length, block) of one axis under `cap`. A multiple of
+    `ROUTE_MULTIPLE` is never padded and takes its largest divisor, as
+    since PR 28. Any other length takes the fewest blocks the cap
+    allows, each the smallest multiple of `step` that covers its share,
+    so the padding is less than one `step` a block."""
+    if length % ROUTE_MULTIPLE == 0:
+        return length, _largest_block(length, cap)
+    blocks = -(-length // cap)
+    block = -(-length // (blocks * step)) * step
+    return blocks * block, block
+
+
+def flash_plan(n: int, m: int, d: int, itemsize: int) -> tuple[int, int, int, int]:
+    """(padded n, padded m, block_q, block_k) for q of n rows, k/v of m
+    rows, heads d wide (as padded) and operands of `itemsize` bytes:
+    each axis tiled (`_tile`) under the caps the sweep found, the caps
+    shrunk (k first: it is only streamed) until a step fits
+    `VMEM_BUDGET`. 1,296 x 1,296 goes as 1,296 x 1,408 (3 x 432 by 1 x
+    1,408), the VAE's 5,184 x 5,184 at d 512 as 5,280 x 5,376 (11 x 480
+    by 6 x 896). Depends on nothing else, so VMEM never grows with m."""
+    if n <= 0 or m <= 0:
         # fail loudly: a zero-length inner grid would silently return
         # an UNWRITTEN output buffer (the finalize step never fires)
-        raise ValueError(
-            f"flash_attention needs N and M to be multiples of "
-            f"{ROUTE_MULTIPLE}, got N={n}, M={m}; route via "
-            "dot_product_attention instead"
-        )
+        raise ValueError(f"flash_attention needs queries and keys, got N={n}, M={m}")
     cap_q, cap_k = MAX_BLOCK_Q, MAX_BLOCK_K
     while True:
-        block_q, block_k = _largest_block(n, cap_q), _largest_block(m, cap_k)
+        n_pad, block_q = _tile(n, cap_q, ROW_MULTIPLE)
+        m_pad, block_k = _tile(m, cap_k, ROUTE_MULTIPLE)
         if flash_vmem_bytes(block_q, block_k, d, itemsize) <= VMEM_BUDGET:
-            return block_q, block_k
+            return n_pad, m_pad, block_q, block_k
         if block_k > ROUTE_MULTIPLE:
             cap_k = block_k - ROUTE_MULTIPLE
         elif block_q > ROUTE_MULTIPLE:
@@ -227,7 +264,7 @@ def flash_attention(
     """Tiled online-softmax attention (Pallas).
 
     Grid: (batch*heads, N/block_q, M/block_k), the blocks chosen from
-    the shape by `flash_blocks`, with K/V STREAMED one (block_k, D)
+    the shape by `flash_plan`, with K/V STREAMED one (block_k, D)
     block per grid step — VMEM holds one K and one V block at a time
     regardless of sequence length (long-video sequences would blow VMEM
     if the whole K/V were block-resident). The online max/denominator/
@@ -239,15 +276,28 @@ def flash_attention(
     on every served path, whose products are exact in float32) and
     accumulate in float32; `p` is rounded to v's dtype for the second.
     Scale, max, `exp`, sum and correction stay float32.
+
+    Lengths that are no multiples of `ROUTE_MULTIPLE` are zero-padded
+    here to the lengths `flash_plan` gives. Padded query rows are
+    computed and sliced away. Padded keys are masked inside the kernel:
+    the scores of the columns from the true length on are set to -inf
+    before the running max, so a padded key weighs an exact zero (the
+    select costs nothing measurable: 1.727 ms with it, 1.739 without,
+    PR 33). Mask, pad and slice are emitted for such a call only.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, n, h, d = q.shape
-    m = k.shape[1]
-    block_q, block_k = flash_blocks(n, m, d, q.dtype.itemsize)
+    b, rows, h, d = q.shape
+    keys = k.shape[1]
+    n, m, block_q, block_k = flash_plan(rows, keys, d, q.dtype.itemsize)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if n > rows:
+        q = jnp.pad(q, ((0, 0), (0, n - rows), (0, 0), (0, 0)))
+    if m > keys:
+        widths = ((0, 0), (0, m - keys), (0, 0), (0, 0))
+        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
 
     # Fold batch and heads; kernel works on [N, D] per (bh, qblock).
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, n, d)
@@ -271,6 +321,11 @@ def flash_attention(
             q_ref[0], k_ref[0], contract_last,
             preferred_element_type=jnp.float32,
         )
+        if m > keys:
+            # the last k block's tail is padding; it always holds a key too
+            # (`_tile` pads less than a block), so no row is -inf throughout
+            cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            scores = jnp.where(cols < keys, scores, -jnp.inf)
         row_max = max_ref[...]
         new_max = jnp.maximum(row_max, scores.max(axis=-1, keepdims=True))
         correction = jnp.exp(row_max - new_max)
@@ -309,4 +364,5 @@ def flash_attention(
         name="flash_attention",  # the kernel's name in a device trace
     )(qf, kf, vf)
 
-    return out.reshape(b, h, n, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, h, n, d).transpose(0, 2, 1, 3)
+    return out[:, :rows] if n > rows else out

@@ -35,4 +35,6 @@ def test_chip_smoke_rehearsal_passes(tmp_path):
     for leg in ("serve", "restart", "attention"):
         assert any(f"leg {leg} passed" in line for line in lines), leg
     assert any("bytes identical to the first server's" in line for line in lines)
-    assert any('"route": "flash (interpreted)"' in line for line in lines)
+    # the attention child ran both routes at every toy shape, a padded one too
+    assert any('"route": "flash"' in line and "pad608x640" in line for line in lines)
+    assert any('"route": "xla"' in line and '"flash": {"entry"' in line for line in lines)

@@ -90,7 +90,7 @@ BF16_CASES = [
 )
 def test_flash_bf16_operands_match_f32_reference(q_shape, m, blocks):
     b, n, h, d = q_shape
-    assert attn.flash_blocks(n, m, d + -d % 128, 2) == blocks
+    assert attn.flash_plan(n, m, d + -d % 128, 2) == (n, m, *blocks)
     kq, kk, kv = jax.random.split(jax.random.key(n * 131 + m * 7 + d), 3)
     q = (2.0 * jax.random.normal(kq, q_shape)).astype(jnp.bfloat16)
     k = jax.random.normal(kk, (b, m, h, d)).astype(jnp.bfloat16)
@@ -106,40 +106,44 @@ def test_flash_bf16_operands_match_f32_reference(q_shape, m, blocks):
     assert err <= chip_smoke.ATTENTION_TOLERANCE * scale, (err, scale)
 
 
-def test_flash_blocks_divide_align_and_fit():
+def test_flash_plan_divides_aligns_and_fits():
     shapes = [
         (q_shape[1], m, q_shape[3]) for _, q_shape, m in chip_smoke.SERVED_SHAPES
     ]
     assert (4608, 4608, 128) in shapes  # FLUX's joint attention
+    assert (1296, 1296, 64) in shapes and (324, 77, 64) in shapes  # SDXL's tile
     # plus lengths with awkward divisors and a video-length key sequence
     shapes += [(128 * 37, 128 * 37, 64), (256, 128 * 257, 128), (128, 32768, 128)]
-    reached = 0
+    # and other tile sizes' lengths, one row, one key
+    shapes += [(10816, 10816, 64), (2704, 2704, 64), (676, 77, 64), (1, 1, 128), (257, 257, 64)]
     for n, m, d in shapes:
-        if n % attn.ROUTE_MULTIPLE or m % attn.ROUTE_MULTIPLE:
-            continue  # routed to XLA (every SDXL tile shape, cross-attention)
-        reached += 1
         padded = d + -d % 128
         for itemsize in (2, 4):
-            block_q, block_k = attn.flash_blocks(n, m, padded, itemsize)
-            assert n % block_q == 0 and m % block_k == 0
-            assert block_q % 128 == 0 and block_k % 128 == 0
+            n_pad, m_pad, block_q, block_k = attn.flash_plan(n, m, padded, itemsize)
+            assert n_pad % block_q == 0 and m_pad % block_k == 0
+            assert block_q % attn.ROW_MULTIPLE == 0 and block_k % 128 == 0
             assert block_q <= attn.MAX_BLOCK_Q and block_k <= attn.MAX_BLOCK_K
             assert (
                 attn.flash_vmem_bytes(block_q, block_k, padded, itemsize)
                 <= attn.VMEM_BUDGET
             )
-    assert reached >= 9
-    # what the sweep chose for the two shapes that carry the benchmark
-    assert attn.flash_blocks(4608, 4608, 128, 2) == (512, 1536)
-    assert attn.flash_blocks(4096, 4096, 128, 2) == (512, 1024)
+            # a multiple of 128 is never padded; any other length by less
+            # than one step a block, so the last k block always holds a key
+            assert n_pad == n if n % 128 == 0 else 0 <= n_pad - n < 16 * (n_pad // block_q)
+            assert m_pad == m if m % 128 == 0 else 0 < m_pad - m < 128 * (m_pad // block_k)
+            assert 0 < m - (m_pad - block_k) <= block_k
+    # what the sweeps chose for the shapes that carry the benchmark
+    assert attn.flash_plan(4608, 4608, 128, 2) == (4608, 4608, 512, 1536)
+    assert attn.flash_plan(4096, 4096, 128, 2) == (4096, 4096, 512, 1024)
+    assert attn.flash_plan(1296, 1296, 128, 2) == (1296, 1408, 432, 1408)
+    assert attn.flash_plan(5184, 5184, 512, 2) == (5280, 5376, 480, 896)
 
 
-@pytest.mark.parametrize("n,m", [(1296, 1296), (256, 77), (100, 128), (0, 128)])
-def test_flash_refuses_lengths_off_the_routing_multiple(n, m):
-    with pytest.raises(ValueError, match="multiples of 128"):
-        attn.flash_blocks(n, m, 128, 2)
-    if n:
-        q = jnp.ones((1, n, 1, 128), jnp.bfloat16)
-        k = jnp.ones((1, m, 1, 128), jnp.bfloat16)
-        with pytest.raises(ValueError, match="multiples of 128"):
-            attn.flash_attention(q, k, k, interpret=True)
+@pytest.mark.parametrize("n,m", [(0, 128), (128, 0)])
+def test_flash_refuses_an_empty_axis(n, m):
+    with pytest.raises(ValueError, match="needs queries and keys"):
+        attn.flash_plan(n, m, 128, 2)
+    q = jnp.ones((1, n, 1, 128), jnp.bfloat16)
+    k = jnp.ones((1, m, 1, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="needs queries and keys"):
+        attn.flash_attention(q, k, k, interpret=True)

@@ -14,11 +14,10 @@ import pytest
 
 from comfyui_distributed_tpu.ops import attention as attn
 
-# chip_smoke.SERVED_SHAPES whose lengths reach the kernel
-SHAPES = [
-    (label, q_shape, m) for label, q_shape, m in chip_smoke.SERVED_SHAPES
-    if q_shape[1] % attn.ROUTE_MULTIPLE == 0 and m % attn.ROUTE_MULTIPLE == 0
-]
+# Every served shape is compiled on the kernel, the ones the shape rule
+# leaves to XLA too: the rule rests on both routes' times, which
+# `chip_smoke.py`'s attention leg takes on the chip.
+SHAPES = list(chip_smoke.SERVED_SHAPES)
 
 
 @pytest.fixture(scope="module")
@@ -35,10 +34,13 @@ def one_chip():
 
 
 def test_the_served_shapes_that_reach_the_kernel():
-    labels = [label for label, _, _ in SHAPES]
-    assert "flux joint 4608" in labels and "flux vae mid 128x128" in labels
-    assert "sd15 self 64x64" in labels and "sd15 vae mid 64x64" in labels
-    assert not any(label.startswith("sdxl") for label in labels)
+    assert [
+        label for label, q_shape, m in SHAPES if attn.kernel_wins(q_shape[1], m)
+    ] == [
+        "sd15 self 64x64", "sd15 self 32x32", "sd15 self 16x16", "sd15 vae mid 64x64",
+        "sdxl tile self 36x36", "sdxl tile vae mid 72x72",
+        "flux joint 4608", "flux vae mid 128x128",
+    ]
 
 
 @pytest.mark.parametrize("name,dtype", [("bf16", jnp.bfloat16), ("f32", jnp.float32)])
@@ -53,23 +55,43 @@ def test_kernel_compiles_for_v5e_at_served_shape(one_chip, q_shape, m, name, dty
     with attn.route_log() as routes:
         compiled = fn.lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    block_q, block_k = attn.flash_blocks(n, m, d + -d % 128, jnp.dtype(dtype).itemsize)
-    assert routes == [f"flash {n}x{m}x{d} bq{block_q} bk{block_k} {name}"]
+    n_pad, m_pad, block_q, block_k = attn.flash_plan(
+        n, m, d + -d % 128, jnp.dtype(dtype).itemsize
+    )
+    pad = "" if (n_pad, m_pad) == (n, m) else f" pad{n_pad}x{m_pad}"
+    assert routes == [f"flash {n}x{m}x{d}{pad} bq{block_q} bk{block_k} {name}"]
+
+
+def test_kernel_compiles_for_v5e_under_the_tile_axis_vmap(one_chip):
+    """The scan tier reaches the kernel under `jax.vmap` over 8 tiles
+    (`ops/upscale._scan_tiles`), CFG's batch of 2 inside: the batching
+    rule puts the tile axis in front of the grid, one kernel as before."""
+    q = jax.ShapeDtypeStruct((8, 2, 1296, 10, 64), jnp.bfloat16, sharding=one_chip)
+    fn = jax.jit(jax.vmap(functools.partial(attn.dot_product_attention, force_flash=True)))
+    text = fn.lower(q, q, q).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_route_log_entries():
-    """What the sampler node writes into its span as `attention`: the
-    blocks and operand dtype for a flash call, `xla` entries as ever."""
+    """What the sampler and upscale nodes write into their spans as
+    `attention`: the blocks and operand dtype for a flash call, the
+    padded lengths too where a length was padded, `xla` entries as ever."""
     flux = jax.ShapeDtypeStruct((1, 4608, 24, 128), jnp.bfloat16)
     sd15 = jax.ShapeDtypeStruct((2, 4096, 8, 40), jnp.bfloat16)
     text = jax.ShapeDtypeStruct((2, 77, 8, 40), jnp.bfloat16)
+    sdxl = jax.ShapeDtypeStruct((16, 1296, 10, 64), jnp.bfloat16)
+    vae = jax.ShapeDtypeStruct((8, 5184, 1, 512), jnp.bfloat16)
     flash = functools.partial(attn.dot_product_attention, force_flash=True)
     with attn.route_log() as routes:
         jax.eval_shape(flash, flux, flux, flux)
         jax.eval_shape(flash, sd15, sd15, sd15)
         jax.eval_shape(attn.dot_product_attention, sd15, text, text)
+        jax.eval_shape(flash, sdxl, sdxl, sdxl)
+        jax.eval_shape(flash, vae, vae, vae)
     assert routes == [
         "flash 4608x4608x128 bq512 bk1536 bf16",
         "flash 4096x4096x40 bq512 bk1024 bf16",
         "xla 4096x77x40",
+        "flash 1296x1296x64 pad1296x1408 bq432 bk1408 bf16",
+        "flash 5184x5184x512 pad5280x5376 bq480 bk896 bf16",
     ]
