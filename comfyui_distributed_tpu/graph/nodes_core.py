@@ -96,8 +96,8 @@ class CheckpointLoaderSimple:
     FUNCTION = "load"
 
     def load(self, ckpt_name: str, context=None):
-        # strip file extensions so ComfyUI workflow values map to registry names
-        name = os.path.splitext(str(ckpt_name))[0]
+        from .nodes_loaders import _stem  # no extension; a registry name ("ouro-2.6b") whole
+        name = _stem(ckpt_name)
         bundle = _get_bundle(context, name)
         _annotate_load(bundle)
         return (bundle, bundle, bundle)

@@ -26,10 +26,13 @@ from .registry import register_node
 
 def _stem(name: str) -> str:
     """Workflow values carry filenames ('clip_l.safetensors'); registry
-    names are stems. Underscores normalize to the registry's hyphens
-    only when the literal name is unknown."""
+    names are stems. A registry name is taken whole ('ouro-2.6b' ends in
+    no extension). Underscores normalize to the registry's hyphens only
+    when the literal name is unknown."""
     from ..models.registry import MODEL_REGISTRY
 
+    if str(name) in MODEL_REGISTRY:
+        return str(name)
     base = os.path.splitext(str(name))[0]
     if base in MODEL_REGISTRY:
         return base

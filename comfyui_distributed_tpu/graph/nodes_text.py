@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..ops.attention import route_log as attention_route_log
 from .nodes_core import resolve_seed
@@ -25,8 +24,10 @@ def generate_tokens(bundle, ids, seed: int, steps: int, temperature: float,
     """The served path's two programs, dispatched and not waited for:
     the prefill of `ids` and `steps` decode steps drawn from `seed`.
     Returns what they return, device arrays: the model's `Prefill` and
-    `Decode`. `collect` (the parity check's) also keeps every step's
-    logits and chosen experts, in programs of their own."""
+    `Decode` (a model whose decode takes the cache by donation leaves
+    `prefill.cache` deleted). `collect` (the parity check's) also keeps
+    what the model compares with its reference, every step's logits
+    among it, in programs of their own."""
     from ..telemetry import get_tracer
 
     tracer = get_tracer()
@@ -70,9 +71,10 @@ class TextGenerate:
     def generate(self, clip, text, seed, max_new_tokens=256, temperature=1.0,
                  context=None):
         from ..telemetry import get_tracer
-        from ..telemetry.instruments import lm_tokens_total
+        from ..telemetry.instruments import lm_layer_passes_total, lm_tokens_total
 
-        if getattr(clip, "lm", None) is None:
+        lm = getattr(clip, "lm", None)
+        if lm is None:
             raise ValueError(
                 "TextGenerate needs the CLIP output of a checkpoint that holds a "
                 f"language model; {clip.model_name!r} holds none"
@@ -87,26 +89,18 @@ class TextGenerate:
         # the one read-back: the executor thread parks here until the
         # device has run both programs
         with tracer.span("device.wait") as wait:
-            new_ids, prefill_loads, decode_loads = jax.device_get(
-                (decode.ids, prefill.loads, decode.loads)
-            )
-            wait.attrs["bytes"] = int(
-                new_ids.nbytes + prefill_loads.nbytes + decode_loads.nbytes
-            )
+            new_ids, *read = jax.device_get((decode.ids, *lm.read_back(prefill, decode)))
+            wait.attrs["bytes"] = int(new_ids.nbytes + sum(a.nbytes for a in read))
         with tracer.span("lm.detokenize"):
             out = clip.tokenizer.decode(new_ids)
-        pairs_a_token = prefill_loads.shape[0] * clip.lm.cfg.num_experts_per_tok
         attrs = dict(
             prompt_tokens=len(ids), new_tokens=steps,
-            **clip.lm.describe(len(ids) + steps, prefill.cache.dtype.itemsize),
+            **lm.describe(len(ids) + steps, prefill.cache.dtype.itemsize),
+            **lm.report(len(ids), steps, *read),
         )
-        for phase, tokens, loads in (
-            ("prefill", len(ids), prefill_loads), ("decode", steps, decode_loads)
-        ):
-            attrs[f"{phase}_routed_pairs"] = tokens * pairs_a_token
-            attrs[f"{phase}_routed_pairs_held"] = int(np.sum(loads))
-            attrs[f"{phase}_expert_load_max"] = int(np.max(loads))
+        for phase, tokens in (("prefill", len(ids)), ("decode", steps)):
             lm_tokens_total().inc(tokens, phase=phase)
+            lm_layer_passes_total().inc(tokens * lm.layer_passes, phase=phase)
         if routes:
             # only the request that traced the programs gets here with
             # anything: which implementation the prefill's attention took

@@ -42,6 +42,15 @@ import numpy as np
 
 from ..ops.attention import dot_product_attention
 from ..parallel.sharding import expert_range
+from .lm_common import (  # noqa: F401  (the names this module has always had)
+    ByteTokenizer,
+    apply_rope,
+    count_params,
+    init_from_shapes,
+    rms_norm,
+    sample,
+    swiglu,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,18 +159,6 @@ def rope_tables(cfg: DeepSeekV2Config, positions: jax.Array):
     return jnp.cos(angles) * m, jnp.sin(angles) * m
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate [..., T, (heads,) rope] by its position, the two halves of
-    the last axis as the pair's members (`rotate_half`)."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    if x.ndim == cos.ndim + 1:  # a heads axis between T and rope
-        cos, sin = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
-
-
 # --- parameters -----------------------------------------------------------
 
 
@@ -211,56 +208,16 @@ def param_shapes(cfg: DeepSeekV2Config) -> dict[str, Any]:
     }
 
 
-def _is_spec(x) -> bool:
-    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
-
-
 def param_count(cfg: DeepSeekV2Config) -> int:
-    specs = jax.tree_util.tree_leaves(param_shapes(cfg), is_leaf=_is_spec)
-    return sum(math.prod(shape) for shape, _ in specs)
-
-
-@partial(jax.jit, static_argnames=("shape", "std", "dtype"))
-def _normal(key, shape, std, dtype):
-    if len(shape) == 3 and math.prod(shape) >= 2**28:
-        # a stack of experts, one at a time: the float32 draw of a whole
-        # stack (2.5 GB at the published widths) is never alive at once
-        keys = jax.random.split(key, shape[0])
-        return jax.lax.map(
-            lambda k: (jax.random.normal(k, shape[1:], jnp.float32) * std).astype(dtype), keys
-        )
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+    return count_params(param_shapes(cfg))
 
 
 def init_params(cfg: DeepSeekV2Config, key, dtype=jnp.float32) -> dict[str, Any]:
-    """Seeded random weights built in `dtype`, weight by weight: normal
-    with standard deviation fan_in^-1/2, so activations keep their scale
-    through the depth and the router's logits spread by about one."""
-    specs, treedef = jax.tree_util.tree_flatten(param_shapes(cfg), is_leaf=_is_spec)
-    dtype = jnp.dtype(dtype)
-    leaves = []
-    for index, (shape, fan_in) in enumerate(specs):
-        if fan_in is None:
-            leaves.append(jnp.ones(shape, dtype))
-        else:
-            leaves.append(
-                _normal(jax.random.fold_in(key, index), shape, float(fan_in) ** -0.5, dtype)
-            )
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    """Seeded random weights in `dtype` (`lm_common.init_from_shapes`)."""
+    return init_from_shapes(param_shapes(cfg), key, dtype)
 
 
 # --- blocks ---------------------------------------------------------------
-
-
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (normed * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def swiglu(x: jax.Array, p: dict) -> jax.Array:
-    gate, up = jnp.split(x @ p["w_gate_up"], 2, axis=-1)
-    return (jax.nn.silu(gate) * up) @ p["w_down"]
 
 
 def _queries(cfg, p, x, cos, sin):
@@ -471,14 +428,6 @@ def decode_step(cfg, params, cache, token, position):
     return _head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
 
 
-def sample(logits, key, temperature):
-    """The next id from float32 logits: the largest at temperature 0,
-    else a draw from softmax(logits / temperature). `temperature` is a
-    traced scalar, so every value runs the one program."""
-    drawn = jax.random.categorical(key, logits / jnp.where(temperature > 0, temperature, 1.0))
-    return jnp.where(temperature > 0, drawn, jnp.argmax(logits)).astype(jnp.int32)
-
-
 @partial(jax.jit, static_argnames=("cfg", "steps", "collect"))
 def decode(cfg: DeepSeekV2Config, params, cache, logits, start, key, temperature, *,
            steps: int, collect: bool = False):
@@ -511,49 +460,18 @@ def decode(cfg: DeepSeekV2Config, params, cache, logits, start, key, temperature
     return Decode(ids, loads, *kept)
 
 
-# --- the stand-in tokenizer -----------------------------------------------
-
-
-class ByteTokenizer:
-    """A stand-in for the published tokenizer, which is not in the
-    sandbox: deterministic and byte-level, into the first ids of the
-    vocabulary's slice. `encode`: id 0 (begin of sentence), then 1 + b
-    for each byte b of the text's UTF-8. `decode`: ids 1..256 give their
-    byte where it is printable ASCII and nothing otherwise; id 0 gives
-    nothing; every other id n gives a space and then n - 257 written in
-    base 26 with the letters a..z, least digit first. So any ids come
-    back as lower-case words that CLIP's BPE can tokenise."""
-
-    BOS = 0
-    BYTES = 256
-
-    def encode(self, text: str) -> list[int]:
-        return [self.BOS] + [1 + b for b in text.encode("utf-8")]
-
-    def decode(self, ids) -> str:
-        pieces = []
-        for n in map(int, ids):
-            if n == self.BOS:
-                continue
-            if n <= self.BYTES:
-                pieces.append(chr(n - 1) if 32 <= n - 1 < 127 else "")
-                continue
-            n -= self.BYTES + 1
-            word = chr(97 + n % 26)
-            while n >= 26:
-                n //= 26
-                word += chr(97 + n % 26)
-            pieces.append(" " + word)
-        return "".join(pieces).strip()
-
-
 class DeepSeekV2:
-    """What a bundle's `lm` part is: the configuration with the two
-    programs bound to it, and what a node reports of them."""
+    """What a bundle's `lm` part is (the contract is in `lm_common`): the
+    configuration with the two programs bound to it, and what a node
+    reads back and reports of them."""
 
     def __init__(self, cfg: DeepSeekV2Config):
         self.cfg = cfg
         self.tokenizer = ByteTokenizer()
+
+    @property
+    def layer_passes(self) -> int:
+        return self.cfg.num_hidden_layers
 
     def init(self, key, dtype=jnp.float32):
         return init_params(self.cfg, key, dtype)
@@ -568,6 +486,10 @@ class DeepSeekV2:
             steps=steps, collect=collect,
         )
 
+    def read_back(self, prefill: Prefill, decode: Decode) -> tuple:
+        """The pairs on each held expert, of either program."""
+        return prefill.loads, decode.loads
+
     def describe(self, cache_len: int, itemsize: int) -> dict[str, int]:
         cfg = self.cfg
         return {
@@ -576,3 +498,16 @@ class DeepSeekV2:
             "experts_total": cfg.n_routed_experts,
             "cache_bytes": cfg.num_hidden_layers * cache_len * cfg.cache_width * itemsize,
         }
+
+    def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
+        """Per phase: the token-expert pairs the router made, those that
+        fell on held experts, and the fullest held expert's."""
+        pairs_a_token = _moe_layers(self.cfg) * self.cfg.num_experts_per_tok
+        attrs = {}
+        for phase, tokens, loads in (
+            ("prefill", prompt_tokens, prefill_loads), ("decode", new_tokens, decode_loads)
+        ):
+            attrs[f"{phase}_routed_pairs"] = tokens * pairs_a_token
+            attrs[f"{phase}_routed_pairs_held"] = int(np.sum(loads))
+            attrs[f"{phase}_expert_load_max"] = int(np.max(loads))
+        return attrs

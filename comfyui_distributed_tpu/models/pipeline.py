@@ -541,10 +541,11 @@ def load_pipeline(
 
 
 def load_language_model(model_name: str, seed: int = 0) -> PipelineBundle:
-    """A bundle that holds a language model and nothing else: seeded
-    random weights built in their storage dtype (10.3 GB in bfloat16 for
-    the benchmark's share of DeepSeek-V2; no float32 copy is ever made),
-    `tokenizer` the model's own."""
+    """A bundle that holds a language model and nothing else (any model
+    of family `lm`: the registry picks the class from the configuration's
+    type): seeded random weights built in their storage dtype, weight by
+    weight (10.3 GB in bfloat16 for the largest the benchmark loads; no
+    float32 copy is ever made), `tokenizer` the model's own."""
     lm = create_model(model_name)
     dtype = params_storage_dtype() or jnp.float32
     return PipelineBundle(

@@ -18,6 +18,7 @@ from .clip_vision import ClipVisionConfig, ClipVisionEncoder
 from .deepseek_v2 import DeepSeekV2, DeepSeekV2Config
 from .dit import DiTConfig, VideoDiT
 from .mmdit import MMDiT, MMDiTConfig
+from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
 from .t5_encoder import T5Encoder, T5EncoderConfig
 from .text_encoder import TextEncoder, TextEncoderConfig
@@ -479,6 +480,18 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             vocab_shards=4,
         ),
     },
+    # Ouro-2.6B whole: every field the published value (48 layers walked
+    # 4 times, 49,152 ids), 2,667,974,657 parameters
+    "ouro-2.6b": {"family": "lm", "config": OuroConfig()},
+    # the loop at a size for the CPU: 4 passes kept, 3 layers, narrow
+    "tiny-ouro": {
+        "family": "lm",
+        "config": OuroConfig(
+            hidden_size=64, num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=4, head_dim=16, intermediate_size=160,
+            vocab_size=2048,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -525,7 +538,14 @@ _CONSTRUCTORS: dict[str, Callable[[Any], Any]] = {
     "t5_encoder": lambda cfg: T5Encoder(cfg),
     "clip_vision": lambda cfg: ClipVisionEncoder(cfg),
     "video_vae": lambda cfg: VideoVAE(cfg),
-    "lm": lambda cfg: DeepSeekV2(cfg),
+    "lm": lambda cfg: _LANGUAGE_MODELS[type(cfg)](cfg),
+}
+
+# family "lm" holds more than one architecture: the configuration's type
+# says which (each meets the contract in `lm_common`)
+_LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
+    DeepSeekV2Config: DeepSeekV2,
+    OuroConfig: Ouro,
 }
 
 

@@ -878,6 +878,16 @@ def lm_tokens_total() -> Counter:
     )
 
 
+def lm_layer_passes_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_lm_layer_passes_total",
+        "Layer bodies a language model's tokens walked through: "
+        "cdt_lm_tokens_total times the layers a token passes (a looped "
+        "model's layers count once for each loop step)",
+        ("phase",),
+    )
+
+
 def tile_jobs_active() -> Gauge:
     return get_metrics_registry().gauge(
         "cdt_tile_jobs_active",
