@@ -20,9 +20,12 @@ Drives the main path once, through the entry points an operator uses:
                bytes as before the restart.
     attention  one child that holds the chip runs the attention
                dispatcher, compiled, at the shapes the served workflows
-               produce, on both routes (the Pallas kernel and XLA),
-               each against a float32 reference, and prints which
-               route the shape rule gives each shape and both times.
+               produce, on both routes (the Pallas kernel and XLA)
+               and on the kernel in the layout it had until PR 35
+               (heads folded into the batch by transpositions), each
+               between the [B, N, H*D] arrays a model holds and against
+               a float32 reference, and prints which route the shape
+               rule gives each shape and all three times.
     multichip  only where the server reports two or more chips: the
                serve leg has then already run on every chip through
                the in-process mesh; this leg checks that, and runs the
@@ -823,6 +826,22 @@ REHEARSAL_SHAPES = (
 )
 
 
+def transposed(attend):
+    """`attend` ([B, N, H, D] attention) in the layout the kernel had
+    until PR 35, kept as the comparison (and as the tests' reference):
+    heads folded into the batch by a transposition before the call and
+    unfolded after it. At one head the kernel's view of its operands is
+    the identity, so this is that program: grid, blocks, arithmetic."""
+    def call(q, k, v):
+        b, n, h, d = q.shape
+
+        def fold(x):
+            return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], 1, d)
+
+        return attend(fold(q), fold(k), fold(v)).reshape(b, h, n, d).transpose(0, 2, 1, 3)
+    return call
+
+
 def attention_child(rehearsal: bool) -> int:
     import jax
     import jax.numpy as jnp
@@ -879,7 +898,7 @@ def attention_child(rehearsal: bool) -> int:
 
         q, k, v = operands(jax.random.key(n * 131 + m * 7 + d))
         # the route the rule gives the shape on a TPU (the CPU never
-        # routes to the kernel by itself), then both routes whatever the
+        # routes to the kernel by itself), then every route whatever the
         # rule says: the rule rests on their times
         route = "flash" if attention.kernel_wins(n, m) else "xla"
         row = {
@@ -888,15 +907,28 @@ def attention_child(rehearsal: bool) -> int:
         }
         if not rehearsal and attention.attention_route(q, k) != route:
             row["ok"] = False
-        for name in ("flash", "xla"):
-            flash = name == "flash"
-            fn = jax.jit(functools.partial(
+
+        def as_served(attend, b=b, n=n, m=m, h=h, d=d):
+            """`attend` between the [B, N, H*D] a model's linears give
+            and take: what a layout costs shows only from there."""
+            def call(q, k, v):
+                out = attend(
+                    q.reshape(b, n, h, d), k.reshape(b, m, h, d), v.reshape(b, m, h, d)
+                )
+                return out.reshape(b, n, h * d)
+            return jax.jit(call)
+
+        flat = lambda x: x.reshape(*x.shape[:2], h * d)
+        for name in ("flash", "xla", "transposed"):
+            flash = name != "xla"
+            attend = functools.partial(
                 attention.dot_product_attention, force_flash=flash,
                 interpret=flash and rehearsal,
-            ))
+            )
+            fn = as_served(transposed(attend) if name == "transposed" else attend)
             with attention.route_log() as routes:
-                out, first_s, ms = timed(fn, q, k, v)
-            err, ref_max = (float(x) for x in errors(out, q, k, v))
+                out, first_s, ms = timed(fn, flat(q), flat(k), flat(v))
+            err, ref_max = (float(x) for x in errors(out.reshape(q.shape), q, k, v))
             scale = max(1.0, ref_max)
             row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
             row[name] = {

@@ -44,6 +44,7 @@ import numpy as np
 
 from .layers import timestep_embedding
 from .dit import _axis_freqs, apply_rope
+from ..ops.qk_norm_rope import norm_rope, norm_rope_route
 from ..ops.attention import dot_product_attention
 
 
@@ -159,13 +160,35 @@ def _modulation(vec: jax.Array, n: int, width: int, name: str) -> list[jax.Array
     return [out[:, None, i * width:(i + 1) * width] for i in range(n)]
 
 
-def _qk_norm(q: jax.Array, k: jax.Array, name: str) -> tuple[jax.Array, jax.Array]:
-    """Per-head RMS norm over head_dim ([..., H, D] inputs); scale
-    params are [D] — the Flux query_norm/key_norm.scale layout."""
+def _qk_norm_rope(
+    proj: jax.Array, freqs: jax.Array, heads: int, dtype: jnp.dtype, name: str,
+) -> tuple[jax.Array, jax.Array]:
+    """q and k out of a block's projection `proj` ([B, N, >= 2*dim]: q
+    in the first `dim` lanes, k in the next), each head RMS-normed over
+    head_dim (scale params are [D] — the Flux query_norm/key_norm.scale
+    layout), rounded to `dtype` and rotated by `freqs` [N, D/2, 2];
+    [B, N, H, D] each. On a TPU one Pallas pass a tensor
+    (`ops/qk_norm_rope.norm_rope`: it reads the heads where the linear
+    left them and writes them where the attention kernel reads them),
+    elsewhere the XLA operations it stands for."""
     prefix = f"{name}_" if name else ""
-    qn = nn.RMSNorm(epsilon=1e-6, dtype=jnp.float32, name=f"{prefix}norm_q")(q)
-    kn = nn.RMSNorm(epsilon=1e-6, dtype=jnp.float32, name=f"{prefix}norm_k")(k)
-    return qn, kn
+    b, n, _ = proj.shape
+    hd = 2 * freqs.shape[1]
+    dim = heads * hd
+    out = []
+    for i, which in enumerate("qk"):
+        norm = nn.RMSNorm(epsilon=1e-6, dtype=jnp.float32, name=f"{prefix}norm_{which}")
+        if norm_rope_route(n, heads, hd, i * dim) == "pallas":
+            norm(jnp.zeros((1, hd), jnp.float32))  # declares `scale`; no work is left of it
+            x = norm_rope(
+                proj, norm.variables["params"]["scale"], freqs,
+                heads=heads, offset=i * dim, epsilon=norm.epsilon,
+            )
+        else:
+            x = proj[..., i * dim:(i + 1) * dim].reshape(b, n, heads, hd)
+            x = apply_rope(norm(x).astype(dtype), freqs)
+        out.append(x.reshape(b, n, heads, hd))
+    return out[0], out[1]
 
 
 class _DoubleBlock(nn.Module):
@@ -192,26 +215,21 @@ class _DoubleBlock(nn.Module):
         i_sh1, i_sc1, i_g1, i_sh2, i_sc2, i_g2 = _modulation(vec, 6, dim, "img_mod")
         t_sh1, t_sc1, t_g1, t_sh2, t_sc2, t_g2 = _modulation(vec, 6, dim, "txt_mod")
 
-        def qkv(x, n, sh, sc, name):
+        def qkv(x, n, sh, sc, rope, name):
             h = nn.LayerNorm(
                 use_bias=False, use_scale=False, dtype=jnp.float32,
                 name=f"{name}_norm1",
             )(x.astype(jnp.float32))
             h = (h * (1 + sc) + sh).astype(self.dtype)
             proj = nn.Dense(3 * dim, dtype=self.dtype, name=f"{name}_attn_qkv")(h)
-            q, k, v = jnp.split(proj, 3, axis=-1)
-            q = q.reshape(b, n, self.heads, hd)
-            k = k.reshape(b, n, self.heads, hd)
-            v = v.reshape(b, n, self.heads, hd)
-            q, k = _qk_norm(q, k, f"{name}_attn")
-            return q.astype(self.dtype), k.astype(self.dtype), v
-
-        iq, ik, iv = qkv(img, ni, i_sh1, i_sc1, "img")
-        tq, tk, tv = qkv(txt, nt, t_sh1, t_sc1, "txt")
+            q, k = _qk_norm_rope(proj, rope, self.heads, self.dtype, f"{name}_attn")
+            return q, k, proj[..., 2 * dim:].reshape(b, n, self.heads, hd)
 
         # joint attention, text tokens first (Flux token order)
-        q = apply_rope(jnp.concatenate([tq, iq], axis=1), freqs)
-        k = apply_rope(jnp.concatenate([tk, ik], axis=1), freqs)
+        iq, ik, iv = qkv(img, ni, i_sh1, i_sc1, freqs[nt:], "img")
+        tq, tk, tv = qkv(txt, nt, t_sh1, t_sc1, freqs[:nt], "txt")
+        q = jnp.concatenate([tq, iq], axis=1)
+        k = jnp.concatenate([tk, ik], axis=1)
         v = jnp.concatenate([tv, iv], axis=1)
         with jax.named_scope("joint_attn"):
             attn = dot_product_attention(q, k, v).reshape(b, nt + ni, dim)
@@ -263,14 +281,10 @@ class _SingleBlock(nn.Module):
         fused = nn.Dense(
             3 * dim + self.mlp_width, dtype=self.dtype, name="linear1"
         )(h)
-        qkv, mlp = fused[..., : 3 * dim], fused[..., 3 * dim:]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, n, self.heads, hd)
-        k = k.reshape(b, n, self.heads, hd)
-        v = v.reshape(b, n, self.heads, hd)
-        q, k = _qk_norm(q, k, "")  # single_blocks.N.norm.{query,key}_norm
-        q = apply_rope(q.astype(self.dtype), freqs)
-        k = apply_rope(k.astype(self.dtype), freqs)
+        mlp = fused[..., 3 * dim:]
+        # single_blocks.N.norm.{query,key}_norm
+        q, k = _qk_norm_rope(fused, freqs, self.heads, self.dtype, "")
+        v = fused[..., 2 * dim:3 * dim].reshape(b, n, self.heads, hd)
         with jax.named_scope("joint_attn"):
             attn = dot_product_attention(q, k, v).reshape(b, n, dim)
         out = nn.Dense(dim, dtype=self.dtype, name="linear2")(

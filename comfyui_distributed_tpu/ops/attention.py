@@ -45,7 +45,7 @@ VMEM_BUDGET = 12 * 2**20
 ROW_MULTIPLE = 16
 # Off the multiple, the kernel takes a call from this many keys on: XLA's
 # cost is the float32 scores, 12 bytes a key for every q row, the kernel's
-# the lane padding and the [B,N,H,D] <-> [BH,N,D] copies, about the same
+# the lane padding and the copy that pads a narrow head, about the same
 # for every row. On a v5e at 64-wide heads (batch 16, PR 33) XLA wins at
 # 324 keys (0.60 ms against 0.76), 400 and 484 are ties, and the kernel
 # wins from 500 (1.25 against 1.47) and 576 (0.66 against 0.81) on.
@@ -61,9 +61,12 @@ _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
 def route_log():
     """Collect one entry for every `dot_product_attention` call made
     inside the block: `xla NxMxD`, or `flash NxMxD [pad<N'>x<M'>]
-    bq<block_q> bk<block_k> <operand dtype>` with what the kernel chose
-    for the shape (`pad` only where a length was padded: `flash
-    1296x1296x64 pad1296x1408 bq432 bk1408 bf16`). Calls happen while a
+    bq<block_q> bk<block_k> <operand dtype> [inplace]` with what the
+    kernel chose for the shape (`pad` only where a length was padded,
+    `inplace` where it reads the heads where the caller left them, a
+    width that is a multiple of 128: `flash 4608x4608x128 bq512 bk1536
+    bf16 inplace`, `flash 1296x1296x64 pad1296x1408 bq432 bk1408
+    bf16`). Calls happen while a
     program is traced, so a block around a jitted call fills only on
     the request that builds the program; the graph's sampler and
     upscale nodes read it into their spans."""
@@ -94,10 +97,12 @@ def dot_product_attention(
     kernel's numerics on the CPU and are never inferred — on a TPU the
     kernel is compiled, and a kernel that does not compile is an error.
 
-    Head dims that aren't lane-aligned (SD1.5 uses 40/80/160) are
-    zero-padded to the 128 lane width before the kernel — the MXU pads
-    those lanes anyway, so this costs nothing extra — with the softmax
-    scale pinned to the ORIGINAL head dim and the output sliced back.
+    Head dims that aren't lane-aligned (SD1.5 uses 40/80/160, SDXL 64)
+    are zero-padded to the 128 lane width inside `flash_attention`, with
+    the softmax scale pinned to the ORIGINAL head dim and the output
+    sliced back. That is not free: the kernel reads, multiplies and
+    writes the padded lanes too, 3.2 x the model's bytes at 40 wide and
+    2 x at 64 (packing narrow heads into one lane tile is ROADMAP S2).
     """
     if causal:
         return causal_attention_blocked(q, k, v, scale=scale)
@@ -118,17 +123,10 @@ def dot_product_attention(
             name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
             if (n_pad, m_pad) != (n, m):
                 entry += f" pad{n_pad}x{m_pad}"
-            entry += f" bq{block_q} bk{block_k} {name}"
+            entry += f" bq{block_q} bk{block_k} {name}" + ("" if pad else " inplace")
         log.append(entry)
     if not use_flash:
         return jax.nn.dot_product_attention(q, k, v)
-    if pad:
-        widths = ((0, 0), (0, 0), (0, 0), (0, pad))
-        out = flash_attention(
-            jnp.pad(q, widths), jnp.pad(k, widths), jnp.pad(v, widths),
-            scale=1.0 / math.sqrt(d), interpret=interpret,
-        )
-        return out[..., :d]
     return flash_attention(q, k, v, interpret=interpret)
 
 
@@ -272,13 +270,31 @@ def flash_attention(
     (sequential, "arbitrary") grid dimension; the output block is
     written on the last K step.
 
+    Heads are read and written where the caller left them: q, k, v and
+    the output are [B, N, H*D] to the kernel (a reshape of the two minor
+    axes, no data moves) and grid index `bh` takes the (1, block, D)
+    block at (bh // H, row block, bh % H), so nothing is transposed in
+    HBM on either side. A block's rows are then D lanes out of H*D: on
+    a v5e the kernel itself runs 6-11 % slower for it at FLUX's 24 x 128
+    (2.07-2.18 ms a call for 1.96), and the four transpositions it no
+    longer needs cost 0.36 ms (PERF.md §6, PR 35). A width off the
+    lane tile is another case: its pad rewrites every byte anyway, and
+    the copy that pads also puts the heads in front of the tokens (a
+    tiled layout holds 40 lanes in 128, so the compiler pads for free
+    while it transposes), after which the kernel takes [B*H, N, D] and
+    reads whole rows. In place such a head costs three passes an operand
+    instead of one (SD1.5's 4,096 x 40: 1.36 ms a call in place, 1.15
+    folded; its closed2 cell 3.5 % slower), so `fold` below follows
+    from the width and nothing else.
+
     Both dots take their operands in the dtype they arrive in (bfloat16
     on every served path, whose products are exact in float32) and
     accumulate in float32; `p` is rounded to v's dtype for the second.
     Scale, max, `exp`, sum and correction stay float32.
 
     Lengths that are no multiples of `ROUTE_MULTIPLE` are zero-padded
-    here to the lengths `flash_plan` gives. Padded query rows are
+    here to the lengths `flash_plan` gives, a narrow head's lanes in
+    the same pad; `scale` defaults to the true width's. Padded query rows are
     computed and sliced away. Padded keys are masked inside the kernel:
     the scores of the columns from the true length on are set to -inf
     before the running max, so a padded key weighs an exact zero (the
@@ -288,21 +304,24 @@ def flash_attention(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, rows, h, d = q.shape
+    b, rows, h, width = q.shape
     keys = k.shape[1]
+    d = width + -width % ROUTE_MULTIPLE
     n, m, block_q, block_k = flash_plan(rows, keys, d, q.dtype.itemsize)
     if scale is None:
-        scale = 1.0 / math.sqrt(d)
-    if n > rows:
-        q = jnp.pad(q, ((0, 0), (0, n - rows), (0, 0), (0, 0)))
-    if m > keys:
-        widths = ((0, 0), (0, m - keys), (0, 0), (0, 0))
+        scale = 1.0 / math.sqrt(width)
+    if (n, d) != (rows, width):
+        q = jnp.pad(q, ((0, 0), (0, n - rows), (0, 0), (0, d - width)))
+    if (m, d) != (keys, width):
+        widths = ((0, 0), (0, m - keys), (0, 0), (0, d - width))
         k, v = jnp.pad(k, widths), jnp.pad(v, widths)
 
-    # Fold batch and heads; kernel works on [N, D] per (bh, qblock).
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, n, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, m, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, m, d)
+    fold = d > width  # a padded head goes in front of the tokens: the docstring says why
+    if fold:
+        q, k, v = (x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d) for x in (q, k, v))
+    else:
+        q, k, v = (x.reshape(b, x.shape[1], h * d) for x in (q, k, v))
+    lanes = 1 if fold else h  # heads side by side in a row of the kernel's operands
 
     num_k_blocks = m // block_k
     contract_last = (((1,), (1,)), ((), ()))  # q @ k.T without the transpose
@@ -342,16 +361,14 @@ def flash_attention(
         def _finalize():
             o_ref[0] = (acc_ref[...] / sum_ref[...]).astype(o_ref.dtype)
 
+    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh // lanes, qi, bh % lanes))
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh // lanes, ki, bh % lanes))
     out = pl.pallas_call(
         kernel,
         grid=(b * h, n // block_q, num_k_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),  # acc
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
@@ -362,7 +379,10 @@ def flash_attention(
         ),
         interpret=interpret,
         name="flash_attention",  # the kernel's name in a device trace
-    )(qf, kf, vf)
+    )(q, k, v)
 
-    out = out.reshape(b, h, n, d).transpose(0, 2, 1, 3)
-    return out[:, :rows] if n > rows else out
+    if fold:
+        out = out.reshape(b, h, n, d).transpose(0, 2, 1, 3)
+    else:
+        out = out.reshape(b, n, h, d)
+    return out[:, :rows, :, :width] if (n, d) != (rows, width) else out

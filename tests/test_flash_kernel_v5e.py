@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 
 from comfyui_distributed_tpu.ops import attention as attn
+from comfyui_distributed_tpu.ops import qk_norm_rope
 
 # Every served shape is compiled on the kernel, the ones the shape rule
 # leaves to XLA too: the rule rests on both routes' times, which
@@ -59,7 +60,8 @@ def test_kernel_compiles_for_v5e_at_served_shape(one_chip, q_shape, m, name, dty
         n, m, d + -d % 128, jnp.dtype(dtype).itemsize
     )
     pad = "" if (n_pad, m_pad) == (n, m) else f" pad{n_pad}x{m_pad}"
-    assert routes == [f"flash {n}x{m}x{d}{pad} bq{block_q} bk{block_k} {name}"]
+    inplace = "" if d % 128 else " inplace"
+    assert routes == [f"flash {n}x{m}x{d}{pad} bq{block_q} bk{block_k} {name}{inplace}"]
 
 
 def test_kernel_compiles_for_v5e_under_the_tile_axis_vmap(one_chip):
@@ -75,7 +77,9 @@ def test_kernel_compiles_for_v5e_under_the_tile_axis_vmap(one_chip):
 def test_route_log_entries():
     """What the sampler and upscale nodes write into their spans as
     `attention`: the blocks and operand dtype for a flash call, the
-    padded lengths too where a length was padded, `xla` entries as ever."""
+    padded lengths too where a length was padded, `inplace` where the
+    kernel reads the heads where the caller left them (a width that is
+    a multiple of 128), `xla` entries as ever."""
     flux = jax.ShapeDtypeStruct((1, 4608, 24, 128), jnp.bfloat16)
     sd15 = jax.ShapeDtypeStruct((2, 4096, 8, 40), jnp.bfloat16)
     text = jax.ShapeDtypeStruct((2, 77, 8, 40), jnp.bfloat16)
@@ -89,9 +93,61 @@ def test_route_log_entries():
         jax.eval_shape(flash, sdxl, sdxl, sdxl)
         jax.eval_shape(flash, vae, vae, vae)
     assert routes == [
-        "flash 4608x4608x128 bq512 bk1536 bf16",
+        "flash 4608x4608x128 bq512 bk1536 bf16 inplace",
         "flash 4096x4096x40 bq512 bk1024 bf16",
         "xla 4096x77x40",
         "flash 1296x1296x64 pad1296x1408 bq432 bk1408 bf16",
-        "flash 5184x5184x512 pad5280x5376 bq480 bk896 bf16",
+        "flash 5184x5184x512 pad5280x5376 bq480 bk896 bf16 inplace",
     ]
+
+
+# (tokens, width of the projection, lane the heads start at): q and k of a
+# FLUX single block's fused linear, k of a double block's image stream, q
+# of its text stream
+NORM_ROPE_SHAPES = [(4608, 21504, 0), (4608, 21504, 3072), (4096, 9216, 3072), (512, 9216, 0)]
+
+
+@pytest.mark.parametrize("n,width,offset", NORM_ROPE_SHAPES)
+def test_norm_rope_compiles_for_v5e_at_flux_shapes(one_chip, n, width, offset):
+    x = jax.ShapeDtypeStruct((1, n, width), jnp.bfloat16, sharding=one_chip)
+    scale = jax.ShapeDtypeStruct((128,), jnp.float32, sharding=one_chip)
+    freqs = jax.ShapeDtypeStruct((n, 64, 2), jnp.float32, sharding=one_chip)
+    fn = jax.jit(functools.partial(qk_norm_rope.norm_rope, heads=24, offset=offset))
+    compiled = fn.lower(x, scale, freqs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_a_flux_single_block_moves_no_tensor_between_its_kernels(one_chip, monkeypatch):
+    """The block at FLUX.1-dev's widths, compiled as a TPU routes it: q
+    and k go from the fused linear through `qk_norm_rope` into
+    `flash_attention`, whose output the next linear reads, and no `copy`,
+    `transpose` or `reshape` of a whole [4608, 3072] tensor is left
+    between them (this pattern finds ten in the parent of PR 35: PERF.md §6)."""
+    import re
+
+    from comfyui_distributed_tpu.models import mmdit
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dim, heads, n = 3072, 24, 4608
+    block = mmdit._SingleBlock(heads=heads, mlp_width=4 * dim, dtype=jnp.bfloat16)
+    args = (
+        jax.ShapeDtypeStruct((1, n, dim), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, dim), jnp.bfloat16),
+        jax.ShapeDtypeStruct((n, dim // heads // 2, 2), jnp.float32),
+    )
+    params = jax.eval_shape(lambda *a: block.init(jax.random.key(0), *a), *args)
+    place = lambda s, dtype: jax.ShapeDtypeStruct(s.shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda s: place(s, jnp.bfloat16), params)
+    with attn.route_log() as routes:
+        text = jax.jit(block.apply).lower(
+            params, *(place(a, a.dtype) for a in args)
+        ).compile().as_text()
+    assert routes == ["flash 4608x4608x128 bq512 bk1536 bf16 inplace"]
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', entry)) == 3
+    assert entry.count("%qk_norm_rope") >= 2 and "%flash_attention" in entry
+    moved = re.findall(
+        r"= (?:bf16|f32)\[(?:1,)?4608,(?:3072|24,128|24,64,2)\]\S* (?:copy|transpose|reshape)\(", entry
+    )
+    assert not moved, moved
