@@ -539,13 +539,26 @@ class DistributedServer:
         PERF.md, PR 31)."""
         from ..telemetry import get_tracer
 
+        # the tracer's clock when this thread came back from a job
+        came_back: Optional[float] = None
         while True:
+            idle = self._prompt_queue.empty()
             job = self._prompt_queue.get()
             if job is None:
                 # no save outlives the loop
                 self._saver.join()
                 return
             tracer = get_tracer()
+            if came_back is not None:
+                # what this thread did between two jobs, in the trace of
+                # the one it picks up: with it every instant of the
+                # thread between two walks lies in a named span
+                tracer.end_span(tracer.start_span(
+                    "executor.between_jobs",
+                    trace_id=job.trace_id,
+                    attrs={"idle": int(idle)},
+                    start=came_back,
+                ))
             tracer.end_span(job.queue_span)
             self._job_taken(job)
             self._interrupt.clear()
@@ -584,6 +597,7 @@ class DistributedServer:
             finally:
                 tracer.deactivate(token)
                 self._work_ended(job)
+                came_back = tracer.now()
 
     def _job_taken(self, job: PromptJob) -> None:
         with self._jobs_lock:
@@ -854,6 +868,10 @@ class DistributedServer:
             await self._runner.cleanup()
         if self._executor_thread is not None:
             self._executor_thread.join(timeout=10)
+        # after the executor and its saver: nothing launches any more
+        from ..telemetry import get_tracer
+
+        get_tracer().stop_device_watch(timeout=10)
         # Journal LAST — after the HTTP listener is down and the
         # executor has drained, so every transition acknowledged during
         # shutdown (late worker RPCs, the in-flight prompt's cleanup)

@@ -235,8 +235,12 @@ class CLIPTextEncode:
         # Conditioning carrying the pooled vector: SDXL-class adm and
         # Flux-class vector_in models consume it; families without
         # pooled conditioning ignore the field (pipeline._make_model_fn)
+        from ..telemetry import get_tracer
+
         _require_part(clip, "text_encoder", "CLIPTextEncode")
-        return (pl.encode_text_pooled(clip, [str(text)]),)
+        cond = pl.encode_text_pooled(clip, [str(text)])
+        get_tracer().device_span("text_encode", cond.context)
+        return (cond,)
 
 
 @register_node
@@ -592,6 +596,8 @@ class KSampler:
         denoise: float = 1.0,
         context=None,
     ):
+        from ..telemetry import get_tracer
+
         spec = resolve_seed(seed)
         bundle = model
         _require_part(bundle, "unet", "KSampler")
@@ -635,6 +641,7 @@ class KSampler:
                         batch_fixed_noise=fixed,
                     )
                 }
+        get_tracer().device_span("sampler", result["samples"])
         return ({**extras, **result},)
 
 
@@ -978,8 +985,11 @@ def _vae_pass(vae, x, method: str) -> jax.Array:
     from ..telemetry import get_tracer
 
     _require_part(vae, "vae", "VAEDecode" if method == "decode" else "VAEEncode")
-    get_tracer().annotate(programs=1)
-    return vae_apply(vae.vae, vae.params["vae"], x, method=method)
+    tracer = get_tracer()
+    tracer.annotate(programs=1)
+    out = vae_apply(vae.vae, vae.params["vae"], x, method=method)
+    tracer.device_span(f"vae_{method}", out)
+    return out
 
 
 def _decode_mesh(vae, mesh, latents) -> jax.Array:
@@ -1385,7 +1395,7 @@ class SaveImage:
         start = reserve_counter(out_dir, filename_prefix, "png", len(images))
         # the executor thread parks here until the device has finished
         # everything the images depend on
-        with get_tracer().span("device.wait") as wait:
+        with get_tracer().device_wait() as wait:
             arr = img_utils.ensure_numpy(images)
             wait.attrs["bytes"] = int(arr.nbytes)
         saved = [f"{filename_prefix}_{start + i:05d}.png" for i in range(arr.shape[0])]

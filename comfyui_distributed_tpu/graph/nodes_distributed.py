@@ -348,7 +348,7 @@ class DistributedCollector:
         # batch — just materialise it.
         from ..telemetry import get_tracer
 
-        with get_tracer().span("device.wait") as wait:
+        with get_tracer().device_wait() as wait:
             mesh_collected = host_collect(images) if isinstance(images, jax.Array) else (
                 img_utils.ensure_numpy(images)
             )

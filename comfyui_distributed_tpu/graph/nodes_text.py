@@ -34,11 +34,14 @@ def generate_tokens(bundle, ids, seed: int, steps: int, temperature: float,
     lm, params = bundle.lm, bundle.params["lm"]
     with tracer.span("lm.prefill"):
         prefill = lm.prefill(params, jnp.asarray(ids, jnp.int32), len(ids) + steps, collect)
+    # the logits, which no decode takes by donation (the cache it may)
+    tracer.device_span("prefill", prefill.logits)
     with tracer.span("lm.decode"):
         decode = lm.decode(
             params, prefill.cache, prefill.logits, len(ids), jax.random.key(seed), steps,
             temperature, collect,
         )
+    tracer.device_span("decode", decode.ids)
     return prefill, decode
 
 
@@ -88,7 +91,7 @@ class TextGenerate:
             )
         # the one read-back: the executor thread parks here until the
         # device has run both programs
-        with tracer.span("device.wait") as wait:
+        with tracer.device_wait() as wait:
             new_ids, *read = jax.device_get((decode.ids, *lm.read_back(prefill, decode)))
             wait.attrs["bytes"] = int(new_ids.nbytes + sum(a.nbytes for a in read))
         with tracer.span("lm.detokenize"):

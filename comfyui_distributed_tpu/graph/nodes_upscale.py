@@ -192,6 +192,8 @@ class UltimateSDUpscaleDistributed:
                 ),
             )
 
+        from ..telemetry import get_tracer
+
         with annotate_attention():
             out = upscale_ops.run_upscale(
                 bundle=model, image=image, pos=positive, neg=negative, mesh=mesh,
@@ -203,4 +205,8 @@ class UltimateSDUpscaleDistributed:
                 mask_blur=int(mask_blur), tiled_decode=bool(tiled_decode),
                 uniform=bool(force_uniform_tiles),
             )
+        # named as the jitted function that ran (ops/upscale.run_upscale)
+        get_tracer().device_span(
+            "upscale_single" if len(out.devices()) == 1 else "upscale_mesh", out
+        )
         return (out,)

@@ -878,6 +878,16 @@ def lm_tokens_total() -> Counter:
     )
 
 
+def device_busy_seconds_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_device_busy_seconds_total",
+        "Seconds the device spent on launched programs, by program "
+        "(the busy_s of the device.run spans); its rate is the chip's "
+        "utilisation by program",
+        ("program",),
+    )
+
+
 def lm_layer_passes_total() -> Counter:
     return get_metrics_registry().counter(
         "cdt_lm_layer_passes_total",

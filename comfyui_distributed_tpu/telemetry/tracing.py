@@ -7,8 +7,9 @@ request's tree is `sched.wait`, `queue_orchestration`,
 `prompt_queue.wait`, `execute_prompt` and under it one
 `node.<class_type>` per node that ran, with `device.wait`,
 `png.encode` and `file.write` where the executor thread blocks and the
-saver thread saves; the elastic tile tier adds `dispatch` and
-`tile.<stage>`
+saver thread saves, and one `device.run` per program a node launched,
+ended by the watcher thread when the device has finished it; the
+elastic tile tier adds `dispatch` and `tile.<stage>`
 (docs/observability.md has the whole vocabulary).
 
 Design:
@@ -35,7 +36,12 @@ Design:
   `set_span_annotator`), every context-managed span is mirrored into
   the capture on the thread that runs it, so the program's spans and
   the device's lines share the profiler's clock. This module imports
-  no jax; with no capture open a span pays one global read.
+  no jax; with no capture open a span pays one global read;
+- `device_span` records what the device did without a wait on the
+  thread that launches: the launch puts (span, one output array) on a
+  FIFO, and one thread, `cdt-device-watch`, waits for each output in
+  launch order. The device runs what it is given in order, so the
+  moment one program's output is ready is the moment the next starts.
 """
 
 from __future__ import annotations
@@ -44,12 +50,14 @@ import collections
 import contextlib
 import contextvars
 import json
+import queue
 import threading
 import time
 import uuid
 from typing import Any, Callable, Iterator, Optional
 
 TRACE_HEADER = "X-CDT-Trace-Id"
+WATCH_THREAD = "cdt-device-watch"
 
 # (trace_id, span_id) of the active span; span_id None = trace joined
 # via activate() but no span open yet.
@@ -153,6 +161,25 @@ class Span:
         }
 
 
+class _Launch:
+    """One launched program: its `device.run` span and the output the
+    watcher waits for, dropped once it is ready."""
+
+    __slots__ = ("span", "ready")
+
+    def __init__(self, span: Span, ready: Any):
+        self.span = span
+        self.ready = ready
+
+
+def _is_ready(ready: Any) -> bool:
+    """Whether a launch's output is there already, without waiting."""
+    try:
+        return bool(ready.is_ready())
+    except Exception:  # noqa: BLE001 - a deleted array cannot say
+        return False
+
+
 class Tracer:
     """Thread-safe bounded span store + context management."""
 
@@ -173,6 +200,13 @@ class Tracer:
         # span_id -> Span per trace: O(1) event attachment (trace_info
         # fires per log line; scanning 20k spans under the lock won't do)
         self._by_id: dict[str, dict[str, Span]] = {}
+        # device_span: the watcher thread, the FIFO it reads (one a
+        # thread, so a stop and a restart never share a sentinel), and
+        # the last launches, which device_wait looks through
+        self._watch_lock = threading.Lock()
+        self._watch_thread: Optional[threading.Thread] = None
+        self._launches: "queue.SimpleQueue[Optional[_Launch]]" = queue.SimpleQueue()
+        self._recent: "collections.deque[_Launch]" = collections.deque(maxlen=32)
 
     # --- bookkeeping ------------------------------------------------------
 
@@ -237,10 +271,13 @@ class Tracer:
         trace_id: Optional[str] = None,
         parent_id: Optional[str] = None,
         attrs: Optional[dict[str, Any]] = None,
+        start: Optional[float] = None,
     ) -> Span:
         """Manual span start (no context mutation); pair with
         `end_span`. Parent resolution: explicit parent_id → active span
-        (same trace) → the trace's root span."""
+        (same trace) → the trace's root span. `start` is an earlier
+        reading of `now()`, for a span whose trace was not known when
+        it began."""
         state = _current.get()
         if trace_id is None:
             if state is None:
@@ -258,7 +295,7 @@ class Tracer:
             span_id=uuid.uuid4().hex[:16],
             parent_id=parent_id,
             name=name,
-            start=self._clock(),
+            start=self._clock() if start is None else start,
             attrs=attrs,
         )
         self._store(span)
@@ -299,6 +336,106 @@ class Tracer:
             if mirror is not None:
                 mirror.__exit__(None, None, None)
             _current.reset(token)
+
+    # --- what the device did ----------------------------------------------
+
+    def device_span(self, program: str, ready: Any, **attrs: Any) -> Optional[Span]:
+        """Call on the thread that launched `program`, right after the
+        launch returned, with one output array of it as `ready` (never
+        one a later program takes by donation). Opens `device.run`
+        under the active span and hands it to the watcher thread, which
+        ends it when `ready` is: `begin` (when the device could start
+        it: the later of this call and the previous launch's end),
+        `queued_s`, `busy_s`. Waits for nothing here; outside a trace
+        it does nothing."""
+        if _current.get() is None:
+            return None
+        span = self.start_span("device.run", attrs={"program": program, **attrs})
+        launch = _Launch(span, ready)
+        with self._watch_lock:
+            if self._watch_thread is None:
+                self._launches = queue.SimpleQueue()
+                self._watch_thread = threading.Thread(
+                    target=self._watch, args=(self._launches,), name=WATCH_THREAD,
+                    daemon=True,
+                )
+                self._watch_thread.start()
+            self._recent.append(launch)
+            self._launches.put(launch)
+        return span
+
+    def _watch(self, launches: "queue.SimpleQueue[Optional[_Launch]]") -> None:
+        """The watcher thread: each launch's output in launch order."""
+        from .instruments import device_busy_seconds_total
+
+        last_end: Optional[float] = None
+        while True:
+            launch = launches.get()
+            if launch is None:
+                return
+            span, error = launch.span, None
+            program = span.attrs.get("program")
+            # only while a capture mirrors it: its annotation on this
+            # thread's line is what ties a `device.run` to the program
+            # on the device's own line
+            watch = self.span(
+                "device.watch", trace_id=span.trace_id, parent_id=span.span_id,
+                program=program,
+            ) if _span_annotator is not None else contextlib.nullcontext()
+            with watch:
+                try:
+                    launch.ready.block_until_ready()
+                except Exception as exc:  # noqa: BLE001 - deleted, or the program failed
+                    error = f"{type(exc).__name__}: {exc}"
+                span.end = self._clock()
+                launch.ready = None
+            if error is None:
+                begin = span.start if last_end is None else max(span.start, last_end)
+                last_end = span.end
+                busy_s = span.end - begin
+                span.attrs.update(begin=begin, queued_s=begin - span.start, busy_s=busy_s)
+                device_busy_seconds_total().inc(max(0.0, busy_s), program=str(program))
+            else:
+                # says nothing of when the device was free: the next
+                # launch keeps the last good end
+                span.status = "error"
+                span.attrs["error"] = error
+            _notify_span("close", span)
+
+    def stop_device_watch(self, timeout: Optional[float] = None) -> None:
+        """End the watcher thread once it has seen every launch so far
+        to its end; the next `device_span` starts another."""
+        with self._watch_lock:
+            thread, self._watch_thread = self._watch_thread, None
+            if thread is not None:
+                self._launches.put(None)
+        if thread is not None:
+            thread.join(timeout)
+
+    @contextlib.contextmanager
+    def device_wait(self, **attrs: Any) -> Iterator[Span]:
+        """The `device.wait` span around a read-back. On the way out it
+        gains `after_ready_s`: its end less the end of the last
+        `device.run` of its trace that had ended by then, which is what
+        the read-back itself cost once the device was done."""
+        with self.span("device.wait", **attrs) as wait:
+            yield wait
+        # after the span has ended, so that it ends where it always did
+        with self._watch_lock:
+            mine = [l for l in self._recent if l.span.trace_id == wait.trace_id]
+        for launch in reversed(mine):
+            # in this order: the watcher stamps `end`, then drops `ready`
+            ready, end = launch.ready, launch.span.end
+            if launch.span.status == "error":
+                continue
+            if end is not None and end <= wait.end:
+                wait.attrs["after_ready_s"] = wait.end - end
+            elif end is not None or _is_ready(ready):
+                # there, and the watcher had not stamped it by then
+                wait.attrs["after_ready_s"] = 0.0
+            else:
+                continue  # still running: the wait was for an earlier one
+            break
 
     def _active_span(self) -> Optional[Span]:
         """The active span, falling back to the active trace's root
@@ -378,6 +515,8 @@ class Tracer:
             self._traces.clear()
             self._roots.clear()
             self._by_id.clear()
+        with self._watch_lock:
+            self._recent.clear()
 
 
 # --- global tracer --------------------------------------------------------

@@ -166,7 +166,10 @@ def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(served):
     spans = served[1][1]
     (node,) = spans_named(spans, "node.TextGenerate")
     below = [s["name"] for s in spans if s["parent_id"] == node["span_id"]]
-    assert below == ["lm.prefill", "lm.decode", "device.wait", "lm.detokenize"]
+    assert below == ["lm.prefill", "device.run", "lm.decode", "device.run", "device.wait",
+                     "lm.detokenize"]
+    assert [s["attrs"]["program"] for s in spans_named(spans, "device.run")
+            if s["parent_id"] == node["span_id"]] == ["prefill", "decode"]
     (wait,) = [s for s in spans_named(spans, "device.wait") if s["parent_id"] == node["span_id"]]
     # the ids and the two counts of pairs per held expert, in one read-back
     assert wait["attrs"]["bytes"] == 4 * (NEW_TOKENS + 2 * 2 * 4)

@@ -144,7 +144,10 @@ def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(served):
     spans = served[1][1]
     (node,) = spans_named(spans, "node.TextGenerate")
     below = [s["name"] for s in spans if s["parent_id"] == node["span_id"]]
-    assert below == ["lm.prefill", "lm.decode", "device.wait", "lm.detokenize"]
+    assert below == ["lm.prefill", "device.run", "lm.decode", "device.run", "device.wait",
+                     "lm.detokenize"]
+    assert [s["attrs"]["program"] for s in spans_named(spans, "device.run")
+            if s["parent_id"] == node["span_id"]] == ["prefill", "decode"]
     (wait,) = [s for s in spans_named(spans, "device.wait") if s["parent_id"] == node["span_id"]]
     # the ids and the two exit distributions, in one read-back
     assert wait["attrs"]["bytes"] == 4 * (NEW_TOKENS + 2 * PASSES)
@@ -287,7 +290,11 @@ def test_the_manifest_has_the_cell_with_the_issues_traffic_and_lists():
     assert listed == {
         "images_per_s", "execute_ms.txt2img", "host_ms.txt2img", "device_idle_pct.txt2img",
         "decode_dispatch_ms.txt2img", "generate_ms.lm", "decode_ms_per_token.lm",
-        "lm_share_pct.rewrite", "cache_gb.lm", "layer_passes_per_token.lm"}
+        "lm_share_pct.rewrite", "cache_gb.lm", "layer_passes_per_token.lm",
+        # PR 36: what reads the cell's `device.run` spans
+        "sampler_device_ms.txt2img", "vae_device_ms.txt2img", "prefill_device_ms.lm",
+        "decode_device_ms_per_token.lm", "decode_hbm_roofline_pct.lm", "prefill_mxu_peak_pct.lm",
+        "device_idle_in_pct.txt2img", "between_jobs_ms.txt2img"}
     for name in ("cache_gb.lm", "layer_passes_per_token.lm"):
         (metric,) = [m for m in manifest["per_layer"] if m["name"] == name]
         assert metric["workloads"] == ["deepseek_v2_rewrite_txt2img_512.closed2", CELL]

@@ -197,6 +197,137 @@ def test_last_timings_and_counts_without_a_server(tmp_path, monkeypatch, tracer)
     assert second.last_timings["1"] == 0.0
 
 
+# --- what the device did: device.run, after_ready_s, executor.between_jobs -----
+
+
+def txt2img_graph(seed=42):
+    return {
+        "1": {"class_type": "CheckpointLoaderSimple", "inputs": {"ckpt_name": "tiny-unet"}},
+        "2": {"class_type": "CLIPTextEncode", "inputs": {"text": "a cat", "clip": ["1", 1]}},
+        "3": {"class_type": "CLIPTextEncode", "inputs": {"text": "", "clip": ["1", 1]}},
+        "4": {"class_type": "EmptyLatentImage",
+              "inputs": {"width": 32, "height": 32, "batch_size": 1}},
+        "6": {"class_type": "KSampler",
+              "inputs": {"model": ["1", 0], "seed": seed, "steps": 2, "cfg": 3.0,
+                         "sampler_name": "euler", "scheduler": "karras",
+                         "positive": ["2", 0], "negative": ["3", 0],
+                         "latent_image": ["4", 0], "denoise": 1.0}},
+        "7": {"class_type": "VAEDecode", "inputs": {"samples": ["6", 0], "vae": ["1", 2]}},
+        "8": {"class_type": "SaveImage",
+              "inputs": {"images": ["7", 0], "filename_prefix": "device"}},
+    }
+
+
+def generate_graph():
+    return {
+        "1": {"class_type": "CheckpointLoaderSimple",
+              "inputs": {"ckpt_name": "tiny-deepseek-v2"}},
+        "2": {"class_type": "TextGenerate",
+              "inputs": {"clip": ["1", 1], "text": "a short prompt", "seed": 7,
+                         "max_new_tokens": 4, "temperature": 1.0}},
+    }
+
+
+@pytest.fixture()
+def wall_tracer():
+    """On the wall clock: the watcher thread reads it too, and a clock
+    that ticks once a reading would order the two threads' readings."""
+    real = Tracer()
+    set_tracer(real)
+    yield real
+    real.stop_device_watch(timeout=30)
+
+
+def walk(tracer, prompt, tmp_path, monkeypatch, trace_id):
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
+    with tracer.span("execute_prompt", trace_id=trace_id):
+        GraphExecutor(ExecutionContext()).execute(prompt)
+    tracer.stop_device_watch(timeout=30)  # every launch seen to its end
+    return by_name(tracer, trace_id)
+
+
+def runs_of(spans):
+    return {s["attrs"]["program"]: s for s in spans["device.run"]}
+
+
+def test_a_txt2img_request_has_a_device_run_under_each_node_that_launched(
+    wall_tracer, tmp_path, monkeypatch
+):
+    spans = walk(wall_tracer, txt2img_graph(), tmp_path, monkeypatch, "t")
+    runs = runs_of(spans)
+    assert set(runs) == {"text_encode", "sampler", "vae_decode"}
+    assert len(spans["device.run"]) == 4  # the positive and the negative prompt
+    assert runs["sampler"]["parent_id"] == spans["node.KSampler"][0]["span_id"]
+    assert runs["vae_decode"]["parent_id"] == spans["node.VAEDecode"][0]["span_id"]
+    assert runs["text_encode"]["parent_id"] in {
+        s["span_id"] for s in spans["node.CLIPTextEncode"]}
+    ordered = sorted(spans["device.run"], key=lambda s: s["start"])
+    for before, after in zip(ordered, ordered[1:]):
+        assert after["attrs"]["begin"] == max(after["start"], before["end"])
+    for run in ordered:
+        assert run["status"] == "ok" and run["end"] >= run["attrs"]["begin"] >= run["start"]
+        assert run["attrs"]["queued_s"] + run["attrs"]["busy_s"] == pytest.approx(
+            run["end"] - run["start"])
+    (wait,) = spans["device.wait"]
+    assert wait["parent_id"] == spans["node.SaveImage"][0]["span_id"]
+    # both ended when the read-back did, give or take the watcher's own wake-up
+    late = 0.25
+    assert runs["sampler"]["end"] <= runs["vae_decode"]["end"] <= wait["end"] + late
+    assert 0.0 <= wait["attrs"]["after_ready_s"] <= wait["duration"] + late
+    assert "device.watch" not in spans  # the watcher's own span is a capture's
+
+
+def test_a_generate_request_has_the_prefill_then_the_decode_back_to_back(
+    wall_tracer, tmp_path, monkeypatch
+):
+    spans = walk(wall_tracer, generate_graph(), tmp_path, monkeypatch, "t")
+    node = spans["node.TextGenerate"][0]
+    below = sorted((s for names in spans.values() for s in names
+                    if s["parent_id"] == node["span_id"]), key=lambda s: s["start"])
+    assert [s["name"] for s in below] == [
+        "lm.prefill", "device.run", "lm.decode", "device.run", "device.wait", "lm.detokenize"]
+    prefill, decode = below[1], below[3]
+    assert (prefill["attrs"]["program"], decode["attrs"]["program"]) == ("prefill", "decode")
+    # the decode was launched before the prefill had ended: it begins where that ends
+    assert decode["attrs"]["begin"] == max(decode["start"], prefill["end"])
+    assert prefill["status"] == decode["status"] == "ok"
+    assert "after_ready_s" in below[4]["attrs"]
+
+
+def test_two_requests_back_to_back_have_one_between_jobs_span_between_them(server, tracer):
+    server.queue_prompt(graph(0.25), "p1")
+    server.queue_prompt(graph(0.75), "p2")
+    run_queued(server)
+    first, second = by_name(tracer, "p1"), by_name(tracer, "p2")
+    assert "executor.between_jobs" not in first
+    (between,) = second["executor.between_jobs"]
+    # the queue held p2 when the thread came back for it
+    assert between["attrs"] == {"idle": 0}
+    assert between["parent_id"] == second["prompt_queue.wait"][0]["span_id"]
+    assert between["start"] > first["node.SaveImage"][0]["end"]
+    assert between["end"] <= second["prompt_queue.wait"][0]["end"]
+    assert between["end"] < second["execute_prompt"][0]["start"]
+
+
+def test_between_jobs_says_when_the_thread_found_the_queue_empty(server, tracer):
+    import threading
+
+    loop = threading.Thread(target=server._executor_loop)
+    loop.start()
+    try:
+        first = server.queue_prompt(graph(0.25), "p1")
+        assert first.done.wait(30)
+        time.sleep(0.05)  # the thread is back at the queue, which is empty
+        second = server.queue_prompt(graph(0.75), "p2")
+        assert second.done.wait(30)
+    finally:
+        server._prompt_queue.put(None)
+        loop.join(30)
+    assert not loop.is_alive()
+    (between,) = by_name(tracer, "p2")["executor.between_jobs"]
+    assert between["attrs"] == {"idle": 1}
+
+
 # --- the profiler mirror ----------------------------------------------------
 
 
