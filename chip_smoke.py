@@ -25,7 +25,11 @@ Drives the main path once, through the entry points an operator uses:
                (heads folded into the batch by transpositions), each
                between the [B, N, H*D] arrays a model holds and against
                a float32 reference, and prints which route the shape
-               rule gives each shape and all three times.
+               rule gives each shape and all three times; then the
+               single-query kernel of a language model's decode
+               (`ops/decode_attention`) against the einsum form over
+               every slot of Ouro's 3.3 GB cache, a call a slot inside
+               one jitted loop.
     multichip  only where the server reports two or more chips: the
                serve leg has then already run on every chip through
                the in-process mesh; this leg checks that, and runs the
@@ -826,6 +830,15 @@ REHEARSAL_SHAPES = (
 )
 
 
+# A language model's decode attends with one query a head over a cache
+# slot (`ops/decode_attention`): Ouro-2.6B's carried cache at the
+# benchmark cell's 2,048 + 64 positions, [passes, layers, keys|values,
+# heads, positions, head_dim], 3.3 GB. Timed as one call a slot inside
+# one jitted loop, which is how the decode runs it.
+DECODE_CACHE = ("ouro decode slot", (4, 48, 2, 16, 2112, 128))
+REHEARSAL_DECODE_CACHE = ("toy decode slot", (2, 3, 2, 2, 64, 128))
+
+
 def transposed(attend):
     """`attend` ([B, N, H, D] attention) in the layout the kernel had
     until PR 35, kept as the comparison (and as the tests' reference):
@@ -938,7 +951,62 @@ def attention_child(rehearsal: bool) -> int:
         row["ref_max_abs"] = round(scale, 3)
         failed += not row["ok"]
         print(json.dumps(row), flush=True)
+    failed += not decode_slot_row(rehearsal, timed)
     return 1 if failed else 0
+
+
+def decode_slot_row(rehearsal: bool, timed) -> bool:
+    """The single-query kernel against the einsum form over every slot
+    of a real cache, a call a slot inside one jitted loop (a call's cost
+    inside a program, not a dispatch), both against a float32 reference."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.ops import decode_attention as da
+
+    label, shape = REHEARSAL_DECODE_CACHE if rehearsal else DECODE_CACHE
+    passes, layers, _, heads, positions, d = shape
+    slots = passes * layers
+
+    def over_slots(attend):
+        def loop(q, cache, position):
+            def body(_, i):
+                return None, attend(q, cache, (i // layers, i % layers), position)
+            return jax.lax.scan(body, None, jnp.arange(slots))[1]
+        return jax.jit(loop)
+
+    def in_float32(q, cache, slot, position):
+        with jax.default_matmul_precision("highest"):
+            return da.decode_attention_xla(
+                q.astype(jnp.float32), cache[slot].astype(jnp.float32)[None, None], (0, 0),
+                position)
+
+    q = (2.0 * jax.random.normal(jax.random.key(1), (heads, d))).astype(jnp.bfloat16)
+    cache = jax.jit(lambda key: jax.random.normal(key, shape, jnp.bfloat16))(jax.random.key(2))
+    position = jnp.int32(positions - 12)
+    route = da.decode_attention_route(heads, positions, d, cache.dtype)
+    row = {
+        "shape": label, "cache": list(shape), "dtype": "bfloat16", "route": route,
+        "heads_a_step": da.decode_plan(heads, positions, d, cache.dtype.itemsize),
+        "position": int(position), "ok": rehearsal or route == "decode-kernel",
+    }
+    ref = np.asarray(over_slots(in_float32)(q, cache, position))
+    scale = max(1.0, float(np.abs(ref).max()))
+    for name, attend in (
+        ("kernel", functools.partial(da.decode_attention, interpret=rehearsal)),
+        ("xla", da.decode_attention_xla),
+    ):
+        out, first_s, ms = timed(over_slots(attend), q, cache, position)
+        err = float(np.abs(np.asarray(out, np.float32) - ref).max())
+        row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
+        row[name] = {
+            "max_abs_err": round(err, 5), "first_call_s": round(first_s, 2),
+            "ms_all_slots": round(ms, 3), "us_a_call": round(1e3 * ms / slots, 2),
+        }
+    row["ref_max_abs"] = round(scale, 3)
+    print(json.dumps(row), flush=True)
+    return row["ok"]
 
 
 # --- entry -----------------------------------------------------------------

@@ -31,7 +31,9 @@ differences that the loop forces:
   the prefill's attention wants its keys in: any other and the TPU's
   compiler keeps that order inside the loop and copies all of the cache
   on the way out), carried through every loop and written in place.
-  `decode` takes it by donation and hands it back.
+  `decode` takes it by donation and hands it back; a decode step reads
+  each slot once, out of the carried cache where it lies
+  (`ops/decode_attention`).
 
 The published rule leaves the loop at the first pass whose cumulative
 p reaches `early_exit_threshold`; at the published threshold, 1, that is
@@ -63,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ..ops import decode_attention
 from ..ops.attention import dot_product_attention
 from .lm_common import (
     ByteTokenizer,
@@ -214,10 +217,11 @@ def layer_whole(cfg, p, x, rope):
     return _after_attention(cfg, p, x, out.reshape(x.shape[0], -1)), kv
 
 
-def layer_cached(cfg, p, x, rope, cache, slot, position, valid):
+def layer_cached(cfg, p, x, rope, cache, slot, position):
     """One layer for one new token x [1, hidden] at `position`: its key
     and value written into the cache's `slot` (pass, layer), attention
-    over that slot's positions `valid` [S]. Returns (x, cache)."""
+    over that slot's positions up to it, read out of the cache where
+    they lie (`ops/decode_attention.attend`). Returns (x, cache)."""
     with jax.named_scope("attn"):
         q, k, v = _qkv(cfg, p, x, rope)
         cache = jax.lax.dynamic_update_slice(
@@ -225,12 +229,7 @@ def layer_cached(cfg, p, x, rope, cache, slot, position, valid):
             cache, jnp.stack([k, v]).reshape(1, 1, 2, cfg.num_attention_heads, 1, cfg.head_dim),
             (*slot, 0, 0, position, 0))
         cache = with_layout_constraint(cache, Layout(major_to_minor=tuple(range(cache.ndim))))
-        keys, values = cache[slot]                               # [heads, S, d] each
-        scores = cfg.head_dim ** -0.5 * jnp.einsum(
-            "hd,hsd->hs", q[0], keys, preferred_element_type=jnp.float32)
-        scores = jnp.where(valid[None, :], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
-        out = jnp.einsum("hs,hsd->hd", probs, values)
+        out = decode_attention.attend(q[0], cache, slot, position)
     return _after_attention(cfg, p, x, out.reshape(1, -1)), cache
 
 
@@ -332,11 +331,10 @@ def prefill(cfg: OuroConfig, params, ids, *, cache_len: int, collect: bool = Fal
 def decode_step(cfg, params, cache, token, position):
     """One token through every pass and layer over the cache. Returns
     (logits [vocab], cache, h_t [T, hidden], p(t) [T])."""
-    valid = jnp.arange(cache.shape[4]) <= position
     rope = rope_tables(cfg, position[None])
 
     def one_layer(p, x, cache, slot):
-        return layer_cached(cfg, p, x, rope, cache, slot, position, valid)
+        return layer_cached(cfg, p, x, rope, cache, slot, position)
 
     x = params["embed"][token][None].astype(jnp.float32)
     h, cache, lam, hidden = _loop(cfg, params, x, cache, one_layer)

@@ -151,3 +151,63 @@ def test_a_flux_single_block_moves_no_tensor_between_its_kernels(one_chip, monke
         r"= (?:bf16|f32)\[(?:1,)?4608,(?:3072|24,128|24,64,2)\]\S* (?:copy|transpose|reshape)\(", entry
     )
     assert not moved, moved
+
+
+# Ouro-2.6B's carried cache at the benchmark cell's lengths (2,048 + 64
+# positions): [passes, layers, keys | values, heads, positions, head_dim]
+OURO_CACHE = (4, 48, 2, 16, 2112, 128)
+# an instruction whose result is one (pass, layer) slot of it, or one half
+SLOT_SHAPED = r"= (?:bf16|f32)\[(?:1,1,)?(?:2,)?16,2112,128\]\S* ([\w-]+)\("
+
+
+@pytest.mark.parametrize("name,dtype", [("bf16", jnp.bfloat16), ("f32", jnp.float32)])
+def test_decode_attention_compiles_for_v5e_over_the_whole_cache(one_chip, name, dtype):
+    """The single-query kernel takes the slot out of the cache it is
+    given: no temporary, so no copy of the cache in front of the call."""
+    from comfyui_distributed_tpu.ops import decode_attention as da
+
+    q = jax.ShapeDtypeStruct((16, 128), dtype, sharding=one_chip)
+    cache = jax.ShapeDtypeStruct(OURO_CACHE, dtype, sharding=one_chip)
+    index = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    fn = jax.jit(lambda q, cache, t, l, p: da.decode_attention(q, cache, (t, l), p))
+    compiled = fn.lower(q, cache, index, index, index).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+# the route is chosen while the program is traced, and `jax.jit` keeps a
+# trace by its static arguments: each case takes a step count of its own
+@pytest.mark.parametrize("backend,steps,entry,calls", [
+    ("tpu", 64, "decode-kernel 16x2112x128 h1 bf16", 1),
+    ("cpu", 2, "decode-xla 16x2112x128", 0),  # the einsum form: what `SLOT_SHAPED` is there to find
+])
+def test_ouro_decode_reads_the_slot_where_it_lies(
+        one_chip, monkeypatch, backend, steps, entry, calls):
+    """The whole decode at the published sizes (64 steps, the cell's),
+    compiled as a TPU routes it: one kernel in the layer body, the cache
+    carried in place (a cache-sized temporary would be a copy around the
+    call) and no instruction left whose result is a slot."""
+    import re
+
+    from comfyui_distributed_tpu.models import ouro
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = ouro.OuroConfig()
+    place = lambda s, dtype=None: jax.ShapeDtypeStruct(
+        s.shape, dtype or s.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: place(s, jnp.bfloat16),
+        jax.eval_shape(lambda: ouro.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        compiled = ouro.decode.lower(
+            cfg, params, jax.ShapeDtypeStruct(OURO_CACHE, jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.float32, sharding=one_chip),
+            scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+            scalar(jnp.float32), steps=steps,
+        ).compile()
+    assert routes == [entry]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    assert bool(re.findall(SLOT_SHAPED, text)) == (backend == "cpu")

@@ -155,7 +155,10 @@ def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(served):
 
 def test_only_the_request_that_traced_the_programs_says_which_attention(served):
     (first,) = spans_named(served[0][1], "node.TextGenerate")
-    assert first["attrs"]["attention"] == "xla-causal 2048x2048x16/16 bq256 f32"
+    # the decode's single-query attention over a slot (`ops/decode_attention`:
+    # the einsum form off a TPU), then the prefill's
+    assert first["attrs"]["attention"] == (
+        f"decode-xla {HEADS}x{2048 + NEW_TOKENS}x{HEAD_DIM}, xla-causal 2048x2048x16/16 bq256 f32")
     (second,) = spans_named(served[1][1], "node.TextGenerate")
     assert "attention" not in second["attrs"]
 
