@@ -74,7 +74,11 @@ class TextGenerate:
     def generate(self, clip, text, seed, max_new_tokens=256, temperature=1.0,
                  context=None):
         from ..telemetry import get_tracer
-        from ..telemetry.instruments import lm_layer_passes_total, lm_tokens_total
+        from ..telemetry.instruments import (
+            lm_layer_passes_total,
+            lm_linear_layer_passes_total,
+            lm_tokens_total,
+        )
 
         lm = getattr(clip, "lm", None)
         if lm is None:
@@ -96,14 +100,18 @@ class TextGenerate:
             wait.attrs["bytes"] = int(new_ids.nbytes + sum(a.nbytes for a in read))
         with tracer.span("lm.detokenize"):
             out = clip.tokenizer.decode(new_ids)
+        described = lm.describe(len(ids) + steps)
         attrs = dict(
             prompt_tokens=len(ids), new_tokens=steps,
-            **lm.describe(len(ids) + steps, prefill.cache.dtype.itemsize),
+            **described,
             **lm.report(len(ids), steps, *read),
         )
         for phase, tokens in (("prefill", len(ids)), ("decode", steps)):
             lm_tokens_total().inc(tokens, phase=phase)
             lm_layer_passes_total().inc(tokens * lm.layer_passes, phase=phase)
+            if described.get("linear_layers"):
+                lm_linear_layer_passes_total().inc(
+                    tokens * described["linear_layers"], phase=phase)
         if routes:
             # only the request that traced the programs gets here with
             # anything: which implementation the prefill's attention took

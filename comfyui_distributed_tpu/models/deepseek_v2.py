@@ -13,12 +13,12 @@ are read by two forms of one block, and the float32 reference in
   the weighted sum, attention over the 576-wide latent cache itself),
   the next id sampled on the device from the seed.
 
-The expert layer is told which experts it holds (`parallel.sharding.
-expert_range` of `ep_rank` / `ep_size`), routes over all of them and
-computes its own experts' part of the result as one grouped product
-(`jax.lax.ragged_dot`) with no dropped token and no capacity factor;
-what absent experts would add is left out. The vocabulary may be a slice
-too (`vocab_shards`): embedding, logits and sampling are over the slice.
+The expert layer (`moe.expert_layer`, shared with `solar_open2.py`) is
+told which experts it holds (`parallel.sharding.expert_range` of
+`ep_rank` / `ep_size`), routes over all of them by this model's rule
+(`route`) and computes its own experts' part of the result; what absent
+experts would add is left out. The vocabulary may be a slice too
+(`vocab_shards`): embedding, logits and sampling are over the slice.
 
 Parameter layout, where it departs from the published checkpoint's (a
 fixed permutation or split of weight columns, nothing a forward pass can
@@ -44,6 +44,7 @@ from ..ops.attention import dot_product_attention
 from ..parallel.sharding import expert_range
 from .lm_common import (  # noqa: F401  (the names this module has always had)
     ByteTokenizer,
+    LanguageModel,
     apply_rope,
     count_params,
     init_from_shapes,
@@ -51,6 +52,7 @@ from .lm_common import (  # noqa: F401  (the names this module has always had)
     sample,
     swiglu,
 )
+from .moe import expert_layer, report_loads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -295,38 +297,11 @@ def route(cfg: DeepSeekV2Config, scores: jax.Array):
 
 
 def moe(cfg, p, x):
-    """The expert layer of this chip over x [T, hidden]: the shared
-    SwiGLU of every token, plus the weighted outputs of the chosen
-    experts that are held here. Returns (output, chosen ids [T, k],
-    pairs on each held expert [held])."""
-    with jax.named_scope("router"):
-        logits = jnp.dot(
-            x.astype(jnp.float32), p["w_g"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        ids, weights = route(cfg, jax.nn.softmax(logits, axis=-1))
-    with jax.named_scope("experts"):
-        held = cfg.held_experts
-        tokens, k = ids.shape
-        local = ids.reshape(-1) - held.start
-        here = (local >= 0) & (local < len(held))
-        # sort the token-expert pairs by held expert, the pairs of absent
-        # experts last: each held expert's rows are then one segment
-        slot = jnp.where(here, local, len(held))
-        order = jnp.argsort(slot, stable=True)
-        sizes = jnp.zeros((len(held),), jnp.int32).at[slot].add(1, mode="drop")
-        rows = x[order // k]
-        gate, up = jnp.split(
-            jax.lax.ragged_dot(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1
-        )
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
-        # rows past the last segment are absent experts' pairs: weight 0
-        out = jnp.where(here[order][:, None], out, 0).astype(jnp.float32)
-        out = out * weights.reshape(-1)[order][:, None]
-        routed = out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
-    with jax.named_scope("shared"):
-        shared = swiglu(x, p["shared"])
-    return shared + routed.astype(x.dtype), ids, sizes
+    """The expert layer of this chip over x [T, hidden] (`moe.expert_layer`
+    under this model's routing rule: softmax scores, `route`). Returns
+    (output, chosen ids [T, k], pairs on each held expert [held])."""
+    return expert_layer(
+        p, x, cfg.held_experts, lambda logits: route(cfg, jax.nn.softmax(logits, axis=-1)))
 
 
 def _feed_forward(cfg, layer, block, x):
@@ -460,54 +435,36 @@ def decode(cfg: DeepSeekV2Config, params, cache, logits, start, key, temperature
     return Decode(ids, loads, *kept)
 
 
-class DeepSeekV2:
-    """What a bundle's `lm` part is (the contract is in `lm_common`): the
-    configuration with the two programs bound to it, and what a node
-    reads back and reports of them."""
+class DeepSeekV2(LanguageModel):
+    """What a bundle's `lm` part is (the contract is in `lm_common`): what
+    a node reads back and reports of this model's two programs."""
 
-    def __init__(self, cfg: DeepSeekV2Config):
-        self.cfg = cfg
-        self.tokenizer = ByteTokenizer()
+    _init = staticmethod(init_params)
+    _prefill = staticmethod(prefill)
+    _decode = staticmethod(decode)
 
     @property
     def layer_passes(self) -> int:
         return self.cfg.num_hidden_layers
 
-    def init(self, key, dtype=jnp.float32):
-        return init_params(self.cfg, key, dtype)
-
-    def prefill(self, params, ids, cache_len: int, collect: bool = False):
-        return prefill(self.cfg, params, ids, cache_len=cache_len, collect=collect)
-
-    def decode(self, params, cache, logits, start: int, key, steps: int, temperature: float,
-               collect: bool = False):
-        return decode(
-            self.cfg, params, cache, logits, jnp.int32(start), key, jnp.float32(temperature),
-            steps=steps, collect=collect,
-        )
-
     def read_back(self, prefill: Prefill, decode: Decode) -> tuple:
         """The pairs on each held expert, of either program."""
         return prefill.loads, decode.loads
 
-    def describe(self, cache_len: int, itemsize: int) -> dict[str, int]:
+    def describe(self, cache_len: int) -> dict[str, int]:
         cfg = self.cfg
+        cache = cfg.num_hidden_layers * cache_len * cfg.cache_width * self.dtype.itemsize
         return {
             "layers": cfg.num_hidden_layers,
             "experts_held": len(cfg.held_experts),
             "experts_total": cfg.n_routed_experts,
-            "cache_bytes": cfg.num_hidden_layers * cache_len * cfg.cache_width * itemsize,
+            "cache_bytes": cache,
+            "state_bytes": 0,
         }
 
     def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
         """Per phase: the token-expert pairs the router made, those that
         fell on held experts, and the fullest held expert's."""
-        pairs_a_token = _moe_layers(self.cfg) * self.cfg.num_experts_per_tok
-        attrs = {}
-        for phase, tokens, loads in (
-            ("prefill", prompt_tokens, prefill_loads), ("decode", new_tokens, decode_loads)
-        ):
-            attrs[f"{phase}_routed_pairs"] = tokens * pairs_a_token
-            attrs[f"{phase}_routed_pairs_held"] = int(np.sum(loads))
-            attrs[f"{phase}_expert_load_max"] = int(np.max(loads))
-        return attrs
+        return report_loads(
+            _moe_layers(self.cfg) * self.cfg.num_experts_per_tok,
+            prompt_tokens, new_tokens, prefill_loads, decode_loads)

@@ -1,21 +1,29 @@
-"""What the language models share (`deepseek_v2.py`, `ouro.py`): the
-blocks both are written from, the one initialisation rule, sampling on
-the device, and the stand-in tokenizer.
+"""What the language models share (`deepseek_v2.py`, `ouro.py`,
+`solar_open2.py`): the blocks they are written from, the one
+initialisation rule, sampling on the device, and the stand-in tokenizer.
 
-A bundle's `lm` part is an object with this contract, which
+A bundle's `lm` part is an object with this contract (`LanguageModel`
+below holds what every model's class has alike), which
 `graph/nodes_text.TextGenerate` holds every model to:
 
-- `cfg`, `tokenizer`, `init(key, dtype)`;
+- `cfg`, `tokenizer`, `init(key, dtype)` (which also records `dtype`,
+  the one the weights and the cache are stored in);
 - `prefill(params, ids, cache_len, collect)` and `decode(params, cache,
   logits, start, key, steps, temperature, collect)`: the two programs;
   what they return has `.cache` and `.logits` (the prefill's) and `.ids`
-  (the decode's);
+  (the decode's). `.cache` is a request's whole state, handed from one
+  program to the other as it is: one array, or a tree of arrays of
+  several kinds and dtypes (keys and values that grow with the position,
+  a recurrent layer's fixed-size state); the node never looks inside;
 - `layer_passes`: layer bodies one token walks through;
 - `read_back(prefill, decode)`: the device arrays a request reads back
   beside the ids, in the one `device.wait`;
-- `describe(cache_len, itemsize)` and `report(prompt_tokens, new_tokens,
-  *read)`: the attributes `node.TextGenerate` carries, the second from
-  `read_back`'s arrays as the host got them.
+- `describe(cache_len)` and `report(prompt_tokens, new_tokens, *read)`:
+  the attributes `node.TextGenerate` carries. The first says from the
+  model's own shapes and dtypes what a request's state takes:
+  `cache_bytes`, what grows with `cache_len`, and `state_bytes`, what
+  does not (0 for a model whose state is keys and values only); the
+  second is made from `read_back`'s arrays as the host got them.
 """
 
 from __future__ import annotations
@@ -140,3 +148,38 @@ class ByteTokenizer:
                 word += chr(97 + n % 26)
             pieces.append(" " + word)
         return "".join(pieces).strip()
+
+
+# --- what a model's class starts from --------------------------------------
+
+
+class LanguageModel:
+    """The part of the contract that is the same for every model: the
+    configuration with the module's three functions bound to it
+    (`init_params(cfg, key, dtype)`, the jitted `prefill(cfg, params, ids,
+    *, cache_len, collect)` and `decode(cfg, params, cache, logits, start,
+    key, temperature, *, steps, collect)`, which a subclass names as
+    `_init`, `_prefill`, `_decode`), the tokenizer, and the dtype the
+    weights and the cache are stored in. A subclass adds `layer_passes`,
+    `read_back`, `describe` and `report`."""
+
+    _init = _prefill = _decode = None
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.tokenizer = ByteTokenizer()
+        self.dtype = jnp.dtype(jnp.float32)  # until `init` says otherwise
+
+    def init(self, key, dtype=jnp.float32):
+        self.dtype = jnp.dtype(dtype)
+        return self._init(self.cfg, key, dtype)
+
+    def prefill(self, params, ids, cache_len: int, collect: bool = False):
+        return self._prefill(self.cfg, params, ids, cache_len=cache_len, collect=collect)
+
+    def decode(self, params, cache, logits, start: int, key, steps: int, temperature: float,
+               collect: bool = False):
+        return self._decode(
+            self.cfg, params, cache, logits, jnp.int32(start), key, jnp.float32(temperature),
+            steps=steps, collect=collect,
+        )

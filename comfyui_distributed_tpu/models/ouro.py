@@ -67,8 +67,9 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops import decode_attention
 from ..ops.attention import dot_product_attention
-from .lm_common import (
+from .lm_common import (  # noqa: F401  (`ByteTokenizer`: a name this module has always had)
     ByteTokenizer,
+    LanguageModel,
     apply_rope,
     count_params,
     init_from_shapes,
@@ -373,41 +374,29 @@ def decode(cfg: OuroConfig, params, cache, logits, start, key, temperature, *,
     return Decode(ids, exit_sum, cache, *kept)
 
 
-class Ouro:
+class Ouro(LanguageModel):
     """What a bundle's `lm` part is (the contract is in `lm_common`)."""
 
-    def __init__(self, cfg: OuroConfig):
-        self.cfg = cfg
-        self.tokenizer = ByteTokenizer()
+    _init = staticmethod(init_params)
+    _prefill = staticmethod(prefill)
+    _decode = staticmethod(decode)
 
     @property
     def layer_passes(self) -> int:
         return self.cfg.layer_passes
 
-    def init(self, key, dtype=jnp.float32):
-        return init_params(self.cfg, key, dtype)
-
-    def prefill(self, params, ids, cache_len: int, collect: bool = False):
-        return prefill(self.cfg, params, ids, cache_len=cache_len, collect=collect)
-
-    def decode(self, params, cache, logits, start: int, key, steps: int, temperature: float,
-               collect: bool = False):
-        return decode(
-            self.cfg, params, cache, logits, jnp.int32(start), key, jnp.float32(temperature),
-            steps=steps, collect=collect,
-        )
-
     def read_back(self, prefill: Prefill, decode: Decode) -> tuple:
         """The exit distribution summed over either program's tokens."""
         return prefill.exit, decode.exit
 
-    def describe(self, cache_len: int, itemsize: int) -> dict[str, int]:
+    def describe(self, cache_len: int) -> dict[str, int]:
         cfg = self.cfg
         return {
             "ut_steps": cfg.total_ut_steps,
             "layers": cfg.num_hidden_layers,
             "cache_slots": cfg.layer_passes,
-            "cache_bytes": int(np.prod(cfg.cache_shape(cache_len))) * itemsize,
+            "cache_bytes": int(np.prod(cfg.cache_shape(cache_len))) * self.dtype.itemsize,
+            "state_bytes": 0,
         }
 
     def report(self, prompt_tokens: int, new_tokens: int, prefill_exit, decode_exit) -> dict:

@@ -20,6 +20,7 @@ from .dit import DiTConfig, VideoDiT
 from .mmdit import MMDiT, MMDiTConfig
 from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
+from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .t5_encoder import T5Encoder, T5EncoderConfig
 from .text_encoder import TextEncoder, TextEncoderConfig
 from .unet import UNet, UNetConfig
@@ -492,6 +493,30 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             vocab_size=2048,
         ),
     },
+    # Solar-Open2-250B as one chip's share of an eight-chip host, every
+    # width as published: one whole period of 48 layers (softmax attention
+    # with grouped queries, then three KDA layers), experts 0-39 of 320
+    # (rank 0 of 8), the first eighth of the vocabulary (the benchmark's
+    # solar-open2-250b configuration says what the cut stands for)
+    "solar-open2-ep8-4l": {
+        "family": "lm",
+        "config": SolarOpen2Config(
+            num_hidden_layers=4, ep_size=8, ep_rank=0, vocab_shards=8,
+        ),
+    },
+    # every mechanism at a size for the CPU: one period, 4 query heads over
+    # 2 key heads, 16 experts (4 a token) of which rank 0 of 8 holds two, a
+    # chunk of 32 tokens (two blocks of `solar_open2.KDA_SUBCHUNK`)
+    "tiny-solar-open2": {
+        "family": "lm",
+        "config": SolarOpen2Config(
+            hidden_size=64, num_hidden_layers=4, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, linear_num_heads=4,
+            linear_head_dim=16, kda_chunk=32, moe_intermediate_size=32,
+            n_routed_experts=16, num_experts_per_tok=4, vocab_size=4096,
+            ep_size=8, ep_rank=0, vocab_shards=8,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -546,6 +571,7 @@ _CONSTRUCTORS: dict[str, Callable[[Any], Any]] = {
 _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     DeepSeekV2Config: DeepSeekV2,
     OuroConfig: Ouro,
+    SolarOpen2Config: SolarOpen2,
 }
 
 

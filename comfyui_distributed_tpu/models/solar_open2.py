@@ -1,0 +1,666 @@
+"""Solar-Open2: layers of two kinds in a period of `gqa_interval + 1`.
+The first of a period is softmax attention with grouped queries, no
+rotary embedding and an output gate; the others are KDA, linear
+attention by a gated delta rule whose state is one float32 matrix a head
+of fixed size, whatever the position. Every layer's feed-forward part is
+a mixture of routed experts beside one shared expert.
+
+    h += mixer(rms(h));  h += moe(rms(h))
+
+KDA, a head (H heads of d_k = d_v = d), x the normed input:
+
+    q_t = l2norm(silu(conv4(W_q x))_t) d^-1/2     k_t = l2norm(silu(conv4(W_k x))_t)
+    v_t = silu(conv4(W_v x))_t                    (a causal depth-wise convolution of 4)
+    g_t = -exp(A_log) softplus(W_f2 W_f1 x + dt_bias)   in R^d, alpha_t = exp(g_t)
+    beta_t = 2 sigmoid(W_beta x)                  (2: `kda_allow_neg_eigval`)
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T,   o_t = S_t^T q_t
+    y_t = W_o [rms_head(o_t) * sigmoid(W_g2 W_g1 x)]
+
+in two forms that must agree: `kda_chunked` (the prefill: `lax.scan` over
+chunks of `kda_chunk` tokens carrying S, a unit-triangular solve inside
+each chunk) and `kda_step` (the decode: the recurrence itself). Gates,
+cumulative sums, the solve and S are float32; the large products take
+their operands in the storage dtype and accumulate in float32.
+
+A request's state is therefore a tree of three kinds (`state_shapes`):
+`kv` [full layers, 2, key heads, positions, d], which grows with the
+position; `state` [linear layers, H, d, d] float32 and `conv` [linear
+layers, 3, 3 H d], the convolutions' last three inputs, which do not.
+The prefill allocates it, the decode takes it by donation, writes it in
+place and hands it back.
+
+The expert layer is `moe.expert_layer` (shared with `deepseek_v2.py`)
+under this model's rule: sigmoid scores, the `num_experts_per_tok`
+largest of score + bias, the chosen scores renormalised. The chip holds
+`expert_range(ep_rank, ep_size)` of the experts and the first of
+`vocab_shards` slices of the vocabulary, as `DeepSeekV2Config` has it.
+
+Parameter layout where it departs from the published checkpoint's (a
+fixed split of weight columns): a KDA layer's q, k and v projections lie
+side by side as `w_qkv` and their convolutions' filters as `conv`;
+`w_gate_up` is a SwiGLU's gate and up.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+from typing import Any, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import dot_product_attention
+from ..ops.decode_attention import decode_attention_xla
+from ..parallel.sharding import expert_range
+from .lm_common import LanguageModel, count_params, init_from_shapes, rms_norm, sample
+from .moe import expert_layer, report_loads
+
+# Rows of a chunk whose pairwise decays are formed pair by pair
+# (`decay_products`); between such blocks they go through one product.
+KDA_SUBCHUNK = 16
+L2_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class SolarOpen2Config:
+    """The published `config.json`'s shape keys under their own names
+    (`linear_*` and `short_conv_kernel_size` are its `linear_attn_config`
+    block), the chunk the prefill scans by, and the chip's share of a
+    deployment as `DeepSeekV2Config` states it."""
+
+    hidden_size: int = 4096
+    num_hidden_layers: int = 48
+    gqa_interval: int = 3
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    use_rope: bool = False
+    use_gqa_gate: bool = True
+    linear_num_heads: int = 64
+    linear_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kda_use_full_proj: bool = False
+    kda_allow_neg_eigval: bool = True
+    kda_chunk: int = 64
+    moe_intermediate_size: int = 1280
+    n_routed_experts: int = 320
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    vocab_size: int = 196608
+    rms_norm_eps: float = 1e-5
+    ep_size: int = 1
+    ep_rank: int = 0
+    vocab_shards: int = 1
+
+    def __post_init__(self):
+        if self.use_rope or not self.use_gqa_gate or self.kda_use_full_proj:
+            raise ValueError(
+                "only the published form is written: no rotary embedding on the full-"
+                "attention layers (use_rope false), their output gated (use_gqa_gate "
+                "true), KDA's gates low-rank (kda_use_full_proj false)"
+            )
+        if self.kda_chunk % min(self.kda_chunk, KDA_SUBCHUNK):
+            raise ValueError(f"a chunk of {self.kda_chunk} is no multiple of {KDA_SUBCHUNK}")
+
+    @property
+    def held_experts(self) -> range:
+        return expert_range(self.n_routed_experts, self.ep_rank, self.ep_size)
+
+    @property
+    def vocab_held(self) -> int:
+        return self.vocab_size // self.vocab_shards
+
+    def is_full(self, layer: int) -> bool:
+        """Softmax attention (the first layer of a period), else KDA."""
+        return layer % (self.gqa_interval + 1) == 0
+
+    @property
+    def full_layers(self) -> int:
+        return sum(self.is_full(layer) for layer in range(self.num_hidden_layers))
+
+    @property
+    def linear_layers(self) -> int:
+        return self.num_hidden_layers - self.full_layers
+
+    @property
+    def linear_width(self) -> int:
+        return self.linear_num_heads * self.linear_head_dim
+
+
+# --- parameters -----------------------------------------------------------
+
+
+def param_shapes(cfg: SolarOpen2Config) -> dict[str, Any]:
+    """The tree's shapes with each weight's fan-in (None: a norm's
+    scale, initialised to one). `a_log` and `dt_bias` are drawn like
+    weights of fan-in 1 and shifted by `init_params`."""
+    h, width = cfg.hidden_size, cfg.moe_intermediate_size
+    heads, kv = cfg.num_attention_heads * cfg.head_dim, cfg.num_key_value_heads * cfg.head_dim
+    lin, rank = cfg.linear_width, cfg.linear_head_dim
+    held = len(cfg.held_experts)
+
+    def mlp(width: int) -> dict:
+        return {"w_gate_up": ((h, 2 * width), h), "w_down": ((width, h), width)}
+
+    layers = []
+    for layer in range(cfg.num_hidden_layers):
+        if cfg.is_full(layer):
+            mixer = {
+                "w_q": ((h, heads), h), "w_k": ((h, kv), h), "w_v": ((h, kv), h),
+                "w_gate": ((h, heads), h), "w_o": ((heads, h), heads),
+            }
+        else:
+            mixer = {
+                "w_qkv": ((h, 3 * lin), h),
+                "conv": ((cfg.short_conv_kernel_size, 3 * lin), cfg.short_conv_kernel_size),
+                "w_f1": ((h, rank), h), "w_f2": ((rank, lin), rank),
+                "a_log": ((cfg.linear_num_heads,), 1), "dt_bias": ((lin,), 1),
+                "w_beta": ((h, cfg.linear_num_heads), h),
+                "w_g1": ((h, rank), h), "w_g2": ((rank, lin), rank),
+                "o_norm": ((cfg.linear_head_dim,), None),
+                "w_o": ((lin, h), lin),
+            }
+        layers.append({
+            "mixer_norm": ((h,), None),
+            "gqa" if cfg.is_full(layer) else "kda": mixer,
+            "ffn_norm": ((h,), None),
+            "moe": {
+                "w_g": ((h, cfg.n_routed_experts), h),
+                "bias": ((cfg.n_routed_experts,), None),
+                "experts": {
+                    "w_gate_up": ((held, h, 2 * width), h),
+                    "w_down": ((held, width, h), width),
+                },
+                "shared": mlp(width * cfg.n_shared_experts),
+            },
+        })
+    return {
+        "embed": ((cfg.vocab_held, h), 1),
+        "layers": layers,
+        "final_norm": ((h,), None),
+        "head": ((h, cfg.vocab_held), h),
+    }
+
+
+def param_count(cfg: SolarOpen2Config) -> int:
+    return count_params(param_shapes(cfg))
+
+
+# What `init_params` subtracts from the drawn `dt_bias`: softplus of
+# N(-4, 2) is 0.01-0.1, so with A = exp(N(0, 1)) a channel's alpha is
+# mostly 0.9-0.999, a memory of tens to hundreds of tokens with a tail
+# either way, as a trained gate's is (a bias drawn about 0 would give
+# alpha 0.4: a state that holds two tokens).
+DT_BIAS_SHIFT = 4.0
+
+
+def init_params(cfg: SolarOpen2Config, key, dtype=jnp.float32) -> dict[str, Any]:
+    """Seeded random weights in `dtype` (`lm_common.init_from_shapes`);
+    the router's selection bias zero, `dt_bias` shifted down, `a_log`
+    and `dt_bias` float32 whatever `dtype`: they are 8,256 numbers a
+    layer, and the decay they make is raised to the chunk's length."""
+    params = init_from_shapes(param_shapes(cfg), key, dtype)
+    for block in params["layers"]:
+        block["moe"]["bias"] = jnp.zeros_like(block["moe"]["bias"], jnp.float32)
+        if "kda" in block:
+            kda = block["kda"]
+            kda["a_log"] = kda["a_log"].astype(jnp.float32)
+            kda["dt_bias"] = kda["dt_bias"].astype(jnp.float32) - DT_BIAS_SHIFT
+    return params
+
+
+# --- a request's state ----------------------------------------------------
+
+
+def state_shapes(cfg: SolarOpen2Config, cache_len: int, dtype) -> dict[str, jax.ShapeDtypeStruct]:
+    """The tree a request carries from its prefill through its decode."""
+    heads, d = cfg.linear_num_heads, cfg.linear_head_dim
+    return {
+        "kv": jax.ShapeDtypeStruct(
+            (cfg.full_layers, 2, cfg.num_key_value_heads, cache_len, cfg.head_dim), dtype),
+        "state": jax.ShapeDtypeStruct((cfg.linear_layers, heads, d, d), jnp.float32),
+        "conv": jax.ShapeDtypeStruct(
+            (cfg.linear_layers, cfg.short_conv_kernel_size - 1, 3 * cfg.linear_width), dtype),
+    }
+
+
+def _nbytes(shape: jax.ShapeDtypeStruct) -> int:
+    return math.prod(shape.shape) * jnp.dtype(shape.dtype).itemsize
+
+
+# --- the routing rule -----------------------------------------------------
+
+
+def route(cfg: SolarOpen2Config, bias: jax.Array, logits: jax.Array):
+    """Over float32 router logits [T, experts]: scores are their
+    sigmoids, the `num_experts_per_tok` largest of score + `bias` are
+    chosen (ties to the lower index), and the weights are the chosen
+    scores, without the bias, over their sum (`norm_topk_prob`) times
+    `routed_scaling_factor`. Returns (ids, weights)."""
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    return ids, weights * cfg.routed_scaling_factor
+
+
+def moe(cfg, p, x):
+    """(output, chosen ids [T, k], pairs on each held expert [held])"""
+    return expert_layer(p, x, cfg.held_experts, partial(route, cfg, p["bias"]))
+
+
+# --- the full-attention layer ---------------------------------------------
+
+
+def _gqa_projections(cfg, p, x):
+    """q [T, heads, d], k and v [T, key heads, d], the output's gate
+    [T, heads x d] (float32) of x [T, hidden]. No rotation, no norm."""
+    tokens = x.shape[0]
+    q = (x @ p["w_q"]).reshape(tokens, cfg.num_attention_heads, cfg.head_dim)
+    k = (x @ p["w_k"]).reshape(tokens, cfg.num_key_value_heads, cfg.head_dim)
+    v = (x @ p["w_v"]).reshape(tokens, cfg.num_key_value_heads, cfg.head_dim)
+    gate = jax.nn.sigmoid(jnp.dot(x, p["w_gate"], preferred_element_type=jnp.float32))
+    return q, k, v, gate
+
+
+def _gated_out(p, out, gate):
+    return (out.astype(jnp.float32) * gate).astype(out.dtype) @ p["w_o"]
+
+
+def gqa_whole(cfg, p, x):
+    """Over a whole sequence x [T, hidden] (the prefill's form). Returns
+    (output [T, hidden], keys and values [2, key heads, T, d])."""
+    q, k, v, gate = _gqa_projections(cfg, p, x)
+    out = dot_product_attention(q[None], k[None], v[None], causal=True)[0]
+    return _gated_out(p, out.reshape(x.shape[0], -1), gate), jnp.stack([k, v]).transpose(0, 2, 1, 3)
+
+
+def gqa_cached(cfg, p, x, kv, index, position):
+    """One new token x [1, hidden] at `position`: its key and value
+    written into slot `index` of kv [full layers, 2, key heads,
+    positions, d], attention over the slot's positions up to it
+    (`decode_attention_xla`, a key head serving its group of queries).
+    Returns (output [1, hidden], kv)."""
+    q, k, v, gate = _gqa_projections(cfg, p, x)
+    kv = jax.lax.dynamic_update_slice(
+        # one token: [2, 1, heads, d] and [2, heads, 1, d] are the same bytes
+        kv, jnp.stack([k, v]).reshape(1, 2, cfg.num_key_value_heads, 1, cfg.head_dim),
+        (index, 0, 0, position, 0))
+    out = decode_attention_xla(q[0], kv, (index,), position)
+    return _gated_out(p, out.reshape(1, -1), gate), kv
+
+
+# --- KDA ------------------------------------------------------------------
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def kda_inputs(cfg, p, x, tail):
+    """What the delta rule takes of x [T, hidden] (normed), `tail`
+    [kernel - 1, 3 H d] the convolutions' inputs of the tokens before:
+    q, k (unit length; q times d^-1/2), v and the output's gate, [T, H,
+    d] in x's dtype, the log-decay g [T, H, d] (< 0) and beta [T, H],
+    float32, and the new tail. Convolution, SiLU and norms float32."""
+    tokens, heads, d = x.shape[0], cfg.linear_num_heads, cfg.linear_head_dim
+    kernel = cfg.short_conv_kernel_size
+    with jax.named_scope("conv"):
+        window = jnp.concatenate([tail, (x @ p["w_qkv"]).astype(tail.dtype)], axis=0)
+        filters = p["conv"].astype(jnp.float32)
+        mixed = sum(
+            window[i:i + tokens].astype(jnp.float32) * filters[i] for i in range(kernel))
+        q, k, v = jnp.split(jax.nn.silu(mixed).reshape(tokens, 3 * heads, d), 3, axis=1)
+        q, k, v = (a.astype(x.dtype) for a in (_l2norm(q) * d ** -0.5, _l2norm(k), v))
+    with jax.named_scope("gates"):
+        def low_rank(first, second):
+            return jnp.dot(x @ p[first], p[second], preferred_element_type=jnp.float32)
+
+        rate = jax.nn.softplus(low_rank("w_f1", "w_f2") + p["dt_bias"])
+        g = -jnp.exp(p["a_log"])[None, :, None] * rate.reshape(tokens, heads, d)
+        beta = jax.nn.sigmoid(jnp.dot(x, p["w_beta"], preferred_element_type=jnp.float32))
+        if cfg.kda_allow_neg_eigval:
+            beta = 2.0 * beta
+        gate = jax.nn.sigmoid(low_rank("w_g1", "w_g2")).reshape(tokens, heads, d).astype(x.dtype)
+    return q, k, v, g, beta, gate, window[tokens:]
+
+
+def kda_output(cfg, p, o, gate):
+    """y [T, hidden] of the rule's outputs o [T, H, d] float32: each
+    head normed over its d channels, gated, projected."""
+    normed = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) * gate.astype(jnp.float32)
+    return normed.astype(gate.dtype).reshape(o.shape[0], -1) @ p["w_o"]
+
+
+def decay_products(x, k, decay, sub: int = KDA_SUBCHUNK):
+    """M[..., i, j] = sum_c x[..., i, c] k[..., j, c] exp(G[..., i, c] -
+    G[..., j, c]) for j <= i, 0 above the diagonal; k and `decay` (G:
+    cumulative log-decays along the row axis, never increasing) are
+    [..., C, c] float32, x the same or with further leading axes. Every
+    ratio of decays is the exponential of a difference that is <= 0,
+    never exp(-G_j) alone: within a block of `sub` rows the differences
+    are formed pair by pair; a row block's products with the columns of
+    earlier blocks go through the decay at the block's first row,
+    exp(G_i - G_first) exp(G_first - G_j), both factors at most one."""
+    size, width = k.shape[-2:]
+    sub = min(sub, size)
+    blocks = size // sub
+
+    def blocked(a):
+        return a.reshape(*a.shape[:-2], blocks, sub, width)
+
+    xb, kb, gb = blocked(x), blocked(k), blocked(decay)
+    # the blocks on the diagonal
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    pairs = jnp.exp(jnp.where(
+        lower[:, :, None], gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
+    on = jnp.sum(xb[..., :, None, :] * (kb[..., None, :, :] * pairs), axis=-1)
+    if blocks == 1:
+        return on.reshape(*x.shape[:-2], size, size)
+    # the blocks below it
+    first = gb[..., :1, :]                                          # [..., blocks, 1, c]
+    rows = xb * jnp.exp(gb - first)
+    columns = k[..., None, :, :] * jnp.exp(
+        jnp.minimum(first - decay[..., None, :, :], 0.0))           # [..., blocks, C, c]
+    below = jnp.einsum("...nic,...njc->...nij", rows, columns,
+                       precision=jax.lax.Precision.HIGHEST)          # [..., blocks, sub, C]
+    earlier = jnp.arange(size)[None, :] // sub < jnp.arange(blocks)[:, None]
+    below = jnp.where(earlier[:, None, :], below, 0.0)
+    below = below.reshape(*x.shape[:-2], blocks, sub, blocks, sub)
+    here = jnp.eye(blocks, dtype=bool)[:, None, :, None]
+    return jnp.where(here, on[..., :, :, None, :], below).reshape(*x.shape[:-2], size, size)
+
+
+def unit_lower_solve(a, rhs, sub: int = KDA_SUBCHUNK):
+    """X with (I + a) X = rhs, for a [..., C, C] strictly lower-
+    triangular and rhs [..., C, n], float32: forward substitution by
+    blocks of `sub` rows, a block's own (I + D)^-1 as the finite product
+    (I - D)(I + D^2)(I + D^4)... that a strictly triangular D allows (D
+    to the power `sub` is zero), so the whole is a few small products
+    and no loop over rows."""
+    size = a.shape[-1]
+    sub = min(sub, size)
+    product = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    eye, solved = jnp.eye(sub, dtype=a.dtype), []
+    for start in range(0, size, sub):
+        rows = slice(start, start + sub)
+        power = a[..., rows, rows]
+        inverse = eye - power
+        for _ in range(max(sub - 1, 1).bit_length() - 1):
+            power = product(power, power)
+            inverse = product(inverse, eye + power)
+        left = rhs[..., rows, :]
+        if start:  # less what the rows above have already settled
+            left = left - product(a[..., rows, :start], jnp.concatenate(solved, axis=-2))
+        solved.append(product(inverse, left))
+    return jnp.concatenate(solved, axis=-2)
+
+
+# Chunks whose terms `kda_chunked` forms at once: the pairwise decays of
+# one are 34 MB at the published sizes (64 heads x 4 blocks x 16 x 16 x
+# 128 float32).
+CHUNKS_AT_ONCE = 8
+
+
+def kda_chunked(q, k, v, g, beta, state, chunk: int):
+    """The delta rule over a whole sequence, a chunk at a time: q, k, v
+    [T, H, d] in the storage dtype, g [T, H, d] and beta [T, H] float32
+    (`kda_inputs`), `state` [H, d, d] float32 before the first token.
+    With G the cumulative g inside a chunk and A_ij = beta_i sum_c k_ic
+    k_jc exp(G_ic - G_jc) (j < i):
+
+        (I + A) [W | U0] = diag(beta) [k exp(G) | v]     (unit lower-triangular)
+        U = U0 - W S0                                    (what each token writes)
+        o_i = (q_i exp(G_i)) S0 + sum_{j<=i} (sum_c q_ic k_jc exp(G_ic - G_jc)) u_j
+        S_C = exp(G_C) S0 + sum_j (k_j exp(G_C - G_j)) u_j^T
+
+    What does not read S (`terms`: float32) is formed first, `CHUNKS_AT_
+    ONCE` chunks at a time; the scan then carries S through four products
+    a chunk, their operands in the storage dtype and their sums float32.
+    A last chunk that is short is filled with tokens that change nothing
+    (g 0, beta 0). Returns (o [T, H, d] float32, the state after the last
+    token)."""
+    tokens, heads, d = q.shape
+    dtype = q.dtype
+    count = -(-tokens // chunk)
+    pad = count * chunk - tokens
+
+    def chunks(a):  # [T, H, ...] -> [chunks, H, chunk, ...]
+        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+        return jnp.moveaxis(a.reshape(count, chunk, *a.shape[1:]), 1, 2)
+
+    strictly = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+
+    def terms(xs):
+        q, k, v, g, beta = xs
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        decay = jnp.cumsum(g, axis=1)                                # G, [H, C, d]
+        kk, qk = decay_products(jnp.stack([k, q]), k, decay)
+        into = jnp.exp(decay)                                        # from the chunk's start
+        solved = unit_lower_solve(
+            jnp.where(strictly, beta[..., None] * kk, 0.0),
+            beta[..., None] * jnp.concatenate([k * into, v], axis=-1))
+        out_of = jnp.exp(decay[:, -1:, :] - decay)                   # to the chunk's end
+        return (solved[..., :d].astype(dtype), solved[..., d:], (q * into).astype(dtype),
+                qk.astype(dtype), (k * out_of).astype(dtype), into[:, -1, :])
+
+    def one_chunk(state, xs):
+        w, u0, q_in, qk, k_out, end = xs
+        held = state.astype(dtype)
+        u = u0 - jnp.einsum("hik,hkv->hiv", w, held, preferred_element_type=jnp.float32)
+        o = jnp.einsum("hik,hkv->hiv", q_in, held, preferred_element_type=jnp.float32) + (
+            jnp.einsum("hij,hjv->hiv", qk, u.astype(dtype), preferred_element_type=jnp.float32))
+        state = end[:, :, None] * state + jnp.einsum(
+            "hjk,hjv->hkv", k_out, u.astype(dtype), preferred_element_type=jnp.float32)
+        return state, o
+
+    xs = jax.lax.map(terms, tuple(map(chunks, (q, k, v, g, beta))), batch_size=CHUNKS_AT_ONCE)
+    state, o = jax.lax.scan(one_chunk, state, xs)                    # o [chunks, H, C, d]
+    return jnp.moveaxis(o, 1, 2).reshape(count * chunk, heads, d)[:tokens], state
+
+
+def kda_step(q, k, v, g, beta, state):
+    """The recurrence itself, one token: q, k, v, g [H, d], beta [H],
+    `state` [H, d, d], all float32. Returns (o [H, d], the state)."""
+    read = partial(jnp.einsum, "hk,hkv->hv", precision=jax.lax.Precision.HIGHEST)
+    state = jnp.exp(g)[:, :, None] * state
+    u = beta[:, None] * (v - read(k, state))
+    state = state + k[:, :, None] * u[:, None, :]
+    return read(q, state), state
+
+
+def kda_whole(cfg, p, x, tail, state):
+    """A KDA mixer over a whole sequence x [T, hidden] from `tail` and
+    `state` (the prefill's form). Returns (output, tail, state)."""
+    q, k, v, g, beta, gate, tail = kda_inputs(cfg, p, x, tail)
+    with jax.named_scope("delta"):
+        o, state = kda_chunked(q, k, v, g, beta, state, cfg.kda_chunk)
+    return kda_output(cfg, p, o, gate), tail, state
+
+
+def kda_cached(cfg, p, x, tail, state):
+    """A KDA mixer for one new token x [1, hidden] (the decode's form)."""
+    q, k, v, g, beta, gate, tail = kda_inputs(cfg, p, x, tail)
+    with jax.named_scope("delta"):
+        q, k, v = (a[0].astype(jnp.float32) for a in (q, k, v))
+        o, state = kda_step(q, k, v, g[0], beta[0], state)
+    return kda_output(cfg, p, o[None], gate), tail, state
+
+
+# --- a layer, in either form ----------------------------------------------
+
+
+def _slot(cfg, layer: int) -> int:
+    """A layer's place among the layers of its kind."""
+    return sum(cfg.is_full(i) == cfg.is_full(layer) for i in range(layer))
+
+
+def _layer(cfg, layer: int, block, h, cache, gqa, kda):
+    """One pre-norm residual layer. `gqa(p, x, kv, index)` returns
+    (output, kv); `kda(p, x, tail, state)` (output, tail, state).
+    Returns (h, cache, chosen ids, pairs per held expert)."""
+    index = _slot(cfg, layer)
+    with jax.named_scope(f"layer_{layer}"):
+        x = rms_norm(h, block["mixer_norm"], cfg.rms_norm_eps)
+        if cfg.is_full(layer):
+            with jax.named_scope("gqa"):
+                out, kv = gqa(block["gqa"], x, cache["kv"], index)
+            cache = {**cache, "kv": kv}
+        else:
+            with jax.named_scope("kda"):
+                out, tail, state = kda(
+                    block["kda"], x, cache["conv"][index], cache["state"][index])
+            cache = {
+                **cache, "conv": cache["conv"].at[index].set(tail),
+                "state": cache["state"].at[index].set(state),
+            }
+        h = h + out
+        out, ids, sizes = moe(cfg, block["moe"], rms_norm(h, block["ffn_norm"], cfg.rms_norm_eps))
+        return h + out, cache, ids, sizes
+
+
+def _head(cfg, params, h):
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+
+
+# --- the two programs -----------------------------------------------------
+
+
+class Prefill(NamedTuple):
+    logits: jax.Array   # [vocab_held] float32, at the prompt's last position
+    cache: dict         # `state_shapes`: the request's state after the prompt
+    loads: jax.Array    # [layers, held] pairs on each held expert
+    chosen: jax.Array | None  # [layers, T, k] experts chosen; under `collect`
+
+
+class Decode(NamedTuple):
+    ids: jax.Array      # [steps]
+    loads: jax.Array    # [layers, held], summed over the steps
+    cache: dict         # the state it was given, after the steps
+    logits: jax.Array | None  # [steps, vocab_held] float32, after id i; under `collect`
+    chosen: jax.Array | None  # [steps, layers, k]; under `collect`
+
+
+@partial(jax.jit, static_argnames=("cfg", "cache_len", "collect"))
+def prefill(cfg: SolarOpen2Config, params, ids, *, cache_len: int, collect: bool = False):
+    """The whole prompt `ids` [T] at once. Returns the logits at its last
+    position, the request's state (allocated here, once: the first T
+    positions of `kv` written, `state` and `conv` as the last token left
+    them), the pairs that fell on each held expert and, under `collect`
+    (the parity check's), the experts chosen."""
+    tokens = ids.shape[0]
+    h = params["embed"][ids]
+    cache = {
+        name: jnp.zeros(s.shape, s.dtype)
+        for name, s in state_shapes(cfg, cache_len, h.dtype).items()
+    }
+
+    def gqa(p, x, kv, index):
+        out, slot_kv = gqa_whole(cfg, p, x)
+        return out, jax.lax.dynamic_update_slice(kv, slot_kv[None], (index, 0, 0, 0, 0))
+
+    chosen, loads = [], []
+    for layer, block in enumerate(params["layers"]):
+        h, cache, ids_l, sizes = _layer(
+            cfg, layer, block, h, cache, gqa, partial(kda_whole, cfg))
+        chosen.append(ids_l)
+        loads.append(sizes)
+    return Prefill(
+        _head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
+        jnp.stack(chosen) if collect else None,
+    )
+
+
+def decode_step(cfg, params, cache, token, position):
+    """One token through every layer over the request's state. Returns
+    (logits [vocab_held], cache, ids [layers, k], pairs per held expert
+    [layers, held])."""
+    h = params["embed"][token][None]
+
+    def gqa(p, x, kv, index):
+        return gqa_cached(cfg, p, x, kv, index, position)
+
+    chosen, loads = [], []
+    for layer, block in enumerate(params["layers"]):
+        h, cache, ids_l, sizes = _layer(
+            cfg, layer, block, h, cache, gqa, partial(kda_cached, cfg))
+        chosen.append(ids_l[0])
+        loads.append(sizes)
+    return _head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
+
+
+@partial(jax.jit, static_argnames=("cfg", "steps", "collect"), donate_argnames=("cache",))
+def decode(cfg: SolarOpen2Config, params, cache, logits, start, key, temperature, *,
+           steps: int, collect: bool = False):
+    """`steps` dependent decode steps in one program, from the prefill's
+    `logits` at position `start - 1`: draw id i from the logits, run it
+    through the model at position `start + i`. Always `steps` ids, no
+    early stop. The state tree is donated, carried through the loop and
+    handed back as `cache`. Returns the ids, the pairs on each held
+    expert summed over the steps and, under `collect`, every step's
+    logits (the logits after id i) and the experts chosen."""
+    layers, k = cfg.num_hidden_layers, cfg.num_experts_per_tok
+
+    def body(i, carry):
+        cache, logits, ids, loads, kept = carry
+        token = sample(logits, jax.random.fold_in(key, i), temperature)
+        logits, cache, chosen_i, loads_i = decode_step(cfg, params, cache, token, start + i)
+        if collect:
+            kept = (kept[0].at[i].set(logits), kept[1].at[i].set(chosen_i))
+        return cache, logits, ids.at[i].set(token), loads + loads_i, kept
+
+    kept = (
+        jnp.zeros((steps, cfg.vocab_held), jnp.float32),
+        jnp.zeros((steps, layers, k), jnp.int32),
+    ) if collect else (None, None)
+    carry = (
+        cache, logits, jnp.zeros((steps,), jnp.int32),
+        jnp.zeros((layers, len(cfg.held_experts)), jnp.int32), kept,
+    )
+    cache, _, ids, loads, kept = jax.lax.fori_loop(0, steps, body, carry)
+    return Decode(ids, loads, cache, *kept)
+
+
+class SolarOpen2(LanguageModel):
+    """What a bundle's `lm` part is (the contract is in `lm_common`)."""
+
+    _init = staticmethod(init_params)
+    _prefill = staticmethod(prefill)
+    _decode = staticmethod(decode)
+
+    @property
+    def layer_passes(self) -> int:
+        return self.cfg.num_hidden_layers
+
+    def read_back(self, prefill: Prefill, decode: Decode) -> tuple:
+        """The pairs on each held expert, of either program."""
+        return prefill.loads, decode.loads
+
+    def describe(self, cache_len: int) -> dict[str, int]:
+        cfg, shapes = self.cfg, state_shapes(self.cfg, cache_len, self.dtype)
+        return {
+            "layers": cfg.num_hidden_layers,
+            "full_layers": cfg.full_layers,
+            "linear_layers": cfg.linear_layers,
+            "experts_held": len(cfg.held_experts),
+            "experts_total": cfg.n_routed_experts,
+            "cache_bytes": _nbytes(shapes["kv"]),
+            "state_bytes": _nbytes(shapes["state"]) + _nbytes(shapes["conv"]),
+        }
+
+    def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
+        """The chunks a linear layer's prefill scanned, and per phase the
+        token-expert pairs the router made and those on held experts."""
+        return {
+            "prefill_chunks": -(-prompt_tokens // self.cfg.kda_chunk),
+            **report_loads(
+                self.cfg.num_hidden_layers * self.cfg.num_experts_per_tok,
+                prompt_tokens, new_tokens, prefill_loads, decode_loads),
+        }

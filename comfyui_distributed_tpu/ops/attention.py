@@ -144,10 +144,21 @@ def causal_attention_blocked(
     above the diagonal are skipped, the block on it is masked), so the
     score tensor is never whole in memory. Scores, softmax and both
     accumulations are float32; the probabilities are rounded to v's
-    dtype for the second product, as the kernel does."""
+    dtype for the second product, as the kernel does. k and v may have
+    fewer heads than q, a divisor of its count: key head j then serves
+    the query heads j x group .. (j + 1) x group - 1, and is read where
+    it lies, not repeated."""
     n, m, d = q.shape[1], k.shape[1], q.shape[3]
     if n > m:
         raise ValueError(f"causal attention of {n} queries over {m} keys")
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if heads % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} / {v.shape[2]} key / value heads")
+    if kv_heads == heads:
+        to_scores, to_out = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
+    else:  # a group axis g beside the key head h
+        q = q.reshape(q.shape[0], n, kv_heads, heads // kv_heads, d)
+        to_scores, to_out = "bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd"
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     block = min(CAUSAL_BLOCK_Q, n)  # the last block is short where n is no multiple
@@ -160,15 +171,15 @@ def causal_attention_blocked(
         stop = min(start + block, n)
         last = stop + m - n  # keys the block's last row sees
         scores = scale * jnp.einsum(
-            "bqhd,bkhd->bhqk", q[:, start:stop], k[:, :last],
-            preferred_element_type=jnp.float32)
+            to_scores, q[:, start:stop], k[:, :last], preferred_element_type=jnp.float32)
         rows = jnp.arange(start, stop)[:, None] + (m - n)
         scores = jnp.where(rows >= jnp.arange(last)[None, :], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
         outs.append(jnp.einsum(
-            "bhqk,bkhd->bqhd", probs, v[:, :last], preferred_element_type=jnp.float32,
+            to_out, probs, v[:, :last], preferred_element_type=jnp.float32,
         ).astype(v.dtype))
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    return out.reshape(out.shape[0], n, heads, v.shape[3])
 
 
 def attention_route(q: jax.Array, k: jax.Array) -> str:

@@ -78,16 +78,21 @@ def decode_attention_route(heads: int, positions: int, d: int, dtype) -> str:
 
 def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, position) -> jax.Array:
     """The einsum form: q [heads, d] over `cache[slot]`'s positions up
-    to `position`. Scores, softmax and the sum's accumulation float32,
-    the probabilities rounded to the cache's dtype. Returns [heads, d]
-    in the cache's dtype."""
-    keys, values = cache[slot]                                   # [heads, S, d] each
-    scores = q.shape[-1] ** -0.5 * jnp.einsum(
-        "hd,hsd->hs", q, keys, preferred_element_type=jnp.float32)
+    to `position`. The slot may hold fewer key and value heads than
+    there are queries, a divisor of their count: key head j serves the
+    query heads j x group .. (j + 1) x group - 1 (the axis g below; one
+    wide where the counts are equal). Scores, softmax and the sum's
+    accumulation float32, the probabilities rounded to the cache's
+    dtype. Returns [heads, d] in the cache's dtype."""
+    keys, values = cache[slot]                                   # [kv heads, S, d] each
+    heads, d = q.shape
+    grouped = q.reshape(keys.shape[0], -1, d)
+    scores = d ** -0.5 * jnp.einsum(
+        "hgd,hsd->hgs", grouped, keys, preferred_element_type=jnp.float32)
     valid = jnp.arange(keys.shape[1]) <= position
-    scores = jnp.where(valid[None, :], scores, -jnp.inf)
+    scores = jnp.where(valid[None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
-    return jnp.einsum("hs,hsd->hd", probs, values)
+    return jnp.einsum("hgs,hsd->hgd", probs, values).reshape(heads, d)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

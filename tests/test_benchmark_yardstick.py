@@ -19,6 +19,19 @@ _TESTS = os.path.join(
 )
 
 
+# Two checks of PR 36 pinned the manifest and the `lm_work` directory as
+# that PR left them (its twelve metrics the manifest's last, two models'
+# work files), and a PR may append to both but may not edit the file that
+# holds them. `benchmark/tests/test_solar_readers.py` has each in the form
+# that holds afterwards (PR 38); those are adopted, these are not.
+_SUPERSEDED = {
+    "test_device_every_new_metric_has_its_reader_and_names_its_cells":
+        "test_device_the_twelve_metrics_of_pr_36_have_their_readers_and_lie_together",
+    "test_device_the_lm_readers_find_a_models_work_by_the_checkpoint_the_workflow_loads":
+        "test_device_every_configuration_with_an_lm_work_file_is_found_by_its_registry_name",
+}
+
+
 def _adopt(filename: str) -> None:
     name = os.path.splitext(filename)[0]
     spec = importlib.util.spec_from_file_location(
@@ -27,7 +40,7 @@ def _adopt(filename: str) -> None:
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     for key, value in vars(module).items():
-        if key.startswith("test_"):
+        if key.startswith("test_") and key not in _SUPERSEDED:
             assert key not in globals(), f"two yardstick checks are named {key}"
             globals()[key] = value
 
@@ -35,3 +48,6 @@ def _adopt(filename: str) -> None:
 for _filename in sorted(os.listdir(_TESTS)):
     if _filename.startswith("test_") and _filename.endswith(".py"):
         _adopt(_filename)
+
+for _old, _new in _SUPERSEDED.items():
+    assert _new in globals(), f"{_old} was set aside and {_new} is not there"

@@ -211,3 +211,31 @@ def test_ouro_decode_reads_the_slot_where_it_lies(
     assert text.count('custom_call_target="tpu_custom_call"') == calls
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert bool(re.findall(SLOT_SHAPED, text)) == (backend == "cpu")
+
+
+def test_solar_decode_carries_its_state_tree_in_place(one_chip):
+    """Solar-Open2's whole decode at the served share's sizes (256 steps
+    over 8,448 positions): the tree of keys and values (34.6 MB), float32
+    KDA states (12.6 MB) and convolution tails is donated, carried through
+    the loop and written where it lies. What the loop needs beside it is
+    12 MB; a temporary the size of either part would be a copy of it a
+    step."""
+    from comfyui_distributed_tpu.models import solar_open2
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("solar-open2-ep8-4l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: solar_open2.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    state = jax.tree.map(place, solar_open2.state_shapes(cfg, 8448, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    compiled = solar_open2.decode.lower(
+        cfg, params, state,
+        jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+        scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+        scalar(jnp.float32), steps=256,
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 24 * 2**20
+    # the donated tree is the output's: nothing of its size is allocated anew
+    assert memory.alias_size_in_bytes >= 34_603_008 + 13_025_280

@@ -195,7 +195,7 @@ def test_every_language_model_meets_the_one_contract(name):
     for attribute in ("cfg", "tokenizer", "layer_passes", "init", "prefill", "decode",
                       "read_back", "describe", "report"):
         assert hasattr(lm, attribute), attribute
-    described = lm.describe(128, 2)
+    described = lm.describe(128)
     assert described["layers"] == lm.cfg.num_hidden_layers
     assert described["cache_bytes"] > 0 and isinstance(described["cache_bytes"], int)
     assert isinstance(lm.tokenizer, ByteTokenizer)
@@ -286,8 +286,8 @@ def test_the_manifest_has_the_cell_with_the_issues_traffic_and_lists():
     manifest = load(os.path.join(ROOT, "BENCHMARK.json"))
     (cell,) = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("ouro-2.6b", "closed2", 1)
-    assert manifest["workloads"][-1] is cell and manifest["configs"][-1]["name"] == "ouro-2.6b"
-    assert manifest["configs"][-1]["reduced"] == []
+    (config,) = [c for c in manifest["configs"] if c["name"] == "ouro-2.6b"]
+    assert config["reduced"] == []
     listed = {m["name"] for m in manifest["per_layer"] + manifest["end_to_end"]
               if CELL in m.get("workloads", [])}
     assert listed == {
@@ -300,7 +300,8 @@ def test_the_manifest_has_the_cell_with_the_issues_traffic_and_lists():
         "device_idle_in_pct.txt2img", "between_jobs_ms.txt2img"}
     for name in ("cache_gb.lm", "layer_passes_per_token.lm"):
         (metric,) = [m for m in manifest["per_layer"] if m["name"] == name]
-        assert metric["workloads"] == ["deepseek_v2_rewrite_txt2img_512.closed2", CELL]
+        # later cells are appended behind
+        assert metric["workloads"][:2] == ["deepseek_v2_rewrite_txt2img_512.closed2", CELL]
         assert (metric["moves"], metric["source"]) == ("images_per_s", "program_counter")
     work = load(WORKLOAD)
     assert work["seed_nodes"] == ["DistributedSeed"]
