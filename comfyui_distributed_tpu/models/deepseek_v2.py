@@ -466,5 +466,5 @@ class DeepSeekV2(LanguageModel):
         """Per phase: the token-expert pairs the router made, those that
         fell on held experts, and the fullest held expert's."""
         return report_loads(
-            _moe_layers(self.cfg) * self.cfg.num_experts_per_tok,
+            self.cfg.num_experts_per_tok, self.cfg.n_routed_experts,
             prompt_tokens, new_tokens, prefill_loads, decode_loads)

@@ -9,6 +9,14 @@ experts' part of the result as one grouped product
 what absent experts would add is left out. The shared expert sees every
 token.
 
+Sorted by held expert, the pairs a chip holds are the first rows and
+every row after them is an absent expert's, so the row gather, the
+grouped products and the weighted way back to `[T, hidden]` run over a
+static prefix of the sorted pairs: the smallest rung of `row_ladder`
+that holds them, which the device picks from the routing's own count
+(`lax.switch`; nothing is read back). The top rung is all `T x k` pairs,
+so no routing, however skewed, drops a pair.
+
 Parameters: `w_g` [hidden, experts] (the router), `experts` {`w_gate_up`
 [held, hidden, 2 x width], `w_down` [held, width, hidden]}, `shared` (one
 SwiGLU); whatever else the model's rule reads (a selection bias) stays
@@ -17,6 +25,7 @@ with the rule.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable
 
 import jax
@@ -24,6 +33,34 @@ import jax.numpy as jnp
 import numpy as np
 
 from .lm_common import swiglu
+
+
+# A rung of the ladder below the top is a whole number of these rows.
+ROW_TILE = 256
+# The columns one scatter-add of a prefix's rows takes.
+SCATTER_LANES = 1024
+
+
+def row_ladder(pairs: int, held: int, experts: int) -> tuple[int, ...]:
+    """The static row counts a layer of `pairs` token-expert pairs may
+    run its held experts' products over, ascending: `pairs` itself last,
+    below it `pairs` halved again and again (rounded up to whole tiles)
+    down to the share `held` of `experts` take when the routing is even.
+    One rung where `pairs` is a tile or less (a decode step), or where
+    every expert is held."""
+    rungs = [pairs]
+    while True:
+        rows = -(-pairs // (ROW_TILE << len(rungs))) * ROW_TILE
+        if rows >= rungs[0] or rows * experts < pairs * held:
+            return tuple(rungs)
+        rungs.insert(0, rows)
+
+
+def rung_index(ladder: tuple[int, ...], pairs_held):
+    """Which rung holds `pairs_held` rows: the smallest that is no
+    smaller. The device branches on it (a traced scalar) and
+    `report_loads` reads the same from the loads on the host."""
+    return sum(pairs_held > rows for rows in ladder[:-1])
 
 
 def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
@@ -42,35 +79,68 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
         local = ids.reshape(-1) - held.start
         here = (local >= 0) & (local < len(held))
         # sort the token-expert pairs by held expert, the pairs of absent
-        # experts last: each held expert's rows are then one segment
+        # experts last: each held expert's rows are then one segment, and
+        # the held pairs are the first `sizes.sum()` rows
         slot = jnp.where(here, local, len(held))
         order = jnp.argsort(slot, stable=True)
         sizes = jnp.zeros((len(held),), jnp.int32).at[slot].add(1, mode="drop")
-        rows = x[order // k]
-        gate, up = jnp.split(
-            jax.lax.ragged_dot(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1
-        )
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
-        # rows past the last segment are absent experts' pairs: weight 0
-        out = jnp.where(here[order][:, None], out, 0).astype(jnp.float32)
-        out = out * weights.reshape(-1)[order][:, None]
-        routed = out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
+
+        def over(rows_n: int):
+            """The held experts' part [T, hidden] float32 from the first
+            `rows_n` sorted pairs, which has to cover every held one."""
+            top = order[:rows_n]
+            token = top // k
+            rows = x[token]
+            gate, up = jnp.split(
+                jax.lax.ragged_dot(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1
+            )
+            out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
+            # rows past the last segment are absent experts' pairs: weight 0
+            out = jnp.where(here[top][:, None], out, 0).astype(jnp.float32)
+            out = out * weights.reshape(-1)[top][:, None]
+            if rows_n == tokens * k:
+                # every pair: back to the pairs' own order, then the sum
+                # over a token's experts
+                return out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
+            # a prefix: each row is added to its token's, a block of
+            # columns at a time (4,608 float32 rows of 5,120 cost a v5e
+            # 4.2 ms whole and 0.7 ms in four blocks: PERF.md §6, PR 40)
+            edges = list(range(SCATTER_LANES, out.shape[1], SCATTER_LANES))
+            return jnp.concatenate([
+                jnp.zeros((tokens, part.shape[1]), jnp.float32).at[token].add(part)
+                for part in jnp.split(out, edges, axis=1)
+            ], axis=1)
+
+        ladder = row_ladder(tokens * k, len(held), p["w_g"].shape[1])
+        if len(ladder) == 1:
+            routed = over(ladder[0])
+        else:
+            routed = jax.lax.switch(
+                rung_index(ladder, sizes.sum()), [partial(over, rows_n) for rows_n in ladder])
     with jax.named_scope("shared"):
         shared = swiglu(x, p["shared"])
     return shared + routed.astype(x.dtype), ids, sizes
 
 
-def report_loads(pairs_a_token: int, prompt_tokens: int, new_tokens: int,
+def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
                  prefill_loads, decode_loads) -> dict:
     """`node.TextGenerate`'s attributes of the routing, per phase: the
-    token-expert pairs the router made, those that fell on held experts
-    (`loads` [expert layers, held], as read back), and the fullest held
-    expert's."""
+    token-expert pairs the router made (`k` a token and expert layer),
+    those that fell on held experts (`loads` [expert layers, held], as
+    read back), the fullest held expert's, and the rows the grouped
+    products were run over: for the prefill each layer's rung, read from
+    its load as the device read it; a decode step's `k` pairs are under
+    a tile, a ladder of one rung, so its rows are its pairs."""
+    layers, held = np.shape(prefill_loads)
     attrs = {}
     for phase, tokens, loads in (
         ("prefill", prompt_tokens, prefill_loads), ("decode", new_tokens, decode_loads)
     ):
-        attrs[f"{phase}_routed_pairs"] = tokens * pairs_a_token
+        attrs[f"{phase}_routed_pairs"] = tokens * k * layers
         attrs[f"{phase}_routed_pairs_held"] = int(np.sum(loads))
         attrs[f"{phase}_expert_load_max"] = int(np.max(loads))
+    ladder = row_ladder(prompt_tokens * k, held, experts)
+    attrs["prefill_expert_rows"] = sum(
+        ladder[rung_index(ladder, int(n))] for n in np.sum(prefill_loads, axis=1))
+    attrs["decode_expert_rows"] = attrs["decode_routed_pairs"]
     return attrs

@@ -661,6 +661,6 @@ class SolarOpen2(LanguageModel):
         return {
             "prefill_chunks": -(-prompt_tokens // self.cfg.kda_chunk),
             **report_loads(
-                self.cfg.num_hidden_layers * self.cfg.num_experts_per_tok,
+                self.cfg.num_experts_per_tok, self.cfg.n_routed_experts,
                 prompt_tokens, new_tokens, prefill_loads, decode_loads),
         }

@@ -160,6 +160,11 @@ def test_node_textgenerate_says_what_ran(served):
         assert 0 < held < attrs[f"{phase}_routed_pairs"]
         # the fullest of 2 layers x 4 held experts
         assert held / 8 <= attrs[f"{phase}_expert_load_max"] <= held
+    # the rows the grouped products ran over: a rung a layer of (1536, 3072,
+    # 6144) for the prefill's 6,144 pairs, every pair of a decode step's 3
+    assert attrs["prefill_expert_rows"] in {2 * 1536, 1536 + 3072, 2 * 3072}
+    assert attrs["prefill_routed_pairs_held"] <= attrs["prefill_expert_rows"]
+    assert attrs["decode_expert_rows"] == attrs["decode_routed_pairs"]
 
 
 def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(served):

@@ -150,6 +150,10 @@ def test_node_textgenerate_says_what_a_model_with_two_kinds_of_state_ran(served)
     assert 0.06 < held / attrs["prefill_routed_pairs"] < 0.2
     assert held / (LAYERS * HELD) <= attrs["prefill_expert_load_max"] <= held
     assert 0 <= attrs["decode_routed_pairs_held"] < attrs["decode_routed_pairs"]
+    # the rows the grouped products ran over: a rung a layer, every pair of a step
+    assert held <= attrs["prefill_expert_rows"] < attrs["prefill_routed_pairs"]
+    assert attrs["prefill_expert_rows"] % 256 == 0
+    assert attrs["decode_expert_rows"] == attrs["decode_routed_pairs"]
     # nothing of a looped model
     assert not any(key.startswith(("exit_mass", "ut_steps", "cache_slots")) for key in attrs)
 
@@ -197,8 +201,8 @@ def test_tokens_layer_passes_and_linear_layer_passes_are_counted_by_phase(
 DEEPSEEK_ATTRS = {
     "prompt_tokens", "new_tokens", "layers", "experts_held", "experts_total", "cache_bytes",
     "prefill_routed_pairs", "prefill_routed_pairs_held", "prefill_expert_load_max",
-    "decode_routed_pairs", "decode_routed_pairs_held", "decode_expert_load_max", "attention",
-    "node_id"}
+    "decode_routed_pairs", "decode_routed_pairs_held", "decode_expert_load_max",
+    "prefill_expert_rows", "decode_expert_rows", "attention", "node_id"}
 OURO_ATTRS = {
     "prompt_tokens", "new_tokens", "ut_steps", "layers", "cache_slots", "cache_bytes",
     "prefill_layer_passes", "decode_layer_passes", "exit_mass_1", "exit_mass_2", "exit_mass_3",
@@ -211,7 +215,7 @@ BUILD_TALLIES = {"compiles", "compile_s", "cache_hits", "cache_misses", "trace_s
     ("deepseek-v2", "deepseek_v2_rewrite_txt2img_512.closed2", DEEPSEEK_ATTRS, {
         "layers": 3, "experts_held": 4, "experts_total": 16,
         "cache_bytes": 3 * (2048 + 16) * 32 * 4, "prefill_routed_pairs": 2048 * 2 * 3,
-        "decode_routed_pairs": 16 * 2 * 3}),
+        "decode_routed_pairs": 16 * 2 * 3, "decode_expert_rows": 16 * 2 * 3}),
     ("ouro-2.6b", "ouro_2_6b_rewrite_txt2img_512.closed2", OURO_ATTRS, {
         "ut_steps": 4, "layers": 3, "cache_slots": 12,
         "cache_bytes": 12 * 2 * 4 * (2048 + 8) * 16 * 4,
