@@ -20,9 +20,10 @@ from .registry import register_node
 
 
 def generate_tokens(bundle, ids, seed: int, steps: int, temperature: float,
-                    collect: bool = False):
+                    collect: bool = False, draft_tokens: int = 0):
     """The served path's two programs, dispatched and not waited for:
-    the prefill of `ids` and `steps` decode steps drawn from `seed`.
+    the prefill of `ids` and the decode of `steps` ids drawn from `seed`
+    (one a step of its loop, or with `draft_tokens` one or more).
     Returns what they return, device arrays: the model's `Prefill` and
     `Decode` (a model whose decode takes the cache by donation leaves
     `prefill.cache` deleted). `collect` (the parity check's) also keeps
@@ -39,7 +40,7 @@ def generate_tokens(bundle, ids, seed: int, steps: int, temperature: float,
     with tracer.span("lm.decode"):
         decode = lm.decode(
             params, prefill.cache, prefill.logits, len(ids), jax.random.key(seed), steps,
-            temperature, collect,
+            temperature, collect, draft_tokens,
         )
     tracer.device_span("decode", decode.ids)
     return prefill, decode
@@ -53,7 +54,15 @@ class TextGenerate:
     gives the same text. An output node, so `/history` carries the text
     and the executor's node cache never answers it: every request
     computes its prefill again and keeps no prefix. Each prompt length
-    is a program of its own."""
+    is a program of its own.
+
+    `draft_tokens` 0 decodes one token a step. 1 asks a model that has a
+    draft module (K-EXAONE's multi-token-prediction module) to decode by
+    self-speculation: a step drafts a token, verifies it with the main
+    model and emits one or two, still one program and exactly
+    `max_new_tokens` ids. The text is distributed as without drafting,
+    but for the same seed the ids differ: the draws are other draws. A
+    model without a draft module refuses anything but 0."""
 
     @classmethod
     def INPUT_TYPES(cls):
@@ -64,7 +73,10 @@ class TextGenerate:
                 "seed": ("INT", {"default": 0}),
                 "max_new_tokens": ("INT", {"default": 256}),
                 "temperature": ("FLOAT", {"default": 1.0}),
-            }
+            },
+            "optional": {
+                "draft_tokens": ("INT", {"default": 0}),
+            },
         }
 
     RETURN_TYPES = ("STRING",)
@@ -72,9 +84,11 @@ class TextGenerate:
     OUTPUT_NODE = True
 
     def generate(self, clip, text, seed, max_new_tokens=256, temperature=1.0,
-                 context=None):
+                 draft_tokens=0, context=None):
         from ..telemetry import get_tracer
         from ..telemetry.instruments import (
+            lm_decode_steps_total,
+            lm_draft_tokens_total,
             lm_layer_passes_total,
             lm_linear_layer_passes_total,
             lm_tokens_total,
@@ -87,11 +101,12 @@ class TextGenerate:
                 f"language model; {clip.model_name!r} holds none"
             )
         tracer = get_tracer()
-        steps = int(max_new_tokens)
+        steps, draft_tokens = int(max_new_tokens), int(draft_tokens)
         ids = clip.tokenizer.encode(str(text))
         with attention_route_log() as routes:
             prefill, decode = generate_tokens(
-                clip, ids, resolve_seed(seed).effective_seed(), steps, float(temperature)
+                clip, ids, resolve_seed(seed).effective_seed(), steps, float(temperature),
+                draft_tokens=draft_tokens,
             )
         # the one read-back: the executor thread parks here until the
         # device has run both programs
@@ -101,14 +116,21 @@ class TextGenerate:
         with tracer.span("lm.detokenize"):
             out = clip.tokenizer.decode(new_ids)
         described = lm.describe(len(ids) + steps)
-        attrs = dict(
-            prompt_tokens=len(ids), new_tokens=steps,
+        attrs = {
+            "prompt_tokens": len(ids), "new_tokens": steps,
+            "draft_tokens": draft_tokens, "decode_steps": steps,  # a step a token, unless the
             **described,
-            **lm.report(len(ids), steps, *read),
-        )
+            **lm.report(len(ids), steps, *read),                  # model says otherwise
+        }
+        lm_decode_steps_total().inc(attrs["decode_steps"])
+        if attrs.get("mtp_drafted"):
+            lm_draft_tokens_total().inc(attrs["mtp_accepted"], outcome="accepted")
+            lm_draft_tokens_total().inc(
+                attrs["mtp_drafted"] - attrs["mtp_accepted"], outcome="rejected")
         for phase, tokens in (("prefill", len(ids)), ("decode", steps)):
             lm_tokens_total().inc(tokens, phase=phase)
-            lm_layer_passes_total().inc(tokens * lm.layer_passes, phase=phase)
+            lm_layer_passes_total().inc(
+                attrs.get(f"{phase}_layer_passes", tokens * lm.layer_passes), phase=phase)
             if described.get("linear_layers"):
                 lm_linear_layer_passes_total().inc(
                     tokens * described["linear_layers"], phase=phase)

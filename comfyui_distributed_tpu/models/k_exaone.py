@@ -1,0 +1,662 @@
+"""K-EXAONE (`exaone_moe`): attention layers of two kinds in a period of
+four, `LLLG`: three that see a window of `sliding_window` positions, then
+one that sees every position; layer 0's feed-forward part dense, the
+others a mixture of routed experts beside one shared expert; and one
+multi-token-prediction (MTP) module that drafts for a self-speculative
+decode.
+
+    h += mixer(rms(h));  h += ffn(rms(h))           (pre-norm, assumed)
+
+Mixer, both kinds: q = W_q x (heads x d), k = W_k x, v = W_v x (key heads
+x d), q and k each normed over a head's d channels with a learned scale,
+causal softmax at d^-1/2, key head j serving its group of query heads,
+y = W_o o. A window layer rotates q and k (all d channels, theta
+`rope_theta`) after the norm and position i sees i - window < j <= i; a
+full layer sees every j <= i and rotates nothing.
+
+The MTP module, for position i with h_i the residual stream after the
+last main layer and x_{i+1} the next token:
+
+    u_i = W_eh [rms_e(E[x_{i+1}]) ; rms_h(h_i)]
+    draft logits for x_{i+2} = Head(rms_mtp(layer(u)_i))
+
+one full-attention layer with a sparse feed-forward part and a cache of
+its own, the main model's embedding and head.
+
+A request's state is a tree (`state_shapes`): `ring` [window layers, 2,
+key heads, `ring_positions`, d], position p in entry p modulo the ring's
+length, keys stored rotated: fixed size whatever the position; `kv`
+[full layers + 1, 2, key heads, positions, d], which grows with the
+position, the MTP module's the last slot; and `h` [hidden], the residual
+stream of the last position the MTP module has not seen yet. The prefill
+allocates it, the decode takes it by donation and hands it back.
+
+The decode is one program either way. `draft_tokens` 0: `steps`
+one-token steps. `draft_tokens` 1: a `while_loop` whose step drafts one
+token with the MTP module, runs the last emitted token and the draft
+through the main model as two positions, keeps the draft with
+probability min(1, p / q) (else draws from the renormalised max(p - q,
+0)) and so emits one or two tokens, until `steps` ids are written. What
+a rejected draft wrote (ring, `kv`, the MTP's slot) the next step writes
+over before anything reads it. A ring of `sliding_window` entries alone
+would not do: a step's second write would land on the oldest key its
+first query still sees, hence `ring_positions`.
+
+The expert layer is `moe.expert_layer` under `moe.sigmoid_route`; the
+chip holds `expert_range(ep_rank, ep_size)` of the experts and the first
+of `vocab_shards` slices of the vocabulary, as `SolarOpen2Config` has it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+from typing import Any, NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.attention import causal_attention_blocked
+from ..ops.decode_attention import decode_attention_xla, ring_valid
+from ..parallel.sharding import expert_range
+from .lm_common import (
+    LanguageModel,
+    apply_rope,
+    count_params,
+    init_from_shapes,
+    rms_norm,
+    sample,
+    swiglu,
+)
+from .moe import expert_layer, report_loads, sigmoid_route
+
+# A ring's length is a whole number of these (the sublane tile of a
+# 32-bit layout; a 16-bit one pads to twice it by itself).
+RING_MULTIPLE = 8
+
+
+@dataclasses.dataclass(frozen=True)
+class KExaoneConfig:
+    """The published `config.json`'s shape keys under their own names
+    (`rope_theta` is its `rope_parameters` block's), and the chip's
+    share of a deployment as `SolarOpen2Config` states it. `ring` 0: the
+    ring's length follows from the window and the drafts a step may
+    make; another value is taken as it is (the parity check's control)."""
+
+    hidden_size: int = 6144
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 64
+    num_key_value_heads: int = 8
+    head_dim: int = 128
+    sliding_window: int = 128
+    sliding_window_pattern: str = "LLLG"
+    rope_theta: float = 1e6
+    intermediate_size: int = 18432
+    first_k_dense_replace: int = 1
+    moe_intermediate_size: int = 2048
+    num_experts: int = 128
+    num_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 2.5
+    num_nextn_predict_layers: int = 1
+    vocab_size: int = 153600
+    rms_norm_eps: float = 1e-5
+    ep_size: int = 1
+    ep_rank: int = 0
+    vocab_shards: int = 1
+    ring: int = 0
+
+    def __post_init__(self):
+        if self.num_nextn_predict_layers != 1 or set(self.sliding_window_pattern) - set("LG"):
+            raise ValueError(
+                "only the published form is written: one MTP module, a layer pattern of "
+                "L (window) and G (full)"
+            )
+
+    @property
+    def held_experts(self) -> range:
+        return expert_range(self.num_experts, self.ep_rank, self.ep_size)
+
+    @property
+    def vocab_held(self) -> int:
+        return self.vocab_size // self.vocab_shards
+
+    def is_window(self, layer: int) -> bool:
+        return self.sliding_window_pattern[layer % len(self.sliding_window_pattern)] == "L"
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace
+
+    @property
+    def window_layers(self) -> int:
+        return sum(self.is_window(layer) for layer in range(self.num_hidden_layers))
+
+    @property
+    def full_layers(self) -> int:
+        """Those of the main model; the MTP module's is one more."""
+        return self.num_hidden_layers - self.window_layers
+
+    @property
+    def sparse_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+    @property
+    def ring_positions(self) -> int:
+        """Entries a window layer keeps: the window and the one drafted
+        position a step writes beside it, rounded up to `RING_MULTIPLE`."""
+        wanted = self.sliding_window + self.num_nextn_predict_layers
+        return self.ring or -(-wanted // RING_MULTIPLE) * RING_MULTIPLE
+
+
+# --- parameters -----------------------------------------------------------
+
+
+def param_shapes(cfg: KExaoneConfig) -> dict[str, Any]:
+    """The tree's shapes with each weight's fan-in (None: a norm's
+    scale, initialised to one)."""
+    h, d = cfg.hidden_size, cfg.head_dim
+    heads, kv = cfg.num_attention_heads * d, cfg.num_key_value_heads * d
+    held = len(cfg.held_experts)
+
+    def mlp(width: int) -> dict:
+        return {"w_gate_up": ((h, 2 * width), h), "w_down": ((width, h), width)}
+
+    def layer(dense: bool) -> dict:
+        width = cfg.moe_intermediate_size
+        ffn = {"mlp": mlp(cfg.intermediate_size)} if dense else {"moe": {
+            "w_g": ((h, cfg.num_experts), h),
+            "bias": ((cfg.num_experts,), None),
+            "experts": {
+                "w_gate_up": ((held, h, 2 * width), h),
+                "w_down": ((held, width, h), width),
+            },
+            "shared": mlp(width * cfg.num_shared_experts),
+        }}
+        return {
+            "mixer_norm": ((h,), None),
+            "attn": {
+                "w_q": ((h, heads), h), "w_k": ((h, kv), h), "w_v": ((h, kv), h),
+                "q_norm": ((d,), None), "k_norm": ((d,), None),
+                "w_o": ((heads, h), heads),
+            },
+            "ffn_norm": ((h,), None),
+            **ffn,
+        }
+
+    return {
+        "embed": ((cfg.vocab_held, h), 1),
+        "layers": [layer(cfg.is_dense(i)) for i in range(cfg.num_hidden_layers)],
+        "final_norm": ((h,), None),
+        "head": ((h, cfg.vocab_held), h),
+        "mtp": {
+            "embed_norm": ((h,), None), "hidden_norm": ((h,), None),
+            "w_eh": ((2 * h, h), 2 * h),
+            "layer": layer(False),
+            "norm": ((h,), None),
+        },
+    }
+
+
+def param_count(cfg: KExaoneConfig) -> int:
+    return count_params(param_shapes(cfg))
+
+
+def init_params(cfg: KExaoneConfig, key, dtype=jnp.float32) -> dict[str, Any]:
+    """Seeded random weights in `dtype` (`lm_common.init_from_shapes`);
+    the routers' selection bias zero and float32."""
+    params = init_from_shapes(param_shapes(cfg), key, dtype)
+    for block in (*params["layers"], params["mtp"]["layer"]):
+        if "moe" in block:
+            block["moe"]["bias"] = jnp.zeros_like(block["moe"]["bias"], jnp.float32)
+    return params
+
+
+# --- a request's state ----------------------------------------------------
+
+
+def state_shapes(cfg: KExaoneConfig, cache_len: int, dtype) -> dict[str, jax.ShapeDtypeStruct]:
+    """The tree a request carries from its prefill through its decode."""
+    heads, d = cfg.num_key_value_heads, cfg.head_dim
+    return {
+        "ring": jax.ShapeDtypeStruct((cfg.window_layers, 2, heads, cfg.ring_positions, d), dtype),
+        "kv": jax.ShapeDtypeStruct((cfg.full_layers + 1, 2, heads, cache_len, d), dtype),
+        "h": jax.ShapeDtypeStruct((cfg.hidden_size,), dtype),
+    }
+
+
+def _nbytes(shape: jax.ShapeDtypeStruct) -> int:
+    return math.prod(shape.shape) * jnp.dtype(shape.dtype).itemsize
+
+
+def _slot(cfg, layer: int) -> int:
+    """A layer's place among the layers of its kind."""
+    return sum(cfg.is_window(i) == cfg.is_window(layer) for i in range(layer))
+
+
+# --- the mixer ------------------------------------------------------------
+
+
+def rope_tables(cfg: KExaoneConfig, positions: jax.Array):
+    """cos and sin [T, d / 2] float32 of the positions' angles."""
+    d = cfg.head_dim
+    inverse = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions.astype(jnp.float32)[:, None] * inverse[None, :]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def _projections(cfg, p, x, rope):
+    """q [T, heads, d], k and v [T, key heads, d] of x [T, hidden]: q and
+    k normed a head, then rotated where `rope` (cos, sin) is given."""
+    tokens, d = x.shape[0], cfg.head_dim
+    q = rms_norm((x @ p["w_q"]).reshape(tokens, -1, d), p["q_norm"], cfg.rms_norm_eps)
+    k = rms_norm((x @ p["w_k"]).reshape(tokens, -1, d), p["k_norm"], cfg.rms_norm_eps)
+    v = (x @ p["w_v"]).reshape(tokens, -1, d)
+    if rope is not None:
+        q, k = apply_rope(q, *rope), apply_rope(k, *rope)
+    return q, k, v
+
+
+def _entries(k, v):
+    """Keys and values [T, key heads, d] as a cache holds them: [2, key
+    heads, T, d]."""
+    return jnp.stack([k, v]).transpose(0, 2, 1, 3)
+
+
+def mixer_whole(cfg, p, x, window: bool):
+    """Over a whole sequence x [T, hidden] (the prefill's form). Returns
+    (output [T, hidden], keys and values [2, key heads, T, d])."""
+    rope = rope_tables(cfg, jnp.arange(x.shape[0])) if window else None
+    q, k, v = _projections(cfg, p, x, rope)
+    out = causal_attention_blocked(
+        q[None], k[None], v[None], window=cfg.sliding_window if window else None)[0]
+    return out.reshape(x.shape[0], -1) @ p["w_o"], _entries(k, v)
+
+
+def mixer_cached(cfg, p, x, cache, name: str, index: int, positions):
+    """A step's W new tokens x [W, hidden] at `positions` [W] (one after
+    another): their keys and values written into slot `index` of
+    `cache[name]`, a ring (an entry a position, modulo its length) or a
+    cache that grows, then each query over what it may see of the slot.
+    Returns (output [W, hidden], the array written)."""
+    window = name == "ring"
+    q, k, v = _projections(cfg, p, x, rope_tables(cfg, positions) if window else None)
+    new, held = _entries(k, v)[None], cache[name]
+    size = held.shape[3]
+    if window:
+        for j in range(x.shape[0]):  # two entries need not lie side by side
+            held = jax.lax.dynamic_update_slice(
+                held, new[:, :, :, j:j + 1], (index, 0, 0, positions[j] % size, 0))
+        valid = ring_valid(positions, size, cfg.sliding_window)
+    else:
+        held = jax.lax.dynamic_update_slice(held, new, (index, 0, 0, positions[0], 0))
+        valid = jnp.arange(size)[None, :] <= positions[:, None]
+    out = decode_attention_xla(q, held, (index,), valid=valid)
+    return out.reshape(x.shape[0], -1) @ p["w_o"], held
+
+
+def ring_of(cfg, entries, tokens: int):
+    """What a window layer's ring holds after a prefill of `tokens`
+    positions whose keys and values are `entries` [2, key heads, T, d]:
+    entry s the newest position that is s modulo the ring's length, zero
+    where there is none yet."""
+    size = cfg.ring_positions
+    held = tokens - 1 - (tokens - 1 - np.arange(size)) % size
+    ring = entries[:, :, np.maximum(held, 0)]
+    return jnp.where((held >= 0)[None, None, :, None], ring, 0)
+
+
+# --- a layer, in either form ----------------------------------------------
+
+
+def _feed_forward(cfg, block, x):
+    """(output, chosen ids [T, k] or None, pairs per held expert or None)"""
+    if "mlp" in block:
+        with jax.named_scope("dense"):
+            return swiglu(x, block["mlp"]), None, None
+    p = block["moe"]
+    route = partial(
+        sigmoid_route, bias=p["bias"], k=cfg.num_experts_per_tok,
+        scale=cfg.routed_scaling_factor, renormalise=cfg.norm_topk_prob)
+    return expert_layer(p, x, cfg.held_experts, route)
+
+
+def _layer(cfg, block, h, window: bool, mixer):
+    """One pre-norm residual layer; `mixer(p, x)` returns (output, what
+    it hands back: the keys and values, or the cache array it wrote).
+    Returns (h, that, chosen ids, pairs per held expert)."""
+    with jax.named_scope("swa" if window else "full"):
+        out, kept = mixer(block["attn"], rms_norm(h, block["mixer_norm"], cfg.rms_norm_eps))
+    h = h + out
+    out, ids, sizes = _feed_forward(
+        cfg, block, rms_norm(h, block["ffn_norm"], cfg.rms_norm_eps))
+    return h + out, kept, ids, sizes
+
+
+def _head(cfg, params, h, norm):
+    with jax.named_scope("head"):
+        return jnp.dot(
+            rms_norm(h, norm, cfg.rms_norm_eps), params["head"],
+            preferred_element_type=jnp.float32)
+
+
+def mtp_input(cfg, params, h, tokens):
+    """u [T, hidden] of the residual streams h [T, hidden] and the
+    tokens that follow each [T]."""
+    p = params["mtp"]
+    both = jnp.concatenate([
+        rms_norm(params["embed"][tokens], p["embed_norm"], cfg.rms_norm_eps),
+        rms_norm(h, p["hidden_norm"], cfg.rms_norm_eps),
+    ], axis=-1)
+    return both @ p["w_eh"]
+
+
+# --- the two programs -----------------------------------------------------
+
+
+class Prefill(NamedTuple):
+    logits: jax.Array   # [vocab_held] float32, at the prompt's last position
+    cache: dict         # `state_shapes`: the request's state after the prompt
+    loads: jax.Array    # [sparse layers, held] pairs on each held expert
+    chosen: jax.Array | None  # [sparse layers, T, k] experts chosen; under `collect`
+
+
+class Decode(NamedTuple):
+    ids: jax.Array      # [steps]
+    loads: jax.Array    # [sparse layers + 1, held], summed over the steps; the MTP's last
+    counts: jax.Array   # [4] int32: steps taken, drafts made, drafts kept, held experts read
+    cache: dict         # the state it was given, after the steps
+    kept: dict | None   # under `collect`: see `decode`
+
+
+@partial(jax.jit, static_argnames=("cfg", "cache_len", "collect"))
+def prefill(cfg: KExaoneConfig, params, ids, *, cache_len: int, collect: bool = False):
+    """The whole prompt `ids` [T] at once. Returns the logits at its last
+    position, the request's state (allocated here, once: each ring with
+    the prompt's last positions, each growing slot's first T positions
+    written, `h` the last position's residual stream), the pairs that
+    fell on each held expert and, under `collect` (the parity check's),
+    the experts chosen.
+
+    Of the MTP module the prompt needs the keys and values only (nothing
+    reads its output before the decode's first draft), so that is what
+    runs: `mtp_input`, the norm, W_k and W_v. Position T - 1 has no next
+    token yet and is written from token 0; the decode's first step
+    writes it again before anything reads it."""
+    tokens = ids.shape[0]
+    h = params["embed"][ids]
+    cache = {
+        name: jnp.zeros(s.shape, s.dtype)
+        for name, s in state_shapes(cfg, cache_len, h.dtype).items()
+    }
+
+    def write(kv, index, entries):
+        return jax.lax.dynamic_update_slice(kv, entries[None], (index, 0, 0, 0, 0))
+
+    chosen, loads = [], []
+    for layer, block in enumerate(params["layers"]):
+        window, index = cfg.is_window(layer), _slot(cfg, layer)
+        with jax.named_scope(f"layer_{layer}"):
+            h, entries, ids_l, sizes = _layer(
+                cfg, block, h, window, lambda p, x: mixer_whole(cfg, p, x, window))
+        if window:
+            cache["ring"] = cache["ring"].at[index].set(ring_of(cfg, entries, tokens))
+        else:
+            cache["kv"] = write(cache["kv"], index, entries)
+        if ids_l is not None:
+            chosen.append(ids_l)
+            loads.append(sizes)
+    with jax.named_scope("mtp"):
+        block = params["mtp"]["layer"]
+        u = mtp_input(cfg, params, h, jnp.concatenate([ids[1:], jnp.zeros((1,), ids.dtype)]))
+        _, k, v = _projections(
+            cfg, block["attn"], rms_norm(u, block["mixer_norm"], cfg.rms_norm_eps), None)
+        cache["kv"] = write(cache["kv"], cfg.full_layers, _entries(k, v))
+    cache["h"] = h[-1]
+    return Prefill(
+        _head(cfg, params, h[-1:], params["final_norm"])[0], cache, jnp.stack(loads),
+        jnp.stack(chosen) if collect else None,
+    )
+
+
+def main_step(cfg, params, cache, tokens, position):
+    """W tokens [W] at `position`, `position` + 1, ... through every
+    main layer over the request's state. Returns (logits [W,
+    vocab_held], the residual streams [W, hidden], cache, ids [sparse
+    layers, W, k], pairs per held expert [sparse layers, held])."""
+    positions = position + jnp.arange(tokens.shape[0])
+    h = params["embed"][tokens]
+    chosen, loads = [], []
+    for layer, block in enumerate(params["layers"]):
+        name = "ring" if cfg.is_window(layer) else "kv"
+        with jax.named_scope(f"layer_{layer}"):
+            h, cache[name], ids_l, sizes = _layer(
+                cfg, block, h, name == "ring",
+                lambda p, x: mixer_cached(cfg, p, x, cache, name, _slot(cfg, layer), positions))
+        if ids_l is not None:
+            chosen.append(ids_l)
+            loads.append(sizes)
+    logits = _head(cfg, params, h, params["final_norm"])
+    return logits, h, cache, jnp.stack(chosen), jnp.stack(loads)
+
+
+def mtp_step(cfg, params, cache, h, tokens, position):
+    """The MTP module over W confirmed positions from `position`: their
+    residual streams h [W, hidden] and the tokens that follow them [W].
+    Returns (draft logits [W, vocab_held], cache, ids [W, k], pairs per
+    held expert [held])."""
+    positions = position + jnp.arange(tokens.shape[0])
+    block = params["mtp"]["layer"]
+    out, cache["kv"], ids, sizes = _layer(
+        cfg, block, mtp_input(cfg, params, h, tokens), False,
+        lambda p, x: mixer_cached(cfg, p, x, cache, "kv", cfg.full_layers, positions))
+    return _head(cfg, params, out, params["mtp"]["norm"]), cache, ids, sizes
+
+
+def accept_probability(p, q, draft):
+    """With which probability a draft drawn from q stands for a draw
+    from p: min(1, p(draft) / q(draft))."""
+    return jnp.minimum(1.0, p[draft] / q[draft])
+
+
+def residual(p, q):
+    """What a rejected draft is replaced from: max(p - q, 0) over its
+    sum (p itself where the two are equal and nothing is left)."""
+    left = jnp.maximum(p - q, 0.0)
+    total = left.sum()
+    return jnp.where(total > 0, left / jnp.where(total > 0, total, 1.0), p)
+
+
+def verify(logits, draft_logits, draft, key, temperature):
+    """The lossless rule over the main model's logits [2, vocab] at the
+    last emitted token and at the draft, and the logits the draft was
+    drawn from. Returns (kept, the token after the last emitted one, the
+    token after that, which counts only where the draft was kept). At
+    temperature 0 the draft is kept iff it is the main model's largest."""
+    key_accept, key_again, key_next = jax.random.split(key, 3)
+    safe = jnp.where(temperature > 0, temperature, 1.0)
+    p = jax.nn.softmax(logits[0] / safe)
+    q = jax.nn.softmax(draft_logits / safe)
+    kept = jnp.where(
+        temperature > 0,
+        jax.random.uniform(key_accept) < accept_probability(p, q, draft),
+        draft == jnp.argmax(logits[0]))
+    again = jnp.where(
+        temperature > 0,
+        jax.random.categorical(key_again, jnp.log(residual(p, q))),
+        jnp.argmax(logits[0])).astype(jnp.int32)
+    return kept, jnp.where(kept, draft, again), sample(logits[1], key_next, temperature)
+
+
+def _decode_plain(cfg, params, cache, logits, start, key, temperature, steps, collect):
+    """`steps` one-token steps, as the other models' decodes."""
+    layers, k, held = cfg.sparse_layers, cfg.num_experts_per_tok, len(cfg.held_experts)
+
+    def body(i, carry):
+        cache, logits, ids, loads, read, kept = carry
+        token = sample(logits, jax.random.fold_in(key, i), temperature)
+        rows, _, cache, chosen_i, loads_i = main_step(cfg, params, cache, token[None], start + i)
+        if collect:
+            kept = {
+                "logits": kept["logits"].at[i].set(rows[0]),
+                "chosen": kept["chosen"].at[i].set(chosen_i[:, 0]),
+            }
+        read = read + jnp.count_nonzero(loads_i)
+        return cache, rows[0], ids.at[i].set(token), loads.at[:layers].add(loads_i), read, kept
+
+    kept = {
+        "logits": jnp.zeros((steps, cfg.vocab_held), jnp.float32),
+        "chosen": jnp.zeros((steps, layers, k), jnp.int32),
+    } if collect else None
+    carry = (
+        cache, logits, jnp.zeros((steps,), jnp.int32),
+        jnp.zeros((layers + 1, held), jnp.int32), jnp.int32(0), kept,
+    )
+    cache, _, ids, loads, read, kept = jax.lax.fori_loop(0, steps, body, carry)
+    counts = jnp.stack([jnp.int32(steps), jnp.int32(0), jnp.int32(0), read.astype(jnp.int32)])
+    return Decode(ids, loads, counts, cache, kept)
+
+
+def _decode_drafting(cfg, params, cache, logits, start, key, temperature, steps, collect):
+    """The self-speculative loop. Before a step the main model's state
+    holds positions 0 .. n - 1, x_n is the last emitted token, and
+    `waiting` of the newest confirmed positions (their residual streams
+    `h`, the tokens that follow them `after`) have not been through the
+    MTP module yet: one after a rejection, two after a kept draft."""
+    layers, k, held = cfg.sparse_layers, cfg.num_experts_per_tok, len(cfg.held_experts)
+    first = sample(logits, jax.random.fold_in(key, 0), temperature)
+
+    def body(c):
+        cache, emitted, step = c["cache"], c["emitted"], c["counts"][0]
+        n = start + emitted - 1  # x_n's position
+        key_draft, key_verify = jax.random.split(jax.random.fold_in(key, step + 1))
+        with jax.named_scope("mtp"):
+            # the second row is of no confirmed position where one waits:
+            # what it writes at n the next step writes over
+            drafts, cache, _, loads_mtp = mtp_step(
+                cfg, params, cache, c["h"], c["after"], n - c["waiting"])
+            draft_logits = drafts[c["waiting"] - 1]
+            draft = sample(draft_logits, key_draft, temperature)
+        rows, h, cache, chosen, loads_main = main_step(
+            cfg, params, cache, jnp.stack([c["last"], draft]), n)
+        with jax.named_scope("verify"):
+            accepted, one, two = verify(rows, draft_logits, draft, key_verify, temperature)
+            ids = c["ids"].at[emitted].set(one)
+            # a second token that would be one too many is not written
+            ids = ids.at[jnp.where(accepted, emitted + 1, steps)].set(two, mode="drop")
+            read = jnp.count_nonzero(loads_main) + jnp.count_nonzero(loads_mtp)
+            counts = c["counts"] + jnp.stack([1, 1, accepted, read]).astype(jnp.int32)
+        kept = c["kept"]
+        if collect:
+            kept = {
+                "logits": kept["logits"].at[step].set(rows),
+                "draft_logits": kept["draft_logits"].at[step].set(draft_logits),
+                "chosen": kept["chosen"].at[step].set(chosen),
+                "position": kept["position"].at[step].set(n),
+                "accepted": kept["accepted"].at[step].set(accepted),
+            }
+        return {
+            "cache": cache, "ids": ids, "emitted": emitted + 1 + accepted,
+            "last": jnp.where(accepted, two, one), "h": h, "after": jnp.stack([one, two]),
+            "waiting": 1 + accepted.astype(jnp.int32),
+            "loads": c["loads"].at[:layers].add(loads_main).at[layers].add(loads_mtp),
+            "counts": counts, "kept": kept,
+        }
+
+    most = max(steps - 1, 1)  # steps the loop may take: each emits at least one token
+    kept = {
+        "logits": jnp.zeros((most, 2, cfg.vocab_held), jnp.float32),
+        "draft_logits": jnp.zeros((most, cfg.vocab_held), jnp.float32),
+        "chosen": jnp.zeros((most, layers, 2, k), jnp.int32),
+        "position": jnp.full((most,), -1, jnp.int32),
+        "accepted": jnp.zeros((most,), bool),
+    } if collect else None
+    done = jax.lax.while_loop(lambda c: c["emitted"] < steps, body, {
+        "cache": cache, "ids": jnp.zeros((steps,), jnp.int32).at[0].set(first),
+        "emitted": jnp.int32(1), "last": first,
+        "h": jnp.stack([cache["h"], jnp.zeros_like(cache["h"])]),
+        "after": jnp.stack([first, jnp.int32(0)]), "waiting": jnp.int32(1),
+        "loads": jnp.zeros((layers + 1, held), jnp.int32),
+        "counts": jnp.zeros((4,), jnp.int32), "kept": kept,
+    })
+    return Decode(done["ids"], done["loads"], done["counts"], done["cache"], done["kept"])
+
+
+@partial(jax.jit, static_argnames=("cfg", "steps", "collect", "draft_tokens"),
+         donate_argnames=("cache",))
+def decode(cfg: KExaoneConfig, params, cache, logits, start, key, temperature, *,
+           steps: int, collect: bool = False, draft_tokens: int = 0):
+    """`steps` ids in one program, from the prefill's `logits` at
+    position `start - 1`; no early stop. With `draft_tokens` 0 that is
+    `steps` one-token steps (draw id i from the logits, run it through
+    the model at `start + i`); with 1 the self-speculative loop, which
+    takes as many steps as its drafts' fates make it, a `while_loop`
+    with no trip to the host. The state tree is donated, carried through
+    the loop and handed back. Returns the ids, the pairs on each held
+    expert, `counts` and, under `collect`, per step: the main model's
+    logits, the experts chosen and, when drafting, the logits each draft
+    was drawn from, the step's position n and whether its draft was
+    kept (the first token comes from the prefill's logits; the logits'
+    row 0 is position n's, row 1 the draft's at n + 1)."""
+    if draft_tokens not in (0, 1):
+        raise ValueError(f"this model's MTP module drafts one token a step, not {draft_tokens}")
+    run = _decode_drafting if draft_tokens else _decode_plain
+    return run(cfg, params, dict(cache), logits, start, key, temperature, steps, collect)
+
+
+class KExaone(LanguageModel):
+    """What a bundle's `lm` part is (the contract is in `lm_common`)."""
+
+    _init = staticmethod(init_params)
+    _prefill = staticmethod(prefill)
+    _decode = staticmethod(decode)
+    draft_tokens_max = 1
+
+    @property
+    def layer_passes(self) -> int:
+        return self.cfg.num_hidden_layers
+
+    def read_back(self, prefill: Prefill, decode: Decode) -> tuple:
+        """The pairs on each held expert, of either program, and the
+        decode's counts."""
+        return prefill.loads, decode.loads, decode.counts
+
+    def describe(self, cache_len: int) -> dict[str, int]:
+        cfg, shapes = self.cfg, state_shapes(self.cfg, cache_len, self.dtype)
+        return {
+            "layers": cfg.num_hidden_layers,
+            "window_layers": cfg.window_layers,
+            "full_layers": cfg.full_layers + 1,
+            "window": cfg.sliding_window,
+            "ring_positions": cfg.ring_positions,
+            "experts_held": len(cfg.held_experts),
+            "experts_total": cfg.num_experts,
+            "cache_bytes": _nbytes(shapes["kv"]),
+            "state_bytes": _nbytes(shapes["ring"]),
+        }
+
+    def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads,
+               counts) -> dict:
+        """What the decode's steps came to, the layer bodies either
+        program ran (the decode's over every position a step ran, a
+        rejected draft's and the MTP module's among them; of the MTP
+        module the prefill runs only the keys and values, no body) and,
+        per phase, the routing as `moe.report_loads` has it, the
+        decode's pairs counted over the positions its steps ran."""
+        cfg = self.cfg
+        steps, drafted, accepted, read = (int(n) for n in counts)
+        width = 2 if drafted else 1  # positions a step runs
+        mtp = 1 if drafted else 0    # and whether the module's layer is among its bodies
+        pairs = steps * width * cfg.num_experts_per_tok * (cfg.sparse_layers + mtp)
+        return {
+            **report_loads(
+                cfg.num_experts_per_tok, cfg.num_experts, prompt_tokens, new_tokens,
+                prefill_loads, decode_loads),
+            "decode_routed_pairs": pairs, "decode_expert_rows": pairs,
+            "decode_steps": steps, "mtp_drafted": drafted, "mtp_accepted": accepted,
+            "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,
+            "decode_layer_passes": steps * width * (cfg.num_hidden_layers + mtp),
+            "decode_experts_read": read,
+        }

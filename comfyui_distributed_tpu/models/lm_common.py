@@ -1,5 +1,5 @@
 """What the language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`): the blocks they are written from, the one
+`solar_open2.py`, `k_exaone.py`): the blocks they are written from, the one
 initialisation rule, sampling on the device, and the stand-in tokenizer.
 
 A bundle's `lm` part is an object with this contract (`LanguageModel`
@@ -9,13 +9,22 @@ below holds what every model's class has alike), which
 - `cfg`, `tokenizer`, `init(key, dtype)` (which also records `dtype`,
   the one the weights and the cache are stored in);
 - `prefill(params, ids, cache_len, collect)` and `decode(params, cache,
-  logits, start, key, steps, temperature, collect)`: the two programs;
-  what they return has `.cache` and `.logits` (the prefill's) and `.ids`
-  (the decode's). `.cache` is a request's whole state, handed from one
-  program to the other as it is: one array, or a tree of arrays of
-  several kinds and dtypes (keys and values that grow with the position,
-  a recurrent layer's fixed-size state); the node never looks inside;
-- `layer_passes`: layer bodies one token walks through;
+  logits, start, key, steps, temperature, collect, draft_tokens)`: the
+  two programs; what they return has `.cache` and `.logits` (the
+  prefill's) and `.ids` (the decode's: `[steps]`, always). `.cache` is a
+  request's whole state, handed from one program to the other as it is:
+  one array, or a tree of arrays of several kinds and dtypes (keys and
+  values that grow with the position, a window layer's ring or a
+  recurrent layer's state of fixed size); the node never looks inside;
+- `draft_tokens_max`: tokens a decode step may draft and verify beside
+  the one it emits anyway (0: the model has no draft module, and
+  `decode` refuses any other `draft_tokens`). With drafting a step
+  yields one token or more, so `steps` ids take fewer steps of the
+  loop, which stays one program; the model's `read_back` then carries
+  how many it took and how many drafts it made and kept;
+- `layer_passes`: layer bodies one token walks through (where a step
+  may run more than one position, `report` gives the counts run as
+  `prefill_layer_passes` and `decode_layer_passes`);
 - `read_back(prefill, decode)`: the device arrays a request reads back
   beside the ids, in the one `device.wait`;
 - `describe(cache_len)` and `report(prompt_tokens, new_tokens, *read)`:
@@ -161,9 +170,11 @@ class LanguageModel:
     key, temperature, *, steps, collect)`, which a subclass names as
     `_init`, `_prefill`, `_decode`), the tokenizer, and the dtype the
     weights and the cache are stored in. A subclass adds `layer_passes`,
-    `read_back`, `describe` and `report`."""
+    `read_back`, `describe` and `report`, and where it has a draft
+    module `draft_tokens_max` and a `_decode` that takes `draft_tokens`."""
 
     _init = _prefill = _decode = None
+    draft_tokens_max = 0
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -178,8 +189,14 @@ class LanguageModel:
         return self._prefill(self.cfg, params, ids, cache_len=cache_len, collect=collect)
 
     def decode(self, params, cache, logits, start: int, key, steps: int, temperature: float,
-               collect: bool = False):
+               collect: bool = False, draft_tokens: int = 0):
+        if not 0 <= draft_tokens <= self.draft_tokens_max:
+            raise ValueError(
+                f"draft_tokens {draft_tokens}: {type(self).__name__} "
+                + (f"drafts at most {self.draft_tokens_max} a step" if self.draft_tokens_max
+                   else "has no draft module; only 0 (one token a step) is served"))
+        drafting = {"draft_tokens": draft_tokens} if self.draft_tokens_max else {}
         return self._decode(
             self.cfg, params, cache, logits, jnp.int32(start), key, jnp.float32(temperature),
-            steps=steps, collect=collect,
+            steps=steps, collect=collect, **drafting,
         )

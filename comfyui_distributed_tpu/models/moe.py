@@ -1,5 +1,6 @@
 """The expert layer of one chip, as the language models with a mixture
-of experts share it (`deepseek_v2.py`, `solar_open2.py`).
+of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`),
+and the routing rule of those that score by sigmoids (`sigmoid_route`).
 
 The layer is told which routed experts it holds (`held`, a contiguous
 run: `parallel.sharding.expert_range` of the chip's rank), routes every
@@ -61,6 +62,21 @@ def rung_index(ladder: tuple[int, ...], pairs_held):
     smaller. The device branches on it (a traced scalar) and
     `report_loads` reads the same from the loads on the host."""
     return sum(pairs_held > rows for rows in ladder[:-1])
+
+
+def sigmoid_route(logits: jax.Array, bias: jax.Array, k: int, scale: float = 1.0,
+                  renormalise: bool = True):
+    """Over float32 router logits [T, experts]: scores are their
+    sigmoids, the `k` largest of score + `bias` (a selection bias an
+    expert) are chosen, ties to the lower index, and the weights are the
+    chosen scores, without the bias, over their sum (`renormalise`)
+    times `scale`. Returns (ids [T, k], weights [T, k])."""
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    if renormalise:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    return ids, weights * scale
 
 
 def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):

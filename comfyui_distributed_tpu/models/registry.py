@@ -20,6 +20,7 @@ from .dit import DiTConfig, VideoDiT
 from .mmdit import MMDiT, MMDiTConfig
 from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
+from .k_exaone import KExaone, KExaoneConfig
 from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .t5_encoder import T5Encoder, T5EncoderConfig
 from .text_encoder import TextEncoder, TextEncoderConfig
@@ -517,6 +518,32 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             ep_size=8, ep_rank=0, vocab_shards=8,
         ),
     },
+    # K-EXAONE-236B-A23B as one chip's share of an eight-chip host, every
+    # width as published: the dense layer 0 and one whole period of the
+    # pattern after it (window, window, full, window), the MTP module,
+    # experts 0-15 of 128 (rank 0 of 8), the first eighth of the vocabulary
+    # (the benchmark's k-exaone-236b-a23b configuration says what the cut
+    # stands for)
+    "k-exaone-ep8-5l": {
+        "family": "lm",
+        "config": KExaoneConfig(
+            num_hidden_layers=5, ep_size=8, ep_rank=0, vocab_shards=8,
+        ),
+    },
+    # every mechanism at a size for the CPU: the dense layer and one
+    # period, 4 query heads over 2 key heads, a window of 12 (a ring of
+    # 16), 16 experts (4 a token) of which rank 0 of 8 holds two, the MTP
+    # module
+    "tiny-k-exaone": {
+        "family": "lm",
+        "config": KExaoneConfig(
+            hidden_size=64, num_hidden_layers=5, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=16, sliding_window=12,
+            intermediate_size=160, moe_intermediate_size=32, num_experts=16,
+            num_experts_per_tok=4, vocab_size=4096, ep_size=8, ep_rank=0,
+            vocab_shards=8,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -572,6 +599,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     DeepSeekV2Config: DeepSeekV2,
     OuroConfig: Ouro,
     SolarOpen2Config: SolarOpen2,
+    KExaoneConfig: KExaone,
 }
 
 

@@ -55,7 +55,7 @@ from ..ops.attention import dot_product_attention
 from ..ops.decode_attention import decode_attention_xla
 from ..parallel.sharding import expert_range
 from .lm_common import LanguageModel, count_params, init_from_shapes, rms_norm, sample
-from .moe import expert_layer, report_loads
+from .moe import expert_layer, report_loads, sigmoid_route
 
 # Rows of a chunk whose pairwise decays are formed pair by pair
 # (`decay_products`); between such blocks they go through one product.
@@ -236,17 +236,9 @@ def _nbytes(shape: jax.ShapeDtypeStruct) -> int:
 
 
 def route(cfg: SolarOpen2Config, bias: jax.Array, logits: jax.Array):
-    """Over float32 router logits [T, experts]: scores are their
-    sigmoids, the `num_experts_per_tok` largest of score + `bias` are
-    chosen (ties to the lower index), and the weights are the chosen
-    scores, without the bias, over their sum (`norm_topk_prob`) times
-    `routed_scaling_factor`. Returns (ids, weights)."""
-    scores = jax.nn.sigmoid(logits)
-    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.num_experts_per_tok)
-    weights = jnp.take_along_axis(scores, ids, axis=-1)
-    if cfg.norm_topk_prob:
-        weights = weights / weights.sum(axis=-1, keepdims=True)
-    return ids, weights * cfg.routed_scaling_factor
+    """`moe.sigmoid_route` at this model's sizes: (ids, weights)."""
+    return sigmoid_route(
+        logits, bias, cfg.num_experts_per_tok, cfg.routed_scaling_factor, cfg.norm_topk_prob)
 
 
 def moe(cfg, p, x):

@@ -137,12 +137,16 @@ CAUSAL_BLOCK_Q = 256
 
 def causal_attention_blocked(
     q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Causal attention, q/k [B, N|M, H, Dq] and v [B, M, H, Dv] with a
     width of its own, as XLA operations over blocks of query rows: a
     block of rows takes only the keys up to its last row (the blocks
     above the diagonal are skipped, the block on it is masked), so the
-    score tensor is never whole in memory. Scores, softmax and both
+    score tensor is never whole in memory. Under a `window` a row sees
+    only the last `window` keys up to its own (itself among them), and a
+    block takes only the keys its band reaches, `window` - 1 before its
+    first row: the rest are skipped, not masked. Scores, softmax and both
     accumulations are float32; the probabilities are rounded to v's
     dtype for the second product, as the kernel does. k and v may have
     fewer heads than q, a divisor of its count: key head j then serves
@@ -165,18 +169,23 @@ def causal_attention_blocked(
     log = _ROUTE_LOG.get()
     if log is not None:
         name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
-        log.append(f"xla-causal {n}x{m}x{d}/{v.shape[3]} bq{block} {name}")
+        banded = "" if window is None else f" w{window}"
+        log.append(f"xla-causal {n}x{m}x{d}/{v.shape[3]}{banded} bq{block} {name}")
     outs = []
     for start in range(0, n, block):
         stop = min(start + block, n)
         last = stop + m - n  # keys the block's last row sees
+        # the first key the block's first row sees
+        first = 0 if window is None else max(start + m - n - window + 1, 0)
         scores = scale * jnp.einsum(
-            to_scores, q[:, start:stop], k[:, :last], preferred_element_type=jnp.float32)
+            to_scores, q[:, start:stop], k[:, first:last], preferred_element_type=jnp.float32)
         rows = jnp.arange(start, stop)[:, None] + (m - n)
-        scores = jnp.where(rows >= jnp.arange(last)[None, :], scores, -jnp.inf)
+        cols = jnp.arange(first, last)[None, :]
+        seen = rows >= cols if window is None else (rows >= cols) & (rows - cols < window)
+        scores = jnp.where(seen, scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
         outs.append(jnp.einsum(
-            to_out, probs, v[:, :last], preferred_element_type=jnp.float32,
+            to_out, probs, v[:, first:last], preferred_element_type=jnp.float32,
         ).astype(v.dtype))
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     return out.reshape(out.shape[0], n, heads, v.shape[3])

@@ -76,23 +76,59 @@ def decode_attention_route(heads: int, positions: int, d: int, dtype) -> str:
     return "decode-kernel" if plan else "decode-xla"
 
 
-def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, position) -> jax.Array:
+def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, position=None, *,
+                         valid: jax.Array | None = None) -> jax.Array:
     """The einsum form: q [heads, d] over `cache[slot]`'s positions up
     to `position`. The slot may hold fewer key and value heads than
     there are queries, a divisor of their count: key head j serves the
     query heads j x group .. (j + 1) x group - 1 (the axis g below; one
     wide where the counts are equal). Scores, softmax and the sum's
     accumulation float32, the probabilities rounded to the cache's
-    dtype. Returns [heads, d] in the cache's dtype."""
+    dtype. Returns [heads, d] in the cache's dtype.
+
+    Or q [W, heads, d], the queries of W positions of one step, with
+    `valid` [W, S] saying which of the slot's S entries each may see
+    (`ring_valid` for a ring, whose entries are positions modulo its
+    length; a comparison with the position for a cache that grows): the
+    W queries lie beside a key head's group, so the slot is still read
+    once. Returns [W, heads, d].
+
+    The two forms are one computation (W = 1 with `valid` the comparison
+    with `position`); the one-query form keeps its own reshapes only so
+    that Ouro's and Solar's decode programs trace the operations they
+    traced before it took W queries. Fold it into the other once their
+    parity scripts have been run on the folded form."""
     keys, values = cache[slot]                                   # [kv heads, S, d] each
-    heads, d = q.shape
-    grouped = q.reshape(keys.shape[0], -1, d)
+    heads, d = q.shape[-2:]
+    if valid is None:
+        grouped = q.reshape(keys.shape[0], -1, d)
+        valid = (jnp.arange(keys.shape[1]) <= position)[None, None, :]
+    else:
+        group = heads // keys.shape[0]
+        grouped = q.reshape(-1, keys.shape[0], group, d).swapaxes(0, 1).reshape(
+            keys.shape[0], -1, d)                                # [kv heads, W x group, d]
+        valid = jnp.repeat(valid, group, axis=0)[None]
     scores = d ** -0.5 * jnp.einsum(
         "hgd,hsd->hgs", grouped, keys, preferred_element_type=jnp.float32)
-    valid = jnp.arange(keys.shape[1]) <= position
-    scores = jnp.where(valid[None, None, :], scores, -jnp.inf)
+    scores = jnp.where(valid, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
-    return jnp.einsum("hgs,hsd->hgd", probs, values).reshape(heads, d)
+    out = jnp.einsum("hgs,hsd->hgd", probs, values)
+    if q.ndim == 2:
+        return out.reshape(heads, d)
+    return out.reshape(keys.shape[0], q.shape[0], -1, d).swapaxes(0, 1).reshape(q.shape)
+
+
+def ring_valid(positions: jax.Array, ring: int, window: int) -> jax.Array:
+    """[W, ring]: which entries of a ring the queries at `positions` [W]
+    (ascending, the last the newest position written) may see. Entry s
+    holds the newest written position that is s modulo `ring`; a query at
+    p sees the positions p - `window` < j <= p, so never an entry a later
+    position of the same step has written over, and never one unwritten
+    (a position below 0)."""
+    newest = positions[-1]
+    held = newest - (newest - jnp.arange(ring)) % ring           # the position in each entry
+    seen = (held[None, :] <= positions[:, None]) & (held[None, :] > positions[:, None] - window)
+    return seen & (held[None, :] >= 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -893,8 +893,28 @@ def lm_layer_passes_total() -> Counter:
         "cdt_lm_layer_passes_total",
         "Layer bodies a language model's tokens walked through: "
         "cdt_lm_tokens_total times the layers a token passes (a looped "
-        "model's layers count once for each loop step)",
+        "model's layers count once for each loop step), or the model's "
+        "own count where a step runs more than one position (a drafted "
+        "token's passes count whether it was kept or not)",
         ("phase",),
+    )
+
+
+def lm_decode_steps_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_lm_decode_steps_total",
+        "Steps a language model's decode loops took: one a generated "
+        "token, fewer where a step drafts and verifies (a self-speculative "
+        "step emits one or two tokens)",
+    )
+
+
+def lm_draft_tokens_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_lm_draft_tokens_total",
+        "Tokens a language model's draft module proposed, by what the "
+        "main model's verification made of them (outcome=accepted|rejected)",
+        ("outcome",),
     )
 
 

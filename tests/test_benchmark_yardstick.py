@@ -24,11 +24,21 @@ _TESTS = os.path.join(
 # work files), and a PR may append to both but may not edit the file that
 # holds them. `benchmark/tests/test_solar_readers.py` has each in the form
 # that holds afterwards (PR 38); those are adopted, these are not.
+#
+# PR 41 appended a cell and a fourth model in the same way, and two checks
+# of `test_solar_readers.py` had pinned PR 38's state (Solar's cell the last
+# of each list, three `lm_work` files; the second is the form adopted
+# above, so it is set aside in its turn): `test_k_exaone_readers.py` has
+# what holds afterwards.
 _SUPERSEDED = {
     "test_device_every_new_metric_has_its_reader_and_names_its_cells":
         "test_device_the_twelve_metrics_of_pr_36_have_their_readers_and_lie_together",
     "test_device_the_lm_readers_find_a_models_work_by_the_checkpoint_the_workflow_loads":
-        "test_device_every_configuration_with_an_lm_work_file_is_found_by_its_registry_name",
+        "test_device_every_lm_work_file_is_found_by_its_registry_name_however_many",
+    "test_device_every_configuration_with_an_lm_work_file_is_found_by_its_registry_name":
+        "test_device_every_lm_work_file_is_found_by_its_registry_name_however_many",
+    "test_the_solar_cell_is_listed_where_its_readers_find_something":
+        "test_the_lm_cells_are_listed_where_their_readers_find_something",
 }
 
 

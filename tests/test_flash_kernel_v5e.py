@@ -239,3 +239,34 @@ def test_solar_decode_carries_its_state_tree_in_place(one_chip):
     assert memory.temp_size_in_bytes < 24 * 2**20
     # the donated tree is the output's: nothing of its size is allocated anew
     assert memory.alias_size_in_bytes >= 34_603_008 + 13_025_280
+
+
+def test_k_exaone_drafting_decode_carries_rings_and_caches_in_place(one_chip):
+    """K-EXAONE's self-speculative decode at the served share's sizes (384
+    ids over 8,576 positions): a `while` loop (the steps it takes are not
+    known when it is built) that carries the donated tree of two growing
+    caches (70.3 MB) and four rings of 136 entries (2.2 MB) and writes a
+    step's two positions where they lie. What the loop needs beside the
+    tree is 13 MB; a temporary the size of the caches would be a copy of
+    them a step."""
+    from comfyui_distributed_tpu.models import k_exaone
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("k-exaone-ep8-5l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: k_exaone.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    state = jax.tree.map(place, k_exaone.state_shapes(cfg, 8576, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    compiled = k_exaone.decode.lower(
+        cfg, params, state,
+        jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+        scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+        scalar(jnp.float32), steps=384, draft_tokens=1,
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 24 * 2**20
+    # the donated tree is the output's: nothing of its size is allocated anew
+    assert memory.alias_size_in_bytes >= 8576 * 8192 + 4 * 136 * 4096
+    text = compiled.as_text()
+    assert " while(" in text and "conditional(" not in text  # one rung a step: no branch

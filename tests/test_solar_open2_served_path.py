@@ -225,7 +225,8 @@ def test_the_other_models_attributes_keep_their_names_and_values(
         name, cell, names, values, tmp_path, monkeypatch):
     """`describe` lost its `itemsize` argument and gained `state_bytes`:
     DeepSeek-V2 and Ouro say what they said, and that they hold no state
-    of fixed size."""
+    of fixed size; since PR 41 also that they draft nothing and take a
+    step a token."""
     monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
     prompt = rehearsed(
         os.path.join(ROOT, "workflows", f"rewrite-txt2img-{name}.json"),
@@ -235,8 +236,12 @@ def test_the_other_models_attributes_keep_their_names_and_values(
         GraphExecutor(ExecutionContext()).execute(prompt)
     (node,) = spans_named(tracer.spans(root.trace_id), "node.TextGenerate")
     attrs = node["attrs"]
-    assert set(attrs) - BUILD_TALLIES == names | {"state_bytes"}
+    # `attention` only on the request that traced the programs in this process: another
+    # file's test on the same worker may have run this graph first
+    assert set(attrs) - BUILD_TALLIES - {"attention"} == (names - {"attention"}) | {
+        "state_bytes", "draft_tokens", "decode_steps"}
     assert attrs["state_bytes"] == 0
+    assert (attrs["draft_tokens"], attrs["decode_steps"]) == (0, attrs["new_tokens"])
     for key, value in values.items():
         assert attrs[key] == value, key
 
@@ -408,7 +413,8 @@ def test_the_manifest_has_the_cell_with_the_issues_traffic_and_lists():
     assert "state_mb.lm" in new and new <= {"state_mb.lm", "linear_attention_device_pct.lm"}
     for name in new:
         (metric,) = [m for m in manifest["per_layer"] if m["name"] == name]
-        assert metric["workloads"] == [CELL] and metric["moves"] == "images_per_s"
+        # its own cell first; a later model's cell may follow (PR 41's, under state_mb.lm)
+        assert metric["workloads"][0] == CELL and metric["moves"] == "images_per_s"
         assert os.path.exists(os.path.join(ROOT, "benchmark", "layer_metrics", name + ".py"))
     work = load(WORKLOAD)
     assert work["workflow"] == "benchmark/workflows/rewrite-txt2img-solar-open2.json"
