@@ -30,6 +30,15 @@ Drives the main path once, through the entry points an operator uses:
                (`ops/decode_attention`) against the einsum form over
                every slot of Ouro's 3.3 GB cache, a call a slot inside
                one jitted loop.
+    experts    one child that holds the chip runs a decode step's two
+               grouped products (gate-up, SiLU, down) over the held
+               experts' stacked weights at the three models' decode
+               shapes, and DeepSeek's at 8 rows beside its 6: the
+               kernel (`ops/expert_matvec`) against `jax.lax.ragged_dot`,
+               a step's routing drawn anew inside one jitted loop, both
+               against a float32 reference; it prints us a step, GB/s
+               of the chosen experts' weights, and which lowering the
+               compiler gave `ragged_dot` at that row count.
     multichip  only where the server reports two or more chips: the
                serve leg has then already run on every chip through
                the in-process mesh; this leg checks that, and runs the
@@ -76,7 +85,7 @@ import urllib.request
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = "comfyui_distributed_tpu"
-LEGS = ("serve", "restart", "attention", "multichip")
+LEGS = ("serve", "restart", "attention", "experts", "multichip")
 
 if not os.path.isdir(os.path.join(HERE, PACKAGE)):
     # a check of the program, not a stand-in for it: without the
@@ -610,13 +619,15 @@ def leg_restart(run: Run) -> None:
         server.stop()
 
 
-def leg_attention(run: Run) -> None:
-    """The dispatcher at the served shapes, in one child that holds
-    the chip (the servers are down by now)."""
-    cmd = [sys.executable, os.path.abspath(__file__), "--attention-child"]
+def leg_child(run: Run, name: str) -> None:
+    """The `attention` and `experts` legs: one child that holds the chip
+    (`--<name>-child`; the servers are down by now), its JSON rows
+    printed, the first one the device; a row with a `shape` that is not
+    `ok`, or none at all, fails the leg."""
+    cmd = [sys.executable, os.path.abspath(__file__), f"--{name}-child"]
     if run.rehearsal:
         cmd += ["--rehearsal", "1"]
-    log_path = os.path.join(run.out, "attention.log")
+    log_path = os.path.join(run.out, f"{name}.log")
     with open(log_path, "wb") as log:
         proc = subprocess.Popen(
             cmd, cwd=HERE, env=run.child_env(devices=1),
@@ -628,13 +639,13 @@ def leg_attention(run: Run) -> None:
         except subprocess.TimeoutExpired:
             os.killpg(proc.pid, signal.SIGKILL)
             proc.wait()
-            raise Failure("attention child still running after 900s")
+            raise Failure(f"{name} child still running after 900s")
     rows = [
         json.loads(line) for line in stdout.decode().splitlines()
         if line.startswith("{")
     ]
     for row in rows:
-        say(f"attention: {json.dumps(row)}")
+        say(f"{name}: {json.dumps(row)}")
         if "devices" in row and run.device is None:
             run.device = {
                 "platform": row["platform"], "kind": row["device_kind"],
@@ -642,11 +653,11 @@ def leg_attention(run: Run) -> None:
             }
     if proc.returncode != 0:
         raise Failure(
-            f"attention child exited {proc.returncode}; end of log:\n{tail(log_path, 40)}"
+            f"{name} child exited {proc.returncode}; end of log:\n{tail(log_path, 40)}"
         )
     bad = [r for r in rows if "shape" in r and not r["ok"]]
     if bad or not any("shape" in r for r in rows):
-        raise Failure(f"attention: {len(bad)} shape(s) out of tolerance")
+        raise Failure(f"{name}: {len(bad)} shape(s) out of tolerance")
 
 
 def leg_multichip(run: Run) -> None:
@@ -855,14 +866,14 @@ def transposed(attend):
     return call
 
 
-def attention_child(rehearsal: bool) -> int:
+def child_device(rehearsal: bool) -> bool:
+    """A child's start: the CPU only for a rehearsal, the compile cache
+    where the server keeps it, and the device as the first row. False
+    (after saying why) on any other device than the one asked for."""
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
     if rehearsal:
         jax.config.update("jax_platforms", "cpu")
-    from comfyui_distributed_tpu.ops import attention
     from comfyui_distributed_tpu.workers.startup import configure_compile_cache
 
     configure_compile_cache()
@@ -874,8 +885,35 @@ def attention_child(rehearsal: bool) -> int:
     }
     print(json.dumps(header), flush=True)
     if device.platform != ("cpu" if rehearsal else "tpu"):
-        print(f"attention child needs a TPU, found {device.platform}", file=sys.stderr)
+        print(f"this child needs a TPU, found {device.platform}", file=sys.stderr)
+        return False
+    return True
+
+
+def timed(fn, *operands):
+    """(result, seconds of the first call, ms a call of ten dispatched
+    back to back: the device's time a call)."""
+    import jax
+
+    started = time.perf_counter()
+    out = jax.block_until_ready(fn(*operands))
+    first_s = time.perf_counter() - started
+    started = time.perf_counter()
+    for _ in range(10):
+        last = fn(*operands)
+    jax.block_until_ready(last)
+    return out, first_s, 1e3 * (time.perf_counter() - started) / 10
+
+
+def attention_child(rehearsal: bool) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if not child_device(rehearsal):
         return 1
+    from comfyui_distributed_tpu.ops import attention
+
     @jax.jit
     def errors(out, q, k, v):
         with jax.default_matmul_precision("highest"):
@@ -885,16 +923,6 @@ def attention_child(rehearsal: bool) -> int:
         return (
             jnp.max(jnp.abs(out.astype(jnp.float32) - ref)), jnp.max(jnp.abs(ref))
         )
-
-    def timed(fn, *operands):
-        started = time.perf_counter()
-        out = jax.block_until_ready(fn(*operands))
-        first_s = time.perf_counter() - started
-        started = time.perf_counter()
-        for _ in range(10):  # dispatched back to back: the device's time a call
-            last = fn(*operands)
-        jax.block_until_ready(last)
-        return out, first_s, 1e3 * (time.perf_counter() - started) / 10
 
     failed = 0
     for label, q_shape, m in REHEARSAL_SHAPES if rehearsal else SERVED_SHAPES:
@@ -951,11 +979,11 @@ def attention_child(rehearsal: bool) -> int:
         row["ref_max_abs"] = round(scale, 3)
         failed += not row["ok"]
         print(json.dumps(row), flush=True)
-    failed += not decode_slot_row(rehearsal, timed)
+    failed += not decode_slot_row(rehearsal)
     return 1 if failed else 0
 
 
-def decode_slot_row(rehearsal: bool, timed) -> bool:
+def decode_slot_row(rehearsal: bool) -> bool:
     """The single-query kernel against the einsum form over every slot
     of a real cache, a call a slot inside one jitted loop (a call's cost
     inside a program, not a dispatch), both against a float32 reference."""
@@ -1009,6 +1037,128 @@ def decode_slot_row(rehearsal: bool, timed) -> bool:
     return row["ok"]
 
 
+# --- the experts child -------------------------------------------------------
+
+# (label, token-expert pairs a step, experts a token, held experts,
+# experts in all, hidden, width): a decode step of each model with a
+# mixture of experts as its benchmark cell runs it. DeepSeek-V2 a
+# quarter of 160 experts, 6 a token; Solar-Open2 an eighth of 320, 8 a
+# token; K-EXAONE an eighth of 128, 8 a token, two positions a drafting
+# step and one in its MTP module. DeepSeek's shape a second time at 8
+# rows: at a row count off the sublane tile the compiler gives
+# `ragged_dot` another lowering (PERF.md §6, PR 42).
+EXPERT_SHAPES = (
+    ("deepseek-v2 step", 6, 6, 40, 160, 5120, 1536),
+    ("deepseek-v2 step at 8 rows", 8, 8, 40, 160, 5120, 1536),
+    ("solar-open2 step", 8, 8, 40, 320, 4096, 1280),
+    ("k-exaone two positions", 16, 8, 16, 128, 6144, 2048),
+    ("k-exaone mtp position", 8, 8, 16, 128, 6144, 2048),
+)
+REHEARSAL_EXPERT_SHAPES = (
+    ("toy step off the sublane tile", 6, 3, 4, 16, 128, 64),
+    ("toy two positions", 16, 8, 4, 16, 256, 128),
+)
+EXPERT_STEPS = 64
+
+
+def step_sizes(seed: int, steps: int, rows: int, k: int, held: int, experts: int):
+    """[steps, held] rows on each held expert, a step's `rows / k` tokens
+    each choosing `k` distinct experts of `experts` evenly: held are the
+    ids below `held`."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    sizes = np.zeros((steps, held), np.int32)
+    for step in range(steps):
+        for _ in range(rows // k):
+            chosen = rng.choice(experts, size=k, replace=False)
+            np.add.at(sizes[step], chosen[chosen < held], 1)
+    return sizes
+
+
+def experts_child(rehearsal: bool) -> int:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    if not child_device(rehearsal):
+        return 1
+    from comfyui_distributed_tpu.models.moe import decode_route
+    from comfyui_distributed_tpu.ops.expert_matvec import expert_matvec
+
+    def steps_of(grouped, dtype=None):
+        """Every step's two products, a step an iteration of one loop."""
+        def loop(x, w_gate_up, w_down, sizes):
+            def body(_, step):
+                rows, sizes_i = step
+                gate, up = jnp.split(grouped(rows, w_gate_up, sizes_i), 2, axis=-1)
+                return None, grouped(jax.nn.silu(gate) * up, w_down, sizes_i)
+            if dtype is not None:
+                x, w_gate_up, w_down = (a.astype(dtype) for a in (x, w_gate_up, w_down))
+            return jax.lax.scan(body, None, (x, sizes))[1]
+        return jax.jit(loop)
+
+    def in_float32(rows, weights, sizes):
+        # on whole sublane tiles of rows: at 6 rows the compiler's float32
+        # lowering read 3.4 off both bfloat16 forms, which agree with each
+        # other to the digit and with the model's reference (PERF.md §6, PR 42)
+        padded = jnp.pad(rows, ((0, -rows.shape[0] % 8), (0, 0)))
+        return jax.lax.ragged_dot(
+            padded, weights, sizes, precision=jax.lax.Precision.HIGHEST)[:rows.shape[0]]
+
+    failed = 0
+    steps = 4 if rehearsal else EXPERT_STEPS
+    for label, rows, k, held, experts, hidden, width in (
+            REHEARSAL_EXPERT_SHAPES if rehearsal else EXPERT_SHAPES):
+        keys = jax.random.split(jax.random.key(hidden + rows), 3)
+        x = jax.random.normal(keys[0], (steps, rows, hidden), jnp.bfloat16)
+        w_gate_up = jax.jit(lambda key: hidden ** -0.5 * jax.random.normal(
+            key, (held, hidden, 2 * width), jnp.bfloat16))(keys[1])
+        w_down = jax.jit(lambda key: width ** -0.5 * jax.random.normal(
+            key, (held, width, hidden), jnp.bfloat16))(keys[2])
+        sizes = step_sizes(rows * 1000 + held, steps, rows, k, held, experts)
+        # what a step has to read: the chosen held experts' matrices
+        step_bytes = np.count_nonzero(sizes, axis=1).mean() * 3 * hidden * width * 2
+        route = decode_route(rows, hidden, width, jnp.bfloat16)
+        row = {
+            "shape": label, "rows": rows, "held": held, "hidden": hidden, "width": width,
+            "dtype": "bfloat16", "route": route,
+            "chosen_a_step": round(float(np.count_nonzero(sizes, axis=1).mean()), 3),
+            "held_pairs_a_step": round(float(sizes.sum(axis=1).mean()), 3),
+            "mb_a_step": round(step_bytes / 1e6, 2),
+            "ok": rehearsal or route == "kernel",
+        }
+        operands = (x, w_gate_up, w_down, jnp.asarray(sizes))
+        ref = np.asarray(steps_of(in_float32, jnp.float32)(*operands))
+        # a row past the step's held pairs is nobody's: the kernel leaves
+        # it zero, `ragged_dot` what it likes
+        mine = (np.arange(rows)[None, :] < sizes.sum(axis=1)[:, None])[:, :, None]
+        scale = max(1.0, float(np.abs(ref * mine).max()))
+        for name, grouped in (
+            ("kernel", functools.partial(expert_matvec, interpret=rehearsal)),
+            ("xla", jax.lax.ragged_dot),
+        ):
+            fn = steps_of(grouped)
+            if name == "xla" and not rehearsal:
+                # off the sublane tile the compiler has no grouped kernel
+                # of its own and multiplies under a mask a group
+                text = fn.lower(*operands).compile().as_text()
+                row["xla_lowering"] = (
+                    "masked convolution" if " convolution(" in text else "custom call")
+            out, first_s, ms = timed(fn, *operands)
+            err = float(np.abs((np.asarray(out, np.float32) - ref) * mine).max())
+            row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
+            row[name] = {
+                "max_abs_err": round(err, 5), "first_call_s": round(first_s, 2),
+                "us_a_step": round(1e3 * ms / steps, 2),
+                "gb_per_s": round(step_bytes / (1e-3 * ms / steps) / 1e9, 1),
+            }
+        row["ref_max_abs"] = round(scale, 3)
+        failed += not row["ok"]
+        print(json.dumps(row), flush=True)
+    return 1 if failed else 0
+
+
 # --- entry -----------------------------------------------------------------
 
 
@@ -1030,10 +1180,13 @@ def main(argv=None) -> int:
     )
     parser.add_argument("--port", type=int, default=18188)
     parser.add_argument("--attention-child", action="store_true", help=argparse.SUPPRESS)
+    parser.add_argument("--experts-child", action="store_true", help=argparse.SUPPRESS)
     args = parser.parse_args(argv)
 
     if args.attention_child:
         return attention_child(bool(args.rehearsal))
+    if args.experts_child:
+        return experts_child(bool(args.rehearsal))
 
     legs = [leg.strip() for leg in args.legs.split(",") if leg.strip()]
     unknown = sorted(set(legs) - set(LEGS))
@@ -1059,7 +1212,9 @@ def main(argv=None) -> int:
         if "restart" in legs:
             run.attempt("restart", lambda: leg_restart(run))
         if "attention" in legs:
-            run.attempt("attention", lambda: leg_attention(run))
+            run.attempt("attention", lambda: leg_child(run, "attention"))
+        if "experts" in legs:
+            run.attempt("experts", lambda: leg_child(run, "experts"))
         if "multichip" in legs and run.device and run.device["count"] >= 2:
             run.attempt("multichip", lambda: leg_multichip(run))
     finally:

@@ -52,7 +52,7 @@ from .lm_common import (  # noqa: F401  (the names this module has always had)
     sample,
     swiglu,
 )
-from .moe import expert_layer, report_loads
+from .moe import decode_route, expert_layer, report_loads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -465,6 +465,9 @@ class DeepSeekV2(LanguageModel):
     def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
         """Per phase: the token-expert pairs the router made, those that
         fell on held experts, and the fullest held expert's."""
+        cfg = self.cfg
         return report_loads(
-            self.cfg.num_experts_per_tok, self.cfg.n_routed_experts,
-            prompt_tokens, new_tokens, prefill_loads, decode_loads)
+            cfg.num_experts_per_tok, cfg.n_routed_experts,
+            prompt_tokens, new_tokens, prefill_loads, decode_loads,
+            decode_route(
+                cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype))

@@ -70,7 +70,7 @@ from .lm_common import (
     sample,
     swiglu,
 )
-from .moe import expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, report_loads, sigmoid_route
 
 # A ring's length is a whole number of these (the sublane tile of a
 # 32-bit layout; a 16-bit one pads to twice it by itself).
@@ -653,7 +653,11 @@ class KExaone(LanguageModel):
         return {
             **report_loads(
                 cfg.num_experts_per_tok, cfg.num_experts, prompt_tokens, new_tokens,
-                prefill_loads, decode_loads),
+                prefill_loads, decode_loads,
+                # a step's positions in a main layer; the module's one takes the same route
+                decode_route(
+                    width * cfg.num_experts_per_tok, cfg.hidden_size,
+                    cfg.moe_intermediate_size, self.dtype)),
             "decode_routed_pairs": pairs, "decode_expert_rows": pairs,
             "decode_steps": steps, "mtp_drafted": drafted, "mtp_accepted": accepted,
             "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,

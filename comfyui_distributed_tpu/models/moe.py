@@ -16,7 +16,11 @@ grouped products and the weighted way back to `[T, hidden]` run over a
 static prefix of the sorted pairs: the smallest rung of `row_ladder`
 that holds them, which the device picks from the routing's own count
 (`lax.switch`; nothing is read back). The top rung is all `T x k` pairs,
-so no routing, however skewed, drops a pair.
+so no routing, however skewed, drops a pair. Where the pairs are a tile
+or less (a decode step: a ladder of one rung) the two grouped products
+run, on a TPU, in the Pallas kernel of `ops/expert_matvec.py`, which
+reads each chosen held expert's weights once, out of the stacked array
+(`decode_route`); the prefill's stay `ragged_dot`.
 
 Parameters: `w_g` [hidden, experts] (the router), `experts` {`w_gate_up`
 [held, hidden, 2 x width], `w_down` [held, width, hidden]}, `shared` (one
@@ -33,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.expert_matvec import expert_matvec, expert_matvec_route
 from .lm_common import swiglu
 
 
@@ -79,6 +84,22 @@ def sigmoid_route(logits: jax.Array, bias: jax.Array, k: int, scale: float = 1.0
     return ids, weights * scale
 
 
+def decode_route(rows: int, hidden: int, width: int, dtype) -> str:
+    """How a layer of `rows` token-expert pairs runs its two grouped
+    products (`[hidden, 2 x width]`, then `[width, hidden]`): "kernel"
+    where the ladder has one rung because the pairs are a tile or less
+    (a decode step) and `ops/expert_matvec` takes both shapes on this
+    backend, else "xla" (`jax.lax.ragged_dot`). The layer asks while it
+    is traced, a model's `report` afterwards."""
+    if rows > ROW_TILE:
+        return "xla"
+    routes = {
+        expert_matvec_route(rows, k, n, dtype)
+        for k, n in ((hidden, 2 * width), (width, hidden))
+    }
+    return "kernel" if routes == {"kernel"} else "xla"
+
+
 def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
     """x [T, hidden] through the layer. `route(logits)` takes the
     router's float32 logits [T, experts] and returns (ids [T, k],
@@ -101,16 +122,20 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
         order = jnp.argsort(slot, stable=True)
         sizes = jnp.zeros((len(held),), jnp.int32).at[slot].add(1, mode="drop")
 
+        # a decode step's few rows: each chosen held expert's weights
+        # read once where they lie (`ops/expert_matvec`)
+        w_down = p["experts"]["w_down"]
+        how = decode_route(tokens * k, w_down.shape[2], w_down.shape[1], w_down.dtype)
+        grouped = expert_matvec if how == "kernel" else jax.lax.ragged_dot
+
         def over(rows_n: int):
             """The held experts' part [T, hidden] float32 from the first
             `rows_n` sorted pairs, which has to cover every held one."""
             top = order[:rows_n]
             token = top // k
             rows = x[token]
-            gate, up = jnp.split(
-                jax.lax.ragged_dot(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1
-            )
-            out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
+            gate, up = jnp.split(grouped(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1)
+            out = grouped(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
             # rows past the last segment are absent experts' pairs: weight 0
             out = jnp.where(here[top][:, None], out, 0).astype(jnp.float32)
             out = out * weights.reshape(-1)[top][:, None]
@@ -139,14 +164,16 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
 
 
 def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
-                 prefill_loads, decode_loads) -> dict:
+                 prefill_loads, decode_loads, decode_expert_route: str) -> dict:
     """`node.TextGenerate`'s attributes of the routing, per phase: the
     token-expert pairs the router made (`k` a token and expert layer),
     those that fell on held experts (`loads` [expert layers, held], as
     read back), the fullest held expert's, and the rows the grouped
     products were run over: for the prefill each layer's rung, read from
     its load as the device read it; a decode step's `k` pairs are under
-    a tile, a ladder of one rung, so its rows are its pairs."""
+    a tile, a ladder of one rung, so its rows are its pairs, and
+    `decode_expert_route` (the model's `decode_route` of a step's pairs)
+    says what multiplied them."""
     layers, held = np.shape(prefill_loads)
     attrs = {}
     for phase, tokens, loads in (
@@ -159,4 +186,5 @@ def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
     attrs["prefill_expert_rows"] = sum(
         ladder[rung_index(ladder, int(n))] for n in np.sum(prefill_loads, axis=1))
     attrs["decode_expert_rows"] = attrs["decode_routed_pairs"]
+    attrs["decode_expert_route"] = decode_expert_route
     return attrs

@@ -55,7 +55,7 @@ from ..ops.attention import dot_product_attention
 from ..ops.decode_attention import decode_attention_xla
 from ..parallel.sharding import expert_range
 from .lm_common import LanguageModel, count_params, init_from_shapes, rms_norm, sample
-from .moe import expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, report_loads, sigmoid_route
 
 # Rows of a chunk whose pairwise decays are formed pair by pair
 # (`decay_products`); between such blocks they go through one product.
@@ -650,9 +650,13 @@ class SolarOpen2(LanguageModel):
     def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
         """The chunks a linear layer's prefill scanned, and per phase the
         token-expert pairs the router made and those on held experts."""
+        cfg = self.cfg
         return {
-            "prefill_chunks": -(-prompt_tokens // self.cfg.kda_chunk),
+            "prefill_chunks": -(-prompt_tokens // cfg.kda_chunk),
             **report_loads(
-                self.cfg.num_experts_per_tok, self.cfg.n_routed_experts,
-                prompt_tokens, new_tokens, prefill_loads, decode_loads),
+                cfg.num_experts_per_tok, cfg.n_routed_experts,
+                prompt_tokens, new_tokens, prefill_loads, decode_loads,
+                decode_route(
+                    cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size,
+                    self.dtype)),
         }

@@ -1,8 +1,8 @@
 """chip_smoke.py end to end in its rehearsal mode (integration tier):
 the same legs, requests and checks as on the chip — a CLI server child,
 both workflows over HTTP cold and warm, a restart that must hit the
-compile cache and reproduce the bytes, the attention child — at toy
-size on the CPU, because the caller said so."""
+compile cache and reproduce the bytes, the attention child, the experts
+child — at toy size on the CPU, because the caller said so."""
 
 import json
 import os
@@ -32,7 +32,7 @@ def test_chip_smoke_rehearsal_passes(tmp_path):
         "rehearsal": True,
     }
     assert "REHEARSAL" in lines[0]
-    for leg in ("serve", "restart", "attention"):
+    for leg in ("serve", "restart", "attention", "experts"):
         assert any(f"leg {leg} passed" in line for line in lines), leg
     assert any("bytes identical to the first server's" in line for line in lines)
     # the attention child ran both routes at every toy shape, a padded one too
@@ -40,3 +40,7 @@ def test_chip_smoke_rehearsal_passes(tmp_path):
     assert any('"route": "xla"' in line and '"flash": {"entry"' in line for line in lines)
     # and the single-query kernel (interpreted) against the einsum form
     assert any('"shape": "toy decode slot"' in line and '"ok": true' in line for line in lines)
+    # and a decode step's grouped products, the kernel (interpreted) against `ragged_dot`
+    assert any(
+        '"shape": "toy step off the sublane tile"' in line and '"ok": true' in line
+        for line in lines)

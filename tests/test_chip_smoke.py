@@ -111,3 +111,48 @@ def test_warm_requests_change_only_the_seed(smoke):
         if warm[node_id]["inputs"][key] != value
     ]
     assert changed == [("5", "seed")]
+
+
+def test_the_legs_by_name(smoke):
+    assert smoke.LEGS == ("serve", "restart", "attention", "experts", "multichip")
+    proc = subprocess.run(
+        [sys.executable, SMOKE, "--legs", "experts,kernels"],
+        capture_output=True, text=True, timeout=60,
+    )
+    assert proc.returncode == 2 and "unknown leg(s) ['kernels']" in proc.stderr
+
+
+def test_the_experts_leg_times_the_shapes_the_three_models_decode_at(smoke):
+    """(pairs a step, experts a token, held, experts, hidden, width) of
+    every row are a registered configuration's own numbers."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    deepseek, solar, exaone = (
+        get_config(name) for name in ("deepseek-v2-ep4-5l", "solar-open2-ep8-4l", "k-exaone-ep8-5l"))
+    served = {
+        "deepseek-v2 step": (
+            deepseek.num_experts_per_tok, deepseek.num_experts_per_tok,
+            len(deepseek.held_experts), deepseek.n_routed_experts, deepseek.hidden_size,
+            deepseek.moe_intermediate_size),
+        "solar-open2 step": (
+            solar.num_experts_per_tok, solar.num_experts_per_tok, len(solar.held_experts),
+            solar.n_routed_experts, solar.hidden_size, solar.moe_intermediate_size),
+        "k-exaone two positions": (
+            2 * exaone.num_experts_per_tok, exaone.num_experts_per_tok,
+            len(exaone.held_experts), exaone.num_experts, exaone.hidden_size,
+            exaone.moe_intermediate_size),
+    }
+    rows = {label: rest for label, *rest in smoke.EXPERT_SHAPES}
+    for label, numbers in served.items():
+        assert tuple(rows[label]) == numbers, label
+    # the lowering question: DeepSeek's widths at 8 rows beside its 6
+    assert rows["deepseek-v2 step at 8 rows"][2:] == rows["deepseek-v2 step"][2:]
+    assert rows["deepseek-v2 step at 8 rows"][0] == 8
+
+
+def test_a_steps_routing_is_k_distinct_experts_a_token(smoke):
+    sizes = smoke.step_sizes(7, steps=200, rows=16, k=8, held=16, experts=128)
+    assert sizes.shape == (200, 16) and sizes.max() <= 2  # two tokens: at most two rows an expert
+    # an eighth of the experts held: two of a step's 16 pairs on average
+    assert 1.5 < sizes.sum(axis=1).mean() < 2.5
+    assert (sizes.sum(axis=1) == 0).any()

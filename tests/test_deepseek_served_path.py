@@ -165,6 +165,7 @@ def test_node_textgenerate_says_what_ran(served):
     assert attrs["prefill_expert_rows"] in {2 * 1536, 1536 + 3072, 2 * 3072}
     assert attrs["prefill_routed_pairs_held"] <= attrs["prefill_expert_rows"]
     assert attrs["decode_expert_rows"] == attrs["decode_routed_pairs"]
+    assert attrs["decode_expert_route"] == "xla"  # off a TPU
 
 
 def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(served):

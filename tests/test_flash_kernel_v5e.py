@@ -270,3 +270,55 @@ def test_k_exaone_drafting_decode_carries_rings_and_caches_in_place(one_chip):
     assert memory.alias_size_in_bytes >= 8576 * 8192 + 4 * 136 * 4096
     text = compiled.as_text()
     assert " while(" in text and "conditional(" not in text  # one rung a step: no branch
+
+
+@pytest.mark.parametrize("label,rows,k,held,experts,hidden,width", chip_smoke.EXPERT_SHAPES)
+def test_expert_matvec_compiles_for_v5e_at_the_decode_shapes(
+        one_chip, label, rows, k, held, experts, hidden, width):
+    """A decode step's two grouped products at each model's published
+    widths: the stacked weights stay where they are (no temporary: no
+    slice of the stack in front of the call)."""
+    from comfyui_distributed_tpu.ops import expert_matvec as em
+
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    for contraction, columns in ((hidden, 2 * width), (width, hidden)):
+        compiled = jax.jit(em.expert_matvec).lower(
+            jax.ShapeDtypeStruct((rows, contraction), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((held, contraction, columns), jnp.bfloat16, sharding=one_chip),
+            sizes,
+        ).compile()
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+# as above: a step count a case, since the route is read while the program is traced
+@pytest.mark.parametrize("backend,steps,calls,masked", [("tpu", 256, 8, 0), ("cpu", 3, 0, 8)])
+def test_deepseek_decode_multiplies_its_six_pairs_in_the_kernel(
+        one_chip, monkeypatch, backend, steps, calls, masked):
+    """DeepSeek-V2's decode at the served share's sizes. Six rows are no
+    multiple of the sublane tile, so the compiler has no grouped kernel
+    of its own for them: left to it, `ragged_dot` is a product with every
+    one of the 40 held experts under a mask (`bf16[40,6,...]`, two a
+    layer); routed as a TPU routes it, two `expert_matvec` calls a layer
+    and no such product."""
+    import re
+
+    from comfyui_distributed_tpu.models import deepseek_v2
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = get_config("deepseek-v2-ep4-5l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: deepseek_v2.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    text = deepseek_v2.decode.lower(
+        cfg, params,
+        jax.ShapeDtypeStruct(
+            (cfg.num_hidden_layers, 2304, cfg.cache_width), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+        scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+        scalar(jnp.float32), steps=steps,
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
+    assert len(re.findall(r"= bf16\[40,6,\d+\]\S* convolution\(", text)) == masked

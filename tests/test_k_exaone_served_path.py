@@ -169,6 +169,7 @@ def test_node_textgenerate_says_what_a_drafting_model_with_rings_ran(served):
         attrs["decode_routed_pairs_held"], steps * (SPARSE + 1) * HELD)
     assert held <= attrs["prefill_expert_rows"] < attrs["prefill_routed_pairs"]
     assert attrs["decode_expert_rows"] == attrs["decode_routed_pairs"]
+    assert attrs["decode_expert_route"] == "xla"  # off a TPU
     assert not any(key.startswith(("exit_mass", "linear_layers", "prefill_chunks"))
                    for key in attrs)
 
@@ -240,9 +241,10 @@ def test_a_model_without_a_draft_module_refuses_to_draft(tmp_path, monkeypatch):
         GraphExecutor(ExecutionContext()).execute(prompt)
 
 
-def test_solars_attributes_are_what_they_were_but_for_two(tmp_path, monkeypatch):
+def test_solars_attributes_are_what_they_were_but_for_three(tmp_path, monkeypatch):
     """`draft_tokens` is an optional input: Solar's committed workflow,
-    which does not give it, runs as before and says 0 and a step a token."""
+    which does not give it, runs as before and says 0 and a step a token
+    (and, since PR 42, what multiplied a decode step's pairs)."""
     monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
     attrs = node_attrs(rehearsed(
         SOLAR_WORKFLOW, os.path.join(ROOT, "benchmark", "workloads", SOLAR_CELL + ".json")))
@@ -254,7 +256,7 @@ def test_solars_attributes_are_what_they_were_but_for_two(tmp_path, monkeypatch)
         "experts_total", "cache_bytes", "state_bytes", "prefill_chunks", "prefill_routed_pairs",
         "prefill_routed_pairs_held", "prefill_expert_load_max", "decode_routed_pairs",
         "decode_routed_pairs_held", "decode_expert_load_max", "prefill_expert_rows",
-        "decode_expert_rows", "node_id", "draft_tokens", "decode_steps"}
+        "decode_expert_rows", "decode_expert_route", "node_id", "draft_tokens", "decode_steps"}
     assert (attrs["draft_tokens"], attrs["decode_steps"]) == (0, attrs["new_tokens"])
 
 

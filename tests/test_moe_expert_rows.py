@@ -232,7 +232,7 @@ def test_the_rung_the_host_reports_is_the_rung_the_device_took(held_pairs, rows_
     assert rows_run() == [want]
     loads = np.stack([np.asarray(sizes)] * 3)  # three expert layers alike
     step = np.asarray([[1, 0], [0, 2], [1, 1]])
-    attrs = moe.report_loads(K, EXPERTS, TOKENS, 5, loads, step)
+    attrs = moe.report_loads(K, EXPERTS, TOKENS, 5, loads, step, "xla")
     assert attrs["prefill_expert_rows"] == 3 * want
     assert attrs["prefill_routed_pairs"] == 3 * TOKENS * K
     assert attrs["prefill_routed_pairs_held"] == 3 * held_pairs
@@ -247,10 +247,11 @@ def test_the_rung_the_host_reports_is_the_rung_the_device_took(held_pairs, rows_
 def test_the_layers_of_one_prefill_report_a_rung_each():
     loads = np.zeros((4, 40), np.int64)
     loads[:, 0] = [6000, 8192, 8193, 40000]
-    attrs = moe.report_loads(8, 320, 8192, 256, loads, np.ones((4, 40), np.int64))
+    attrs = moe.report_loads(8, 320, 8192, 256, loads, np.ones((4, 40), np.int64), "kernel")
     assert attrs["prefill_expert_rows"] == 8192 + 8192 + 16384 + 65536
     assert attrs["prefill_routed_pairs"] == 4 * 65536
     assert attrs["decode_expert_rows"] == attrs["decode_routed_pairs"] == 256 * 8 * 4
+    assert attrs["decode_expert_route"] == "kernel"
 
 
 def _primitives(jaxpr) -> list:

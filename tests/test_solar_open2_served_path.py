@@ -154,6 +154,7 @@ def test_node_textgenerate_says_what_a_model_with_two_kinds_of_state_ran(served)
     assert held <= attrs["prefill_expert_rows"] < attrs["prefill_routed_pairs"]
     assert attrs["prefill_expert_rows"] % 256 == 0
     assert attrs["decode_expert_rows"] == attrs["decode_routed_pairs"]
+    assert attrs["decode_expert_route"] == "xla"  # off a TPU
     # nothing of a looped model
     assert not any(key.startswith(("exit_mass", "ut_steps", "cache_slots")) for key in attrs)
 
@@ -202,7 +203,7 @@ DEEPSEEK_ATTRS = {
     "prompt_tokens", "new_tokens", "layers", "experts_held", "experts_total", "cache_bytes",
     "prefill_routed_pairs", "prefill_routed_pairs_held", "prefill_expert_load_max",
     "decode_routed_pairs", "decode_routed_pairs_held", "decode_expert_load_max",
-    "prefill_expert_rows", "decode_expert_rows", "attention", "node_id"}
+    "prefill_expert_rows", "decode_expert_rows", "decode_expert_route", "attention", "node_id"}
 OURO_ATTRS = {
     "prompt_tokens", "new_tokens", "ut_steps", "layers", "cache_slots", "cache_bytes",
     "prefill_layer_passes", "decode_layer_passes", "exit_mass_1", "exit_mass_2", "exit_mass_3",
