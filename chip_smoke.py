@@ -26,7 +26,10 @@ Drives the main path once, through the entry points an operator uses:
                between the [B, N, H*D] arrays a model holds and against
                a float32 reference, and prints which route the shape
                rule gives each shape and all three times; then the
-               single-query kernel of a language model's decode
+               four causal calls of the language models' prefills
+               (`CAUSAL_SHAPES`) on the kernel under its mask and on the
+               XLA form, and the kernel under each pair of block caps
+               of the sweep; then the single-query kernel of a language model's decode
                (`ops/decode_attention`) against the einsum form over
                every slot of Ouro's 3.3 GB cache, a call a slot inside
                one jitted loop.
@@ -841,6 +844,30 @@ REHEARSAL_SHAPES = (
 )
 
 
+# (label, [B, N, H, Dq] of q, key heads, value width, window): the causal
+# calls of the four language models' prefills (PR 43). Solar-Open2's one
+# softmax layer and K-EXAONE's full layer are the same call, 8,192 tokens
+# at 64 query over 8 key heads; K-EXAONE's window layers the same under a
+# band of 128; Ouro 2,048 tokens at 16 over 16; DeepSeek-V2's MLA 2,048
+# tokens at 128 heads, q and k 192 wide beside a v of 128 and a scale of
+# its own.
+CAUSAL_SHAPES = (
+    ("solar / k-exaone full 8192", (1, 8192, 64, 128), 8, 128, None),
+    ("k-exaone window 8192", (1, 8192, 64, 128), 8, 128, 128),
+    ("ouro 2048", (1, 2048, 16, 128), 16, 128, None),
+    ("deepseek-v2 mla 2048", (1, 2048, 128, 192), 128, 128, None),
+)
+REHEARSAL_CAUSAL_SHAPES = (
+    ("toy causal grouped", (1, 1280, 4, 128), 2, 128, None),
+    ("toy causal window", (1, 1280, 2, 128), 2, 128, 100),
+    ("toy causal value width", (1, 200, 2, 192), 2, 128, None),
+)
+# (block_q, block_k) caps the causal kernel is timed under beside the
+# plan's own: what `ops/attention.CAUSAL_CAPS` and `BAND_CAPS` rest on
+CAUSAL_SWEEP = ((256, 512), (512, 256), (512, 512), (512, 1024), (1024, 512), (1024, 1024))
+BAND_SWEEP = ((256, 128), (256, 256), (512, 128), (512, 256), (512, 512), (1024, 256))
+
+
 # A language model's decode attends with one query a head over a cache
 # slot (`ops/decode_attention`): Ouro-2.6B's carried cache at the
 # benchmark cell's 2,048 + 64 positions, [passes, layers, keys|values,
@@ -979,8 +1006,90 @@ def attention_child(rehearsal: bool) -> int:
         row["ref_max_abs"] = round(scale, 3)
         failed += not row["ok"]
         print(json.dumps(row), flush=True)
+    for shape in REHEARSAL_CAUSAL_SHAPES if rehearsal else CAUSAL_SHAPES:
+        failed += not causal_row(rehearsal, *shape)
     failed += not decode_slot_row(rehearsal)
     return 1 if failed else 0
+
+
+def causal_row(rehearsal: bool, label, q_shape, kv_heads, v_width, window) -> bool:
+    """A prefill's causal call on both routes, between the [B, N, H*D]
+    arrays a model's projections give and take, against the XLA form in
+    float32; then the kernel under each pair of block caps of the sweep
+    (ms only: the plan's caps rest on these)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.ops import attention
+
+    b, n, h, d = q_shape
+    scale = 0.1147 if d != v_width else None  # DeepSeek-V2's is its own (YaRN)
+    shapes = (q_shape, (b, n, kv_heads, d), (b, n, kv_heads, v_width))
+
+    @jax.jit
+    def operands(key):
+        keys = jax.random.split(key, 3)
+        return tuple(
+            (gain * jax.random.normal(k, (s[0], s[1], s[2] * s[3]))).astype(jnp.bfloat16)
+            for k, s, gain in zip(keys, shapes, (2.0, 1.0, 1.0)))
+
+    def as_served(attend):
+        def call(*flat):
+            out = attend(*(x.reshape(s) for x, s in zip(flat, shapes)))
+            return out.reshape(b, n, h * v_width)
+        return jax.jit(call)
+
+    flat = operands(jax.random.key(n * 131 + h * 7 + d))
+    route = "flash" if attention.causal_kernel_wins(n, v_width, window) else "xla"
+    row = {
+        "shape": label, "q": list(q_shape), "key_heads": kv_heads, "value_width": v_width,
+        "window": window, "dtype": "bfloat16", "route": route, "ok": True,
+    }
+    if not rehearsal and attention.causal_route(
+            *(jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes), window) != route:
+        row["ok"] = False
+
+    @as_served
+    def in_float32(q, k, v):
+        with jax.default_matmul_precision("highest"):
+            return attention.causal_attention_blocked(
+                q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+                scale=scale, window=window)
+
+    ref = np.asarray(in_float32(*flat))
+    ref_max = max(1.0, float(np.abs(ref).max()))
+    for name in ("flash", "xla"):
+        flash = name == "flash"
+        fn = as_served(functools.partial(
+            attention.causal_attention, scale=scale, window=window, force_flash=flash,
+            interpret=flash and rehearsal))
+        with attention.route_log() as routes:
+            out, first_s, ms = timed(fn, *flat)
+        err = float(np.abs(np.asarray(out, np.float32) - ref).max())
+        row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * ref_max
+        row[name] = {
+            "entry": routes[0], "max_abs_err": round(err, 5),
+            "first_call_s": round(first_s, 2), "ms": round(ms, 3),
+        }
+    row["ref_max_abs"] = round(ref_max, 3)
+    caps_name = "CAUSAL_CAPS" if window is None else "BAND_CAPS"
+    chosen, sweep = getattr(attention, caps_name), {}
+    for caps in () if rehearsal else (CAUSAL_SWEEP if window is None else BAND_SWEEP):
+        # the plan reads the caps while the call is traced: a new trace a pair
+        setattr(attention, caps_name, caps)
+        try:
+            _, block_q, block_k = attention.flash_plan(
+                n, n, max(d + -d % 128, v_width), 2, causal=True, window=window)[1:]
+            fn = as_served(functools.partial(
+                attention.flash_attention.__wrapped__, scale=scale, causal=True, window=window))
+            sweep[f"bq{block_q} bk{block_k}"] = round(timed(fn, *flat)[2], 3)
+        finally:
+            setattr(attention, caps_name, chosen)
+    if sweep:
+        row["sweep_ms"] = sweep
+    print(json.dumps(row), flush=True)
+    return row["ok"]
 
 
 def decode_slot_row(rehearsal: bool) -> bool:

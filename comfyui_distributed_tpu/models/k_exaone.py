@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import causal_attention_blocked
+from ..ops.attention import causal_attention
 from ..ops.decode_attention import decode_attention_xla, ring_valid
 from ..parallel.sharding import expert_range
 from .lm_common import (
@@ -270,7 +270,7 @@ def mixer_whole(cfg, p, x, window: bool):
     (output [T, hidden], keys and values [2, key heads, T, d])."""
     rope = rope_tables(cfg, jnp.arange(x.shape[0])) if window else None
     q, k, v = _projections(cfg, p, x, rope)
-    out = causal_attention_blocked(
+    out = causal_attention(
         q[None], k[None], v[None], window=cfg.sliding_window if window else None)[0]
     return out.reshape(x.shape[0], -1) @ p["w_o"], _entries(k, v)
 

@@ -319,8 +319,10 @@ def prefill(cfg: OuroConfig, params, ids, *, cache_len: int, collect: bool = Fal
 
     def one_layer(p, x, cache, slot):
         x, slot_kv = layer_whole(cfg, p, x, rope)
-        return x, jax.lax.dynamic_update_slice(
-            cache, slot_kv[None, None], (*slot, 0, 0, 0, 0))
+        cache = jax.lax.dynamic_update_slice(cache, slot_kv[None, None], (*slot, 0, 0, 0, 0))
+        # as in `layer_cached`: the attention kernel takes k and v token-major, and
+        # the compiler would else order the cache's axes so and copy it whole at the end
+        return x, with_layout_constraint(cache, Layout(major_to_minor=tuple(range(cache.ndim))))
 
     cache = jnp.zeros(cfg.cache_shape(cache_len), params["embed"].dtype)
     h, cache, lam, hidden = _loop(cfg, params, x, cache, one_layer)

@@ -8,6 +8,12 @@
 - `jax.nn.dot_product_attention` elsewhere (other backends, and the
   shapes `kernel_wins` leaves to XLA).
 
+A causal call (`causal_attention`: a language model's prefill) takes the
+same kernel under a causal mask, with a band where the layer has a
+window, on a TPU for the shapes `causal_kernel_wins` names, and
+`causal_attention_blocked`, XLA operations over blocks of query rows,
+elsewhere.
+
 The reference has no attention code at all (torch/ComfyUI provides
 it); this is new TPU-native surface.
 """
@@ -50,6 +56,24 @@ ROW_MULTIPLE = 16
 # 324 keys (0.60 ms against 0.76), 400 and 484 are ties, and the kernel
 # wins from 500 (1.25 against 1.47) and 576 (0.66 against 0.81) on.
 MIN_RAGGED_KEYS = 512
+# (block_q, block_k) caps of a causal call and of one under a window,
+# swept on a v5e (PERF.md §6, PR 43). A step's cost is mostly fixed (the
+# running max, the rescaled accumulator, the pipeline's turn), so the
+# widest k block wins although the triangle then computes more of the
+# square: 8,192 tokens at 64 heads take 16.9 ms as 512 x 512 (136 of 256
+# blocks), 11.5 as 512 x 1,024 (72 of 128), 30.3 as 512 x 256. A band
+# of 128 keys a row is all edge: 512 x 512 was its best (3.74 ms).
+CAUSAL_CAPS = (512, 1024)
+BAND_CAPS = (512, 512)
+# Under a window shorter than this a causal call stays on XLA: every
+# block the kernel computes is crossed by an edge, and of a q block's
+# 512 + window keys fetched in blocks of 512 a window of 128 sees an
+# eighth, where `causal_attention_blocked` multiplies 255 + window keys
+# a row: 2.10 ms against the kernel's 3.74 at 8,192 tokens, 64 heads
+# (PR 43). By those two rates the routes cross between 512 and 768;
+# two q blocks is where at least half of what is computed is seen. No
+# window between 128 and 8,192 has been timed.
+MIN_BAND_WINDOW = 1024
 
 
 _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
@@ -66,7 +90,9 @@ def route_log():
     `inplace` where it reads the heads where the caller left them, a
     width that is a multiple of 128: `flash 4608x4608x128 bq512 bk1536
     bf16 inplace`, `flash 1296x1296x64 pad1296x1408 bq432 bk1408
-    bf16`). Calls happen while a
+    bf16`); a causal call logs `flash-causal ...` (`causal_attention`
+    has its grammar) or `xla-causal NxMxDq/Dv [w<window>] bq<rows>
+    <dtype>`. Calls happen while a
     program is traced, so a block around a jitted call fills only on
     the request that builds the program; the graph's sampler and
     upscale nodes read it into their spans."""
@@ -88,9 +114,10 @@ def dot_product_attention(
 ) -> jax.Array:
     """[B, N, H, D] attention; returns [B, N, H, D].
 
-    `causal` (query i sees keys 0..i + M - N) and a value width or a
-    `scale` of its own go to `causal_attention_blocked`, an XLA form;
-    `force_flash` and `interpret` do not apply there.
+    `causal` (query i sees keys 0..i + M - N), with a value width or a
+    `scale` of its own where given, is `causal_attention`'s call: the
+    kernel under its mask on a TPU for the shapes `causal_kernel_wins`
+    names, else `causal_attention_blocked`, an XLA form.
 
     `force_flash` overrides backend routing and `interpret` runs the
     Pallas kernel in the interpreter: both exist for tests that pin the
@@ -105,7 +132,8 @@ def dot_product_attention(
     2 x at 64 (packing narrow heads into one lane tile is ROADMAP S2).
     """
     if causal:
-        return causal_attention_blocked(q, k, v, scale=scale)
+        return causal_attention(
+            q, k, v, scale=scale, force_flash=force_flash, interpret=interpret)
     if scale is not None or v.shape[-1] != q.shape[-1]:
         raise NotImplementedError(
             "a scale or a value width of its own is implemented for causal attention only"
@@ -130,6 +158,54 @@ def dot_product_attention(
     return flash_attention(q, k, v, interpret=interpret)
 
 
+def _check_causal(q: jax.Array, k: jax.Array, v: jax.Array) -> None:
+    n, m, heads, kv_heads = q.shape[1], k.shape[1], q.shape[2], k.shape[2]
+    if n > m:
+        raise ValueError(f"causal attention of {n} queries over {m} keys")
+    if heads % kv_heads or v.shape[2] != kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} / {v.shape[2]} key / value heads")
+
+
+def causal_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float | None = None,
+    window: int | None = None, force_flash: bool | None = None, interpret: bool = False,
+) -> jax.Array:
+    """Causal attention, q/k [B, N|M, H|H_kv, Dq] and v [B, M, H_kv, Dv]:
+    query i sees keys up to i + M - N, under a `window` the last `window`
+    of them. One computation on two routes, chosen from the operands and
+    the backend alone (`causal_route`): `flash_attention` under its causal
+    mask, or `causal_attention_blocked`, the form every other backend
+    keeps and the kernel's tests compare with. `force_flash` and
+    `interpret` are the tests', as in `dot_product_attention`. A call
+    that takes the kernel logs `flash-causal NxMxDq/Dv [pad<N'>x<M'>]
+    [w<window>] g<query heads a key head> bq<block_q> bk<block_k> <dtype>
+    [inplace] blocks<computed>/<square>`: the last is how much of the
+    square of blocks the grid computes."""
+    _check_causal(q, k, v)
+    if force_flash is None:
+        force_flash = causal_route(q, k, v, window) == "flash"
+    if not force_flash:
+        return causal_attention_blocked(q, k, v, scale=scale, window=window)
+    log = _ROUTE_LOG.get()
+    if log is not None:
+        n, m, d, dv = q.shape[1], k.shape[1], q.shape[3], v.shape[3]
+        pad, pad_v = -d % ROUTE_MULTIPLE, -dv % ROUTE_MULTIPLE
+        n_pad, m_pad, block_q, block_k = flash_plan(
+            n, m, max(d + pad, dv + pad_v), q.dtype.itemsize, causal=True, window=window)
+        _, computed = causal_blocks(n_pad, block_q, block_k, n, m, window)
+        entry = f"flash-causal {n}x{m}x{d}/{dv}"
+        if (n_pad, m_pad) != (n, m):
+            entry += f" pad{n_pad}x{m_pad}"
+        if window is not None:
+            entry += f" w{window}"
+        entry += f" g{q.shape[2] // k.shape[2]} bq{block_q} bk{block_k}"
+        entry += f" {_DTYPE_NAMES.get(q.dtype.name, q.dtype.name)}"
+        entry += "" if pad and pad_v else " inplace"
+        log.append(f"{entry} blocks{computed}/{n_pad // block_q * (m_pad // block_k)}")
+    return flash_attention(
+        q, k, v, scale=scale, interpret=interpret, causal=True, window=window)
+
+
 # Query rows a block of `causal_attention_blocked` takes: its float32
 # scores are heads x this x M at most (128 heads over 2,048 keys: 268 MB).
 CAUSAL_BLOCK_Q = 256
@@ -152,12 +228,9 @@ def causal_attention_blocked(
     fewer heads than q, a divisor of its count: key head j then serves
     the query heads j x group .. (j + 1) x group - 1, and is read where
     it lies, not repeated."""
+    _check_causal(q, k, v)
     n, m, d = q.shape[1], k.shape[1], q.shape[3]
-    if n > m:
-        raise ValueError(f"causal attention of {n} queries over {m} keys")
     heads, kv_heads = q.shape[2], k.shape[2]
-    if heads % kv_heads or v.shape[2] != kv_heads:
-        raise ValueError(f"{heads} query heads over {kv_heads} / {v.shape[2]} key / value heads")
     if kv_heads == heads:
         to_scores, to_out = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
     else:  # a group axis g beside the key head h
@@ -195,11 +268,36 @@ def attention_route(q: jax.Array, k: jax.Array) -> str:
     """The implementation `dot_product_attention` gives these operands:
     "flash" (the Pallas kernel: a TPU backend and a shape `kernel_wins`
     names) or "xla"."""
-    if os.environ.get("CDT_FLASH") == "0":  # kill switch
-        return "xla"
-    if jax.default_backend() != "tpu":
+    if not _kernel_allowed():
         return "xla"
     return "flash" if kernel_wins(q.shape[1], k.shape[1]) else "xla"
+
+
+def _kernel_allowed() -> bool:
+    if os.environ.get("CDT_FLASH") == "0":  # kill switch
+        return False
+    return jax.default_backend() == "tpu"
+
+
+def causal_route(q: jax.Array, k: jax.Array, v: jax.Array, window: int | None = None) -> str:
+    """The implementation `causal_attention` gives these operands: "flash"
+    (a TPU backend and a shape `causal_kernel_wins` names) or "xla"."""
+    if not _kernel_allowed():
+        return "xla"
+    return "flash" if causal_kernel_wins(k.shape[1], v.shape[3], window) else "xla"
+
+
+def causal_kernel_wins(m: int, v_width: int, window: int | None = None) -> bool:
+    """Whether a causal call over m keys with values `v_width` wide goes
+    to the kernel on a TPU: a value width on the lane tile (the output is
+    written where the caller reads it; q and k of another width are padded
+    where they lie), `MIN_RAGGED_KEYS` keys or more, where the XLA
+    form's float32 scores cost more than the kernel's steps, and no
+    window shorter than `MIN_BAND_WINDOW`. By the chip's times at the
+    four served shapes (PERF.md §6, PR 43)."""
+    if window is not None and window < MIN_BAND_WINDOW:
+        return False
+    return v_width % ROUTE_MULTIPLE == 0 and m >= MIN_RAGGED_KEYS
 
 
 def kernel_wins(n: int, m: int) -> bool:
@@ -245,19 +343,25 @@ def _tile(length: int, cap: int, step: int) -> tuple[int, int]:
     return blocks * block, block
 
 
-def flash_plan(n: int, m: int, d: int, itemsize: int) -> tuple[int, int, int, int]:
+def flash_plan(
+    n: int, m: int, d: int, itemsize: int, causal: bool = False, window: int | None = None,
+) -> tuple[int, int, int, int]:
     """(padded n, padded m, block_q, block_k) for q of n rows, k/v of m
     rows, heads d wide (as padded) and operands of `itemsize` bytes:
     each axis tiled (`_tile`) under the caps the sweep found, the caps
     shrunk (k first: it is only streamed) until a step fits
     `VMEM_BUDGET`. 1,296 x 1,296 goes as 1,296 x 1,408 (3 x 432 by 1 x
     1,408), the VAE's 5,184 x 5,184 at d 512 as 5,280 x 5,376 (11 x 480
-    by 6 x 896). Depends on nothing else, so VMEM never grows with m."""
+    by 6 x 896). Depends on nothing else, so VMEM never grows with m.
+    A causal call's caps are its own (`CAUSAL_CAPS`, under a window
+    `BAND_CAPS`): its grid covers a triangle or a band, not a square."""
     if n <= 0 or m <= 0:
         # fail loudly: a zero-length inner grid would silently return
         # an UNWRITTEN output buffer (the finalize step never fires)
         raise ValueError(f"flash_attention needs queries and keys, got N={n}, M={m}")
     cap_q, cap_k = MAX_BLOCK_Q, MAX_BLOCK_K
+    if causal:
+        cap_q, cap_k = CAUSAL_CAPS if window is None else BAND_CAPS
     while True:
         n_pad, block_q = _tile(n, cap_q, ROW_MULTIPLE)
         m_pad, block_k = _tile(m, cap_k, ROUTE_MULTIPLE)
@@ -274,10 +378,47 @@ def flash_plan(n: int, m: int, d: int, itemsize: int) -> tuple[int, int, int, in
             )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+# What a causal call's kernel writes over a score no row of the block may
+# see. Finite, so a row whose first blocks lie wholly before its band
+# (a window shorter than the q block) carries a finite running max until
+# its own keys come: `exp(MASKED - real)` is an exact zero, which wipes
+# what the masked blocks summed, and no `-inf - -inf` is ever formed.
+MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def key_block_range(xp, qi, block_q: int, block_k: int, rows: int, keys: int,
+                    window: int | None):
+    """(first, last) k block that q block `qi` of a causal call sees:
+    query i of `rows` sees keys max(0, i + keys - rows - window + 1) ..
+    i + keys - rows, so the block's first row gives the first key and
+    its last row (no later than the last true key) the last. `xp` is
+    numpy for the plan's counts and jax.numpy inside the kernel and its
+    index maps: the same arithmetic on whole numbers or traced ones."""
+    offset = keys - rows
+    last = xp.minimum(qi * block_q + block_q - 1 + offset, keys - 1) // block_k
+    if window is None:
+        return 0 * last, last
+    return xp.maximum(qi * block_q + offset - window + 1, 0) // block_k, last
+
+
+def causal_blocks(n: int, block_q: int, block_k: int, rows: int, keys: int,
+                  window: int | None) -> tuple[int, int]:
+    """(k blocks the widest q block sees: the inner grid's extent; blocks
+    computed over all q blocks) of a causal call padded to n rows."""
+    import numpy as np
+
+    first, last = key_block_range(
+        np, np.arange(n // block_q), block_q, block_k, rows, keys, window)
+    seen = last - first + 1
+    return int(seen.max()), int(seen.sum())
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "scale", "causal", "window"))
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     scale: float | None = None, interpret: bool = False,
+    causal: bool = False, window: int | None = None,
 ) -> jax.Array:
     """Tiled online-softmax attention (Pallas).
 
@@ -305,7 +446,7 @@ def flash_attention(
     reads whole rows. In place such a head costs three passes an operand
     instead of one (SD1.5's 4,096 x 40: 1.36 ms a call in place, 1.15
     folded; its closed2 cell 3.5 % slower), so `fold` below follows
-    from the width and nothing else.
+    from the widths and nothing else.
 
     Both dots take their operands in the dtype they arrive in (bfloat16
     on every served path, whose products are exact in float32) and
@@ -320,30 +461,58 @@ def flash_attention(
     before the running max, so a padded key weighs an exact zero (the
     select costs nothing measurable: 1.727 ms with it, 1.739 without,
     PR 33). Mask, pad and slice are emitted for such a call only.
+
+    `causal`: query i of N sees keys up to i + M - N, under a `window`
+    only the last `window` of them (its own among them). The inner grid
+    axis then counts the k blocks a q block sees, from the first one
+    (`key_block_range`): a step past the q block's last one does no
+    arithmetic and fetches nothing, since the k/v index maps hold the
+    last block's index there and a block index that repeats is not
+    copied again. Of the blocks computed, only those the diagonal or the
+    band's lower edge crosses build the comparison (`MASKED` says why
+    its value is finite); a block every row sees whole runs the code a
+    call without a mask runs. k and v may have fewer heads than q, a
+    divisor of its count (query head h reads key head h // group through
+    the index maps: nothing is repeated in HBM), and v a width of its
+    own, which is the output's: q and k of a width off the lane tile are
+    padded where they lie when v's is on it (DeepSeek-V2's 192 beside
+    128). Clamp, comparison, head map and the second width are emitted
+    for a call that has them only: every other call traces to the
+    program it traced to before they existed (tests/test_causal_attention.py).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, rows, h, width = q.shape
-    keys = k.shape[1]
+    keys, kv_heads, v_width = k.shape[1], k.shape[2], v.shape[3]
+    group = h // kv_heads
     d = width + -width % ROUTE_MULTIPLE
-    n, m, block_q, block_k = flash_plan(rows, keys, d, q.dtype.itemsize)
+    dv = v_width + -v_width % ROUTE_MULTIPLE
+    n, m, block_q, block_k = flash_plan(
+        rows, keys, max(d, dv), q.dtype.itemsize, causal=causal, window=window)
     if scale is None:
         scale = 1.0 / math.sqrt(width)
     if (n, d) != (rows, width):
         q = jnp.pad(q, ((0, 0), (0, n - rows), (0, 0), (0, d - width)))
     if (m, d) != (keys, width):
-        widths = ((0, 0), (0, m - keys), (0, 0), (0, d - width))
-        k, v = jnp.pad(k, widths), jnp.pad(v, widths)
+        k = jnp.pad(k, ((0, 0), (0, m - keys), (0, 0), (0, d - width)))
+    if (m, dv) != (keys, v_width):
+        v = jnp.pad(v, ((0, 0), (0, m - keys), (0, 0), (0, dv - v_width)))
 
-    fold = d > width  # a padded head goes in front of the tokens: the docstring says why
+    # padded heads go in front of the tokens: the docstring says why
+    fold = d > width and dv > v_width
     if fold:
-        q, k, v = (x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d) for x in (q, k, v))
+        q, k, v = (
+            x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], x.shape[1], x.shape[3])
+            for x in (q, k, v))
     else:
-        q, k, v = (x.reshape(b, x.shape[1], h * d) for x in (q, k, v))
+        q, k, v = (x.reshape(b, x.shape[1], x.shape[2] * x.shape[3]) for x in (q, k, v))
     lanes = 1 if fold else h  # heads side by side in a row of the kernel's operands
 
     num_k_blocks = m // block_k
+    if causal:
+        span = (block_q, block_k, rows, keys, window)
+        num_k_blocks, _ = causal_blocks(n, *span)
     contract_last = (((1,), (1,)), ((), ()))  # q @ k.T without the transpose
 
     def kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, max_ref, sum_ref):
@@ -355,42 +524,88 @@ def flash_attention(
             max_ref[...] = jnp.full_like(max_ref, -jnp.inf)
             sum_ref[...] = jnp.zeros_like(sum_ref)
 
-        vb = v_ref[0]                                # [block_k, D]
-        scores = scale * jax.lax.dot_general(        # [block_q, block_k]
-            q_ref[0], k_ref[0], contract_last,
-            preferred_element_type=jnp.float32,
-        )
-        if m > keys:
+        def update(mask):
+            """One k block into the running max, sum and accumulator;
+            `mask(scores)` where some score of the block is not seen."""
+            vb = v_ref[0]                                # [block_k, Dv]
+            scores = scale * jax.lax.dot_general(        # [block_q, block_k]
+                q_ref[0], k_ref[0], contract_last,
+                preferred_element_type=jnp.float32,
+            )
+            if mask is not None:
+                scores = mask(scores)
+            row_max = max_ref[...]
+            new_max = jnp.maximum(row_max, scores.max(axis=-1, keepdims=True))
+            correction = jnp.exp(row_max - new_max)
+            p = jnp.exp(scores - new_max)
+            acc_ref[...] = acc_ref[...] * correction + jnp.dot(
+                p.astype(vb.dtype), vb, preferred_element_type=jnp.float32
+            )
+            sum_ref[...] = sum_ref[...] * correction + p.sum(
+                axis=-1, keepdims=True
+            )
+            max_ref[...] = new_max
+
+        def padded_keys(scores):
             # the last k block's tail is padding; it always holds a key too
             # (`_tile` pads less than a block), so no row is -inf throughout
             cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-            scores = jnp.where(cols < keys, scores, -jnp.inf)
-        row_max = max_ref[...]
-        new_max = jnp.maximum(row_max, scores.max(axis=-1, keepdims=True))
-        correction = jnp.exp(row_max - new_max)
-        p = jnp.exp(scores - new_max)
-        acc_ref[...] = acc_ref[...] * correction + jnp.dot(
-            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32
-        )
-        sum_ref[...] = sum_ref[...] * correction + p.sum(
-            axis=-1, keepdims=True
-        )
-        max_ref[...] = new_max
+            return jnp.where(cols < keys, scores, -jnp.inf)
+
+        if not causal:
+            update(padded_keys if m > keys else None)
+        else:
+            qi = pl.program_id(1)
+            first, last = key_block_range(jnp, qi, *span)
+            kb = first + ki  # the k block in the buffers, where it is one this q block sees
+            seen = kb <= last
+            top = qi * block_q + (keys - rows)  # the last key the block's first row sees
+            crossed = kb * block_k + block_k - 1 > top  # by the diagonal
+            if window is not None:  # or by the band's lower edge, the last row's
+                crossed |= kb * block_k < top + block_q - window
+
+            def band(scores):
+                row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+                if n > rows:  # a padded row sees what the last true row sees
+                    row = jnp.minimum(row, rows - 1)
+                own = row + (keys - rows)  # the row's own key: padded keys lie past it
+                cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+                visible = cols <= own
+                if window is not None:
+                    visible &= cols > own - window
+                return jnp.where(visible, scores, MASKED)
+
+            pl.when(seen & crossed)(lambda: update(band))
+            pl.when(seen & ~crossed)(lambda: update(None))
 
         @pl.when(ki == num_k_blocks - 1)
         def _finalize():
             o_ref[0] = (acc_ref[...] / sum_ref[...]).astype(o_ref.dtype)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh // lanes, qi, bh % lanes))
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh // lanes, ki, bh % lanes))
+    def q_map(bh, qi, ki):
+        return bh // lanes, qi, bh % lanes
+
+    def kv_map(bh, qi, ki):
+        if causal:
+            first, last = key_block_range(jnp, qi, *span)
+            ki = jnp.minimum(first + ki, last)
+        if group == 1:
+            return bh // lanes, ki, bh % lanes
+        # bh counts (batch, query head): folded, key heads lie in that order too
+        return (bh // group, ki, 0) if fold else (bh // h, ki, bh % h // group)
+
     out = pl.pallas_call(
         kernel,
         grid=(b * h, n // block_q, num_k_blocks),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), q_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, dv), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, dv), q_map),
+        out_shape=jax.ShapeDtypeStruct((*q.shape[:2], lanes * dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),  # acc
+            pltpu.VMEM((block_q, dv), jnp.float32),  # acc
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
             pltpu.VMEM((block_q, 1), jnp.float32),  # running sum
         ],
@@ -398,11 +613,12 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_attention",  # the kernel's name in a device trace
+        # the kernel's name in a device trace; a causal call's is its own
+        name="flash_attention_causal" if causal else "flash_attention",
     )(q, k, v)
 
     if fold:
-        out = out.reshape(b, h, n, d).transpose(0, 2, 1, 3)
+        out = out.reshape(b, h, n, dv).transpose(0, 2, 1, 3)
     else:
-        out = out.reshape(b, n, h, d)
-    return out[:, :rows, :, :width] if (n, d) != (rows, width) else out
+        out = out.reshape(b, n, h, dv)
+    return out[:, :rows, :, :v_width] if (n, dv) != (rows, v_width) else out

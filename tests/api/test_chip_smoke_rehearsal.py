@@ -38,6 +38,10 @@ def test_chip_smoke_rehearsal_passes(tmp_path):
     # the attention child ran both routes at every toy shape, a padded one too
     assert any('"route": "flash"' in line and "pad608x640" in line for line in lines)
     assert any('"route": "xla"' in line and '"flash": {"entry"' in line for line in lines)
+    # and the causal calls, the kernel under its mask (interpreted) beside the XLA form
+    assert any(
+        '"shape": "toy causal window"' in line and "flash-causal" in line and "xla-causal" in line
+        and '"ok": true' in line for line in lines)
     # and the single-query kernel (interpreted) against the einsum form
     assert any('"shape": "toy decode slot"' in line and '"ok": true' in line for line in lines)
     # and a decode step's grouped products, the kernel (interpreted) against `ragged_dot`

@@ -150,6 +150,38 @@ def test_the_experts_leg_times_the_shapes_the_three_models_decode_at(smoke):
     assert rows["deepseek-v2 step at 8 rows"][0] == 8
 
 
+@pytest.mark.parametrize("label, registry, window", [
+    ("solar / k-exaone full 8192", "solar-open2-ep8-4l", False),
+    ("solar / k-exaone full 8192", "k-exaone-ep8-5l", False),
+    ("k-exaone window 8192", "k-exaone-ep8-5l", True),
+    ("ouro 2048", "ouro-2.6b", False),
+    ("deepseek-v2 mla 2048", "deepseek-v2-ep4-5l", False),
+])
+def test_the_attention_leg_times_the_causal_calls_the_four_prefills_make(
+        smoke, label, registry, window):
+    """(query heads, q/k width, key heads, v width, window) of a causal
+    row are a registered configuration's own numbers, and its length the
+    benchmark cell's prompt."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config(registry)
+    rows = {row[0]: row[1:] for row in smoke.CAUSAL_SHAPES}
+    (_, tokens, heads, width), kv_heads, v_width, band = rows[label]
+    if registry.startswith("deepseek"):
+        own = (cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+               cfg.num_attention_heads, cfg.v_head_dim)
+    else:
+        own = (cfg.num_attention_heads, cfg.head_dim, cfg.num_key_value_heads, cfg.head_dim)
+    assert (heads, width, kv_heads, v_width) == own
+    assert band == (cfg.sliding_window if window else None)
+    workflow = {"ouro-2.6b": "ouro-2.6b", "deepseek-v2-ep4-5l": "deepseek-v2",
+                "solar-open2-ep8-4l": "solar-open2", "k-exaone-ep8-5l": "k-exaone"}[registry]
+    with open(os.path.join(REPO_ROOT, "workflows", f"rewrite-txt2img-{workflow}.json")) as fh:
+        (text,) = [node["inputs"]["text"] for node in json.load(fh).values()
+                   if node["class_type"] == "TextGenerate"]
+    assert tokens == 1 + len(text.encode("utf-8"))  # the byte tokenizer's, after its BOS
+
+
 def test_a_steps_routing_is_k_distinct_experts_a_token(smoke):
     sizes = smoke.step_sizes(7, steps=200, rows=16, k=8, held=16, experts=128)
     assert sizes.shape == (200, 16) and sizes.max() <= 2  # two tokens: at most two rows an expert

@@ -101,6 +101,62 @@ def test_route_log_entries():
     ]
 
 
+CAUSAL_ENTRIES = [
+    "flash-causal 8192x8192x128/128 g8 bq512 bk1024 bf16 inplace blocks72/128",
+    # the shape rule leaves this one to XLA (a band of 128 is all edge); it compiles all the same
+    "flash-causal 8192x8192x128/128 w128 g8 bq512 bk512 bf16 inplace blocks31/256",
+    "flash-causal 2048x2048x128/128 g1 bq512 bk1024 bf16 inplace blocks6/8",
+    "flash-causal 2048x2048x192/128 g1 bq512 bk1024 bf16 inplace blocks6/8",
+]
+
+
+@pytest.mark.parametrize(
+    "shape,entry", list(zip(chip_smoke.CAUSAL_SHAPES, CAUSAL_ENTRIES)),
+    ids=[s[0] for s in chip_smoke.CAUSAL_SHAPES])
+def test_causal_kernel_compiles_for_v5e_at_the_prefills_shapes(one_chip, shape, entry):
+    """The four language models' causal calls on the kernel under its
+    mask: key heads read where they lie, q and k of DeepSeek-V2's 192
+    padded where they lie beside a v of 128, the band's clamped index
+    maps. The route entry is the one the traced request reports."""
+    _, q_shape, kv_heads, v_width, window = shape
+    b, n, h, d = q_shape
+    place = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+    fn = jax.jit(functools.partial(attn.causal_attention, window=window, force_flash=True))
+    with attn.route_log() as routes:
+        compiled = fn.lower(
+            place(*q_shape), place(b, n, kv_heads, d), place(b, n, kv_heads, v_width)).compile()
+    assert routes == [entry]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%flash_attention_causal" in text
+
+
+def test_ouro_prefill_attends_in_the_causal_kernel(one_chip, monkeypatch):
+    """Ouro-2.6B's whole prefill at the cell's 2,048 tokens, compiled as
+    a TPU routes it: one kernel in the layer body of the two scans, and
+    no temporary the size of the cache. The kernel takes keys and values
+    token-major and the cache is head-major, so left alone the compiler
+    orders the cache's axes by what writes it and copies all 3.3 GB at
+    the program's end (a job then waits for memory: 52 ms of `job_s.p50`
+    on the chip, PERF.md §6, PR 43); `prefill` holds the cache's layout
+    as `layer_cached` does."""
+    from comfyui_distributed_tpu.models import ouro
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ouro.OuroConfig()
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: ouro.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        compiled = ouro.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((2048,), jnp.int32, sharding=one_chip),
+            cache_len=2080,  # a length of this test's own: the route is read while tracing
+        ).compile()
+    assert routes == [CAUSAL_ENTRIES[2]]
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 # (tokens, width of the projection, lane the heads start at): q and k of a
 # FLUX single block's fused linear, k of a double block's image stream, q
 # of its text stream

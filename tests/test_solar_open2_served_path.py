@@ -423,3 +423,47 @@ def test_the_manifest_has_the_cell_with_the_issues_traffic_and_lists():
     assert work["compute_nodes"] == ["TextGenerate", "KSampler"]
     assert work["rate"] == {"metric": "images_per_s", "units_per_job": 1}
     assert work["trace"] == {"start_s": 5, "slice_s": 12}
+
+
+def test_the_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeypatch):
+    """What a TPU does with the prefill's causal call (PR 43), forced here
+    in the Pallas interpreter: the softmax layer's attention in
+    `flash_attention` under its mask, two query heads a key head read where
+    it lies, 16-wide heads folded into the batch; the logits and the KDA
+    states are the XLA route's to what float32 rounding does to a router. The
+    route is the test's to steer: no option of the program chooses it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models import solar_open2
+    from comfyui_distributed_tpu.models.registry import get_config
+    from comfyui_distributed_tpu.ops import attention
+
+    cfg = get_config("tiny-solar-open2")
+    params = solar_open2.init_params(cfg, jax.random.key(3), jnp.float32)
+    ids = jax.random.randint(jax.random.key(4), (640,), 0, cfg.vocab_size)
+    with attention.route_log() as routes:
+        want = solar_open2.prefill(cfg, params, ids, cache_len=672, collect=True)
+    assert routes == ["xla-causal 640x640x16/16 bq256 f32"]
+
+    monkeypatch.setattr(attention, "causal_route", lambda *operands: "flash")
+    kernel = attention.flash_attention
+    monkeypatch.setattr(
+        attention, "flash_attention",
+        lambda *operands, **options: kernel(*operands, **{**options, "interpret": True}))
+    with attention.route_log() as routes:  # another cache length: traced anew
+        got = solar_open2.prefill(cfg, params, ids, cache_len=704, collect=True)
+    assert routes == ["flash-causal 640x640x16/16 g2 bq128 bk640 f32 blocks5/5"]
+    # another order of summation moves a score in its last digit, and a router
+    # over seeded weights then gives a few (token, layer) pairs another expert
+    # (the XLA form in blocks of 128 rows for 256: 5 of 10,240 choices; the
+    # kernel 14, the logits 1.1e-3 and the states 3.0e-3 off by relative L2
+    # norm); a key head read in another's place moves the logits by their own
+    # size
+    assert float(np.mean(np.asarray(got.chosen) != np.asarray(want.chosen))) < 0.005
+    distance = lambda a, b: float(np.linalg.norm(np.asarray(a) - np.asarray(b)) / np.linalg.norm(b))
+    assert distance(got.logits, want.logits) < 1e-2
+    assert distance(got.cache["state"], want.cache["state"]) < 1e-2
+    np.testing.assert_array_equal(  # layer 0 writes them before it attends
+        np.asarray(got.cache["kv"][..., :640, :]), np.asarray(want.cache["kv"][..., :640, :]))
