@@ -1113,11 +1113,14 @@ def decode_slot_row(rehearsal: bool) -> bool:
             return jax.lax.scan(body, None, jnp.arange(slots))[1]
         return jax.jit(loop)
 
+    def xla(q, cache, slot, position):
+        valid = da.position_valid(position[None], positions)
+        return da.decode_attention_xla(q[None], cache, slot, valid)[0]
+
     def in_float32(q, cache, slot, position):
         with jax.default_matmul_precision("highest"):
-            return da.decode_attention_xla(
-                q.astype(jnp.float32), cache[slot].astype(jnp.float32)[None, None], (0, 0),
-                position)
+            return xla(q.astype(jnp.float32), cache[slot].astype(jnp.float32)[None, None],
+                       (0, 0), position)
 
     q = (2.0 * jax.random.normal(jax.random.key(1), (heads, d))).astype(jnp.bfloat16)
     cache = jax.jit(lambda key: jax.random.normal(key, shape, jnp.bfloat16))(jax.random.key(2))
@@ -1132,7 +1135,7 @@ def decode_slot_row(rehearsal: bool) -> bool:
     scale = max(1.0, float(np.abs(ref).max()))
     for name, attend in (
         ("kernel", functools.partial(da.decode_attention, interpret=rehearsal)),
-        ("xla", da.decode_attention_xla),
+        ("xla", xla),
     ):
         out, first_s, ms = timed(over_slots(attend), q, cache, position)
         err = float(np.abs(np.asarray(out, np.float32) - ref).max())

@@ -14,8 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import route_log as attention_route_log
-from .nodes_core import resolve_seed
+from .nodes_core import annotate_attention, resolve_seed
 from .registry import register_node
 
 
@@ -88,9 +87,7 @@ class TextGenerate:
         from ..telemetry import get_tracer
         from ..telemetry.instruments import (
             lm_decode_steps_total,
-            lm_draft_tokens_total,
             lm_layer_passes_total,
-            lm_linear_layer_passes_total,
             lm_tokens_total,
         )
 
@@ -103,7 +100,7 @@ class TextGenerate:
         tracer = get_tracer()
         steps, draft_tokens = int(max_new_tokens), int(draft_tokens)
         ids = clip.tokenizer.encode(str(text))
-        with attention_route_log() as routes:
+        with annotate_attention():
             prefill, decode = generate_tokens(
                 clip, ids, resolve_seed(seed).effective_seed(), steps, float(temperature),
                 draft_tokens=draft_tokens,
@@ -115,28 +112,13 @@ class TextGenerate:
             wait.attrs["bytes"] = int(new_ids.nbytes + sum(a.nbytes for a in read))
         with tracer.span("lm.detokenize"):
             out = clip.tokenizer.decode(new_ids)
-        described = lm.describe(len(ids) + steps)
-        attrs = {
-            "prompt_tokens": len(ids), "new_tokens": steps,
-            "draft_tokens": draft_tokens, "decode_steps": steps,  # a step a token, unless the
-            **described,
-            **lm.report(len(ids), steps, *read),                  # model says otherwise
-        }
-        lm_decode_steps_total().inc(attrs["decode_steps"])
-        if attrs.get("mtp_drafted"):
-            lm_draft_tokens_total().inc(attrs["mtp_accepted"], outcome="accepted")
-            lm_draft_tokens_total().inc(
-                attrs["mtp_drafted"] - attrs["mtp_accepted"], outcome="rejected")
+        report = lm.report(len(ids), steps, len(ids) + steps, *read)
+        counted = lm.counted(report, len(ids), steps)
+        tracer.annotate(**{
+            "prompt_tokens": len(ids), "new_tokens": steps, "draft_tokens": draft_tokens,
+            "decode_steps": counted["decode_steps"], **report})
+        lm_decode_steps_total().inc(counted["decode_steps"])
         for phase, tokens in (("prefill", len(ids)), ("decode", steps)):
             lm_tokens_total().inc(tokens, phase=phase)
-            lm_layer_passes_total().inc(
-                attrs.get(f"{phase}_layer_passes", tokens * lm.layer_passes), phase=phase)
-            if described.get("linear_layers"):
-                lm_linear_layer_passes_total().inc(
-                    tokens * described["linear_layers"], phase=phase)
-        if routes:
-            # only the request that traced the programs gets here with
-            # anything: which implementation the prefill's attention took
-            attrs["attention"] = ", ".join(sorted(set(routes)))
-        tracer.annotate(**attrs)
+            lm_layer_passes_total().inc(counted[f"{phase}_layer_passes"], phase=phase)
         return (out, {"ui": {"text": [out]}})

@@ -42,14 +42,15 @@ import numpy as np
 
 from ..ops.attention import dot_product_attention
 from ..parallel.sharding import expert_range
-from .lm_common import (  # noqa: F401  (the names this module has always had)
-    ByteTokenizer,
+from .lm_common import (
     LanguageModel,
     apply_rope,
     count_params,
+    decode_loop,
+    head,
     init_from_shapes,
+    mlp_shapes,
     rms_norm,
-    sample,
     swiglu,
 )
 from .moe import decode_route, expert_layer, report_loads
@@ -153,7 +154,8 @@ def yarn_inv_freq(cfg: DeepSeekV2Config) -> np.ndarray:
 
 def rope_tables(cfg: DeepSeekV2Config, positions: jax.Array):
     """cos and sin, [T, rope/2] float32; YaRN's factor on them is
-    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim). Its own:
+    `lm_common.rope_tables` has neither YaRN's blend nor its factor."""
     angles = positions.astype(jnp.float32)[:, None] * jnp.asarray(yarn_inv_freq(cfg))
     m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / _yarn_mscale(
         cfg.rope_factor, cfg.rope_mscale_all_dim
@@ -169,9 +171,6 @@ def param_shapes(cfg: DeepSeekV2Config) -> dict[str, Any]:
     scale, initialised to one)."""
     h, heads = cfg.hidden_size, cfg.num_attention_heads
     held = len(cfg.held_experts)
-
-    def mlp(width: int) -> dict:
-        return {"w_gate_up": ((h, 2 * width), h), "w_down": ((width, h), width)}
 
     layers = []
     for layer in range(cfg.num_hidden_layers):
@@ -190,7 +189,7 @@ def param_shapes(cfg: DeepSeekV2Config) -> dict[str, Any]:
             "ffn_norm": ((h,), None),
         }
         if cfg.is_dense(layer):
-            block["mlp"] = mlp(cfg.intermediate_size)
+            block["mlp"] = mlp_shapes(h, cfg.intermediate_size)
         else:
             width = cfg.moe_intermediate_size
             block["moe"] = {
@@ -199,7 +198,7 @@ def param_shapes(cfg: DeepSeekV2Config) -> dict[str, Any]:
                     "w_gate_up": ((held, h, 2 * width), h),
                     "w_down": ((held, width, h), width),
                 },
-                "shared": mlp(width * cfg.n_shared_experts),
+                "shared": mlp_shapes(h, width * cfg.n_shared_experts),
             }
         layers.append(block)
     return {
@@ -326,16 +325,6 @@ def block_expanded(cfg, layer: int, block: dict, h, rope):
         return h + out, latents, ids, sizes
 
 
-def _head(cfg, params, h):
-    with jax.named_scope("head"):
-        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
-
-
-def _moe_layers(cfg) -> int:
-    return sum(not cfg.is_dense(layer) for layer in range(cfg.num_hidden_layers))
-
-
 # --- the two programs -----------------------------------------------------
 
 
@@ -373,7 +362,7 @@ def prefill(cfg: DeepSeekV2Config, params, ids, *, cache_len: int, collect: bool
             chosen.append(ids_l)
             loads.append(sizes)
     return Prefill(
-        _head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
+        head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
         jnp.stack(chosen) if collect else None,
     )
 
@@ -400,7 +389,7 @@ def decode_step(cfg, params, cache, token, position):
         if ids_l is not None:
             chosen.append(ids_l[0])
             loads.append(sizes)
-    return _head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
+    return head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps", "collect"))
@@ -413,26 +402,13 @@ def decode(cfg: DeepSeekV2Config, params, cache, logits, start, key, temperature
     summed over the steps [moe layers, held] and, under `collect`, every
     step's logits [steps, vocab_held] (the logits after id i) and the
     experts chosen [steps, moe layers, k]."""
-    moe_layers, k = _moe_layers(cfg), cfg.num_experts_per_tok
 
-    def body(i, carry):
-        cache, logits, ids, loads, kept = carry
-        token = sample(logits, jax.random.fold_in(key, i), temperature)
-        logits, cache, chosen_i, loads_i = decode_step(cfg, params, cache, token, start + i)
-        if collect:
-            kept = (kept[0].at[i].set(logits), kept[1].at[i].set(chosen_i))
-        return cache, logits, ids.at[i].set(token), loads + loads_i, kept
+    def step(cache, token, position):
+        logits, cache, chosen, loads = decode_step(cfg, params, cache, token, position)
+        return logits, cache, loads, (logits, chosen) if collect else None
 
-    kept = (
-        jnp.zeros((steps, cfg.vocab_held), jnp.float32),
-        jnp.zeros((steps, moe_layers, k), jnp.int32),
-    ) if collect else (None, None)
-    carry = (
-        cache, logits, jnp.zeros((steps,), jnp.int32),
-        jnp.zeros((moe_layers, len(cfg.held_experts)), jnp.int32), kept,
-    )
-    _, _, ids, loads, kept = jax.lax.fori_loop(0, steps, body, carry)
-    return Decode(ids, loads, *kept)
+    _, ids, loads, kept = decode_loop(step, cache, logits, start, key, temperature, steps)
+    return Decode(ids, loads, *(kept or (None, None)))
 
 
 class DeepSeekV2(LanguageModel):
@@ -462,12 +438,18 @@ class DeepSeekV2(LanguageModel):
             "state_bytes": 0,
         }
 
-    def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
-        """Per phase: the token-expert pairs the router made, those that
-        fell on held experts, and the fullest held expert's."""
+    def report(self, prompt_tokens: int, new_tokens: int, cache_len: int,
+               prefill_loads, decode_loads) -> dict:
+        """`describe` and, per phase, the token-expert pairs the router
+        made, those that fell on held experts, and the fullest held
+        expert's."""
         cfg = self.cfg
-        return report_loads(
-            cfg.num_experts_per_tok, cfg.n_routed_experts,
-            prompt_tokens, new_tokens, prefill_loads, decode_loads,
-            decode_route(
-                cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype))
+        return {
+            **self.describe(cache_len),
+            **report_loads(
+                cfg.num_experts_per_tok, cfg.n_routed_experts,
+                prompt_tokens, new_tokens, prefill_loads, decode_loads,
+                decode_route(
+                    cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size,
+                    self.dtype)),
+        }

@@ -50,7 +50,6 @@ of `vocab_shards` slices of the vocabulary, as `SolarOpen2Config` has it.
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, NamedTuple
 
@@ -59,16 +58,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import causal_attention
-from ..ops.decode_attention import decode_attention_xla, ring_valid
+from ..ops.decode_attention import attend_xla, position_valid, ring_valid
 from ..parallel.sharding import expert_range
 from .lm_common import (
     LanguageModel,
     apply_rope,
     count_params,
+    decode_loop,
+    head,
     init_from_shapes,
+    mlp_shapes,
+    nbytes,
     rms_norm,
+    rope_tables,
     sample,
     swiglu,
+    zeros,
 )
 from .moe import decode_route, expert_layer, report_loads, sigmoid_route
 
@@ -161,19 +166,16 @@ def param_shapes(cfg: KExaoneConfig) -> dict[str, Any]:
     heads, kv = cfg.num_attention_heads * d, cfg.num_key_value_heads * d
     held = len(cfg.held_experts)
 
-    def mlp(width: int) -> dict:
-        return {"w_gate_up": ((h, 2 * width), h), "w_down": ((width, h), width)}
-
     def layer(dense: bool) -> dict:
         width = cfg.moe_intermediate_size
-        ffn = {"mlp": mlp(cfg.intermediate_size)} if dense else {"moe": {
+        ffn = {"mlp": mlp_shapes(h, cfg.intermediate_size)} if dense else {"moe": {
             "w_g": ((h, cfg.num_experts), h),
             "bias": ((cfg.num_experts,), None),
             "experts": {
                 "w_gate_up": ((held, h, 2 * width), h),
                 "w_down": ((held, width, h), width),
             },
-            "shared": mlp(width * cfg.num_shared_experts),
+            "shared": mlp_shapes(h, width * cfg.num_shared_experts),
         }}
         return {
             "mixer_norm": ((h,), None),
@@ -227,24 +229,12 @@ def state_shapes(cfg: KExaoneConfig, cache_len: int, dtype) -> dict[str, jax.Sha
     }
 
 
-def _nbytes(shape: jax.ShapeDtypeStruct) -> int:
-    return math.prod(shape.shape) * jnp.dtype(shape.dtype).itemsize
-
-
 def _slot(cfg, layer: int) -> int:
     """A layer's place among the layers of its kind."""
     return sum(cfg.is_window(i) == cfg.is_window(layer) for i in range(layer))
 
 
 # --- the mixer ------------------------------------------------------------
-
-
-def rope_tables(cfg: KExaoneConfig, positions: jax.Array):
-    """cos and sin [T, d / 2] float32 of the positions' angles."""
-    d = cfg.head_dim
-    inverse = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions.astype(jnp.float32)[:, None] * inverse[None, :]
-    return jnp.cos(angles), jnp.sin(angles)
 
 
 def _projections(cfg, p, x, rope):
@@ -268,7 +258,7 @@ def _entries(k, v):
 def mixer_whole(cfg, p, x, window: bool):
     """Over a whole sequence x [T, hidden] (the prefill's form). Returns
     (output [T, hidden], keys and values [2, key heads, T, d])."""
-    rope = rope_tables(cfg, jnp.arange(x.shape[0])) if window else None
+    rope = rope_tables(cfg.rope_theta, cfg.head_dim, jnp.arange(x.shape[0])) if window else None
     q, k, v = _projections(cfg, p, x, rope)
     out = causal_attention(
         q[None], k[None], v[None], window=cfg.sliding_window if window else None)[0]
@@ -282,7 +272,8 @@ def mixer_cached(cfg, p, x, cache, name: str, index: int, positions):
     cache that grows, then each query over what it may see of the slot.
     Returns (output [W, hidden], the array written)."""
     window = name == "ring"
-    q, k, v = _projections(cfg, p, x, rope_tables(cfg, positions) if window else None)
+    rope = rope_tables(cfg.rope_theta, cfg.head_dim, positions) if window else None
+    q, k, v = _projections(cfg, p, x, rope)
     new, held = _entries(k, v)[None], cache[name]
     size = held.shape[3]
     if window:
@@ -292,8 +283,8 @@ def mixer_cached(cfg, p, x, cache, name: str, index: int, positions):
         valid = ring_valid(positions, size, cfg.sliding_window)
     else:
         held = jax.lax.dynamic_update_slice(held, new, (index, 0, 0, positions[0], 0))
-        valid = jnp.arange(size)[None, :] <= positions[:, None]
-    out = decode_attention_xla(q, held, (index,), valid=valid)
+        valid = position_valid(positions, size)
+    out = attend_xla(q, held, (index,), valid)
     return out.reshape(x.shape[0], -1) @ p["w_o"], held
 
 
@@ -333,13 +324,6 @@ def _layer(cfg, block, h, window: bool, mixer):
     out, ids, sizes = _feed_forward(
         cfg, block, rms_norm(h, block["ffn_norm"], cfg.rms_norm_eps))
     return h + out, kept, ids, sizes
-
-
-def _head(cfg, params, h, norm):
-    with jax.named_scope("head"):
-        return jnp.dot(
-            rms_norm(h, norm, cfg.rms_norm_eps), params["head"],
-            preferred_element_type=jnp.float32)
 
 
 def mtp_input(cfg, params, h, tokens):
@@ -387,10 +371,7 @@ def prefill(cfg: KExaoneConfig, params, ids, *, cache_len: int, collect: bool = 
     writes it again before anything reads it."""
     tokens = ids.shape[0]
     h = params["embed"][ids]
-    cache = {
-        name: jnp.zeros(s.shape, s.dtype)
-        for name, s in state_shapes(cfg, cache_len, h.dtype).items()
-    }
+    cache = zeros(state_shapes(cfg, cache_len, h.dtype))
 
     def write(kv, index, entries):
         return jax.lax.dynamic_update_slice(kv, entries[None], (index, 0, 0, 0, 0))
@@ -416,7 +397,7 @@ def prefill(cfg: KExaoneConfig, params, ids, *, cache_len: int, collect: bool = 
         cache["kv"] = write(cache["kv"], cfg.full_layers, _entries(k, v))
     cache["h"] = h[-1]
     return Prefill(
-        _head(cfg, params, h[-1:], params["final_norm"])[0], cache, jnp.stack(loads),
+        head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
         jnp.stack(chosen) if collect else None,
     )
 
@@ -438,7 +419,7 @@ def main_step(cfg, params, cache, tokens, position):
         if ids_l is not None:
             chosen.append(ids_l)
             loads.append(sizes)
-    logits = _head(cfg, params, h, params["final_norm"])
+    logits = head(cfg, params, h)
     return logits, h, cache, jnp.stack(chosen), jnp.stack(loads)
 
 
@@ -452,7 +433,7 @@ def mtp_step(cfg, params, cache, h, tokens, position):
     out, cache["kv"], ids, sizes = _layer(
         cfg, block, mtp_input(cfg, params, h, tokens), False,
         lambda p, x: mixer_cached(cfg, p, x, cache, "kv", cfg.full_layers, positions))
-    return _head(cfg, params, out, params["mtp"]["norm"]), cache, ids, sizes
+    return head(cfg, params, out, params["mtp"]["norm"]), cache, ids, sizes
 
 
 def accept_probability(p, q, draft):
@@ -492,29 +473,15 @@ def verify(logits, draft_logits, draft, key, temperature):
 
 def _decode_plain(cfg, params, cache, logits, start, key, temperature, steps, collect):
     """`steps` one-token steps, as the other models' decodes."""
-    layers, k, held = cfg.sparse_layers, cfg.num_experts_per_tok, len(cfg.held_experts)
 
-    def body(i, carry):
-        cache, logits, ids, loads, read, kept = carry
-        token = sample(logits, jax.random.fold_in(key, i), temperature)
-        rows, _, cache, chosen_i, loads_i = main_step(cfg, params, cache, token[None], start + i)
-        if collect:
-            kept = {
-                "logits": kept["logits"].at[i].set(rows[0]),
-                "chosen": kept["chosen"].at[i].set(chosen_i[:, 0]),
-            }
-        read = read + jnp.count_nonzero(loads_i)
-        return cache, rows[0], ids.at[i].set(token), loads.at[:layers].add(loads_i), read, kept
+    def step(cache, token, position):
+        rows, _, cache, chosen, loads = main_step(cfg, params, cache, token[None], position)
+        kept = {"logits": rows[0], "chosen": chosen[:, 0]} if collect else None
+        return rows[0], cache, (loads, jnp.count_nonzero(loads)), kept
 
-    kept = {
-        "logits": jnp.zeros((steps, cfg.vocab_held), jnp.float32),
-        "chosen": jnp.zeros((steps, layers, k), jnp.int32),
-    } if collect else None
-    carry = (
-        cache, logits, jnp.zeros((steps,), jnp.int32),
-        jnp.zeros((layers + 1, held), jnp.int32), jnp.int32(0), kept,
-    )
-    cache, _, ids, loads, read, kept = jax.lax.fori_loop(0, steps, body, carry)
+    cache, ids, (loads, read), kept = decode_loop(
+        step, cache, logits, start, key, temperature, steps)
+    loads = jnp.concatenate([loads, jnp.zeros_like(loads[:1])])  # the MTP module's row
     counts = jnp.stack([jnp.int32(steps), jnp.int32(0), jnp.int32(0), read.astype(jnp.int32)])
     return Decode(ids, loads, counts, cache, kept)
 
@@ -633,24 +600,25 @@ class KExaone(LanguageModel):
             "ring_positions": cfg.ring_positions,
             "experts_held": len(cfg.held_experts),
             "experts_total": cfg.num_experts,
-            "cache_bytes": _nbytes(shapes["kv"]),
-            "state_bytes": _nbytes(shapes["ring"]),
+            "cache_bytes": nbytes(shapes["kv"]),
+            "state_bytes": nbytes(shapes["ring"]),
         }
 
-    def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads,
-               counts) -> dict:
-        """What the decode's steps came to, the layer bodies either
-        program ran (the decode's over every position a step ran, a
+    def report(self, prompt_tokens: int, new_tokens: int, cache_len: int,
+               prefill_loads, decode_loads, counts) -> dict:
+        """`describe`, what the decode's steps came to, the layer bodies
+        either program ran (the decode's over every position a step ran, a
         rejected draft's and the MTP module's among them; of the MTP
         module the prefill runs only the keys and values, no body) and,
-        per phase, the routing as `moe.report_loads` has it, the
-        decode's pairs counted over the positions its steps ran."""
+        per phase, the routing as `moe.report_loads` has it, the decode's
+        pairs counted over the positions its steps ran."""
         cfg = self.cfg
         steps, drafted, accepted, read = (int(n) for n in counts)
         width = 2 if drafted else 1  # positions a step runs
         mtp = 1 if drafted else 0    # and whether the module's layer is among its bodies
         pairs = steps * width * cfg.num_experts_per_tok * (cfg.sparse_layers + mtp)
         return {
+            **self.describe(cache_len),
             **report_loads(
                 cfg.num_experts_per_tok, cfg.num_experts, prompt_tokens, new_tokens,
                 prefill_loads, decode_loads,

@@ -1,10 +1,11 @@
 """What the language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`, `k_exaone.py`): the blocks they are written from, the one
-initialisation rule, sampling on the device, and the stand-in tokenizer.
+`solar_open2.py`, `k_exaone.py`): the blocks and helpers they are written
+from, the one initialisation rule, sampling on the device, the decode
+loop, and the stand-in tokenizer.
 
 A bundle's `lm` part is an object with this contract (`LanguageModel`
-below holds what every model's class has alike), which
-`graph/nodes_text.TextGenerate` holds every model to:
+below holds what every model's class has alike), which is all that
+`graph/nodes_text.TextGenerate` knows of a model:
 
 - `cfg`, `tokenizer`, `init(key, dtype)` (which also records `dtype`,
   the one the weights and the cache are stored in);
@@ -12,27 +13,23 @@ below holds what every model's class has alike), which
   logits, start, key, steps, temperature, collect, draft_tokens)`: the
   two programs; what they return has `.cache` and `.logits` (the
   prefill's) and `.ids` (the decode's: `[steps]`, always). `.cache` is a
-  request's whole state, handed from one program to the other as it is:
-  one array, or a tree of arrays of several kinds and dtypes (keys and
-  values that grow with the position, a window layer's ring or a
-  recurrent layer's state of fixed size); the node never looks inside;
+  request's whole state, one array or a tree of them, handed from one
+  program to the other as it is; the node never looks inside;
 - `draft_tokens_max`: tokens a decode step may draft and verify beside
-  the one it emits anyway (0: the model has no draft module, and
-  `decode` refuses any other `draft_tokens`). With drafting a step
-  yields one token or more, so `steps` ids take fewer steps of the
-  loop, which stays one program; the model's `read_back` then carries
-  how many it took and how many drafts it made and kept;
-- `layer_passes`: layer bodies one token walks through (where a step
-  may run more than one position, `report` gives the counts run as
-  `prefill_layer_passes` and `decode_layer_passes`);
+  the one it emits anyway (0: no draft module, and `decode` refuses any
+  other `draft_tokens`);
 - `read_back(prefill, decode)`: the device arrays a request reads back
   beside the ids, in the one `device.wait`;
-- `describe(cache_len)` and `report(prompt_tokens, new_tokens, *read)`:
-  the attributes `node.TextGenerate` carries. The first says from the
-  model's own shapes and dtypes what a request's state takes:
-  `cache_bytes`, what grows with `cache_len`, and `state_bytes`, what
-  does not (0 for a model whose state is keys and values only); the
-  second is made from `read_back`'s arrays as the host got them.
+- `report(prompt_tokens, new_tokens, cache_len, *read)`: everything the
+  model says on `node.TextGenerate`: what its own shapes and dtypes give
+  (`layers`; `cache_bytes`, what grows with `cache_len`; `state_bytes`,
+  what does not), and what `read_back`'s arrays do as the host got them.
+  The first part is also callable alone, `describe(cache_len)`: the
+  benchmark's reader tests hold their counts by hand against it;
+- `layer_passes`, the layer bodies one token walks through, from which
+  `counted` answers what the node's counters take where `report` does
+  not say otherwise (`decode_steps`, `prefill_layer_passes`,
+  `decode_layer_passes`: a model whose step runs more than one position).
 """
 
 from __future__ import annotations
@@ -43,6 +40,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ..ops.attention import route_log
 
 
 # --- blocks ---------------------------------------------------------------
@@ -71,6 +71,22 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     ).astype(x.dtype)
 
 
+def rope_tables(theta: float, head_dim: int, positions: jax.Array):
+    """cos and sin, [T, head_dim / 2] float32, of the positions' angles at
+    the frequencies theta^(-2i / head_dim); no scaling."""
+    inv_freq = 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    angles = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def head(cfg, params, h, norm=None):
+    """Float32 logits of h [T, hidden]: a norm (the final one unless
+    another scale is given), then the output embedding."""
+    with jax.named_scope("head"):
+        h = rms_norm(h, params["final_norm"] if norm is None else norm, cfg.rms_norm_eps)
+        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
+
+
 def sample(logits, key, temperature):
     """The next id from float32 logits: the largest at temperature 0,
     else a draw from softmax(logits / temperature). `temperature` is a
@@ -79,9 +95,62 @@ def sample(logits, key, temperature):
     return jnp.where(temperature > 0, drawn, jnp.argmax(logits)).astype(jnp.int32)
 
 
+# --- a request's state and the decode loop --------------------------------
+
+
+def nbytes(shape: jax.ShapeDtypeStruct) -> int:
+    return math.prod(shape.shape) * jnp.dtype(shape.dtype).itemsize
+
+
+def zeros(shapes):
+    """A `ShapeDtypeStruct`, or a dict or tuple of them, as arrays of
+    zeros, made in the order they are written in."""
+    if isinstance(shapes, jax.ShapeDtypeStruct):
+        return jnp.zeros(shapes.shape, shapes.dtype)
+    if isinstance(shapes, dict):
+        return {name: zeros(s) for name, s in shapes.items()}
+    return tuple(zeros(s) for s in shapes)
+
+
+def decode_loop(step, cache, logits, start, key, temperature, steps: int):
+    """`steps` dependent one-token steps as one `fori_loop`, from the
+    prefill's `logits` at position `start - 1`: draw id i from the logits
+    with the key folded by i, run it through `step(cache, token, start +
+    i)`, which returns (logits, cache, a tree the loop sums over the
+    steps, a tree of which the loop keeps every step's or None). Always
+    `steps` ids, no early stop. Returns (cache, ids [steps], the summed
+    tree, the kept rows stacked or None)."""
+
+    def body(i, carry):
+        cache, logits, ids, tally, kept = carry
+        token = sample(logits, jax.random.fold_in(key, i), temperature)
+        logits, cache, tally_i, kept_i = step(cache, token, start + i)
+        if kept is not None:
+            kept = jax.tree_util.tree_map(lambda rows, row: rows.at[i].set(row), kept, kept_i)
+        return (cache, logits, ids.at[i].set(token),
+                jax.tree_util.tree_map(jnp.add, tally, tally_i), kept)
+
+    # what a step adds and keeps, as shapes: the step's own word. Nothing is run, and
+    # the routes this extra tracing logs are dropped: the loop's body logs the program's
+    with route_log():
+        _, _, tally, kept = jax.eval_shape(
+            step, cache, jax.ShapeDtypeStruct((), jnp.int32), start)
+    if kept is not None:
+        kept = zeros(jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct((steps, *s.shape), s.dtype), kept))
+    carry = (cache, logits, jnp.zeros((steps,), jnp.int32), zeros(tally), kept)
+    cache, _, ids, tally, kept = jax.lax.fori_loop(0, steps, body, carry)
+    return cache, ids, tally, kept
+
+
 # --- parameters -----------------------------------------------------------
 # A tree of specs: each leaf ((shape), fan_in), fan_in None for a norm's
 # scale, initialised to one.
+
+
+def mlp_shapes(hidden: int, width: int) -> dict:
+    """One SwiGLU's specs: gate and up side by side, then down."""
+    return {"w_gate_up": ((hidden, 2 * width), hidden), "w_down": ((width, hidden), width)}
 
 
 def _is_spec(x) -> bool:
@@ -168,10 +237,11 @@ class LanguageModel:
     (`init_params(cfg, key, dtype)`, the jitted `prefill(cfg, params, ids,
     *, cache_len, collect)` and `decode(cfg, params, cache, logits, start,
     key, temperature, *, steps, collect)`, which a subclass names as
-    `_init`, `_prefill`, `_decode`), the tokenizer, and the dtype the
-    weights and the cache are stored in. A subclass adds `layer_passes`,
-    `read_back`, `describe` and `report`, and where it has a draft
-    module `draft_tokens_max` and a `_decode` that takes `draft_tokens`."""
+    `_init`, `_prefill`, `_decode`), the tokenizer, the dtype the weights
+    and the cache are stored in, and `counted`. A subclass adds
+    `layer_passes`, `read_back` and `report` (over its `describe`), and
+    where it has a draft module `draft_tokens_max` and a `_decode` that
+    takes `draft_tokens`."""
 
     _init = _prefill = _decode = None
     draft_tokens_max = 0
@@ -184,6 +254,17 @@ class LanguageModel:
     def init(self, key, dtype=jnp.float32):
         self.dtype = jnp.dtype(dtype)
         return self._init(self.cfg, key, dtype)
+
+    def counted(self, report: dict, prompt_tokens: int, new_tokens: int) -> dict[str, int]:
+        """What the node's counters take of a request: the model's own
+        `report` where it says them, else a step a token and each token
+        through `layer_passes` layer bodies."""
+        defaults = {
+            "decode_steps": new_tokens,
+            "prefill_layer_passes": prompt_tokens * self.layer_passes,
+            "decode_layer_passes": new_tokens * self.layer_passes,
+        }
+        return {key: report.get(key, value) for key, value in defaults.items()}
 
     def prefill(self, params, ids, cache_len: int, collect: bool = False):
         return self._prefill(self.cfg, params, ids, cache_len=cache_len, collect=collect)

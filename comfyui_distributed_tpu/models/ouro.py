@@ -67,14 +67,14 @@ from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops import decode_attention
 from ..ops.attention import dot_product_attention
-from .lm_common import (  # noqa: F401  (`ByteTokenizer`: a name this module has always had)
-    ByteTokenizer,
+from .lm_common import (
     LanguageModel,
     apply_rope,
     count_params,
+    decode_loop,
     init_from_shapes,
     rms_norm,
-    sample,
+    rope_tables,
     swiglu,
 )
 
@@ -118,14 +118,6 @@ class OuroConfig:
     def cache_shape(self, cache_len: int) -> tuple[int, ...]:
         return (self.total_ut_steps, self.num_hidden_layers, 2,
                 self.num_attention_heads, cache_len, self.head_dim)
-
-
-def rope_tables(cfg: OuroConfig, positions: jax.Array):
-    """cos and sin, [T, head_dim / 2] float32; no scaling."""
-    inv_freq = 1.0 / cfg.rope_theta ** (
-        np.arange(0, cfg.head_dim, 2, dtype=np.float64) / cfg.head_dim)
-    angles = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)
-    return jnp.cos(angles), jnp.sin(angles)
 
 
 # --- parameters -----------------------------------------------------------
@@ -281,6 +273,7 @@ def _loop(cfg, params, x, cache, one_layer):
 
 
 def _head(params, h):
+    # its own: `_close_pass` has normed h already, and h is float32 beside stored weights
     with jax.named_scope("head"):
         head = params["head"]
         return jnp.dot(h.astype(head.dtype), head, preferred_element_type=jnp.float32)
@@ -314,7 +307,7 @@ def prefill(cfg: OuroConfig, params, ids, *, cache_len: int, collect: bool = Fal
     the prompt's tokens and, under `collect` (the parity check's), every
     pass's h_t and p(t) at the last position."""
     tokens = ids.shape[0]
-    rope = rope_tables(cfg, jnp.arange(tokens))
+    rope = rope_tables(cfg.rope_theta, cfg.head_dim, jnp.arange(tokens))
     x = params["embed"][ids].astype(jnp.float32)
 
     def one_layer(p, x, cache, slot):
@@ -334,7 +327,7 @@ def prefill(cfg: OuroConfig, params, ids, *, cache_len: int, collect: bool = Fal
 def decode_step(cfg, params, cache, token, position):
     """One token through every pass and layer over the cache. Returns
     (logits [vocab], cache, h_t [T, hidden], p(t) [T])."""
-    rope = rope_tables(cfg, position[None])
+    rope = rope_tables(cfg.rope_theta, cfg.head_dim, position[None])
 
     def one_layer(p, x, cache, slot):
         return layer_cached(cfg, p, x, rope, cache, slot, position)
@@ -354,26 +347,13 @@ def decode(cfg: OuroConfig, params, cache, logits, start, key, temperature, *,
     as `cache` (which is what lets the buffer be reused). Returns the ids,
     the exit distribution summed over the steps and, under `collect`,
     every step's logits (the logits after id i), h_t and p(t)."""
-    passes = cfg.total_ut_steps
 
-    def body(i, carry):
-        cache, logits, ids, exit_sum, kept = carry
-        token = sample(logits, jax.random.fold_in(key, i), temperature)
-        logits, cache, hidden, exits = decode_step(cfg, params, cache, token, start + i)
-        if collect:
-            kept = (kept[0].at[i].set(logits), kept[1].at[i].set(hidden),
-                    kept[2].at[i].set(exits))
-        return cache, logits, ids.at[i].set(token), exit_sum + exits, kept
+    def step(cache, token, position):
+        logits, cache, hidden, exits = decode_step(cfg, params, cache, token, position)
+        return logits, cache, exits, (logits, hidden, exits) if collect else None
 
-    kept = (
-        jnp.zeros((steps, cfg.vocab_size), jnp.float32),
-        jnp.zeros((steps, passes, cfg.hidden_size), jnp.float32),
-        jnp.zeros((steps, passes), jnp.float32),
-    ) if collect else (None, None, None)
-    carry = (cache, logits, jnp.zeros((steps,), jnp.int32),
-             jnp.zeros((passes,), jnp.float32), kept)
-    cache, _, ids, exit_sum, kept = jax.lax.fori_loop(0, steps, body, carry)
-    return Decode(ids, exit_sum, cache, *kept)
+    cache, ids, exit_sum, kept = decode_loop(step, cache, logits, start, key, temperature, steps)
+    return Decode(ids, exit_sum, cache, *(kept or (None, None, None)))
 
 
 class Ouro(LanguageModel):
@@ -401,10 +381,13 @@ class Ouro(LanguageModel):
             "state_bytes": 0,
         }
 
-    def report(self, prompt_tokens: int, new_tokens: int, prefill_exit, decode_exit) -> dict:
-        """Layer bodies walked in either phase, and where the request's
-        tokens would have left the loop had the gate been acted on."""
+    def report(self, prompt_tokens: int, new_tokens: int, cache_len: int,
+               prefill_exit, decode_exit) -> dict:
+        """`describe`, the layer bodies walked in either phase, and where
+        the request's tokens would have left the loop had the gate been
+        acted on."""
         attrs = {
+            **self.describe(cache_len),
             "prefill_layer_passes": prompt_tokens * self.layer_passes,
             "decode_layer_passes": new_tokens * self.layer_passes,
         }

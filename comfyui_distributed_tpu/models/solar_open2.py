@@ -44,7 +44,6 @@ side by side as `w_qkv` and their convolutions' filters as `conv`;
 from __future__ import annotations
 
 import dataclasses
-import math
 from functools import partial
 from typing import Any, NamedTuple
 
@@ -52,9 +51,19 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
-from ..ops.decode_attention import decode_attention_xla
+from ..ops.decode_attention import attend_xla, position_valid
 from ..parallel.sharding import expert_range
-from .lm_common import LanguageModel, count_params, init_from_shapes, rms_norm, sample
+from .lm_common import (
+    LanguageModel,
+    count_params,
+    decode_loop,
+    head,
+    init_from_shapes,
+    mlp_shapes,
+    nbytes,
+    rms_norm,
+    zeros,
+)
 from .moe import decode_route, expert_layer, report_loads, sigmoid_route
 
 # Rows of a chunk whose pairwise decays are formed pair by pair
@@ -143,9 +152,6 @@ def param_shapes(cfg: SolarOpen2Config) -> dict[str, Any]:
     lin, rank = cfg.linear_width, cfg.linear_head_dim
     held = len(cfg.held_experts)
 
-    def mlp(width: int) -> dict:
-        return {"w_gate_up": ((h, 2 * width), h), "w_down": ((width, h), width)}
-
     layers = []
     for layer in range(cfg.num_hidden_layers):
         if cfg.is_full(layer):
@@ -175,7 +181,7 @@ def param_shapes(cfg: SolarOpen2Config) -> dict[str, Any]:
                     "w_gate_up": ((held, h, 2 * width), h),
                     "w_down": ((held, width, h), width),
                 },
-                "shared": mlp(width * cfg.n_shared_experts),
+                "shared": mlp_shapes(h, width * cfg.n_shared_experts),
             },
         })
     return {
@@ -228,10 +234,6 @@ def state_shapes(cfg: SolarOpen2Config, cache_len: int, dtype) -> dict[str, jax.
     }
 
 
-def _nbytes(shape: jax.ShapeDtypeStruct) -> int:
-    return math.prod(shape.shape) * jnp.dtype(shape.dtype).itemsize
-
-
 # --- the routing rule -----------------------------------------------------
 
 
@@ -276,14 +278,15 @@ def gqa_cached(cfg, p, x, kv, index, position):
     """One new token x [1, hidden] at `position`: its key and value
     written into slot `index` of kv [full layers, 2, key heads,
     positions, d], attention over the slot's positions up to it
-    (`decode_attention_xla`, a key head serving its group of queries).
-    Returns (output [1, hidden], kv)."""
+    (`decode_attention.attend_xla`, a key head serving its group of
+    queries). Returns (output [1, hidden], kv)."""
     q, k, v, gate = _gqa_projections(cfg, p, x)
     kv = jax.lax.dynamic_update_slice(
         # one token: [2, 1, heads, d] and [2, heads, 1, d] are the same bytes
         kv, jnp.stack([k, v]).reshape(1, 2, cfg.num_key_value_heads, 1, cfg.head_dim),
         (index, 0, 0, position, 0))
-    out = decode_attention_xla(q[0], kv, (index,), position)
+    valid = position_valid(jnp.asarray(position).reshape(1), kv.shape[3])
+    out = attend_xla(q, kv, (index,), valid)
     return _gated_out(p, out.reshape(1, -1), gate), kv
 
 
@@ -516,12 +519,6 @@ def _layer(cfg, layer: int, block, h, cache, gqa, kda):
         return h + out, cache, ids, sizes
 
 
-def _head(cfg, params, h):
-    with jax.named_scope("head"):
-        h = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        return jnp.dot(h, params["head"], preferred_element_type=jnp.float32)
-
-
 # --- the two programs -----------------------------------------------------
 
 
@@ -549,10 +546,7 @@ def prefill(cfg: SolarOpen2Config, params, ids, *, cache_len: int, collect: bool
     (the parity check's), the experts chosen."""
     tokens = ids.shape[0]
     h = params["embed"][ids]
-    cache = {
-        name: jnp.zeros(s.shape, s.dtype)
-        for name, s in state_shapes(cfg, cache_len, h.dtype).items()
-    }
+    cache = zeros(state_shapes(cfg, cache_len, h.dtype))
 
     def gqa(p, x, kv, index):
         out, slot_kv = gqa_whole(cfg, p, x)
@@ -565,7 +559,7 @@ def prefill(cfg: SolarOpen2Config, params, ids, *, cache_len: int, collect: bool
         chosen.append(ids_l)
         loads.append(sizes)
     return Prefill(
-        _head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
+        head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
         jnp.stack(chosen) if collect else None,
     )
 
@@ -585,7 +579,7 @@ def decode_step(cfg, params, cache, token, position):
             cfg, layer, block, h, cache, gqa, partial(kda_cached, cfg))
         chosen.append(ids_l[0])
         loads.append(sizes)
-    return _head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
+    return head(cfg, params, h)[0], cache, jnp.stack(chosen), jnp.stack(loads)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps", "collect"), donate_argnames=("cache",))
@@ -598,26 +592,13 @@ def decode(cfg: SolarOpen2Config, params, cache, logits, start, key, temperature
     handed back as `cache`. Returns the ids, the pairs on each held
     expert summed over the steps and, under `collect`, every step's
     logits (the logits after id i) and the experts chosen."""
-    layers, k = cfg.num_hidden_layers, cfg.num_experts_per_tok
 
-    def body(i, carry):
-        cache, logits, ids, loads, kept = carry
-        token = sample(logits, jax.random.fold_in(key, i), temperature)
-        logits, cache, chosen_i, loads_i = decode_step(cfg, params, cache, token, start + i)
-        if collect:
-            kept = (kept[0].at[i].set(logits), kept[1].at[i].set(chosen_i))
-        return cache, logits, ids.at[i].set(token), loads + loads_i, kept
+    def step(cache, token, position):
+        logits, cache, chosen, loads = decode_step(cfg, params, cache, token, position)
+        return logits, cache, loads, (logits, chosen) if collect else None
 
-    kept = (
-        jnp.zeros((steps, cfg.vocab_held), jnp.float32),
-        jnp.zeros((steps, layers, k), jnp.int32),
-    ) if collect else (None, None)
-    carry = (
-        cache, logits, jnp.zeros((steps,), jnp.int32),
-        jnp.zeros((layers, len(cfg.held_experts)), jnp.int32), kept,
-    )
-    cache, _, ids, loads, kept = jax.lax.fori_loop(0, steps, body, carry)
-    return Decode(ids, loads, cache, *kept)
+    cache, ids, loads, kept = decode_loop(step, cache, logits, start, key, temperature, steps)
+    return Decode(ids, loads, cache, *(kept or (None, None)))
 
 
 class SolarOpen2(LanguageModel):
@@ -643,15 +624,18 @@ class SolarOpen2(LanguageModel):
             "linear_layers": cfg.linear_layers,
             "experts_held": len(cfg.held_experts),
             "experts_total": cfg.n_routed_experts,
-            "cache_bytes": _nbytes(shapes["kv"]),
-            "state_bytes": _nbytes(shapes["state"]) + _nbytes(shapes["conv"]),
+            "cache_bytes": nbytes(shapes["kv"]),
+            "state_bytes": nbytes(shapes["state"]) + nbytes(shapes["conv"]),
         }
 
-    def report(self, prompt_tokens: int, new_tokens: int, prefill_loads, decode_loads) -> dict:
-        """The chunks a linear layer's prefill scanned, and per phase the
-        token-expert pairs the router made and those on held experts."""
+    def report(self, prompt_tokens: int, new_tokens: int, cache_len: int,
+               prefill_loads, decode_loads) -> dict:
+        """`describe`, the chunks a linear layer's prefill scanned, and
+        per phase the token-expert pairs the router made and those on
+        held experts."""
         cfg = self.cfg
         return {
+            **self.describe(cache_len),
             "prefill_chunks": -(-prompt_tokens // cfg.kda_chunk),
             **report_loads(
                 cfg.num_experts_per_tok, cfg.n_routed_experts,

@@ -69,53 +69,41 @@ def decode_plan(heads: int, positions: int, d: int, itemsize: int) -> int | None
 
 def decode_attention_route(heads: int, positions: int, d: int, dtype) -> str:
     """"decode-kernel" on a TPU for a shape `decode_plan` takes, else
-    "decode-xla" (`decode_attention_xla`)."""
+    "decode-xla" (`attend_xla`)."""
     if jax.default_backend() != "tpu":
         return "decode-xla"
     plan = decode_plan(heads, positions, d, jnp.dtype(dtype).itemsize)
     return "decode-kernel" if plan else "decode-xla"
 
 
-def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, position=None, *,
-                         valid: jax.Array | None = None) -> jax.Array:
-    """The einsum form: q [heads, d] over `cache[slot]`'s positions up
-    to `position`. The slot may hold fewer key and value heads than
-    there are queries, a divisor of their count: key head j serves the
-    query heads j x group .. (j + 1) x group - 1 (the axis g below; one
-    wide where the counts are equal). Scores, softmax and the sum's
-    accumulation float32, the probabilities rounded to the cache's
-    dtype. Returns [heads, d] in the cache's dtype.
-
-    Or q [W, heads, d], the queries of W positions of one step, with
-    `valid` [W, S] saying which of the slot's S entries each may see
-    (`ring_valid` for a ring, whose entries are positions modulo its
-    length; a comparison with the position for a cache that grows): the
-    W queries lie beside a key head's group, so the slot is still read
-    once. Returns [W, heads, d].
-
-    The two forms are one computation (W = 1 with `valid` the comparison
-    with `position`); the one-query form keeps its own reshapes only so
-    that Ouro's and Solar's decode programs trace the operations they
-    traced before it took W queries. Fold it into the other once their
-    parity scripts have been run on the folded form."""
+def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, valid: jax.Array) -> jax.Array:
+    """The einsum form: q [W, heads, d], the queries of W positions of
+    one step (one: `q[None]`), over `cache[slot]`, with `valid` [W, S]
+    saying which of the slot's S entries each may see (`position_valid`
+    for a cache that grows, `ring_valid` for a ring). The slot may hold
+    fewer key and value heads than there are queries, a divisor of their
+    count: key head j serves the query heads j x group .. (j + 1) x group
+    - 1 (one wide where the counts are equal), and the W queries lie
+    beside a key head's group, so the slot is read once. Scores, softmax
+    and the sum's accumulation float32, the probabilities rounded to the
+    cache's dtype. Returns [W, heads, d] in the cache's dtype."""
     keys, values = cache[slot]                                   # [kv heads, S, d] each
     heads, d = q.shape[-2:]
-    if valid is None:
-        grouped = q.reshape(keys.shape[0], -1, d)
-        valid = (jnp.arange(keys.shape[1]) <= position)[None, None, :]
-    else:
-        group = heads // keys.shape[0]
-        grouped = q.reshape(-1, keys.shape[0], group, d).swapaxes(0, 1).reshape(
-            keys.shape[0], -1, d)                                # [kv heads, W x group, d]
-        valid = jnp.repeat(valid, group, axis=0)[None]
+    group = heads // keys.shape[0]
+    grouped = q.reshape(-1, keys.shape[0], group, d).swapaxes(0, 1).reshape(
+        keys.shape[0], -1, d)                                    # [kv heads, W x group, d]
     scores = d ** -0.5 * jnp.einsum(
         "hgd,hsd->hgs", grouped, keys, preferred_element_type=jnp.float32)
-    scores = jnp.where(valid, scores, -jnp.inf)
+    scores = jnp.where(jnp.repeat(valid, group, axis=0)[None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
     out = jnp.einsum("hgs,hsd->hgd", probs, values)
-    if q.ndim == 2:
-        return out.reshape(heads, d)
     return out.reshape(keys.shape[0], q.shape[0], -1, d).swapaxes(0, 1).reshape(q.shape)
+
+
+def position_valid(positions: jax.Array, size: int) -> jax.Array:
+    """[W, size]: which entries of a cache that grows (entry j position
+    j) the queries at `positions` [W] may see: every j <= p."""
+    return jnp.arange(size)[None, :] <= positions[:, None]
 
 
 def ring_valid(positions: jax.Array, ring: int, window: int) -> jax.Array:
@@ -206,22 +194,33 @@ def decode_attention(
     return out.reshape(heads, d)
 
 
-def attend(q: jax.Array, cache: jax.Array, slot, position) -> jax.Array:
-    """`decode_attention` where `decode_attention_route` gives the
-    kernel, else `decode_attention_xla`; one entry in
-    `ops/attention.route_log`: `decode-kernel 16x2112x128 h1 bf16`
-    (heads x positions x width, the heads a grid step takes, the
-    cache's dtype) or `decode-xla 16x2112x128`."""
-    heads, d = q.shape
-    positions = cache.shape[4]
-    route = decode_attention_route(heads, positions, d, cache.dtype)
+def _log_route(entry: str) -> None:
     log = _ROUTE_LOG.get()
     if log is not None:
-        entry = f"{route} {heads}x{positions}x{d}"
-        if route == "decode-kernel":
-            group = decode_plan(heads, positions, d, cache.dtype.itemsize)
-            entry += f" h{group} {_DTYPE_NAMES.get(cache.dtype.name, cache.dtype.name)}"
         log.append(entry)
-    if route == "decode-kernel":
+
+
+def attend_xla(q: jax.Array, cache: jax.Array, slot, valid: jax.Array) -> jax.Array:
+    """`decode_attention_xla` as a model calls it: with one entry in
+    `ops/attention.route_log`, `decode-xla 16x2112x128` (query heads x
+    the slot's entries x width)."""
+    heads, d = q.shape[-2:]
+    _log_route(f"decode-xla {heads}x{cache.shape[-2]}x{d}")
+    return decode_attention_xla(q, cache, slot, valid)
+
+
+def attend(q: jax.Array, cache: jax.Array, slot, position) -> jax.Array:
+    """One query a head, q [heads, d], over the slot's positions up to
+    `position`: `decode_attention` where `decode_attention_route` gives
+    the kernel (its entry in the route log: `decode-kernel 16x2112x128 h1
+    bf16`, the heads a grid step takes and the cache's dtype last), else
+    `attend_xla`."""
+    heads, d = q.shape
+    positions = cache.shape[4]
+    if decode_attention_route(heads, positions, d, cache.dtype) == "decode-kernel":
+        group = decode_plan(heads, positions, d, cache.dtype.itemsize)
+        _log_route(f"decode-kernel {heads}x{positions}x{d} h{group} "
+                   f"{_DTYPE_NAMES.get(cache.dtype.name, cache.dtype.name)}")
         return decode_attention(q, cache, slot, position)
-    return decode_attention_xla(q, cache, slot, position)
+    valid = position_valid(jnp.asarray(position).reshape(1), positions)
+    return attend_xla(q[None], cache, slot, valid)[0]

@@ -909,25 +909,6 @@ def lm_decode_steps_total() -> Counter:
     )
 
 
-def lm_draft_tokens_total() -> Counter:
-    return get_metrics_registry().counter(
-        "cdt_lm_draft_tokens_total",
-        "Tokens a language model's draft module proposed, by what the "
-        "main model's verification made of them (outcome=accepted|rejected)",
-        ("outcome",),
-    )
-
-
-def lm_linear_layer_passes_total() -> Counter:
-    return get_metrics_registry().counter(
-        "cdt_lm_linear_layer_passes_total",
-        "Of cdt_lm_layer_passes_total, the layer bodies whose mixer is "
-        "linear attention over a fixed-size recurrent state (the rest "
-        "attend over keys and values that grow with the position)",
-        ("phase",),
-    )
-
-
 def tile_jobs_active() -> Gauge:
     return get_metrics_registry().gauge(
         "cdt_tile_jobs_active",

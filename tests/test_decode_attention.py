@@ -26,6 +26,13 @@ def operands(heads, positions, dtype, seed=0):
     return q, cache
 
 
+def einsum_form(q, cache, slot, position):
+    """`decode_attention_xla` at W = 1: the one query's `valid` row made
+    from its position."""
+    valid = da.position_valid(jnp.asarray(position, jnp.int32).reshape(1), cache.shape[-2])
+    return da.decode_attention_xla(q[None], cache, slot, valid)[0]
+
+
 def reference(q, cache, slot, position):
     """float64 over the same operands, on the host."""
     keys, values = (np.asarray(x, np.float64) for x in cache[slot])
@@ -46,7 +53,7 @@ def test_kernel_matches_the_einsum_form(positions, where, dtype, heads):
     q, cache = operands(heads, positions, dtype, seed=positions + heads)
     slot = tuple(jnp.int32(i) for i in SLOT)
     got = da.decode_attention(q, cache, slot, jnp.int32(position), interpret=True)
-    form = da.decode_attention_xla(q, cache, slot, jnp.int32(position))
+    form = einsum_form(q, cache, slot, jnp.int32(position))
     assert got.shape == form.shape == (heads, D) and got.dtype == form.dtype == cache.dtype
     tolerance = TOLERANCE[jnp.dtype(dtype).name]
     want = reference(q, cache, SLOT, position)
@@ -85,7 +92,7 @@ def test_every_slot_is_its_own():
     for slot in [(0, 0), (0, 1), (1, 0), (1, 1)]:
         outs[slot] = np.asarray(da.decode_attention(q, cache, slot, 63, interpret=True))
         np.testing.assert_allclose(
-            outs[slot], np.asarray(da.decode_attention_xla(q, cache, slot, 63)), atol=2e-5)
+            outs[slot], np.asarray(einsum_form(q, cache, slot, 63)), atol=2e-5)
     assert len({out.tobytes() for out in outs.values()}) == 4
 
 
@@ -142,6 +149,39 @@ def test_attend_writes_one_route_log_entry(monkeypatch, backend, entry):
     # outside a `route_log` block nothing is collected and nothing fails
     jax.eval_shape(
         lambda q, cache, t, l, p: da.attend(q, cache, (t, l), p), q, cache, index, index, index)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("kv_heads", [4, 2, 1])
+def test_one_query_is_row_0_of_two_under_the_positions_valid_rows(kv_heads, dtype):
+    """The einsum form has one shape of call: W = 1 with the position's
+    `valid` row is what row 0 of a W = 2 call gives, and row 1 is the next
+    position's; a key head serves its group of query heads either way."""
+    heads, positions, position = 4, 40, 29
+    keys = jax.random.split(jax.random.key(7), 2)
+    q = jax.random.normal(keys[0], (2, heads, 16), dtype)
+    cache = jax.random.normal(keys[1], (3, 2, kv_heads, positions, 16), dtype)
+    valid = da.position_valid(jnp.asarray([position, position + 1]), positions)
+    assert valid.shape == (2, positions) and valid.sum(axis=1).tolist() == [30, 31]
+    both = da.decode_attention_xla(q, cache, (2,), valid)
+    assert both.shape == q.shape and both.dtype == cache.dtype
+    for row in (0, 1):
+        alone = da.decode_attention_xla(q[row:row + 1], cache, (2,), valid[row:row + 1])
+        np.testing.assert_allclose(
+            np.asarray(alone[0], np.float32), np.asarray(both[row], np.float32),
+            atol=TOLERANCE[jnp.dtype(dtype).name], rtol=0)
+    assert not np.array_equal(np.asarray(both[0]), np.asarray(both[1]))
+
+
+@pytest.mark.parametrize("kv_heads, entry", [(16, "decode-xla 16x2112x128"),
+                                             (2, "decode-xla 16x2112x128")])
+def test_attend_xla_writes_one_route_log_entry_whatever_the_key_heads(kv_heads, entry):
+    q = jax.ShapeDtypeStruct((2, 16, D), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((3, 2, kv_heads, 2112, D), jnp.bfloat16)
+    valid = jax.ShapeDtypeStruct((2, 2112), jnp.bool_)
+    with attn.route_log() as routes:
+        out = jax.eval_shape(lambda q, c, v: da.attend_xla(q, c, (1,), v), q, cache, valid)
+    assert routes == [entry] and (out.shape, out.dtype) == ((2, 16, D), jnp.bfloat16)
 
 
 def test_a_shape_the_plan_refuses_is_an_error_on_the_kernel():

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from comfyui_distributed_tpu.models import deepseek_v2 as ds
+from comfyui_distributed_tpu.models.lm_common import ByteTokenizer
 from comfyui_distributed_tpu.models.registry import get_config
 from comfyui_distributed_tpu.parallel.sharding import expert_range
 from comfyui_distributed_tpu.reference import deepseek_v2 as ref
@@ -235,7 +236,7 @@ def test_another_temperature_builds_no_program():
 
 @pytest.mark.parametrize("text", ["a cat", "", "déjà vu", "x" * 300])
 def test_the_stand_in_tokenizer_is_bytes_in_and_words_out(text):
-    tok = ds.ByteTokenizer()
+    tok = ByteTokenizer()
     ids = tok.encode(text)
     assert ids[0] == 0 and len(ids) == 1 + len(text.encode("utf-8")) and max(ids) <= 256
     kept = "".join(ch for ch in text if 32 <= ord(ch) < 127)
@@ -243,7 +244,7 @@ def test_the_stand_in_tokenizer_is_bytes_in_and_words_out(text):
 
 
 def test_the_tokenizer_turns_any_id_of_the_slice_into_lower_case_words():
-    tok = ds.ByteTokenizer()
+    tok = ByteTokenizer()
     assert tok.decode([0, 66, 67, 257, 258, 283, 25599]) == "AB a b ab smlb"
     text = tok.decode(range(25600))
     assert set(text) <= set("abcdefghijklmnopqrstuvwxyz ") | {chr(c) for c in range(32, 127)}

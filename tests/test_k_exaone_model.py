@@ -189,11 +189,12 @@ def _decode_attention_xla_before_pr41(q, cache, slot, position):
 @pytest.mark.parametrize("kv_heads", [4, 2])
 def test_one_query_over_a_slot_is_bit_for_bit_what_it_was(kv_heads, dtype):
     """Solar-Open2's softmax layer (grouped) and Ouro's fallback (equal
-    heads) call the einsum form with a position and no mask."""
+    heads): one query, its `valid` row made from its position."""
     keys = jax.random.split(jax.random.key(5), 2)
     q = jax.random.normal(keys[0], (4, 16), dtype)
     cache = jax.random.normal(keys[1], (3, 2, kv_heads, 40, 16), dtype)
-    got = decode_attention.decode_attention_xla(q, cache, (1,), 29)
+    valid = decode_attention.position_valid(jnp.asarray([29]), 40)
+    got = decode_attention.decode_attention_xla(q[None], cache, (1,), valid)[0]
     want = _decode_attention_xla_before_pr41(q, cache, (1,), 29)
     np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
 
@@ -203,10 +204,10 @@ def test_two_queries_over_a_slot_are_each_query_alone_under_its_own_mask():
     q = jax.random.normal(keys[0], (2, 4, 16))
     cache = jax.random.normal(keys[1], (3, 2, 2, 40, 16))
     valid = jnp.arange(40)[None, :] <= jnp.asarray([29, 30])[:, None]
-    got = decode_attention.decode_attention_xla(q, cache, (2,), valid=valid)
+    got = decode_attention.decode_attention_xla(q, cache, (2,), valid)
     assert got.shape == (2, 4, 16)
     for row, position in enumerate((29, 30)):
-        want = decode_attention.decode_attention_xla(q[row], cache, (2,), position)
+        want = _decode_attention_xla_before_pr41(q[row], cache, (2,), position)
         np.testing.assert_allclose(np.asarray(got[row]), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 

@@ -1,0 +1,1058 @@
+"""The committed rewrite-then-txt2img workflows, one a language model,
+through the graph executor on the tiny presets. One table (`MODELS`) has a
+row a model; the tests every model shares run once a row: a PNG a request,
+equal bytes for equal seeds, no program built by a third request, the
+spans under `node.TextGenerate`, exactly the attributes the row says (their
+names and, where the shapes give them, their values: written from the
+tree before the node stopped merging `describe` and `report` itself), what the node counts,
+the workflow, the configuration file, the registry entry, the reference
+and the benchmark's copies. What only one model has stays a test of its
+own below. The one contract (`models/lm_common`) is held against every
+registry entry of family `lm`, and the shared decode loop against a Python
+loop over each model's own step.
+
+A `model_config` PR adds a row here, and edits no other row."""
+
+import dataclasses
+import json
+import os
+from typing import Callable
+
+import pytest
+
+from comfyui_distributed_tpu.graph import nodes_core
+from comfyui_distributed_tpu.graph.executor import ExecutionContext, GraphExecutor
+from comfyui_distributed_tpu.models.lm_common import ByteTokenizer
+from comfyui_distributed_tpu.models.registry import MODEL_REGISTRY
+from comfyui_distributed_tpu.telemetry import get_metrics_registry, get_tracer
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BUILD_TALLIES = {"compiles", "compile_s", "cache_hits", "cache_misses", "trace_s", "lower_s",
+                 "cache_fetch_s"}
+ROUTING = {f"{phase}_{what}" for phase in ("prefill", "decode") for what in (
+    "routed_pairs", "routed_pairs_held", "expert_load_max", "expert_rows")} | {
+    "decode_expert_route"}
+# the metrics every language-model cell lists in BENCHMARK.json
+LM_METRICS = {
+    "images_per_s", "execute_ms.txt2img", "host_ms.txt2img", "device_idle_pct.txt2img",
+    "decode_dispatch_ms.txt2img", "generate_ms.lm", "decode_ms_per_token.lm",
+    "lm_share_pct.rewrite", "cache_gb.lm", "layer_passes_per_token.lm",
+    "sampler_device_ms.txt2img", "vae_device_ms.txt2img", "prefill_device_ms.lm",
+    "decode_device_ms_per_token.lm", "decode_hbm_roofline_pct.lm", "prefill_mxu_peak_pct.lm",
+    "device_idle_in_pct.txt2img", "between_jobs_ms.txt2img"}
+PLAIN_IMPORTS = ["from __future__ import annotations\n", "import dataclasses\n", "import jax\n",
+                 "import jax.numpy as jnp\n", "import numpy as np\n"]
+
+
+def load(path):
+    with open(os.path.join(ROOT, path), encoding="utf-8") as fh:
+        return json.load(fh)
+
+
+def by_kind(prompt):
+    return {n["class_type"]: n["inputs"] for n in prompt.values()}
+
+
+def spans_named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+# --- what only one model's row can say ---------------------------------------
+# `drawn(attrs)`: the attributes whose values the drawn tokens and the seeded
+# weights decide; `published(config)` and `entry(cfg, config)`: the parts of the
+# configuration file and of the registry entry no other model has;
+# `workflow(mine, deepseeks)`: how the committed graph differs from the first.
+
+
+def differing(mine, theirs):
+    assert mine.keys() == theirs.keys()
+    return {(mine[node]["class_type"], key)
+            for node in mine for key in mine[node]["inputs"]
+            if mine[node]["inputs"][key] != theirs[node]["inputs"].get(key)}
+
+
+def held_share(attrs, layers, held, low=0.0, high=1.0, decode_layers=None):
+    """The routing's drawn attributes, `layers` expert layers of `held`
+    held experts: a share of the pairs falls on them, the fullest holds
+    its part, and the rows run cover the held pairs."""
+    for phase, rows in (("prefill", layers), ("decode", decode_layers or layers)):
+        pairs, on_held = attrs[f"{phase}_routed_pairs"], attrs[f"{phase}_routed_pairs_held"]
+        assert 0 <= on_held < pairs
+        assert on_held / (rows * held) <= attrs[f"{phase}_expert_load_max"] <= on_held
+    assert low < attrs["prefill_routed_pairs_held"] / attrs["prefill_routed_pairs"] < high
+    assert attrs["prefill_routed_pairs_held"] <= attrs["prefill_expert_rows"]
+    assert attrs["prefill_expert_rows"] < attrs["prefill_routed_pairs"]
+    assert attrs["prefill_expert_rows"] % 256 == 0  # a rung a layer
+
+
+def deepseek_drawn(attrs):
+    held_share(attrs, layers=2, held=4)
+    # a rung a layer of (1536, 3072, 6144) for the prefill's 6,144 pairs
+    assert attrs["prefill_expert_rows"] in {2 * 1536, 1536 + 3072, 2 * 3072}
+
+
+def deepseek_workflow(mine, _):
+    assert sorted(n["class_type"] for n in mine.values()) == sorted([
+        "CheckpointLoaderSimple", "TextGenerate", "CLIPLoader", "UNETLoader", "VAELoader",
+        "CLIPTextEncode", "CLIPTextEncode", "EmptyLatentImage", "DistributedSeed", "KSampler",
+        "VAEDecode", "DistributedCollector", "SaveImage"])
+    kinds = by_kind(mine)
+    sampler, generate = kinds["KSampler"], kinds["TextGenerate"]
+    assert (sampler["steps"], sampler["cfg"], sampler["sampler_name"], sampler["scheduler"],
+            sampler["denoise"]) == (20, 7.0, "euler", "karras", 1.0)
+    assert kinds["EmptyLatentImage"] == {"width": 512, "height": 512, "batch_size": 1}
+    assert (generate["max_new_tokens"], generate["temperature"]) == (256, 1.0)
+    # the language model's CLIP output feeds TextGenerate; its text feeds the positive prompt
+    assert mine[generate["clip"][0]]["class_type"] == "CheckpointLoaderSimple"
+    assert generate["clip"][1] == 1
+    positive = mine[sampler["positive"][0]]
+    assert mine[positive["inputs"]["text"][0]]["class_type"] == "TextGenerate"
+    assert mine[positive["inputs"]["clip"][0]]["class_type"] == "CLIPLoader"
+    assert mine[sampler["model"][0]]["class_type"] == "UNETLoader"
+    assert mine[sampler["negative"][0]]["inputs"]["text"] == "blurry, low quality"
+    # one seed for the text and for the image
+    assert mine[generate["seed"][0]]["class_type"] == "DistributedSeed"
+    assert generate["seed"] == sampler["seed"]
+    assert (kinds["UNETLoader"]["unet_name"], kinds["CLIPLoader"]["clip_name"],
+            kinds["VAELoader"]["vae_name"]) == ("sd15", "clip-l", "vae-sd")
+    assert generate["text"].isascii() and generate["text"].endswith("Prompt: ")
+
+
+def deepseek_published(config):
+    assert config["rope_scaling"] == {
+        "beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707, "mscale_all_dim": 0.707,
+        "original_max_position_embeddings": 4096, "type": "yarn"}
+    assert "rank 0" in config["deployment"] and "four chips" in config["deployment"]
+    assert config["assumed"] and config["parity"]["tolerance_rel_l2_median"] > 0
+
+
+def deepseek_entry(cfg, config):
+    assert len(cfg.held_experts) == config["n_routed_experts"]
+    assert cfg.n_routed_experts == config["published"]["n_routed_experts"]
+    assert cfg.vocab_held == config["vocab_size"]
+    scaling = config["rope_scaling"]
+    assert (cfg.rope_factor, cfg.rope_beta_fast, cfg.rope_beta_slow, cfg.rope_mscale,
+            cfg.rope_mscale_all_dim, cfg.rope_original_max_position_embeddings) == (
+        scaling["factor"], scaling["beta_fast"], scaling["beta_slow"], scaling["mscale"],
+        scaling["mscale_all_dim"], scaling["original_max_position_embeddings"])
+
+
+def ouro_drawn(attrs):
+    mass = [attrs[f"exit_mass_{step}"] for step in range(1, 5)]
+    assert all(m > 0 for m in mass)
+    assert sum(mass) == pytest.approx(attrs["prompt_tokens"] + attrs["new_tokens"], rel=1e-4)
+
+
+def ouro_workflow(mine, theirs):
+    assert differing(mine, theirs) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "max_new_tokens"),
+        ("SaveImage", "filename_prefix")}
+    assert by_kind(mine)["TextGenerate"]["max_new_tokens"] == 64
+
+
+def ouro_published(config):
+    assert config["layer_types"] == ["full_attention"] * 48
+    assert config["as_run"]["parameters"] == {"lm": 2667974657}
+    assert config["as_run"]["cache_bytes_per_token"] == 1572864
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max"] < 0.3
+    assert 0 < limits["tolerance_exit_abs_max"] < 0.1
+
+
+def ouro_entry(cfg, config):
+    assert (cfg.num_hidden_layers, cfg.total_ut_steps, cfg.vocab_size) == (48, 4, 49152)
+
+
+def solar_drawn(attrs):
+    held_share(attrs, layers=4, held=2, low=0.06, high=0.2)  # an eighth in expectation
+
+
+def solar_workflow(mine, theirs):
+    assert differing(mine, theirs) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "text"),
+        ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["temperature"]) == (256, 1.0)
+    text = generate["text"]
+    assert len(text.encode("utf-8")) == 8191 and text.isascii()
+    # the DeepSeek cell's instruction, a house style guide, worked pairs, the user's line
+    theirs_text = by_kind(theirs)["TextGenerate"]["text"]
+    assert text.startswith(theirs_text.split("\n\nExample 1\n")[0])
+    assert "House style guide" in text and text.count("\nRequest: ") == 13
+    assert text.endswith("\n\nRequest: a photograph of a mountain lake at dawn\nPrompt:")
+    assert theirs_text.rstrip().endswith(text[-55:])
+
+
+def solar_published(config):
+    assert config["linear_attn_config"] == {
+        "short_conv_kernel_size": 4, "head_dim": 128, "num_heads": 64, "num_kv_heads": None}
+    assert config["gqa_layers"] == list(range(0, 48, 4))
+    assert config["as_run"]["parameters"] == {"lm": 3308353344}
+    assert config["as_run"]["cache_bytes_per_token"] == 4096
+    assert config["as_run"]["state_bytes"] == 13025280
+    assert set(config["held"]) == {"layers", "experts", "vocabulary", "state"}
+    assert "8 chips of one v5e-8 host" in config["deployment"]
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max_unflipped"] < 0.2
+    assert 0 < limits["tolerance_expert_set_mismatch"] < 0.5
+    assert 0 < limits["tolerance_state_rel_l2"] < 0.2
+
+
+def solar_entry(cfg, config):
+    linear = config["linear_attn_config"]
+    assert (cfg.linear_num_heads, cfg.linear_head_dim, cfg.short_conv_kernel_size) == (
+        linear["num_heads"], linear["head_dim"], linear["short_conv_kernel_size"])
+    assert (cfg.num_hidden_layers, len(cfg.held_experts), cfg.vocab_held) == (
+        config["num_hidden_layers"], config["n_routed_experts"], config["vocab_size"])
+    assert (cfg.n_routed_experts, cfg.vocab_size, cfg.ep_size, cfg.vocab_shards) == (
+        320, 196608, 8, 8)
+    assert cfg.kda_chunk == config["as_run"]["kda_chunk"] == 64
+    assert [layer for layer in range(48) if type(cfg)().is_full(layer)] == config["gqa_layers"]
+
+
+def k_exaone_drawn(attrs):
+    # four sparse main layers and the MTP module's, 2 of 16 experts held
+    held_share(attrs, layers=4, held=2, low=0.06, high=0.2, decode_layers=5)
+    # one draft a step, one or two tokens out of each, the first from the prefill
+    steps, accepted = attrs["decode_steps"], attrs["mtp_accepted"]
+    assert attrs["mtp_drafted"] == steps and 0 <= accepted <= steps
+    assert 1 + steps + accepted in (attrs["new_tokens"], attrs["new_tokens"] + 1)
+    # two positions a step through five layers and the MTP module's, kept or not
+    assert attrs["decode_layer_passes"] == steps * 2 * (5 + 1)
+    assert attrs["decode_routed_pairs"] == steps * 2 * (4 + 1) * 4 == attrs["decode_expert_rows"]
+    # distinct held experts a step and layer read: never more than the pairs on them
+    assert 0 <= attrs["decode_experts_read"] <= min(
+        attrs["decode_routed_pairs_held"], steps * (4 + 1) * 2)
+
+
+def k_exaone_workflow(mine, _):
+    solars = load("workflows/rewrite-txt2img-solar-open2.json")
+    assert differing(mine, solars) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "max_new_tokens"),
+        ("TextGenerate", "draft_tokens"), ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["draft_tokens"], generate["temperature"]) == (
+        384, 1, 1.0)
+    # the Solar cell's 8,191-byte instruction, byte for byte
+    assert generate["text"] == by_kind(solars)["TextGenerate"]["text"]
+
+
+def k_exaone_published(config):
+    assert config["rope_parameters"] == {"rope_theta": 1000000, "rope_type": "default"}
+    assert config["layer_types"] == (["sliding_attention"] * 3 + ["full_attention"]) * 12
+    assert config["mlp_layer_types"] == ["dense"] + ["sparse"] * 47
+    assert (config["mtp_layer_types"], config["mtp_sliding_windows"]) == (["full_attention"], [0])
+    assert config["as_run"]["parameters"] == {"lm": 4543318144}
+    assert set(config["held"]) == {"layers", "experts", "vocabulary", "state"}
+    assert "8 chips of one v5e-8 host" in config["deployment"]
+    assert "not the trained model's" in config["as_run"]["drafts_kept"]
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max_unflipped"] < 0.2
+    assert 0 < limits["tolerance_draft_rel_l2_median"] < 0.2
+    assert 0 < limits["tolerance_expert_set_mismatch"] < 0.5
+
+
+def k_exaone_entry(cfg, config):
+    assert cfg.rope_theta == config["rope_parameters"]["rope_theta"]
+    assert (cfg.num_hidden_layers, len(cfg.held_experts), cfg.vocab_held) == (
+        config["num_hidden_layers"], config["num_experts"], config["vocab_size"])
+    assert (cfg.num_experts, cfg.vocab_size, cfg.ep_size, cfg.vocab_shards) == (
+        128, 153600, 8, 8)
+    whole = type(cfg)()
+    assert ["sliding_attention" if whole.is_window(i) else "full_attention"
+            for i in range(48)] == config["layer_types"]
+    assert ["dense" if whole.is_dense(i) else "sparse" for i in range(48)] == (
+        config["mlp_layer_types"])
+
+
+@dataclasses.dataclass(frozen=True)
+class Model:
+    """One language model's row. `attrs`: `node.TextGenerate`'s attributes
+    whose values the shapes give (float32 on the CPU), exactly; `drawn`:
+    the names of the others; nothing else is on the span but the build's
+    tallies and, on the tracing request, `attention`."""
+
+    name: str
+    served: str                 # the registry entries: the cell's, and the rehearsal's
+    tiny: str
+    workflow: str               # under workflows/ and benchmark/workflows/
+    config: str                 # under benchmark/configs/
+    reference: str              # under comfyui_distributed_tpu/reference/ and benchmark/reference/
+    catalog: str                # the model's name in the catalog of architectures
+    cell: str
+    prompt: int                 # tokens, as the cell's rehearsal runs it
+    new_tokens: int
+    drafts: int                 # `draft_tokens_max`
+    attrs: dict
+    drawn: frozenset
+    drawn_check: Callable
+    wait_bytes: int             # the one read-back: the ids and what `read_back` names
+    attention: str              # on the request that traced the programs
+    passes: Callable            # attrs -> layer bodies (prefill, decode) the counter takes
+    widths: dict                # the published configuration's values, kept
+    reduced: dict               # key -> (published, held)
+    assumed: tuple              # words its `assumed` list has to say
+    published: Callable
+    entry: Callable
+    check_workflow: Callable
+    metrics: frozenset = frozenset()   # what its cell lists beyond `LM_METRICS`
+    imports: tuple = tuple(PLAIN_IMPORTS)
+
+    def __str__(self):
+        return self.name
+
+
+MODELS = [
+    Model(
+        name="deepseek-v2", served="deepseek-v2-ep4-5l", tiny="tiny-deepseek-v2",
+        workflow="rewrite-txt2img-deepseek-v2.json", config="deepseek-v2.json",
+        reference="deepseek_v2.py", catalog="DeepSeek-V2",
+        cell="deepseek_v2_rewrite_txt2img_512.closed2", prompt=2048, new_tokens=16, drafts=0,
+        # tiny-deepseek-v2: 3 layers (one dense), a latent of 24 + 8, 4 of 16 experts
+        # held, 3 a token
+        attrs={
+            "prompt_tokens": 2048, "new_tokens": 16, "draft_tokens": 0, "decode_steps": 16,
+            "layers": 3, "experts_held": 4, "experts_total": 16,
+            "cache_bytes": 3 * (2048 + 16) * 32 * 4, "state_bytes": 0,
+            "prefill_routed_pairs": 2048 * 2 * 3, "decode_routed_pairs": 16 * 2 * 3,
+            "decode_expert_rows": 16 * 2 * 3, "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
+                                   "decode_expert_rows", "decode_expert_route"}),
+        drawn_check=deepseek_drawn,
+        wait_bytes=4 * (16 + 2 * 2 * 4),  # the ids, the pairs per held expert of either program
+        attention="xla-causal 2048x2048x24/16 bq256 f32",
+        passes=lambda attrs: (2048 * 3, 16 * 3),
+        widths={
+            "hidden_size": 5120, "intermediate_size": 12288, "moe_intermediate_size": 1536,
+            "kv_lora_rank": 512, "q_lora_rank": 1536, "qk_nope_head_dim": 128,
+            "qk_rope_head_dim": 64, "v_head_dim": 128, "num_attention_heads": 128,
+            "num_key_value_heads": 128, "n_shared_experts": 2, "num_experts_per_tok": 6,
+            "n_group": 8, "topk_group": 3, "routed_scaling_factor": 16,
+            "first_k_dense_replace": 1, "rms_norm_eps": 1e-6, "rope_theta": 10000,
+            "max_position_embeddings": 163840, "norm_topk_prob": False,
+            "topk_method": "group_limited_greedy", "scoring_func": "softmax"},
+        reduced={"num_hidden_layers": (60, 5), "n_routed_experts": (160, 40),
+                 "vocab_size": (102400, 25600)},
+        assumed=("seeded random", "stand-in", "batch is 1"),
+        published=deepseek_published, entry=deepseek_entry, check_workflow=deepseek_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm"}),
+        imports=tuple(PLAIN_IMPORTS + ["import math\n"]),
+    ),
+    Model(
+        name="ouro", served="ouro-2.6b", tiny="tiny-ouro",
+        workflow="rewrite-txt2img-ouro-2.6b.json", config="ouro-2.6b.json",
+        reference="ouro.py", catalog="Ouro-2.6B",
+        cell="ouro_2_6b_rewrite_txt2img_512.closed2", prompt=2048, new_tokens=8, drafts=0,
+        # tiny-ouro: 4 passes x 3 layers, 4 heads of 16; a key and a value of every
+        # head in every slot
+        attrs={
+            "prompt_tokens": 2048, "new_tokens": 8, "draft_tokens": 0, "decode_steps": 8,
+            "ut_steps": 4, "layers": 3, "cache_slots": 12,
+            "cache_bytes": 12 * 2 * 4 * (2048 + 8) * 16 * 4, "state_bytes": 0,
+            "prefill_layer_passes": 2048 * 12, "decode_layer_passes": 8 * 12, "node_id": "6"},
+        drawn=frozenset({"exit_mass_1", "exit_mass_2", "exit_mass_3", "exit_mass_4"}),
+        drawn_check=ouro_drawn,
+        wait_bytes=4 * (8 + 2 * 4),  # the ids and the two exit distributions
+        # the decode's single-query attention over a slot (the einsum form off a
+        # TPU), then the prefill's
+        attention="decode-xla 4x2056x16, xla-causal 2048x2048x16/16 bq256 f32",
+        passes=lambda attrs: (2048 * 12, 8 * 12),
+        widths={
+            "hidden_size": 2048, "intermediate_size": 5632, "num_hidden_layers": 48,
+            "num_attention_heads": 16, "num_key_value_heads": 16, "head_dim": 128,
+            "total_ut_steps": 4, "early_exit_threshold": 1, "vocab_size": 49152,
+            "rms_norm_eps": 1e-6, "rope_theta": 1000000, "max_position_embeddings": 65536,
+            "tie_word_embeddings": False, "hidden_act": "silu", "model_type": "ouro",
+            "rope_scaling": None, "sliding_window": None, "use_sliding_window": False},
+        reduced={},
+        assumed=("seeded random", "stand-in", "batch is 1", "four RMS norms", "final norm",
+                 "bias", "never exit early", "system prompt"),
+        published=ouro_published, entry=ouro_entry, check_workflow=ouro_workflow,
+    ),
+    Model(
+        name="solar-open2", served="solar-open2-ep8-4l", tiny="tiny-solar-open2",
+        workflow="rewrite-txt2img-solar-open2.json", config="solar-open2-250b.json",
+        reference="solar_open2.py", catalog="Solar-Open2-250B",
+        cell="solar_open2_rewrite_txt2img_512.closed2", prompt=8192, new_tokens=16, drafts=0,
+        # tiny-solar-open2: a gated NoPE layer (4 query heads over 2 key heads of 16)
+        # and three KDA layers (4 heads of 16, chunks of 32), 2 of 16 experts held, 4
+        # a token. What grows: a key and a value of each key head in the one softmax
+        # layer; what does not: a matrix state a KDA head, the convolutions' last 3 inputs
+        attrs={
+            "prompt_tokens": 8192, "new_tokens": 16, "draft_tokens": 0, "decode_steps": 16,
+            "layers": 4, "full_layers": 1, "linear_layers": 3, "experts_held": 2,
+            "experts_total": 16, "cache_bytes": 2 * 2 * (8192 + 16) * 16 * 4,
+            "state_bytes": 3 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4),
+            "prefill_chunks": 8192 // 32, "prefill_routed_pairs": 8192 * 4 * 4,
+            "decode_routed_pairs": 16 * 4 * 4, "decode_expert_rows": 16 * 4 * 4,
+            "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
+                                   "decode_expert_rows", "decode_expert_route"}),
+        drawn_check=solar_drawn,
+        wait_bytes=4 * (16 + 2 * 4 * 2),  # nothing of the state tree leaves the device
+        # the decode's one softmax layer in four (the einsum form, a key head
+        # serving two queries), then the prefill's
+        attention="decode-xla 4x8208x16, xla-causal 8192x8192x16/16 bq256 f32",
+        passes=lambda attrs: (8192 * 4, 16 * 4),
+        widths={
+            "hidden_size": 4096, "num_attention_heads": 64, "num_key_value_heads": 8,
+            "head_dim": 128, "moe_intermediate_size": 1280, "intermediate_size": 10240,
+            "num_experts_per_tok": 8, "n_shared_experts": 1, "rms_norm_eps": 1e-5,
+            "gqa_interval": 3, "use_rope": False, "use_gqa_gate": True,
+            "kda_use_full_proj": False, "kda_allow_neg_eigval": True, "norm_topk_prob": True,
+            "routed_scaling_factor": 1, "first_k_dense_replace": 0,
+            "max_position_embeddings": 1048576, "tie_word_embeddings": False},
+        reduced={"num_hidden_layers": (48, 4), "n_routed_experts": (320, 40),
+                 "vocab_size": (196608, 24576)},
+        assumed=("low-rank", "element-wise", "sigmoids", "seeded random", "stand-in",
+                 "batch is 1", "dt_bias", "house style guide"),
+        published=solar_published, entry=solar_entry, check_workflow=solar_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm",
+                           "linear_attention_device_pct.lm"}),
+    ),
+    Model(
+        name="k-exaone", served="k-exaone-ep8-5l", tiny="tiny-k-exaone",
+        workflow="rewrite-txt2img-k-exaone.json", config="k-exaone-236b-a23b.json",
+        reference="k_exaone.py", catalog="K-EXAONE-236B-A23B",
+        cell="k_exaone_rewrite_txt2img_512.closed2", prompt=8192, new_tokens=16, drafts=1,
+        # tiny-k-exaone: a dense layer and four sparse ones (window, window, window,
+        # full, window over all five), 4 query heads over 2 key heads of 16, a window
+        # of 12 in a ring of 16, 2 of 16 experts held, 4 a token, the MTP module. What
+        # grows: the full layer's cache and the MTP module's; what does not: four rings
+        attrs={
+            "prompt_tokens": 8192, "new_tokens": 16, "draft_tokens": 1,
+            "layers": 5, "window_layers": 4, "full_layers": 2, "window": 12,
+            "ring_positions": 16, "experts_held": 2, "experts_total": 16,
+            "cache_bytes": 2 * 2 * 2 * (8192 + 16) * 16 * 4, "state_bytes": 4 * 2 * 2 * 16 * 16 * 4,
+            "prefill_layer_passes": 8192 * 5, "prefill_routed_pairs": 8192 * 4 * 4,
+            "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
+            "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
+            "decode_experts_read"},
+        drawn_check=k_exaone_drawn,
+        # the ids, the pairs per held expert of either program (the decode's with the
+        # MTP module's row) and the four counts
+        wait_bytes=4 * (16 + 4 * 2 + (4 + 1) * 2 + 4),
+        # a step's two queries over a ring and over a growing cache; the prefill's
+        # full route beside the windowed one
+        attention=("decode-xla 4x16x16, decode-xla 4x8208x16, "
+                   "xla-causal 8192x8192x16/16 bq256 f32, "
+                   "xla-causal 8192x8192x16/16 w12 bq256 f32"),
+        passes=lambda attrs: (8192 * 5, attrs["decode_steps"] * 2 * (5 + 1)),
+        widths={
+            "hidden_size": 6144, "num_attention_heads": 64, "num_key_value_heads": 8,
+            "head_dim": 128, "intermediate_size": 18432, "moe_intermediate_size": 2048,
+            "num_experts_per_tok": 8, "num_shared_experts": 1, "rms_norm_eps": 1e-5,
+            "sliding_window": 128, "sliding_window_pattern": "LLLG", "first_k_dense_replace": 1,
+            "norm_topk_prob": True, "routed_scaling_factor": 2.5, "num_nextn_predict_layers": 1,
+            "n_group": 1, "topk_group": 1, "scoring_func": "sigmoid",
+            "max_position_embeddings": 262144, "tie_word_embeddings": False},
+        reduced={"num_hidden_layers": (48, 5), "num_experts": (128, 16),
+                 "vocab_size": (153600, 19200)},
+        assumed=("pre-norm", "QK norm", "which layers rotate", "window's convention",
+                 "selection bias", "DeepSeek-V3's", "before the final norm", "seeded random",
+                 "stand-in", "batch is 1", "share of drafts kept", "house style guide"),
+        published=k_exaone_published, entry=k_exaone_entry, check_workflow=k_exaone_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
+                           "mtp_device_pct.lm"}),
+    ),
+]
+BY_NAME = {m.name: m for m in MODELS}
+LM_ENTRIES = sorted(name for name, entry in MODEL_REGISTRY.items() if entry["family"] == "lm")
+
+
+def rehearsed(model, **text_generate):
+    """A committed graph with its cell's own rehearsal edits."""
+    prompt = load(os.path.join("workflows", model.workflow))
+    for edit in load(f"benchmark/workloads/{model.cell}.json")["rehearsal"]["set"]:
+        for node in prompt.values():
+            if node["class_type"] == edit["class_type"]:
+                node["inputs"][edit["input"]] = edit["value"]
+    by_kind(prompt)["TextGenerate"].update(text_generate)
+    return prompt
+
+
+def counted():
+    """The three `cdt_lm_*` counters as they stand."""
+    registry = get_metrics_registry()
+    values = {"steps": registry.counter("cdt_lm_decode_steps_total", "").value()}
+    for name in ("cdt_lm_tokens_total", "cdt_lm_layer_passes_total"):
+        counter = registry.counter(name, "", ("phase",))
+        for phase in ("prefill", "decode"):
+            values[name, phase] = counter.value(phase=phase)
+    return values
+
+
+def node_attrs(prompt):
+    tracer = get_tracer()
+    with tracer.span("execute_prompt") as root:
+        GraphExecutor(ExecutionContext()).execute(prompt)
+    (node,) = spans_named(tracer.spans(root.trace_id), "node.TextGenerate")
+    return node["attrs"]
+
+
+@pytest.fixture(scope="module", params=MODELS, ids=str)
+def model(request):
+    return request.param
+
+
+@pytest.fixture(scope="module")
+def served(model, tmp_path_factory):
+    """Seeds 42, 43 and 42 again through one executor, which builds the
+    model's programs once for every test of its row: (PNG bytes, spans,
+    outputs, programs built, what the counters grew by) per request."""
+    from comfyui_distributed_tpu.telemetry import runtime
+
+    runtime.install_jax_monitoring()
+    graph = rehearsed(model)
+    out_dir = tmp_path_factory.mktemp("out")
+    os.environ["CDT_OUTPUT_DIR"] = str(out_dir)
+    executor, tracer, runs = GraphExecutor(ExecutionContext()), get_tracer(), []
+    try:
+        for seed in (42, 43, 42):
+            by_kind(graph)["DistributedSeed"]["seed"] = seed
+            compiles, counters = runtime.tallies()["compiles"], counted()
+            with tracer.span("execute_prompt") as root:
+                outputs = executor.execute(graph)
+            built = runtime.tallies()["compiles"] - compiles
+            grew = {key: value - counters[key] for key, value in counted().items()}
+            (name,) = [i["ui"]["images"] for r in outputs.values() for i in r
+                       if isinstance(i, dict) and "images" in i.get("ui", {})][0]
+            with open(os.path.join(out_dir, name), "rb") as fh:
+                runs.append((fh.read(), tracer.spans(root.trace_id), outputs, built, grew))
+    finally:
+        os.environ.pop("CDT_OUTPUT_DIR", None)
+    return runs
+
+
+# --- what every model's served path does ---------------------------------------
+
+
+def test_a_request_gives_a_png_and_the_text_that_was_drawn(model, served):
+    png, _, outputs, _, _ = served[0]
+    assert png[:8] == b"\x89PNG\r\n\x1a\n"
+    texts = [i["ui"]["text"] for r in outputs.values() for i in r
+             if isinstance(i, dict) and "text" in i.get("ui", {})]
+    assert len(texts) == 1 and len(texts[0]) == 1
+    words = texts[0][0].split()
+    assert 0 < len(words) <= model.new_tokens and texts[0][0] == texts[0][0].strip()
+
+
+def test_history_outputs_carry_the_text_beside_the_images(served):
+    from comfyui_distributed_tpu.api.server import _jsonable_outputs
+
+    entries = _jsonable_outputs(served[0][2])
+    assert sorted(key for entry in entries.values() for key in entry) == ["images", "text"]
+    json.dumps(entries)
+
+
+def test_equal_seeds_give_equal_bytes_and_another_seed_other_bytes(served):
+    assert served[0][0] == served[2][0]
+    assert served[0][0] != served[1][0]
+
+
+def test_the_third_request_builds_no_program(served):
+    assert served[0][3] > 0
+    assert served[2][3] == 0
+    (node,) = spans_named(served[2][1], "node.TextGenerate")
+    assert "compiles" not in node["attrs"]
+
+
+def test_the_language_model_runs_again_for_a_seed_it_has_seen(served):
+    # an output node: the executor's node cache never answers it
+    for _, spans, _, _, _ in served:
+        assert len(spans_named(spans, "node.TextGenerate")) == 1
+        assert len(spans_named(spans, "lm.prefill")) == 1
+
+
+def test_node_textgenerate_says_what_its_row_says_and_nothing_else(model, served):
+    """The attributes' names are the row's exactly, whatever model ran
+    before in this process; the values the shapes give are the row's,
+    the drawn ones pass the row's own check."""
+    (node,) = spans_named(served[1][1], "node.TextGenerate")
+    attrs = node["attrs"]
+    assert set(attrs) - BUILD_TALLIES == set(model.attrs) | model.drawn
+    assert {key: attrs[key] for key in model.attrs} == model.attrs
+    model.drawn_check(attrs)
+    # and on the request that traced the programs, which attention they took
+    (first,) = spans_named(served[0][1], "node.TextGenerate")
+    assert set(first["attrs"]) - BUILD_TALLIES == set(model.attrs) | model.drawn | {"attention"}
+
+
+def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(model, served):
+    spans = served[1][1]
+    (node,) = spans_named(spans, "node.TextGenerate")
+    below = [s["name"] for s in spans if s["parent_id"] == node["span_id"]]
+    assert below == ["lm.prefill", "device.run", "lm.decode", "device.run", "device.wait",
+                     "lm.detokenize"]
+    assert [s["attrs"]["program"] for s in spans_named(spans, "device.run")
+            if s["parent_id"] == node["span_id"]] == ["prefill", "decode"]
+    (wait,) = [s for s in spans_named(spans, "device.wait") if s["parent_id"] == node["span_id"]]
+    assert wait["attrs"]["bytes"] == model.wait_bytes
+
+
+def test_only_the_request_that_traced_the_programs_says_which_attention(model, served):
+    (first,) = spans_named(served[0][1], "node.TextGenerate")
+    assert first["attrs"]["attention"] == model.attention
+    (second,) = spans_named(served[1][1], "node.TextGenerate")
+    assert "attention" not in second["attrs"]
+
+
+def test_tokens_steps_and_layer_passes_are_counted_by_phase(model, served):
+    """Three counters, fed from the span's attributes and the contract's
+    defaults: a model that says nothing of steps or passes counts a step a
+    token and tokens x layers."""
+    for _, spans, _, _, grew in served:
+        (node,) = spans_named(spans, "node.TextGenerate")
+        prefill_passes, decode_passes = model.passes(node["attrs"])
+        assert grew == {
+            "steps": node["attrs"]["decode_steps"],
+            ("cdt_lm_tokens_total", "prefill"): model.prompt,
+            ("cdt_lm_tokens_total", "decode"): model.new_tokens,
+            ("cdt_lm_layer_passes_total", "prefill"): prefill_passes,
+            ("cdt_lm_layer_passes_total", "decode"): decode_passes,
+        }
+
+
+def test_the_loader_reports_the_lm_part(served):
+    (loader,) = spans_named(served[0][1], "node.CheckpointLoaderSimple")
+    assert loader["attrs"]["lm_bytes"] == 4 * loader["attrs"]["lm_params"] > 0
+    assert not any(key.startswith(("unet_", "vae_", "te_")) for key in loader["attrs"])
+    assert spans_named(served[1][1], "node.CheckpointLoaderSimple") == []  # cached
+
+
+# --- the committed files of every model ------------------------------------------
+
+
+def test_the_workflow_is_the_first_one_but_for_what_its_row_names(model):
+    mine = load(os.path.join("workflows", model.workflow))
+    model.check_workflow(mine, load(os.path.join("workflows", MODELS[0].workflow)))
+    inputs = by_kind(mine)
+    config = load(os.path.join("benchmark/configs", model.config))
+    assert inputs["CheckpointLoaderSimple"]["ckpt_name"] == config["registry_name"] == model.served
+    # a token a byte, and begin-of-sentence: the rehearsal keeps the cell's prompt
+    assert len(ByteTokenizer().encode(inputs["TextGenerate"]["text"])) == model.prompt
+
+
+@pytest.mark.parametrize("kind", ["workflows", "reference"])
+def test_the_benchmarks_copies_are_the_committed_files(model, kind):
+    mine, theirs = {
+        "workflows": (f"benchmark/workflows/{model.workflow}", f"workflows/{model.workflow}"),
+        "reference": (f"benchmark/reference/{model.reference}",
+                      f"comfyui_distributed_tpu/reference/{model.reference}"),
+    }[kind]
+    with open(os.path.join(ROOT, mine), "rb") as a, open(os.path.join(ROOT, theirs), "rb") as b:
+        assert a.read() == b.read()
+
+
+def test_the_reference_imports_nothing_of_the_system(model):
+    with open(os.path.join(ROOT, "comfyui_distributed_tpu/reference", model.reference),
+              encoding="utf-8") as fh:
+        imports = [line for line in fh if line.startswith(("import ", "from "))]
+    assert sorted(imports) == sorted(model.imports)
+
+
+def test_the_configuration_keeps_every_published_width_and_states_its_cut(model):
+    config = load(os.path.join("benchmark/configs", model.config))
+    for key, value in model.widths.items():
+        assert config[key] == value, key
+    assert config["reduced"] == list(model.reduced)
+    for key, (published, held) in model.reduced.items():
+        assert (config["published"][key], config[key]) == (published, held), key
+    assert config["reference"] == f"benchmark/reference/{model.reference}"
+    assumed = " ".join(config["assumed"])
+    for word in model.assumed:
+        assert word in assumed, word
+    model.published(config)
+
+
+def test_the_configuration_file_is_the_catalogs_row_but_for_the_cut(model):
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(path):
+        pytest.skip("the catalog is not on this machine")
+    with open(path, encoding="utf-8") as fh:
+        row = next(row for row in map(json.loads, fh) if row["name"] == model.catalog)
+    config = load(os.path.join("benchmark/configs", model.config))
+    assert config["source"] == row["source_url"]
+    for key, value in row["config"].items():
+        if key in model.reduced:
+            assert config["published"][key] == value, key
+        else:
+            assert config[key] == value, key
+
+
+def test_the_registry_entry_is_the_configuration_file(model):
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    config = load(os.path.join("benchmark/configs", model.config))
+    cfg = get_config(config["registry_name"])
+    for key in model.widths:
+        if hasattr(cfg, key):
+            assert getattr(cfg, key) == config[key], key
+    assert cfg.num_hidden_layers == config["num_hidden_layers"]
+    model.entry(cfg, config)
+
+
+def test_the_manifest_has_the_cell_its_configuration_and_its_metrics(model):
+    manifest = load("BENCHMARK.json")
+    (cell,) = [w for w in manifest["workloads"] if w["name"] == model.cell]
+    name = model.config.removesuffix(".json")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (name, "closed2", 1)
+    assert len(cell["why"]) <= 200
+    (config,) = [c for c in manifest["configs"] if c["name"] == name]
+    assert config["reduced"] == list(model.reduced)
+    assert config["file"] == f"benchmark/configs/{model.config}"
+    assert config["source"] == load(config["file"])["source"]
+    metrics = manifest["per_layer"] + manifest["end_to_end"]
+    listed = {m["name"] for m in metrics if model.cell in m.get("workloads", [])}
+    assert listed == LM_METRICS | model.metrics
+    for metric in manifest["per_layer"]:
+        if metric["name"] in model.metrics | {"cache_gb.lm", "layer_passes_per_token.lm"}:
+            assert metric["moves"] == "images_per_s"
+            assert os.path.exists(
+                os.path.join(ROOT, "benchmark", "layer_metrics", metric["name"] + ".py"))
+    # the cells in the order the models came: a later one is appended, never put inside
+    assert [w["name"] for w in manifest["workloads"]][-len(MODELS):] == [m.cell for m in MODELS]
+    work = load(f"benchmark/workloads/{model.cell}.json")
+    assert work["workflow"] == f"benchmark/workflows/{model.workflow}"
+    assert work["seed_nodes"] == ["DistributedSeed"]
+    assert work["compute_nodes"] == ["TextGenerate", "KSampler"]
+    assert work["rate"] == {"metric": "images_per_s", "units_per_job": 1}
+    assert work["trace"] == {"start_s": 5, "slice_s": 12}
+    edits = {(e["class_type"], e["input"]): e["value"] for e in work["rehearsal"]["set"]}
+    assert edits["CheckpointLoaderSimple", "ckpt_name"] == model.tiny
+    assert edits["TextGenerate", "max_new_tokens"] == model.new_tokens
+
+
+# --- the one contract, over every registry entry of the family -----------------
+
+
+@pytest.mark.parametrize("name", LM_ENTRIES)
+def test_every_language_model_meets_the_one_contract(name):
+    """What `TextGenerate` asks of a bundle's `lm` part (`lm_common`); the
+    model, not the node, says how many bytes each kind of state is, and
+    the class, not the node, what a model that says nothing counts."""
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    assert {m.served for m in MODELS} | {m.tiny for m in MODELS} == set(LM_ENTRIES)
+    lm = create_model(name)
+    for attribute in ("cfg", "tokenizer", "dtype", "layer_passes", "init", "prefill", "decode",
+                      "read_back", "report", "counted", "draft_tokens_max"):
+        assert hasattr(lm, attribute), attribute
+    assert isinstance(lm.tokenizer, ByteTokenizer)
+    (model,) = [m for m in MODELS if name in (m.served, m.tiny)]
+    assert lm.draft_tokens_max == model.drafts
+    with pytest.raises(ValueError, match="draft_tokens"):
+        lm.decode(None, None, None, 0, None, 4, 1.0, draft_tokens=model.drafts + 1)
+
+    held = len(getattr(lm.cfg, "held_experts", ()))
+    read = {"ouro": ([1.0] * 4, [0.5] * 4)}.get(model.name, ([[3] * held], [[1] * held]))
+    if model.drafts:
+        read += ([4, 0, 0, 2],)
+
+    def report(cache_len):
+        return lm.report(100, 4, cache_len, *read)
+
+    said = report(128)
+    assert said["layers"] == lm.cfg.num_hidden_layers
+    assert said["cache_bytes"] > 0 and isinstance(said["cache_bytes"], int)
+    assert said["state_bytes"] >= 0 and isinstance(said["state_bytes"], int)
+    assert report(256)["cache_bytes"] == 2 * said["cache_bytes"]
+    assert report(256)["state_bytes"] == said["state_bytes"]
+    # the part the shapes alone give, which the benchmark's reader tests call by itself
+    assert lm.describe(128).items() <= said.items()
+    # a step a token and tokens x layers, unless the model's own report says them
+    assert lm.counted({}, 100, 4) == {
+        "decode_steps": 4, "prefill_layer_passes": 100 * lm.layer_passes,
+        "decode_layer_passes": 4 * lm.layer_passes}
+    assert lm.counted(said, 100, 4) == {
+        key: said.get(key, value) for key, value in lm.counted({}, 100, 4).items()}
+    assert lm.counted({"decode_steps": 3, "decode_layer_passes": 7, "layers": 9}, 100, 4) == {
+        "decode_steps": 3, "prefill_layer_passes": 100 * lm.layer_passes,
+        "decode_layer_passes": 7}
+    lm.dtype = jnp.dtype(jnp.bfloat16)  # what `init(key, bfloat16)` records
+    assert report(128)["cache_bytes"] == said["cache_bytes"] // 2
+
+
+@pytest.mark.parametrize("name, passes", [
+    ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
+    ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5)])
+def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    assert create_model(name).layer_passes == passes
+
+
+# --- the shared decode loop against each model's own step ----------------------
+
+
+def _step_of(name):
+    """(module, `step(cfg, params, cache, token, position) -> (logits,
+    cache, what the decode sums over its steps)`) of a tiny model."""
+    from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, ouro, solar_open2
+
+    def k_exaone_step(cfg, params, cache, token, position):
+        rows, _, cache, _, loads = k_exaone.main_step(
+            cfg, params, dict(cache), token[None], position)
+        return rows[0], cache, loads
+
+    def ouro_step(cfg, params, cache, token, position):
+        logits, cache, _, exits = ouro.decode_step(cfg, params, cache, token, position)
+        return logits, cache, exits
+
+    def with_loads(module):
+        def step(cfg, params, cache, token, position):
+            logits, cache, _, loads = module.decode_step(cfg, params, cache, token, position)
+            return logits, cache, loads
+        return step
+
+    return {
+        "deepseek-v2": (deepseek_v2, with_loads(deepseek_v2)), "ouro": (ouro, ouro_step),
+        "solar-open2": (solar_open2, with_loads(solar_open2)),
+        "k-exaone": (k_exaone, k_exaone_step),
+    }[name]
+
+
+@pytest.mark.parametrize("steps", [1, 5])
+@pytest.mark.parametrize("name", list(BY_NAME))
+def test_decode_is_the_models_own_step_walked_with_the_same_folded_keys(name, steps):
+    """`lm_common.decode_loop` under each model's `decode`: the ids are a
+    Python loop's over the model's own step (`sample` of the logits under
+    the key folded by the step's index), `steps` of them always, every
+    kept row of logits is that step's, and the tally is the steps' sum."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.lm_common import sample
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    prompt, temperature = 12, jnp.float32(0.8)
+    module, step = _step_of(name)
+    step = jax.jit(step, static_argnums=0)
+    lm = create_model(BY_NAME[name].tiny)
+    cfg, key = lm.cfg, jax.random.key(steps)
+    params = lm.init(jax.random.key(1))
+    ids = jax.random.randint(jax.random.key(2), (prompt,), 0, getattr(
+        cfg, "vocab_held", cfg.vocab_size))
+
+    walked = lm.prefill(params, ids, prompt + steps)  # the decode below may take its own by donation
+    cache, logits, tokens, rows, tally = walked.cache, walked.logits, [], [], 0
+    for i in range(steps):
+        tokens.append(sample(logits, jax.random.fold_in(key, i), temperature))
+        logits, cache, added = step(cfg, params, cache, tokens[-1], jnp.int32(prompt + i))
+        rows.append(logits)
+        tally = tally + np.asarray(added)
+
+    first = lm.prefill(params, ids, prompt + steps)
+    decode = lm.decode(params, first.cache, first.logits, prompt, key, steps, temperature, True)
+    assert decode.ids.shape == (steps,) and decode.ids.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(decode.ids), np.asarray(jnp.stack(tokens)))
+    kept = decode.kept["logits"] if name == "k-exaone" else decode.logits
+    np.testing.assert_allclose(np.asarray(kept), np.asarray(jnp.stack(rows)), rtol=1e-5, atol=1e-5)
+    if name == "ouro":
+        np.testing.assert_allclose(np.asarray(decode.exit), tally, rtol=1e-5)
+        return
+    loads = np.asarray(decode.loads)
+    if name == "k-exaone":  # the MTP module's row last, which no plain step runs
+        assert not loads[-1].any()
+        assert np.asarray(decode.counts).tolist()[:3] == [steps, 0, 0]
+        loads = loads[:-1]
+    np.testing.assert_array_equal(loads, tally)
+    assert loads.sum() <= steps * loads.shape[0] * cfg.num_experts_per_tok
+
+
+@pytest.mark.parametrize("collect", [False, True])
+def test_the_loop_sums_what_a_step_adds_and_keeps_what_it_hands_over(collect):
+    """The loop by itself, under a step that is arithmetic: the cache is
+    threaded, the summed tree is added leaf by leaf from zero (its shapes
+    are the step's own, asked of it without running it), the kept rows
+    are the steps' in order, and a step that keeps None has none."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.lm_common import decode_loop
+
+    def step(cache, token, position):
+        logits = jnp.zeros((7,)).at[(position + 1) % 7].set(50.0)  # the next id: position + 1
+        kept = {"at": position, "token": token} if collect else None
+        return logits, cache + 1, (jnp.ones((2,), jnp.int32), token), kept
+
+    start_logits = jnp.zeros((7,)).at[3].set(50.0)
+    cache, ids, (ones, tokens), rows = jax.jit(lambda: decode_loop(
+        step, jnp.int32(0), start_logits, jnp.int32(3), jax.random.key(0), jnp.float32(0.0), 4))()
+    assert int(cache) == 4 and np.asarray(ids).tolist() == [3, 4, 5, 6]
+    assert np.asarray(ones).tolist() == [4, 4] and int(tokens) == 3 + 4 + 5 + 6
+    if collect:
+        assert np.asarray(rows["at"]).tolist() == np.asarray(rows["token"]).tolist() == [3, 4, 5, 6]
+    else:
+        assert rows is None
+
+
+# --- what only one model has -----------------------------------------------------
+
+
+def test_without_drafting_k_exaones_node_reports_a_step_a_token(tmp_path, monkeypatch):
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
+    before = counted()
+    attrs = node_attrs(rehearsed(BY_NAME["k-exaone"], draft_tokens=0))
+    assert (attrs["draft_tokens"], attrs["decode_steps"]) == (0, 16)
+    assert (attrs["mtp_drafted"], attrs["mtp_accepted"]) == (0, 0)
+    assert attrs["decode_layer_passes"] == 16 * 5
+    assert attrs["decode_routed_pairs"] == 16 * 4 * 4
+    model = BY_NAME["k-exaone"]
+    assert set(attrs) - BUILD_TALLIES - {"attention"} == set(model.attrs) | model.drawn
+    after = counted()
+    assert after["steps"] - before["steps"] == 16
+    key = ("cdt_lm_layer_passes_total", "decode")
+    assert after[key] - before[key] == 16 * 5
+
+
+@pytest.mark.parametrize("name, kind", [
+    ("deepseek-v2", "DeepSeekV2"), ("ouro", "Ouro"), ("solar-open2", "SolarOpen2")])
+def test_a_model_without_a_draft_module_refuses_to_draft(name, kind, tmp_path, monkeypatch):
+    """`draft_tokens` is an optional input: the committed workflows that do
+    not give it run as before and say 0; anything else is refused by the
+    model's class, by name."""
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
+    assert "draft_tokens" not in by_kind(rehearsed(BY_NAME[name]))["TextGenerate"]
+    with pytest.raises(Exception, match=f"{kind} has no draft module"):
+        GraphExecutor(ExecutionContext()).execute(rehearsed(BY_NAME[name], draft_tokens=1))
+
+
+def test_k_exaones_served_share_holds_2_mb_of_rings_and_8_kb_a_position():
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    lm = create_model("k-exaone-ep8-5l")
+    lm.dtype = jnp.dtype(jnp.bfloat16)
+    counts = [300, 300, 140, 700]
+    attrs = lm.report(8192, 384, 8576, [[2000] * 16] * 4, [[10] * 16] * 5, counts)
+    config = load("benchmark/configs/k-exaone-236b-a23b.json")
+    # two caches that grow (layer 3's and the MTP module's), 4,096 B a position each
+    assert attrs["cache_bytes"] == 8576 * 8192 == 8576 * config["as_run"]["cache_bytes_per_token"]
+    # four rings of 136 entries
+    assert attrs["state_bytes"] == 4 * 136 * 4096 == config["as_run"]["state_bytes"]
+    assert attrs["ring_positions"] == config["as_run"]["ring_positions"] == 136
+    assert (attrs["window_layers"], attrs["full_layers"], attrs["window"]) == (4, 2, 128)
+    assert attrs["decode_layer_passes"] == 300 * 2 * 6 and attrs["decode_steps"] == 300
+    assert (attrs["mtp_drafted"], attrs["mtp_accepted"]) == (300, 140)
+    # each layer's 32,000 held pairs take the rung of 32,768 rows
+    assert attrs["prefill_expert_rows"] == 4 * 32768
+
+
+def test_solars_served_share_holds_13_mb_of_state_and_4_kb_a_position():
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    lm = create_model("solar-open2-ep8-4l")
+    lm.dtype = jnp.dtype(jnp.bfloat16)
+    attrs = lm.report(8192, 256, 8448, [[0]], [[0]])
+    assert attrs["cache_bytes"] == 8448 * 4096
+    # the matrix states float32 whatever the weights' dtype, the tails bfloat16
+    assert attrs["state_bytes"] == 3 * 64 * 128 * 128 * 4 + 3 * 3 * 24576 * 2 == 13_025_280
+    assert (lm.layer_passes, attrs["linear_layers"], attrs["full_layers"]) == (4, 3, 1)
+    assert attrs["prefill_chunks"] == 128
+
+
+def test_the_three_models_with_experts_call_the_one_expert_layer_and_two_the_one_rule():
+    from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, moe, solar_open2
+
+    assert deepseek_v2.expert_layer is moe.expert_layer is solar_open2.expert_layer
+    assert k_exaone.expert_layer is moe.expert_layer
+    assert k_exaone.sigmoid_route is moe.sigmoid_route is solar_open2.sigmoid_route
+    for module in (deepseek_v2, solar_open2, k_exaone):
+        with open(module.__file__, encoding="utf-8") as fh:
+            source = fh.read()
+        assert "ragged_dot(" not in source
+        # the rule's top-k is written once, in moe.py (DeepSeek's grouped rule is its own)
+        assert ("top_k(" in source) == (module is deepseek_v2)
+
+
+def test_solars_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeypatch):
+    """What a TPU does with the prefill's causal call (PR 43), forced here
+    in the Pallas interpreter: the softmax layer's attention in
+    `flash_attention` under its mask, two query heads a key head read where
+    it lies, 16-wide heads folded into the batch; the logits and the KDA
+    states are the XLA route's to what float32 rounding does to a router. The
+    route is the test's to steer: no option of the program chooses it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models import solar_open2
+    from comfyui_distributed_tpu.models.registry import get_config
+    from comfyui_distributed_tpu.ops import attention
+
+    cfg = get_config("tiny-solar-open2")
+    params = solar_open2.init_params(cfg, jax.random.key(3), jnp.float32)
+    ids = jax.random.randint(jax.random.key(4), (640,), 0, cfg.vocab_size)
+    with attention.route_log() as routes:
+        want = solar_open2.prefill(cfg, params, ids, cache_len=672, collect=True)
+    assert routes == ["xla-causal 640x640x16/16 bq256 f32"]
+
+    monkeypatch.setattr(attention, "causal_route", lambda *operands: "flash")
+    kernel = attention.flash_attention
+    monkeypatch.setattr(
+        attention, "flash_attention",
+        lambda *operands, **options: kernel(*operands, **{**options, "interpret": True}))
+    with attention.route_log() as routes:  # another cache length: traced anew
+        got = solar_open2.prefill(cfg, params, ids, cache_len=704, collect=True)
+    assert routes == ["flash-causal 640x640x16/16 g2 bq128 bk640 f32 blocks5/5"]
+    # another order of summation moves a score in its last digit, and a router
+    # over seeded weights then gives a few (token, layer) pairs another expert
+    # (the XLA form in blocks of 128 rows for 256: 5 of 10,240 choices; the
+    # kernel 14, the logits 1.1e-3 and the states 3.0e-3 off by relative L2
+    # norm); a key head read in another's place moves the logits by their own
+    # size
+    assert float(np.mean(np.asarray(got.chosen) != np.asarray(want.chosen))) < 0.005
+    distance = lambda a, b: float(np.linalg.norm(np.asarray(a) - np.asarray(b)) / np.linalg.norm(b))
+    assert distance(got.logits, want.logits) < 1e-2
+    assert distance(got.cache["state"], want.cache["state"]) < 1e-2
+    np.testing.assert_array_equal(  # layer 0 writes them before it attends
+        np.asarray(got.cache["kv"][..., :640, :]), np.asarray(want.cache["kv"][..., :640, :]))
+
+
+# --- the nodes around the language model ---------------------------------------
+
+
+def test_textgenerate_refuses_a_bundle_without_a_language_model():
+    from comfyui_distributed_tpu.graph.nodes_text import TextGenerate
+    from comfyui_distributed_tpu.models import pipeline as pl
+
+    bundle = pl.PipelineBundle(model_name="tiny-unet", unet=None, vae=None, text_encoder=None,
+                               params={}, tokenizer=None)
+    with pytest.raises(ValueError, match="holds none"):
+        TextGenerate().generate(bundle, "a cat", 1)
+
+
+@pytest.mark.parametrize("node, call", [
+    ("KSampler", lambda b: nodes_core.KSampler().sample(
+        b, 1, 2, 7.0, "euler", "karras", None, None, {"samples": None})),
+    ("VAEDecode", lambda b: nodes_core.VAEDecode().decode({"samples": None}, b)),
+    ("CLIPTextEncode", lambda b: nodes_core.CLIPTextEncode().encode("a cat", b)),
+])
+def test_a_node_given_the_language_models_bundle_says_which_part_is_missing(node, call):
+    from comfyui_distributed_tpu.models import pipeline as pl
+
+    bundle = pl.load_pipeline("tiny-deepseek-v2")
+    with pytest.raises(ValueError, match=f"{node} needs a bundle with a .* holds lm"):
+        call(bundle)
+
+
+@pytest.mark.parametrize("given, loaded", [
+    ("ouro-2.6b", "ouro-2.6b"), ("sd15.safetensors", "sd15"), ("tiny-unet", "tiny-unet"),
+    ("v1-5-pruned.ckpt", "v1-5-pruned"),
+])
+def test_the_loader_takes_a_registry_name_with_a_dot_whole(given, loaded, monkeypatch):
+    """`ouro-2.6b` is no file name with the extension `.6b`."""
+    seen = []
+    monkeypatch.setattr(nodes_core, "_get_bundle", lambda context, name: seen.append(name))
+    monkeypatch.setattr(nodes_core, "_annotate_load", lambda bundle: None)
+    nodes_core.CheckpointLoaderSimple().load(given)
+    assert seen == [loaded]

@@ -358,9 +358,10 @@ def test_the_decodes_einsum_form_serves_a_group_of_queries_from_one_key_head(kv_
     keys = jax.random.split(jax.random.key(3), 2)
     q = jax.random.normal(keys[0], (heads, d))
     cache = jax.random.normal(keys[1], (2, 2, kv_heads, positions, d))
-    got = decode_attention.decode_attention_xla(q, cache, (1,), position)
+    valid = decode_attention.position_valid(jnp.asarray([position]), positions)
+    got = decode_attention.decode_attention_xla(q[None], cache, (1,), valid)[0]
     repeated = jnp.repeat(cache, heads // kv_heads, axis=2)
-    want = decode_attention.decode_attention_xla(q, repeated, (1,), position)
+    want = decode_attention.decode_attention_xla(q[None], repeated, (1,), valid)[0]
     assert got.shape == (heads, d)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
