@@ -32,10 +32,13 @@ Drives the main path once, through the entry points an operator uses:
                of the sweep; then the single-query kernel of a language model's decode
                (`ops/decode_attention`) against the einsum form over
                every slot of Ouro's 3.3 GB cache, a call a slot inside
-               one jitted loop.
+               one jitted loop; then a drafting step's delta rule over
+               Ling-3.0-flash's six KDA states, two positions a step,
+               with two slots a layer and a flipped bit against one
+               slot and a select at the step's end.
     experts    one child that holds the chip runs a decode step's two
                grouped products (gate-up, SiLU, down) over the held
-               experts' stacked weights at the three models' decode
+               experts' stacked weights at the four models' decode
                shapes, and DeepSeek's at 8 rows beside its 6: the
                kernel (`ops/expert_matvec`) against `jax.lax.ragged_dot`,
                a step's routing drawn anew inside one jitted loop, both
@@ -850,12 +853,14 @@ REHEARSAL_SHAPES = (
 # at 64 query over 8 key heads; K-EXAONE's window layers the same under a
 # band of 128; Ouro 2,048 tokens at 16 over 16; DeepSeek-V2's MLA 2,048
 # tokens at 128 heads, q and k 192 wide beside a v of 128 and a scale of
-# its own.
+# its own; Ling-3.0-flash's one MLA layer the same widths at 32 heads over
+# 8,192 tokens (PR 45).
 CAUSAL_SHAPES = (
     ("solar / k-exaone full 8192", (1, 8192, 64, 128), 8, 128, None),
     ("k-exaone window 8192", (1, 8192, 64, 128), 8, 128, 128),
     ("ouro 2048", (1, 2048, 16, 128), 16, 128, None),
     ("deepseek-v2 mla 2048", (1, 2048, 128, 192), 128, 128, None),
+    ("ling-flash mla 8192", (1, 8192, 32, 192), 32, 128, None),
 )
 REHEARSAL_CAUSAL_SHAPES = (
     ("toy causal grouped", (1, 1280, 4, 128), 2, 128, None),
@@ -1009,6 +1014,7 @@ def attention_child(rehearsal: bool) -> int:
     for shape in REHEARSAL_CAUSAL_SHAPES if rehearsal else CAUSAL_SHAPES:
         failed += not causal_row(rehearsal, *shape)
     failed += not decode_slot_row(rehearsal)
+    failed += not kda_keep_row(rehearsal)
     return 1 if failed else 0
 
 
@@ -1149,6 +1155,93 @@ def decode_slot_row(rehearsal: bool) -> bool:
     return row["ok"]
 
 
+# A self-speculative step over recurrent layers (`models/ling_flash.py`):
+# (label, KDA layers, heads, head width), Ling-3.0-flash's held group.
+KDA_STATES = ("ling-flash two positions over six KDA states", 6, 32, 128)
+REHEARSAL_KDA_STATES = ("toy two positions over two KDA states", 2, 2, 16)
+
+
+def kda_keep_row(rehearsal: bool) -> bool:
+    """A drafting step's delta rule over every KDA layer's float32 matrix
+    states, two positions a step, the draft kept or dropped by a drawn
+    bit, in the two forms a model could take, many steps inside one
+    jitted loop: `slots` (what `ling_flash.kda_cached` does: two slots a
+    layer in an array of the layer's own, the state after the first
+    position into the one that does not stand, that after the second
+    over the one read, `standing` flips a bit) and `select` (one slot a layer; every layer's
+    pair of states kept to the step's end, then one `where` between
+    them). They have to agree to the digit; the row prints us a step of
+    each, which is what PERF.md §6 (PR 45) sets the choice on."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.kda import kda_step
+    from comfyui_distributed_tpu.models.ling_flash import standing
+
+    label, layers, heads, d = REHEARSAL_KDA_STATES if rehearsal else KDA_STATES
+    steps = 8 if rehearsal else 256
+
+    def two_positions(state, q, k, v, g, beta):
+        out_1, state_1 = kda_step(q[0], k[0], v[0], g[0], beta[0], state)
+        out_2, state_2 = kda_step(q[1], k[1], v[1], g[1], beta[1], state_1)
+        return out_1 + out_2, state_1, state_2
+
+    @jax.jit
+    def slots(states, xs):
+        def body(carry, x):
+            pairs, slot = carry
+            *operands, kept = x
+            outs, written = [], []
+            for layer in range(layers):
+                out, first, second = two_positions(
+                    pairs[layer][slot], *(a[layer] for a in operands))
+                pair = jax.lax.dynamic_update_slice(pairs[layer], second[None], (slot, 0, 0, 0))
+                written.append(
+                    jax.lax.dynamic_update_slice(pair, first[None], (1 - slot, 0, 0, 0)))
+                outs.append(out)
+            return (tuple(written), standing(slot, kept)), jnp.stack(outs)
+        pairs = tuple(jnp.stack([state, jnp.zeros_like(state)]) for state in states)
+        (pairs, slot), outs = jax.lax.scan(body, (pairs, jnp.int32(0)), xs)
+        return jnp.stack([pair[slot] for pair in pairs]), outs
+
+    @jax.jit
+    def select(states, xs):
+        def body(states, x):
+            *operands, kept = x
+            outs, firsts, seconds = zip(*(
+                two_positions(states[layer], *(a[layer] for a in operands))
+                for layer in range(layers)))
+            return jnp.where(kept, jnp.stack(seconds), jnp.stack(firsts)), jnp.stack(outs)
+        return jax.lax.scan(body, states, xs)
+
+    keys = jax.random.split(jax.random.key(layers * 1000 + heads), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    shape = (steps, layers, 2, heads, d)
+    xs = (
+        unit(jax.random.normal(keys[0], shape)) * d ** -0.5, unit(jax.random.normal(keys[1], shape)),
+        jax.random.normal(keys[2], shape),
+        -5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], shape) - 6.0),
+        jax.nn.sigmoid(jax.random.normal(keys[4], shape[:-1])),
+        jax.random.bernoulli(keys[5], 0.5, (steps,)),
+    )
+    states = 0.1 * jax.random.normal(keys[6], (layers, heads, d, d))
+    state_bytes = layers * heads * d * d * 4
+    row = {"shape": label, "layers": layers, "heads": heads, "d": d, "dtype": "float32",
+           "steps": steps, "state_mb": round(state_bytes / 1e6, 2), "ok": True}
+    results = {}
+    for name, fn in (("slots", slots), ("select", select)):
+        (final, outs), first_s, ms = timed(fn, states, xs)
+        results[name] = (np.asarray(final), np.asarray(outs))
+        row[name] = {"first_call_s": round(first_s, 2), "us_a_step": round(1e3 * ms / steps, 2)}
+    for mine, theirs in zip(results["slots"], results["select"]):
+        err = float(np.abs(mine - theirs).max())
+        row["ok"] &= bool(np.isfinite(err)) and err <= 1e-4 * max(1.0, float(np.abs(theirs).max()))
+    row["max_abs_diff"] = round(float(np.abs(results["slots"][0] - results["select"][0]).max()), 7)
+    print(json.dumps(row), flush=True)
+    return row["ok"]
+
+
 # --- the experts child -------------------------------------------------------
 
 # (label, token-expert pairs a step, experts a token, held experts,
@@ -1156,7 +1249,9 @@ def decode_slot_row(rehearsal: bool) -> bool:
 # mixture of experts as its benchmark cell runs it. DeepSeek-V2 a
 # quarter of 160 experts, 6 a token; Solar-Open2 an eighth of 320, 8 a
 # token; K-EXAONE an eighth of 128, 8 a token, two positions a drafting
-# step and one in its MTP module. DeepSeek's shape a second time at 8
+# step and one in its MTP module; Ling-3.0-flash an eighth of 512, 8 a
+# token, the narrowest (2,560 columns, 768-wide experts), two positions a
+# drafting step and one a plain step. DeepSeek's shape a second time at 8
 # rows: at a row count off the sublane tile the compiler gives
 # `ragged_dot` another lowering (PERF.md §6, PR 42).
 EXPERT_SHAPES = (
@@ -1165,6 +1260,8 @@ EXPERT_SHAPES = (
     ("solar-open2 step", 8, 8, 40, 320, 4096, 1280),
     ("k-exaone two positions", 16, 8, 16, 128, 6144, 2048),
     ("k-exaone mtp position", 8, 8, 16, 128, 6144, 2048),
+    ("ling-flash two positions", 16, 8, 64, 512, 2560, 768),
+    ("ling-flash step", 8, 8, 64, 512, 2560, 768),
 )
 REHEARSAL_EXPERT_SHAPES = (
     ("toy step off the sublane tile", 6, 3, 4, 16, 128, 64),
@@ -1245,7 +1342,7 @@ def experts_child(rehearsal: bool) -> int:
         # a row past the step's held pairs is nobody's: the kernel leaves
         # it zero, `ragged_dot` what it likes
         mine = (np.arange(rows)[None, :] < sizes.sum(axis=1)[:, None])[:, :, None]
-        scale = max(1.0, float(np.abs(ref * mine).max()))
+        scale = max(1.0, float(np.abs(np.where(mine, ref, 0.0)).max()))
         for name, grouped in (
             ("kernel", functools.partial(expert_matvec, interpret=rehearsal)),
             ("xla", jax.lax.ragged_dot),
@@ -1258,7 +1355,9 @@ def experts_child(rehearsal: bool) -> int:
                 row["xla_lowering"] = (
                     "masked convolution" if " convolution(" in text else "custom call")
             out, first_s, ms = timed(fn, *operands)
-            err = float(np.abs((np.asarray(out, np.float32) - ref) * mine).max())
+            # picked out, not multiplied by a mask: what `ragged_dot` likes there may be
+            # no number (Ling-3.0-flash's shapes on the chip, PR 45)
+            err = float(np.abs(np.where(mine, np.asarray(out, np.float32) - ref, 0.0)).max())
             row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
             row[name] = {
                 "max_abs_err": round(err, 5), "first_call_s": round(first_s, 2),

@@ -56,10 +56,12 @@ class TextGenerate:
     is a program of its own.
 
     `draft_tokens` 0 decodes one token a step. 1 asks a model that has a
-    draft module (K-EXAONE's multi-token-prediction module) to decode by
-    self-speculation: a step drafts a token, verifies it with the main
-    model and emits one or two, still one program and exactly
-    `max_new_tokens` ids. The text is distributed as without drafting,
+    draft module (K-EXAONE's and Ling-3.0-flash's multi-token-prediction
+    modules) to decode by self-speculation: a step drafts a token,
+    verifies it with the main model and emits one or two, still one
+    program and exactly `max_new_tokens` ids; how a dropped draft is
+    kept out of the model's state (written over, or left in a slot that
+    does not stand) is the model's own. The text is distributed as without drafting,
     but for the same seed the ids differ: the draws are other draws. A
     model without a draft module refuses anything but 0."""
 

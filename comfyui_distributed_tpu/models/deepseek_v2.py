@@ -7,11 +7,15 @@ are read by two forms of one block, and the float32 reference in
 
 - `prefill`: the whole prompt at once. MLA in its *expanded* form (keys
   and values built from the latent, causal attention through
-  `ops.attention.dot_product_attention`), the latent cache written.
+  `ops.attention.causal_attention`), the latent cache written.
 - `decode`: N dependent steps in one program (`lax.fori_loop`), MLA in
   its *absorbed* form (W_uk folded into the query, W_uv applied after
   the weighted sum, attention over the 576-wide latent cache itself),
   the next id sampled on the device from the seed.
+
+The two forms themselves are `models/mla.py`'s (shared with
+`ling_flash.py`); this model's own are its queries (a query latent with
+a norm, YaRN's rotation) and its softmax scale.
 
 The expert layer (`moe.expert_layer`, shared with `solar_open2.py`) is
 told which experts it holds (`parallel.sharding.expert_range` of
@@ -40,8 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import dot_product_attention
 from ..parallel.sharding import expert_range
+from . import mla
 from .lm_common import (
     LanguageModel,
     apply_rope,
@@ -229,50 +233,25 @@ def _queries(cfg, p, x, cos, sin):
     return q_nope, apply_rope(q_rope, cos, sin)
 
 
-def _latents(cfg, p, x, cos, sin):
-    """What the cache holds of x [T, hidden]: the normed latent and the
-    rotated shared rope key side by side, [T, kv_lora + rope]."""
-    down = x @ p["w_dkv"]
-    c_kv = rms_norm(down[:, : cfg.kv_lora_rank], p["kv_norm"], cfg.rms_norm_eps)
-    k_rope = apply_rope(down[:, cfg.kv_lora_rank:], cos, sin)
-    return jnp.concatenate([c_kv, k_rope], axis=-1)
-
-
 def mla_expanded(cfg, p, x, rope):
-    """MLA over a whole sequence, keys and values built from the latent;
-    `rope` is `rope_tables` of its positions. Returns the block's output
-    [T, hidden] and the latents to cache."""
-    cos, sin = rope
-    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
-    latents = _latents(cfg, p, x, cos, sin)
-    c_kv, k_rope = latents[:, : cfg.kv_lora_rank], latents[:, cfg.kv_lora_rank:]
-    k_nope = jnp.einsum("tc,chd->thd", c_kv, p["w_uk"])
-    v = jnp.einsum("tc,chd->thd", c_kv, p["w_uv"])
-    k_rope = jnp.broadcast_to(k_rope[:, None, :], (*k_nope.shape[:2], cfg.qk_rope_head_dim))
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate([k_nope, k_rope], axis=-1)
-    out = dot_product_attention(
-        q[None], k[None], v[None], causal=True, scale=cfg.softmax_scale
-    )[0]
+    """MLA over a whole sequence, keys and values built from the latent
+    (`mla.expanded`); `rope` is `rope_tables` of its positions. Returns
+    the block's output [T, hidden] and the latents to cache."""
+    q_nope, q_rope = _queries(cfg, p, x, *rope)
+    latents = mla.latents(p, x, rope, cfg.rms_norm_eps)
+    out = mla.expanded(q_nope, q_rope, latents, p["w_uk"], p["w_uv"], cfg.softmax_scale)
     return out.reshape(x.shape[0], -1) @ p["w_o"], latents
 
 
 def mla_absorbed(cfg, p, x, rope, cache, valid):
     """MLA for new tokens x [T, hidden] over a latent cache [S, kv_lora +
-    rope] that already holds their own latents: W_uk folded into the
-    query, attention over the latent itself, W_uv after the weighted
-    sum. `valid` [T, S] says which cached positions each token sees."""
-    cos, sin = rope
-    q_nope, q_rope = _queries(cfg, p, x, cos, sin)
-    q_lat = jnp.einsum("thd,chd->thc", q_nope, p["w_uk"])
-    q = jnp.concatenate([q_lat, q_rope], axis=-1)            # [T, heads, 576]
-    scores = cfg.softmax_scale * jnp.einsum(
-        "thc,sc->ths", q, cache, preferred_element_type=jnp.float32
-    )
-    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cache.dtype)
-    o_lat = jnp.einsum("ths,sc->thc", probs, cache[:, : cfg.kv_lora_rank])
-    out = jnp.einsum("thc,chd->thd", o_lat, p["w_uv"])
+    rope] that already holds their own latents (`mla.absorbed`: W_uk
+    folded into the query, attention over the latent itself, W_uv after
+    the weighted sum). `valid` [T, S] says which cached positions each
+    token sees."""
+    q_nope, q_rope = _queries(cfg, p, x, *rope)
+    out = mla.absorbed(
+        q_nope, q_rope, cache, valid, p["w_uk"], p["w_uv"], cfg.softmax_scale)
     return out.reshape(x.shape[0], -1) @ p["w_o"]
 
 
@@ -379,7 +358,7 @@ def decode_step(cfg, params, cache, token, position):
         with jax.named_scope(cfg.layer_name(layer)):
             with jax.named_scope("mla"):
                 x = rms_norm(h, block["attn_norm"], cfg.rms_norm_eps)
-                latent = _latents(cfg, block["attn"], x, *rope)
+                latent = mla.latents(block["attn"], x, rope, cfg.rms_norm_eps)
                 cache = jax.lax.dynamic_update_slice(cache, latent[None], (layer, position, 0))
                 h = h + mla_absorbed(cfg, block["attn"], x, rope, cache[layer], valid)
             out, ids_l, sizes = _feed_forward(

@@ -68,11 +68,13 @@ from .lm_common import (
     head,
     init_from_shapes,
     mlp_shapes,
+    mtp_input,
     nbytes,
     rms_norm,
     rope_tables,
     sample,
     swiglu,
+    verify,
     zeros,
 )
 from .moe import decode_route, expert_layer, report_loads, sigmoid_route
@@ -326,17 +328,6 @@ def _layer(cfg, block, h, window: bool, mixer):
     return h + out, kept, ids, sizes
 
 
-def mtp_input(cfg, params, h, tokens):
-    """u [T, hidden] of the residual streams h [T, hidden] and the
-    tokens that follow each [T]."""
-    p = params["mtp"]
-    both = jnp.concatenate([
-        rms_norm(params["embed"][tokens], p["embed_norm"], cfg.rms_norm_eps),
-        rms_norm(h, p["hidden_norm"], cfg.rms_norm_eps),
-    ], axis=-1)
-    return both @ p["w_eh"]
-
-
 # --- the two programs -----------------------------------------------------
 
 
@@ -434,41 +425,6 @@ def mtp_step(cfg, params, cache, h, tokens, position):
         cfg, block, mtp_input(cfg, params, h, tokens), False,
         lambda p, x: mixer_cached(cfg, p, x, cache, "kv", cfg.full_layers, positions))
     return head(cfg, params, out, params["mtp"]["norm"]), cache, ids, sizes
-
-
-def accept_probability(p, q, draft):
-    """With which probability a draft drawn from q stands for a draw
-    from p: min(1, p(draft) / q(draft))."""
-    return jnp.minimum(1.0, p[draft] / q[draft])
-
-
-def residual(p, q):
-    """What a rejected draft is replaced from: max(p - q, 0) over its
-    sum (p itself where the two are equal and nothing is left)."""
-    left = jnp.maximum(p - q, 0.0)
-    total = left.sum()
-    return jnp.where(total > 0, left / jnp.where(total > 0, total, 1.0), p)
-
-
-def verify(logits, draft_logits, draft, key, temperature):
-    """The lossless rule over the main model's logits [2, vocab] at the
-    last emitted token and at the draft, and the logits the draft was
-    drawn from. Returns (kept, the token after the last emitted one, the
-    token after that, which counts only where the draft was kept). At
-    temperature 0 the draft is kept iff it is the main model's largest."""
-    key_accept, key_again, key_next = jax.random.split(key, 3)
-    safe = jnp.where(temperature > 0, temperature, 1.0)
-    p = jax.nn.softmax(logits[0] / safe)
-    q = jax.nn.softmax(draft_logits / safe)
-    kept = jnp.where(
-        temperature > 0,
-        jax.random.uniform(key_accept) < accept_probability(p, q, draft),
-        draft == jnp.argmax(logits[0]))
-    again = jnp.where(
-        temperature > 0,
-        jax.random.categorical(key_again, jnp.log(residual(p, q))),
-        jnp.argmax(logits[0])).astype(jnp.int32)
-    return kept, jnp.where(kept, draft, again), sample(logits[1], key_next, temperature)
 
 
 def _decode_plain(cfg, params, cache, logits, start, key, temperature, steps, collect):

@@ -1,7 +1,8 @@
 """What the language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`, `k_exaone.py`): the blocks and helpers they are written
-from, the one initialisation rule, sampling on the device, the decode
-loop, and the stand-in tokenizer.
+`solar_open2.py`, `k_exaone.py`, `ling_flash.py`): the blocks and helpers
+they are written from, the one initialisation rule, sampling on the
+device, the rule by which a drafted token is kept or replaced, the
+decode loop, and the stand-in tokenizer.
 
 A bundle's `lm` part is an object with this contract (`LanguageModel`
 below holds what every model's class has alike), which is all that
@@ -54,9 +55,18 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (normed * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def swiglu(x: jax.Array, p: dict) -> jax.Array:
+def clamped_silu_product(gate: jax.Array, up: jax.Array, limit: float = 0.0) -> jax.Array:
+    """silu(gate) * up, a SwiGLU's middle; under a `limit` > 0 the gate
+    is held to at most it and up to within it either way first (0: no
+    clamp)."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def swiglu(x: jax.Array, p: dict, limit: float = 0.0) -> jax.Array:
     gate, up = jnp.split(x @ p["w_gate_up"], 2, axis=-1)
-    return (jax.nn.silu(gate) * up) @ p["w_down"]
+    return clamped_silu_product(gate, up, limit) @ p["w_down"]
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -93,6 +103,59 @@ def sample(logits, key, temperature):
     traced scalar, so every value runs the one program."""
     drawn = jax.random.categorical(key, logits / jnp.where(temperature > 0, temperature, 1.0))
     return jnp.where(temperature > 0, drawn, jnp.argmax(logits)).astype(jnp.int32)
+
+
+# --- drafting: the lossless rule of a self-speculative step ----------------
+
+
+def mtp_input(cfg, params, h, tokens):
+    """What a multi-token-prediction module's layer takes (DeepSeek-V3's
+    form, which K-EXAONE's and Ling-3.0-flash's modules share): u [T,
+    hidden] = W_eh [rms_e(E[x_{i+1}]) ; rms_h(h_i)] of the residual
+    streams h [T, hidden] after the last main layer and the tokens that
+    follow each [T]; `params["mtp"]` holds `embed_norm`, `hidden_norm`
+    and `w_eh`."""
+    p = params["mtp"]
+    both = jnp.concatenate([
+        rms_norm(params["embed"][tokens], p["embed_norm"], cfg.rms_norm_eps),
+        rms_norm(h, p["hidden_norm"], cfg.rms_norm_eps),
+    ], axis=-1)
+    return both @ p["w_eh"]
+
+
+def accept_probability(p, q, draft):
+    """With which probability a draft drawn from q stands for a draw
+    from p: min(1, p(draft) / q(draft))."""
+    return jnp.minimum(1.0, p[draft] / q[draft])
+
+
+def residual(p, q):
+    """What a rejected draft is replaced from: max(p - q, 0) over its
+    sum (p itself where the two are equal and nothing is left)."""
+    left = jnp.maximum(p - q, 0.0)
+    total = left.sum()
+    return jnp.where(total > 0, left / jnp.where(total > 0, total, 1.0), p)
+
+
+def verify(logits, draft_logits, draft, key, temperature):
+    """The lossless rule over the main model's logits [2, vocab] at the
+    last emitted token and at the draft, and the logits the draft was
+    drawn from. Returns (kept, the token after the last emitted one, the
+    token after that, which counts only where the draft was kept). At
+    temperature 0 the draft is kept iff it is the main model's largest."""
+    key_accept, key_again, key_next = jax.random.split(key, 3)
+    safe = jnp.where(temperature > 0, temperature, 1.0)
+    p = jax.nn.softmax(logits[0] / safe)
+    q = jax.nn.softmax(draft_logits / safe)
+    kept = jnp.where(
+        temperature > 0,
+        jax.random.uniform(key_accept) < accept_probability(p, q, draft),
+        draft == jnp.argmax(logits[0]))
+    again = jnp.where(
+        temperature > 0,
+        jax.random.categorical(key_again, jnp.log(residual(p, q))),
+        jnp.argmax(logits[0])).astype(jnp.int32)
+    return kept, jnp.where(kept, draft, again), sample(logits[1], key_next, temperature)
 
 
 # --- a request's state and the decode loop --------------------------------

@@ -1,0 +1,64 @@
+"""Multi-head latent attention's two forms, as the models with such
+layers share them (`deepseek_v2.py`, `ling_flash.py`), over a head's
+queries in two parts (`q_nope` [T, heads, nope], and `q_rope` [T, heads,
+rope], rotated), the latents a cache holds ([S, rank + rope]: the normed
+latent c and the one rotated rope key r of all heads, side by side) and
+the two halves of the up-projection, `w_uk` [rank, heads, nope] and
+`w_uv` [rank, heads, v]:
+
+    k_nope = W_uk c,  v = W_uv c,  score_ij = scale (q_nope_i . k_nope_j + q_rope_i . r_j)
+
+`latents` makes what the cache holds; `expanded` builds every key and value from the latents (a whole
+sequence, causal: a prefill); `absorbed` folds W_uk into the query,
+attends over the latents themselves and applies W_uv after the weighted
+sum (a decode step's new positions over a cache). Both return the
+heads' outputs [T, heads, v]. How the queries are made of the input
+(a query latent or none, which rotation), the softmax's scale, and what
+follows the heads' outputs (a gate, the projection) are each model's own.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import causal_attention
+from .lm_common import apply_rope, rms_norm
+
+
+def latents(p, x, rope, eps: float):
+    """What the cache holds of x [T, hidden]: the normed latent (`w_dkv`'s
+    first columns under `kv_norm`) and the rope key, rotated by `rope`
+    (cos, sin of the positions), side by side, [T, rank + rope]."""
+    rank = p["kv_norm"].shape[0]
+    down = x @ p["w_dkv"]
+    c_kv = rms_norm(down[:, :rank], p["kv_norm"], eps)
+    return jnp.concatenate([c_kv, apply_rope(down[:, rank:], *rope)], axis=-1)
+
+
+def expanded(q_nope, q_rope, latents, w_uk, w_uv, scale: float):
+    """Causal attention of a whole sequence's queries over the keys and
+    values built from its own `latents` [T, rank + rope]."""
+    rank = w_uk.shape[0]
+    c_kv, k_rope = latents[:, :rank], latents[:, rank:]
+    k_nope = jnp.einsum("tc,chd->thd", c_kv, w_uk)
+    v = jnp.einsum("tc,chd->thd", c_kv, w_uv)
+    k_rope = jnp.broadcast_to(k_rope[:, None, :], (*k_nope.shape[:2], k_rope.shape[-1]))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([k_nope, k_rope], axis=-1)
+    return causal_attention(q[None], k[None], v[None], scale=scale)[0]
+
+
+def absorbed(q_nope, q_rope, cache, valid, w_uk, w_uv, scale: float):
+    """New positions' queries over a latent cache [S, rank + rope] that
+    already holds their own latents; `valid` [T, S] says which cached
+    positions each sees. Scores and softmax float32, the probabilities
+    rounded to the cache's dtype."""
+    rank = w_uk.shape[0]
+    q_lat = jnp.einsum("thd,chd->thc", q_nope, w_uk)
+    q = jnp.concatenate([q_lat, q_rope], axis=-1)            # [T, heads, rank + rope]
+    scores = scale * jnp.einsum("thc,sc->ths", q, cache, preferred_element_type=jnp.float32)
+    scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cache.dtype)
+    o_lat = jnp.einsum("ths,sc->thc", probs, cache[:, :rank])
+    return jnp.einsum("thc,chd->thd", o_lat, w_uv)

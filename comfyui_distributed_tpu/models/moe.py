@@ -1,6 +1,7 @@
 """The expert layer of one chip, as the language models with a mixture
-of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`),
-and the routing rule of those that score by sigmoids (`sigmoid_route`).
+of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`,
+`ling_flash.py`), and the routing rule of those that score by sigmoids
+(`sigmoid_route`, with or without groups chosen first).
 
 The layer is told which routed experts it holds (`held`, a contiguous
 run: `parallel.sharding.expert_range` of the chip's rank), routes every
@@ -38,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.expert_matvec import expert_matvec, expert_matvec_route
-from .lm_common import swiglu
+from .lm_common import clamped_silu_product, swiglu
 
 
 # A rung of the ladder below the top is a whole number of these rows.
@@ -70,14 +71,25 @@ def rung_index(ladder: tuple[int, ...], pairs_held):
 
 
 def sigmoid_route(logits: jax.Array, bias: jax.Array, k: int, scale: float = 1.0,
-                  renormalise: bool = True):
+                  renormalise: bool = True, n_group: int = 1, topk_group: int = 1):
     """Over float32 router logits [T, experts]: scores are their
     sigmoids, the `k` largest of score + `bias` (a selection bias an
     expert) are chosen, ties to the lower index, and the weights are the
     chosen scores, without the bias, over their sum (`renormalise`)
-    times `scale`. Returns (ids [T, k], weights [T, k])."""
+    times `scale`. With `n_group` > 1 the experts are that many runs of
+    equal length and groups are chosen first: a group's score is the sum
+    of its two largest score + bias, the `topk_group` best groups stay
+    (ties to the lower index), and the `k` are chosen among their
+    experts. Returns (ids [T, k], weights [T, k])."""
     scores = jax.nn.sigmoid(logits)
-    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    biased = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        tokens, experts = biased.shape
+        groups = biased.reshape(tokens, n_group, experts // n_group)
+        _, best = jax.lax.top_k(jax.lax.top_k(groups, 2)[0].sum(axis=-1), topk_group)
+        stays = jnp.zeros((tokens, n_group), bool).at[jnp.arange(tokens)[:, None], best].set(True)
+        biased = jnp.where(stays[:, :, None], groups, -jnp.inf).reshape(tokens, experts)
+    _, ids = jax.lax.top_k(biased, k)
     weights = jnp.take_along_axis(scores, ids, axis=-1)
     if renormalise:
         weights = weights / weights.sum(axis=-1, keepdims=True)
@@ -100,11 +112,15 @@ def decode_route(rows: int, hidden: int, width: int, dtype) -> str:
     return "kernel" if routes == {"kernel"} else "xla"
 
 
-def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
+def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
+                 limit: float = 0.0, shared_limit: float = 0.0):
     """x [T, hidden] through the layer. `route(logits)` takes the
     router's float32 logits [T, experts] and returns (ids [T, k],
-    weights [T, k] float32). Returns (output, chosen ids [T, k], pairs
-    on each held expert [held])."""
+    weights [T, k] float32). `limit` and `shared_limit` clamp the routed
+    experts' and the shared expert's SwiGLU (`lm_common.
+    clamped_silu_product`; 0: none), on either route of the grouped
+    products. Returns (output, chosen ids [T, k], pairs on each held
+    expert [held])."""
     with jax.named_scope("router"):
         logits = jnp.dot(
             x.astype(jnp.float32), p["w_g"].astype(jnp.float32),
@@ -135,7 +151,8 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
             token = top // k
             rows = x[token]
             gate, up = jnp.split(grouped(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1)
-            out = grouped(jax.nn.silu(gate) * up, p["experts"]["w_down"], sizes)
+            out = grouped(
+                clamped_silu_product(gate, up, limit), p["experts"]["w_down"], sizes)
             # rows past the last segment are absent experts' pairs: weight 0
             out = jnp.where(here[top][:, None], out, 0).astype(jnp.float32)
             out = out * weights.reshape(-1)[top][:, None]
@@ -159,7 +176,7 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable):
             routed = jax.lax.switch(
                 rung_index(ladder, sizes.sum()), [partial(over, rows_n) for rows_n in ladder])
     with jax.named_scope("shared"):
-        shared = swiglu(x, p["shared"])
+        shared = swiglu(x, p["shared"], shared_limit)
     return shared + routed.astype(x.dtype), ids, sizes
 
 

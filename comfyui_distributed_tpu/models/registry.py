@@ -21,6 +21,7 @@ from .mmdit import MMDiT, MMDiTConfig
 from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
 from .k_exaone import KExaone, KExaoneConfig
+from .ling_flash import LingFlash, LingFlashConfig
 from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .t5_encoder import T5Encoder, T5EncoderConfig
 from .text_encoder import TextEncoder, TextEncoderConfig
@@ -544,6 +545,36 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             vocab_shards=8,
         ),
     },
+    # Ling-3.0-flash as one chip's share of an eight-chip host, every width
+    # as published: published layers 1-7 (the dense layer 1, then one whole
+    # group of six at its 5 KDA : 1 MLA), the MTP module, experts 0-63 of
+    # 512 (rank 0 of 8: routing group 0 whole), the first eighth of the
+    # vocabulary (the benchmark's ling-3.0-flash configuration says what
+    # the cut stands for)
+    "ling-flash-ep8-7l": {
+        "family": "lm",
+        "config": LingFlashConfig(
+            num_hidden_layers=7, first_layer=1, ep_size=8, ep_rank=0, vocab_shards=8,
+        ),
+    },
+    # every mechanism at a size for the CPU: the same seven layers, 4 heads
+    # of 16, a latent of 24 + 8, chunks of 32, 32 experts in 8 groups (4
+    # groups and 4 experts a token) of which rank 0 of 8 holds group 0, the
+    # MTP module, and limits low enough that the clamps act (routed 0.5 in
+    # layers 5-7, shared 0.75 in 4-5 and 1 in 6-7)
+    "tiny-ling-flash": {
+        "family": "lm",
+        "config": LingFlashConfig(
+            hidden_size=64, num_hidden_layers=7, first_layer=1, num_attention_heads=4,
+            head_dim=16, kda_chunk=32, kv_lora_rank=24, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, intermediate_size=160,
+            moe_intermediate_size=32, moe_shared_expert_intermediate_size=32,
+            num_experts=32, num_experts_per_tok=4, vocab_size=4096,
+            expert_swiglu_limit_list=(0, 0, 0, 0, 0, 0.5, 0.5, 0.5),
+            share_expert_swiglu_limit_list=(0, 0, 0, 0, 0.75, 0.75, 1.0, 1.0),
+            ep_size=8, ep_rank=0, vocab_shards=8,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -600,6 +631,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     OuroConfig: Ouro,
     SolarOpen2Config: SolarOpen2,
     KExaoneConfig: KExaone,
+    LingFlashConfig: LingFlash,
 }
 
 

@@ -16,11 +16,10 @@ KDA, a head (H heads of d_k = d_v = d), x the normed input:
     S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T,   o_t = S_t^T q_t
     y_t = W_o [rms_head(o_t) * sigmoid(W_g2 W_g1 x)]
 
-in two forms that must agree: `kda_chunked` (the prefill: `lax.scan` over
-chunks of `kda_chunk` tokens carrying S, a unit-triangular solve inside
-each chunk) and `kda_step` (the decode: the recurrence itself). Gates,
-cumulative sums, the solve and S are float32; the large products take
-their operands in the storage dtype and accumulate in float32.
+in the two forms of `models/kda.py` (shared with `ling_flash.py`), which
+must agree: `kda_chunked` (the prefill, by chunks of `kda_chunk` tokens)
+and `kda_step` (the decode: the recurrence itself). The gates above are
+this model's own.
 
 A request's state is therefore a tree of three kinds (`state_shapes`):
 `kv` [full layers, 2, key heads, positions, d], which grows with the
@@ -53,6 +52,7 @@ import jax.numpy as jnp
 from ..ops.attention import dot_product_attention
 from ..ops.decode_attention import attend_xla, position_valid
 from ..parallel.sharding import expert_range
+from .kda import KDA_SUBCHUNK, conv_qkv, gated_output, kda_chunked, kda_step
 from .lm_common import (
     LanguageModel,
     count_params,
@@ -65,11 +65,6 @@ from .lm_common import (
     zeros,
 )
 from .moe import decode_route, expert_layer, report_loads, sigmoid_route
-
-# Rows of a chunk whose pairwise decays are formed pair by pair
-# (`decay_products`); between such blocks they go through one product.
-KDA_SUBCHUNK = 16
-L2_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -293,10 +288,6 @@ def gqa_cached(cfg, p, x, kv, index, position):
 # --- KDA ------------------------------------------------------------------
 
 
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
-
-
 def kda_inputs(cfg, p, x, tail):
     """What the delta rule takes of x [T, hidden] (normed), `tail`
     [kernel - 1, 3 H d] the convolutions' inputs of the tokens before:
@@ -304,14 +295,8 @@ def kda_inputs(cfg, p, x, tail):
     d] in x's dtype, the log-decay g [T, H, d] (< 0) and beta [T, H],
     float32, and the new tail. Convolution, SiLU and norms float32."""
     tokens, heads, d = x.shape[0], cfg.linear_num_heads, cfg.linear_head_dim
-    kernel = cfg.short_conv_kernel_size
     with jax.named_scope("conv"):
-        window = jnp.concatenate([tail, (x @ p["w_qkv"]).astype(tail.dtype)], axis=0)
-        filters = p["conv"].astype(jnp.float32)
-        mixed = sum(
-            window[i:i + tokens].astype(jnp.float32) * filters[i] for i in range(kernel))
-        q, k, v = jnp.split(jax.nn.silu(mixed).reshape(tokens, 3 * heads, d), 3, axis=1)
-        q, k, v = (a.astype(x.dtype) for a in (_l2norm(q) * d ** -0.5, _l2norm(k), v))
+        q, k, v, window = conv_qkv(x @ p["w_qkv"], p["conv"], tail, heads, d)
     with jax.named_scope("gates"):
         def low_rank(first, second):
             return jnp.dot(x @ p[first], p[second], preferred_element_type=jnp.float32)
@@ -325,157 +310,13 @@ def kda_inputs(cfg, p, x, tail):
     return q, k, v, g, beta, gate, window[tokens:]
 
 
-def kda_output(cfg, p, o, gate):
-    """y [T, hidden] of the rule's outputs o [T, H, d] float32: each
-    head normed over its d channels, gated, projected."""
-    normed = rms_norm(o, p["o_norm"], cfg.rms_norm_eps) * gate.astype(jnp.float32)
-    return normed.astype(gate.dtype).reshape(o.shape[0], -1) @ p["w_o"]
-
-
-def decay_products(x, k, decay, sub: int = KDA_SUBCHUNK):
-    """M[..., i, j] = sum_c x[..., i, c] k[..., j, c] exp(G[..., i, c] -
-    G[..., j, c]) for j <= i, 0 above the diagonal; k and `decay` (G:
-    cumulative log-decays along the row axis, never increasing) are
-    [..., C, c] float32, x the same or with further leading axes. Every
-    ratio of decays is the exponential of a difference that is <= 0,
-    never exp(-G_j) alone: within a block of `sub` rows the differences
-    are formed pair by pair; a row block's products with the columns of
-    earlier blocks go through the decay at the block's first row,
-    exp(G_i - G_first) exp(G_first - G_j), both factors at most one."""
-    size, width = k.shape[-2:]
-    sub = min(sub, size)
-    blocks = size // sub
-
-    def blocked(a):
-        return a.reshape(*a.shape[:-2], blocks, sub, width)
-
-    xb, kb, gb = blocked(x), blocked(k), blocked(decay)
-    # the blocks on the diagonal
-    lower = jnp.tril(jnp.ones((sub, sub), bool))
-    pairs = jnp.exp(jnp.where(
-        lower[:, :, None], gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
-    on = jnp.sum(xb[..., :, None, :] * (kb[..., None, :, :] * pairs), axis=-1)
-    if blocks == 1:
-        return on.reshape(*x.shape[:-2], size, size)
-    # the blocks below it
-    first = gb[..., :1, :]                                          # [..., blocks, 1, c]
-    rows = xb * jnp.exp(gb - first)
-    columns = k[..., None, :, :] * jnp.exp(
-        jnp.minimum(first - decay[..., None, :, :], 0.0))           # [..., blocks, C, c]
-    below = jnp.einsum("...nic,...njc->...nij", rows, columns,
-                       precision=jax.lax.Precision.HIGHEST)          # [..., blocks, sub, C]
-    earlier = jnp.arange(size)[None, :] // sub < jnp.arange(blocks)[:, None]
-    below = jnp.where(earlier[:, None, :], below, 0.0)
-    below = below.reshape(*x.shape[:-2], blocks, sub, blocks, sub)
-    here = jnp.eye(blocks, dtype=bool)[:, None, :, None]
-    return jnp.where(here, on[..., :, :, None, :], below).reshape(*x.shape[:-2], size, size)
-
-
-def unit_lower_solve(a, rhs, sub: int = KDA_SUBCHUNK):
-    """X with (I + a) X = rhs, for a [..., C, C] strictly lower-
-    triangular and rhs [..., C, n], float32: forward substitution by
-    blocks of `sub` rows, a block's own (I + D)^-1 as the finite product
-    (I - D)(I + D^2)(I + D^4)... that a strictly triangular D allows (D
-    to the power `sub` is zero), so the whole is a few small products
-    and no loop over rows."""
-    size = a.shape[-1]
-    sub = min(sub, size)
-    product = partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
-    eye, solved = jnp.eye(sub, dtype=a.dtype), []
-    for start in range(0, size, sub):
-        rows = slice(start, start + sub)
-        power = a[..., rows, rows]
-        inverse = eye - power
-        for _ in range(max(sub - 1, 1).bit_length() - 1):
-            power = product(power, power)
-            inverse = product(inverse, eye + power)
-        left = rhs[..., rows, :]
-        if start:  # less what the rows above have already settled
-            left = left - product(a[..., rows, :start], jnp.concatenate(solved, axis=-2))
-        solved.append(product(inverse, left))
-    return jnp.concatenate(solved, axis=-2)
-
-
-# Chunks whose terms `kda_chunked` forms at once: the pairwise decays of
-# one are 34 MB at the published sizes (64 heads x 4 blocks x 16 x 16 x
-# 128 float32).
-CHUNKS_AT_ONCE = 8
-
-
-def kda_chunked(q, k, v, g, beta, state, chunk: int):
-    """The delta rule over a whole sequence, a chunk at a time: q, k, v
-    [T, H, d] in the storage dtype, g [T, H, d] and beta [T, H] float32
-    (`kda_inputs`), `state` [H, d, d] float32 before the first token.
-    With G the cumulative g inside a chunk and A_ij = beta_i sum_c k_ic
-    k_jc exp(G_ic - G_jc) (j < i):
-
-        (I + A) [W | U0] = diag(beta) [k exp(G) | v]     (unit lower-triangular)
-        U = U0 - W S0                                    (what each token writes)
-        o_i = (q_i exp(G_i)) S0 + sum_{j<=i} (sum_c q_ic k_jc exp(G_ic - G_jc)) u_j
-        S_C = exp(G_C) S0 + sum_j (k_j exp(G_C - G_j)) u_j^T
-
-    What does not read S (`terms`: float32) is formed first, `CHUNKS_AT_
-    ONCE` chunks at a time; the scan then carries S through four products
-    a chunk, their operands in the storage dtype and their sums float32.
-    A last chunk that is short is filled with tokens that change nothing
-    (g 0, beta 0). Returns (o [T, H, d] float32, the state after the last
-    token)."""
-    tokens, heads, d = q.shape
-    dtype = q.dtype
-    count = -(-tokens // chunk)
-    pad = count * chunk - tokens
-
-    def chunks(a):  # [T, H, ...] -> [chunks, H, chunk, ...]
-        a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        return jnp.moveaxis(a.reshape(count, chunk, *a.shape[1:]), 1, 2)
-
-    strictly = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-
-    def terms(xs):
-        q, k, v, g, beta = xs
-        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-        decay = jnp.cumsum(g, axis=1)                                # G, [H, C, d]
-        kk, qk = decay_products(jnp.stack([k, q]), k, decay)
-        into = jnp.exp(decay)                                        # from the chunk's start
-        solved = unit_lower_solve(
-            jnp.where(strictly, beta[..., None] * kk, 0.0),
-            beta[..., None] * jnp.concatenate([k * into, v], axis=-1))
-        out_of = jnp.exp(decay[:, -1:, :] - decay)                   # to the chunk's end
-        return (solved[..., :d].astype(dtype), solved[..., d:], (q * into).astype(dtype),
-                qk.astype(dtype), (k * out_of).astype(dtype), into[:, -1, :])
-
-    def one_chunk(state, xs):
-        w, u0, q_in, qk, k_out, end = xs
-        held = state.astype(dtype)
-        u = u0 - jnp.einsum("hik,hkv->hiv", w, held, preferred_element_type=jnp.float32)
-        o = jnp.einsum("hik,hkv->hiv", q_in, held, preferred_element_type=jnp.float32) + (
-            jnp.einsum("hij,hjv->hiv", qk, u.astype(dtype), preferred_element_type=jnp.float32))
-        state = end[:, :, None] * state + jnp.einsum(
-            "hjk,hjv->hkv", k_out, u.astype(dtype), preferred_element_type=jnp.float32)
-        return state, o
-
-    xs = jax.lax.map(terms, tuple(map(chunks, (q, k, v, g, beta))), batch_size=CHUNKS_AT_ONCE)
-    state, o = jax.lax.scan(one_chunk, state, xs)                    # o [chunks, H, C, d]
-    return jnp.moveaxis(o, 1, 2).reshape(count * chunk, heads, d)[:tokens], state
-
-
-def kda_step(q, k, v, g, beta, state):
-    """The recurrence itself, one token: q, k, v, g [H, d], beta [H],
-    `state` [H, d, d], all float32. Returns (o [H, d], the state)."""
-    read = partial(jnp.einsum, "hk,hkv->hv", precision=jax.lax.Precision.HIGHEST)
-    state = jnp.exp(g)[:, :, None] * state
-    u = beta[:, None] * (v - read(k, state))
-    state = state + k[:, :, None] * u[:, None, :]
-    return read(q, state), state
-
-
 def kda_whole(cfg, p, x, tail, state):
     """A KDA mixer over a whole sequence x [T, hidden] from `tail` and
     `state` (the prefill's form). Returns (output, tail, state)."""
     q, k, v, g, beta, gate, tail = kda_inputs(cfg, p, x, tail)
     with jax.named_scope("delta"):
         o, state = kda_chunked(q, k, v, g, beta, state, cfg.kda_chunk)
-    return kda_output(cfg, p, o, gate), tail, state
+    return gated_output(o, gate, p["o_norm"], p["w_o"], cfg.rms_norm_eps), tail, state
 
 
 def kda_cached(cfg, p, x, tail, state):
@@ -484,7 +325,7 @@ def kda_cached(cfg, p, x, tail, state):
     with jax.named_scope("delta"):
         q, k, v = (a[0].astype(jnp.float32) for a in (q, k, v))
         o, state = kda_step(q, k, v, g[0], beta[0], state)
-    return kda_output(cfg, p, o[None], gate), tail, state
+    return gated_output(o[None], gate, p["o_norm"], p["w_o"], cfg.rms_norm_eps), tail, state
 
 
 # --- a layer, in either form ----------------------------------------------

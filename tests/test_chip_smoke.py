@@ -182,6 +182,42 @@ def test_the_attention_leg_times_the_causal_calls_the_four_prefills_make(
     assert tokens == 1 + len(text.encode("utf-8"))  # the byte tokenizer's, after its BOS
 
 
+def test_the_legs_have_the_fifth_models_rows_at_its_own_numbers(smoke):
+    """Ling-3.0-flash's rows (PR 45): the 2,560-wide `expert_matvec` shapes
+    of a drafting and of a plain step, the 32-head MLA row at the cell's
+    prompt, and the two-position step over its six KDA layers' states."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("ling-flash-ep8-7l")
+    experts = {label: tuple(rest) for label, *rest in smoke.EXPERT_SHAPES}
+    per_step = (cfg.num_experts_per_tok, len(cfg.held_experts), cfg.num_experts,
+                cfg.hidden_size, cfg.moe_intermediate_size)
+    assert experts["ling-flash two positions"] == (2 * cfg.num_experts_per_tok, *per_step)
+    assert experts["ling-flash step"] == (cfg.num_experts_per_tok, *per_step)
+    causal = {row[0]: row[1:] for row in smoke.CAUSAL_SHAPES}
+    (_, tokens, heads, width), kv_heads, v_width, band = causal["ling-flash mla 8192"]
+    assert (heads, width, kv_heads, v_width, band) == (
+        cfg.num_attention_heads, cfg.qk_head_dim, cfg.num_attention_heads, cfg.v_head_dim, None)
+    with open(os.path.join(REPO_ROOT, "workflows", "rewrite-txt2img-ling-flash.json")) as fh:
+        (text,) = [node["inputs"]["text"] for node in json.load(fh).values()
+                   if node["class_type"] == "TextGenerate"]
+    assert tokens == 1 + len(text.encode("utf-8"))
+    assert smoke.KDA_STATES[1:] == (cfg.kda_layers, cfg.num_attention_heads, cfg.head_dim)
+
+
+def test_the_kda_row_agrees_with_itself_in_both_forms(smoke, capsys):
+    """Two slots and a flipped bit against one slot and a select, at the
+    rehearsal's toy size on the CPU: the same states, the same outputs."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("the rehearsal's row is the CPU's")
+    assert smoke.kda_keep_row(True)
+    (row,) = _result_lines(capsys.readouterr().out)
+    assert row["ok"] and row["max_abs_diff"] < 1e-5
+    assert set(row) >= {"slots", "select", "state_mb", "steps"}
+
+
 def test_a_steps_routing_is_k_distinct_experts_a_token(smoke):
     sizes = smoke.step_sizes(7, steps=200, rows=16, k=8, held=16, experts=128)
     assert sizes.shape == (200, 16) and sizes.max() <= 2  # two tokens: at most two rows an expert

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, moe, solar_open2
+from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, ling_flash, moe, solar_open2
 from comfyui_distributed_tpu.models.registry import get_config
 from comfyui_distributed_tpu.ops import expert_matvec as em
 
@@ -153,7 +153,7 @@ def layer_of(name: str):
     its tiny configuration at widths the kernel tiles (128, 64)."""
     module, tokens = {
         "tiny-deepseek-v2": (deepseek_v2, 1), "tiny-solar-open2": (solar_open2, 1),
-        "tiny-k-exaone": (k_exaone, 2),
+        "tiny-k-exaone": (k_exaone, 2), "tiny-ling-flash": (ling_flash, 2),
     }[name]
     cfg = dataclasses.replace(get_config(name), hidden_size=128, moe_intermediate_size=64)
     block = next(
@@ -161,10 +161,13 @@ def layer_of(name: str):
         if "moe" in b)
     if module is k_exaone:
         return (lambda x: k_exaone._feed_forward(cfg, block, x)), tokens
+    if module is ling_flash:  # published layer 2: grouped routing, no clamp
+        return (lambda x: ling_flash._feed_forward(cfg, block, x, 2)), tokens
     return (lambda x: module.moe(cfg, block["moe"], x)), tokens
 
 
-@pytest.mark.parametrize("name", ["tiny-deepseek-v2", "tiny-solar-open2", "tiny-k-exaone"])
+@pytest.mark.parametrize(
+    "name", ["tiny-deepseek-v2", "tiny-solar-open2", "tiny-k-exaone", "tiny-ling-flash"])
 def test_a_models_decode_step_through_the_kernel_is_the_step_through_ragged_dot(
         name, monkeypatch):
     layer, tokens = layer_of(name)
@@ -186,7 +189,8 @@ def test_a_models_decode_step_through_the_kernel_is_the_step_through_ragged_dot(
 
 
 @pytest.mark.parametrize("name,prompt", [
-    ("tiny-deepseek-v2", 512), ("tiny-solar-open2", 512), ("tiny-k-exaone", 512)])
+    ("tiny-deepseek-v2", 512), ("tiny-solar-open2", 512), ("tiny-k-exaone", 512),
+    ("tiny-ling-flash", 512)])
 def test_a_prefill_never_reaches_the_kernel(name, prompt, monkeypatch):
     """A ladder of several rungs is `ragged_dot` under `lax.switch`, as
     it was, on a backend that would route a decode step to the kernel."""

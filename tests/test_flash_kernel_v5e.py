@@ -107,6 +107,7 @@ CAUSAL_ENTRIES = [
     "flash-causal 8192x8192x128/128 w128 g8 bq512 bk512 bf16 inplace blocks31/256",
     "flash-causal 2048x2048x128/128 g1 bq512 bk1024 bf16 inplace blocks6/8",
     "flash-causal 2048x2048x192/128 g1 bq512 bk1024 bf16 inplace blocks6/8",
+    "flash-causal 8192x8192x192/128 g1 bq512 bk1024 bf16 inplace blocks72/128",
 ]
 
 
@@ -326,6 +327,39 @@ def test_k_exaone_drafting_decode_carries_rings_and_caches_in_place(one_chip):
     assert memory.alias_size_in_bytes >= 8576 * 8192 + 4 * 136 * 4096
     text = compiled.as_text()
     assert " while(" in text and "conditional(" not in text  # one rung a step: no branch
+
+
+def test_ling_flash_drafting_decode_keeps_or_drops_a_draft_without_a_copy_of_its_state(
+        one_chip, monkeypatch):
+    """Ling-3.0-flash's self-speculative decode at the served share's sizes
+    (1,024 ids over 9,216 positions), routed as a TPU routes it: a `while`
+    loop that carries the donated tree of two latent caches (21.2 MB) and
+    six KDA layers' states and tails in two slots each (26.05 MB); a
+    step's two grouped products a sparse layer and the MTP module's in
+    the `expert_matvec` kernel (14 calls), and what the loop needs beside
+    the tree stays under the size of the tree."""
+    from comfyui_distributed_tpu.models import ling_flash
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("ling-flash-ep8-7l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: ling_flash.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    state = jax.tree.map(place, ling_flash.state_shapes(cfg, 9216, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    compiled = ling_flash.decode.lower(
+        cfg, params, state,
+        jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+        scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+        scalar(jnp.float32), steps=1000, draft_tokens=1,  # a count of this test's own
+    ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 44 * 2**20
+    assert memory.alias_size_in_bytes >= 9216 * 2304 + 26_050_560
+    text = compiled.as_text()
+    assert " while(" in text and "conditional(" not in text  # one rung a step: no branch
+    assert text.count('custom_call_target="tpu_custom_call"') == 14
 
 
 @pytest.mark.parametrize("label,rows,k,held,experts,hidden,width", chip_smoke.EXPERT_SHAPES)

@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from comfyui_distributed_tpu.models import k_exaone as ke
+from comfyui_distributed_tpu.models import lm_common
 from comfyui_distributed_tpu.models import moe as moe_layer
 from comfyui_distributed_tpu.models.lm_common import rms_norm, swiglu
 from comfyui_distributed_tpu.models.registry import create_model, get_config
@@ -374,20 +375,20 @@ def test_the_rule_emits_the_main_models_distribution_exactly():
     keys = jax.random.split(jax.random.key(4), 2)
     p = jax.nn.softmax(2.0 * jax.random.normal(keys[0], (11,)))
     q = jax.nn.softmax(2.0 * jax.random.normal(keys[1], (11,)))
-    left = ke.residual(p, q)
+    left = lm_common.residual(p, q)
     emitted = jnp.zeros_like(p)
     for draft in range(11):
-        a = ke.accept_probability(p, q, draft)
+        a = lm_common.accept_probability(p, q, draft)
         emitted = emitted + q[draft] * (a * jax.nn.one_hot(draft, 11) + (1.0 - a) * left)
     np.testing.assert_allclose(np.asarray(emitted), np.asarray(p), atol=1e-6)
     accept, ref_left, ref_emitted = ref.speculative_rule(p, q)
     np.testing.assert_allclose(np.asarray(ref_emitted), np.asarray(p), atol=1e-6)
     np.testing.assert_allclose(np.asarray(ref_left), np.asarray(left), atol=1e-7)
     np.testing.assert_allclose(
-        np.asarray(accept), [float(ke.accept_probability(p, q, d)) for d in range(11)], atol=1e-7)
+        np.asarray(accept), [float(lm_common.accept_probability(p, q, d)) for d in range(11)], atol=1e-7)
     # equal distributions: every draft is kept, and the residual is p itself
-    assert float(ke.accept_probability(p, p, 3)) == 1.0
-    np.testing.assert_array_equal(np.asarray(ke.residual(p, p)), np.asarray(p))
+    assert float(lm_common.accept_probability(p, p, 3)) == 1.0
+    np.testing.assert_array_equal(np.asarray(lm_common.residual(p, p)), np.asarray(p))
 
 
 def test_the_step_draws_what_the_rule_says():
@@ -402,7 +403,7 @@ def test_the_step_draws_what_the_rule_says():
     def one(key):
         key_draft, key_verify = jax.random.split(key)
         draft = ke.sample(draft_logits, key_draft, temperature)
-        return ke.verify(logits, draft_logits, draft, key_verify, temperature)
+        return lm_common.verify(logits, draft_logits, draft, key_verify, temperature)
 
     kept, first, second = jax.vmap(one)(jax.random.split(keys[2], 60000))
     p = np.asarray(jax.nn.softmax(logits / temperature, axis=-1))

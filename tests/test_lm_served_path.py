@@ -265,6 +265,63 @@ def k_exaone_entry(cfg, config):
         config["mlp_layer_types"])
 
 
+def ling_flash_drawn(attrs):
+    # six sparse main layers and the MTP module's, 4 of 32 experts held (routing group 0):
+    # group 0 stays for every second token, which then sends it about 1 of its 4
+    held_share(attrs, layers=6, held=4, low=0.06, high=0.2, decode_layers=7)
+    steps, accepted = attrs["decode_steps"], attrs["mtp_accepted"]
+    assert attrs["mtp_drafted"] == steps and 0 <= accepted <= steps
+    assert 1 + steps + accepted in (attrs["new_tokens"], attrs["new_tokens"] + 1)
+    # two positions a step through seven layers and the MTP module's, kept or not
+    assert attrs["decode_layer_passes"] == steps * 2 * (7 + 1)
+    assert attrs["decode_routed_pairs"] == steps * 2 * (6 + 1) * 4 == attrs["decode_expert_rows"]
+    assert 0 <= attrs["decode_experts_read"] <= min(
+        attrs["decode_routed_pairs_held"], steps * (6 + 1) * 4)
+
+
+def ling_flash_workflow(mine, _):
+    theirs = load("workflows/rewrite-txt2img-k-exaone.json")
+    assert differing(mine, theirs) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "max_new_tokens"),
+        ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["draft_tokens"], generate["temperature"]) == (
+        1024, 1, 1.0)
+    # the K-EXAONE cell's 8,191-byte instruction, byte for byte
+    assert generate["text"] == by_kind(theirs)["TextGenerate"]["text"]
+
+
+def ling_flash_published(config):
+    assert config["expert_swiglu_limit_list"] == [0] * 35 + [4] * 7
+    assert config["share_expert_swiglu_limit_list"] == [0] * 34 + [5] * 6 + [7] * 2
+    assert config["model_type"] == "bailing_hybrid" and config["q_lora_rank"] is None
+    assert config["as_run"]["parameters"] == {"lm": 3296050624}
+    assert config["as_run"]["cache_bytes_per_token"] == 2304
+    assert config["as_run"]["state_bytes"] == 26050560
+    assert (config["as_run"]["first_layer"], config["as_run"]["kda_chunk"]) == (1, 64)
+    assert set(config["held"]) == {"layers", "experts", "vocabulary", "state"}
+    assert "routing group 0 whole" in config["held"]["experts"]
+    assert "8 chips of one v5e-8 host" in config["deployment"]
+    assert "not the trained model's" in config["as_run"]["drafts_kept"]
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max_unflipped"] < 0.2
+    assert 0 < limits["tolerance_draft_rel_l2_median"] < 0.2
+    assert 0 < limits["tolerance_expert_set_mismatch"] < 0.5
+
+
+def ling_flash_entry(cfg, config):
+    assert (cfg.first_layer, list(cfg.layers)) == (config["as_run"]["first_layer"], list(range(1, 8)))
+    assert (cfg.num_hidden_layers, len(cfg.held_experts), cfg.vocab_held) == (
+        config["num_hidden_layers"], config["num_experts"], config["vocab_size"])
+    assert (cfg.num_experts, cfg.vocab_size, cfg.ep_size, cfg.vocab_shards) == (512, 157184, 8, 8)
+    assert list(cfg.expert_swiglu_limit_list) == config["expert_swiglu_limit_list"]
+    assert list(cfg.share_expert_swiglu_limit_list) == config["share_expert_swiglu_limit_list"]
+    assert cfg.kda_chunk == config["as_run"]["kda_chunk"]
+    whole = type(cfg)()
+    assert [layer for layer in whole.layers if whole.is_mla(layer)] == [5, 11, 17, 23, 29, 35, 41]
+    assert [layer for layer in whole.layers if whole.is_dense(layer)] == [0, 1]
+
+
 @dataclasses.dataclass(frozen=True)
 class Model:
     """One language model's row. `attrs`: `node.TextGenerate`'s attributes
@@ -335,7 +392,8 @@ MODELS = [
                  "vocab_size": (102400, 25600)},
         assumed=("seeded random", "stand-in", "batch is 1"),
         published=deepseek_published, entry=deepseek_entry, check_workflow=deepseek_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm"}),
+        # `mla_device_pct.lm` since PR 45, whose reader finds this cell's `mla` scope too
+        metrics=frozenset({"experts_held_share_pct.lm", "mla_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS + ["import math\n"]),
     ),
     Model(
@@ -455,6 +513,61 @@ MODELS = [
         published=k_exaone_published, entry=k_exaone_entry, check_workflow=k_exaone_workflow,
         metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
                            "mtp_device_pct.lm"}),
+    ),
+    Model(
+        name="ling-flash", served="ling-flash-ep8-7l", tiny="tiny-ling-flash",
+        workflow="rewrite-txt2img-ling-flash.json", config="ling-3.0-flash.json",
+        reference="ling_flash.py", catalog="Ling-3.0-flash",
+        cell="ling_flash_rewrite_txt2img_512.closed2", prompt=8192, new_tokens=16, drafts=1,
+        # tiny-ling-flash: published layers 1-7, a dense KDA layer and six sparse ones (KDA,
+        # KDA, KDA, MLA, KDA, KDA), 4 heads of 16, a latent of 24 + 8, chunks of 32, 4 of
+        # 32 experts held, 4 a token, the MTP module. What grows: the MLA layer's latents
+        # and the MTP module's; what does not: six KDA layers' matrix states and tails,
+        # two slots each
+        attrs={
+            "prompt_tokens": 8192, "new_tokens": 16, "draft_tokens": 1,
+            "layers": 7, "linear_layers": 6, "latent_layers": 2, "experts_held": 4,
+            "experts_total": 32, "cache_bytes": 2 * (8192 + 16) * 32 * 4,
+            "state_bytes": 6 * 2 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4),
+            "prefill_chunks": 8192 // 32, "prefill_layer_passes": 8192 * 7,
+            "prefill_routed_pairs": 8192 * 6 * 4, "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
+            "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
+            "decode_experts_read"},
+        drawn_check=ling_flash_drawn,
+        # the ids, the pairs per held expert of either program (the decode's with the
+        # MTP module's row) and the four counts
+        wait_bytes=4 * (16 + 6 * 4 + (6 + 1) * 4 + 4),
+        # the prefill's expanded latent attention (24-wide queries and keys, 16-wide
+        # values); the decode's absorbed form is plain einsums and logs no route
+        attention="xla-causal 8192x8192x24/16 bq256 f32",
+        passes=lambda attrs: (8192 * 7, attrs["decode_steps"] * 2 * (7 + 1)),
+        widths={
+            "hidden_size": 2560, "num_attention_heads": 32, "num_key_value_heads": 32,
+            "head_dim": 128, "intermediate_size": 6144, "moe_intermediate_size": 768,
+            "moe_shared_expert_intermediate_size": 768, "num_experts_per_tok": 8,
+            "num_shared_experts": 1, "n_group": 8, "topk_group": 4, "kv_lora_rank": 512,
+            "q_lora_rank": None, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+            "qk_head_dim": 192, "v_head_dim": 128, "rope_theta": 6000000, "rotary_dim": 64,
+            "layer_group_size": 6, "first_k_dense_replace": 2, "short_conv_kernel_size": 4,
+            "kda_lower_bound": -5, "kda_safe_gate": True, "no_kda_lora": True,
+            "use_kda_lora": False, "routed_scaling_factor": 2.5, "norm_topk_prob": True,
+            "score_function": "sigmoid", "topk_method": "noaux_tc",
+            "gated_attention_proj_granularity_type": "head_wise",
+            "num_nextn_predict_layers": 1, "mtp_use_kda": False, "rms_norm_eps": 1e-6,
+            "max_position_embeddings": 262144, "tie_word_embeddings": False},
+        reduced={"num_hidden_layers": (42, 7), "num_experts": (512, 64),
+                 "vocab_size": (157184, 19648)},
+        assumed=("pre-norm", "layer_group_size 6", "full rank", "kda_safe_gate", "use_qk_norm",
+                 "head_wise", "sum of its two largest", "the clamp", "DeepSeek-V3's",
+                 "before the final norm", "seeded random", "shifted down by 6", "stand-in",
+                 "batch is 1", "share of drafts kept", "house style guide"),
+        published=ling_flash_published, entry=ling_flash_entry,
+        check_workflow=ling_flash_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
+                           "mtp_device_pct.lm", "linear_attention_device_pct.lm",
+                           "state_keep_device_pct.lm", "mla_device_pct.lm"}),
+        imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
     ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
@@ -778,7 +891,7 @@ def test_every_language_model_meets_the_one_contract(name):
 
 @pytest.mark.parametrize("name, passes", [
     ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
-    ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5)])
+    ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7)])
 def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
     from comfyui_distributed_tpu.models.registry import create_model
 
@@ -791,12 +904,14 @@ def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
 def _step_of(name):
     """(module, `step(cfg, params, cache, token, position) -> (logits,
     cache, what the decode sums over its steps)`) of a tiny model."""
-    from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, ouro, solar_open2
+    from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, ling_flash, ouro, solar_open2
 
-    def k_exaone_step(cfg, params, cache, token, position):
-        rows, _, cache, _, loads = k_exaone.main_step(
-            cfg, params, dict(cache), token[None], position)
-        return rows[0], cache, loads
+    def one_position(module):
+        def step(cfg, params, cache, token, position):
+            rows, _, cache, _, loads = module.main_step(
+                cfg, params, dict(cache), token[None], position)
+            return rows[0], cache, loads
+        return step
 
     def ouro_step(cfg, params, cache, token, position):
         logits, cache, _, exits = ouro.decode_step(cfg, params, cache, token, position)
@@ -811,7 +926,8 @@ def _step_of(name):
     return {
         "deepseek-v2": (deepseek_v2, with_loads(deepseek_v2)), "ouro": (ouro, ouro_step),
         "solar-open2": (solar_open2, with_loads(solar_open2)),
-        "k-exaone": (k_exaone, k_exaone_step),
+        "k-exaone": (k_exaone, one_position(k_exaone)),
+        "ling-flash": (ling_flash, one_position(ling_flash)),
     }[name]
 
 
@@ -850,13 +966,14 @@ def test_decode_is_the_models_own_step_walked_with_the_same_folded_keys(name, st
     decode = lm.decode(params, first.cache, first.logits, prompt, key, steps, temperature, True)
     assert decode.ids.shape == (steps,) and decode.ids.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(decode.ids), np.asarray(jnp.stack(tokens)))
-    kept = decode.kept["logits"] if name == "k-exaone" else decode.logits
+    drafts = BY_NAME[name].drafts
+    kept = decode.kept["logits"] if drafts else decode.logits
     np.testing.assert_allclose(np.asarray(kept), np.asarray(jnp.stack(rows)), rtol=1e-5, atol=1e-5)
     if name == "ouro":
         np.testing.assert_allclose(np.asarray(decode.exit), tally, rtol=1e-5)
         return
     loads = np.asarray(decode.loads)
-    if name == "k-exaone":  # the MTP module's row last, which no plain step runs
+    if drafts:  # the MTP module's row last, which no plain step runs
         assert not loads[-1].any()
         assert np.asarray(decode.counts).tolist()[:3] == [steps, 0, 0]
         loads = loads[:-1]
@@ -895,20 +1012,22 @@ def test_the_loop_sums_what_a_step_adds_and_keeps_what_it_hands_over(collect):
 # --- what only one model has -----------------------------------------------------
 
 
-def test_without_drafting_k_exaones_node_reports_a_step_a_token(tmp_path, monkeypatch):
+@pytest.mark.parametrize("name, layers, sparse", [("k-exaone", 5, 4), ("ling-flash", 7, 6)])
+def test_without_drafting_a_drafting_models_node_reports_a_step_a_token(
+        name, layers, sparse, tmp_path, monkeypatch):
     monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
     before = counted()
-    attrs = node_attrs(rehearsed(BY_NAME["k-exaone"], draft_tokens=0))
+    attrs = node_attrs(rehearsed(BY_NAME[name], draft_tokens=0))
     assert (attrs["draft_tokens"], attrs["decode_steps"]) == (0, 16)
     assert (attrs["mtp_drafted"], attrs["mtp_accepted"]) == (0, 0)
-    assert attrs["decode_layer_passes"] == 16 * 5
-    assert attrs["decode_routed_pairs"] == 16 * 4 * 4
-    model = BY_NAME["k-exaone"]
+    assert attrs["decode_layer_passes"] == 16 * layers
+    assert attrs["decode_routed_pairs"] == 16 * sparse * 4
+    model = BY_NAME[name]
     assert set(attrs) - BUILD_TALLIES - {"attention"} == set(model.attrs) | model.drawn
     after = counted()
     assert after["steps"] - before["steps"] == 16
     key = ("cdt_lm_layer_passes_total", "decode")
-    assert after[key] - before[key] == 16 * 5
+    assert after[key] - before[key] == 16 * layers
 
 
 @pytest.mark.parametrize("name, kind", [
@@ -945,6 +1064,30 @@ def test_k_exaones_served_share_holds_2_mb_of_rings_and_8_kb_a_position():
     assert attrs["prefill_expert_rows"] == 4 * 32768
 
 
+def test_lings_served_share_holds_26_mb_of_state_in_two_slots_and_2_kb_a_position():
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    lm = create_model("ling-flash-ep8-7l")
+    lm.dtype = jnp.dtype(jnp.bfloat16)
+    counts = [690, 690, 333, 9000]
+    attrs = lm.report(8192, 1024, 9216, [[128] * 64] * 6, [[20] * 64] * 7, counts)
+    config = load("benchmark/configs/ling-3.0-flash.json")
+    # two latent caches (layer 5's and the MTP module's), 576 x 2 B a position each
+    assert attrs["cache_bytes"] == 9216 * 2304 == 9216 * config["as_run"]["cache_bytes_per_token"]
+    # six KDA layers, two slots each: float32 matrix states whatever the weights' dtype,
+    # bfloat16 tails
+    assert attrs["state_bytes"] == 6 * 2 * (32 * 128 * 128 * 4 + 3 * 12288 * 2) == (
+        config["as_run"]["state_bytes"])
+    assert (attrs["linear_layers"], attrs["latent_layers"], attrs["prefill_chunks"]) == (6, 2, 128)
+    assert attrs["decode_layer_passes"] == 690 * 2 * 8 and attrs["decode_steps"] == 690
+    assert (attrs["mtp_drafted"], attrs["mtp_accepted"]) == (690, 333)
+    assert attrs["decode_routed_pairs"] == 690 * 2 * 7 * 8
+    # each layer's 8,192 held pairs take the rung of an eighth of the 65,536
+    assert attrs["prefill_expert_rows"] == 6 * 8192
+
+
 def test_solars_served_share_holds_13_mb_of_state_and_4_kb_a_position():
     import jax.numpy as jnp
 
@@ -972,6 +1115,36 @@ def test_the_three_models_with_experts_call_the_one_expert_layer_and_two_the_one
         assert "ragged_dot(" not in source
         # the rule's top-k is written once, in moe.py (DeepSeek's grouped rule is its own)
         assert ("top_k(" in source) == (module is deepseek_v2)
+
+
+def test_the_fifth_model_is_written_from_the_modules_the_others_are():
+    """Shared-code identities: the expert layer and the sigmoid rule are
+    `moe.py`'s, the delta rule `kda.py`'s for Solar-Open2 and Ling alike,
+    the two forms of latent attention `mla.py`'s for DeepSeek-V2 and Ling
+    alike, the drafting rule `lm_common.py`'s for K-EXAONE and Ling alike;
+    none of the importers keeps a body of its own."""
+    from comfyui_distributed_tpu.models import (
+        deepseek_v2, k_exaone, kda, ling_flash, lm_common, mla, moe, solar_open2)
+
+    assert ling_flash.expert_layer is moe.expert_layer
+    assert ling_flash.sigmoid_route is moe.sigmoid_route
+    for module in (solar_open2, ling_flash):
+        assert module.kda_chunked is kda.kda_chunked and module.kda_step is kda.kda_step
+        assert module.conv_qkv is kda.conv_qkv and module.gated_output is kda.gated_output
+    for module in (deepseek_v2, ling_flash):
+        assert module.mla is mla
+    for module in (k_exaone, ling_flash):
+        assert module.verify is lm_common.verify
+    for module, gone in ((solar_open2, ("def kda_chunked", "def kda_step", "def decay_products",
+                                        "def unit_lower_solve", "def _l2norm")),
+                         (deepseek_v2, ("def _latents", "thc,sc->ths")),
+                         (k_exaone, ("def verify", "def residual", "def accept_probability")),
+                         (ling_flash, ("top_k(", "ragged_dot(", "softmax(", "def verify",
+                                       "def kda_step"))):
+        with open(module.__file__, encoding="utf-8") as fh:
+            source = fh.read()
+        for body in gone:
+            assert body not in source, (module.__name__, body)
 
 
 def test_solars_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeypatch):

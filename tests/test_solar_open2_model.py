@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from comfyui_distributed_tpu.models import kda
 from comfyui_distributed_tpu.models import solar_open2 as so
 from comfyui_distributed_tpu.models.lm_common import rms_norm, swiglu
 from comfyui_distributed_tpu.models.registry import get_config
@@ -38,8 +39,8 @@ def rule_inputs(tokens, heads=3, d=16, decay=1.0, seed=0):
     """q, k (unit length), v, a log-decay of the given strength, beta in
     (0, 2) and a state to start from."""
     keys = jax.random.split(jax.random.key(seed), 6)
-    q = so._l2norm(jax.random.normal(keys[0], (tokens, heads, d))) * d ** -0.5
-    k = so._l2norm(jax.random.normal(keys[1], (tokens, heads, d)))
+    q = kda._l2norm(jax.random.normal(keys[0], (tokens, heads, d))) * d ** -0.5
+    k = kda._l2norm(jax.random.normal(keys[1], (tokens, heads, d)))
     v = jax.random.normal(keys[2], (tokens, heads, d))
     g = -decay * jax.nn.softplus(jax.random.normal(keys[3], (tokens, heads, d)))
     beta = 2 * jax.nn.sigmoid(jax.random.normal(keys[4], (tokens, heads)))
@@ -48,7 +49,7 @@ def rule_inputs(tokens, heads=3, d=16, decay=1.0, seed=0):
 
 def recurrence(q, k, v, g, beta, state):
     def token(state, xs):
-        o, state = so.kda_step(*xs, state)
+        o, state = kda.kda_step(*xs, state)
         return state, o
 
     state, o = jax.lax.scan(token, state, (q, k, v, g, beta))
@@ -65,7 +66,7 @@ def test_the_chunked_delta_rule_is_the_recurrence(tokens, decay):
     cumulative decay would make, is infinite): float32 rounding, ~3e-7
     of values of order one."""
     q, k, v, g, beta, state = rule_inputs(tokens, decay=decay)
-    o, after = so.kda_chunked(q, k, v, g, beta, state, 32)
+    o, after = kda.kda_chunked(q, k, v, g, beta, state, 32)
     o_want, after_want = recurrence(q, k, v, g, beta, state)
     assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(after)).all()
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_want), rtol=1e-5, atol=3e-6)
@@ -78,7 +79,7 @@ def test_decay_products_are_the_pairwise_sums_whatever_the_block(sub):
     pairs through a block's first row."""
     q, k, _, g, _, _ = rule_inputs(64, decay=3.0, seed=2)
     x, k, decay = (a.transpose(1, 0, 2) for a in (q, k, jnp.cumsum(g, axis=0)))
-    got = so.decay_products(x, k, decay, sub)
+    got = kda.decay_products(x, k, decay, sub)
     ratio = jnp.exp(decay[:, :, None, :] - decay[:, None, :, :])
     want = jnp.where(
         jnp.tril(jnp.ones((64, 64), bool)),
@@ -92,7 +93,7 @@ def test_the_block_solve_is_the_triangular_solve():
     want = jax.scipy.linalg.solve_triangular(
         a + jnp.eye(64), rhs, lower=True, unit_diagonal=True)
     np.testing.assert_allclose(
-        np.asarray(so.unit_lower_solve(a, rhs)), np.asarray(want), rtol=2e-5, atol=2e-5)
+        np.asarray(kda.unit_lower_solve(a, rhs)), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def generate(cfg, params, seed=1, temperature=1.0):
