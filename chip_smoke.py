@@ -35,7 +35,11 @@ Drives the main path once, through the entry points an operator uses:
                one jitted loop; then a drafting step's delta rule over
                Ling-3.0-flash's six KDA states, two positions a step,
                with two slots a layer and a flipped bit against one
-               slot and a select at the step's end.
+               slot and a select at the step's end; then a prefill's
+               chunked delta rule over one KDA layer at Ling's and at
+               Solar-Open2's heads (`KDA_DELTA_SHAPES`): the kernel
+               (`ops/kda_delta`) against the XLA form, us a (head,
+               chunk) of each and the kernel at other heads a step.
     experts    one child that holds the chip runs a decode step's two
                grouped products (gate-up, SiLU, down) over the held
                experts' stacked weights at the four models' decode
@@ -1015,6 +1019,8 @@ def attention_child(rehearsal: bool) -> int:
         failed += not causal_row(rehearsal, *shape)
     failed += not decode_slot_row(rehearsal)
     failed += not kda_keep_row(rehearsal)
+    for shape in REHEARSAL_KDA_DELTA_SHAPES if rehearsal else KDA_DELTA_SHAPES:
+        failed += not kda_delta_row(rehearsal, *shape)
     return 1 if failed else 0
 
 
@@ -1238,6 +1244,81 @@ def kda_keep_row(rehearsal: bool) -> bool:
         err = float(np.abs(mine - theirs).max())
         row["ok"] &= bool(np.isfinite(err)) and err <= 1e-4 * max(1.0, float(np.abs(theirs).max()))
     row["max_abs_diff"] = round(float(np.abs(results["slots"][0] - results["select"][0]).max()), 7)
+    print(json.dumps(row), flush=True)
+    return row["ok"]
+
+
+# A prefill's chunked delta rule over one KDA layer (`models/kda.
+# kda_chunked`): (label, tokens, heads, head width, chunk), the cells'
+# prompt at Ling-3.0-flash's and at Solar-Open2's held heads.
+KDA_DELTA_SHAPES = (
+    ("ling-flash a KDA layer's prefill", 8192, 32, 128, 64),
+    ("solar-open2 a KDA layer's prefill", 8192, 64, 128, 64),
+)
+REHEARSAL_KDA_DELTA_SHAPES = (("toy a KDA layer's prefill", 72, 3, 128, 32),)
+# Heads a grid step, timed beside the plan's: `ops/kda_delta.MAX_HEADS`
+# rests on these.
+KDA_DELTA_SWEEP = (1, 2, 4, 8)
+KDA_DELTA_TOLERANCE = 2e-3
+
+
+def kda_delta_row(rehearsal: bool, label, tokens, heads, d, chunk) -> bool:
+    """The delta rule over a whole prompt in its two forms, alone: the
+    kernel (`ops/kda_delta.kda_delta`) and the XLA form
+    (`models/kda.kda_chunked_scan`), each between the [T, H*d] arrays a
+    model's projections give and take, bfloat16 as stored. The row
+    prints us a (head, chunk) of each, the largest difference of their
+    outputs and of their final states relative to the largest entry,
+    and the kernel at other heads a grid step (`sweep_us`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models.kda import kda_chunked_scan
+    from comfyui_distributed_tpu.ops import kda_delta
+
+    @jax.jit
+    def operands(key):
+        keys = jax.random.split(key, 6)
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+        shape = (tokens, heads, d)
+        flat = lambda x, dtype: x.reshape(tokens, heads * d).astype(dtype)
+        return (
+            flat(unit(jax.random.normal(keys[0], shape)) * d ** -0.5, jnp.bfloat16),
+            flat(unit(jax.random.normal(keys[1], shape)), jnp.bfloat16),
+            flat(jax.random.normal(keys[2], shape), jnp.bfloat16),
+            flat(-5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], shape) - 3.0), jnp.float32),
+            2.0 * jax.nn.sigmoid(jax.random.normal(keys[4], (tokens, heads))),
+            0.1 * jax.random.normal(keys[5], (heads, d, d)),
+        )
+
+    def as_served(form):
+        def call(q, k, v, g, beta, state):
+            o, state = form(*(a.reshape(tokens, heads, d) for a in (q, k, v, g)), beta, state)
+            return o.reshape(tokens, heads * d), state
+        return jax.jit(call)
+
+    def kernel(group=None):
+        return as_served(functools.partial(
+            kda_delta.kda_delta, chunk=chunk, group=group, interpret=rehearsal))
+
+    xs = operands(jax.random.key(tokens + heads * 7 + d))
+    pairs = heads * -(-tokens // chunk)
+    plan = kda_delta.delta_plan(heads, d, chunk, 2)
+    row = {"shape": label, "tokens": tokens, "heads": heads, "d": d, "chunk": chunk,
+           "dtype": "bfloat16", "heads_a_step": plan, "ok": True}
+    results = {}
+    for name, fn in (("kernel", kernel()), ("scan", as_served(
+            functools.partial(kda_chunked_scan, chunk=chunk)))):
+        results[name], first_s, ms = timed(fn, *xs)
+        row[name] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3),
+                     "us_a_head_chunk": round(1e3 * ms / pairs, 3)}
+    for what, mine, theirs in zip(("o", "state"), results["kernel"], results["scan"]):
+        diff = float(jnp.max(jnp.abs(mine - theirs)) / jnp.max(jnp.abs(theirs)))
+        row[f"max_rel_diff_{what}"] = round(diff, 7)
+        row["ok"] &= diff <= KDA_DELTA_TOLERANCE  # a NaN fails it too
+    row["sweep_us"] = {
+        str(group): round(1e3 * timed(kernel(group), *xs)[2] / pairs, 3)
+        for group in KDA_DELTA_SWEEP if group != plan and group <= heads}
     print(json.dumps(row), flush=True)
     return row["ok"]
 

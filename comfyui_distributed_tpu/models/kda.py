@@ -4,13 +4,17 @@
     S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T,   o_t = S_t^T q_t
 
 with alpha_t = exp(g_t) a decay a channel, in two forms that must agree:
-`kda_chunked` (a prefill: `lax.scan` over chunks of tokens carrying S, a
-unit-triangular solve inside each chunk) and `kda_step` (a decode step:
-the recurrence itself); and what both models put in front of it, the
-causal depth-wise convolution of the q, k and v projections
-(`conv_qkv`). Gates, cumulative sums, the solve and S are float32; the
-large products take their operands in the storage dtype and accumulate
-in float32. How g and beta are made of the input is each model's own.
+`kda_chunked` (a prefill: chunks of tokens in order carrying S, a
+unit-triangular solve inside each chunk; on a TPU one Pallas kernel
+that holds S in VMEM across the chunks, `ops/kda_delta.py`, elsewhere
+`kda_chunked_scan`, a `lax.scan` over XLA operations and the kernel's
+reference; `kda_delta.kda_delta_route` says which, a model's `report`
+repeats it as `kda_form`) and `kda_step` (a decode step: the recurrence
+itself); and what both models put in front of it, the causal depth-wise
+convolution of the q, k and v projections (`conv_qkv`). Gates,
+cumulative sums, the solve and S are float32; the large products take
+their operands in the storage dtype and accumulate in float32. How g
+and beta are made of the input is each model's own.
 """
 
 from __future__ import annotations
@@ -20,11 +24,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..ops import kda_delta
 from .lm_common import rms_norm
 
 # Rows of a chunk whose pairwise decays are formed pair by pair
 # (`decay_products`); between such blocks they go through one product.
-KDA_SUBCHUNK = 16
+KDA_SUBCHUNK = kda_delta.SUBCHUNK
 L2_EPS = 1e-6
 
 
@@ -121,9 +126,9 @@ def unit_lower_solve(a, rhs, sub: int = KDA_SUBCHUNK):
     return jnp.concatenate(solved, axis=-2)
 
 
-# Chunks whose terms `kda_chunked` forms at once: the pairwise decays of
-# one are 34 MB at the published sizes (64 heads x 4 blocks x 16 x 16 x
-# 128 float32).
+# Chunks whose terms `kda_chunked_scan` forms at once: the pairwise
+# decays of one are 34 MB at the published sizes (64 heads x 4 blocks x
+# 16 x 16 x 128 float32).
 CHUNKS_AT_ONCE = 8
 
 
@@ -139,12 +144,26 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int):
         o_i = (q_i exp(G_i)) S0 + sum_{j<=i} (sum_c q_ic k_jc exp(G_ic - G_jc)) u_j
         S_C = exp(G_C) S0 + sum_j (k_j exp(G_C - G_j)) u_j^T
 
-    What does not read S (`terms`: float32) is formed first, `CHUNKS_AT_
-    ONCE` chunks at a time; the scan then carries S through four products
-    a chunk, their operands in the storage dtype and their sums float32.
     A last chunk that is short is filled with tokens that change nothing
     (g 0, beta 0). Returns (o [T, H, d] float32, the state after the last
-    token)."""
+    token). One algorithm in the form its input allows
+    (`kda_delta.kda_delta_route`: the backend, d, the chunk): the
+    kernel of `ops/kda_delta.py` or `kda_chunked_scan`, with one entry
+    in `ops/attention.route_log` a traced call."""
+    tokens, heads, d = q.shape
+    form = kda_delta.kda_delta_route(heads, d, chunk, q.dtype)
+    kda_delta.log_route(form, tokens, heads, d, chunk, q.dtype)
+    if form == "kernel":
+        return kda_delta.kda_delta(q, k, v, g, beta, state, chunk=chunk)
+    return kda_chunked_scan(q, k, v, g, beta, state, chunk)
+
+
+def kda_chunked_scan(q, k, v, g, beta, state, chunk: int):
+    """`kda_chunked` in XLA operations, every backend's form and the
+    kernel's reference. What does not read S (`terms`: float32) is
+    formed first, `CHUNKS_AT_ONCE` chunks at a time; the scan then
+    carries S through four products a chunk, their operands in the
+    storage dtype and their sums float32."""
     tokens, heads, d = q.shape
     dtype = q.dtype
     count = -(-tokens // chunk)

@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.decode_attention import position_valid
+from ..ops.kda_delta import kda_delta_route
 from ..parallel.sharding import expert_range
 from . import mla
 from .kda import conv_qkv, gated_output, kda_chunked, kda_step
@@ -723,8 +724,9 @@ class LingFlash(LanguageModel):
 
     def report(self, prompt_tokens: int, new_tokens: int, cache_len: int,
                prefill_loads, decode_loads, counts) -> dict:
-        """`describe`, the chunks a KDA layer's prefill scanned, what the
-        decode's steps came to, the layer bodies either program ran (the
+        """`describe`, the chunks a KDA layer's prefill walked and in
+        which form (`kda_delta_route`), what the decode's steps came to,
+        the layer bodies either program ran (the
         decode's over every position a step ran, a rejected draft's and
         the MTP module's among them; of the MTP module the prefill runs
         only the latents, no body) and, per phase, the routing as
@@ -738,6 +740,8 @@ class LingFlash(LanguageModel):
         return {
             **self.describe(cache_len),
             "prefill_chunks": -(-prompt_tokens // cfg.kda_chunk),
+            "kda_form": kda_delta_route(
+                cfg.num_attention_heads, cfg.head_dim, cfg.kda_chunk, self.dtype),
             **report_loads(
                 cfg.num_experts_per_tok, cfg.num_experts, prompt_tokens, new_tokens,
                 prefill_loads, decode_loads,

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.decode_attention import attend_xla, position_valid
+from ..ops.kda_delta import kda_delta_route
 from ..parallel.sharding import expert_range
 from .kda import KDA_SUBCHUNK, conv_qkv, gated_output, kda_chunked, kda_step
 from .lm_common import (
@@ -471,13 +472,15 @@ class SolarOpen2(LanguageModel):
 
     def report(self, prompt_tokens: int, new_tokens: int, cache_len: int,
                prefill_loads, decode_loads) -> dict:
-        """`describe`, the chunks a linear layer's prefill scanned, and
-        per phase the token-expert pairs the router made and those on
-        held experts."""
+        """`describe`, the chunks a linear layer's prefill walked and in
+        which form (`kda_delta_route`), and per phase the token-expert
+        pairs the router made and those on held experts."""
         cfg = self.cfg
         return {
             **self.describe(cache_len),
             "prefill_chunks": -(-prompt_tokens // cfg.kda_chunk),
+            "kda_form": kda_delta_route(
+                cfg.linear_num_heads, cfg.linear_head_dim, cfg.kda_chunk, self.dtype),
             **report_loads(
                 cfg.num_experts_per_tok, cfg.n_routed_experts,
                 prompt_tokens, new_tokens, prefill_loads, decode_loads,

@@ -218,6 +218,37 @@ def test_the_kda_row_agrees_with_itself_in_both_forms(smoke, capsys):
     assert set(row) >= {"slots", "select", "state_mb", "steps"}
 
 
+def test_the_delta_rows_are_the_two_kda_cells_own_numbers(smoke):
+    """(tokens, heads, width, chunk) of a prefill's delta-rule row are a
+    registered configuration's own, at its cell's prompt."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    ling, solar = get_config("ling-flash-ep8-7l"), get_config("solar-open2-ep8-4l")
+    causal = {row[0]: row[1] for row in smoke.CAUSAL_SHAPES}
+    tokens = causal["ling-flash mla 8192"][1]
+    assert [row[1:] for row in smoke.KDA_DELTA_SHAPES] == [
+        (tokens, ling.num_attention_heads, ling.head_dim, ling.kda_chunk),
+        (tokens, solar.linear_num_heads, solar.linear_head_dim, solar.kda_chunk),
+    ]
+
+
+def test_the_delta_row_times_the_kernel_beside_the_scan(smoke, capsys):
+    """The rehearsal's toy row on the CPU, the kernel interpreted: three
+    heads in steps of three, two and one, a short last chunk, and the
+    two forms within the row's tolerance of each other."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("the rehearsal's row is the CPU's")
+    (shape,) = smoke.REHEARSAL_KDA_DELTA_SHAPES
+    assert smoke.kda_delta_row(True, *shape)
+    (row,) = _result_lines(capsys.readouterr().out)
+    assert row["ok"] and row["heads_a_step"] == 3 and set(row["sweep_us"]) == {"1", "2"}
+    assert row["max_rel_diff_o"] < smoke.KDA_DELTA_TOLERANCE
+    assert row["max_rel_diff_state"] < smoke.KDA_DELTA_TOLERANCE
+    assert set(row["kernel"]) == set(row["scan"]) == {"first_call_s", "ms", "us_a_head_chunk"}
+
+
 def test_a_steps_routing_is_k_distinct_experts_a_token(smoke):
     sizes = smoke.step_sizes(7, steps=200, rows=16, k=8, held=16, experts=128)
     assert sizes.shape == (200, 16) and sizes.max() <= 2  # two tokens: at most two rows an expert

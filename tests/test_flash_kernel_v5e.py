@@ -362,6 +362,60 @@ def test_ling_flash_drafting_decode_keeps_or_drops_a_draft_without_a_copy_of_its
     assert text.count('custom_call_target="tpu_custom_call"') == 14
 
 
+@pytest.mark.parametrize("label,tokens,heads,d,chunk", chip_smoke.KDA_DELTA_SHAPES)
+def test_kda_delta_compiles_for_v5e_at_the_prefills_shapes(
+        one_chip, label, tokens, heads, d, chunk):
+    """A KDA layer's delta rule over the cells' prompt (`ops/kda_delta`),
+    at Ling-3.0-flash's and Solar-Open2's held heads, between the
+    `[T, H d]` arrays a model's projections give and take: one kernel
+    and no copy of an operand in front of it."""
+    from comfyui_distributed_tpu.ops import kda_delta
+
+    def call(q, k, v, g, beta, state):
+        o, state = kda_delta.kda_delta(
+            *(a.reshape(tokens, heads, d) for a in (q, k, v, g)), beta, state, chunk=chunk)
+        return o.reshape(tokens, heads * d), state
+
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(call).lower(
+        *(place((tokens, heads * d), jnp.bfloat16) for _ in range(3)),
+        place((tokens, heads * d), jnp.float32), place((tokens, heads), jnp.float32),
+        place((heads, d, d), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%kda_delta" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_ling_flash_prefill_walks_its_six_kda_layers_in_the_kernel(one_chip, monkeypatch):
+    """Ling-3.0-flash's whole prefill at the cell's 8,192 tokens, routed
+    as a TPU routes it: the delta rule of each of the six KDA layers is
+    one `kda_delta` call (the route log says so as it is traced), the
+    MLA layer's attention the causal kernel; the grouped products are
+    the compiler's own."""
+    import re
+
+    from comfyui_distributed_tpu.models import ling_flash
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("ling-flash-ep8-7l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: ling_flash.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        compiled = ling_flash.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((8192,), jnp.int32, sharding=one_chip),
+            cache_len=8200,  # a length of this test's own: the route is read while tracing
+        ).compile()
+    assert [r for r in routes if r.startswith("kda-")] == (
+        ["kda-kernel 8192x32x128 c64 hb8 bf16"] * cfg.kda_layers)
+    text = compiled.as_text()
+    assert len(re.findall(r"%kda_delta[.\d]* = ", text)) == cfg.kda_layers
+    assert "%flash_attention_causal" in text
+
+
 @pytest.mark.parametrize("label,rows,k,held,experts,hidden,width", chip_smoke.EXPERT_SHAPES)
 def test_expert_matvec_compiles_for_v5e_at_the_decode_shapes(
         one_chip, label, rows, k, held, experts, hidden, width):

@@ -441,16 +441,19 @@ MODELS = [
             "layers": 4, "full_layers": 1, "linear_layers": 3, "experts_held": 2,
             "experts_total": 16, "cache_bytes": 2 * 2 * (8192 + 16) * 16 * 4,
             "state_bytes": 3 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4),
-            "prefill_chunks": 8192 // 32, "prefill_routed_pairs": 8192 * 4 * 4,
-            "decode_routed_pairs": 16 * 4 * 4, "decode_expert_rows": 16 * 4 * 4,
+            "prefill_chunks": 8192 // 32, "kda_form": "scan",
+            "prefill_routed_pairs": 8192 * 4 * 4, "decode_routed_pairs": 16 * 4 * 4,
+            "decode_expert_rows": 16 * 4 * 4,
             "decode_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
                                    "decode_expert_rows", "decode_expert_route"}),
         drawn_check=solar_drawn,
         wait_bytes=4 * (16 + 2 * 4 * 2),  # nothing of the state tree leaves the device
         # the decode's one softmax layer in four (the einsum form, a key head
-        # serving two queries), then the prefill's
-        attention="decode-xla 4x8208x16, xla-causal 8192x8192x16/16 bq256 f32",
+        # serving two queries), the prefill's three KDA layers (d = 16: the XLA
+        # form on any backend), then its softmax layer
+        attention=("decode-xla 4x8208x16, kda-scan 8192x4x16 c32 f32, "
+                   "xla-causal 8192x8192x16/16 bq256 f32"),
         passes=lambda attrs: (8192 * 4, 16 * 4),
         widths={
             "hidden_size": 4096, "num_attention_heads": 64, "num_key_value_heads": 8,
@@ -529,7 +532,7 @@ MODELS = [
             "layers": 7, "linear_layers": 6, "latent_layers": 2, "experts_held": 4,
             "experts_total": 32, "cache_bytes": 2 * (8192 + 16) * 32 * 4,
             "state_bytes": 6 * 2 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4),
-            "prefill_chunks": 8192 // 32, "prefill_layer_passes": 8192 * 7,
+            "prefill_chunks": 8192 // 32, "kda_form": "scan", "prefill_layer_passes": 8192 * 7,
             "prefill_routed_pairs": 8192 * 6 * 4, "decode_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
             "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
@@ -538,9 +541,10 @@ MODELS = [
         # the ids, the pairs per held expert of either program (the decode's with the
         # MTP module's row) and the four counts
         wait_bytes=4 * (16 + 6 * 4 + (6 + 1) * 4 + 4),
-        # the prefill's expanded latent attention (24-wide queries and keys, 16-wide
-        # values); the decode's absorbed form is plain einsums and logs no route
-        attention="xla-causal 8192x8192x24/16 bq256 f32",
+        # the prefill's six KDA layers (d = 16: the XLA form) and its expanded latent
+        # attention (24-wide queries and keys, 16-wide values); the decode's absorbed
+        # form is plain einsums and logs no route
+        attention="kda-scan 8192x4x16 c32 f32, xla-causal 8192x8192x24/16 bq256 f32",
         passes=lambda attrs: (8192 * 7, attrs["decode_steps"] * 2 * (7 + 1)),
         widths={
             "hidden_size": 2560, "num_attention_heads": 32, "num_key_value_heads": 32,
@@ -1167,7 +1171,8 @@ def test_solars_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeyp
     ids = jax.random.randint(jax.random.key(4), (640,), 0, cfg.vocab_size)
     with attention.route_log() as routes:
         want = solar_open2.prefill(cfg, params, ids, cache_len=672, collect=True)
-    assert routes == ["xla-causal 640x640x16/16 bq256 f32"]
+    delta_rule = ["kda-scan 640x4x16 c32 f32"] * cfg.linear_layers  # d = 16: never the kernel
+    assert sorted(routes) == delta_rule + ["xla-causal 640x640x16/16 bq256 f32"]
 
     monkeypatch.setattr(attention, "causal_route", lambda *operands: "flash")
     kernel = attention.flash_attention
@@ -1176,7 +1181,8 @@ def test_solars_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeyp
         lambda *operands, **options: kernel(*operands, **{**options, "interpret": True}))
     with attention.route_log() as routes:  # another cache length: traced anew
         got = solar_open2.prefill(cfg, params, ids, cache_len=704, collect=True)
-    assert routes == ["flash-causal 640x640x16/16 g2 bq128 bk640 f32 blocks5/5"]
+    assert sorted(routes) == (
+        ["flash-causal 640x640x16/16 g2 bq128 bk640 f32 blocks5/5"] + delta_rule)
     # another order of summation moves a score in its last digit, and a router
     # over seeded weights then gives a few (token, layer) pairs another expert
     # (the XLA form in blocks of 128 rows for 256: 5 of 10,240 choices; the
