@@ -68,10 +68,11 @@ class PromptJob:
 
 class SaveThread:
     """Runs the work handed to it one piece at a time, in hand-off
-    order, on a thread of its own: the PNG encode and file write of
-    prompt N while the executor thread walks prompt N+1. At most one
-    piece waits beside the one running; `submit` blocks beyond that, so
-    a burst holds two images on the host, not a queue of them."""
+    order, on a thread of its own: the read-back, PNG encode and file
+    write of prompt N while the executor thread walks prompt N+1. At
+    most one piece waits beside the one running; `submit` blocks beyond
+    that, so the executor thread is at most two prompts ahead of the
+    disk and a burst holds two images, not a queue of them."""
 
     def __init__(self) -> None:
         self._queue: "thread_queue.Queue[Any]" = thread_queue.Queue(maxsize=1)
@@ -374,11 +375,14 @@ class DistributedServer:
         self._saver = SaveThread()
         # under _jobs_lock: prompts taken and not done; prompts ever
         # taken (a save is overlapped when this moves before it ends);
-        # saves handed to the saver and not yet on disk
+        # saves handed to the saver and not yet on disk; those of them
+        # whose read-back has not ended (a walk that begins meanwhile
+        # is ahead: the device may still run the earlier prompt)
         self._jobs_lock = threading.Lock()
         self._unfinished = 0
         self._taken = 0
         self.saves_pending = 0
+        self._reading = 0
         self._history: dict[str, PromptJob] = {}
         self._interrupt = threading.Event()
         self.execution_context = ExecutionContext(mesh=mesh)
@@ -532,6 +536,13 @@ class DistributedServer:
         """Walk one prompt's graph at a time on this thread. A job is
         done when its walk and every save it handed off have ended.
 
+        The thread waits for the device only where a node has to see a
+        value (`TextGenerate`'s ids): the image's read-back is the
+        saver thread's, so this thread takes prompt N+1 as soon as N's
+        last program is launched, and N+1's programs queue on the
+        device behind N's. `SaveThread`'s bound (one piece running, one
+        waiting) keeps it at most two prompts ahead of the disk.
+
         The walk stays in this function's frame, with no closure in
         it: the traced programs record the stack they were built
         under, and a frame more between here and `execute` cost every
@@ -560,7 +571,7 @@ class DistributedServer:
                     start=came_back,
                 ))
             tracer.end_span(job.queue_span)
-            self._job_taken(job)
+            ahead = self._job_taken(job)
             self._interrupt.clear()
             ctx = ExecutionContext(
                 mesh=self.mesh,
@@ -580,6 +591,7 @@ class DistributedServer:
                 attrs={
                     "prompt_id": job.prompt_id,
                     "role": "worker" if self.is_worker else "master",
+                    "ahead": ahead,
                 },
             )
             # The compute thread joins the prompt's trace under that
@@ -599,18 +611,26 @@ class DistributedServer:
                 self._work_ended(job)
                 came_back = tracer.now()
 
-    def _job_taken(self, job: PromptJob) -> None:
+    def _job_taken(self, job: PromptJob) -> int:
+        """Count the job in; 1 when its walk begins ahead of an earlier
+        prompt's read-back, else 0."""
+        from ..telemetry.instruments import walks_total
+
         with self._jobs_lock:
             job.open_work += 1
             self._unfinished += 1
             self._taken += 1
             self._executing.set()
+            ahead = int(self._reading > 0)
+        walks_total().inc(ahead=str(ahead))
+        return ahead
 
     def _defer(self, job: PromptJob, work: Any) -> None:
         """`ExecutionContext.defer` of a served prompt, called on the
-        executor thread from inside a node: run `work(overlapped)` on
-        the saver thread, in the job's trace under the span active
-        here. Blocks while a save runs and another waits."""
+        executor thread from inside a node: run `work(overlapped,
+        landed)` on the saver thread, in the job's trace under the span
+        active here. `work` calls `landed()` when its read-back has
+        ended. Blocks while a save runs and another waits."""
         from ..telemetry import get_tracer
 
         tracer = get_tracer()
@@ -618,16 +638,23 @@ class DistributedServer:
         with self._jobs_lock:
             job.open_work += 1
             self.saves_pending += 1
+            self._reading += 1
             taken = self._taken
+
+        @functools.cache  # once: by `work`, or below where its read-back raised
+        def landed() -> None:
+            with self._jobs_lock:
+                self._reading -= 1
 
         def run() -> None:
             token = tracer.activate(job.trace_id, parent_id)
             try:
-                work(lambda: self._taken > taken)
+                work(lambda: self._taken > taken, landed)
             except Exception as exc:  # noqa: BLE001 - reported to client
                 self._fail(job, exc)
             finally:
                 tracer.deactivate(token)
+                landed()
                 with self._jobs_lock:
                     self.saves_pending -= 1
                 self._work_ended(job)

@@ -34,10 +34,10 @@ class ExecutionContext:
     # caches shared across nodes in one process
     pipelines: dict[str, Any] = dataclasses.field(default_factory=dict)
     extras: dict[str, Any] = dataclasses.field(default_factory=dict)
-    # defer(work): a served prompt's hand-off of host work that may end
-    # after the graph walk (SaveImage's encode and write); the server
-    # runs `work(overlapped)` on its saver thread and the prompt is done
-    # when it has. None: the node does the work itself.
+    # defer(work): a served prompt's hand-off of work that may end after
+    # the graph walk (SaveImage's read-back, encode and write); the
+    # server runs `work(overlapped, landed)` on its saver thread and the
+    # prompt is done when it has. None: the node does the work itself.
     defer: Any = None
 
     def check_interrupted(self) -> None:

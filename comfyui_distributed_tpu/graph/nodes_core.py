@@ -1366,8 +1366,9 @@ class SaveImage:
     """PNG per image as <prefix>_NNNNN.png, the counter reserved when the
     node runs; a served prompt is done when its files are written.
 
-    In a served prompt encode and write run on the server's saver
-    thread while the executor thread walks the next prompt."""
+    In a served prompt the read-back, the encode and the write run on
+    the server's saver thread while the executor thread walks the next
+    prompt: the images leave the device once, there."""
 
     @classmethod
     def INPUT_TYPES(cls):
@@ -1387,37 +1388,38 @@ class SaveImage:
 
         out_dir = get_output_dir(context)
         os.makedirs(out_dir, exist_ok=True)
-        from ..telemetry import get_tracer
-
         # resume numbering after existing files so runs never clobber
         # each other (ComfyUI counter-scan behavior); reserved, because
         # an earlier prompt's file may not be written yet
         start = reserve_counter(out_dir, filename_prefix, "png", len(images))
-        # the executor thread parks here until the device has finished
-        # everything the images depend on
-        with get_tracer().device_wait() as wait:
-            arr = img_utils.ensure_numpy(images)
-            wait.attrs["bytes"] = int(arr.nbytes)
-        saved = [f"{filename_prefix}_{start + i:05d}.png" for i in range(arr.shape[0])]
-        write = partial(_write_pngs, arr, [os.path.join(out_dir, n) for n in saved])
-        # A served request hands encode and write to the server's saver
-        # thread, and the executor thread goes on to the next prompt;
-        # the prompt is done when they have run. Anywhere else: here.
+        saved = [f"{filename_prefix}_{start + i:05d}.png" for i in range(len(images))]
+        save = partial(_save_pngs, images, [os.path.join(out_dir, n) for n in saved])
+        # A served request hands read-back, encode and write to the
+        # server's saver thread: this thread waits for nothing and goes
+        # on to the next prompt, whose programs queue on the device
+        # behind this one's; the prompt is done when the files are
+        # written. Anywhere else: here.
         defer = getattr(context, "defer", None)
         if defer is None:
-            write()
+            save()
         else:
-            defer(write)
+            defer(save)
         return ({"ui": {"images": saved}, "images": images},)
 
 
-def _write_pngs(arr, paths, overlapped=lambda: False) -> None:
-    """Encode and write one PNG per image of `arr`. `overlapped()` says
-    whether the executor has taken another prompt since the hand-off."""
+def _save_pngs(images, paths, overlapped=lambda: False, landed=lambda: None) -> None:
+    """Read `images` back and write one PNG per image. The thread parks
+    in the read-back until the device has finished everything the
+    images depend on, then calls `landed()`; `overlapped()` says whether
+    the executor has taken another prompt since the hand-off."""
     from ..telemetry import get_tracer
     from ..telemetry.instruments import saves_total
 
     tracer = get_tracer()
+    with tracer.device_wait() as wait:
+        arr = img_utils.ensure_numpy(images)
+        wait.attrs["bytes"] = int(arr.nbytes)
+    landed()
     for image, path in zip(arr, paths):
         with tracer.span("png.encode") as encode:
             png = img_utils.encode_png(image, compress_level=4)

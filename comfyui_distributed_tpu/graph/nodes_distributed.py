@@ -343,6 +343,13 @@ class DistributedCollector:
 
     def _collect_master(self, images, audio, job_id, enabled_worker_ids, context):
         server = getattr(context, "server", None) if context is not None else None
+        alone = not enabled_worker_ids or server is None
+
+        # Nobody to gather from and nothing to gather: an array that
+        # lies whole on one device IS the collected batch. It stays
+        # there, and whoever needs its bytes (SaveImage) waits for it.
+        if alone and isinstance(images, jax.Array) and len(images.sharding.device_set) == 1:
+            return (images, audio)
 
         # Mesh tier: the sharded participant-major array IS the collected
         # batch — just materialise it.
@@ -354,7 +361,7 @@ class DistributedCollector:
             )
             wait.attrs["bytes"] = int(mesh_collected.nbytes)
 
-        if not enabled_worker_ids or server is None:
+        if alone:
             combined_audio = audio
             return (jnp.asarray(mesh_collected), combined_audio)
 

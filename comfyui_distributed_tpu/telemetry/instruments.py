@@ -869,6 +869,16 @@ def saves_total() -> Counter:
     )
 
 
+def walks_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_walks_total",
+        "Prompts the executor thread took; ahead=1 when an earlier "
+        "prompt's read-back had not ended, so its programs could still "
+        "be running",
+        ("ahead",),
+    )
+
+
 def lm_tokens_total() -> Counter:
     return get_metrics_registry().counter(
         "cdt_lm_tokens_total",

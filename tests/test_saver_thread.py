@@ -1,9 +1,10 @@
-"""The saver thread: a served prompt's PNG encode and file write run
-while the executor thread walks the next prompt. `done` means on disk,
-names are reserved at hand-off, a saver-side failure is the job's error,
-at most one save waits beside the one running, and nothing that waits
-for the server (the loop's sentinel, `stop`, `drain_worker`) leaves a
-save unwritten. Encodes are held on events, never on sleeps."""
+"""The saver thread: a served prompt's read-back, PNG encode and file
+write run while the executor thread walks the next prompt, which is
+"ahead" while the earlier read-back lasts. `done` means on disk, names
+are reserved at hand-off, a saver-side failure is the job's error, at
+most one save waits beside the one running, and nothing that waits for
+the server (the loop's sentinel, `stop`, `drain_worker`) leaves a save
+unwritten. Read-backs and encodes are held on events, never on sleeps."""
 
 import asyncio
 import os
@@ -19,7 +20,7 @@ from comfyui_distributed_tpu.graph.executor import ExecutionContext, GraphExecut
 from comfyui_distributed_tpu.graph.registry import NODE_REGISTRY
 from comfyui_distributed_tpu.resilience.chaos import FakeClock
 from comfyui_distributed_tpu.telemetry import Tracer, set_tracer
-from comfyui_distributed_tpu.telemetry.instruments import saves_total
+from comfyui_distributed_tpu.telemetry.instruments import saves_total, walks_total
 from comfyui_distributed_tpu.telemetry.metrics import get_metrics_registry
 from comfyui_distributed_tpu.utils import image as img_utils
 from comfyui_distributed_tpu.workers.startup import drain_worker
@@ -41,9 +42,51 @@ class HostImage:
         return (np.full((1, 8, 8, 3), float(value), np.float32),)
 
 
-def graph(value, prefix="saved"):
+class Gate:
+    """While `hold` is clear whoever passes parks, having said so on
+    `entered`; `fail_next` makes one pass raise `error`."""
+
+    error = OSError("No space left on device")
+
+    def __init__(self):
+        self.hold = threading.Event()
+        self.hold.set()
+        self.entered = threading.Event()
+        self.fail_next = False
+        self.threads = []
+
+    def pass_through(self):
+        self.threads.append(threading.current_thread().name)
+        self.entered.set()
+        assert self.hold.wait(10), "the test never released the gate"
+        if self.fail_next:
+            self.fail_next = False
+            raise self.error
+
+
+class ReadBack(Gate):
+    error = RuntimeError("the program failed on the device")
+
+
+class OnDevice:
+    """Stands in for an image on the device: its bytes reach the host
+    when `__array__` returns, which passes `gate` first, as a read-back
+    waits for the programs before it."""
+
+    def __init__(self, value, gate):
+        self.value, self.gate = value, gate
+
+    def __len__(self):
+        return 1
+
+    def __array__(self, dtype=None, copy=None):
+        self.gate.pass_through()
+        return np.full((1, 8, 8, 3), self.value, dtype or np.float32)
+
+
+def graph(value, prefix="saved", source="HostImage"):
     return {
-        "1": {"class_type": "HostImage", "inputs": {"value": value}},
+        "1": {"class_type": source, "inputs": {"value": value}},
         "2": {"class_type": "SaveImage",
               "inputs": {"images": ["1", 0], "filename_prefix": prefix}},
     }
@@ -74,24 +117,11 @@ def out_dir(tmp_path, monkeypatch):
     return tmp_path / "out"
 
 
-class Encoder:
-    """`encode_png` with a gate: while `hold` is clear an encode parks,
-    having said so on `entered`; `fail_next` makes one raise."""
-
-    def __init__(self):
-        self.hold = threading.Event()
-        self.hold.set()
-        self.entered = threading.Event()
-        self.fail_next = False
-        self.threads = []
+class Encoder(Gate):
+    """`encode_png` behind a gate."""
 
     def __call__(self, image, compress_level=0):
-        self.threads.append(threading.current_thread().name)
-        self.entered.set()
-        assert self.hold.wait(10), "the test never released the encode"
-        if self.fail_next:
-            self.fail_next = False
-            raise OSError("No space left on device")
+        self.pass_through()
         return encode_png(image, compress_level)
 
 
@@ -100,6 +130,20 @@ def encoder(monkeypatch):
     gate = Encoder()
     monkeypatch.setattr(img_utils, "encode_png", gate)
     return gate
+
+
+@pytest.fixture()
+def read_back(monkeypatch):
+    """The gate of every image a `HeldImage` node makes."""
+    gate = ReadBack()
+
+    class HeldImage(HostImage):
+        def make(self, value):
+            return (OnDevice(float(value), gate),)
+
+    monkeypatch.setitem(NODE_REGISTRY, "HeldImage", HeldImage)
+    yield gate
+    gate.hold.set()
 
 
 @pytest.fixture()
@@ -211,6 +255,91 @@ def test_the_overlap_counter_and_attribute_read_one_and_zero(
     assert 'cdt_saves_total{overlapped="0"} 1' in text
 
 
+@pytest.fixture()
+def two_reading(server, tracer, read_back, out_dir):
+    """p1's read-back held on the saver thread, p2 walked behind it."""
+    read_back.hold.clear()
+    first = server.queue_prompt(graph(0.25, source="HeldImage"), "p1")
+    second = server.queue_prompt(graph(0.75, source="HeldImage"), "p2")
+    assert read_back.entered.wait(10)
+    wait_until(lambda: walked(tracer, "p2"), "p2's walk has ended")
+    return first, second
+
+
+def test_the_next_prompt_is_walked_while_the_read_back_is_held(
+    two_reading, server, tracer, read_back, out_dir
+):
+    first, second = two_reading
+    (wait,) = spans_named(tracer, "p1", "device.wait")
+    assert wait["end"] is None and read_back.threads == ["cdt-saver"]
+    assert wait["parent_id"] == spans_named(tracer, "p1", "node.SaveImage")[0]["span_id"]
+    # the executor thread waited for nothing: p2 is walked, its read-back queued
+    assert spans_named(tracer, "p2", "node.HeldImage")[0]["end"] is not None
+    assert not spans_named(tracer, "p2", "device.wait")
+    assert server._reading == 2 and not first.done.is_set()
+    release(read_back, first, second)
+    (wait,) = spans_named(tracer, "p1", "device.wait")
+    nodes = [s for s in tracer.spans("p2") if s["name"].startswith("node.")]
+    assert min(node["start"] for node in nodes) < wait["end"]
+    assert read_back.threads == ["cdt-saver", "cdt-saver"] and server._reading == 0
+    assert (out_dir / "saved_00000.png").read_bytes() == expected_png(0.25)
+    assert (out_dir / "saved_00001.png").read_bytes() == expected_png(0.75)
+
+
+def test_ahead_says_a_walk_began_before_an_earlier_read_back_ended(
+    two_reading, server, tracer, read_back
+):
+    first, second = two_reading
+    release(read_back, first, second)
+    # nothing was on the device when p1 was taken; p1 was when p2 was
+    assert spans_named(tracer, "p1", "execute_prompt")[0]["attrs"]["ahead"] == 0
+    assert spans_named(tracer, "p2", "execute_prompt")[0]["attrs"]["ahead"] == 1
+    # and a prompt that arrives once both have landed is ahead of nothing
+    third = server.queue_prompt(graph(0.5, source="HeldImage"), "p3")
+    assert third.done.wait(10)
+    assert spans_named(tracer, "p3", "execute_prompt")[0]["attrs"]["ahead"] == 0
+    assert walks_total().value(ahead="1") == 1 and walks_total().value(ahead="0") == 2
+    text = get_metrics_registry().render()
+    assert 'cdt_walks_total{ahead="1"} 1' in text
+    assert 'cdt_walks_total{ahead="0"} 2' in text
+
+
+def test_a_walk_behind_a_held_encode_is_overlapped_and_not_ahead(server, tracer, encoder):
+    """What `ahead` counts is the read-back, not the save."""
+    encoder.hold.clear()
+    first = server.queue_prompt(graph(0.25), "p1")
+    assert encoder.entered.wait(10)  # p1's image has landed; its encode is held
+    second = server.queue_prompt(graph(0.75), "p2")
+    wait_until(lambda: walked(tracer, "p2"), "p2's walk has ended")
+    release(encoder, first, second)
+    assert spans_named(tracer, "p2", "execute_prompt")[0]["attrs"]["ahead"] == 0
+    assert spans_named(tracer, "p1", "png.encode")[0]["attrs"]["overlapped"] == 1
+    assert walks_total().value(ahead="0") == 2 and walks_total().value(ahead="1") == 0
+
+
+def test_the_served_paths_png_is_the_inline_paths_for_the_same_array(
+    server, out_dir, monkeypatch
+):
+    import jax.numpy as jnp
+
+    ramp = np.linspace(0.0, 1.0, 2 * 8 * 8 * 3, dtype=np.float32).reshape(2, 8, 8, 3)
+    on_device = jnp.asarray(ramp)
+
+    class DeviceRamp(HostImage):
+        def make(self, value):
+            return (on_device,)
+
+    monkeypatch.setitem(NODE_REGISTRY, "DeviceRamp", DeviceRamp)
+    job = server.queue_prompt(graph(0.0, prefix="served", source="DeviceRamp"), "p1")
+    assert job.done.wait(30) and job.error is None
+    GraphExecutor(ExecutionContext()).execute(graph(0.0, prefix="inline", source="DeviceRamp"))
+    for i in (0, 1):
+        served = (out_dir / f"served_{i:05d}.png").read_bytes()
+        assert served == (out_dir / f"inline_{i:05d}.png").read_bytes()
+        assert served == encode_png(ramp[i], compress_level=4)
+    assert job.outputs["2"][0]["images"] is on_device  # the walk made no copy of it
+
+
 def test_the_pending_gauge_is_in_the_scrape(two_in_flight, server, encoder):
     from comfyui_distributed_tpu.telemetry import bind_server_collectors
 
@@ -224,41 +353,58 @@ def test_the_pending_gauge_is_in_the_scrape(two_in_flight, server, encoder):
         unbind()
 
 
-def test_a_third_hand_off_blocks_until_the_running_save_ends(
-    two_in_flight, server, tracer, encoder
+@pytest.fixture(params=["encode", "read_back"])
+def held(request, encoder, read_back):
+    """What a test holds on the saver thread: the gate, the node whose
+    image passes it, and the span that is open meanwhile."""
+    if request.param == "encode":
+        return encoder, "HostImage", "png.encode"
+    return read_back, "HeldImage", "device.wait"
+
+
+def test_a_third_hand_off_blocks_until_the_first_file_is_written(
+    held, server, tracer, out_dir
 ):
-    first, second = two_in_flight
-    third = server.queue_prompt(graph(0.5), "p3")
-    fourth = server.queue_prompt(graph(0.6), "p4")
-    wait_until(lambda: spans_named(tracer, "p3", "device.wait")
-               and spans_named(tracer, "p3", "device.wait")[0]["end"] is not None,
+    gate, source, _ = held
+    gate.hold.clear()
+    jobs = [server.queue_prompt(graph(0.1 * i, source=source), f"p{i}") for i in (1, 2, 3, 4)]
+    assert gate.entered.wait(10)
+    wait_until(lambda: spans_named(tracer, "p3", "node.SaveImage"),
                "p3 has reached its hand-off")
     time.sleep(0.005)
     # one save runs (p1), one waits (p2): the executor thread is parked
-    # inside p3's SaveImage and has not taken p4
+    # inside p3's SaveImage and has not taken p4 (the gauge counts p3's
+    # save from the moment its hand-off began)
     assert spans_named(tracer, "p3", "node.SaveImage")[0]["end"] is None
     assert spans_named(tracer, "p4", "prompt_queue.wait")[0]["end"] is None
-    assert server.queue_remaining == 4
-    release(encoder, first, second, third, fourth)
+    assert server.queue_remaining == 4 and server.saves_pending == 3
+    assert os.listdir(out_dir) == []
+    release(gate, *jobs)
     ends = [spans_named(tracer, p, "file.write")[0]["end"] for p in ("p1", "p2", "p3", "p4")]
     assert ends == sorted(ends)
+    # p3's hand-off returned only once p1's file was there
+    assert spans_named(tracer, "p3", "node.SaveImage")[0]["end"] > ends[0]
 
 
 def test_a_failed_save_is_the_jobs_error_and_the_next_job_runs(
-    server, tracer, encoder, out_dir
+    held, server, tracer, out_dir
 ):
-    encoder.fail_next = True
-    failed = server.queue_prompt(graph(0.25), "p1")
-    fine = server.queue_prompt(graph(0.75), "p2")
+    gate, source, span = held
+    gate.fail_next = True
+    failed = server.queue_prompt(graph(0.25, source=source), "p1")
+    fine = server.queue_prompt(graph(0.75, source=source), "p2")
     assert failed.done.wait(10) and fine.done.wait(10)
-    assert failed.error == "OSError: No space left on device"
+    assert failed.error == f"{type(gate.error).__name__}: {gate.error}"
     execute = spans_named(tracer, "p1", "execute_prompt")[0]
     assert execute["status"] == "error" and execute["attrs"]["error"] == failed.error
-    assert spans_named(tracer, "p1", "png.encode")[0]["status"] == "error"
+    assert spans_named(tracer, "p1", span)[0]["status"] == "error"
+    assert gate.threads == ["cdt-saver", "cdt-saver"]  # it failed there, not in the walk
     assert fine.error is None
     # the failed job's reserved name is not reused
     assert sorted(os.listdir(out_dir)) == ["saved_00001.png"]
     assert (out_dir / "saved_00001.png").read_bytes() == expected_png(0.75)
+    # a read-back that raised has ended: nothing is ahead of it for ever
+    assert server._reading == 0 and server.saves_pending == 0
 
 
 def test_a_failed_walk_still_ends_execute_prompt_with_the_error(server, tracer):

@@ -175,14 +175,102 @@ def test_node_spans_carry_the_program_work_done_in_them(server, tracer):
     assert spans["node.SaveImage"][0]["attrs"] == {"node_id": "2"}
 
 
-def test_the_collector_read_back_is_a_device_wait(tracer):
+def collect(tracer, images, workers=(), context=None):
+    """The master's collector on `images`; its result and its waits."""
     from comfyui_distributed_tpu.graph.nodes_distributed import DistributedCollector
 
-    images = np.zeros((2, 4, 4, 3), np.float32)
     with tracer.span("node.DistributedCollector", trace_id="t"):
-        DistributedCollector().run(images)
-    (wait,) = by_name(tracer, "t")["device.wait"]
+        result, _ = DistributedCollector().run(
+            images, enabled_worker_ids=list(workers), context=context)
+    return result, by_name(tracer, "t").get("device.wait", [])
+
+
+def test_the_collector_hands_on_an_array_that_lies_whole_on_one_device(tracer):
+    """No worker to gather from, nothing to gather: no read-back, no
+    copy; the image leaves the device once, where it is saved."""
+    import jax.numpy as jnp
+
+    images = jnp.zeros((2, 4, 4, 3), jnp.float32)
+    for context in (None, ExecutionContext(server=object())):
+        result, waits = collect(tracer, images, context=context)
+        assert result is images and waits == []
+    # workers named and no server to drain them from: nobody to gather from
+    result, waits = collect(tracer, images, workers=["w1"])
+    assert result is images and waits == []
+
+
+def sharded_images():
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    return jax.device_put(
+        np.ones((2, 4, 4, 3), np.float32), NamedSharding(mesh, PartitionSpec("data")))
+
+
+@pytest.mark.parametrize("make", [
+    lambda: np.ones((2, 4, 4, 3), np.float32), sharded_images,
+], ids=["host data", "a sharded array"])
+def test_the_collector_read_back_is_a_device_wait(tracer, make):
+    import jax
+
+    images = make()
+    result, (wait,) = collect(tracer, images)
     assert wait["attrs"]["bytes"] == images.nbytes
+    # gathered on the host, then one array on one device, as before
+    assert isinstance(result, jax.Array) and len(result.sharding.device_set) == 1
+    np.testing.assert_array_equal(np.asarray(result), np.asarray(images))
+
+
+def test_the_collector_with_enabled_workers_reads_its_own_batch_back(tracer, monkeypatch):
+    """The elastic tier concatenates on the host: a one-device array is
+    read back there, as before, and the workers' images follow it."""
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.graph.nodes_distributed import DistributedCollector
+
+    theirs = np.full((4, 4, 3), 0.5, np.float32)
+    monkeypatch.setattr(
+        DistributedCollector, "_drain_worker_results",
+        lambda self, server, job_id, workers, context: [
+            {"worker_id": "w1", "batch_idx": 0, "tensor": theirs}])
+    images = jnp.zeros((2, 4, 4, 3), jnp.float32)
+    result, (wait,) = collect(
+        tracer, images, workers=["w1"], context=ExecutionContext(server=object()))
+    assert wait["attrs"]["bytes"] == images.nbytes
+    assert result is not images and result.shape == (3, 4, 4, 3)
+    np.testing.assert_array_equal(np.asarray(result[2]), theirs)
+
+
+def test_a_served_image_crosses_to_the_host_once_below_save_image(
+    server, tracer, monkeypatch
+):
+    """Source -> collector -> save, as the txt2img graphs end: one
+    `device.wait` in the whole request, the saver thread's."""
+    import jax.numpy as jnp
+
+    on_device = jnp.full((1, 8, 8, 3), 0.5, jnp.float32)
+
+    class DeviceImage(SpanTestImage):
+        def make(self, value):
+            return (on_device,)
+
+    monkeypatch.setitem(NODE_REGISTRY, "DeviceImage", DeviceImage)
+    job = server.queue_prompt({
+        "1": {"class_type": "DeviceImage", "inputs": {"value": 0.5}},
+        "2": {"class_type": "DistributedCollector", "inputs": {"images": ["1", 0]}},
+        "3": {"class_type": "SaveImage",
+              "inputs": {"images": ["2", 0], "filename_prefix": "once"}},
+    }, "p1")
+    run_queued(server)
+    assert job.error is None
+    spans = by_name(tracer, "p1")
+    (wait,) = spans["device.wait"]
+    assert wait["parent_id"] == spans["node.SaveImage"][0]["span_id"]
+    assert wait["attrs"]["bytes"] == on_device.nbytes
+    # the walk handed the very array on: SaveImage's own output is it
+    assert job.outputs["3"][0]["images"] is on_device
+    assert spans["execute_prompt"][0]["attrs"]["ahead"] == 0
 
 
 def test_last_timings_and_counts_without_a_server(tmp_path, monkeypatch, tracer):
