@@ -39,11 +39,16 @@ Drives the main path once, through the entry points an operator uses:
                chunked delta rule over one KDA layer at Ling's and at
                Solar-Open2's heads (`KDA_DELTA_SHAPES`): the kernel
                (`ops/kda_delta`) against the XLA form, us a (head,
-               chunk) of each and the kernel at other heads a step.
+               chunk) of each and the kernel at other heads a step; a
+               Mamba-2 block's chunked scan at Nemotron-3-Nano's sizes
+               (`SSD_SHAPE`) against the recurrence token by token, and
+               a decode step's update of its 23 states.
     experts    one child that holds the chip runs a decode step's two
                grouped products (gate-up, SiLU, down) over the held
                experts' stacked weights at the four models' decode
-               shapes, and DeepSeek's at 8 rows beside its 6: the
+               shapes, DeepSeek's at 8 rows beside its 6, and the fifth
+               model's experts without a gate at 1,856 columns (up
+               stored out by in, relu squared, down): the
                kernel (`ops/expert_matvec`) against `jax.lax.ragged_dot`,
                a step's routing drawn anew inside one jitted loop, both
                against a float32 reference; it prints us a step, GB/s
@@ -858,13 +863,15 @@ REHEARSAL_SHAPES = (
 # band of 128; Ouro 2,048 tokens at 16 over 16; DeepSeek-V2's MLA 2,048
 # tokens at 128 heads, q and k 192 wide beside a v of 128 and a scale of
 # its own; Ling-3.0-flash's one MLA layer the same widths at 32 heads over
-# 8,192 tokens (PR 45).
+# 8,192 tokens (PR 45); Nemotron-3-Nano's six attention blocks 8,192 tokens
+# at 32 query heads over 2 key heads, 16 queries a key head (PR 48).
 CAUSAL_SHAPES = (
     ("solar / k-exaone full 8192", (1, 8192, 64, 128), 8, 128, None),
     ("k-exaone window 8192", (1, 8192, 64, 128), 8, 128, 128),
     ("ouro 2048", (1, 2048, 16, 128), 16, 128, None),
     ("deepseek-v2 mla 2048", (1, 2048, 128, 192), 128, 128, None),
     ("ling-flash mla 8192", (1, 8192, 32, 192), 32, 128, None),
+    ("nemotron3-nano 32:2 8192", (1, 8192, 32, 128), 2, 128, None),
 )
 REHEARSAL_CAUSAL_SHAPES = (
     ("toy causal grouped", (1, 1280, 4, 128), 2, 128, None),
@@ -1021,6 +1028,7 @@ def attention_child(rehearsal: bool) -> int:
     failed += not kda_keep_row(rehearsal)
     for shape in REHEARSAL_KDA_DELTA_SHAPES if rehearsal else KDA_DELTA_SHAPES:
         failed += not kda_delta_row(rehearsal, *shape)
+    failed += not ssd_row(rehearsal, *(REHEARSAL_SSD_SHAPE if rehearsal else SSD_SHAPE))
     return 1 if failed else 0
 
 
@@ -1323,6 +1331,82 @@ def kda_delta_row(rehearsal: bool, label, tokens, heads, d, chunk) -> bool:
     return row["ok"]
 
 
+# A Mamba-2 block's recurrence in its two forms (`models/mamba2`), as
+# Nemotron-3-Nano's cell runs them: the chunked scan over the 8,192-token
+# prompt (64 heads of 64 over a state of 128, B and C in 8 groups, chunks
+# of 128) and the decode's step over 23 blocks' states inside one jitted
+# loop: (label, tokens, heads, width, groups, state, chunk, blocks).
+SSD_SHAPE = ("nemotron3-nano mamba-2", 8192, 64, 64, 8, 128, 128, 23)
+REHEARSAL_SSD_SHAPE = ("toy mamba-2", 75, 4, 8, 2, 16, 32, 3)
+SSD_TOLERANCE = 2e-2  # bfloat16 operands against the float32 recurrence, of the largest entry
+
+
+def ssd_row(rehearsal: bool, label, tokens, heads, width, groups, n, chunk, blocks) -> bool:
+    """The chunked scan over a whole prompt (bfloat16 operands as
+    stored, float32 state) against the recurrence token by token in
+    float32: ms a block's prefill and the largest difference of outputs
+    and final states relative to the largest entry; then a decode step's
+    update of `blocks` states (`ssm_step`, a block an iteration of one
+    jitted loop): us a block and GB/s of the states read and written."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models import mamba2
+
+    @jax.jit
+    def operands(key):
+        keys = jax.random.split(key, 6)
+        u = jax.random.normal(keys[0], (tokens, heads, width))
+        b = jax.random.normal(keys[1], (tokens, groups, n)) * n ** -0.5
+        c = jax.random.normal(keys[2], (tokens, groups, n))
+        steps = mamba2.init_steps(keys[3], (heads,), 0.001, 0.1, 1e-4)
+        step = jax.nn.softplus(jax.random.normal(keys[4], (tokens, heads)) + steps["dt_bias"])
+        return u, b, c, step, -jnp.exp(steps["a_log"]), jnp.zeros((heads, width, n))
+
+    u, b, c, step, a, state = operands(jax.random.key(tokens + heads))
+    low = tuple(t.astype(jnp.bfloat16) for t in (u, b, c))
+    chunked = jax.jit(functools.partial(mamba2.ssd_chunked, chunk=chunk))
+
+    @jax.jit
+    def recurrence(u, b, c, step, a, state):
+        def token(state, xs):
+            y, state = mamba2.ssm_step(*xs, a, state)
+            return state, y
+        state, y = jax.lax.scan(token, state, (u, b, c, step))
+        return y, state
+
+    (y, after), first_s, ms = timed(chunked, *low, step, a, state)
+    y_ref, after_ref = recurrence(*(t.astype(jnp.float32) for t in low), step, a, state)
+    row = {"shape": label, "tokens": tokens, "heads": heads, "width": width, "groups": groups,
+           "state": n, "chunk": chunk, "dtype": "bfloat16",
+           "chunked": {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}, "ok": True}
+    for what, mine, theirs in (("y", y, y_ref), ("state", after, after_ref)):
+        diff = float(jnp.max(jnp.abs(mine - theirs)) / jnp.max(jnp.abs(theirs)))
+        row[f"max_rel_diff_{what}"] = round(diff, 6)
+        row["ok"] &= diff <= SSD_TOLERANCE  # a NaN fails it too
+
+    @jax.jit
+    def step_over_blocks(u, b, c, step, a, states):
+        def block(_, state):
+            y, state = mamba2.ssm_step(u, b, c, step, a, state)
+            return None, (y, state)
+        return jax.lax.scan(block, None, states)[1]
+
+    states = jnp.broadcast_to(after_ref, (blocks, *after_ref.shape)) + 0.0
+    one = (u[0], b[0], c[0], step[0], a)
+    (ys, stepped), first_s, ms = timed(step_over_blocks, *one, states)
+    want_y, want_state = mamba2.ssm_step(*one, after_ref)
+    row["ok"] &= bool(np.allclose(np.asarray(stepped[-1]), np.asarray(want_state), atol=1e-5))
+    row["ok"] &= bool(np.allclose(np.asarray(ys[0]), np.asarray(want_y), atol=1e-4))
+    moved = 2 * blocks * heads * width * n * 4
+    row["step"] = {"blocks": blocks, "first_call_s": round(first_s, 2),
+                   "us_a_block": round(1e3 * ms / blocks, 2),
+                   "gb_per_s": round(moved / (1e-3 * ms) / 1e9, 1)}
+    print(json.dumps(row), flush=True)
+    return row["ok"]
+
+
 # --- the experts child -------------------------------------------------------
 
 # (label, token-expert pairs a step, experts a token, held experts,
@@ -1347,6 +1431,17 @@ EXPERT_SHAPES = (
 REHEARSAL_EXPERT_SHAPES = (
     ("toy step off the sublane tile", 6, 3, 4, 16, 128, 64),
     ("toy two positions", 16, 8, 4, 16, 256, 128),
+)
+# The same tuple for experts without a gate, relu(x W_up)^2 W_down, whose
+# up-projection is stored out by in (`models/moe.gated`): Nemotron-3-Nano
+# a sixteenth of 128, 6 a token, 1,856 columns, off the lane tile: the
+# kernel's `[2688] -> [1856]` walk by rows of the stored array and its
+# `[1856] -> [2688]` walk by columns (PR 48).
+RELU2_EXPERT_SHAPES = (
+    ("nemotron3-nano step", 6, 6, 8, 128, 2688, 1856),
+)
+REHEARSAL_RELU2_EXPERT_SHAPES = (
+    ("toy step without a gate", 6, 3, 4, 16, 128, 48),
 )
 EXPERT_STEPS = 64
 
@@ -1374,61 +1469,76 @@ def experts_child(rehearsal: bool) -> int:
     if not child_device(rehearsal):
         return 1
     from comfyui_distributed_tpu.models.moe import decode_route
-    from comfyui_distributed_tpu.ops.expert_matvec import expert_matvec
+    from comfyui_distributed_tpu.ops.expert_matvec import expert_matvec, grouped_xla
 
-    def steps_of(grouped, dtype=None):
-        """Every step's two products, a step an iteration of one loop."""
-        def loop(x, w_gate_up, w_down, sizes):
+    def steps_of(grouped, gated, dtype=None):
+        """Every step's two products, a step an iteration of one loop: a
+        SwiGLU's, or relu squared between an up-projection stored out by
+        in and a down-projection."""
+        def loop(x, w_first, w_down, sizes):
             def body(_, step):
                 rows, sizes_i = step
-                gate, up = jnp.split(grouped(rows, w_gate_up, sizes_i), 2, axis=-1)
-                return None, grouped(jax.nn.silu(gate) * up, w_down, sizes_i)
+                if gated:
+                    gate, up = jnp.split(grouped(rows, w_first, sizes_i), 2, axis=-1)
+                    middle = jax.nn.silu(gate) * up
+                else:
+                    middle = jnp.square(jax.nn.relu(grouped(rows, w_first, sizes_i, out_major=True)))
+                return None, grouped(middle, w_down, sizes_i)
             if dtype is not None:
-                x, w_gate_up, w_down = (a.astype(dtype) for a in (x, w_gate_up, w_down))
+                x, w_first, w_down = (a.astype(dtype) for a in (x, w_first, w_down))
             return jax.lax.scan(body, None, (x, sizes))[1]
         return jax.jit(loop)
 
-    def in_float32(rows, weights, sizes):
+    def in_float32(rows, weights, sizes, out_major=False):
         # on whole sublane tiles of rows: at 6 rows the compiler's float32
         # lowering read 3.4 off both bfloat16 forms, which agree with each
         # other to the digit and with the model's reference (PERF.md §6, PR 42)
+        # weights stored out by in are turned round first: in float32 the compiler's
+        # grouped product over the stored array read 5.6 off both bfloat16 forms, which
+        # agree with each other to the digit (my chip run, PR 48)
         padded = jnp.pad(rows, ((0, -rows.shape[0] % 8), (0, 0)))
+        if out_major:
+            weights = weights.swapaxes(1, 2)
         return jax.lax.ragged_dot(
             padded, weights, sizes, precision=jax.lax.Precision.HIGHEST)[:rows.shape[0]]
 
     failed = 0
     steps = 4 if rehearsal else EXPERT_STEPS
-    for label, rows, k, held, experts, hidden, width in (
-            REHEARSAL_EXPERT_SHAPES if rehearsal else EXPERT_SHAPES):
+    shapes = [(True, *shape) for shape in (
+        REHEARSAL_EXPERT_SHAPES if rehearsal else EXPERT_SHAPES)] + [(False, *shape) for shape in (
+            REHEARSAL_RELU2_EXPERT_SHAPES if rehearsal else RELU2_EXPERT_SHAPES)]
+    for gated, label, rows, k, held, experts, hidden, width in shapes:
         keys = jax.random.split(jax.random.key(hidden + rows), 3)
         x = jax.random.normal(keys[0], (steps, rows, hidden), jnp.bfloat16)
+        first = (held, hidden, 2 * width) if gated else (held, width, hidden)
         w_gate_up = jax.jit(lambda key: hidden ** -0.5 * jax.random.normal(
-            key, (held, hidden, 2 * width), jnp.bfloat16))(keys[1])
+            key, first, jnp.bfloat16))(keys[1])
         w_down = jax.jit(lambda key: width ** -0.5 * jax.random.normal(
             key, (held, width, hidden), jnp.bfloat16))(keys[2])
         sizes = step_sizes(rows * 1000 + held, steps, rows, k, held, experts)
         # what a step has to read: the chosen held experts' matrices
-        step_bytes = np.count_nonzero(sizes, axis=1).mean() * 3 * hidden * width * 2
-        route = decode_route(rows, hidden, width, jnp.bfloat16)
+        matrices = 3 if gated else 2
+        step_bytes = np.count_nonzero(sizes, axis=1).mean() * matrices * hidden * width * 2
+        route = decode_route(rows, hidden, width, jnp.bfloat16, gated)
         row = {
             "shape": label, "rows": rows, "held": held, "hidden": hidden, "width": width,
-            "dtype": "bfloat16", "route": route,
+            "dtype": "bfloat16", "gated": gated, "route": route,
             "chosen_a_step": round(float(np.count_nonzero(sizes, axis=1).mean()), 3),
             "held_pairs_a_step": round(float(sizes.sum(axis=1).mean()), 3),
             "mb_a_step": round(step_bytes / 1e6, 2),
             "ok": rehearsal or route == "kernel",
         }
         operands = (x, w_gate_up, w_down, jnp.asarray(sizes))
-        ref = np.asarray(steps_of(in_float32, jnp.float32)(*operands))
+        ref = np.asarray(steps_of(in_float32, gated, jnp.float32)(*operands))
         # a row past the step's held pairs is nobody's: the kernel leaves
         # it zero, `ragged_dot` what it likes
         mine = (np.arange(rows)[None, :] < sizes.sum(axis=1)[:, None])[:, :, None]
         scale = max(1.0, float(np.abs(np.where(mine, ref, 0.0)).max()))
         for name, grouped in (
             ("kernel", functools.partial(expert_matvec, interpret=rehearsal)),
-            ("xla", jax.lax.ragged_dot),
+            ("xla", grouped_xla),
         ):
-            fn = steps_of(grouped)
+            fn = steps_of(grouped, gated)
             if name == "xla" and not rehearsal:
                 # off the sublane tile the compiler has no grouped kernel
                 # of its own and multiplies under a mask a group

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import kda_delta
-from .lm_common import rms_norm
+from .lm_common import rms_norm, short_conv
 
 # Rows of a chunk whose pairwise decays are formed pair by pair
 # (`decay_products`); between such blocks they go through one product.
@@ -45,10 +45,8 @@ def conv_qkv(projected, filters, tail, heads: int, d: int):
     convolution, SiLU, the norms, all float32. Also returns the inputs
     themselves, tail first, [kernel - 1 + T, 3 H d]: the rows after
     token t's are the tail token t leaves."""
-    tokens, kernel = projected.shape[0], filters.shape[0]
-    window = jnp.concatenate([tail, projected.astype(tail.dtype)], axis=0)
-    filters = filters.astype(jnp.float32)
-    mixed = sum(window[i:i + tokens].astype(jnp.float32) * filters[i] for i in range(kernel))
+    tokens = projected.shape[0]
+    mixed, window = short_conv(projected, filters, tail)
     q, k, v = jnp.split(jax.nn.silu(mixed).reshape(tokens, 3 * heads, d), 3, axis=1)
     q, k, v = (a.astype(projected.dtype) for a in (_l2norm(q) * d ** -0.5, _l2norm(k), v))
     return q, k, v, window

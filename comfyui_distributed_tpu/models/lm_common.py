@@ -1,5 +1,5 @@
 """What the language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`, `k_exaone.py`, `ling_flash.py`): the blocks and helpers
+`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`): the blocks and helpers
 they are written from, the one initialisation rule, sampling on the
 device, the rule by which a drafted token is kept or replaced, the
 decode loop, and the stand-in tokenizer.
@@ -67,6 +67,27 @@ def clamped_silu_product(gate: jax.Array, up: jax.Array, limit: float = 0.0) -> 
 def swiglu(x: jax.Array, p: dict, limit: float = 0.0) -> jax.Array:
     gate, up = jnp.split(x @ p["w_gate_up"], 2, axis=-1)
     return clamped_silu_product(gate, up, limit) @ p["w_down"]
+
+
+def relu2_mlp(x: jax.Array, p: dict) -> jax.Array:
+    """An ungated feed-forward part, two matrices: relu(x W_up)^2 W_down."""
+    return jnp.square(jax.nn.relu(x @ p["w_up"])) @ p["w_down"]
+
+
+def short_conv(projected: jax.Array, filters: jax.Array, tail: jax.Array):
+    """A causal depth-wise convolution over the token axis, float32:
+    `projected` [T, channels], `filters` [kernel, channels], `tail`
+    [kernel - 1, channels] the inputs of the tokens before. Returns
+    (the sums [T, channels] float32, before any bias or activation, and
+    the inputs themselves, tail first, [kernel - 1 + T, channels]: the
+    rows after token t's are the tail token t leaves). What a KDA layer
+    runs over its q, k and v projections (`kda.conv_qkv`) and a Mamba-2
+    layer over x, B and C (`mamba2.mixer_inputs`)."""
+    tokens, kernel = projected.shape[0], filters.shape[0]
+    window = jnp.concatenate([tail, projected.astype(tail.dtype)], axis=0)
+    filters = filters.astype(jnp.float32)
+    mixed = sum(window[i:i + tokens].astype(jnp.float32) * filters[i] for i in range(kernel))
+    return mixed, window
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -216,21 +237,21 @@ def mlp_shapes(hidden: int, width: int) -> dict:
     return {"w_gate_up": ((hidden, 2 * width), hidden), "w_down": ((width, hidden), width)}
 
 
-def _is_spec(x) -> bool:
+def is_spec(x) -> bool:
     return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
 
 
 def count_params(shapes: dict[str, Any]) -> int:
-    specs = jax.tree_util.tree_leaves(shapes, is_leaf=_is_spec)
+    specs = jax.tree_util.tree_leaves(shapes, is_leaf=is_spec)
     return sum(math.prod(shape) for shape, _ in specs)
 
 
 @partial(jax.jit, static_argnames=("shape", "std", "dtype"))
 def _normal(key, shape, std, dtype):
-    if len(shape) == 3 and math.prod(shape) >= 2**28:
-        # a stack of experts or of layers, one at a time: the float32
-        # draw of a whole stack (2.5 GB at the published widths) is never
-        # alive at once
+    if len(shape) >= 3 and math.prod(shape) >= 2**28:
+        # a stack of experts or of layers (or a run's stack of stacks),
+        # one at a time: the float32 draw of a whole stack (2.5 GB at
+        # the published widths) is never alive at once
         keys = jax.random.split(key, shape[0])
         return jax.lax.map(
             lambda k: (jax.random.normal(k, shape[1:], jnp.float32) * std).astype(dtype), keys
@@ -242,7 +263,7 @@ def init_from_shapes(shapes: dict[str, Any], key, dtype=jnp.float32) -> dict[str
     """Seeded random weights built in `dtype`, weight by weight: normal
     with standard deviation fan_in^-1/2, so activations keep their scale
     through the depth (and a router's logits spread by about one)."""
-    specs, treedef = jax.tree_util.tree_flatten(shapes, is_leaf=_is_spec)
+    specs, treedef = jax.tree_util.tree_flatten(shapes, is_leaf=is_spec)
     dtype = jnp.dtype(dtype)
     leaves = []
     for index, (shape, fan_in) in enumerate(specs):

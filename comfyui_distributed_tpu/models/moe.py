@@ -1,6 +1,6 @@
 """The expert layer of one chip, as the language models with a mixture
 of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`,
-`ling_flash.py`), and the routing rule of those that score by sigmoids
+`ling_flash.py`, `nemotron_h.py`), and the routing rule of those that score by sigmoids
 (`sigmoid_route`, with or without groups chosen first).
 
 The layer is told which routed experts it holds (`held`, a contiguous
@@ -23,10 +23,17 @@ run, on a TPU, in the Pallas kernel of `ops/expert_matvec.py`, which
 reads each chosen held expert's weights once, out of the stacked array
 (`decode_route`); the prefill's stay `ragged_dot`.
 
-Parameters: `w_g` [hidden, experts] (the router), `experts` {`w_gate_up`
-[held, hidden, 2 x width], `w_down` [held, width, hidden]}, `shared` (one
-SwiGLU); whatever else the model's rule reads (a selection bias) stays
-with the rule.
+Parameters: `w_g` [hidden, experts] (the router), `experts` and `shared`
+in the expert's form, which the tree itself says (`gated`): a SwiGLU,
+{`w_gate_up` [held, hidden, 2 x width], `w_down` [held, width, hidden]}
+and `shared` one such, or two matrices without a gate, relu(x W_up)^2
+W_down (Nemotron-H's): {`w_up` [held, width, hidden], stored out by in
+as a checkpoint stores a projection, because the width may be off the
+lane tile and the hidden size is not (`ops/expert_matvec`), `w_down`
+[held, width, hidden]} and `shared` {`w_up` [hidden, width], `w_down`};
+whatever else the model's rule reads (a selection bias) stays with the
+rule. A model that scans over stacked layers hands the routed experts'
+stacks whole with the layer's index (`index`).
 """
 
 from __future__ import annotations
@@ -38,8 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.expert_matvec import expert_matvec, expert_matvec_route
-from .lm_common import clamped_silu_product, swiglu
+from ..ops.expert_matvec import expert_matvec, expert_matvec_route, grouped_xla
+from .lm_common import clamped_silu_product, relu2_mlp, swiglu
 
 
 # A rung of the ladder below the top is a whole number of these rows.
@@ -96,30 +103,40 @@ def sigmoid_route(logits: jax.Array, bias: jax.Array, k: int, scale: float = 1.0
     return ids, weights * scale
 
 
-def decode_route(rows: int, hidden: int, width: int, dtype) -> str:
+def gated(expert: dict) -> bool:
+    """The form of an expert's tree (routed stack or shared expert): a
+    SwiGLU (`w_gate_up`, `w_down`), else two matrices without a gate
+    (`w_up`, `w_down`: relu squared between them)."""
+    return "w_gate_up" in expert
+
+
+def decode_route(rows: int, hidden: int, width: int, dtype, with_gate: bool = True) -> str:
     """How a layer of `rows` token-expert pairs runs its two grouped
-    products (`[hidden, 2 x width]`, then `[width, hidden]`): "kernel"
+    products (`[hidden, 2 x width]`, or `[hidden, width]` for an expert
+    without a gate, then `[width, hidden]`): "kernel"
     where the ladder has one rung because the pairs are a tile or less
     (a decode step) and `ops/expert_matvec` takes both shapes on this
     backend, else "xla" (`jax.lax.ragged_dot`). The layer asks while it
     is traced, a model's `report` afterwards."""
     if rows > ROW_TILE:
         return "xla"
-    routes = {
-        expert_matvec_route(rows, k, n, dtype)
-        for k, n in ((hidden, 2 * width), (width, hidden))
-    }
+    up = (expert_matvec_route(rows, hidden, 2 * width, dtype) if with_gate
+          else expert_matvec_route(rows, hidden, width, dtype, out_major=True))
+    routes = {up, expert_matvec_route(rows, width, hidden, dtype)}
     return "kernel" if routes == {"kernel"} else "xla"
 
 
 def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
-                 limit: float = 0.0, shared_limit: float = 0.0):
+                 limit: float = 0.0, shared_limit: float = 0.0, index=None):
     """x [T, hidden] through the layer. `route(logits)` takes the
     router's float32 logits [T, experts] and returns (ids [T, k],
     weights [T, k] float32). `limit` and `shared_limit` clamp the routed
     experts' and the shared expert's SwiGLU (`lm_common.
     clamped_silu_product`; 0: none), on either route of the grouped
-    products. Returns (output, chosen ids [T, k], pairs on each held
+    products; an expert without a gate (`gated`) has nothing to clamp.
+    With `index` (a traced scalar: a scan's body) `p["experts"]` holds
+    stacks of layers `[layers, held, ...]` of which this layer is that
+    one, read where it lies. Returns (output, chosen ids [T, k], pairs on each held
     expert [held])."""
     with jax.named_scope("router"):
         logits = jnp.dot(
@@ -140,9 +157,11 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
 
         # a decode step's few rows: each chosen held expert's weights
         # read once where they lie (`ops/expert_matvec`)
-        w_down = p["experts"]["w_down"]
-        how = decode_route(tokens * k, w_down.shape[2], w_down.shape[1], w_down.dtype)
-        grouped = expert_matvec if how == "kernel" else jax.lax.ragged_dot
+        experts = p["experts"]
+        w_down = experts["w_down"]
+        how = decode_route(
+            tokens * k, w_down.shape[-1], w_down.shape[-2], w_down.dtype, gated(experts))
+        grouped = expert_matvec if how == "kernel" else grouped_xla
 
         def over(rows_n: int):
             """The held experts' part [T, hidden] float32 from the first
@@ -150,9 +169,14 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
             top = order[:rows_n]
             token = top // k
             rows = x[token]
-            gate, up = jnp.split(grouped(rows, p["experts"]["w_gate_up"], sizes), 2, axis=-1)
-            out = grouped(
-                clamped_silu_product(gate, up, limit), p["experts"]["w_down"], sizes)
+            if gated(experts):
+                gate, up = jnp.split(
+                    grouped(rows, experts["w_gate_up"], sizes, index), 2, axis=-1)
+                middle = clamped_silu_product(gate, up, limit)
+            else:
+                middle = jnp.square(jax.nn.relu(
+                    grouped(rows, experts["w_up"], sizes, index, out_major=True)))
+            out = grouped(middle, w_down, sizes, index)
             # rows past the last segment are absent experts' pairs: weight 0
             out = jnp.where(here[top][:, None], out, 0).astype(jnp.float32)
             out = out * weights.reshape(-1)[top][:, None]
@@ -176,7 +200,8 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
             routed = jax.lax.switch(
                 rung_index(ladder, sizes.sum()), [partial(over, rows_n) for rows_n in ladder])
     with jax.named_scope("shared"):
-        shared = swiglu(x, p["shared"], shared_limit)
+        shared = (swiglu(x, p["shared"], shared_limit) if gated(p["shared"])
+                  else relu2_mlp(x, p["shared"]))
     return shared + routed.astype(x.dtype), ids, sizes
 
 

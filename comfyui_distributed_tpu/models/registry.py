@@ -22,6 +22,7 @@ from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
 from .k_exaone import KExaone, KExaoneConfig
 from .ling_flash import LingFlash, LingFlashConfig
+from .nemotron_h import NemotronH, NemotronHConfig
 from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .t5_encoder import T5Encoder, T5EncoderConfig
 from .text_encoder import TextEncoder, TextEncoderConfig
@@ -575,6 +576,31 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             ep_size=8, ep_rank=0, vocab_shards=8,
         ),
     },
+    # NVIDIA-Nemotron-3-Nano-30B-A3B as one chip's share of two eight-chip
+    # hosts that hold the whole model, every width as published and at full
+    # depth: all 52 blocks of the published string (23 Mamba-2, 23 sparse, 6
+    # attention), experts 0-7 of 128 (rank 0 of 16), the first eighth of the
+    # vocabulary (the benchmark's nemotron-3-nano-30b-a3b configuration says
+    # what the cut stands for, and why it is not the eight-way one)
+    "nemotron3-nano-ep16-52l": {
+        "family": "lm",
+        "config": NemotronHConfig(ep_size=16, ep_rank=0, vocab_shards=8),
+    },
+    # every mechanism at a size for the CPU: all three kinds of block and
+    # `EM` runs of two lengths (2 and 3 pairs: one M, a run, attention, a
+    # run, one E), 4 Mamba-2 heads of 8 over a state of 16 in 2 groups,
+    # chunks of 32, 4 query heads over 1 key head of 16, 16 experts of 24
+    # columns (off any tile; 3 a token) of which rank 0 of 8 holds two
+    "tiny-nemotron3-nano": {
+        "family": "lm",
+        "config": NemotronHConfig(
+            hidden_size=64, hybrid_override_pattern="MEMEM*EMEMEME", mamba_num_heads=4,
+            mamba_head_dim=8, ssm_state_size=16, n_groups=2, chunk_size=32,
+            num_attention_heads=4, num_key_value_heads=1, head_dim=16, n_routed_experts=16,
+            moe_intermediate_size=24, moe_shared_expert_intermediate_size=48,
+            num_experts_per_tok=3, vocab_size=4096, ep_size=8, ep_rank=0, vocab_shards=8,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -632,6 +658,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     SolarOpen2Config: SolarOpen2,
     KExaoneConfig: KExaone,
     LingFlashConfig: LingFlash,
+    NemotronHConfig: NemotronH,
 }
 
 

@@ -34,8 +34,15 @@ _TESTS = os.path.join(
 # PR 45 appended a fifth model's cell to the lists that check had pinned
 # as PR 41 left them (K-EXAONE's cell the last of each): its form that
 # holds whoever came last is in `test_ling_flash_readers.py`.
-_LISTED = "test_the_lm_cells_are_listed_where_their_readers_find_something_whoever_came_last"
+#
+# PR 48 appended a sixth model's cell and two metrics, and that form had
+# in its turn pinned PR 45's state (Ling's cell and its two metrics the last
+# of each list): `test_nemotron3_nano_readers.py` has the one that holds the
+# order the PRs came in and no one's place at the end.
+_LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_in_the_order_"
+           "they_came")
 _SUPERSEDED = {
+    "test_the_lm_cells_are_listed_where_their_readers_find_something_whoever_came_last": _LISTED,
     "test_device_every_new_metric_has_its_reader_and_names_its_cells":
         "test_device_the_twelve_metrics_of_pr_36_have_their_readers_and_lie_together",
     "test_device_the_lm_readers_find_a_models_work_by_the_checkpoint_the_workflow_loads":

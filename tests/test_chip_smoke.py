@@ -205,6 +205,47 @@ def test_the_legs_have_the_fifth_models_rows_at_its_own_numbers(smoke):
     assert smoke.KDA_STATES[1:] == (cfg.kda_layers, cfg.num_attention_heads, cfg.head_dim)
 
 
+def test_the_legs_have_the_sixth_models_rows_at_its_own_numbers(smoke):
+    """Nemotron-3-Nano's rows (PR 48): the `[2688, 1856]` / `[1856, 2688]`
+    `expert_matvec` shapes of its experts without a gate, the 32 : 2
+    causal row at the cell's prompt, and a Mamba-2 block's chunk and step
+    over its 23 states."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("nemotron3-nano-ep16-52l")
+    (step,) = smoke.RELU2_EXPERT_SHAPES
+    assert step == ("nemotron3-nano step", cfg.num_experts_per_tok, cfg.num_experts_per_tok,
+                    len(cfg.held_experts), cfg.n_routed_experts, cfg.hidden_size,
+                    cfg.moe_intermediate_size)
+    causal = {row[0]: row[1:] for row in smoke.CAUSAL_SHAPES}
+    (_, tokens, heads, width), kv_heads, v_width, band = causal["nemotron3-nano 32:2 8192"]
+    assert (heads, width, kv_heads, v_width, band) == (
+        cfg.num_attention_heads, cfg.head_dim, cfg.num_key_value_heads, cfg.head_dim, None)
+    with open(os.path.join(REPO_ROOT, "workflows", "rewrite-txt2img-nemotron3-nano.json")) as fh:
+        (text,) = [node["inputs"]["text"] for node in json.load(fh).values()
+                   if node["class_type"] == "TextGenerate"]
+    assert tokens == 1 + len(text.encode("utf-8"))
+    assert smoke.SSD_SHAPE[1:] == (
+        tokens, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
+        cfg.chunk_size, len(cfg.blocks_of("M")))
+
+
+def test_the_mamba_row_holds_the_chunked_scan_to_the_recurrence(smoke, capsys):
+    """The rehearsal's toy row on the CPU: a length that is no whole
+    number of chunks, bfloat16 operands against the float32 recurrence,
+    and a step over three blocks' states."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("the rehearsal's row is the CPU's")
+    assert smoke.ssd_row(True, *smoke.REHEARSAL_SSD_SHAPE)
+    (row,) = _result_lines(capsys.readouterr().out)
+    assert row["ok"] and row["max_rel_diff_y"] < smoke.SSD_TOLERANCE
+    assert row["max_rel_diff_state"] < smoke.SSD_TOLERANCE
+    assert set(row["step"]) == {"blocks", "first_call_s", "us_a_block", "gb_per_s"}
+    assert row["step"]["blocks"] == 3
+
+
 def test_the_kda_row_agrees_with_itself_in_both_forms(smoke, capsys):
     """Two slots and a flipped bit against one slot and a select, at the
     rehearsal's toy size on the CPU: the same states, the same outputs."""

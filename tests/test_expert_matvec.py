@@ -2,8 +2,9 @@
 (`ops/expert_matvec.py`), interpreted on the CPU: against
 `jax.lax.ragged_dot` at the row counts a decode step has, the plan and
 the route from the shape, and `models/moe.expert_layer` through the
-kernel against the same layer through `ragged_dot` for the three models
-that call it, with the prefill left where it was."""
+kernel against the same layer through `ragged_dot` for the models that
+call it (four with SwiGLU experts, one whose experts have no gate and an
+up-projection stored out by in), with the prefill left where it was."""
 
 import dataclasses
 import functools
@@ -13,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, ling_flash, moe, solar_open2
+from comfyui_distributed_tpu.models import (
+    deepseek_v2, k_exaone, ling_flash, moe, nemotron_h, solar_open2)
 from comfyui_distributed_tpu.models.registry import get_config
 from comfyui_distributed_tpu.ops import expert_matvec as em
 
@@ -65,6 +67,41 @@ def test_kernel_gives_what_ragged_dot_gives(rows, groups, case, dtype, tolerance
         np.asarray(got[:held], np.float32), np.asarray(want[:held], np.float32),
         rtol=tolerance, atol=tolerance)
     assert not np.asarray(got[held:], np.float32).any()
+
+
+N_OFF = 240  # off the lane tile: 1.875 tiles, as 1,856 is 14.5
+
+
+@pytest.mark.parametrize("dtype,tolerance,blocks", [(jnp.bfloat16, 2e-2, 3), (jnp.float32, 1e-4, 6)])
+@pytest.mark.parametrize("case", [
+    "empty groups between", "two rows on one expert", "no held row", "all rows held",
+    "all rows on the first"])
+@pytest.mark.parametrize("rows", [6, 16])
+def test_kernel_gives_what_ragged_dot_gives_over_weights_stored_out_by_in(
+        rows, case, dtype, tolerance, blocks):
+    """A width off the lane tile, the weights [groups, N, K]: the kernel
+    walks blocks of rows of the stored array (80 of 240 in bfloat16, 40
+    in float32), each block's product final; `ragged_dot` over the
+    transposes says what it has to give."""
+    groups = 16
+    sizes = sizes_of(case, rows, groups)
+    keys = jax.random.split(jax.random.key(rows * 100 + groups), 2)
+    x = jax.random.normal(keys[0], (rows, K_IN)).astype(dtype)
+    w = (K_IN ** -0.5 * jax.random.normal(keys[1], (groups, N_OFF, K_IN))).astype(dtype)
+    itemsize = jnp.dtype(dtype).itemsize
+    assert em.matvec_plan(rows, K_IN, N_OFF, itemsize) is None
+    assert N_OFF // em.matvec_plan(rows, K_IN, N_OFF, itemsize, out_major=True)[1] == blocks
+    got = em.expert_matvec(x, w, jnp.asarray(sizes), out_major=True, interpret=True)
+    want = jax.lax.ragged_dot(x, w.swapaxes(1, 2), jnp.asarray(sizes))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    held = int(sizes.sum())
+    np.testing.assert_allclose(
+        np.asarray(got[:held], np.float32), np.asarray(want[:held], np.float32),
+        rtol=tolerance, atol=tolerance)
+    assert not np.asarray(got[held:], np.float32).any()
+    np.testing.assert_allclose(
+        np.asarray(em.grouped_xla(x, w, jnp.asarray(sizes), out_major=True)[:held], np.float32),
+        np.asarray(want[:held], np.float32), rtol=tolerance, atol=tolerance)
 
 
 def test_a_shared_expert_is_fetched_once_and_an_unchosen_one_never():
@@ -129,7 +166,7 @@ def test_a_call_without_a_plan_raises():
 
 def route_to_the_kernel(monkeypatch):
     """`expert_layer` routed as a TPU routes it, the kernel interpreted."""
-    monkeypatch.setattr(moe, "expert_matvec_route", lambda *shape: "kernel")
+    monkeypatch.setattr(moe, "expert_matvec_route", lambda *shape, **how: "kernel")
     monkeypatch.setattr(
         moe, "expert_matvec", functools.partial(em.expert_matvec, interpret=True))
 
@@ -154,7 +191,13 @@ def layer_of(name: str):
     module, tokens = {
         "tiny-deepseek-v2": (deepseek_v2, 1), "tiny-solar-open2": (solar_open2, 1),
         "tiny-k-exaone": (k_exaone, 2), "tiny-ling-flash": (ling_flash, 2),
+        "tiny-nemotron3-nano": (nemotron_h, 1),
     }[name]
+    if module is nemotron_h:  # its own width of 24: off every tile, the up stored out by in
+        cfg = dataclasses.replace(get_config(name), hidden_size=128)
+        block = nemotron_h.unstacked(
+            cfg, nemotron_h.init_params(cfg, jax.random.key(3), jnp.float32))["blocks"][12]
+        return (lambda x: nemotron_h.moe(cfg, block["moe"], x)), tokens
     cfg = dataclasses.replace(get_config(name), hidden_size=128, moe_intermediate_size=64)
     block = next(
         b for b in module.init_params(cfg, jax.random.key(3), jnp.float32)["layers"]
@@ -167,7 +210,8 @@ def layer_of(name: str):
 
 
 @pytest.mark.parametrize(
-    "name", ["tiny-deepseek-v2", "tiny-solar-open2", "tiny-k-exaone", "tiny-ling-flash"])
+    "name", ["tiny-deepseek-v2", "tiny-solar-open2", "tiny-k-exaone", "tiny-ling-flash",
+             "tiny-nemotron3-nano"])
 def test_a_models_decode_step_through_the_kernel_is_the_step_through_ragged_dot(
         name, monkeypatch):
     layer, tokens = layer_of(name)
@@ -176,7 +220,7 @@ def test_a_models_decode_step_through_the_kernel_is_the_step_through_ragged_dot(
     wanted = [layer(x) for x in steps]
     route_to_the_kernel(monkeypatch)
     layer, _ = layer_of(name)  # a function no trace of which is kept
-    assert _calls(jax.make_jaxpr(layer)(steps[0]).jaxpr) == 2  # gate-up, down
+    assert _calls(jax.make_jaxpr(layer)(steps[0]).jaxpr) == 2  # gate-up (or up), down
     held_pairs = 0
     for x, (want, want_ids, want_sizes) in zip(steps, wanted):
         out, ids, sizes = layer(x)
@@ -190,7 +234,7 @@ def test_a_models_decode_step_through_the_kernel_is_the_step_through_ragged_dot(
 
 @pytest.mark.parametrize("name,prompt", [
     ("tiny-deepseek-v2", 512), ("tiny-solar-open2", 512), ("tiny-k-exaone", 512),
-    ("tiny-ling-flash", 512)])
+    ("tiny-ling-flash", 512), ("tiny-nemotron3-nano", 512)])
 def test_a_prefill_never_reaches_the_kernel(name, prompt, monkeypatch):
     """A ladder of several rungs is `ragged_dot` under `lax.switch`, as
     it was, on a backend that would route a decode step to the kernel."""

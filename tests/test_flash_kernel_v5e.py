@@ -108,6 +108,8 @@ CAUSAL_ENTRIES = [
     "flash-causal 2048x2048x128/128 g1 bq512 bk1024 bf16 inplace blocks6/8",
     "flash-causal 2048x2048x192/128 g1 bq512 bk1024 bf16 inplace blocks6/8",
     "flash-causal 8192x8192x192/128 g1 bq512 bk1024 bf16 inplace blocks72/128",
+    # Nemotron-3-Nano's 32 : 2: sixteen query heads read one key head where it lies
+    "flash-causal 8192x8192x128/128 g16 bq512 bk1024 bf16 inplace blocks72/128",
 ]
 
 
@@ -433,6 +435,66 @@ def test_expert_matvec_compiles_for_v5e_at_the_decode_shapes(
         ).compile()
         assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
         assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("label,rows,k,held,experts,hidden,width", chip_smoke.RELU2_EXPERT_SHAPES)
+@pytest.mark.parametrize("pairs", [0, 4])
+def test_expert_matvec_compiles_for_v5e_out_by_in_and_out_of_a_stack(
+        one_chip, label, rows, k, held, experts, hidden, width, pairs):
+    """Nemotron-3-Nano's two products: the up-projection stored out by in
+    (1,856 rows of 2,688: the width is off the lane tile, so the kernel
+    walks blocks of rows of the stored array) and the down-projection by
+    columns, each alone and read out of a run's stack of four pairs by the
+    pair's index: no temporary, no slice of the stack in front of the
+    call. The array the other way round, [2688, 1856], has no plan."""
+    from comfyui_distributed_tpu.ops import expert_matvec as em
+
+    assert em.matvec_plan(rows, hidden, width, 2) is None
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    layer = (jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),) if pairs else ()
+    lead = (pairs,) if pairs else ()
+    for contraction, out_major in ((hidden, True), (width, False)):
+        compiled = jax.jit(functools.partial(em.expert_matvec, out_major=out_major)).lower(
+            jax.ShapeDtypeStruct((rows, contraction), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((*lead, held, width, hidden), jnp.bfloat16, sharding=one_chip),
+            sizes, *layer,
+        ).compile()
+        assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+        assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_nemotron_decode_reads_its_experts_where_they_lie_and_carries_its_state_in_place(
+        one_chip, monkeypatch):
+    """Nemotron-3-Nano's whole decode at the served share's sizes (512
+    steps over 8,704 positions), routed as a TPU routes it: two
+    `expert_matvec` calls a body that has experts (7 runs' and the
+    trailing block's: 16), each on a run's whole stack; the tree of six
+    key/value caches (53.5 MB), 23 float32 states and tails (49.1 MB) is
+    donated and written where it lies. What the loop needs beside it is
+    under 64 MB: a temporary the size of a run's experts (160 MB a
+    matrix) would be the scan's slice of the stack, copied a step, and
+    one of gigabytes the stack turned round (a width off the lane tile
+    stored last: PERF.md section 6, PR 48)."""
+    from comfyui_distributed_tpu.models import nemotron_h
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("nemotron3-nano-ep16-52l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: nemotron_h.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    state = jax.tree.map(place, nemotron_h.state_shapes(cfg, 8704, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    compiled = nemotron_h.decode.lower(
+        cfg, params, state,
+        jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+        scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+        scalar(jnp.float32), steps=510,  # a step count of its own: the route is read while tracing
+    ).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 16
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 * 2**20
+    assert memory.alias_size_in_bytes >= 8704 * 6144 + 49_082_368
 
 
 # as above: a step count a case, since the route is read while the program is traced

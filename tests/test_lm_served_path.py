@@ -322,6 +322,60 @@ def ling_flash_entry(cfg, config):
     assert [layer for layer in whole.layers if whole.is_dense(layer)] == [0, 1]
 
 
+def nemotron_drawn(attrs):
+    # six sparse blocks, 2 of 16 experts held, 3 a token
+    held_share(attrs, layers=6, held=2, low=0.06, high=0.2)
+    # one position a step, whose experts are distinct: what the kernel calls read
+    # are the held pairs
+    assert attrs["decode_experts_read"] == attrs["decode_routed_pairs_held"]
+
+
+def nemotron_workflow(mine, _):
+    solars = load("workflows/rewrite-txt2img-solar-open2.json")
+    assert differing(mine, solars) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "max_new_tokens"),
+        ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["temperature"]) == (512, 1.0)
+    assert "draft_tokens" not in generate
+    # the Solar cell's 8,191-byte instruction, byte for byte
+    assert generate["text"] == by_kind(solars)["TextGenerate"]["text"]
+
+
+def nemotron_published(config):
+    assert config["hybrid_override_pattern"] == (
+        "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+    assert config["model_type"] == "nemotron_h" and config["mlp_hidden_act"] == "relu2"
+    assert config["as_run"]["parameters"] == {"lm": 3422495040}
+    assert config["as_run"]["cache_bytes_per_token"] == 6144
+    assert config["as_run"]["state_bytes"] == 49082368
+    assert set(config["held"]) == {"layers", "experts", "vocabulary", "state"}
+    assert "all 52 blocks" in config["held"]["layers"]
+    assert "16 chips of two v5e-8 hosts" in config["deployment"]
+    assert "No pipeline stage" in config["deployment"]
+    # the issue's fallback, with what was measured on the eight-way cut first
+    assert "WHICH CUT WAS BUILT AND WHY" in config["deployment"]
+    limits = config["parity"]
+    # 52 blocks deep in bfloat16: an unflipped position stays within 0.15, the median
+    # over positions most of which a flipped expert has moved within 0.25
+    assert 0 < limits["tolerance_rel_l2_max_unflipped"] < limits["tolerance_rel_l2_median"] < 0.3
+    assert 0.3 < limits["tolerance_expert_set_mismatch"] < 0.6
+    # the first Mamba-2 block's state is arithmetic alone: ten times tighter than the deepest
+    assert 0 < 10 * limits["tolerance_first_state_rel_l2"] < limits["tolerance_state_rel_l2"] < 0.2
+    assert "carrying S in bfloat16" in limits["why_these_limits"]
+
+
+def nemotron_entry(cfg, config):
+    assert (cfg.num_hidden_layers, len(cfg.held_experts), cfg.vocab_held) == (
+        52, config["n_routed_experts"], config["vocab_size"])
+    assert (cfg.n_routed_experts, cfg.vocab_size, cfg.ep_size, cfg.vocab_shards) == (
+        128, 131072, 16, 8)
+    assert cfg.blocks_of("*") == [5, 12, 19, 26, 33, 42]
+    assert (len(cfg.blocks_of("M")), len(cfg.blocks_of("E"))) == (23, 23)
+    assert cfg.layer_norm_epsilon == config["layer_norm_epsilon"] == config["norm_eps"]
+    assert type(cfg)() == dataclasses.replace(cfg, ep_size=1, vocab_shards=1)  # nothing else cut
+
+
 @dataclasses.dataclass(frozen=True)
 class Model:
     """One language model's row. `attrs`: `node.TextGenerate`'s attributes
@@ -572,6 +626,56 @@ MODELS = [
                            "mtp_device_pct.lm", "linear_attention_device_pct.lm",
                            "state_keep_device_pct.lm", "mla_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
+    ),
+    Model(
+        name="nemotron3-nano", served="nemotron3-nano-ep16-52l", tiny="tiny-nemotron3-nano",
+        workflow="rewrite-txt2img-nemotron3-nano.json", config="nemotron-3-nano-30b-a3b.json",
+        reference="nemotron_h.py", catalog="NVIDIA-Nemotron-3-Nano-30B-A3B-BF16",
+        cell="nemotron3_nano_rewrite_txt2img_512.closed2", prompt=8192, new_tokens=16, drafts=0,
+        # tiny-nemotron3-nano: 13 blocks MEMEM*EMEMEME, each one part alone: six Mamba-2
+        # blocks (4 heads of 8 over a state of 16, 2 groups, chunks of 32), one attention
+        # block (4 query heads over 1 key head of 16), six sparse blocks (2 of 16 experts
+        # held, 3 a token, no gate). What grows: the one attention block's keys and values;
+        # what does not: six float32 matrix states and the convolutions' last 3 inputs
+        attrs={
+            "prompt_tokens": 8192, "new_tokens": 16, "draft_tokens": 0, "decode_steps": 16,
+            "layers": 13, "mamba_layers": 6, "attention_layers": 1, "sparse_layers": 6,
+            "experts_held": 2, "experts_total": 16, "cache_bytes": 2 * (8192 + 16) * 16 * 4,
+            "state_bytes": 6 * (4 * 8 * 16 * 4 + 3 * (32 + 2 * 2 * 16) * 4),
+            "prefill_chunks": 8192 // 32,
+            "prefill_routed_pairs": 8192 * 6 * 3, "decode_routed_pairs": 16 * 6 * 3,
+            "decode_expert_rows": 16 * 6 * 3,
+            "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
+                                   "decode_expert_rows", "decode_expert_route"}) | {
+            "decode_experts_read"},
+        drawn_check=nemotron_drawn,
+        wait_bytes=4 * (16 + 2 * 6 * 2),  # nothing of the state tree leaves the device
+        # the decode's one attention block (the einsum form, one key head serving four
+        # queries), then the prefill's; the state-space blocks log no route
+        attention="decode-xla 4x8208x16, xla-causal 8192x8192x16/16 bq256 f32",
+        passes=lambda attrs: (8192 * 13, 16 * 13),
+        widths={
+            "hidden_size": 2688, "num_hidden_layers": 52, "mamba_num_heads": 64,
+            "mamba_head_dim": 64, "ssm_state_size": 128, "n_groups": 8, "conv_kernel": 4,
+            "chunk_size": 128, "expand": 2, "time_step_min": 0.001, "time_step_max": 0.1,
+            "time_step_floor": 0.0001, "num_attention_heads": 32, "num_key_value_heads": 2,
+            "head_dim": 128, "intermediate_size": 1856, "moe_intermediate_size": 1856,
+            "moe_shared_expert_intermediate_size": 3712, "num_experts_per_tok": 6,
+            "n_shared_experts": 1, "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+            "routed_scaling_factor": 2.5, "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+            "use_conv_bias": True, "mamba_proj_bias": False, "attention_bias": False,
+            "layer_norm_epsilon": 1e-5, "rope_theta": 10000, "partial_rotary_factor": 1,
+            "residual_in_fp32": False, "max_position_embeddings": 262144,
+            "tie_word_embeddings": False},
+        reduced={"n_routed_experts": (128, 8), "vocab_size": (131072, 16384)},
+        assumed=("pre-norm", "no rotary embedding", "expand 2 is read by nothing",
+                 "head h reads group h // 8", "gated by silu(z) first", "relu2",
+                 "no group step", "seeded random", "no bias is shifted", "stand-in",
+                 "batch is 1", "house style guide", "no MTP module"),
+        published=nemotron_published, entry=nemotron_entry, check_workflow=nemotron_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "ssm_device_pct.lm",
+                           "expert_matvec_hbm_pct.lm"}),
     ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
@@ -895,7 +999,8 @@ def test_every_language_model_meets_the_one_contract(name):
 
 @pytest.mark.parametrize("name, passes", [
     ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
-    ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7)])
+    ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7),
+    ("nemotron3-nano-ep16-52l", 52)])
 def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
     from comfyui_distributed_tpu.models.registry import create_model
 
@@ -908,7 +1013,8 @@ def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
 def _step_of(name):
     """(module, `step(cfg, params, cache, token, position) -> (logits,
     cache, what the decode sums over its steps)`) of a tiny model."""
-    from comfyui_distributed_tpu.models import deepseek_v2, k_exaone, ling_flash, ouro, solar_open2
+    from comfyui_distributed_tpu.models import (
+        deepseek_v2, k_exaone, ling_flash, nemotron_h, ouro, solar_open2)
 
     def one_position(module):
         def step(cfg, params, cache, token, position):
@@ -932,6 +1038,7 @@ def _step_of(name):
         "solar-open2": (solar_open2, with_loads(solar_open2)),
         "k-exaone": (k_exaone, one_position(k_exaone)),
         "ling-flash": (ling_flash, one_position(ling_flash)),
+        "nemotron3-nano": (nemotron_h, with_loads(nemotron_h)),
     }[name]
 
 
@@ -1035,7 +1142,8 @@ def test_without_drafting_a_drafting_models_node_reports_a_step_a_token(
 
 
 @pytest.mark.parametrize("name, kind", [
-    ("deepseek-v2", "DeepSeekV2"), ("ouro", "Ouro"), ("solar-open2", "SolarOpen2")])
+    ("deepseek-v2", "DeepSeekV2"), ("ouro", "Ouro"), ("solar-open2", "SolarOpen2"),
+    ("nemotron3-nano", "NemotronH")])
 def test_a_model_without_a_draft_module_refuses_to_draft(name, kind, tmp_path, monkeypatch):
     """`draft_tokens` is an optional input: the committed workflows that do
     not give it run as before and say 0; anything else is refused by the
@@ -1105,6 +1213,58 @@ def test_solars_served_share_holds_13_mb_of_state_and_4_kb_a_position():
     assert attrs["state_bytes"] == 3 * 64 * 128 * 128 * 4 + 3 * 3 * 24576 * 2 == 13_025_280
     assert (lm.layer_passes, attrs["linear_layers"], attrs["full_layers"]) == (4, 3, 1)
     assert attrs["prefill_chunks"] == 128
+
+
+def test_nemotrons_served_share_holds_48_mb_of_state_and_6_kb_a_position():
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    lm = create_model("nemotron3-nano-ep16-52l")
+    lm.dtype = jnp.dtype(jnp.bfloat16)
+    attrs = lm.report(8192, 512, 8704, [[384] * 8] * 23, [[24] * 8] * 23)
+    config = load("benchmark/configs/nemotron-3-nano-30b-a3b.json")
+    # six key/value caches, 2 key heads x 128 x 2 x 2 B a position each
+    assert attrs["cache_bytes"] == 8704 * 6144 == 8704 * config["as_run"]["cache_bytes_per_token"]
+    # 23 Mamba-2 blocks: float32 matrix states whatever the weights' dtype (48.2 MB),
+    # bfloat16 tails of 3 x 6,144
+    assert attrs["state_bytes"] == 23 * (64 * 64 * 128 * 4 + 3 * 6144 * 2) == (
+        config["as_run"]["state_bytes"]) == 49_082_368
+    assert 23 * 64 * 64 * 128 * 4 == 48_234_496
+    assert (lm.layer_passes, attrs["layers"], attrs["mamba_layers"], attrs["sparse_layers"],
+            attrs["attention_layers"]) == (52, 52, 23, 23, 6)
+    assert (attrs["experts_held"], attrs["experts_total"], attrs["prefill_chunks"]) == (8, 128, 64)
+    assert attrs["decode_routed_pairs"] == 512 * 23 * 6
+    assert attrs["decode_experts_read"] == attrs["decode_routed_pairs_held"] == 23 * 8 * 24
+    # each block's 3,072 held pairs take the rung of a sixteenth of the 49,152
+    assert attrs["prefill_expert_rows"] == 23 * 3072
+    assert lm.counted(attrs, 8192, 512) == {
+        "decode_steps": 512, "prefill_layer_passes": 8192 * 52, "decode_layer_passes": 512 * 52}
+
+
+def test_the_sixth_model_is_written_from_the_modules_the_others_are():
+    """Shared-code identities: the expert layer and the sigmoid rule are
+    `moe.py`'s (groups 1 and 1: Solar-Open2's and K-EXAONE's rule as it
+    is), the causal convolution in front of the recurrence is
+    `lm_common.short_conv` for KDA and Mamba-2 alike, the decode loop, the
+    head and the norm `lm_common.py`'s; the state-space layer is
+    `mamba2.py`'s alone and the model file keeps no body of it."""
+    from comfyui_distributed_tpu.models import (
+        kda, lm_common, mamba2, moe, nemotron_h, solar_open2)
+
+    assert nemotron_h.expert_layer is moe.expert_layer
+    assert nemotron_h.sigmoid_route is moe.sigmoid_route is solar_open2.sigmoid_route
+    assert mamba2.short_conv is lm_common.short_conv is kda.short_conv
+    assert nemotron_h.decode_loop is lm_common.decode_loop
+    assert nemotron_h.head is lm_common.head and nemotron_h.rms_norm is lm_common.rms_norm
+    for module, gone in ((nemotron_h, ("top_k(", "ragged_dot(", "softmax(", "cumsum(",
+                                       "softplus(", "def ssd_", "silu(")),
+                         (kda, ("window = jnp.concatenate",)),
+                         (mamba2, ("window = jnp.concatenate", "ragged_dot(", "top_k("))):
+        with open(module.__file__, encoding="utf-8") as fh:
+            source = fh.read()
+        for body in gone:
+            assert body not in source, (module.__name__, body)
 
 
 def test_the_three_models_with_experts_call_the_one_expert_layer_and_two_the_one_rule():
