@@ -20,20 +20,41 @@ the chosen scores renormalised, times `routed_scaling_factor`; `n_group`
 1: no group step), its experts without a gate: relu(x W_up)^2 W_down,
 and one shared expert of the same form that every token takes.
 
-**The walk** (`plan`): the pattern is cut into segments, a maximal run
-of two or more `EM` pairs one segment and every other block its own.
-A run's pairs lie stacked along a leading axis and run under one
-`lax.scan` (the published string: one `M`, then runs of 2, 3, 3, 3, 3,
-4, 4 pairs with an attention block before each but the first, then one
-`E`), so the two programs hold 16 bodies, not 52. `unstacked` gives the
-same tree block by block, which the reference and the tests walk.
+**The tree**: `params["blocks"]` has an entry a published block, a tree
+of its own weights, and a block that keeps a state has a leaf of its
+own in the request's state. **The walk** (`walk`) is chosen by what it
+sees, the token count. One token (a decode step) goes through the 52
+blocks one after another: 52 bodies whose matrices are whole arrays,
+read once where they lie, and whose states are read and overwritten
+where they lie in the loop's carry; the Mamba and the sparse block are
+jitted there, so that the step traces and lowers each once and calls it
+23 times. Until PR 49 a maximal run of `EM` pairs lay stacked along a
+leading axis under one `lax.scan` in both programs: the scan handed a
+run's states back as a new stack, which the decode's loop copied into
+its carry every step (46 MB), and a step that walked the pairs itself,
+reading each one's weights out of the stacks by a static index, had
+whole stacks prefetched and waited for (PERF.md section 6, PR 49). A
+sequence (the prefill) still takes a run under one `lax.scan`
+(`scanned_run`), over the pairs' weights stacked inside the program
+once a request: with 52 prefill bodies, 23 of them holding the expert
+layer's ladder of row counts, every cached start spent 22 s fetching
+the two compiled programs, where it spends 10 now and spent 6 with the
+runs scanned in both (PERF.md section 6, PR 49).
+
+`plan` cuts the pattern into segments, a maximal run of two or more
+`EM` pairs one segment and every other block its own (the published
+string: one `M`, then runs of 2, 3, 3, 3, 3, 4, 4 pairs with an
+attention block before each but the first, then one `E`): what the
+prefill scans, and how the weights are **drawn**, a run's pairs as one
+stack in one program and then handed out block by block, so that a
+seed's weights are what they were when the runs were stacks.
 
 A request's state (`state_shapes`) has an entry only for what keeps
 one: `kv`, one `[2, key heads, positions, d]` array an attention block,
-which grows; `ssm` and `conv`, a segment with Mamba blocks each (`[H, P,
-N]` float32 and `[kernel - 1, inner + 2 G N]`, with the run's leading
-axis where it is one), which do not. The prefill allocates it, the
-decode takes it by donation and hands it back.
+which grows; `ssm` and `conv`, one `[H, P, N]` float32 state and one
+`[kernel - 1, inner + 2 G N]` tail a Mamba block, which do not. The
+prefill allocates it, the decode takes it by donation and hands it
+back.
 
 The chip holds `expert_range(ep_rank, ep_size)` of the experts and the
 first of `vocab_shards` slices of the vocabulary, as `DeepSeekV2Config`
@@ -139,7 +160,7 @@ class NemotronHConfig:
 
 
 class Segment(NamedTuple):
-    kind: str    # "M", "E", "*": one block; "EM": a run of pairs under one scan
+    kind: str    # "M", "E", "*": one block; "EM": a run of pairs (one stack drawn, one scan)
     first: int   # the published index of its first block
     pairs: int   # pairs in a run, else 0
 
@@ -206,12 +227,11 @@ def _segment_shapes(cfg: NemotronHConfig, segment: Segment) -> dict[str, Any]:
 
 def param_shapes(cfg: NemotronHConfig) -> dict[str, Any]:
     """The tree's shapes with each weight's fan-in (None: a norm's
-    scale, initialised to one); `blocks` has an entry a segment of
-    `plan`, a run's {"e", "m"} stacked along its pairs."""
+    scale, initialised to one); `blocks` has an entry a published
+    block."""
     return {
         "embed": ((cfg.vocab_held, cfg.hidden_size), 1),
-        "blocks": tuple(
-            _segment_shapes(cfg, segment) for segment in plan(cfg.hybrid_override_pattern)),
+        "blocks": tuple(_block_shapes(cfg, kind) for kind in cfg.hybrid_override_pattern),
         "final_norm": ((cfg.hidden_size,), None),
         "head": ((cfg.hidden_size, cfg.vocab_held), cfg.hidden_size),
     }
@@ -223,11 +243,14 @@ def param_count(cfg: NemotronHConfig) -> int:
 
 @partial(jax.jit, static_argnames=("cfg", "segment", "dtype"))
 def _init_segment(key, *, cfg: NemotronHConfig, segment: Segment, dtype):
-    """One segment's weights in one program. `segment.first` is 0 here
-    whatever its place: the segments of one kind and length are one
-    program, and the published string has 15 segments of 6 kinds, where
-    its 130 weights drawn one a program cost a cold start 130 compiles
-    (156 s of a 168 s load on a v5e; PERF.md section 6, PR 48)."""
+    """One segment's blocks' weights in one program, a tuple of a tree a
+    block: a run's pairs are drawn as one stack along a leading axis
+    (the values a seed gave while the runs were stacks) and handed out
+    pair by pair. `segment.first` is 0 here whatever its place: the
+    segments of one kind and length are one program, and the published
+    string has 15 segments of 6 kinds, where its 130 weights drawn one a
+    program cost a cold start 130 compiles (156 s of a 168 s load on a
+    v5e; PERF.md section 6, PR 48)."""
     params = init_from_shapes(_segment_shapes(cfg, segment), key, dtype)
     key = jax.random.fold_in(key, 1)
     for half in (params.values() if segment.pairs else (params,)):
@@ -237,12 +260,16 @@ def _init_segment(key, *, cfg: NemotronHConfig, segment: Segment, dtype):
             half["mamba"].update(mamba2.init_steps(
                 key, half["mamba"]["a_log"].shape, cfg.time_step_min, cfg.time_step_max,
                 cfg.time_step_floor))
-    return params
+    if not segment.pairs:
+        return (params,)
+    return tuple(
+        jax.tree_util.tree_map(lambda leaf: leaf[pair], params[half])
+        for pair in range(segment.pairs) for half in "em")
 
 
 def init_params(cfg: NemotronHConfig, key, dtype=jnp.float32) -> dict[str, Any]:
     """Seeded random weights in `dtype` (`lm_common.init_from_shapes`), a
-    segment a program under a key of its own; the router's selection
+    segment of `plan` a program under a key of its own; the router's selection
     bias zero; a Mamba block's `a_log`, `dt_bias` and `d` by the layer's
     published initialisation (`mamba2.init_steps`), float32 whatever
     `dtype`: no bias is shifted, the published ranges already give a
@@ -252,40 +279,18 @@ def init_params(cfg: NemotronHConfig, key, dtype=jnp.float32) -> dict[str, Any]:
     return {
         **init_from_shapes(ends, jax.random.fold_in(key, len(cfg.hybrid_override_pattern)), dtype),
         "blocks": tuple(
-            _init_segment(jax.random.fold_in(key, segment.first), cfg=cfg,
-                          segment=segment._replace(first=0), dtype=dtype)
-            for segment in plan(cfg.hybrid_override_pattern)),
+            block for segment in plan(cfg.hybrid_override_pattern)
+            for block in _init_segment(jax.random.fold_in(key, segment.first), cfg=cfg,
+                                       segment=segment._replace(first=0), dtype=dtype)),
     }
 
 
-class _Blocks:
-    """The segments as a sequence of per-block trees in published order,
-    each sliced out of its run when it is asked for: what
-    `reference/nemotron_h.py` walks, with no second copy of a stack
-    alive."""
-
-    def __init__(self, cfg: NemotronHConfig, blocks: tuple):
-        self.where = []
-        for at, segment in enumerate(plan(cfg.hybrid_override_pattern)):
-            for offset in range(segment.blocks):
-                self.where.append((at, "em"[offset % 2] if segment.pairs else None, offset // 2))
-        self.blocks = blocks
-
-    def __len__(self) -> int:
-        return len(self.where)
-
-    def __getitem__(self, index: int) -> dict:
-        if not 0 <= index < len(self):
-            raise IndexError(index)
-        at, half, pair = self.where[index]
-        if half is None:
-            return self.blocks[at]
-        return jax.tree_util.tree_map(lambda leaf: leaf[pair], self.blocks[at][half])
-
-
 def unstacked(cfg: NemotronHConfig, params: dict) -> dict:
-    """The tree with `blocks` as a sequence of the published blocks."""
-    return {**params, "blocks": _Blocks(cfg, params["blocks"])}
+    """The tree with `blocks` as a sequence of the published blocks:
+    the tree as it is held. The name dates from when a run's pairs lay
+    stacked; the reference's callers (`benchmark/nemotron3_nano_parity.py`
+    among them) ask by it."""
+    return params
 
 
 # --- a request's state ----------------------------------------------------
@@ -293,19 +298,18 @@ def unstacked(cfg: NemotronHConfig, params: dict) -> dict:
 
 def state_shapes(cfg: NemotronHConfig, cache_len: int, dtype) -> dict[str, tuple]:
     """The tree a request carries from its prefill through its decode:
-    `kv` an entry an attention block, `ssm` and `conv` an entry a
-    segment that has Mamba blocks (a run's with its pairs leading)."""
-    segments = plan(cfg.hybrid_override_pattern)
-    runs = [(s.pairs,) if s.pairs else () for s in segments if s.kind in ("M", "EM")]
+    `kv` an entry an attention block, `ssm` and `conv` an entry a Mamba
+    block, in published order. A leaf a block, never a stack of several:
+    the decode's loop carries the tree, and a leaf is updated where it
+    lies."""
+    mamba_blocks = len(cfg.blocks_of("M"))
     matrix = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
+    kv = (2, cfg.num_key_value_heads, cache_len, cfg.head_dim)
+    tail = (cfg.conv_kernel - 1, cfg.conv_channels)
     return {
-        "kv": tuple(
-            jax.ShapeDtypeStruct((2, cfg.num_key_value_heads, cache_len, cfg.head_dim), dtype)
-            for s in segments if s.kind == "*"),
-        "ssm": tuple(jax.ShapeDtypeStruct((*run, *matrix), jnp.float32) for run in runs),
-        "conv": tuple(
-            jax.ShapeDtypeStruct((*run, cfg.conv_kernel - 1, cfg.conv_channels), dtype)
-            for run in runs),
+        "kv": (jax.ShapeDtypeStruct(kv, dtype),) * len(cfg.blocks_of("*")),
+        "ssm": (jax.ShapeDtypeStruct(matrix, jnp.float32),) * mamba_blocks,
+        "conv": (jax.ShapeDtypeStruct(tail, dtype),) * mamba_blocks,
     }
 
 
@@ -369,64 +373,91 @@ def attn_cached(cfg, position, p, x, kv):
 # --- the walk -------------------------------------------------------------
 
 
+def mamba_block(cfg: NemotronHConfig, block: dict, h, tail, state):
+    """h += mamba(rms(h)) over the block's tail and state: (h, tail, state)."""
+    with jax.named_scope("mamba"):
+        out, tail, state = mamba(
+            cfg, block["mamba"], rms_norm(h, block["norm"], cfg.layer_norm_epsilon), tail, state)
+    return h + out, tail, state
+
+
+def sparse_block(cfg: NemotronHConfig, block: dict, h, index=None):
+    """h += moe(rms(h)): (h, chosen ids [T, k], pairs on each held expert
+    [held]); `index` as `moe` takes it."""
+    out, ids, sizes = moe(
+        cfg, block["moe"], rms_norm(h, block["norm"], cfg.layer_norm_epsilon), index)
+    return h + out, ids, sizes
+
+
+def scanned_run(cfg: NemotronHConfig, blocks: tuple, h, tails: list, states: list):
+    """A run of `EM` pairs over a sequence (the prefill), under one
+    `lax.scan` over the pairs' weights, tails and states, stacked here
+    once a request (6.1 GB copied, 17.6 ms of a 0.47 s program): walked
+    block by block a prefill is 52 bodies for 16 and 15 ms shorter, but
+    its compiled program costs every cached start 12 s more to fetch
+    (PERF.md section 6, PR 49). The routed experts' stacks stay whole
+    beside the scan, a pair's read by its index where they lie: what
+    the scan slices out it copies. Returns (h, tails, states, ids
+    [pairs, T, k], sizes [pairs, held])."""
+    stacked = lambda trees: jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), *trees)
+    sparse, mixers = stacked(blocks[0::2]), stacked(blocks[1::2])
+    experts = sparse["moe"].pop("experts")
+
+    def pair(h, xs):
+        index, e, m, tail, state = xs
+        h, ids, sizes = sparse_block(cfg, {**e, "moe": {**e["moe"], "experts": experts}}, h, index)
+        h, tail, state = mamba_block(cfg, m, h, tail, state)
+        return h, (tail, state, ids, sizes)
+
+    h, (tails, states, ids, sizes) = jax.lax.scan(
+        pair, h, (jnp.arange(len(states)), sparse, mixers, jnp.stack(tails), jnp.stack(states)))
+    return h, list(tails), list(states), ids, sizes
+
+
 def walk(cfg: NemotronHConfig, blocks: tuple, h, cache: dict, attn):
-    """h [T, hidden] through every block over the request's state:
+    """h [T, hidden] through the 52 blocks over the request's state:
     `attn(p, x, kv) -> (output, kv)` is the attention block's form (the
-    other two parts have one form each way). Returns (h, cache, the
+    other two parts have one form each way). One token (a decode step)
+    goes block after block, each matrix a whole array read where it lies
+    and each state updated where it lies in the loop's carry; a sequence
+    takes a run of pairs through `scanned_run`. Returns (h, cache, the
     experts chosen [E blocks, T, k], the pairs on each held expert [E
     blocks, held]), the E blocks in published order."""
-    eps = cfg.layer_norm_epsilon
     kv, ssm, conv = (list(cache[name]) for name in ("kv", "ssm", "conv"))
     chosen, loads, kv_at, ssm_at = [], [], 0, 0
-
-    def sparse(block, h, index=None):
-        out, ids, sizes = moe(cfg, block["moe"], rms_norm(h, block["norm"], eps), index)
-        return h + out, ids, sizes
-
-    def state_space(block, h, tail, state):
-        with jax.named_scope("mamba"):
-            out, tail, state = mamba(
-                cfg, block["mamba"], rms_norm(h, block["norm"], eps), tail, state)
-        return h + out, tail, state
-
-    for segment, block in zip(plan(cfg.hybrid_override_pattern), blocks):
-        if segment.pairs:
-            # the routed experts' stacks stay whole beside the scan, a pair's read by
-            # its index where they lie: what the scan slices out it copies, and the
-            # expert kernel's operand cannot be a slice
-            experts = block["e"]["moe"]["experts"]
-            sliced = {**block, "e": {**block["e"], "moe": {
-                name: leaf for name, leaf in block["e"]["moe"].items() if name != "experts"}}}
-
-            def pair(h, xs):
-                index, block, tail, state = xs
-                sparse_block = {**block["e"], "moe": {**block["e"]["moe"], "experts": experts}}
-                h, ids, sizes = sparse(sparse_block, h, index)
-                h, tail, state = state_space(block["m"], h, tail, state)
-                return h, (tail, state, ids, sizes)
-
+    # jitted here and not at the module's level: a program traces and lowers each of
+    # the two kinds that come 23 times once, and a new trace of the program (the
+    # parity script's, under a patched `mamba2.mixer`) traces them anew
+    one_mamba, one_sparse = (
+        jax.jit(partial(block, cfg)) for block in (mamba_block, sparse_block))
+    for segment in plan(cfg.hybrid_override_pattern):
+        mine = blocks[segment.first:segment.first + segment.blocks]
+        if segment.pairs and h.shape[0] > 1:
+            held = slice(ssm_at, ssm_at + segment.pairs)
             with jax.named_scope(f"run_{segment.first}_{segment.first + segment.blocks - 1}"):
-                h, (conv[ssm_at], ssm[ssm_at], ids, sizes) = jax.lax.scan(
-                    pair, h, (jnp.arange(segment.pairs), sliced, conv[ssm_at], ssm[ssm_at]))
-            ssm_at += 1
-            chosen.append(ids)
-            loads.append(sizes)
+                h, conv[held], ssm[held], ids, sizes = scanned_run(
+                    cfg, mine, h, conv[held], ssm[held])
+            ssm_at += segment.pairs
+            chosen.extend(ids)
+            loads.extend(sizes)
             continue
-        with jax.named_scope(f"block_{segment.first}"):
-            if segment.kind == "M":
-                h, conv[ssm_at], ssm[ssm_at] = state_space(block, h, conv[ssm_at], ssm[ssm_at])
-                ssm_at += 1
-            elif segment.kind == "*":
-                with jax.named_scope("attn"):
-                    out, kv[kv_at] = attn(
-                        block["attn"], rms_norm(h, block["norm"], eps), kv[kv_at])
-                h, kv_at = h + out, kv_at + 1
-            else:
-                h, ids, sizes = sparse(block, h)
-                chosen.append(ids[None])
-                loads.append(sizes[None])
+        for index, block in enumerate(mine, segment.first):
+            with jax.named_scope(f"block_{index}"):
+                if "mamba" in block:
+                    h, conv[ssm_at], ssm[ssm_at] = one_mamba(block, h, conv[ssm_at], ssm[ssm_at])
+                    ssm_at += 1
+                elif "attn" in block:
+                    with jax.named_scope("attn"):
+                        out, kv[kv_at] = attn(
+                            block["attn"], rms_norm(h, block["norm"], cfg.layer_norm_epsilon),
+                            kv[kv_at])
+                    h, kv_at = h + out, kv_at + 1
+                else:
+                    h, ids, sizes = one_sparse(block, h)
+                    chosen.append(ids)
+                    loads.append(sizes)
     cache = {"kv": tuple(kv), "ssm": tuple(ssm), "conv": tuple(conv)}
-    return h, cache, jnp.concatenate(chosen), jnp.concatenate(loads)
+    return h, cache, jnp.stack(chosen), jnp.stack(loads)
 
 
 # --- the two programs -----------------------------------------------------

@@ -39,9 +39,18 @@ _TESTS = os.path.join(
 # in its turn pinned PR 45's state (Ling's cell and its two metrics the last
 # of each list): `test_nemotron3_nano_readers.py` has the one that holds the
 # order the PRs came in and no one's place at the end.
+#
+# PR 49 holds Nemotron-3-Nano's weights as a tree a published block, where
+# `test_nemotron3_nano_readers.py` looks the attention block up as the third
+# *segment* of the tree PR 48 held (a run of pairs one entry). Its form that
+# finds each kind by its published index is below, in this file: a PR that
+# claims a gain adds nothing under `benchmark/`.
 _LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_in_the_order_"
            "they_came")
+_MODULES = {}
 _SUPERSEDED = {
+    "test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys":
+        "test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys_a_tree_a_block",
     "test_the_lm_cells_are_listed_where_their_readers_find_something_whoever_came_last": _LISTED,
     "test_device_every_new_metric_has_its_reader_and_names_its_cells":
         "test_device_the_twelve_metrics_of_pr_36_have_their_readers_and_lie_together",
@@ -61,6 +70,7 @@ def _adopt(filename: str) -> None:
     )
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
+    _MODULES[name] = module
     for key, value in vars(module).items():
         if key.startswith("test_") and key not in _SUPERSEDED:
             assert key not in globals(), f"two yardstick checks are named {key}"
@@ -70,6 +80,42 @@ def _adopt(filename: str) -> None:
 for _filename in sorted(os.listdir(_TESTS)):
     if _filename.startswith("test_") and _filename.endswith(".py"):
         _adopt(_filename)
+
+
+def test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys_a_tree_a_block():
+    """What the set-aside check asserts, the blocks found by published
+    index (0 a Mamba block, 5 the first attention block, 51 the trailing
+    sparse block)."""
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models import get_config, nemotron_h
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    readers = _MODULES["test_nemotron3_nano_readers"]
+    config, counts = readers.CONFIG, readers.counts
+    model = get_config(config["registry_name"])
+    assert nemotron_h.param_count(model) == counts.total_params(config)
+    assert (len(model.blocks_of("M")), len(model.blocks_of("E")), len(model.blocks_of("*"))) == (
+        counts.blocks(config))
+    assert model.hybrid_override_pattern == config["hybrid_override_pattern"]
+    assert (model.mamba_inner, model.conv_channels, model.chunk_size) == (
+        counts.mamba_inner(config), counts.conv_channels(config), config["chunk_size"])
+    blocks = nemotron_h.param_shapes(model)["blocks"]
+    assert len(blocks) == 52
+    assert nemotron_h.count_params(blocks[0]["mamba"]) == counts.mamba_params(config)
+    assert nemotron_h.count_params(blocks[5]["attn"]) == counts.attention_params(config)
+    last = blocks[51]["moe"]
+    assert nemotron_h.count_params(last["experts"]) == 8 * counts.expert_params(config)
+    assert nemotron_h.count_params({"r": last["w_g"], "s": last["shared"]}) == (
+        counts.always_params(config))
+    lm = create_model(config["registry_name"])
+    lm.dtype = jnp.dtype(config["as_run"]["weights_dtype"])
+    described = lm.describe(8704)
+    assert described["cache_bytes"] == counts.cache_bytes(config, 8704)
+    assert described["state_bytes"] == counts.state_bytes(config)
+    assert (described["mamba_layers"], described["sparse_layers"], described["attention_layers"],
+            described["layers"]) == (23, 23, 6, 52)
+
 
 for _old, _new in _SUPERSEDED.items():
     assert _new in globals(), f"{_old} was set aside and {_new} is not there"

@@ -466,15 +466,21 @@ def test_expert_matvec_compiles_for_v5e_out_by_in_and_out_of_a_stack(
 def test_nemotron_decode_reads_its_experts_where_they_lie_and_carries_its_state_in_place(
         one_chip, monkeypatch):
     """Nemotron-3-Nano's whole decode at the served share's sizes (512
-    steps over 8,704 positions), routed as a TPU routes it: two
-    `expert_matvec` calls a body that has experts (7 runs' and the
-    trailing block's: 16), each on a run's whole stack; the tree of six
-    key/value caches (53.5 MB), 23 float32 states and tails (49.1 MB) is
-    donated and written where it lies. What the loop needs beside it is
-    under 64 MB: a temporary the size of a run's experts (160 MB a
-    matrix) would be the scan's slice of the stack, copied a step, and
-    one of gigabytes the stack turned round (a width off the lane tile
-    stored last: PERF.md section 6, PR 48)."""
+    steps over 8,704 positions), routed as a TPU routes it: a step walks
+    the 52 blocks one after another, so two `expert_matvec` calls a
+    sparse block (46), each on the block's own experts; the tree of six
+    key/value caches (53.5 MB), 23 float32 states and 23 tails (49.1
+    MB), a leaf each, is donated and written where it lies. What the
+    loop needs beside it is under 64 MB: a temporary of gigabytes would
+    be a weight turned round (a width off the lane tile stored last:
+    PERF.md section 6, PR 48). And the program holds no `copy` of a
+    megabyte: not of a state (the loop copied seven stacks of them, 46
+    MB, into its carry every step while the runs of pairs were scans
+    over stacks: PERF.md section 6, PR 49), not of a weight (the largest
+    left are the 37 KB tails)."""
+    import math
+    import re
+
     from comfyui_distributed_tpu.models import nemotron_h
     from comfyui_distributed_tpu.models.registry import get_config
 
@@ -491,10 +497,15 @@ def test_nemotron_decode_reads_its_experts_where_they_lie_and_carries_its_state_
         scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
         scalar(jnp.float32), steps=510,  # a step count of its own: the route is read while tracing
     ).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 16
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 46
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64 * 2**20
     assert memory.alias_size_in_bytes >= 8704 * 6144 + 49_082_368
+    copied = re.findall(r" = (\w+)\[([\d,]*)\]\S* copy\(", text)
+    assert copied  # the tails, among others
+    assert not [(dtype, dims) for dtype, dims in copied
+                if math.prod(int(d) for d in dims.split(",") if d) >= 2**19]
 
 
 # as above: a step count a case, since the route is read while the program is traced

@@ -7,11 +7,15 @@ block kind and the whole model, the chunked form of the state-space
 layer against the recurrence, its groups and its gated norm, the expert's
 form on both routes of the grouped products, the expert kernel at a
 width off the lane tile, the eight ranks' shares of a sparse block,
-prefill + decode through the state tree, and the block walker against
-the published string and against an unrolled walk."""
+prefill + decode through the state tree (a leaf a state-space block,
+which a compiled decode step copies nowhere), and the block walker
+against the published string, against the blocks one by one, and
+against the walk it replaced, runs of pairs under `lax.scan` over
+stacked weights and states."""
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -193,21 +197,180 @@ def test_prefill_and_decode_through_the_state_tree_match_the_reference_in_float3
                                          ) if rank == 0 else True
 
 
-def test_a_served_request_collects_nothing_draws_the_same_ids_and_gets_its_state_back(params):
+def served(params, collect, steps=STEPS):
     ids = jax.random.randint(jax.random.key(3), (PROMPT,), 0, TINY.vocab_held)
+    prefill = nh.prefill(TINY, params, ids, cache_len=PROMPT + STEPS, collect=collect)
+    return prefill, nh.decode(
+        TINY, params, prefill.cache, prefill.logits, jnp.int32(PROMPT), jax.random.key(4),
+        jnp.float32(1.0), steps=steps, collect=collect)
 
-    def run(collect):
-        prefill = nh.prefill(TINY, params, ids, cache_len=PROMPT + STEPS, collect=collect)
-        return prefill, nh.decode(
-            TINY, params, prefill.cache, prefill.logits, jnp.int32(PROMPT), jax.random.key(4),
-            jnp.float32(1.0), steps=STEPS, collect=collect)
 
-    prefill, decode = run(False)
+def test_a_served_request_collects_nothing_draws_the_same_ids_and_gets_its_state_back(params):
+    prefill, decode = served(params, False)
     assert prefill.chosen is None and decode.logits is None and decode.chosen is None
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(prefill.cache))  # donated
     assert jax.tree_util.tree_structure(decode.cache) == jax.tree_util.tree_structure(
         nh.state_shapes(TINY, PROMPT + STEPS, jnp.float32))
-    np.testing.assert_array_equal(np.asarray(decode.ids), np.asarray(run(True)[1].ids))
+    np.testing.assert_array_equal(np.asarray(decode.ids), np.asarray(served(params, True)[1].ids))
+
+
+def scanned_walk(cfg, blocks, h, cache, attn):
+    """The walk until PR 49, for the comparison below: a run of `EM`
+    pairs under one `lax.scan` over the pairs' weights, tails and states
+    stacked along a leading axis, the states handed back as the scan's
+    `ys`; every other block on its own."""
+    eps = cfg.layer_norm_epsilon
+    kv, ssm, conv = (list(cache[name]) for name in ("kv", "ssm", "conv"))
+    chosen, loads, kv_at, ssm_at = [], [], 0, 0
+    stacked = lambda trees: jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), *trees)
+
+    def sparse(block, h):
+        out, ids, sizes = nh.moe(cfg, block["moe"], rms_norm(h, block["norm"], eps))
+        return h + out, ids, sizes
+
+    def state_space(block, h, tail, state):
+        out, tail, state = nh.mamba(
+            cfg, block["mamba"], rms_norm(h, block["norm"], eps), tail, state)
+        return h + out, tail, state
+
+    def pair(h, xs):
+        e, m, tail, state = xs
+        h, ids, sizes = sparse(e, h)
+        h, tail, state = state_space(m, h, tail, state)
+        return h, (tail, state, ids, sizes)
+
+    for segment in nh.plan(cfg.hybrid_override_pattern):
+        mine = blocks[segment.first:segment.first + segment.blocks]
+        if segment.pairs:
+            held = slice(ssm_at, ssm_at + segment.pairs)
+            h, (tails, states, ids, sizes) = jax.lax.scan(pair, h, (
+                stacked(mine[0::2]), stacked(mine[1::2]), jnp.stack(conv[held]),
+                jnp.stack(ssm[held])))
+            conv[held], ssm[held], ssm_at = list(tails), list(states), ssm_at + segment.pairs
+            chosen.extend(ids)
+            loads.extend(sizes)
+        elif segment.kind == "M":
+            h, conv[ssm_at], ssm[ssm_at] = state_space(mine[0], h, conv[ssm_at], ssm[ssm_at])
+            ssm_at += 1
+        elif segment.kind == "*":
+            out, kv[kv_at] = attn(
+                mine[0]["attn"], rms_norm(h, mine[0]["norm"], eps), kv[kv_at])
+            h, kv_at = h + out, kv_at + 1
+        else:
+            h, ids, sizes = sparse(mine[0], h)
+            chosen.append(ids)
+            loads.append(sizes)
+    cache = {"kv": tuple(kv), "ssm": tuple(ssm), "conv": tuple(conv)}
+    return h, cache, jnp.stack(chosen), jnp.stack(loads)
+
+
+def test_the_one_token_walk_over_a_leaf_a_block_is_the_scanned_walk_bit_for_bit(
+        params, monkeypatch):
+    """A served decode (block after block, a leaf of state a block)
+    against the same decode with `scanned_walk` in `walk`'s place: the
+    ids drawn, the loads, and every leaf of the state they end in, the
+    same float32 bits. One step count a form: the walk is chosen while
+    the program is traced."""
+    _, served_form = served(params, False, steps=STEPS - 1)
+    monkeypatch.setattr(nh, "walk", scanned_walk)
+    _, scanned = served(params, False, steps=STEPS - 2)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(
+        np.asarray(served_form.ids)[:STEPS - 2], np.asarray(scanned.ids))
+    _, again = served(params, False, steps=STEPS - 2)
+    np.testing.assert_array_equal(np.asarray(again.ids), np.asarray(scanned.ids))
+    np.testing.assert_array_equal(np.asarray(again.loads), np.asarray(scanned.loads))
+    for got, want in zip(jax.tree_util.tree_leaves(again.cache),
+                         jax.tree_util.tree_leaves(scanned.cache)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def loop_bodies(text):
+    """{name: lines} of the computations that are a `while`'s body in a
+    compiled module's text."""
+    bodies, found, name = set(re.findall(r"body=(%?[\w.\-]+)", text)), {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%?[\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+        elif name in bodies:
+            found.setdefault(name, []).append(line)
+    return found
+
+
+COPY = re.compile(r" = \(?(\w+)\[([\d,]*)\]\S* (?:copy|copy-start)\(")
+
+
+def state_copies(text, cfg):
+    """The shapes of the copies, in the loops' bodies, of a state-space
+    state, of a stack of them or of a stack of convolution tails."""
+    state = [cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size]
+    tail = [cfg.conv_kernel - 1, cfg.conv_channels]
+    found = []
+    for lines in loop_bodies(text).values():
+        for line in lines:
+            copied = COPY.search(line)
+            if copied:
+                shape = [int(d) for d in copied.group(2).split(",") if d]
+                if shape[-3:] == state or (len(shape) == 3 and shape[1:] == tail):
+                    found.append(shape)
+    return found
+
+
+def stacked_form(stack):
+    """The form until PR 49 in small: a loop that carries a run's stacked
+    states, its step a `lax.scan` that takes them as `xs` and hands them
+    back as `ys`."""
+    def step(h, state):
+        state = 0.5 * state + h
+        return h + state.sum(), state
+
+    def body(_, carry):
+        return jax.lax.scan(step, carry[0], carry[1])
+
+    return jax.lax.fori_loop(0, 5, body, (jnp.float32(1.0), stack))
+
+
+def test_a_compiled_decode_step_copies_no_state_space_state(params):
+    """The optimised decode program (CPU, 5 steps; copy insertion runs
+    before any backend's own passes): no body of a loop holds a `copy`
+    of a `[4, 8, 16]` state, of a stack of them or of a stack of tails.
+    What a step hands back as a new array its loop copies into the
+    carry: `stacked_form` holds that copy of its stack, and the decode
+    held four until PR 49, one a run's states and one a run's tails
+    (its runs were scans over stacks: `scanned_walk`). The
+    `[3, 96]` tails themselves are still copied, 1 KB each: the shifted
+    window reads what it overwrites."""
+    prefill = nh.prefill(TINY, params, jnp.zeros((PROMPT,), jnp.int32), cache_len=PROMPT + 8)
+    text = nh.decode.lower(
+        TINY, params, prefill.cache, prefill.logits, jnp.int32(PROMPT), jax.random.key(4),
+        jnp.float32(1.0), steps=5).compile().as_text()
+    assert " while(" in text and loop_bodies(text)
+    assert state_copies(text, TINY) == []
+    control = jax.jit(stacked_form).lower(jnp.zeros((2, 4, 8, 16))).compile().as_text()
+    assert state_copies(control, TINY) == [[2, 4, 8, 16]]
+
+
+def test_prefill_and_decode_hand_on_the_tree_state_shapes_describes(params):
+    """A leaf a block: 6 states and 6 tails beside the one cache, the
+    prefill's tree and the decode's the one `state_shapes` gives, shape
+    and dtype; and what `describe` counts of it from shapes alone at the
+    served sizes is what it was when a run's states were one stack."""
+    described = nh.state_shapes(TINY, PROMPT + STEPS, jnp.float32)
+    assert [len(described[name]) for name in ("kv", "ssm", "conv")] == [1, 6, 6]
+    assert {s.shape for s in described["ssm"]} == {(4, 8, 16)}
+    assert {s.shape for s in described["conv"]} == {(3, 96)}
+    prefill, decode = served(params, True)
+    for cache in (prefill.cache, decode.cache):
+        assert jax.tree_util.tree_structure(cache) == jax.tree_util.tree_structure(described)
+        for leaf, spec in zip(jax.tree_util.tree_leaves(cache),
+                              jax.tree_util.tree_leaves(described)):
+            assert (leaf.shape, leaf.dtype) == (spec.shape, spec.dtype)
+    lm = nh.NemotronH(SERVED)
+    lm.dtype = jnp.dtype(jnp.bfloat16)
+    said = lm.describe(8704)
+    assert said["state_bytes"] == 23 * (64 * 64 * 128 * 4 + 3 * 6144 * 2) == 49_082_368
+    assert said["cache_bytes"] == 8704 * 6144
 
 
 def test_bfloat16_stays_near_the_reference_and_float8_does_not(params):
@@ -271,22 +434,26 @@ def test_an_expert_is_relu_squared_between_two_matrices_on_both_routes(route, mo
         assert calls == (2 if route == "kernel" else 0)
 
 
-def test_a_runs_experts_are_read_out_of_the_stack_by_the_pairs_index(monkeypatch, params):
-    """Inside a run's scan the routed experts' stacks stay whole and the
-    grouped products take the pair's index (the kernel's operand cannot
-    be a slice): the same block as the one sliced out of the stack."""
+def test_a_stacks_experts_are_read_out_of_it_by_the_layers_index(monkeypatch):
+    """`moe.expert_layer` with `index`: the routed experts' stacks of
+    several layers stay whole and the grouped products take the layer's
+    index (the kernel's operand cannot be a slice), which is the same
+    block as the one alone. No program of this model stacks its blocks
+    since PR 49; the capability is `models/moe.py`'s."""
     cfg = dataclasses.replace(TINY, hidden_size=128)
-    params = nh.init_params(cfg, jax.random.key(7))
-    stack = params["blocks"][1]["e"]["moe"]   # the first run: two pairs
+    blocks = nh.init_params(cfg, jax.random.key(7))["blocks"]
+    pair = [blocks[1]["moe"], blocks[3]["moe"]]   # the first run's two sparse blocks
+    stack = jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), *pair)["experts"]
     x = jax.random.normal(jax.random.key(8), (1, 128))
     for how in ("xla", "kernel"):
         if how == "kernel":
             kernel_route(monkeypatch)
-        for pair in range(2):
-            alone = jax.tree_util.tree_map(lambda leaf: leaf[pair], stack)
+        for index, alone in enumerate(pair):
             want = nh.moe(cfg, alone, x)[0]
-            mixed = {**alone, "experts": stack["experts"]}
-            got = (lambda x: nh.moe(cfg, mixed, x, jnp.int32(pair)))(x)[0]
+            mixed = {**alone, "experts": stack}
+            got = (lambda x: moe.expert_layer(
+                mixed, x, cfg.held_experts, functools.partial(nh.route, cfg, mixed["bias"]),
+                index=jnp.int32(index)))(x)[0]
             np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
@@ -409,10 +576,11 @@ def test_the_published_string_is_23_mamba_23_sparse_and_6_attention_blocks():
         assert s.first == at and cfg.hybrid_override_pattern[at:at + s.blocks] == (
             "EM" * s.pairs or s.kind)
         at += s.blocks
-    # a state entry only for what keeps one: 6 caches, 23 states and tails in 8 entries
+    # a state entry only for what keeps one: 6 caches, 23 states and 23 tails, a leaf a block
     shapes = nh.state_shapes(SERVED, 8704, jnp.bfloat16)
-    assert len(shapes["kv"]) == 6 and len(shapes["ssm"]) == len(shapes["conv"]) == 8
-    assert sum(s.shape[0] if s.ndim == 4 else 1 for s in shapes["ssm"]) == 23
+    assert len(shapes["kv"]) == 6 and len(shapes["ssm"]) == len(shapes["conv"]) == 23
+    assert {s.shape for s in shapes["ssm"]} == {(64, 64, 128)}
+    assert {s.shape for s in shapes["conv"]} == {(3, 6144)}
     with pytest.raises(ValueError, match="M, E or"):
         nh.NemotronHConfig(hybrid_override_pattern="ME-")
 
@@ -425,12 +593,15 @@ def test_a_pattern_without_runs_or_with_a_single_pair_is_walked_block_by_block()
         ("M", 0, 0), ("E", 1, 0), ("M", 2, 0), ("E", 3, 0), ("E", 4, 0)]
 
 
-def test_the_scanned_runs_are_an_unrolled_walk_over_the_published_blocks(params):
-    """`walk` (runs of 2 and 3 pairs under `lax.scan`, the experts read
-    out of the stack by index) against the 13 blocks one after another,
-    each sliced out of its run: the residual stream, the keys and values,
-    every state and tail, the chosen experts and the loads."""
-    ids = jax.random.randint(jax.random.key(9), (PROMPT,), 0, TINY.vocab_held)
+@pytest.mark.parametrize("tokens", [PROMPT, 1])
+def test_either_walk_is_the_published_blocks_one_after_another(params, tokens):
+    """`walk` over a sequence (the prefill's: runs of 2 and 3 pairs under
+    `lax.scan` over weights stacked there, the experts read out of the
+    stack by index) and over one token (the decode's: block after block,
+    the Mamba and the sparse block jitted) against the 13 blocks' parts
+    called one after another here: the residual stream, the keys and
+    values, every state and tail, the chosen experts and the loads."""
+    ids = jax.random.randint(jax.random.key(9), (tokens,), 0, TINY.vocab_held)
     h0 = params["embed"][ids]
     cache = nh.zeros(nh.state_shapes(TINY, PROMPT, jnp.float32))
     h, after, chosen, loads = nh.walk(
@@ -453,7 +624,8 @@ def test_the_scanned_runs_are_an_unrolled_walk_over_the_published_blocks(params)
             ids_want.append(ids_b)
             loads_want.append(sizes)
         h_want = h_want + out
-    np.testing.assert_allclose(np.asarray(h), np.asarray(h_want), rtol=1e-5, atol=1e-5)
+    # float32 rounding over 13 blocks: here each operation is a program, there a block is one
+    np.testing.assert_allclose(np.asarray(h), np.asarray(h_want), rtol=3e-5, atol=3e-5)
     flat = lambda entries, trailing: np.concatenate(
         [np.asarray(e).reshape(-1, *e.shape[-trailing:]) for e in entries])
     np.testing.assert_allclose(flat(after["ssm"], 3), np.stack(states), rtol=1e-5, atol=1e-5)
@@ -486,9 +658,10 @@ def test_the_cut_holds_the_parameters_the_issue_counted():
     assert nh.param_count(nh.NemotronHConfig()) == 31_577_940_288
     shapes = nh.param_shapes(SERVED)
     count = lambda tree: nh.count_params(tree)
-    mamba_alone, first_run = shapes["blocks"][0], shapes["blocks"][1]
-    assert count(mamba_alone) == 38_742_208 + 2688            # the block and its norm
-    # 8 experts, the shared one, the router and its bias, the norm: twice, a run of two pairs
-    assert count(first_run["e"]) == 2 * (8 * 9_977_856 + 19_955_712 + 344_064 + 128 + 2688)
-    assert count(shapes["blocks"][2]) == 23_396_352 + 2688    # an attention block
+    assert len(shapes["blocks"]) == 52 and [sorted(set(b) - {"norm"})[0] for b in shapes[
+        "blocks"][:6]] == ["mamba", "moe", "mamba", "moe", "mamba", "attn"]
+    assert count(shapes["blocks"][0]) == 38_742_208 + 2688    # a Mamba block and its norm
+    # 8 experts, the shared one, the router and its bias, the norm
+    assert count(shapes["blocks"][1]) == 8 * 9_977_856 + 19_955_712 + 344_064 + 128 + 2688
+    assert count(shapes["blocks"][5]) == 23_396_352 + 2688    # an attention block
     assert (len(SERVED.held_experts), SERVED.vocab_held) == (8, 16384)
