@@ -7,6 +7,10 @@ The same process serves both roles (role decided per-prompt by hidden
 inputs, reference distributed.py pattern); --worker only suppresses
 master-side startup behavior (auto-launch, signal-driven worker
 cleanup) and enables the master-pid watchdog.
+
+The start itself is the trace `startup` (`/distributed/trace/startup`):
+`process.start`, from the operating system's creation of the process
+to the bound socket, with a child for each step below.
 """
 
 from __future__ import annotations
@@ -15,9 +19,28 @@ import argparse
 import asyncio
 import os
 import sys
+import time
+
+from .telemetry.tracing import STARTUP_TRACE, get_tracer
+
+
+def _seconds_since_creation() -> float:
+    """How long ago the operating system created this process: its
+    start time in /proc, in ticks since boot, against the boot clock.
+    0.0 where that cannot be had."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as fh:
+            # the 22nd field, counted after the parenthesised command
+            ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        created = ticks / os.sysconf("SC_CLK_TCK")
+        return max(0.0, time.clock_gettime(time.CLOCK_BOOTTIME) - created)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return 0.0
 
 
 def main(argv: list[str] | None = None) -> int:
+    tracer = get_tracer()
+    entered, python_s = tracer.now(), _seconds_since_creation()
     parser = argparse.ArgumentParser(prog="comfyui_distributed_tpu")
     parser.add_argument("--port", type=int, default=8188)
     parser.add_argument(
@@ -48,6 +71,15 @@ def main(argv: list[str] | None = None) -> int:
     if args.worker:
         os.environ.setdefault("CDT_IS_WORKER", "1")
 
+    # the process's own start as one trace, kept for its life: the root
+    # ends when the socket is bound, each child where its work happens
+    root = tracer.start_span(
+        "process.start", trace_id=STARTUP_TRACE, start=entered - python_s,
+        attrs={"pid": os.getpid(), "role": "worker" if args.worker else "master",
+               "python_s": python_s},
+    )
+    joined = tracer.activate(STARTUP_TRACE, root.span_id)
+
     from .utils.logging import log
     from .workers.startup import (
         apply_master_chips,
@@ -59,39 +91,57 @@ def main(argv: list[str] | None = None) -> int:
     # backend (libtpu takes every chip it can see), the cache before
     # the first compile, the backend before the listener so no request
     # handler is ever the first to touch it.
-    apply_master_chips(args.config)
+    with tracer.span("startup.chips"):
+        apply_master_chips(args.config)
     try:
-        configure_compile_cache()
-        init_backend(args.platform)
+        with tracer.span("startup.compile_cache") as span:
+            span.attrs["dir"] = configure_compile_cache()
+        with tracer.span("startup.backend") as span:
+            devices = init_backend(args.platform)
+            span.attrs.update(
+                platform=devices[0].platform,
+                device_kind=str(devices[0].device_kind), devices=len(devices),
+            )
     except RuntimeError as exc:
         log(f"backend start-up failed: {exc}")
         return 1
 
-    from . import native
-    from .api.server import DistributedServer
-    from .parallel.mesh import mesh_summary, note_serving_mesh, worker_mesh
-    from .workers.monitor import start_master_watchdog
-    from .workers.startup import (
-        auto_populate_workers,
-        delayed_auto_launch,
-        register_signals,
-        register_worker_drain,
-    )
+    with tracer.span("startup.imports"):
+        from . import native
+        from .api.server import DistributedServer
+        from .parallel.mesh import mesh_summary, note_serving_mesh, worker_mesh
+        from .workers.monitor import start_master_watchdog
+        from .workers.startup import (
+            auto_populate_workers,
+            delayed_auto_launch,
+            register_signals,
+            register_worker_drain,
+        )
 
     # every local chip serves: the same mesh rule the elastic tile tier
     # uses (None on one chip, and on the CPU unless CDT_MESH_SHAPE opts
     # in), handed to every node through the execution context
-    mesh = worker_mesh()
-    note_serving_mesh(mesh)
-    log(f"serving mesh {mesh_summary(mesh)}; data plane: {native.backend()}")
+    with tracer.span("startup.mesh") as span:
+        mesh = worker_mesh()
+        note_serving_mesh(mesh)
+        # the first question to the data plane builds or loads it
+        span.attrs["data_plane"] = native.backend()
+    log(f"serving mesh {mesh_summary(mesh)}; data plane: {span.attrs['data_plane']}")
 
+    # a manual pair: the constructor here, the listener on the loop
+    serving = tracer.start_span("startup.server", attrs={"port": args.port})
     server = DistributedServer(
         port=args.port, is_worker=args.worker, mesh=mesh,
         config_path=args.config, host=args.host, standby_of=args.standby,
     )
+    # before the loop copies this context: a request's handler joins
+    # its own trace, never this one
+    tracer.deactivate(joined)
 
     async def start():
         await server.start()
+        tracer.end_span(serving)
+        tracer.end_span(root)
         register_signals(asyncio.get_running_loop(), args.config)
         if not server.is_worker:
             auto_populate_workers(args.config)

@@ -128,22 +128,22 @@ def _toposort(prompt: Prompt) -> list[str]:
     return order
 
 
-# telemetry/runtime tally -> the node span attribute its delta goes by
+# telemetry/runtime.program_work() key -> the node span attribute its delta goes by
 _TALLY_ATTRS = {
     "compiles": "compiles",
     "compile_time_s": "compile_s",
     "cache_hits": "cache_hits",
     "cache_misses": "cache_misses",
-    "trace_time_s": "trace_s",
-    "lower_time_s": "lower_s",
-    "cache_retrieval_s": "cache_fetch_s",
+    "trace_s": "trace_s",
+    "lower_s": "lower_s",
+    "cache_fetch_s": "cache_fetch_s",
 }
 
 
 def _program_work(before: dict[str, Any], after: dict[str, Any]) -> dict[str, Any]:
-    """What JAX traced, lowered, built or fetched between two
-    `runtime.tallies()` snapshots, under the span attribute names;
-    quantities that did not move are left out."""
+    """Between two `runtime.program_work()` snapshots: four tallies of the
+    process, and the wall-clock sums over the `program.build` spans closed
+    under the node (the last three); what did not move is left out."""
     return {
         attr: after[key] - before[key]
         for key, attr in _TALLY_ATTRS.items()
@@ -244,13 +244,13 @@ class GraphExecutor:
             with tracer.span(
                 f"node.{node_def['class_type']}", node_id=node_id
             ) as span:
-                before = runtime.tallies()
+                before = runtime.program_work()
                 started = time.perf_counter()
                 result = fn(**kwargs)
                 self.last_timings[node_id] = round(
                     time.perf_counter() - started, 4
                 )
-                span.attrs.update(_program_work(before, runtime.tallies()))
+                span.attrs.update(_program_work(before, runtime.program_work()))
             self.nodes_run += 1
             if result is None:
                 result = ()

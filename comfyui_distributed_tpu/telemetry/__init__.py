@@ -9,8 +9,9 @@ The observability subsystem the ROADMAP's perf work hangs off:
   propagated master→worker via the ``X-CDT-Trace-Id`` header and
   served by `/distributed/trace/{trace_id}`: a served request's queue
   wait, nodes, device waits and PNG save are one tree, mirrored into
-  an open profiler capture; JSONL export feeds
-  `scripts/perf_report.py`;
+  an open profiler capture; the process's own start is the trace
+  `startup`; JSONL export feeds `scripts/perf_report.py` and, written
+  beside a capture, the benchmark's set-up split;
 - `instruments`: every metric name/label vocabulary in one place,
   plus `bind_server_collectors` for live-state gauges;
 - `events`: push-based event bus (metric deltas, span open/close,
@@ -18,9 +19,10 @@ The observability subsystem the ROADMAP's perf work hangs off:
   `GET /distributed/events` WebSocket;
 - `watchdog`: straggler & stall detector feeding breaker suspect
   transitions and speculative tail-tile re-dispatch;
-- `runtime`: JAX trace/lower/compile/cache/HBM/host-RSS collectors on
-  the scrape (read by `benchmark/client.py`) and, via
-  `runtime_snapshot`, in a worker's fleet snapshot;
+- `runtime`: JAX compile/cache/HBM/host-RSS collectors on the scrape
+  (read by `benchmark/client.py`) and, via `runtime_snapshot`, in a
+  worker's fleet snapshot; one `program.build` span a program JAX
+  takes to the device, its trace/lower/build/fetch on a wall clock;
 - `timeseries`: bounded two-tier ring-buffer retention (10 s raw /
   5 min rollup) for the fleet plane's windowed history;
 - `fleet`: worker snapshot production + the master's `FleetRegistry`

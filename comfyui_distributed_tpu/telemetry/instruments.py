@@ -389,25 +389,14 @@ def jax_compile_time_seconds() -> Gauge:
     )
 
 
-def jax_trace_time_seconds() -> Gauge:
-    return get_metrics_registry().gauge(
-        "cdt_jax_trace_time_seconds",
-        "Cumulative jaxpr tracing time since process start",
-    )
-
-
-def jax_lower_time_seconds() -> Gauge:
-    return get_metrics_registry().gauge(
-        "cdt_jax_lower_time_seconds",
-        "Cumulative jaxpr-to-MLIR lowering time since process start",
-    )
-
-
-def jax_cache_retrieval_seconds() -> Gauge:
-    return get_metrics_registry().gauge(
-        "cdt_jax_cache_retrieval_seconds",
-        "Cumulative compilation-cache retrieval time since process start "
-        "(part of cdt_jax_compile_time_seconds)",
+def program_seconds_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_program_seconds_total",
+        "Wall-clock seconds of a program's way to the device, by phase "
+        "(trace|lower|build|fetch): what the program.build spans hold, "
+        "a nested jit's tracing counted once. A build rate above zero "
+        "in steady state means something recompiles",
+        ("phase",),
     )
 
 

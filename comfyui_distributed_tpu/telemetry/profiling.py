@@ -38,7 +38,11 @@ Two pieces:
   slice); while a capture is open the tracer's context-managed spans
   are written into it as ``TraceAnnotation``s, and the start answer
   carries the tracer's clock and the wall clock read beside
-  ``start_trace``.
+  ``start_trace``. Every span the tracer holds at that moment (the
+  ``startup`` trace, the requests so far) is written once into the
+  capture's own directory, ``spans_before.jsonl``: a capture is one
+  bundle of the device's trace, the two clocks and what the host did
+  up to its start.
 
 Determinism contract (cdt-lint CDT004 covers this file): all clocks are
 injectable and used only for durations, capture ids derive from a
@@ -61,6 +65,8 @@ from ..utils.logging import debug_log
 from .tracing import get_tracer, set_span_annotator
 
 _NS = 1_000_000_000
+# beside a capture's .xplane.pb: the tracer's spans when it began
+SPANS_BEFORE = "spans_before.jsonl"
 
 # Transfer directions (metric label vocabulary).
 H2D = "h2d"
@@ -99,6 +105,22 @@ def _annotate_span(span: Any) -> Any:
             if k != "name" and isinstance(v, _ANNOTATION_TYPES)
         },
     )
+
+
+def _write_spans_before(path: str) -> dict[str, Any]:
+    """Every span the tracer holds, one a line, into the capture's
+    directory; on the thread that asked for the capture (the route's
+    pool, never the executor). `spans` written and the `write_s` it
+    took, on the tracer's clock; a disk that refuses costs the file,
+    not the capture."""
+    tracer = get_tracer()
+    began = tracer.now()
+    try:
+        spans = tracer.write_jsonl(None, os.path.join(path, SPANS_BEFORE))
+    except OSError as exc:
+        debug_log(f"profiler capture: {SPANS_BEFORE} not written: {exc}")
+        return {}
+    return {"spans": spans, "write_s": tracer.now() - began}
 
 
 def _to_ns(seconds: float) -> int:
@@ -429,6 +451,7 @@ class ProfilerCapture:
                 "duration_s": duration,
                 "tracer_clock_s": tracer_clock_s,
                 "unix_ns": unix_ns,
+                **_write_spans_before(path),
             }
 
     def stop(self) -> dict[str, Any]:

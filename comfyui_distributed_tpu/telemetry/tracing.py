@@ -30,8 +30,13 @@ Design:
   the trace's root span (if any) — server-side RPC spans connect to
   the orchestration root without shipping span ids over the wire;
 - storage is bounded: at most `max_traces` traces (oldest evicted) of
-  at most `max_spans_per_trace` spans each;
-- `write_jsonl` exports one span per line for offline analysis;
+  at most `max_spans_per_trace` spans each. The trace `startup`
+  (`STARTUP_TRACE`: the process's own start, opened by `__main__`) is
+  never evicted;
+- `record_span` stores a span whose start and end are already known
+  (a program JAX built, telemetry/runtime.py), parented like any other;
+- `write_jsonl` exports one span per line, of one trace or of several,
+  for offline analysis;
 - while a profiler capture is open (telemetry/profiling.py installs
   `set_span_annotator`), every context-managed span is mirrored into
   the capture on the thread that runs it, so the program's spans and
@@ -58,6 +63,9 @@ from typing import Any, Callable, Iterator, Optional
 
 TRACE_HEADER = "X-CDT-Trace-Id"
 WATCH_THREAD = "cdt-device-watch"
+# the process's own start: `process.start` and its children, and the
+# programs built outside any request; kept for the life of the process
+STARTUP_TRACE = "startup"
 
 # (trace_id, span_id) of the active span; span_id None = trace joined
 # via activate() but no span open yet.
@@ -219,7 +227,12 @@ class Tracer:
                 self._by_id[span.trace_id] = {}
                 self._roots.setdefault(span.trace_id, span.span_id)
                 while len(self._traces) > self.max_traces:
-                    evicted, _ = self._traces.popitem(last=False)
+                    evicted = next(
+                        (t for t in self._traces if t != STARTUP_TRACE), None
+                    )
+                    if evicted is None:
+                        break
+                    del self._traces[evicted]
                     self._roots.pop(evicted, None)
                     self._by_id.pop(evicted, None)
             else:
@@ -310,6 +323,25 @@ class Tracer:
             if span.status == "ok":
                 span.status = status
             _notify_span("close", span)
+
+    def record_span(
+        self,
+        name: str,
+        start: float,
+        end: float,
+        trace_id: Optional[str] = None,
+        parent_id: Optional[str] = None,
+        attrs: Optional[dict[str, Any]] = None,
+    ) -> Span:
+        """Store a span that is over: `start` and `end` are readings of
+        `now()` taken by whoever watched the work. Parented as
+        `start_span` parents (explicit parent, else the active span of
+        the calling thread, else the trace's root); never the active
+        span itself, and mirrored into no capture."""
+        span = self.start_span(name, trace_id, parent_id, attrs, start=start)
+        span.end = end
+        _notify_span("close", span)
+        return span
 
     @contextlib.contextmanager
     def span(
@@ -502,13 +534,22 @@ class Tracer:
         sort_rec(roots)
         return roots
 
-    def write_jsonl(self, trace_id: str, path: str) -> int:
-        """Export one span per line; returns the number written."""
-        spans = self.spans(trace_id)
+    def write_jsonl(
+        self, trace_id: "str | list[str] | None", path: str
+    ) -> int:
+        """Export one span per line: of one trace, of the traces
+        listed, or (None) of every trace held, in storage order.
+        Returns the number written."""
+        if trace_id is None:
+            trace_id = self.trace_ids()
+        wanted = [trace_id] if isinstance(trace_id, str) else list(trace_id)
+        written = 0
         with open(path, "w", encoding="utf-8") as fh:
-            for span in spans:
-                fh.write(json.dumps(span, sort_keys=True) + "\n")
-        return len(spans)
+            for one in wanted:
+                for span in self.spans(one):
+                    fh.write(json.dumps(span, sort_keys=True, default=str) + "\n")
+                    written += 1
+        return written
 
     def reset(self) -> None:
         with self._lock:

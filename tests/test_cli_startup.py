@@ -112,6 +112,21 @@ def test_cli_server_builds_its_mesh_from_worker_mesh(tmp_path):
         assert topology["platform"] == "cpu"
         assert topology["local_device_count"] == 8
         assert topology["mesh"] == {"data": 4, "model": 1, "devices": 4}
+        # the start itself is a trace the running server serves
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/distributed/trace/startup", timeout=5
+        ) as resp:
+            (root,) = json.loads(resp.read())["tree"]
+        assert root["name"] == "process.start" and root["end"] is not None
+        assert root["attrs"]["pid"] == proc.pid and root["attrs"]["role"] == "master"
+        assert 0.0 < root["attrs"]["python_s"] < root["duration"]
+        children = [c for c in root["children"] if c["name"].startswith("startup.")]
+        assert [c["name"] for c in children] == [
+            "startup.chips", "startup.compile_cache", "startup.backend",
+            "startup.imports", "startup.mesh", "startup.server"]
+        assert all(root["start"] <= c["start"] <= c["end"] <= root["end"] for c in children)
+        assert children[2]["attrs"] == {"platform": "cpu", "device_kind": "cpu", "devices": 8}
+        assert children[1]["attrs"]["dir"] and children[5]["attrs"] == {"port": port}
         proc.send_signal(signal.SIGTERM)
         assert proc.wait(timeout=60) == 0
     finally:

@@ -1,8 +1,13 @@
 """The served path's span tree: queue wait, nodes, device waits and the
 PNG save in the request's one trace; the profiler mirror; the set-up
-tallies. All on a tracer whose clock ticks once per reading, so every
+split (one `program.build` span a program, the counter, the file beside
+a capture). All on a tracer whose clock ticks once per reading, so every
 duration is a pure function of the span sequence."""
 
+import json
+import os
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -33,12 +38,36 @@ class SpanTestImage:
     FUNCTION = "make"
 
     def make(self, value):
-        import jax
-
         # what a node that builds a program makes JAX report
-        jax.monitoring.record_event_duration_secs(TRACE_EVENT, 0.25)
-        jax.monitoring.record_event_duration_secs(FETCH_EVENT, 0.5)
+        report_program("make", at=100.0, trace=0.25, lower=0.125, fetch=0.5, compile_=0.75)
         return (np.full((1, 8, 8, 3), float(value), np.float32),)
+
+
+def report_program(name, at, trace=0.0, lower=0.0, fetch=None, compile_=None, inner=()):
+    """The events of one program's way to the device as JAX reports
+    them on the calling thread: the trace of `name` from `at` (with
+    `inner` jits' traces inside it, as (name, offset, seconds)), the
+    lowering and the backend compile of `jit(name)` after it, the
+    compile holding a cache retrieval of `fetch` seconds. `at` is on
+    the listener's clock, whatever JAX's own reads."""
+    from jax import monitoring
+
+    at -= runtime._clock_offset
+    for inner_name, offset, seconds in inner:
+        monitoring.record_event_time_span(
+            TRACE_EVENT, at + offset, at + offset + seconds, fun_name=inner_name)
+    monitoring.record_event_time_span(TRACE_EVENT, at, at + trace, fun_name=name)
+    at += trace
+    if lower:
+        monitoring.record_event_time_span(LOWER_EVENT, at, at + lower, fun_name=f"jit({name})")
+        at += lower
+    if compile_ is not None:
+        if fetch is not None:
+            monitoring.record_event("/jax/compilation_cache/cache_hits")
+            monitoring.record_event_duration_secs(FETCH_EVENT, fetch)
+        monitoring.record_event_duration_secs(COMPILE_EVENT, compile_, fun_name=f"jit({name})")
+        monitoring.record_event_time_span(
+            COMPILE_EVENT, at, at + compile_, fun_name=f"jit({name})")
 
 
 def graph(value=0.5):
@@ -170,9 +199,17 @@ def test_node_spans_carry_the_program_work_done_in_them(server, tracer):
     server.queue_prompt(graph(), "p1")
     run_queued(server)
     spans = by_name(tracer, "p1")
-    source = spans["node.SpanTestImage"][0]["attrs"]
-    assert source == {"node_id": "1", "trace_s": 0.25, "cache_fetch_s": 0.5}
+    source = spans["node.SpanTestImage"][0]
+    assert source["attrs"] == {
+        "node_id": "1", "compiles": 1, "compile_s": 0.75, "cache_hits": 1,
+        "trace_s": 0.25, "lower_s": 0.125, "cache_fetch_s": 0.5}
     assert spans["node.SaveImage"][0]["attrs"] == {"node_id": "2"}
+    # the node's numbers are its one program.build child's
+    (built,) = spans["program.build"]
+    assert built["parent_id"] == source["span_id"]
+    assert built["attrs"] == {"program": "jit(make)", "outcome": "fetched", "trace_s": 0.25,
+                              "lower_s": 0.125, "build_s": 0.25, "fetch_s": 0.5}
+    assert (built["start"], built["end"]) == (100.0, 101.125)
 
 
 def collect(tracer, images, workers=(), context=None):
@@ -509,31 +546,188 @@ def test_a_mirror_that_raises_does_not_break_the_span(tracer):
 # --- set-up, split ------------------------------------------------------------
 
 
-def test_the_new_tallies_fill_from_monitoring_events_and_reach_the_scrape():
+def seconds_by_phase():
+    """cdt_program_seconds_total as the scrape shows it."""
+    out = {}
+    for line in get_metrics_registry().render().splitlines():
+        if line.startswith("cdt_program_seconds_total{"):
+            out[line.split('"')[1]] = float(line.rsplit(" ", 1)[1])
+    return out
+
+
+def test_the_new_tallies_fill_from_monitoring_events_and_reach_the_scrape(tracer):
     import jax
 
     runtime.install_jax_monitoring()
-    before = runtime.tallies()
-    jax.monitoring.record_event_duration_secs(TRACE_EVENT, 1.5)
-    jax.monitoring.record_event_duration_secs(LOWER_EVENT, 0.75)
-    jax.monitoring.record_event_duration_secs(FETCH_EVENT, 0.25)
-    jax.monitoring.record_event_duration_secs(COMPILE_EVENT, 2.0)
+    before, counted = runtime.program_work(), seconds_by_phase()
+    with tracer.span("node.X", trace_id="t"):
+        report_program("f", at=10.0, trace=1.5, lower=0.75, fetch=0.25, compile_=2.0)
     jax.monitoring.record_event_duration_secs("/jax/some/other_duration", 9.0)
-    after = runtime.tallies()
+    jax.monitoring.record_event_time_span("/jax/some/other_span", 1.0, 10.0)
+    after = runtime.program_work()
     moved = {k: after[k] - before[k] for k in after if after[k] != before[k]}
-    assert moved == {"trace_time_s": 1.5, "lower_time_s": 0.75,
-                     "cache_retrieval_s": 0.25, "compile_time_s": 2.0, "compiles": 1}
-    runtime.collect_runtime_gauges()
+    assert moved == {"trace_s": 1.5, "lower_s": 0.75, "cache_fetch_s": 0.25,
+                     "compile_time_s": 2.0, "compiles": 1, "cache_hits": 1}
+    now = seconds_by_phase()
+    for phase, seconds in (("trace", 1.5), ("lower", 0.75), ("fetch", 0.25), ("build", 1.75)):
+        assert now[phase] - counted.get(phase, 0.0) == seconds, phase
     text = get_metrics_registry().render()
-    for name, key in (("cdt_jax_trace_time_seconds", "trace_time_s"),
-                      ("cdt_jax_lower_time_seconds", "lower_time_s"),
-                      ("cdt_jax_cache_retrieval_seconds", "cache_retrieval_s")):
-        assert f"{name} {after[key]}" in text or f"{name} {after[key]:g}" in text
+    for gone in ("cdt_jax_trace_time_seconds", "cdt_jax_lower_time_seconds",
+                 "cdt_jax_cache_retrieval_seconds"):
+        assert gone not in text
 
 
 def test_reset_zeroes_every_tally_with_its_type():
     runtime.reset_runtime_tallies()
-    zero = runtime.tallies()
+    zero = runtime.program_work()
     assert all(v == 0 for v in zero.values())
-    assert isinstance(zero["compiles"], int) and isinstance(zero["trace_time_s"], float)
-    assert set(runtime.runtime_snapshot()) >= set(zero)
+    assert isinstance(zero["compiles"], int) and isinstance(zero["trace_s"], float)
+    assert set(runtime.runtime_snapshot()) >= set(runtime.tallies())
+    assert set(zero) == set(runtime.tallies()) | {"trace_s", "lower_s", "cache_fetch_s"}
+
+
+def test_a_jit_inside_a_jit_is_one_program_build_span_counted_once(tracer):
+    """The case the duration tallies counted twice: `inner` is traced
+    inside `outer`'s trace, twice. One span, closed by the backend
+    compile of `jit(outer)`, whose phases are unions: they add up to no
+    more than the span covers."""
+    runtime.install_jax_monitoring()
+    with tracer.span("node.KSampler", trace_id="t") as node:
+        before = runtime.program_work()
+        report_program("outer", at=50.0, trace=4.0, lower=1.0, compile_=2.0,
+                       inner=[("sin", 0.5, 0.25), ("inner", 0.25, 1.5), ("inner", 2.0, 1.0)])
+        after = runtime.program_work()
+    (built,) = by_name(tracer, "t")["program.build"]
+    attrs = built["attrs"]
+    assert attrs == {"program": "jit(outer)", "outcome": "built", "trace_s": 4.0,
+                     "lower_s": 1.0, "build_s": 2.0, "fetch_s": 0.0}
+    assert (built["start"], built["end"], built["parent_id"]) == (50.0, 57.0, node.span_id)
+    assert attrs["trace_s"] + attrs["lower_s"] + attrs["build_s"] + attrs["fetch_s"] <= (
+        built["duration"])
+    assert after["trace_s"] - before["trace_s"] == 4.0  # not 4.0 + 0.25 + 1.5 + 1.0
+
+
+def test_what_is_only_traced_is_a_span_of_its_own_closed_with_the_node(tracer):
+    """An `eval_shape` ends in no compile: its outermost trace is closed
+    when the node asks what it cost (or by the next compile), before the
+    program that follows it, each with its own inner traces."""
+    runtime.install_jax_monitoring()
+    with tracer.span("node.Loader", trace_id="t"):
+        before = runtime.program_work()
+        report_program("shapes", at=10.0, trace=2.0, inner=[("init", 0.5, 1.0)])
+        report_program("cast", at=13.0, trace=0.5, lower=0.25, compile_=0.25)
+        report_program("late", at=20.0, trace=1.0)
+        assert len(by_name(tracer, "t")["program.build"]) == 2  # `late` still pending
+        after = runtime.program_work()
+    spans = by_name(tracer, "t")["program.build"]
+    assert [(s["attrs"]["program"], s["attrs"]["outcome"], s["start"], s["end"])
+            for s in spans] == [("shapes", "traced", 10.0, 12.0), ("jit(cast)", "built", 13.0, 14.0),
+                                ("late", "traced", 20.0, 21.0)]
+    assert after["trace_s"] - before["trace_s"] == 3.5
+    assert sum(s["attrs"]["trace_s"] for s in spans) == 3.5
+
+
+def test_a_program_built_outside_a_request_goes_under_the_startup_root(tracer):
+    runtime.install_jax_monitoring()
+    report_program("nowhere", at=1.0, trace=1.0, compile_=1.0)  # no trace: no span
+    assert tracer.trace_ids() == []
+    root = tracer.start_span("process.start", trace_id=tracing.STARTUP_TRACE)
+    report_program("warm", at=5.0, trace=1.0, lower=1.0, compile_=1.0)
+    (built,) = by_name(tracer, tracing.STARTUP_TRACE)["program.build"]
+    assert (built["parent_id"], built["attrs"]["program"]) == (root.span_id, "jit(warm)")
+
+
+def test_a_real_nested_jit_adds_up_and_the_node_sums_its_children(wall_tracer):
+    """The same on JAX's own events, whatever this machine's speed."""
+    import jax
+    import jax.numpy as jnp
+
+    runtime.install_jax_monitoring()
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) * 2
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + inner(x + 1)
+
+    x = jnp.ones(7)
+    with wall_tracer.span("node.X", trace_id="t") as node:
+        before = runtime.program_work()
+        outer(x)
+        jax.eval_shape(outer, jnp.ones(9))
+        node.attrs.update(
+            {k: v - before[k] for k, v in runtime.program_work().items() if v != before[k]})
+    spans = [s for s in by_name(wall_tracer, "t")["program.build"]]
+    assert [s["attrs"]["program"] for s in spans if "outer" in s["attrs"]["program"]] == [
+        "jit(outer)", "outer"]
+    for span in spans:
+        attrs = span["attrs"]
+        assert span["parent_id"] == node.span_id
+        assert node.start <= span["start"] <= span["end"] <= node.end
+        assert attrs["trace_s"] + attrs["lower_s"] + attrs["build_s"] + attrs["fetch_s"] <= (
+            span["duration"] + 1e-9)
+    assert node.attrs["trace_s"] == pytest.approx(sum(s["attrs"]["trace_s"] for s in spans))
+    assert node.attrs["lower_s"] == pytest.approx(sum(s["attrs"]["lower_s"] for s in spans))
+
+
+_TWICE = """
+import json, sys
+import jax, jax.numpy as jnp
+from comfyui_distributed_tpu.telemetry import get_tracer
+from comfyui_distributed_tpu.workers.startup import configure_compile_cache
+configure_compile_cache()
+tracer = get_tracer()
+with tracer.span("node.X", trace_id="t"):
+    jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((16, 16))).block_until_ready()
+    from comfyui_distributed_tpu.telemetry import runtime
+    runtime.close_programs()
+print(json.dumps([s["attrs"] for s in tracer.spans("t") if s["name"] == "program.build"
+                  and "lambda" in s["attrs"]["program"]]))
+"""
+
+
+def test_outcome_is_fetched_on_the_second_process_of_a_cache_directory(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               PYTHONPATH=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    outcomes = []
+    for _ in range(2):
+        done = subprocess.run([sys.executable, "-c", _TWICE], env=env, capture_output=True,
+                              text=True, timeout=120, cwd=str(tmp_path))
+        assert done.returncode == 0, done.stderr[-2000:]
+        (attrs,) = json.loads(done.stdout.strip().splitlines()[-1])
+        outcomes.append(attrs)
+    assert [a["outcome"] for a in outcomes] == ["built", "fetched"]
+    assert outcomes[0]["fetch_s"] == 0.0 and outcomes[0]["build_s"] > 0.0
+    assert outcomes[1]["fetch_s"] > 0.0
+
+
+def test_capture_start_writes_the_spans_it_holds_beside_the_capture(
+    tmp_path, fake_jax_profiler, tracer
+):
+    """One bundle: the device's trace, the two clocks, and what the host
+    did up to the capture's start, the `startup` trace among it."""
+    root = tracer.start_span("process.start", trace_id=tracing.STARTUP_TRACE)
+    tracer.end_span(root)
+    with tracer.span("execute_prompt", trace_id="exec_1"):
+        with tracer.span("node.KSampler"):
+            pass
+    running = tracer.start_span("execute_prompt", trace_id="exec_2")  # still open
+    capture = profiling.ProfilerCapture(str(tmp_path))
+    answer = capture.start(5, "bench")
+    assert answer["started"] and answer["spans"] == 4 and answer["write_s"] == 1.0
+    path = os.path.join(answer["path"], profiling.SPANS_BEFORE)
+    assert os.path.dirname(path) == str(tmp_path / "trace-0001-bench")
+    with open(path, encoding="utf-8") as fh:
+        lines = [json.loads(line) for line in fh]
+    assert [(l["trace_id"], l["name"]) for l in lines] == [
+        (tracing.STARTUP_TRACE, "process.start"), ("exec_1", "execute_prompt"),
+        ("exec_1", "node.KSampler"), ("exec_2", "execute_prompt")]
+    assert lines[3]["end"] is None and lines[0] == tracer.spans(tracing.STARTUP_TRACE)[0]
+    tracer.end_span(running)
+    with tracer.span("after", trace_id="exec_3"):
+        pass
+    capture.stop()
+    with open(path, encoding="utf-8") as fh:
+        assert len(fh.readlines()) == 4  # written once, at the start

@@ -4,7 +4,7 @@ trace root, bounded retention, JSONL export, deterministic fake clock."""
 import json
 import threading
 
-from comfyui_distributed_tpu.telemetry import Tracer, get_tracer, reset_tracer
+from comfyui_distributed_tpu.telemetry import Tracer, get_tracer, reset_tracer, tracing
 from comfyui_distributed_tpu.resilience.chaos import FakeClock
 
 
@@ -119,6 +119,81 @@ def test_eviction_is_lru_not_insertion_order():
     assert len(active) == 11  # nothing lost to eviction
     # and the root survived, so the tree stays singly-rooted
     assert len(tracer.tree("active")) == 1
+
+
+def test_the_startup_trace_outlives_257_traces():
+    """The oldest of `max_traces` goes first, but never the process's
+    own start: a server that has answered 300 prompts still serves it."""
+    tracer = Tracer(clock=FakeClock(step=1.0))
+    root = tracer.start_span("process.start", trace_id=tracing.STARTUP_TRACE)
+    with tracer.span("startup.backend", trace_id=tracing.STARTUP_TRACE):
+        pass
+    tracer.end_span(root)
+    for i in range(257):
+        with tracer.span("execute_prompt", trace_id=f"exec_{i}"):
+            pass
+    ids = tracer.trace_ids()
+    assert len(ids) == tracer.max_traces == 256
+    assert tracing.STARTUP_TRACE in ids and "exec_0" not in ids and "exec_1" not in ids
+    assert [s["name"] for s in tracer.spans(tracing.STARTUP_TRACE)] == [
+        "process.start", "startup.backend"]
+    assert tracer.root_span_id(tracing.STARTUP_TRACE) == root.span_id
+
+
+def test_record_span_parents_and_orders_as_a_context_managed_span_does():
+    """A span whose start and end someone else read: under the active
+    span, else under the trace's root, between its siblings by start,
+    and never the active span itself."""
+    tracer = Tracer(clock=FakeClock(step=1.0))
+    with tracer.span("node.KSampler", trace_id="t") as node:
+        with tracer.span("device.wait"):
+            pass
+        built = tracer.record_span(
+            "program.build", node.start + 0.25, node.start + 0.5, attrs={"program": "jit(f)"})
+        assert tracer.current_span_id() == node.span_id
+        with tracer.span("png.encode") as later:
+            pass
+    assert (built.parent_id, built.trace_id, built.status) == (node.span_id, "t", "ok")
+    assert built.duration == 0.25 and built.attrs == {"program": "jit(f)"}
+    (tree,) = tracer.tree("t")
+    assert [c["name"] for c in tree["children"]] == ["program.build", "device.wait", "png.encode"]
+    assert later.parent_id == node.span_id
+    # outside any span of the trace: the root's child
+    token = tracer.activate("t")
+    try:
+        orphan = tracer.record_span("program.build", 0.0, 1.0)
+    finally:
+        tracer.deactivate(token)
+    assert orphan.parent_id == node.span_id  # node is the trace's root
+    # an explicit trace and parent, as start_span takes them
+    other = tracer.record_span("program.build", 1.0, 2.0, trace_id="u")
+    assert (other.trace_id, other.parent_id) == ("u", None)
+
+
+def test_record_span_reaches_the_span_listener_open_then_close():
+    seen, installed = [], tracing._span_listener  # the event bus's, once it is up
+    tracing.set_span_listener(lambda phase, span: seen.append((phase, span.name, span.end)))
+    try:
+        Tracer().record_span("program.build", 1.0, 3.0, trace_id="t")
+    finally:
+        tracing.set_span_listener(installed)
+    assert seen == [("open", "program.build", None), ("close", "program.build", 3.0)]
+
+
+def test_jsonl_export_takes_several_traces_or_all(tmp_path):
+    tracer = Tracer(clock=FakeClock(step=1.0))
+    for trace_id in ("a", "b", "c"):
+        with tracer.span("execute_prompt", trace_id=trace_id):
+            with tracer.span("node.SaveImage"):
+                pass
+    path = tmp_path / "some.jsonl"
+    assert tracer.write_jsonl(["c", "a", "nowhere"], str(path)) == 4
+    lines = [json.loads(line) for line in path.read_text().splitlines()]
+    assert [l["trace_id"] for l in lines] == ["c", "c", "a", "a"]
+    assert tracer.write_jsonl(None, str(path)) == 6
+    lines = [json.loads(line) for line in path.read_text().splitlines()]
+    assert [l["trace_id"] for l in lines] == ["a", "a", "b", "b", "c", "c"]
+    assert lines[0] == tracer.spans("a")[0]
 
 
 def test_jsonl_export_round_trip(tmp_path):
