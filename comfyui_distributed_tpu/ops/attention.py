@@ -35,12 +35,23 @@ import jax.numpy as jnp
 ROUTE_MULTIPLE = 128
 
 # Block caps, from the sweep on a v5e (PERF.md §6, PR 28): a grid step has
-# a fixed cost of a few tenths of a microsecond and an online-softmax
-# update costs per q row whatever the number of k columns, so a step
-# should cover as much of the problem as VMEM takes. Wider than this the
-# kernel stopped gaining (block_k 2304, 2048) or lost.
+# a fixed cost of a few tenths of a microsecond (the pipeline's turn, and
+# a q block's first and last step set up and divide the carried state), so
+# a step should cover as much of the problem as VMEM takes. Wider than
+# this the kernel stopped gaining (block_k 2304, 2048) or lost.
 MAX_BLOCK_Q = 512
 MAX_BLOCK_K = 1536
+# Rows of a q block a k step takes at a time: a chunk's scores, max, `exp`,
+# partial sums and second product before the next chunk's, so that its
+# float32 scores pass through VMEM once. What stretched a step over the
+# MXU's time was the one vector-store slot, three stores in four of them
+# register spills of scores swept whole four times, and two cross-lane
+# reductions a step (`scripts/kernel_bundles.py`; PERF.md §6, PR 51). On a
+# v5e, ms a call at no chunks / 256 / 128: SD1.5's 4,096 x 40 1.059 / 0.983
+# / 1.162 (1.136 before), FLUX's 4,608 x 128 2.151 / 2.051 / 2.179 (2.314),
+# 8,192 causal at 64 over 8 heads 11.15 / 10.59 / 12.14 (11.90): under 256
+# the K tiles go into the MXU again for every chunk and the chunks serialise.
+ROW_CHUNK = 256
 # What one grid step may hold in VMEM by `flash_vmem_bytes`' count. The
 # compiler's scoped limit is 16 MiB, and it keeps temporaries of its own.
 VMEM_BUDGET = 12 * 2**20
@@ -57,9 +68,9 @@ ROW_MULTIPLE = 16
 # wins from 500 (1.25 against 1.47) and 576 (0.66 against 0.81) on.
 MIN_RAGGED_KEYS = 512
 # (block_q, block_k) caps of a causal call and of one under a window,
-# swept on a v5e (PERF.md §6, PR 43). A step's cost is mostly fixed (the
-# running max, the rescaled accumulator, the pipeline's turn), so the
-# widest k block wins although the triangle then computes more of the
+# swept on a v5e (PERF.md §6, PR 43, on the k step as it was until PR 51:
+# its stores and reductions cost per q row whatever the k block's width).
+# The widest k block wins although the triangle then computes more of the
 # square: 8,192 tokens at 64 heads take 16.9 ms as 512 x 512 (136 of 256
 # blocks), 11.5 as 512 x 1,024 (72 of 128), 30.3 as 512 x 256. A band
 # of 128 keys a row is all edge: 512 x 512 was its best (3.74 ms).
@@ -127,9 +138,12 @@ def dot_product_attention(
     Head dims that aren't lane-aligned (SD1.5 uses 40/80/160, SDXL 64)
     are zero-padded to the 128 lane width inside `flash_attention`, with
     the softmax scale pinned to the ORIGINAL head dim and the output
-    sliced back. That is not free: the kernel reads, multiplies and
-    writes the padded lanes too, 3.2 x the model's bytes at 40 wide and
-    2 x at 64 (packing narrow heads into one lane tile is ROADMAP S2).
+    sliced back. The padded lanes cost the MXU nothing: a 40-wide
+    head's QK^T is one 128-deep pass and its P.V one 128-wide output
+    tile, as a 128-wide head's, and heads cannot share a pass since each
+    has its own P. What a narrow head pays is the copy that pads and
+    folds it, about 0.16 of a 1.18 ms call at SD1.5's 4,096 x 40
+    (ROADMAP S2 has what dropping it would give back; PERF.md §6, PR 51).
     """
     if causal:
         return causal_attention(
@@ -323,7 +337,8 @@ def flash_vmem_bytes(block_q: int, block_k: int, d: int, itemsize: int) -> int:
     """VMEM one grid step of the kernel holds: the q, k, v and output
     blocks (double-buffered by the pipeline), the float32 accumulator,
     the running max and sum (a lane tile wide each), and the step's
-    float32 scores, their `exp`, and `p` in the operands' dtype."""
+    float32 scores, their `exp`, and `p` in the operands' dtype (a bound:
+    since PR 51 a step holds them a `ROW_CHUNK` of rows at a time)."""
     blocks = 2 * (2 * block_q + 2 * block_k) * d * itemsize
     carried = block_q * (d + 2 * ROUTE_MULTIPLE) * 4
     scores = block_q * block_k * (4 + 4 + itemsize)
@@ -431,6 +446,17 @@ def flash_attention(
     (sequential, "arbitrary") grid dimension; the output block is
     written on the last K step.
 
+    A k step walks its q block `ROW_CHUNK` rows at a time, and carries
+    its softmax state a lane tile wide: every lane of a row of the
+    running max holds the row's max (the VPU's max over the step's lane
+    tiles of scores, then the one cross-lane reduction a step, which
+    comes back replicated), so the correction multiplies accumulator
+    and sum without a broadcast; lane l of the running sum holds the sum
+    of `p` over the columns l, l + 128, ... seen so far, added a lane
+    tile at a time on the VPU, and is reduced across lanes once a q
+    block, before the divide. Float32 throughout, as before: only the
+    order of the float32 sum differs.
+
     Heads are read and written where the caller left them: q, k, v and
     the output are [B, N, H*D] to the kernel (a reshape of the two minor
     axes, no data moves) and grid index `bh` takes the (1, block, D)
@@ -524,29 +550,37 @@ def flash_attention(
             max_ref[...] = jnp.full_like(max_ref, -jnp.inf)
             sum_ref[...] = jnp.zeros_like(sum_ref)
 
+        def lane_tiles(x):
+            return [x[:, at:at + ROUTE_MULTIPLE] for at in range(0, x.shape[1], ROUTE_MULTIPLE)]
+
+        def lanes_wide(x, width):  # a lane-replicated [rows, 128] as [rows, width]
+            return x if width == ROUTE_MULTIPLE else jnp.tile(x, (1, width // ROUTE_MULTIPLE))
+
         def update(mask):
             """One k block into the running max, sum and accumulator;
-            `mask(scores)` where some score of the block is not seen."""
+            `mask(scores, first row)` where some score of the block is not
+            seen."""
             vb = v_ref[0]                                # [block_k, Dv]
-            scores = scale * jax.lax.dot_general(        # [block_q, block_k]
-                q_ref[0], k_ref[0], contract_last,
-                preferred_element_type=jnp.float32,
-            )
-            if mask is not None:
-                scores = mask(scores)
-            row_max = max_ref[...]
-            new_max = jnp.maximum(row_max, scores.max(axis=-1, keepdims=True))
-            correction = jnp.exp(row_max - new_max)
-            p = jnp.exp(scores - new_max)
-            acc_ref[...] = acc_ref[...] * correction + jnp.dot(
-                p.astype(vb.dtype), vb, preferred_element_type=jnp.float32
-            )
-            sum_ref[...] = sum_ref[...] * correction + p.sum(
-                axis=-1, keepdims=True
-            )
-            max_ref[...] = new_max
+            for start in range(0, block_q, ROW_CHUNK):
+                chunk = slice(start, min(start + ROW_CHUNK, block_q))
+                scores = scale * jax.lax.dot_general(    # [chunk, block_k]
+                    q_ref[0, chunk], k_ref[0], contract_last,
+                    preferred_element_type=jnp.float32,
+                )
+                if mask is not None:
+                    scores = mask(scores, start)
+                row_max = max_ref[chunk]                 # every lane the row's
+                new_max = jnp.maximum(row_max, functools.reduce(
+                    jnp.maximum, lane_tiles(scores)).max(axis=-1, keepdims=True))
+                correction = jnp.exp(row_max - new_max)
+                p = jnp.exp(scores - lanes_wide(new_max, block_k))
+                sum_ref[chunk] = sum_ref[chunk] * correction + functools.reduce(
+                    jnp.add, lane_tiles(p))
+                acc_ref[chunk] = acc_ref[chunk] * lanes_wide(correction, dv) + jnp.dot(
+                    p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+                max_ref[chunk] = new_max
 
-        def padded_keys(scores):
+        def padded_keys(scores, start):
             # the last k block's tail is padding; it always holds a key too
             # (`_tile` pads less than a block), so no row is -inf throughout
             cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -564,8 +598,8 @@ def flash_attention(
             if window is not None:  # or by the band's lower edge, the last row's
                 crossed |= kb * block_k < top + block_q - window
 
-            def band(scores):
-                row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+            def band(scores, start):
+                row = qi * block_q + start + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
                 if n > rows:  # a padded row sees what the last true row sees
                     row = jnp.minimum(row, rows - 1)
                 own = row + (keys - rows)  # the row's own key: padded keys lie past it
@@ -580,7 +614,8 @@ def flash_attention(
 
         @pl.when(ki == num_k_blocks - 1)
         def _finalize():
-            o_ref[0] = (acc_ref[...] / sum_ref[...]).astype(o_ref.dtype)
+            total = sum_ref[...].sum(axis=-1, keepdims=True)
+            o_ref[0] = (acc_ref[...] / total).astype(o_ref.dtype)
 
     def q_map(bh, qi, ki):
         return bh // lanes, qi, bh % lanes
@@ -606,8 +641,8 @@ def flash_attention(
         out_shape=jax.ShapeDtypeStruct((*q.shape[:2], lanes * dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),  # acc
-            pltpu.VMEM((block_q, 1), jnp.float32),  # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),  # running sum
+            pltpu.VMEM((block_q, ROUTE_MULTIPLE), jnp.float32),  # running max, lane-replicated
+            pltpu.VMEM((block_q, ROUTE_MULTIPLE), jnp.float32),  # running sum, a partial a lane
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),

@@ -1,0 +1,155 @@
+#!/usr/bin/env python3
+"""VLIW bundles and slot totals of a Pallas kernel's body, compiled for a
+described TPU v5e with libtpu's own dump. By hand, on the CPU, no chip:
+
+    python scripts/kernel_bundles.py                  # every case below
+    python scripts/kernel_bundles.py "flux joint 4608" --keep /root/scratch/llo
+
+A case is a label, the kernel's name in the compiled program, and a
+function that lowers one call of it at a served shape. libtpu writes one
+file a compiler pass and kernel under `--xla_jf_dump_to`; the file
+`*-<kernel>*-final_hlo-static-per-bundle-utilization.txt` has one line a
+bundle and the slots it fills. The process that loaded libtpu with those
+flags aborts once its dumps are written, so every case compiles in a child
+of its own (`--child`) and the parent, which never imports JAX, reads the
+files. How to read the numbers: docs/performance.md, "Reading a kernel's
+bundles". Nothing here runs or times anything: a time comes from the chip.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import os
+import re
+import shutil
+import subprocess
+import sys
+import tempfile
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+UTILIZATION = "final_hlo-static-per-bundle-utilization.txt"
+
+
+def _attention(q_shape, kv_heads=None, v_width=None, window=None, causal=False):
+    """Lower one `ops/attention` call on the kernel: q [B, N, H, D] over
+    as many keys, bf16 as every served path."""
+    def lower(place):
+        import functools
+
+        from comfyui_distributed_tpu.ops import attention as attn
+
+        b, n, h, d = q_shape
+        q = place(q_shape)
+        k = place((b, n, kv_heads or h, d))
+        v = place((b, n, kv_heads or h, v_width or d))
+        if causal:
+            fn = functools.partial(attn.causal_attention, window=window, force_flash=True)
+        else:
+            fn = functools.partial(attn.dot_product_attention, force_flash=True)
+        return fn, (q, k, v)
+    return lower
+
+
+# label -> (kernel's name in the compiled program, lowering). The labels
+# are `chip_smoke.SERVED_SHAPES` / `CAUSAL_SHAPES`', so a bundle count and
+# the chip's time of `chip_smoke.py --legs attention` read side by side.
+CASES = {
+    "sd15 self 64x64": ("flash_attention", _attention((2, 4096, 8, 40))),
+    "flux joint 4608": ("flash_attention", _attention((1, 4608, 24, 128))),
+    "sdxl tile self 36x36": ("flash_attention", _attention((16, 1296, 10, 64))),
+    "solar / k-exaone full 8192": (
+        "flash_attention_causal", _attention((1, 8192, 64, 128), kv_heads=8, causal=True)),
+    "k-exaone window 8192": (
+        "flash_attention_causal",
+        _attention((1, 8192, 64, 128), kv_heads=8, window=128, causal=True)),
+    "deepseek-v2 mla 2048": (
+        "flash_attention_causal", _attention((1, 2048, 128, 192), v_width=128, causal=True)),
+}
+DEFAULT = ("sd15 self 64x64", "flux joint 4608", "solar / k-exaone full 8192")
+
+
+def child(label: str) -> None:
+    """Compile the case for a described v5e; libtpu dumps as it goes."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    fn, args = CASES[label][1](
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip))
+    jax.jit(fn).lower(*args).compile()
+
+
+def read_bundles(dump: str, kernel: str) -> dict:
+    """Bundles and the sum of every slot's column over the kernel's file."""
+    pattern = re.compile(rf"^\d+-{re.escape(kernel)}(\.\d+)?-\d+-{re.escape(UTILIZATION)}$")
+    found = [p for p in glob.glob(os.path.join(dump, f"*{UTILIZATION}"))
+             if pattern.match(os.path.basename(p))]
+    if len(found) != 1:
+        raise SystemExit(f"{len(found)} utilization files of {kernel} under {dump}")
+    lines = open(found[0]).read().splitlines()
+    at = lines.index("== UTILIZATION:")
+    slots = [s.strip() for s in lines[at - 2].split(",")]
+    capacity = [int(x) for x in lines[at - 1].split()]
+    rows = [[int(x) for x in line.split()] for line in lines[at + 1:] if line.strip()]
+    totals = [sum(col) for col in zip(*rows)]
+    return {"bundles": len(rows), "slots": dict(zip(slots, zip(totals, capacity)))}
+
+
+def measure(label: str, keep: str | None) -> dict:
+    kernel = CASES[label][0]
+    dump = tempfile.mkdtemp(prefix="kernel_bundles_", dir=keep)
+    env = dict(
+        os.environ,
+        LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true",
+        ALLOW_MULTIPLE_LIBTPU_LOAD="1",  # a test run beside this one may hold libtpu's lock
+    )
+    done = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--child", label],
+        env=env, cwd=REPO, capture_output=True, text=True)
+    try:
+        return read_bundles(dump, kernel)
+    except SystemExit:
+        sys.stderr.write(done.stderr[-4000:])  # a compile that failed says why here
+        raise
+    finally:
+        if keep is None:
+            shutil.rmtree(dump, ignore_errors=True)
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("labels", nargs="*", help=f"cases (default: {', '.join(DEFAULT)})")
+    ap.add_argument("--all", action="store_true", help="every case")
+    ap.add_argument("--keep", help="keep the dumps under this directory (~70 MB a case)")
+    ap.add_argument("--child", help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.child:
+        return child(args.child)
+    labels = list(CASES) if args.all else args.labels or DEFAULT
+    if args.keep:
+        os.makedirs(args.keep, exist_ok=True)
+    columns = ("MXU", "XLU", "VALU", "EUP", "VLOAD", "VLOAD:FILL", "VSTORE", "VSTORE:SPILL")
+    print("| case | kernel | bundles | us at 1.5 GHz | "
+          + " | ".join(columns) + " | MXU floor |")
+    print("| --- " * (len(columns) + 5) + "|")
+    for label in labels:
+        got = measure(label, args.keep)
+        slots = got["slots"]
+        cells = [f"{slots[c][0]} ({slots[c][1]})" for c in columns]
+        floor = -(-slots["MXU"][0] // slots["MXU"][1])
+        print(f"| {label} | {CASES[label][0]} | {got['bundles']} | "
+              f"{got['bundles'] / 1500:.2f} | " + " | ".join(cells) + f" | {floor} |",
+              flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -174,6 +174,19 @@ KERNEL_CASES = [
     pytest.param(1280, 1280, 4, 2, 16, 16, 100, None,
                  "1280x1280x16/16 w100 g2 bq256 bk256 {} blocks9/25",
                  id="narrow heads, folded into the batch, grouped, banded"),
+    # the k step's own state (PR 51): row chunks of 256, lane-replicated max, a partial sum a lane
+    pytest.param(1536, 1536, 2, 2, 128, 128, 100, None,
+                 "1536x1536x128/128 w100 g1 bq512 bk512 {} inplace blocks5/9",
+                 id="a band of 100 under q blocks of 512: rows whose first k block is wholly masked"),
+    pytest.param(512, 1536, 2, 2, 192, 256, None, 0.1147,
+                 "512x1536x192/256 g1 bq512 bk768 {} inplace blocks2/2",
+                 id="a value width of 256 beside q and k of 192: the correction tiled over two lane tiles"),
+    pytest.param(128, 128, 2, 1, 128, 128, None, None,
+                 "128x128x128/128 g2 bq128 bk128 {} inplace blocks1/1",
+                 id="one step over a block of 128 keys"),
+    pytest.param(1296, 1296, 2, 2, 128, 128, None, None,
+                 "1296x1296x128/128 pad1296x1536 g1 bq432 bk768 {} inplace blocks5/6",
+                 id="1,296 rows as 3 x 432: a ragged last row chunk under the diagonal"),
 ]
 
 
@@ -224,34 +237,41 @@ def test_the_kernel_never_lets_a_later_key_reach_an_earlier_row():
         assert not np.allclose(np.asarray(before[:, 700:]), np.asarray(after[:, 700:]))
 
 
-# the kernel's own equations for a call without a mask, one after another,
-# as the parent of PR 43 traced them (python, at d34906f): max, exp, sum and
-# both products of one k block between the two `cond`s of the first and the
-# last k step
-UNMASKED_BODY = [
-    "program_id", "eq", "convert_element_type", "cond",
-    "get", "get", "get", "dot_general", "mul",
-    "get", "reduce_max", "broadcast_in_dim", "max", "sub", "exp", "sub", "exp",
-    "get", "mul", "convert_element_type", "dot_general", "add", "swap",
-    "get", "mul", "reduce_sum", "broadcast_in_dim", "add", "swap", "swap",
-    "eq", "convert_element_type", "cond",
-]
+def unmasked_body(chunks: int, tiles: int) -> list[str]:
+    """The kernel's own equations for a call without a mask, one after
+    another (the body of PR 51; until then the parent of PR 43's, traced
+    at d34906f): between the two `cond`s of the first and the last k step,
+    each of a q block's row chunks takes its scores, the max over their
+    `tiles` lane tiles and one row reduction, both `exp`, the partial sums
+    a lane, and the second product into the rescaled accumulator."""
+    chunk = [
+        "get", "get", "dot_general", "mul",
+        "get", *["slice"] * tiles, *["max"] * (tiles - 1), "reduce_max", "broadcast_in_dim", "max",
+        "sub", "exp", "tile", "sub", "exp",
+        "get", "mul", *["slice"] * tiles, *["add"] * tiles, "swap",
+        "get", "mul", "convert_element_type", "dot_general", "add", "swap", "swap",
+    ]
+    return [
+        "program_id", "eq", "convert_element_type", "cond", "get",
+        *chunk * chunks,
+        "eq", "convert_element_type", "cond",
+    ]
 
 
-@pytest.mark.parametrize("label, shape", [
-    ("sd15 self 64x64", (2, 4096, 8, 40)), ("flux joint 4608", (1, 4608, 24, 128))])
-def test_a_call_without_a_mask_traces_to_the_program_it_traced_to(label, shape):
+@pytest.mark.parametrize("label, shape, tiles", [
+    ("sd15 self 64x64", (2, 4096, 8, 40), 8), ("flux joint 4608", (1, 4608, 24, 128), 12)])
+def test_a_call_without_a_mask_traces_to_the_program_it_traced_to(label, shape, tiles):
     """SD1.5's and FLUX's kernels share `flash_attention` with the causal
     calls: theirs holds no `iota`, no comparison beyond the two `eq` of
     the first and last k step, no clamp in an index map, no operand
-    beyond q, k and v, and its body is the parent's, equation for
-    equation."""
+    beyond q, k and v, and its body is the unmasked one, equation for
+    equation: two row chunks of 256 a q block of 512."""
     operand = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     jaxpr = jax.make_jaxpr(att.flash_attention)(operand, operand, operand).jaxpr
     (call,) = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
     assert len(call.invars) == 3 and call.params["name"] == "flash_attention"
     body = call.params["jaxpr"]
-    assert [e.primitive.name for e in body.eqns] == UNMASKED_BODY
+    assert [e.primitive.name for e in body.eqns] == unmasked_body(512 // att.ROW_CHUNK, tiles)
     inside = {e.primitive.name for e in _eqns(body)}
     assert not inside & {"iota", "select_n", "lt", "le", "gt", "ge", "min", "and", "or", "not"}
     for mapping in call.params["grid_mapping"].block_mappings:

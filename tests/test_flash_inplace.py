@@ -96,6 +96,32 @@ def test_padded_widths_and_ragged_lengths_keep_their_heads(q_shape, m):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(folded(q, k, v)))
 
 
+# (label, [B, N, H, D] of q, keys M, k steps): calls whose k step walks its q
+# block in row chunks over several lane tiles of scores, the running max
+# lane-replicated and the running sum a partial a lane (PR 51); a call of
+# one step sets up, updates and divides its state in that one step
+STEP_CASES = [
+    ("two q blocks of two row chunks, two k steps of twelve lane tiles", (1, 1024, 3, 128), 3072, 2),
+    ("a 256-wide accumulator takes the correction tiled", (1, 512, 2, 256), 2560, 2),
+    ("37 k steps of one lane tile", (2, 128, 3, 128), 128 * 37, 37),
+    ("one k step over a block of 128 keys", (2, 384, 3, 128), 128, 1),
+    ("one k step, two row chunks", (1, 512, 2, 128), 1024, 1),
+]
+
+
+@pytest.mark.parametrize(
+    "q_shape,m,steps", [c[1:] for c in STEP_CASES], ids=[c[0] for c in STEP_CASES])
+def test_a_k_step_in_row_chunks_and_lane_tiles_keeps_every_head_its_own_sum(q_shape, m, steps):
+    b, n, h, d = q_shape
+    _, _, block_q, block_k = attn.flash_plan(n, m, d, 2)
+    assert m // block_k == steps
+    q, k, v = operands(b, n, m, h, d)
+    out = flash(q, k, v)
+    assert out.shape == q_shape and not bool(jnp.any(jnp.isnan(out.astype(jnp.float32))))
+    assert_close_to_float32(out, q, k, v)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(folded(q, k, v)))
+
+
 @pytest.mark.parametrize(
     "q_shape,m",
     [((2, 256, 3, 128), 256), ((2, 200, 3, 64), 200), ((1, 128, 1, 512), 256)],
@@ -123,8 +149,9 @@ def test_an_aligned_call_moves_nothing_around_the_kernel(h, d):
     operand = jax.ShapeDtypeStruct((2, 256, h, d), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(flash)(operand, operand, operand).jaxpr
     found = _primitives(jaxpr)
-    assert "pallas_call" in found
-    assert not found & {"transpose", "pad", "slice", "copy", "gather", "concatenate"}, found
+    assert "pallas_call" in found and not found & {"transpose", "pad", "copy", "gather"}, found
+    # the kernel's body cuts its scores into lane tiles (PR 51); around it nothing is cut
+    assert not _primitives(jaxpr, kernels=False) & {"slice", "concatenate"}
 
     eqns = list(_outside_the_kernel(jaxpr))
     assert [e.primitive.name for e in eqns if e.primitive.name != "reshape"] == ["pallas_call"]

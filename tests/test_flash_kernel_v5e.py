@@ -14,6 +14,7 @@ import pytest
 
 from comfyui_distributed_tpu.ops import attention as attn
 from comfyui_distributed_tpu.ops import qk_norm_rope
+from test_causal_attention import _eqns
 
 # Every served shape is compiled on the kernel, the ones the shape rule
 # leaves to XLA too: the rule rests on both routes' times, which
@@ -132,6 +133,45 @@ def test_causal_kernel_compiles_for_v5e_at_the_prefills_shapes(one_chip, shape, 
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "%flash_attention_causal" in text
+
+
+def _kernel_refs(fn, *operands):
+    """(blocks, scratch): the avals of the refs the one kernel of `fn` takes."""
+    (call,) = [
+        e for e in _eqns(jax.make_jaxpr(fn)(*operands).jaxpr) if e.primitive.name == "pallas_call"]
+    refs = [v.aval for v in call.params["jaxpr"].invars]
+    held = call.params["grid_mapping"].num_scratch_operands
+    return refs[:-held], refs[-held:]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_flash_vmem_bytes_bounds_the_blocks_and_scratch_of_every_served_call(dtype):
+    """What `flash_plan` fits to `VMEM_BUDGET` counts at least what the
+    call that the tests above compile asks of VMEM: q, k, v and output
+    blocks twice (the pipeline double-buffers them) and the carried
+    scratch, the accumulator beside a running max and sum that are a lane
+    tile wide each (PR 51), at every served and causal shape."""
+    size = lambda avals: sum(a.size * a.dtype.itemsize for a in avals)
+    place = lambda *dims: jax.ShapeDtypeStruct(dims, dtype)
+    cases = [
+        (label, attn.flash_attention, (b, n, h, d), (b, m, h, d), (b, m, h, d), False, None)
+        for label, (b, n, h, d), m in SHAPES
+    ] + [
+        (label, functools.partial(attn.flash_attention, causal=True, window=window),
+         (b, n, h, d), (b, n, kv_heads, d), (b, n, kv_heads, v_width), True, window)
+        for label, (b, n, h, d), kv_heads, v_width, window in chip_smoke.CAUSAL_SHAPES
+    ]
+    for label, fn, q, k, v, causal, window in cases:
+        blocks, scratch = _kernel_refs(fn, place(*q), place(*k), place(*v))
+        width = max(x + -x % 128 for x in (q[3], v[3]))
+        itemsize = jnp.dtype(dtype).itemsize
+        _, _, block_q, block_k = attn.flash_plan(
+            q[1], k[1], width, itemsize, causal=causal, window=window)
+        assert [a.shape for a in scratch] == [
+            (block_q, v[3] + -v[3] % 128), (block_q, 128), (block_q, 128)], label
+        assert all(a.dtype == jnp.float32 for a in scratch), label
+        counted = attn.flash_vmem_bytes(block_q, block_k, width, itemsize)
+        assert 2 * size(blocks) + size(scratch) <= counted <= attn.VMEM_BUDGET, (label, counted)
 
 
 def test_ouro_prefill_attends_in_the_causal_kernel(one_chip, monkeypatch):

@@ -38,15 +38,18 @@ def test_an_aligned_call_keeps_the_blocks_it_took(shape, blocks):
     assert attn.flash_plan(*shape) == (n, m, *blocks)
 
 
-def _primitives(jaxpr) -> set[str]:
-    """Names of every primitive in a jaxpr, kernels' bodies included."""
+def _primitives(jaxpr, kernels: bool = True) -> set[str]:
+    """Names of every primitive in a jaxpr, kernels' bodies included
+    unless `kernels` is false."""
     names = set()
     for eqn in jaxpr.eqns:
         names.add(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call" and not kernels:
+            continue
         for value in eqn.params.values():
             inner = getattr(value, "jaxpr", value)
             if hasattr(inner, "eqns"):
-                names |= _primitives(inner)
+                names |= _primitives(inner, kernels)
     return names
 
 
@@ -54,9 +57,12 @@ def test_an_aligned_call_traces_no_mask_no_pad_and_no_slice():
     aligned = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
     ragged = jax.ShapeDtypeStruct((1, 200, 2, 128), jnp.bfloat16)
     flash = functools.partial(attn.dot_product_attention, force_flash=True, interpret=True)
-    mask = {"iota", "select_n", "pad", "slice"}
-    plain = _primitives(jax.make_jaxpr(flash)(aligned, aligned, aligned).jaxpr)
+    mask = {"iota", "select_n", "pad"}
+    traced = jax.make_jaxpr(flash)(aligned, aligned, aligned).jaxpr
+    plain = _primitives(traced)
     assert "pallas_call" in plain and not plain & mask, plain & mask
+    # the kernel's body slices its scores into lane tiles (PR 51); around it nothing is sliced
+    assert "slice" not in _primitives(traced, kernels=False)
     # the same walk does find them where keys are padded (one k step: no cond)
     masked = _primitives(jax.make_jaxpr(flash)(ragged, ragged, ragged).jaxpr)
     assert {"pallas_call", "iota", "select_n", "pad", "slice"} <= masked
@@ -81,6 +87,13 @@ RAGGED_CASES = [
     ("aligned rows over ragged keys, d=40", (2, 256, 2, 40), 200, (256, 256, 256, 256)),
     ("two k steps, the tail masked, d=512", (1, 300, 1, 512), 1700, (304, 1792, 304, 896)),
     ("two q blocks, two k steps", (1, 600, 1, 128), 1600, (608, 1792, 304, 896)),
+    # the k step's own state (PR 51): q blocks of 432 rows go as row chunks of 256 and 176, a
+    # step's scores are several lane tiles, and the running sum is a partial a lane until the end
+    ("1,296 rows as 3 x 432 in two row chunks, five k steps of five lane tiles",
+     (1, 1296, 2, 64), 3200, (1296, 3200, 432, 640)),
+    ("1,296 rows, two k steps of twelve lane tiles, 72 padded keys in the last",
+     (1, 1296, 1, 64), 3000, (1296, 3072, 432, 1536)),
+    ("one k step over a block of 128 keys", (1, 384, 2, 128), 128, (384, 128, 384, 128)),
 ]
 
 
@@ -108,13 +121,16 @@ def test_flash_masks_a_ragged_tail(q_shape, m, plan, dtype):
     assert err <= limit, (err, scale)
 
 
-@pytest.mark.parametrize("m", [77, 200, 1700], ids=["one k step", "one k step, 56 padded", "two k steps"])
-def test_padded_keys_weigh_nothing_where_they_would_win_the_softmax(m):
+@pytest.mark.parametrize("rows,m", [(64, 77), (64, 200), (64, 1700), (432, 1296), (304, 3000)], ids=[
+    "one k step", "one k step, 56 padded", "two k steps",
+    "1,296 keys as 1,408: eleven lane tiles, two row chunks", "two k steps of twelve lane tiles"])
+def test_padded_keys_weigh_nothing_where_they_would_win_the_softmax(rows, m):
     """Every true score is far below 0, the score of a zero-padded key:
     unmasked, the padding would take the whole softmax and the output
-    would be its zero values, not the mean of v."""
+    would be its zero values, not the mean of v. A padded key's `p` is
+    an exact zero in its lane's partial sum too."""
     d = 128
-    q = jnp.full((1, 64, 1, d), 4.0, jnp.float32)
+    q = jnp.full((1, rows, 1, d), 4.0, jnp.float32)
     k = jnp.full((1, m, 1, d), -4.0, jnp.float32)
     v = jax.random.normal(jax.random.key(m), (1, m, 1, d)) + 3.0
     out = attn.flash_attention(q, k, v, interpret=True)
