@@ -42,7 +42,12 @@ Drives the main path once, through the entry points an operator uses:
                chunk) of each and the kernel at other heads a step; a
                Mamba-2 block's chunked scan at Nemotron-3-Nano's sizes
                (`SSD_SHAPE`) against the recurrence token by token, and
-               a decode step's update of its 23 states.
+               a decode step's update of its 23 states; learned sparse
+               attention at GLM-5.2's sizes (`DSA_SHAPE`): a part's
+               indexer scores, its selection by `lax.top_k` and by
+               bisection, attention over the chosen rows gathered and
+               under the selection's mask, and a step's two positions
+               in both forms.
     experts    one child that holds the chip runs a decode step's two
                grouped products (gate-up, SiLU, down) over the held
                experts' stacked weights at the four models' decode
@@ -1029,6 +1034,7 @@ def attention_child(rehearsal: bool) -> int:
     for shape in REHEARSAL_KDA_DELTA_SHAPES if rehearsal else KDA_DELTA_SHAPES:
         failed += not kda_delta_row(rehearsal, *shape)
     failed += not ssd_row(rehearsal, *(REHEARSAL_SSD_SHAPE if rehearsal else SSD_SHAPE))
+    failed += not dsa_row(rehearsal, *(REHEARSAL_DSA_SHAPE if rehearsal else DSA_SHAPE))
     return 1 if failed else 0
 
 
@@ -1403,6 +1409,97 @@ def ssd_row(rehearsal: bool, label, tokens, heads, width, groups, n, chunk, bloc
     row["step"] = {"blocks": blocks, "first_call_s": round(first_s, 2),
                    "us_a_block": round(1e3 * ms / blocks, 2),
                    "gb_per_s": round(moved / (1e-3 * ms) / 1e9, 1)}
+    print(json.dumps(row), flush=True)
+    return row["ok"]
+
+
+# Learned sparse attention as GLM-5.2's cell runs it: the last part of the
+# 32,768-token prompt over caches of the request's full length, and a
+# drafting step's two positions: (label, a part's queries, cache rows,
+# positions chosen, heads, nope, rope, value width, latent rank, indexer
+# heads, indexer width).
+DSA_SHAPE = ("glm-5.2 dsa", 8192, 32896, 2048, 64, 192, 64, 256, 512, 32, 128)
+REHEARSAL_DSA_SHAPE = ("toy dsa", 40, 72, 8, 4, 12, 8, 16, 24, 2, 16)
+DSA_TOLERANCE = 2e-2  # one form's bfloat16 outputs against the other's, of the largest entry
+
+
+def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, rank,
+            index_heads, index_width) -> bool:
+    """`models/dsa.py` at one part's shapes, bfloat16 as stored: ms of
+    the indexer's scores, of the selection in either form (`lax.top_k`;
+    the bisection) and of attention in either form (the chosen rows
+    gathered; `mla.absorbed` under the selection's mask), all a block of
+    `dsa.BLOCK_ROWS` query rows at a time; that both selections are one
+    set and both attentions one result; then a step's two positions,
+    scores and selection and attention together, in either form."""
+    import jax
+    import jax.numpy as jnp
+
+    from comfyui_distributed_tpu.models import dsa, mla
+
+    dtype = jnp.float32 if rehearsal else jnp.bfloat16
+
+    @jax.jit
+    def operands(key):
+        keys = jax.random.split(key, 8)
+        normal = lambda k, *shape: jax.random.normal(k, shape).astype(dtype)  # noqa: E731
+        return (normal(keys[0], queries, index_heads, index_width),
+                jax.random.normal(keys[1], (queries, index_heads)),
+                normal(keys[2], rows, index_width), normal(keys[3], queries, heads, nope),
+                normal(keys[4], queries, heads, rope), normal(keys[5], rows, rank + rope),
+                (jax.random.normal(keys[6], (rank, heads, nope)) * rank ** -0.5).astype(dtype),
+                (jax.random.normal(keys[7], (rank, heads, v)) * rank ** -0.5).astype(dtype))
+
+    q_index, weights, cached, q_nope, q_rope, cache, w_uk, w_uv = operands(jax.random.key(rows))
+    positions = jnp.arange(rows - queries, rows)
+    scale = (nope + rope) ** -0.5
+    def by_blocks(fn):
+        return jax.jit(lambda *arrays: dsa.by_rows(fn, dsa.BLOCK_ROWS, *arrays))
+
+    scores = by_blocks(lambda q, w, at: dsa.scores(q, w, cached, at))
+    index, first_s, ms = timed(scores, q_index, weights, positions)
+    row = {"shape": label, "queries": queries, "rows": rows, "top": top, "heads": heads,
+           "dtype": jnp.dtype(dtype).name, "block_rows": dsa.BLOCK_ROWS,
+           "scores": {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}, "ok": True}
+    selections = {}
+    for name, choose in (("top_k", dsa.top), ("bisection", dsa.above_threshold)):
+        selections[name], first_s, ms = timed(by_blocks(lambda i: choose(i, top)), index)
+        row[f"select_{name}"] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
+    same = jnp.array_equal(*(dsa.as_mask(s, rows) for s in selections.values()))
+    row["selections_equal"] = bool(same)
+    row["ok"] &= row["selections_equal"]
+
+    gathered = selections["top_k"]
+    forms = {
+        "gathered": jax.jit(lambda a, b, c, d: dsa.attend(
+            a, b, cache, dsa.Selection(c, d), w_uk, w_uv, scale)),
+        "masked": by_blocks(lambda a, b, c, d: mla.absorbed(
+            a, b, cache, dsa.as_mask(dsa.Selection(c, d), rows), w_uk, w_uv, scale)),
+    }
+    outs = {}
+    for name, attend in forms.items():
+        outs[name], first_s, ms = timed(attend, q_nope, q_rope, *gathered)
+        row[f"attend_{name}"] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
+    diff = jnp.max(jnp.abs(outs["gathered"].astype(jnp.float32) - outs["masked"].astype(jnp.float32)))
+    row["max_rel_diff"] = round(float(diff / jnp.max(jnp.abs(outs["masked"].astype(jnp.float32)))), 6)
+    row["ok"] &= row["max_rel_diff"] <= DSA_TOLERANCE  # a NaN fails it too
+
+    # a drafting step's two positions: what `glm_dsa.attention` runs of this module, and
+    # the other form at the same two
+    def step(choose):
+        def run(q_index, weights, q_nope, q_rope, positions):
+            selection = choose(dsa.scores(q_index, weights, cached, positions), top)
+            return dsa.attend(q_nope, q_rope, cache, selection, w_uk, w_uv, scale)
+        return jax.jit(run)
+
+    last = (q_index[-2:], weights[-2:], q_nope[-2:], q_rope[-2:], positions[-2:])
+    assert dsa.form(2) == "masked"
+    steps = {}
+    for form, choose in (("masked", dsa.above_threshold), ("gathered", dsa.top)):
+        steps[form], first_s, ms = timed(step(choose), *last)
+        row[f"step_{form}"] = {"first_call_s": round(first_s, 2), "us": round(1e3 * ms, 1)}
+    diff = jnp.max(jnp.abs(steps["masked"].astype(jnp.float32) - steps["gathered"].astype(jnp.float32)))
+    row["ok"] &= float(diff) <= DSA_TOLERANCE * float(jnp.max(jnp.abs(steps["masked"].astype(jnp.float32))))
     print(json.dumps(row), flush=True)
     return row["ok"]
 

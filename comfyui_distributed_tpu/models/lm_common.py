@@ -1,5 +1,5 @@
 """What the language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`): the blocks and helpers
+`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`): the blocks and helpers
 they are written from, the one initialisation rule, sampling on the
 device, the rule by which a drafted token is kept or replaced, the
 decode loop, and the stand-in tokenizer.
@@ -100,6 +100,18 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     ).astype(x.dtype)
+
+
+def apply_rope_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate [..., T, (heads,) rope] by its position, channels 2i and
+    2i + 1 as the pair's members (the interleaved form, as a checkpoint
+    stores it)."""
+    x1, x2 = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
+    if x.ndim == cos.ndim + 1:  # a heads axis between T and rope
+        cos, sin = cos[:, None, :], sin[:, None, :]
+    return jnp.stack(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).reshape(x.shape).astype(x.dtype)
 
 
 def rope_tables(theta: float, head_dim: int, positions: jax.Array):

@@ -1,5 +1,5 @@
 """Multi-head latent attention's two forms, as the models with such
-layers share them (`deepseek_v2.py`, `ling_flash.py`), over a head's
+layers share them (`deepseek_v2.py`, `ling_flash.py`, `glm_dsa.py`), over a head's
 queries in two parts (`q_nope` [T, heads, nope], and `q_rope` [T, heads,
 rope], rotated), the latents a cache holds ([S, rank + rope]: the normed
 latent c and the one rotated rope key r of all heads, side by side) and
@@ -11,8 +11,10 @@ the two halves of the up-projection, `w_uk` [rank, heads, nope] and
 `latents` makes what the cache holds; `expanded` builds every key and value from the latents (a whole
 sequence, causal: a prefill); `absorbed` folds W_uk into the query,
 attends over the latents themselves and applies W_uv after the weighted
-sum (a decode step's new positions over a cache). Both return the
-heads' outputs [T, heads, v]. How the queries are made of the input
+sum (a decode step's new positions over a cache); `models/dsa.py` has
+the absorbed form over the rows of the cache a learned index chose. All
+return the heads' outputs [T, heads, v]; the value width v need not be
+the nope width. How the queries are made of the input
 (a query latent or none, which rotation), the softmax's scale, and what
 follows the heads' outputs (a gate, the projection) are each model's own.
 """
@@ -26,14 +28,16 @@ from ..ops.attention import causal_attention
 from .lm_common import apply_rope, rms_norm
 
 
-def latents(p, x, rope, eps: float):
+def latents(p, x, rope, eps: float, rotate=apply_rope):
     """What the cache holds of x [T, hidden]: the normed latent (`w_dkv`'s
     first columns under `kv_norm`) and the rope key, rotated by `rope`
-    (cos, sin of the positions), side by side, [T, rank + rope]."""
+    (cos, sin of the positions; `rotate` says which channels pair up:
+    `lm_common.apply_rope`'s halves, or `apply_rope_pairs`), side by
+    side, [T, rank + rope]."""
     rank = p["kv_norm"].shape[0]
     down = x @ p["w_dkv"]
     c_kv = rms_norm(down[:, :rank], p["kv_norm"], eps)
-    return jnp.concatenate([c_kv, apply_rope(down[:, rank:], *rope)], axis=-1)
+    return jnp.concatenate([c_kv, rotate(down[:, rank:], *rope)], axis=-1)
 
 
 def expanded(q_nope, q_rope, latents, w_uk, w_uv, scale: float):

@@ -20,6 +20,7 @@ from .dit import DiTConfig, VideoDiT
 from .mmdit import MMDiT, MMDiTConfig
 from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
+from .glm_dsa import GlmDsa, GlmDsaConfig
 from .k_exaone import KExaone, KExaoneConfig
 from .ling_flash import LingFlash, LingFlashConfig
 from .nemotron_h import NemotronH, NemotronHConfig
@@ -601,6 +602,34 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             num_experts_per_tok=3, vocab_size=4096, ep_size=8, ep_rank=0, vocab_shards=8,
         ),
     },
+    # GLM-5.2 as one chip's share of a sixteen-way expert-parallel deployment,
+    # every width as published: published layers 2-6 (the dense layer 2, which
+    # computes an index, layers 3-5 that attend by it, layer 6 with an index
+    # of its own: one whole period of the indexer's pattern), the MTP module,
+    # experts 0-15 of 256 (rank 0 of 16), the first eighth of the vocabulary
+    # (the benchmark's glm-5.2 configuration says what the cut stands for)
+    "glm-5.2-ep16-5l": {
+        "family": "lm",
+        "config": GlmDsaConfig(
+            num_hidden_layers=5, first_layer=2, ep_size=16, ep_rank=0, vocab_shards=8,
+        ),
+    },
+    # every mechanism at a size for the CPU: the same five layers, 4 heads
+    # (12 + 8 wide, values 16), a query latent of 32 and a latent of 24, an
+    # indexer of 2 heads of 16 that keeps 8 positions (so the selection binds
+    # from the ninth position on), parts of 16 positions, 32 experts (4 a
+    # token) of which rank 0 of 16 holds two, the MTP module
+    "tiny-glm-dsa": {
+        "family": "lm",
+        "config": GlmDsaConfig(
+            hidden_size=64, num_hidden_layers=5, first_layer=2, num_attention_heads=4,
+            q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=12, qk_rope_head_dim=8,
+            v_head_dim=16, index_n_heads=2, index_head_dim=16, index_topk=8,
+            intermediate_size=160, moe_intermediate_size=32, n_routed_experts=32,
+            num_experts_per_tok=4, vocab_size=4096, ep_size=16, ep_rank=0, vocab_shards=8,
+            prefill_part=16,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -659,6 +688,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     KExaoneConfig: KExaone,
     LingFlashConfig: LingFlash,
     NemotronHConfig: NemotronH,
+    GlmDsaConfig: GlmDsa,
 }
 
 

@@ -246,6 +246,36 @@ def test_the_mamba_row_holds_the_chunked_scan_to_the_recurrence(smoke, capsys):
     assert row["step"]["blocks"] == 3
 
 
+def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys):
+    """The row's shape is the cell's: the last part of the committed
+    workflow's prompt over caches of the request's length, the
+    registry's widths; and the rehearsal's toy row on the CPU: both
+    selections one set, both attentions one result, a step in either
+    form."""
+    import jax
+
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("glm-5.2-ep16-5l")
+    with open(os.path.join(REPO_ROOT, "workflows", "longdoc-txt2img-glm-5.2.json")) as fh:
+        (node,) = [node["inputs"] for node in json.load(fh).values()
+                   if node["class_type"] == "TextGenerate"]
+    tokens = 1 + len(node["text"].encode("utf-8"))
+    assert smoke.DSA_SHAPE[1:] == (
+        cfg.prefill_part, tokens + node["max_new_tokens"], cfg.index_topk,
+        cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+        cfg.kv_lora_rank, cfg.index_n_heads, cfg.index_head_dim)
+    assert tokens % cfg.prefill_part == 0
+    if jax.default_backend() != "cpu":
+        pytest.skip("the rehearsal's row is the CPU's")
+    assert smoke.dsa_row(True, *smoke.REHEARSAL_DSA_SHAPE)
+    (row,) = _result_lines(capsys.readouterr().out)
+    assert row["ok"] and row["selections_equal"] and row["max_rel_diff"] < smoke.DSA_TOLERANCE
+    for name in ("scores", "select_top_k", "select_bisection", "attend_gathered", "attend_masked"):
+        assert set(row[name]) == {"first_call_s", "ms"}, name
+    assert set(row["step_masked"]) == set(row["step_gathered"]) == {"first_call_s", "us"}
+
+
 def test_the_kda_row_agrees_with_itself_in_both_forms(smoke, capsys):
     """Two slots and a flipped bit against one slot and a select, at the
     rehearsal's toy size on the CPU: the same states, the same outputs."""

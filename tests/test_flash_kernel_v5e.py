@@ -579,3 +579,58 @@ def test_deepseek_decode_multiplies_its_six_pairs_in_the_kernel(
     ).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == calls
     assert len(re.findall(r"= bf16\[40,6,\d+\]\S* convolution\(", text)) == masked
+
+
+def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_caches_in_place(
+        one_chip, monkeypatch):
+    """GLM-5.2's two programs at the cell's sizes (32,768 ids in four parts
+    of 8,192 over caches of 32,896 positions), routed as a TPU routes
+    them. The prefill is one `while` over the parts: what it holds beside
+    its arguments is a part's working set (the expert ladder's top rung,
+    the indexer's scores and the gathered rows a block of query rows),
+    not the prompt's; each attention layer logs the gathered form. The
+    drafting decode carries the donated tree of six latent caches and
+    three indexer caches (252.6 MB) through its loop, a step's queries
+    take the masked form, and a step's grouped products run in the
+    `expert_matvec` kernel (two a sparse layer and the MTP module's)."""
+    from comfyui_distributed_tpu.models import glm_dsa
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("glm-5.2-ep16-5l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: glm_dsa.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        prefill = glm_dsa.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((32768,), jnp.int32, sharding=one_chip),
+            cache_len=32896,
+        ).compile()
+    assert routes == ["dsa-gathered 8192x32896 k2048 h64 bf16"] * cfg.num_hidden_layers
+    memory = prefill.memory_analysis()
+    assert memory.temp_size_in_bytes < 4.5e9      # 4.13 GB: a part's, whatever the parts' number
+    assert memory.output_size_in_bytes >= 32896 * 7680
+    text = prefill.as_text()
+    # the parts' scan; `lax.top_k` at 2,048 of 32,896 is a sort
+    assert " while(" in text and [line for line in text.splitlines()
+                                  if " sort(" in line and "32896" in line]
+
+    state = jax.tree.map(place, glm_dsa.state_shapes(cfg, 32896, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        decode = glm_dsa.decode.lower(
+            cfg, params, state,
+            jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+            scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+            scalar(jnp.float32), steps=120, draft_tokens=1,  # a count of this test's own
+        ).compile()
+    assert routes == ["dsa-masked 2x32896 k32896 h64 bf16"] * (cfg.num_hidden_layers + 1)
+    memory = decode.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert memory.alias_size_in_bytes >= 32896 * 7680
+    text = decode.as_text()
+    # neither a sort nor a gather over a cache's positions in a step (the router's own
+    # top 8 of 256 is a sort; the embedding's rows are a gather)
+    assert not [line for line in text.splitlines()
+                if (" sort(" in line or " gather(" in line) and "32896" in line]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * (cfg.sparse_layers + 1)

@@ -14,6 +14,7 @@ loop over each model's own step.
 A `model_config` PR adds a row here, and edits no other row."""
 
 import dataclasses
+import importlib.util
 import json
 import os
 from typing import Callable
@@ -376,6 +377,95 @@ def nemotron_entry(cfg, config):
     assert type(cfg)() == dataclasses.replace(cfg, ep_size=1, vocab_shards=1)  # nothing else cut
 
 
+def glm_drawn(attrs):
+    # four sparse main layers and the MTP module's, 2 of 32 experts held (a sixteenth)
+    for phase, rows in (("prefill", 4), ("decode", 5)):
+        pairs, on_held = attrs[f"{phase}_routed_pairs"], attrs[f"{phase}_routed_pairs_held"]
+        assert 0 <= on_held < pairs
+        assert on_held / (rows * 2) <= attrs[f"{phase}_expert_load_max"] <= on_held
+    assert 0.02 < attrs["prefill_routed_pairs_held"] / attrs["prefill_routed_pairs"] < 0.12
+    # a part's 64 pairs a layer are under a tile: a ladder of one rung, a part and layer
+    assert attrs["prefill_expert_rows"] == attrs["prefill_routed_pairs"]
+    steps, accepted = attrs["decode_steps"], attrs["mtp_accepted"]
+    assert attrs["mtp_drafted"] == steps and 0 <= accepted <= steps
+    assert 1 + steps + accepted in (attrs["new_tokens"], attrs["new_tokens"] + 1)
+    # two positions a step through five layers and the MTP module's, kept or not
+    assert attrs["decode_layer_passes"] == steps * 2 * (5 + 1)
+    assert attrs["decode_routed_pairs"] == steps * 2 * (4 + 1) * 4 == attrs["decode_expert_rows"]
+    assert 0 <= attrs["decode_experts_read"] <= min(
+        attrs["decode_routed_pairs_held"], steps * (4 + 1) * 2)
+    # the prompt's 2,048 positions in five layers, then what the steps' positions saw:
+    # 8 chosen of everything before, a step's 2 x (5 + 1) queries past the prompt
+    prompt = 5 * 2048 * 2049 // 2
+    assert prompt + steps * 12 * 2048 < attrs["keys_visible"] < prompt + steps * 12 * 2064
+    assert attrs["keys_selected"] == 5 * (8 * 9 // 2 + 2040 * 8) + steps * 12 * 8
+
+
+def glm_workflow(mine, _):
+    theirs = load("workflows/rewrite-txt2img-k-exaone.json")
+    assert differing(mine, theirs) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "text"),
+        ("TextGenerate", "max_new_tokens"), ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["draft_tokens"], generate["temperature"]) == (
+        128, 1, 1.0)
+    # the cells' 8,191-byte style guide byte for byte, then a 24,576-byte manuscript that
+    # ends in the line asking for one scene's prompt
+    guide = by_kind(theirs)["TextGenerate"]["text"]
+    text = generate["text"]
+    assert text.startswith(guide) and text.isascii()
+    assert (len(guide), len(text) - len(guide)) == (8191, 24576)
+    assert text[len(guide):].startswith("\n\nManuscript, chapter nine")
+    assert text.count("\nScene ") >= 40 and text.endswith("of the other scenes.\nPrompt:")
+    # what `scripts/gen_longdoc_workflow.py` writes, to the byte
+    spec = importlib.util.spec_from_file_location(
+        "gen_longdoc_workflow", os.path.join(ROOT, "scripts", "gen_longdoc_workflow.py"))
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    assert text == guide + script.manuscript()
+    # the rehearsal reads the document's first 2,047 bytes
+    edits = load("benchmark/workloads/glm_5_2_longdoc_txt2img_512.closed2.json")["rehearsal"]["set"]
+    (short,) = [e["value"] for e in edits if (e["class_type"], e["input"]) == ("TextGenerate", "text")]
+    assert short == text[:2047]
+
+
+def glm_published(config):
+    assert config["rope_parameters"] == {"rope_theta": 8000000, "rope_type": "default"}
+    assert config["indexer_types"] == ["full"] * 3 + ["shared", "shared", "shared", "full"] * 18 + [
+        "shared"] * 3
+    assert config["mlp_layer_types"] == ["dense"] * 3 + ["sparse"] * 75
+    assert config["model_type"] == "glm_moe_dsa" and config["index_topk_pattern"] is None
+    assert config["ep_size"] == 1 and "expert-parallel width is 16" in config["held"]["ep_size"]
+    assert config["as_run"]["parameters"] == {"lm": 4774740992}
+    assert (config["as_run"]["cache_bytes_per_token"], config["as_run"]["state_bytes"]) == (7680, 0)
+    assert (config["first_layer"], config["as_run"]["prefill_part"]) == (2, 8192)
+    assert set(config["held"]) == {"layers", "experts", "vocabulary", "state", "ep_size"}
+    assert "published layers 2-6 of 78" in config["held"]["layers"]
+    assert "shared by 16 chips (two v5e-8 hosts)" in config["deployment"]
+    assert "The sixteen-way cut was built" in config["deployment"]
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max_unflipped"] < 0.2
+    assert 0 < limits["tolerance_draft_rel_l2_median"] < 0.2
+    assert 0 < limits["tolerance_expert_set_mismatch"] < 0.5
+    assert 0 < limits["tolerance_selection_mismatch"] < 0.5
+
+
+def glm_entry(cfg, config):
+    assert (cfg.first_layer, list(cfg.layers)) == (config["first_layer"], [2, 3, 4, 5, 6])
+    assert cfg.rope_theta == config["rope_parameters"]["rope_theta"]
+    assert (cfg.num_hidden_layers, len(cfg.held_experts), cfg.vocab_held) == (
+        config["num_hidden_layers"], config["n_routed_experts"], config["vocab_size"])
+    assert (cfg.n_routed_experts, cfg.vocab_size, cfg.ep_size, cfg.vocab_shards) == (
+        256, 154880, 16, 8)
+    assert cfg.prefill_part == config["as_run"]["prefill_part"]
+    whole = type(cfg)()
+    assert ["full" if whole.is_full(i) else "shared" for i in range(78)] == config["indexer_types"]
+    assert ["dense" if whole.is_dense(i) else "sparse" for i in range(78)] == (
+        config["mlp_layer_types"])
+    assert type(cfg)() == dataclasses.replace(
+        cfg, num_hidden_layers=78, first_layer=0, ep_size=1, vocab_shards=1)  # nothing else cut
+
+
 @dataclasses.dataclass(frozen=True)
 class Model:
     """One language model's row. `attrs`: `node.TextGenerate`'s attributes
@@ -408,6 +498,7 @@ class Model:
     check_workflow: Callable
     metrics: frozenset = frozenset()   # what its cell lists beyond `LM_METRICS`
     imports: tuple = tuple(PLAIN_IMPORTS)
+    cell_prompt: int = 0        # tokens of the committed workflow, where the rehearsal cuts it
 
     def __str__(self):
         return self.name
@@ -677,6 +768,64 @@ MODELS = [
         metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "ssm_device_pct.lm",
                            "expert_matvec_hbm_pct.lm"}),
     ),
+    Model(
+        name="glm-5.2", served="glm-5.2-ep16-5l", tiny="tiny-glm-dsa",
+        workflow="longdoc-txt2img-glm-5.2.json", config="glm-5.2.json",
+        reference="glm_dsa.py", catalog="GLM-5.2",
+        cell="glm_5_2_longdoc_txt2img_512.closed2", prompt=2048, cell_prompt=32768,
+        new_tokens=16, drafts=1,
+        # tiny-glm-dsa: published layers 2-6, a dense layer with an indexer, three sparse
+        # ones that attend by its selection, a sparse one with its own; 4 heads (12 + 8
+        # wide, values 16), latents of 24 + 8, indexer keys of 16, 8 positions chosen, parts
+        # of 16 positions, 2 of 32 experts held, 4 a token, the MTP module. What grows: a
+        # latent a layer and the module's, an indexer key a full layer and the module's
+        attrs={
+            "prompt_tokens": 2048, "new_tokens": 16, "draft_tokens": 1,
+            "layers": 5, "index_topk": 8, "indexer_layers": 2, "index_shared_layers": 3,
+            "prefill_part": 16, "prefill_parts": 128, "experts_held": 2, "experts_total": 32,
+            "cache_bytes": (2048 + 16) * (6 * 32 + 3 * 16) * 4,
+            "indexer_cache_bytes": (2048 + 16) * 3 * 16 * 4, "state_bytes": 0,
+            "prefill_sparse_attention_form": "gathered",
+            "decode_sparse_attention_form": "masked",
+            "prefill_layer_passes": 2048 * 5, "prefill_routed_pairs": 2048 * 4 * 4,
+            "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
+            "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
+            "decode_experts_read", "keys_visible", "keys_selected"},
+        drawn_check=glm_drawn,
+        # the ids; a part's pairs per held expert and keys seen a layer (128 parts); the
+        # decode's of both (the MTP module's row and column) and the four counts
+        wait_bytes=4 * (16 + 128 * 4 * 2 + 128 * 2 * 5 + (4 + 1) * 2 + 2 * (5 + 1) + 4),
+        # a part's 16 queries over the rows a top-k chose, gathered; a step's two under
+        # the mask the bisection gave
+        attention="dsa-gathered 16x2064 k8 h4 f32, dsa-masked 2x2064 k2064 h4 f32",
+        passes=lambda attrs: (2048 * 5, attrs["decode_steps"] * 2 * (5 + 1)),
+        widths={
+            "hidden_size": 6144, "num_attention_heads": 64, "num_key_value_heads": 64,
+            "head_dim": 192, "q_lora_rank": 2048, "kv_lora_rank": 512, "qk_nope_head_dim": 192,
+            "qk_rope_head_dim": 64, "qk_head_dim": 256, "v_head_dim": 256,
+            "index_n_heads": 32, "index_head_dim": 128, "index_topk": 2048,
+            "index_topk_freq": 4, "index_skip_topk_offset": 3,
+            "index_share_for_mtp_iteration": True, "indexer_rope_interleave": True,
+            "rope_interleave": True, "intermediate_size": 12288, "moe_intermediate_size": 2048,
+            "num_experts_per_tok": 8, "n_shared_experts": 1, "n_group": 1, "topk_group": 1,
+            "norm_topk_prob": True, "routed_scaling_factor": 2.5, "scoring_func": "sigmoid",
+            "topk_method": "noaux_tc", "first_k_dense_replace": 3, "moe_layer_freq": 1,
+            "num_nextn_predict_layers": 1, "rms_norm_eps": 1e-5, "attention_bias": False,
+            "max_position_embeddings": 1048576, "tie_word_embeddings": False},
+        reduced={"num_hidden_layers": (78, 5), "n_routed_experts": (256, 16),
+                 "vocab_size": (154880, 19360)},
+        assumed=("pre-norm", "rotated in pairs", "published DSA's", "the first 64",
+                 "no Hadamard rotation", "ties in the selection", "holds no indexer weights",
+                 "module's layer is full", "selection bias", "DeepSeek-V3's",
+                 "before the final norm", "seeded random", "stand-in", "batch is 1",
+                 "share of drafts kept", "style guide"),
+        published=glm_published, entry=glm_entry, check_workflow=glm_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "mtp_accept_pct.lm",
+                           "mtp_device_pct.lm", "mla_device_pct.lm", "indexer_device_pct.lm",
+                           "keys_selected_pct.lm"}),
+        imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
+    ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
 LM_ENTRIES = sorted(name for name, entry in MODEL_REGISTRY.items() if entry["family"] == "lm")
@@ -852,7 +1001,9 @@ def test_the_workflow_is_the_first_one_but_for_what_its_row_names(model):
     config = load(os.path.join("benchmark/configs", model.config))
     assert inputs["CheckpointLoaderSimple"]["ckpt_name"] == config["registry_name"] == model.served
     # a token a byte, and begin-of-sentence: the rehearsal keeps the cell's prompt
-    assert len(ByteTokenizer().encode(inputs["TextGenerate"]["text"])) == model.prompt
+    # (or, the long document's, its first bytes)
+    assert len(ByteTokenizer().encode(inputs["TextGenerate"]["text"])) == (
+        model.cell_prompt or model.prompt)
 
 
 @pytest.mark.parametrize("kind", ["workflows", "reference"])
@@ -969,7 +1120,11 @@ def test_every_language_model_meets_the_one_contract(name):
         lm.decode(None, None, None, 0, None, 4, 1.0, draft_tokens=model.drafts + 1)
 
     held = len(getattr(lm.cfg, "held_experts", ()))
-    read = {"ouro": ([1.0] * 4, [0.5] * 4)}.get(model.name, ([[3] * held], [[1] * held]))
+    read = {
+        "ouro": ([1.0] * 4, [0.5] * 4),
+        # a part's loads and keys seen (visible, read) a layer, then the decode's
+        "glm-5.2": ([[[3] * held]], [[[9], [5]]], [[1] * held], [[7], [2]]),
+    }.get(model.name, ([[3] * held], [[1] * held]))
     if model.drafts:
         read += ([4, 0, 0, 2],)
 
@@ -1000,7 +1155,7 @@ def test_every_language_model_meets_the_one_contract(name):
 @pytest.mark.parametrize("name, passes", [
     ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
     ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7),
-    ("nemotron3-nano-ep16-52l", 52)])
+    ("nemotron3-nano-ep16-52l", 52), ("glm-5.2-ep16-5l", 5)])
 def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
     from comfyui_distributed_tpu.models.registry import create_model
 
@@ -1014,7 +1169,12 @@ def _step_of(name):
     """(module, `step(cfg, params, cache, token, position) -> (logits,
     cache, what the decode sums over its steps)`) of a tiny model."""
     from comfyui_distributed_tpu.models import (
-        deepseek_v2, k_exaone, ling_flash, nemotron_h, ouro, solar_open2)
+        deepseek_v2, glm_dsa, k_exaone, ling_flash, nemotron_h, ouro, solar_open2)
+
+    def glm_step(cfg, params, cache, token, position):
+        rows, _, cache, _, loads, _, _ = glm_dsa.main_step(
+            cfg, params, dict(cache), token[None], position)
+        return rows[0], cache, loads
 
     def one_position(module):
         def step(cfg, params, cache, token, position):
@@ -1039,6 +1199,7 @@ def _step_of(name):
         "k-exaone": (k_exaone, one_position(k_exaone)),
         "ling-flash": (ling_flash, one_position(ling_flash)),
         "nemotron3-nano": (nemotron_h, with_loads(nemotron_h)),
+        "glm-5.2": (glm_dsa, glm_step),
     }[name]
 
 
@@ -1123,7 +1284,8 @@ def test_the_loop_sums_what_a_step_adds_and_keeps_what_it_hands_over(collect):
 # --- what only one model has -----------------------------------------------------
 
 
-@pytest.mark.parametrize("name, layers, sparse", [("k-exaone", 5, 4), ("ling-flash", 7, 6)])
+@pytest.mark.parametrize("name, layers, sparse", [
+    ("k-exaone", 5, 4), ("ling-flash", 7, 6), ("glm-5.2", 5, 4)])
 def test_without_drafting_a_drafting_models_node_reports_a_step_a_token(
         name, layers, sparse, tmp_path, monkeypatch):
     monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
@@ -1240,6 +1402,67 @@ def test_nemotrons_served_share_holds_48_mb_of_state_and_6_kb_a_position():
     assert attrs["prefill_expert_rows"] == 23 * 3072
     assert lm.counted(attrs, 8192, 512) == {
         "decode_steps": 512, "prefill_layer_passes": 8192 * 52, "decode_layer_passes": 512 * 52}
+
+
+def test_glms_served_share_holds_two_caches_of_7680_bytes_a_position():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    lm = create_model("glm-5.2-ep16-5l")
+    lm.dtype = jnp.dtype(jnp.bfloat16)
+    # four parts of 8,192: a sixteenth of a part's 65,536 pairs on the 16 held experts
+    loads = np.full((4, 4, 16), 256)
+    keys = np.zeros((4, 2, 5), np.int64)
+    for part in range(4):
+        first, last = part * 8192, (part + 1) * 8192
+        keys[part, 0] = (last * (last + 1) - first * (first + 1)) // 2
+        keys[part, 1] = 8192 * 2048 - (2048 * 2047 // 2 if part == 0 else 0)
+    attrs = lm.report(
+        32768, 128, 32896, loads, keys, [[6] * 16] * 5, np.zeros((2, 6), np.int64), [87, 87, 40, 700])
+    config = load("benchmark/configs/glm-5.2.json")
+    # a latent of 512 + 64 in six layers (the module's among them), an indexer key of
+    # 128 in three: 6 x 1,152 + 3 x 256 B a position
+    assert attrs["cache_bytes"] == 32896 * 7680 == 32896 * config["as_run"]["cache_bytes_per_token"]
+    assert attrs["indexer_cache_bytes"] == 32896 * 3 * 256 and attrs["state_bytes"] == 0
+    assert (lm.layer_passes, attrs["layers"], attrs["indexer_layers"],
+            attrs["index_shared_layers"], attrs["index_topk"]) == (5, 5, 2, 3, 2048)
+    assert (attrs["prefill_parts"], attrs["prefill_part"]) == (4, 8192)
+    assert (attrs["experts_held"], attrs["experts_total"]) == (16, 256)
+    # a part's 4,096 held pairs a layer take the ladder's lowest rung, 4,096 rows
+    assert attrs["prefill_routed_pairs"] == 32768 * 8 * 4
+    assert attrs["prefill_routed_pairs_held"] == attrs["prefill_expert_rows"] == 4 * 4 * 4096
+    # 12.1 % of what a causal mask allows over the prompt
+    assert attrs["keys_visible"] == 5 * 32768 * 32769 // 2
+    assert attrs["keys_selected"] == 5 * (2048 * 2049 // 2 + 30720 * 2048)
+    assert 0.121 < attrs["keys_selected"] / attrs["keys_visible"] < 0.1212
+    assert (attrs["prefill_sparse_attention_form"], attrs["decode_sparse_attention_form"]) == (
+        "gathered", "masked")
+    assert (attrs["decode_steps"], attrs["mtp_drafted"], attrs["mtp_accepted"]) == (87, 87, 40)
+    assert attrs["decode_layer_passes"] == 87 * 2 * 6
+    assert lm.counted(attrs, 32768, 128) == {
+        "decode_steps": 87, "prefill_layer_passes": 32768 * 5, "decode_layer_passes": 87 * 12}
+
+
+def test_the_seventh_model_is_written_from_the_modules_the_others_are():
+    """Shared-code identities: the expert layer, the one sigmoid rule, the
+    latents' one body, the drafting rule and the decode loop are the
+    modules' own, and `glm_dsa.py` spells none of them out again."""
+    from comfyui_distributed_tpu.models import (
+        deepseek_v2, dsa, glm_dsa, k_exaone, ling_flash, lm_common, mla, moe)
+
+    assert glm_dsa.expert_layer is moe.expert_layer is k_exaone.expert_layer
+    assert glm_dsa.sigmoid_route is moe.sigmoid_route is k_exaone.sigmoid_route
+    assert glm_dsa.mla is mla is ling_flash.mla is deepseek_v2.mla and dsa.mla is mla
+    assert glm_dsa.verify is lm_common.verify and glm_dsa.mtp_input is lm_common.mtp_input
+    assert glm_dsa.decode_loop is lm_common.decode_loop
+    assert glm_dsa.head is lm_common.head and glm_dsa.rms_norm is lm_common.rms_norm
+    with open(glm_dsa.__file__, encoding="utf-8") as fh:
+        source = fh.read()
+    for gone in ("top_k(", "ragged_dot(", "softmax(", "cumsum(", "einsum(", "argsort("):
+        assert gone not in source, gone
+    assert source.count("mla.latents(") == 2  # a layer's and the module's in the prefill
 
 
 def test_the_sixth_model_is_written_from_the_modules_the_others_are():
