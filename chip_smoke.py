@@ -1427,11 +1427,14 @@ def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, ra
             index_heads, index_width) -> bool:
     """`models/dsa.py` at one part's shapes, bfloat16 as stored: ms of
     the indexer's scores, of the selection in either form (`lax.top_k`;
-    the bisection) and of attention in either form (the chosen rows
-    gathered; `mla.absorbed` under the selection's mask), all a block of
-    `dsa.BLOCK_ROWS` query rows at a time; that both selections are one
-    set and both attentions one result; then a step's two positions,
-    scores and selection and attention together, in either form."""
+    the bisection), of XLA's gather of the chosen rows with nothing
+    behind it, and of attention in each form (`mla.absorbed` under the
+    selection's mask a block of `dsa.BLOCK_ROWS` query rows at a time;
+    the chosen rows gathered by XLA; gathered inside the `dsa_attend`
+    kernel, interpreted in a rehearsal); that both selections are one
+    set and the gathered forms the masked form's result (`DSA_TOLERANCE`);
+    then a step's two positions, scores and selection and attention
+    together, in either form."""
     import jax
     import jax.numpy as jnp
 
@@ -1470,19 +1473,27 @@ def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, ra
     row["ok"] &= row["selections_equal"]
 
     gathered = selections["top_k"]
+    _, first_s, ms = timed(
+        jax.jit(lambda c: dsa.by_rows(lambda c: cache[c].sum(axis=1), dsa.ATTEND_ROWS, c)),
+        gathered.chosen)
+    row["gather_alone"] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
+    on_all = lambda form, **how: jax.jit(lambda a, b, c, d: form(  # noqa: E731
+        a, b, cache, dsa.Selection(c, d), w_uk, w_uv, scale, **how))
     forms = {
-        "gathered": jax.jit(lambda a, b, c, d: dsa.attend(
-            a, b, cache, dsa.Selection(c, d), w_uk, w_uv, scale)),
         "masked": by_blocks(lambda a, b, c, d: mla.absorbed(
             a, b, cache, dsa.as_mask(dsa.Selection(c, d), rows), w_uk, w_uv, scale)),
+        "gathered": on_all(dsa.attend_gathered),
+        "kernel": on_all(dsa.attend_kernel, interpret=rehearsal),
     }
     outs = {}
     for name, attend in forms.items():
         outs[name], first_s, ms = timed(attend, q_nope, q_rope, *gathered)
         row[f"attend_{name}"] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
-    diff = jnp.max(jnp.abs(outs["gathered"].astype(jnp.float32) - outs["masked"].astype(jnp.float32)))
-    row["max_rel_diff"] = round(float(diff / jnp.max(jnp.abs(outs["masked"].astype(jnp.float32)))), 6)
-    row["ok"] &= row["max_rel_diff"] <= DSA_TOLERANCE  # a NaN fails it too
+    want = outs["masked"].astype(jnp.float32)
+    for name in ("gathered", "kernel"):
+        diff = jnp.max(jnp.abs(outs[name].astype(jnp.float32) - want)) / jnp.max(jnp.abs(want))
+        row[f"attend_{name}"]["max_rel_diff"] = round(float(diff), 6)
+        row["ok"] &= float(diff) <= DSA_TOLERANCE  # a NaN fails it too
 
     # a drafting step's two positions: what `glm_dsa.attention` runs of this module, and
     # the other form at the same two

@@ -19,11 +19,18 @@ infinity, `select` S and `attend` latent attention over it. A layer that
 computes no index of its own attends by the `Selection` handed to it.
 
 S has two forms, by the number of queries alone (`form`). **gathered**
-(a part of a prompt): `lax.top_k` gives the chosen positions, their rows
-of the latent cache are brought together and `mla.absorbed`'s products
-run over them, a block of query rows at a time (`BLOCK_ROWS`,
-`ATTEND_ROWS`), so that neither the heads' products `[T, heads, S]` nor
-the gathered rows `[T, index_topk, width]` are ever whole in memory. **masked** (a decode
+(a part of a prompt): `lax.top_k` gives the chosen positions, a block
+of query rows at a time (`BLOCK_ROWS`), their rows of the latent cache
+are brought together and `mla.absorbed`'s products run over them. Where
+they are brought together is the backend's (`ops/dsa_attend.route`): on
+a TPU inside one Pallas kernel that holds the layer's cache in VMEM and
+copies a query's rows beside one another on chip (`attend_kernel`; a
+part of 8,192 queries over 32,896 rows 41 ms on a v5e against 124);
+elsewhere, and for a cache the kernel has no plan for, in HBM by XLA's
+gather, a block of `ATTEND_ROWS` query rows at a time
+(`attend_gathered`), so that neither the heads' products `[T, heads,
+S]` nor the gathered rows `[T, index_topk, width]` are ever whole in
+memory. **masked** (a decode
 step's one or two queries): the `index_topk`-th largest score by
 bisection on the scores' bit patterns, the mask `I >= threshold` under
 the tie rule, and `mla.absorbed` over the whole cache under it: a
@@ -39,7 +46,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import _DTYPE_NAMES, _ROUTE_LOG
+from ..ops import dsa_attend
 from . import mla
 from .lm_common import apply_rope_pairs
 
@@ -52,6 +59,11 @@ BLOCK_ROWS = 128
 # index_topk, width] (0.15 GB at 64 rows of 2,048 latents 576 wide in
 # bfloat16); 124 ms a part at 64 rows, 134 at 128.
 ATTEND_ROWS = 64
+# Query rows a call of the `dsa_attend` kernel takes: what lives beside a
+# part's own arrays is a block's folded queries and latent outputs
+# (0.27 GB at 2,048 rows of 64 heads), not the part's (1.1 GB), and the
+# cache's words come into VMEM once a block (62 us of a call's 10 ms).
+KERNEL_ROWS = 2048
 # Queries up to which a selection is a mask over the whole cache.
 MASKED_ROWS = 8
 
@@ -197,28 +209,14 @@ def select(q: jax.Array, w: jax.Array, cached: jax.Array, positions: jax.Array,
     return jax.lax.switch(rung, rungs, q, w, positions)
 
 
-def attend(q_nope: jax.Array, q_rope: jax.Array, cache: jax.Array, selection: Selection,
-           w_uk: jax.Array, w_uv: jax.Array, scale: float) -> jax.Array:
-    """Latent attention of the queries ([T, heads, nope], [T, heads,
-    rope] rotated) over the positions of the latent cache [S, rank +
-    rope] that `selection` holds. Masked: `mla.absorbed` under the mask.
-    Gathered: the same products (W_uk folded into the query, the
-    weighted sum over the latents, W_uv after it) over the chosen rows
-    brought together, a block of query rows at a time; scores and
-    softmax float32, the probabilities rounded to the cache's dtype.
-    Returns the heads' outputs [T, heads, v]. A traced call logs `dsa-
-    <form> <queries>x<cache rows> k<keys a query reads at most> h<heads>
-    <dtype>` in `ops/attention.route_log`."""
-    rank, (queries, heads) = w_uk.shape[0], q_nope.shape[:2]
-    masked = selection.chosen is None
-    log = _ROUTE_LOG.get()
-    if log is not None:
-        most = selection.counts.shape[1]  # the cache's rows, or the positions chosen
-        dtype = _DTYPE_NAMES.get(cache.dtype.name, cache.dtype.name)
-        kind = "masked" if masked else "gathered"
-        log.append(f"dsa-{kind} {queries}x{cache.shape[0]} k{most} h{heads} {dtype}")
-    if masked:
-        return mla.absorbed(q_nope, q_rope, cache, selection.counts, w_uk, w_uv, scale)
+def attend_gathered(q_nope: jax.Array, q_rope: jax.Array, cache: jax.Array,
+                    selection: Selection, w_uk: jax.Array, w_uv: jax.Array,
+                    scale: float) -> jax.Array:
+    """`attend` over a gathered selection, left to XLA: the chosen rows
+    brought together (`cache[chosen]`, written out and read back by the
+    two products) a block of `ATTEND_ROWS` query rows at a time. What
+    every backend but a TPU runs, and what the kernel is held against."""
+    rank = w_uk.shape[0]
 
     def block(q_nope, q_rope, chosen, counts):
         rows = cache[chosen]                                       # [R, k, rank + rope]
@@ -231,6 +229,51 @@ def attend(q_nope: jax.Array, q_rope: jax.Array, cache: jax.Array, selection: Se
         return jnp.einsum("thc,chd->thd", o_lat, w_uv)
 
     return by_rows(block, ATTEND_ROWS, q_nope, q_rope, *selection)
+
+
+def attend_kernel(q_nope: jax.Array, q_rope: jax.Array, cache: jax.Array,
+                  selection: Selection, w_uk: jax.Array, w_uv: jax.Array,
+                  scale: float, interpret: bool = False) -> jax.Array:
+    """`attend` over a gathered selection through `ops/dsa_attend`: the
+    same products, the chosen rows brought together inside the kernel
+    (the layer's cache resident in VMEM, laid out for it once a call)
+    and never in HBM; a block of `KERNEL_ROWS` query rows at a time,
+    W_uk folded into the block's queries before the kernel and W_uv
+    applied after it."""
+    words = dsa_attend.table(cache, w_uk.shape[0])
+
+    def block(q_nope, q_rope, chosen, counts):
+        q_lat = jnp.einsum("thd,chd->thc", q_nope, w_uk)
+        o_lat = dsa_attend.dsa_attend(
+            q_lat, q_rope, words, chosen, counts, scale=scale, interpret=interpret)
+        return jnp.einsum("thc,chd->thd", o_lat, w_uv)
+
+    return by_rows(block, KERNEL_ROWS, q_nope, q_rope, *selection)
+
+
+def attend(q_nope: jax.Array, q_rope: jax.Array, cache: jax.Array, selection: Selection,
+           w_uk: jax.Array, w_uv: jax.Array, scale: float) -> jax.Array:
+    """Latent attention of the queries ([T, heads, nope], [T, heads,
+    rope] rotated) over the positions of the latent cache [S, rank +
+    rope] that `selection` holds. Masked: `mla.absorbed` under the mask.
+    Gathered: the same products (W_uk folded into the query, the
+    weighted sum over the latents, W_uv after it) over the chosen rows
+    brought together: on a TPU inside the `dsa_attend` kernel for a shape
+    it has a plan for (`ops/dsa_attend.route`: `attend_kernel`), else by
+    XLA (`attend_gathered`); scores and softmax float32, the
+    probabilities rounded to the cache's dtype. Returns the heads'
+    outputs [T, heads, v]. A traced call logs `dsa-<masked, kernel or
+    gathered> <queries>x<cache rows> k<keys a query reads at most>
+    h<heads> <dtype>` in `ops/attention.route_log`."""
+    rank, (queries, heads) = w_uk.shape[0], q_nope.shape[:2]
+    most = selection.counts.shape[1]  # the cache's rows, or the positions chosen
+    form = "masked" if selection.chosen is None else dsa_attend.route(
+        heads, cache.shape[1], rank, most, cache.shape[0], cache.dtype)
+    dsa_attend.log_route(form, queries, cache.shape[0], most, heads, cache.dtype)
+    if form == "masked":
+        return mla.absorbed(q_nope, q_rope, cache, selection.counts, w_uk, w_uv, scale)
+    gathered = attend_kernel if form == "kernel" else attend_gathered
+    return gathered(q_nope, q_rope, cache, selection, w_uk, w_uv, scale)
 
 
 def as_mask(selection: Selection, size: int) -> jax.Array:

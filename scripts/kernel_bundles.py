@@ -53,6 +53,28 @@ def _attention(q_shape, kv_heads=None, v_width=None, window=None, causal=False):
     return lower
 
 
+def _dsa_attend(queries, rows, k, heads, rank, rope):
+    """Lower one `ops/dsa_attend` call: a block of a part's queries over
+    the chosen rows of a latent cache, bf16."""
+    def lower(place):
+        import jax
+        import jax.numpy as jnp
+
+        from comfyui_distributed_tpu.ops import dsa_attend
+
+        def like(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=place(shape).sharding)
+
+        def fn(q_lat, q_rope, cache, chosen, counts):
+            return dsa_attend.dsa_attend(
+                q_lat, q_rope, dsa_attend.table(cache, rank), chosen, counts, scale=1.0)
+
+        return fn, (place((queries, heads, rank)), place((queries, heads, rope)),
+                    place((rows, rank + rope)),
+                    like((queries, k), jnp.int32), like((queries, k), jnp.bool_))
+    return lower
+
+
 # label -> (kernel's name in the compiled program, lowering). The labels
 # are `chip_smoke.SERVED_SHAPES` / `CAUSAL_SHAPES`', so a bundle count and
 # the chip's time of `chip_smoke.py --legs attention` read side by side.
@@ -67,6 +89,7 @@ CASES = {
         _attention((1, 8192, 64, 128), kv_heads=8, window=128, causal=True)),
     "deepseek-v2 mla 2048": (
         "flash_attention_causal", _attention((1, 2048, 128, 192), v_width=128, causal=True)),
+    "glm-5.2 dsa": ("dsa_attend", _dsa_attend(2048, 32896, 2048, 64, 512, 64)),
 }
 DEFAULT = ("sd15 self 64x64", "flux joint 4608", "solar / k-exaone full 8192")
 

@@ -250,7 +250,8 @@ def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys
     """The row's shape is the cell's: the last part of the committed
     workflow's prompt over caches of the request's length, the
     registry's widths; and the rehearsal's toy row on the CPU: both
-    selections one set, both attentions one result, a step in either
+    selections one set, the gathered forms (XLA's, and the `dsa_attend`
+    kernel interpreted) the masked form's result, a step in either
     form."""
     import jax
 
@@ -270,9 +271,12 @@ def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys
         pytest.skip("the rehearsal's row is the CPU's")
     assert smoke.dsa_row(True, *smoke.REHEARSAL_DSA_SHAPE)
     (row,) = _result_lines(capsys.readouterr().out)
-    assert row["ok"] and row["selections_equal"] and row["max_rel_diff"] < smoke.DSA_TOLERANCE
-    for name in ("scores", "select_top_k", "select_bisection", "attend_gathered", "attend_masked"):
+    assert row["ok"] and row["selections_equal"]
+    for name in ("scores", "select_top_k", "select_bisection", "gather_alone", "attend_masked"):
         assert set(row[name]) == {"first_call_s", "ms"}, name
+    for name in ("attend_gathered", "attend_kernel"):
+        assert set(row[name]) == {"first_call_s", "ms", "max_rel_diff"}, name
+        assert row[name]["max_rel_diff"] < smoke.DSA_TOLERANCE
     assert set(row["step_masked"]) == set(row["step_gathered"]) == {"first_call_s", "us"}
 
 

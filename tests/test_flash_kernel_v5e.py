@@ -581,14 +581,47 @@ def test_deepseek_decode_multiplies_its_six_pairs_in_the_kernel(
     assert len(re.findall(r"= bf16\[40,6,\d+\]\S* convolution\(", text)) == masked
 
 
+def test_dsa_attend_compiles_for_v5e_at_a_parts_shape(one_chip):
+    """Latent attention over the chosen rows at `chip_smoke.DSA_SHAPE`,
+    a part of GLM-5.2's prompt over the cell's cache, as
+    `dsa.attend_kernel` runs it: the `dsa_attend` kernel a block of
+    `dsa.KERNEL_ROWS` queries, holding the layer's cache in VMEM (the
+    compiler takes the raised limit; the default 16 MiB would refuse
+    it), and no gathered row in HBM. What the program holds beside its
+    arguments and its result is a block's folded queries, latent
+    outputs and selection as int32, and the cache's words."""
+    from comfyui_distributed_tpu.models import dsa
+    from comfyui_distributed_tpu.ops import dsa_attend
+
+    _, queries, rows, top, heads, nope, rope, v, rank, *_ = chip_smoke.DSA_SHAPE
+    sizes = dsa_attend.plan(heads, rank + rope, rank, top, rows, 2)
+    assert 16 * 2**20 < sizes.vmem_bytes <= dsa_attend.VMEM_RESIDENT_BUDGET
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda a, b, cache, c, d, w_uk, w_uv: dsa.attend_kernel(
+            a, b, cache, dsa.Selection(c, d), w_uk, w_uv, 0.0625)
+    ).lower(
+        place((queries, heads, nope), jnp.bfloat16), place((queries, heads, rope), jnp.bfloat16),
+        place((rows, rank + rope), jnp.bfloat16),
+        place((queries, top), jnp.int32), place((queries, top), jnp.bool_),
+        place((rank, heads, nope), jnp.bfloat16), place((rank, heads, v), jnp.bfloat16),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%dsa_attend" in text
+    assert " gather(" not in text
+    block = dsa.KERNEL_ROWS * (heads * (2 * sizes.value_width + sizes.key_width) * 2 + 2 * top * 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * block + 2 * rows * sizes.lanes * 4
+
+
 def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_caches_in_place(
         one_chip, monkeypatch):
     """GLM-5.2's two programs at the cell's sizes (32,768 ids in four parts
     of 8,192 over caches of 32,896 positions), routed as a TPU routes
     them. The prefill is one `while` over the parts: what it holds beside
     its arguments is a part's working set (the expert ladder's top rung,
-    the indexer's scores and the gathered rows a block of query rows),
-    not the prompt's; each attention layer logs the gathered form. The
+    the indexer's scores, a part's folded queries), not the prompt's;
+    each attention layer attends in the `dsa_attend` kernel and no
+    gathered row is in the program. The
     drafting decode carries the donated tree of six latent caches and
     three indexer caches (252.6 MB) through its loop, a step's queries
     take the masked form, and a step's grouped products run in the
@@ -606,7 +639,7 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
             cfg, params, jax.ShapeDtypeStruct((32768,), jnp.int32, sharding=one_chip),
             cache_len=32896,
         ).compile()
-    assert routes == ["dsa-gathered 8192x32896 k2048 h64 bf16"] * cfg.num_hidden_layers
+    assert routes == ["dsa-kernel 8192x32896 k2048 h64 bf16"] * cfg.num_hidden_layers
     memory = prefill.memory_analysis()
     assert memory.temp_size_in_bytes < 4.5e9      # 4.13 GB: a part's, whatever the parts' number
     assert memory.output_size_in_bytes >= 32896 * 7680
@@ -614,6 +647,8 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
     # the parts' scan; `lax.top_k` at 2,048 of 32,896 is a sort
     assert " while(" in text and [line for line in text.splitlines()
                                   if " sort(" in line and "32896" in line]
+    assert not [line for line in text.splitlines() if " gather(" in line and "2048,576" in line]
+    assert text.count("%dsa_attend") >= 1
 
     state = jax.tree.map(place, glm_dsa.state_shapes(cfg, 32896, jnp.bfloat16))
     scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)

@@ -823,7 +823,7 @@ MODELS = [
         published=glm_published, entry=glm_entry, check_workflow=glm_workflow,
         metrics=frozenset({"experts_held_share_pct.lm", "mtp_accept_pct.lm",
                            "mtp_device_pct.lm", "mla_device_pct.lm", "indexer_device_pct.lm",
-                           "keys_selected_pct.lm"}),
+                           "keys_selected_pct.lm", "dsa_attend_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
     ),
 ]
