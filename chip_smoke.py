@@ -877,6 +877,7 @@ CAUSAL_SHAPES = (
     ("deepseek-v2 mla 2048", (1, 2048, 128, 192), 128, 128, None),
     ("ling-flash mla 8192", (1, 8192, 32, 192), 32, 128, None),
     ("nemotron3-nano 32:2 8192", (1, 8192, 32, 128), 2, 128, None),
+    ("granite-4.0-h 32:8 of 64 8192", (1, 8192, 32, 64), 8, 64, None),
 )
 REHEARSAL_CAUSAL_SHAPES = (
     ("toy causal grouped", (1, 1280, 4, 128), 2, 128, None),

@@ -21,6 +21,7 @@ from .mmdit import MMDiT, MMDiTConfig
 from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
 from .glm_dsa import GlmDsa, GlmDsaConfig
+from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .k_exaone import KExaone, KExaoneConfig
 from .ling_flash import LingFlash, LingFlashConfig
 from .nemotron_h import NemotronH, NemotronHConfig
@@ -630,6 +631,31 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             prefill_part=16,
         ),
     },
+    # ibm-granite/granite-4.0-h-micro whole, every field as published: all 40
+    # layers (36 Mamba-2, attention at 5, 15, 25, 35), all 100,352 ids, a
+    # one-chip replica (3.19 B parameters, 6.38 GB in bfloat16); nothing is a
+    # share of anything (the benchmark's granite-4.0-h-micro configuration)
+    "granite-4.0-h-micro": {
+        "family": "lm",
+        "config": GraniteHybridConfig(),
+    },
+    # every mechanism at a size for the CPU: 8 layers with attention at 2 and
+    # 6, so Mamba runs of three lengths (2, 3, 1) stand before, between and
+    # after the attention layers; 4 Mamba-2 heads of 8 over a state of 16 in
+    # one group, chunks of 8 (a part is two), 4 query heads over 2 key heads
+    # of 16, a SwiGLU of 96, parts of 16 positions, the four multipliers at
+    # their published values
+    "tiny-granite-hybrid": {
+        "family": "lm",
+        "config": GraniteHybridConfig(
+            hidden_size=64,
+            layer_types=tuple(
+                "attention" if index in (2, 6) else "mamba" for index in range(8)),
+            mamba_n_heads=4, mamba_d_head=8, mamba_d_state=16, mamba_chunk_size=8,
+            num_attention_heads=4, num_key_value_heads=2, shared_intermediate_size=96,
+            vocab_size=4096, prefill_part=16,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -689,6 +715,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     LingFlashConfig: LingFlash,
     NemotronHConfig: NemotronH,
     GlmDsaConfig: GlmDsa,
+    GraniteHybridConfig: GraniteHybrid,
 }
 
 

@@ -305,13 +305,13 @@ def causal_kernel_wins(m: int, v_width: int, window: int | None = None) -> bool:
     """Whether a causal call over m keys with values `v_width` wide goes
     to the kernel on a TPU: a value width on the lane tile (the output is
     written where the caller reads it; q and k of another width are padded
-    where they lie), `MIN_RAGGED_KEYS` keys or more, where the XLA
-    form's float32 scores cost more than the kernel's steps, and no
-    window shorter than `MIN_BAND_WINDOW`. By the chip's times at the
-    four served shapes (PERF.md §6, PR 43)."""
+    where they lie) from `MIN_RAGGED_KEYS` keys on, where the XLA form's
+    float32 scores cost more than the kernel's steps (PERF.md §6, PR 43),
+    a narrower one from `narrow_keys(v_width)` on (PR 54, at this file's
+    end), and no window shorter than `MIN_BAND_WINDOW`."""
     if window is not None and window < MIN_BAND_WINDOW:
         return False
-    return v_width % ROUTE_MULTIPLE == 0 and m >= MIN_RAGGED_KEYS
+    return m >= (MIN_RAGGED_KEYS if v_width % ROUTE_MULTIPLE == 0 else narrow_keys(v_width))
 
 
 def kernel_wins(n: int, m: int) -> bool:
@@ -657,3 +657,28 @@ def flash_attention(
     else:
         out = out.reshape(b, n, h, dv)
     return out[:, :rows, :, :v_width] if (n, dv) != (rows, v_width) else out
+
+
+# --- a causal call narrower than the lane tile (PR 54) ----------------------
+# Down here so that no line above moved: the kernel's source lines are part
+# of what a compiled program is cached by, and a shift rebuilds every
+# kernel-carrying program of every cell once.
+#
+# A causal call whose values are `NARROW_WIDTH` wide takes the kernel from
+# `MIN_NARROW_KEYS` keys on, its heads padded to the lane tile and folded in
+# front of the tokens (`flash_attention`'s `fold`): half of every MXU pass
+# is then zeros, but `causal_attention_blocked` writes its heads' float32
+# scores of a block of rows to HBM, 12 bytes a key and row and more, and at
+# 65,536 keys one block of 256 rows of 32 heads is 2.1 GB. On a v5e, 8,192
+# queries of 32 heads over 8 key heads of 64 (granite-4.0-h-micro's parts;
+# PERF.md §6, PR 54), ms a call, kernel / XLA blocks: 5.26 / 17.44 at 8,192
+# keys, 26.56 / 130.89 at 32,768, 55.16 at 65,536. Below 8,192 keys, and at
+# any other width off the tile, no such call has been timed: they stay on XLA.
+NARROW_WIDTH = 64
+MIN_NARROW_KEYS = 8192
+
+
+def narrow_keys(v_width: int) -> float:
+    """The keys from which a causal call with values `v_width` wide, off
+    the lane tile, goes to the kernel on a TPU: never, but at `NARROW_WIDTH`."""
+    return MIN_NARROW_KEYS if v_width == NARROW_WIDTH else math.inf

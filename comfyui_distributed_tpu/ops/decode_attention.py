@@ -76,7 +76,7 @@ def decode_attention_route(heads: int, positions: int, d: int, dtype) -> str:
     return "decode-kernel" if plan else "decode-xla"
 
 
-def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, valid: jax.Array) -> jax.Array:
+def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, valid, scale=None) -> jax.Array:
     """The einsum form: q [W, heads, d], the queries of W positions of
     one step (one: `q[None]`), over `cache[slot]`, with `valid` [W, S]
     saying which of the slot's S entries each may see (`position_valid`
@@ -84,15 +84,15 @@ def decode_attention_xla(q: jax.Array, cache: jax.Array, slot, valid: jax.Array)
     fewer key and value heads than there are queries, a divisor of their
     count: key head j serves the query heads j x group .. (j + 1) x group
     - 1 (one wide where the counts are equal), and the W queries lie
-    beside a key head's group, so the slot is read once. Scores, softmax
-    and the sum's accumulation float32, the probabilities rounded to the
-    cache's dtype. Returns [W, heads, d] in the cache's dtype."""
+    beside a key head's group, so the slot is read once. Scores (times `scale`,
+    d^-1/2 where None), softmax and the sum's accumulation float32, the probabilities
+    rounded to the cache's dtype. Returns [W, heads, d] in the cache's dtype."""
     keys, values = cache[slot]                                   # [kv heads, S, d] each
     heads, d = q.shape[-2:]
     group = heads // keys.shape[0]
     grouped = q.reshape(-1, keys.shape[0], group, d).swapaxes(0, 1).reshape(
         keys.shape[0], -1, d)                                    # [kv heads, W x group, d]
-    scores = d ** -0.5 * jnp.einsum(
+    scores = (d ** -0.5 if scale is None else scale) * jnp.einsum(
         "hgd,hsd->hgs", grouped, keys, preferred_element_type=jnp.float32)
     scores = jnp.where(jnp.repeat(valid, group, axis=0)[None], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(values.dtype)
@@ -200,13 +200,13 @@ def _log_route(entry: str) -> None:
         log.append(entry)
 
 
-def attend_xla(q: jax.Array, cache: jax.Array, slot, valid: jax.Array) -> jax.Array:
+def attend_xla(q: jax.Array, cache: jax.Array, slot, valid: jax.Array, scale=None) -> jax.Array:
     """`decode_attention_xla` as a model calls it: with one entry in
     `ops/attention.route_log`, `decode-xla 16x2112x128` (query heads x
     the slot's entries x width)."""
     heads, d = q.shape[-2:]
     _log_route(f"decode-xla {heads}x{cache.shape[-2]}x{d}")
-    return decode_attention_xla(q, cache, slot, valid)
+    return decode_attention_xla(q, cache, slot, valid, scale)
 
 
 def attend(q: jax.Array, cache: jax.Array, slot, position) -> jax.Array:

@@ -3,6 +3,9 @@
 new tokens, one draft a step, and a 32,767-byte text: the cells' 8,191-byte
 style guide byte for byte, then a 24,576-byte manuscript, a story's chapter
 told scene by scene, ending in the line that asks for one scene's prompt.
+And `longdoc-txt2img-granite-4.0-h-micro.json` beside it (`DOCUMENTS`):
+the same graph with `granite-4.0-h-micro`, no draft, and a 65,535-byte
+text, the guide and a 57,344-byte manuscript from another seed.
 
 The manuscript is original prose put together from the phrase lists below
 by a seeded generator (no network, no corpus): `python3
@@ -21,6 +24,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SOURCE = os.path.join(ROOT, "workflows", "rewrite-txt2img-k-exaone.json")
 NAME = "longdoc-txt2img-glm-5.2.json"
 MANUSCRIPT_BYTES = 24576
+# file name: (checkpoint, the generator's seed, the manuscript's bytes, drafts a step)
+DOCUMENTS = {
+    NAME: ("glm-5.2-ep16-5l", 52, MANUSCRIPT_BYTES, 1),
+    "longdoc-txt2img-granite-4.0-h-micro.json": ("granite-4.0-h-micro", 54, 57344, 0),
+}
 ASK = "\n\nIllustrate scene {scene} of the chapter above, and nothing of the other scenes.\nPrompt:"
 
 PEOPLE = ["Marit", "the ferryman", "old Tobias", "the lamplighter's daughter", "Ilse",
@@ -61,7 +69,7 @@ AFTER = ["Nobody spoke of it afterwards.", "The dog watched from under the bench
          "The gulls had gone inland, which meant weather.", "A kettle was already on."]
 
 
-def manuscript(seed: int = 52) -> str:
+def manuscript(seed: int = 52, size: int = MANUSCRIPT_BYTES) -> str:
     rng = random.Random(seed)
     text = "\n\nManuscript, chapter nine: The Winter Crossing.\n"
     scene = 0
@@ -74,9 +82,9 @@ def manuscript(seed: int = 52) -> str:
             lines.append(f" {rng.choice([who, other]).capitalize()} {act}. {rng.choice(AFTER)}")
         piece = "".join(lines) + "\n"
         ask = ASK.format(scene=max(scene // 2, 1))
-        if len(text) + len(piece) + len(ask) > MANUSCRIPT_BYTES:
+        if len(text) + len(piece) + len(ask) > size:
             ask = ASK.format(scene=max((scene - 1) // 2, 1))
-            room = MANUSCRIPT_BYTES - len(ask)
+            room = size - len(ask)
             text += "\nThe chapter ends here, with the lake shut and the road not yet open."
             while len(text) < room:  # short closing sentences, then spaces, up to the byte
                 more = " " + rng.choice(AFTER)
@@ -86,24 +94,25 @@ def manuscript(seed: int = 52) -> str:
 
 
 def main() -> None:
-    with open(SOURCE, encoding="utf-8") as fh:
-        graph = json.load(fh)
-    for node in graph.values():
-        inputs = node["inputs"]
-        if node["class_type"] == "CheckpointLoaderSimple":
-            inputs["ckpt_name"] = "glm-5.2-ep16-5l"
-        elif node["class_type"] == "TextGenerate":
-            guide = inputs["text"]
-            assert len(guide.encode()) == 8191, len(guide.encode())
-            body = manuscript()
-            assert body.isascii() and len(body) == MANUSCRIPT_BYTES, len(body)
-            inputs.update(text=guide + body, max_new_tokens=128, draft_tokens=1)
-        elif node["class_type"] == "SaveImage":
-            inputs["filename_prefix"] = NAME[: -len(".json")]
-    data = json.dumps(graph, indent=2) + "\n"
-    for folder in ("workflows", os.path.join("benchmark", "workflows")):
-        with open(os.path.join(ROOT, folder, NAME), "w", encoding="utf-8") as fh:
-            fh.write(data)
+    for name, (checkpoint, seed, size, drafts) in DOCUMENTS.items():
+        with open(SOURCE, encoding="utf-8") as fh:
+            graph = json.load(fh)
+        for node in graph.values():
+            inputs = node["inputs"]
+            if node["class_type"] == "CheckpointLoaderSimple":
+                inputs["ckpt_name"] = checkpoint
+            elif node["class_type"] == "TextGenerate":
+                guide = inputs["text"]
+                assert len(guide.encode()) == 8191, len(guide.encode())
+                body = manuscript(seed, size)
+                assert body.isascii() and len(body) == size, len(body)
+                inputs.update(text=guide + body, max_new_tokens=128, draft_tokens=drafts)
+            elif node["class_type"] == "SaveImage":
+                inputs["filename_prefix"] = name[: -len(".json")]
+        data = json.dumps(graph, indent=2) + "\n"
+        for folder in ("workflows", os.path.join("benchmark", "workflows")):
+            with open(os.path.join(ROOT, folder, name), "w", encoding="utf-8") as fh:
+                fh.write(data)
 
 
 if __name__ == "__main__":

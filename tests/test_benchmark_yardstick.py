@@ -50,8 +50,13 @@ _TESTS = os.path.join(
 # to be what PR 48 found (the two drafting metrics' and latent attention's):
 # `test_glm_dsa_readers.py` has the one that holds what each list began
 # with and lets later cells follow.
-_LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_each_after_"
-           "those_before")
+#
+# PR 54 appended an eighth model's cell to a list that form had held to be
+# what PR 52 found (`ssm_device_pct.lm` listing Nemotron-3-Nano's cell
+# alone): `test_granite_hybrid_readers.py` has the one that holds every
+# list from its start and none to its end.
+_LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_each_list_from_"
+           "its_start")
 _MODULES = {}
 _SUPERSEDED = {
     "test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys":
@@ -67,6 +72,8 @@ _SUPERSEDED = {
         "test_device_every_lm_work_file_is_found_by_its_registry_name_however_many",
     "test_the_solar_cell_is_listed_where_its_readers_find_something": _LISTED,
     "test_the_lm_cells_are_listed_where_their_readers_find_something": _LISTED,
+    "test_the_lm_cells_are_listed_where_their_readers_find_something_each_after_those_before":
+        _LISTED,
 }
 
 

@@ -318,6 +318,10 @@ def test_the_plan_of_a_causal_call_and_the_share_of_the_square_it_computes(
     (8192, 128, None, True), (2048, 128, None, True), (512, 256, None, True),
     (384, 128, None, False),   # a short prompt: the XLA form's scores are small
     (2048, 64, None, False),   # a value width off the lane tile
+    (8192, 64, None, True),    # ... goes padded where its float32 scores would be gigabytes:
+    (65536, 64, None, True),   # granite-4.0-h-micro's parts (PERF.md §6, PR 54)
+    (8192, 96, None, False),   # 64 is what was timed, nothing else off the tile
+    (8192, 64, 128, False),
     (8192, 128, 128, False),   # K-EXAONE's window layers: a band that is all edge (PERF.md §6)
     (8192, 128, 1024, True),
 ])

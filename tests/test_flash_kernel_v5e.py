@@ -111,6 +111,8 @@ CAUSAL_ENTRIES = [
     "flash-causal 8192x8192x192/128 g1 bq512 bk1024 bf16 inplace blocks72/128",
     # Nemotron-3-Nano's 32 : 2: sixteen query heads read one key head where it lies
     "flash-causal 8192x8192x128/128 g16 bq512 bk1024 bf16 inplace blocks72/128",
+    # granite-4.0-h-micro's first part: 64-wide heads padded to the lane tile and folded
+    "flash-causal 8192x8192x64/64 g4 bq512 bk1024 bf16 blocks72/128",
 ]
 
 
@@ -544,6 +546,62 @@ def test_nemotron_decode_reads_its_experts_where_they_lie_and_carries_its_state_
     assert memory.alias_size_in_bytes >= 8704 * 6144 + 49_082_368
     copied = re.findall(r" = (\w+)\[([\d,]*)\]\S* copy\(", text)
     assert copied  # the tails, among others
+    assert not [(dtype, dims) for dtype, dims in copied
+                if math.prod(int(d) for d in dims.split(",") if d) >= 2**19]
+
+
+def test_granite_prefill_in_parts_attends_64_wide_heads_in_the_kernel_and_its_decode_carries_40_leaves_in_place(
+        one_chip, monkeypatch):
+    """granite-4.0-h-micro's two programs at the cell's sizes (65,536 ids
+    in eight parts of 8,192 over caches of 65,664 positions), routed as a
+    TPU routes them. The prefill is one `while` over the parts whose body
+    holds, for each of the four attention layers, a causal kernel call a
+    possible count of keys (32 in all), 64-wide heads padded to the lane
+    tile; what it holds beside its arguments is a part's working set
+    (2.0 GB: a Mamba layer's chunk weights, a SwiGLU's middle, a part's
+    folded keys), not the prompt's. The decode carries the donated tree
+    of four caches (537.9 MB) and 36 states and tails (76.4 MB), a leaf a
+    layer, through its loop and copies none of them."""
+    import math
+    import re
+
+    from comfyui_distributed_tpu.models import granite_hybrid
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("granite-4.0-h-micro")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: granite_hybrid.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        prefill = granite_hybrid.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((65536,), jnp.int32, sharding=one_chip),
+            cache_len=65664,
+        ).compile()
+    assert routes == [
+        f"flash-causal 8192x{8192 * k}x64/64 g4 bq512 bk1024 bf16 blocks{128 * k - 56}/{128 * k}"
+        for k in range(1, 9)] * 4
+    memory = prefill.memory_analysis()
+    assert memory.temp_size_in_bytes < 2.4e9      # 1.98 GB: a part's, whatever the parts' number
+    assert memory.output_size_in_bytes >= 65664 * 8192 + 76_437_504
+    text = prefill.as_text()
+    assert " while(" in text and text.count('custom_call_target="tpu_custom_call"') == 32
+    assert "%flash_attention_causal" in text
+
+    state = jax.tree.map(place, granite_hybrid.state_shapes(cfg, 65664, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        decode = granite_hybrid.decode.lower(
+            cfg, params, state,
+            jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.float32, sharding=one_chip),
+            scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+            scalar(jnp.float32), steps=126,  # a step count of its own: the route is read while tracing
+        ).compile()
+    assert routes == ["decode-xla 32x65664x64"] * 4
+    memory = decode.memory_analysis()
+    assert memory.temp_size_in_bytes < 128 * 2**20    # 66.5 MB
+    assert memory.alias_size_in_bytes >= 65664 * 8192 + 76_437_504
+    copied = re.findall(r" = (\w+)\[([\d,]*)\]\S* copy\(", decode.as_text())
     assert not [(dtype, dims) for dtype, dims in copied
                 if math.prod(int(d) for d in dims.split(",") if d) >= 2**19]
 

@@ -429,6 +429,68 @@ def glm_workflow(mine, _):
     assert short == text[:2047]
 
 
+def granite_workflow(mine, _):
+    theirs = load("workflows/rewrite-txt2img-k-exaone.json")
+    assert differing(mine, theirs) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "text"),
+        ("TextGenerate", "max_new_tokens"), ("TextGenerate", "draft_tokens"),
+        ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["draft_tokens"], generate["temperature"]) == (
+        128, 0, 1.0)
+    # the cells' 8,191-byte style guide byte for byte, then a 57,344-byte manuscript that
+    # ends in the line asking for one scene's prompt: 65,535 bytes, 65,536 tokens
+    guide = by_kind(theirs)["TextGenerate"]["text"]
+    text = generate["text"]
+    assert text.startswith(guide) and text.isascii()
+    assert (len(guide), len(text) - len(guide)) == (8191, 57344)
+    assert text[len(guide):].startswith("\n\nManuscript, chapter nine")
+    assert text.count("\nScene ") >= 100 and text.endswith("of the other scenes.\nPrompt:")
+    # what `scripts/gen_longdoc_workflow.py` writes, to the byte, and not GLM's manuscript
+    spec = importlib.util.spec_from_file_location(
+        "gen_longdoc_workflow", os.path.join(ROOT, "scripts", "gen_longdoc_workflow.py"))
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    assert text == guide + script.manuscript(54, 57344)
+    assert not text[len(guide):].startswith(script.manuscript()[:4096])
+    # the rehearsal reads the document's first 255 bytes: 16 parts of 16 positions
+    edits = load(
+        "benchmark/workloads/granite_4_0_h_micro_longdoc_txt2img_512.closed2.json")["rehearsal"]["set"]
+    (short,) = [e["value"] for e in edits if (e["class_type"], e["input"]) == ("TextGenerate", "text")]
+    assert short == text[:255]
+
+
+def granite_published(config):
+    assert config["layer_types"] == [
+        "attention" if index % 10 == 5 else "mamba" for index in range(40)]
+    assert config["model_type"] == "granitemoehybrid" and config["position_embedding_type"] == "nope"
+    assert (config["num_local_experts"], config["num_experts_per_tok"]) == (0, 0)
+    assert config["as_run"]["parameters"] == {"lm": 3191396096}
+    assert (config["as_run"]["cache_bytes_per_token"], config["as_run"]["state_bytes"]) == (
+        8192, 76437504)
+    assert (config["as_run"]["prefill_part"], config["as_run"]["prefill_chunk"]) == (8192, 256)
+    assert set(config["held"]) == {"layers", "vocabulary", "state"}
+    assert "all 40 published layers" in config["held"]["layers"]
+    assert "all 100,352 ids" in config["held"]["vocabulary"]
+    assert "whole on one v5e chip beside SD1.5" in config["deployment"]
+    assert "no layer is shared between chips, no stage is cut" in config["deployment"]
+    assert "published" not in config
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max"] < 0.3
+    # layer 0's state is arithmetic alone: far tighter than the deepest
+    assert 0 < 5 * limits["tolerance_first_state_rel_l2"] < limits["tolerance_state_rel_l2"] < 0.3
+    assert 0 < limits["tolerance_kv_rel_l2"] < 0.05
+    assert "bfloat16 between parts" in limits["why_these_limits"]
+
+
+def granite_entry(cfg, config):
+    assert tuple(config["layer_types"]) == cfg.layer_types
+    assert (cfg.num_hidden_layers, cfg.vocab_size, cfg.head_dim) == (40, 100352, 64)
+    assert (cfg.prefill_part, cfg.mamba_chunk_size) == (
+        config["as_run"]["prefill_part"], config["as_run"]["prefill_chunk"])
+    assert type(cfg)() == cfg  # every field the published value: the defaults
+
+
 def glm_published(config):
     assert config["rope_parameters"] == {"rope_theta": 8000000, "rope_type": "default"}
     assert config["indexer_types"] == ["full"] * 3 + ["shared", "shared", "shared", "full"] * 18 + [
@@ -499,6 +561,7 @@ class Model:
     metrics: frozenset = frozenset()   # what its cell lists beyond `LM_METRICS`
     imports: tuple = tuple(PLAIN_IMPORTS)
     cell_prompt: int = 0        # tokens of the committed workflow, where the rehearsal cuts it
+    trace: tuple = (5, 12)      # the cell's traced slice: (start_s, slice_s)
 
     def __str__(self):
         return self.name
@@ -766,7 +829,7 @@ MODELS = [
                  "batch is 1", "house style guide", "no MTP module"),
         published=nemotron_published, entry=nemotron_entry, check_workflow=nemotron_workflow,
         metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "ssm_device_pct.lm",
-                           "expert_matvec_hbm_pct.lm"}),
+                           "expert_matvec_hbm_pct.lm", "attn_device_pct.lm"}),
     ),
     Model(
         name="glm-5.2", served="glm-5.2-ep16-5l", tiny="tiny-glm-dsa",
@@ -825,6 +888,52 @@ MODELS = [
                            "mtp_device_pct.lm", "mla_device_pct.lm", "indexer_device_pct.lm",
                            "keys_selected_pct.lm", "dsa_attend_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
+    ),
+    Model(
+        name="granite-4.0-h-micro", served="granite-4.0-h-micro", tiny="tiny-granite-hybrid",
+        workflow="longdoc-txt2img-granite-4.0-h-micro.json", config="granite-4.0-h-micro.json",
+        reference="granite_hybrid.py", catalog="granite-4.0-h-micro",
+        cell="granite_4_0_h_micro_longdoc_txt2img_512.closed2", prompt=256, cell_prompt=65536,
+        new_tokens=16, drafts=0, trace=(5, 20),
+        # tiny-granite-hybrid: 8 layers, each a mixer and a SwiGLU of 96: six Mamba-2 (4 heads
+        # of 8 over a state of 16, one group, chunks of 8) and attention at 2 and 6 (4 query
+        # heads over 2 key heads of 16), parts of 16 positions, a tied head of 4,096 ids. What
+        # grows: two layers' keys and values; what does not: six float32 matrix states and
+        # the convolutions' last 3 inputs
+        attrs={
+            "prompt_tokens": 256, "new_tokens": 16, "draft_tokens": 0, "decode_steps": 16,
+            "layers": 8, "mamba_layers": 6, "attention_layers": 2, "prefill_part": 16,
+            "prefill_parts": 16, "prefill_chunks": 32,
+            "cache_bytes": 2 * 2 * 2 * (256 + 16) * 16 * 4,
+            "state_bytes": 6 * (4 * 8 * 16 * 4 + 3 * (32 + 2 * 16) * 4),
+            "tied_head_bytes": 4096 * 64 * 4, "node_id": "6"},
+        drawn=frozenset(), drawn_check=lambda attrs: None,
+        wait_bytes=4 * 16,  # the ids: the model reads nothing else back
+        # the decode's two attention layers (the einsum form, a key head serving two
+        # queries), then a causal call a part of the prompt, each over the keys so far
+        attention=", ".join(sorted(
+            ["decode-xla 4x272x16"]
+            + [f"xla-causal 16x{16 * (part + 1)}x16/16 bq16 f32" for part in range(16)])),
+        passes=lambda attrs: (256 * 8, 16 * 8),
+        widths={
+            "hidden_size": 2048, "num_hidden_layers": 40, "num_attention_heads": 32,
+            "num_key_value_heads": 8, "intermediate_size": 8192, "shared_intermediate_size": 8192,
+            "vocab_size": 100352, "mamba_n_heads": 64, "mamba_d_head": 64, "mamba_d_state": 128,
+            "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_expand": 2, "mamba_chunk_size": 256,
+            "mamba_conv_bias": True, "mamba_proj_bias": False, "attention_bias": False,
+            "embedding_multiplier": 12, "attention_multiplier": 0.015625,
+            "residual_multiplier": 0.22, "logits_scaling": 8, "rms_norm_eps": 1e-5,
+            "hidden_act": "silu", "normalization_function": "rmsnorm", "rope_theta": 10000,
+            "rope_scaling": None, "max_position_embeddings": 131072,
+            "tie_word_embeddings": True},
+        reduced={},
+        assumed=("letter for letter", "pre-norm, two norms a layer", "no experts",
+                 "no rotary embedding", "mamba_expand 2", "normed over all 4,096 channels",
+                 "seeded random", "drawn as a head is", "hard-wired", "stand-in",
+                 "ids 0-256 of 100,352", "batch is 1", "long document", "no MTP module"),
+        published=granite_published, entry=granite_entry, check_workflow=granite_workflow,
+        metrics=frozenset({"state_mb.lm", "ssm_device_pct.lm", "attn_device_pct.lm",
+                           "mlp_device_pct.lm", "flash_attention_causal_roofline_pct.lm"}),
     ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
@@ -1090,7 +1199,7 @@ def test_the_manifest_has_the_cell_its_configuration_and_its_metrics(model):
     assert work["seed_nodes"] == ["DistributedSeed"]
     assert work["compute_nodes"] == ["TextGenerate", "KSampler"]
     assert work["rate"] == {"metric": "images_per_s", "units_per_job": 1}
-    assert work["trace"] == {"start_s": 5, "slice_s": 12}
+    assert work["trace"] == dict(zip(("start_s", "slice_s"), model.trace))
     edits = {(e["class_type"], e["input"]): e["value"] for e in work["rehearsal"]["set"]}
     assert edits["CheckpointLoaderSimple", "ckpt_name"] == model.tiny
     assert edits["TextGenerate", "max_new_tokens"] == model.new_tokens
@@ -1124,6 +1233,7 @@ def test_every_language_model_meets_the_one_contract(name):
         "ouro": ([1.0] * 4, [0.5] * 4),
         # a part's loads and keys seen (visible, read) a layer, then the decode's
         "glm-5.2": ([[[3] * held]], [[[9], [5]]], [[1] * held], [[7], [2]]),
+        "granite-4.0-h-micro": (),  # nothing is read back beside the ids
     }.get(model.name, ([[3] * held], [[1] * held]))
     if model.drafts:
         read += ([4, 0, 0, 2],)
@@ -1155,7 +1265,7 @@ def test_every_language_model_meets_the_one_contract(name):
 @pytest.mark.parametrize("name, passes", [
     ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
     ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7),
-    ("nemotron3-nano-ep16-52l", 52), ("glm-5.2-ep16-5l", 5)])
+    ("nemotron3-nano-ep16-52l", 52), ("glm-5.2-ep16-5l", 5), ("granite-4.0-h-micro", 40)])
 def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
     from comfyui_distributed_tpu.models.registry import create_model
 
@@ -1169,7 +1279,10 @@ def _step_of(name):
     """(module, `step(cfg, params, cache, token, position) -> (logits,
     cache, what the decode sums over its steps)`) of a tiny model."""
     from comfyui_distributed_tpu.models import (
-        deepseek_v2, glm_dsa, k_exaone, ling_flash, nemotron_h, ouro, solar_open2)
+        deepseek_v2, glm_dsa, granite_hybrid, k_exaone, ling_flash, nemotron_h, ouro, solar_open2)
+
+    def granite_step(cfg, params, cache, token, position):
+        return (*granite_hybrid.decode_step(cfg, params, cache, token, position), 0)
 
     def glm_step(cfg, params, cache, token, position):
         rows, _, cache, _, loads, _, _ = glm_dsa.main_step(
@@ -1200,6 +1313,7 @@ def _step_of(name):
         "ling-flash": (ling_flash, one_position(ling_flash)),
         "nemotron3-nano": (nemotron_h, with_loads(nemotron_h)),
         "glm-5.2": (glm_dsa, glm_step),
+        "granite-4.0-h-micro": (granite_hybrid, granite_step),
     }[name]
 
 
@@ -1243,6 +1357,8 @@ def test_decode_is_the_models_own_step_walked_with_the_same_folded_keys(name, st
     np.testing.assert_allclose(np.asarray(kept), np.asarray(jnp.stack(rows)), rtol=1e-5, atol=1e-5)
     if name == "ouro":
         np.testing.assert_allclose(np.asarray(decode.exit), tally, rtol=1e-5)
+        return
+    if not hasattr(decode, "loads"):  # a step that adds nothing to a tally: no router, no exit
         return
     loads = np.asarray(decode.loads)
     if drafts:  # the MTP module's row last, which no plain step runs
@@ -1314,6 +1430,16 @@ def test_a_model_without_a_draft_module_refuses_to_draft(name, kind, tmp_path, m
     assert "draft_tokens" not in by_kind(rehearsed(BY_NAME[name]))["TextGenerate"]
     with pytest.raises(Exception, match=f"{kind} has no draft module"):
         GraphExecutor(ExecutionContext()).execute(rehearsed(BY_NAME[name], draft_tokens=1))
+
+
+def test_granite_refuses_to_draft(tmp_path, monkeypatch):
+    """Its committed workflow gives `draft_tokens` 0, which is served;
+    anything else is refused by the model's class, by name."""
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path))
+    model = BY_NAME["granite-4.0-h-micro"]
+    assert by_kind(rehearsed(model))["TextGenerate"]["draft_tokens"] == 0
+    with pytest.raises(Exception, match="GraniteHybrid has no draft module"):
+        GraphExecutor(ExecutionContext()).execute(rehearsed(model, draft_tokens=1))
 
 
 def test_k_exaones_served_share_holds_2_mb_of_rings_and_8_kb_a_position():
