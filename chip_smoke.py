@@ -40,9 +40,12 @@ Drives the main path once, through the entry points an operator uses:
                Solar-Open2's heads (`KDA_DELTA_SHAPES`): the kernel
                (`ops/kda_delta`) against the XLA form, us a (head,
                chunk) of each and the kernel at other heads a step; a
-               Mamba-2 block's chunked scan at Nemotron-3-Nano's sizes
-               (`SSD_SHAPE`) against the recurrence token by token, and
-               a decode step's update of its 23 states; learned sparse
+               Mamba-2 layer's chunked scan at Nemotron-3-Nano's and at
+               granite-4.0-h-micro's sizes (`SSD_SHAPES`): the kernel
+               (`ops/ssd_chunk`) and the XLA form, each against the
+               recurrence token by token, ms a layer of each and the
+               kernel at other heads a step, and a decode step's update
+               of the blocks' states; learned sparse
                attention at GLM-5.2's sizes (`DSA_SHAPE`): a part's
                indexer scores, its selection by `lax.top_k` and by
                bisection, attention over the chosen rows gathered and
@@ -1034,7 +1037,8 @@ def attention_child(rehearsal: bool) -> int:
     failed += not kda_keep_row(rehearsal)
     for shape in REHEARSAL_KDA_DELTA_SHAPES if rehearsal else KDA_DELTA_SHAPES:
         failed += not kda_delta_row(rehearsal, *shape)
-    failed += not ssd_row(rehearsal, *(REHEARSAL_SSD_SHAPE if rehearsal else SSD_SHAPE))
+    for shape in REHEARSAL_SSD_SHAPES if rehearsal else SSD_SHAPES:
+        failed += not ssd_row(rehearsal, *shape)
     failed += not dsa_row(rehearsal, *(REHEARSAL_DSA_SHAPE if rehearsal else DSA_SHAPE))
     return 1 if failed else 0
 
@@ -1338,28 +1342,46 @@ def kda_delta_row(rehearsal: bool, label, tokens, heads, d, chunk) -> bool:
     return row["ok"]
 
 
-# A Mamba-2 block's recurrence in its two forms (`models/mamba2`), as
-# Nemotron-3-Nano's cell runs them: the chunked scan over the 8,192-token
-# prompt (64 heads of 64 over a state of 128, B and C in 8 groups, chunks
-# of 128) and the decode's step over 23 blocks' states inside one jitted
-# loop: (label, tokens, heads, width, groups, state, chunk, blocks).
-SSD_SHAPE = ("nemotron3-nano mamba-2", 8192, 64, 64, 8, 128, 128, 23)
-REHEARSAL_SSD_SHAPE = ("toy mamba-2", 75, 4, 8, 2, 16, 32, 3)
+# A Mamba-2 layer's recurrence in its two forms (`models/mamba2`), as
+# Nemotron-3-Nano's cell runs them (the chunked scan over the 8,192-token
+# prompt: 64 heads of 64 over a state of 128, B and C in 8 groups, chunks
+# of 128; the decode's step over 23 blocks' states inside one jitted
+# loop) and as granite-4.0-h-micro's does (a part of 8,192 of its
+# document: one group, chunks of 256, 36 layers): (label, tokens, heads,
+# width, groups, state, chunk, blocks).
+SSD_SHAPES = (
+    ("nemotron3-nano mamba-2", 8192, 64, 64, 8, 128, 128, 23),
+    ("granite-4.0-h-micro mamba-2", 8192, 64, 64, 1, 128, 256, 36),
+)
+# the second on the lane tile, so that the rehearsal runs the kernel too (interpreted)
+REHEARSAL_SSD_SHAPES = (
+    ("toy mamba-2", 75, 4, 8, 2, 16, 32, 3),
+    ("toy mamba-2 on the lane tile", 200, 4, 64, 2, 128, 128, 2),
+)
+# Heads a grid step, timed beside the plan's: `ops/ssd_chunk.MAX_HEADS`
+# rests on these.
+SSD_SWEEP = (2, 4, 8, 16)
 SSD_TOLERANCE = 2e-2  # bfloat16 operands against the float32 recurrence, of the largest entry
 
 
 def ssd_row(rehearsal: bool, label, tokens, heads, width, groups, n, chunk, blocks) -> bool:
     """The chunked scan over a whole prompt (bfloat16 operands as
-    stored, float32 state) against the recurrence token by token in
-    float32: ms a block's prefill and the largest difference of outputs
-    and final states relative to the largest entry; then a decode step's
-    update of `blocks` states (`ssm_step`, a block an iteration of one
-    jitted loop): us a block and GB/s of the states read and written."""
+    stored, float32 state) in its two forms, alone: the XLA form
+    (`mamba2.ssd_chunked_xla`) and, where the shape has a plan, the
+    kernel (`ops/ssd_chunk.ssd_chunk`), each against the recurrence
+    token by token in float32: ms a layer's prefill and the largest
+    difference of outputs and final states relative to the largest
+    entry, the kernel at other heads a grid step (`sweep_ms`), and which
+    of the two `mamba2.ssd_chunked` takes here (`route`); then a decode
+    step's update of `blocks` states (`ssm_step`, a block an iteration
+    of one jitted loop): us a block and GB/s of the states read and
+    written."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from comfyui_distributed_tpu.models import mamba2
+    from comfyui_distributed_tpu.ops import ssd_chunk
 
     @jax.jit
     def operands(key):
@@ -1373,7 +1395,10 @@ def ssd_row(rehearsal: bool, label, tokens, heads, width, groups, n, chunk, bloc
 
     u, b, c, step, a, state = operands(jax.random.key(tokens + heads))
     low = tuple(t.astype(jnp.bfloat16) for t in (u, b, c))
-    chunked = jax.jit(functools.partial(mamba2.ssd_chunked, chunk=chunk))
+
+    def kernel(block=None):
+        return functools.partial(
+            ssd_chunk.ssd_chunk, chunk=chunk, block=block, interpret=rehearsal)
 
     @jax.jit
     def recurrence(u, b, c, step, a, state):
@@ -1383,15 +1408,28 @@ def ssd_row(rehearsal: bool, label, tokens, heads, width, groups, n, chunk, bloc
         state, y = jax.lax.scan(token, state, (u, b, c, step))
         return y, state
 
-    (y, after), first_s, ms = timed(chunked, *low, step, a, state)
     y_ref, after_ref = recurrence(*(t.astype(jnp.float32) for t in low), step, a, state)
+    plan = ssd_chunk.chunk_plan(heads, width, groups, n, chunk, 2)
     row = {"shape": label, "tokens": tokens, "heads": heads, "width": width, "groups": groups,
-           "state": n, "chunk": chunk, "dtype": "bfloat16",
-           "chunked": {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}, "ok": True}
-    for what, mine, theirs in (("y", y, y_ref), ("state", after, after_ref)):
-        diff = float(jnp.max(jnp.abs(mine - theirs)) / jnp.max(jnp.abs(theirs)))
-        row[f"max_rel_diff_{what}"] = round(diff, 6)
-        row["ok"] &= diff <= SSD_TOLERANCE  # a NaN fails it too
+           "state": n, "chunk": chunk, "dtype": "bfloat16", "heads_a_step": plan,
+           "route": ssd_chunk.ssd_route(heads, width, groups, n, chunk, jnp.bfloat16),
+           "max_rel_diff_y": 0.0, "max_rel_diff_state": 0.0, "ok": True}
+    forms = [("xla", jax.jit(functools.partial(mamba2.ssd_chunked_xla, chunk=chunk)))]
+    if plan:
+        forms.append(("kernel", kernel()))
+    for name, fn in forms:
+        (y, after), first_s, ms = timed(fn, *low, step, a, state)
+        row[name] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
+        for what, mine, theirs in (("y", y, y_ref), ("state", after, after_ref)):
+            diff = float(jnp.max(jnp.abs(mine - theirs)) / jnp.max(jnp.abs(theirs)))
+            row[name][f"max_rel_diff_{what}"] = round(diff, 6)
+            row[f"max_rel_diff_{what}"] = max(row[f"max_rel_diff_{what}"], round(diff, 6))
+            row["ok"] &= diff <= SSD_TOLERANCE  # a NaN fails it too
+    if plan:
+        row["sweep_ms"] = {
+            str(block): round(timed(kernel(block), *low, step, a, state)[2], 3)
+            for block in SSD_SWEEP
+            if block != plan and ssd_chunk.tiles_a_group(block, heads // groups, width)}
 
     @jax.jit
     def step_over_blocks(u, b, c, step, a, states):

@@ -46,6 +46,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops import ssd_chunk
 from .lm_common import short_conv
 
 
@@ -107,11 +108,27 @@ def ssd_chunked(u, b, c, step, a, state, chunk: int):
     P], b and c [T, G, N] in the storage dtype, `step` [T, H] float32,
     `a` [H] float32 (< 0), `state` [H, P, N] float32 before the first
     token. A last chunk that is short is filled with tokens that change
-    nothing (a step of 0). What does not read S is formed for every
-    chunk at once; S is then carried through the chunks by a scan of one
+    nothing (a step of 0). Returns (y [T, H, P] float32, without the
+    skip, and the state after the last token). One algorithm in the form
+    its input allows (`ssd_chunk.ssd_route`: the backend, the dtype, P,
+    N, the groups, the chunk): the kernel of `ops/ssd_chunk.py`, which
+    never writes a chunk's Q x Q weights to HBM, or `ssd_chunked_xla`,
+    with one entry in `ops/attention.route_log` a traced call."""
+    tokens, heads, width = u.shape
+    groups, n = b.shape[1:]
+    form = ssd_chunk.ssd_route(heads, width, groups, n, chunk, u.dtype)
+    ssd_chunk.log_route(form, tokens, heads, width, groups, n, chunk, u.dtype)
+    if form == "kernel":
+        return ssd_chunk.ssd_chunk(u, b, c, step, a, state, chunk=chunk)
+    return ssd_chunked_xla(u, b, c, step, a, state, chunk)
+
+
+def ssd_chunked_xla(u, b, c, step, a, state, chunk: int):
+    """`ssd_chunked` in XLA operations, every backend's form and the
+    kernel's reference. What does not read S is formed for every chunk
+    at once; S is then carried through the chunks by a scan of one
     multiply-add a chunk, and what each token reads of the state that
-    entered its chunk is one more product. Returns (y [T, H, P] float32,
-    without the skip, and the state after the last token)."""
+    entered its chunk is one more product."""
     tokens, heads, width = u.shape
     groups, n = b.shape[1:]
     per, dtype = heads // groups, u.dtype

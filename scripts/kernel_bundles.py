@@ -75,6 +75,26 @@ def _dsa_attend(queries, rows, k, heads, rank, rope):
     return lower
 
 
+def _ssd_chunk(tokens, heads, width, groups, n, chunk):
+    """Lower one `ops/ssd_chunk` call: a Mamba-2 layer's chunked scan
+    over a part of a prompt, bf16 operands and float32 steps and state."""
+    def lower(place):
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from comfyui_distributed_tpu.ops import ssd_chunk
+
+        def f32(shape):
+            return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=place(shape).sharding)
+
+        return functools.partial(ssd_chunk.ssd_chunk, chunk=chunk), (
+            place((tokens, heads, width)), place((tokens, groups, n)), place((tokens, groups, n)),
+            f32((tokens, heads)), f32((heads,)), f32((heads, width, n)))
+    return lower
+
+
 # label -> (kernel's name in the compiled program, lowering). The labels
 # are `chip_smoke.SERVED_SHAPES` / `CAUSAL_SHAPES`', so a bundle count and
 # the chip's time of `chip_smoke.py --legs attention` read side by side.
@@ -90,6 +110,8 @@ CASES = {
     "deepseek-v2 mla 2048": (
         "flash_attention_causal", _attention((1, 2048, 128, 192), v_width=128, causal=True)),
     "glm-5.2 dsa": ("dsa_attend", _dsa_attend(2048, 32896, 2048, 64, 512, 64)),
+    "granite-4.0-h-micro mamba-2": ("ssd_chunk", _ssd_chunk(8192, 64, 64, 1, 128, 256)),
+    "nemotron3-nano mamba-2": ("ssd_chunk", _ssd_chunk(8192, 64, 64, 8, 128, 128)),
 }
 DEFAULT = ("sd15 self 64x64", "flux joint 4608", "solar / k-exaone full 8192")
 

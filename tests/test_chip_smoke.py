@@ -225,25 +225,50 @@ def test_the_legs_have_the_sixth_models_rows_at_its_own_numbers(smoke):
         (text,) = [node["inputs"]["text"] for node in json.load(fh).values()
                    if node["class_type"] == "TextGenerate"]
     assert tokens == 1 + len(text.encode("utf-8"))
-    assert smoke.SSD_SHAPE[1:] == (
+    assert smoke.SSD_SHAPES[0][1:] == (
         tokens, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.ssm_state_size,
         cfg.chunk_size, len(cfg.blocks_of("M")))
 
 
-def test_the_mamba_row_holds_the_chunked_scan_to_the_recurrence(smoke, capsys):
-    """The rehearsal's toy row on the CPU: a length that is no whole
+def test_the_second_mamba_row_is_a_part_of_the_granite_cells_prompt(smoke):
+    """granite-4.0-h-micro's row (PR 55): a part of its document through
+    one Mamba-2 layer at the registry's widths, one group and chunks of
+    256, and the decode's step over its 36 states; the kernel's plan
+    takes both cells' shapes at eight heads a grid step."""
+    from comfyui_distributed_tpu.models.registry import get_config
+    from comfyui_distributed_tpu.ops import ssd_chunk
+
+    cfg = get_config("granite-4.0-h-micro")
+    assert smoke.SSD_SHAPES[1] == (
+        "granite-4.0-h-micro mamba-2", cfg.prefill_part, cfg.mamba_n_heads, cfg.mamba_d_head,
+        cfg.mamba_n_groups, cfg.mamba_d_state, cfg.mamba_chunk_size,
+        sum(kind == "mamba" for kind in cfg.layer_types))
+    for _, _, heads, width, groups, n, chunk, _ in smoke.SSD_SHAPES:
+        assert ssd_chunk.chunk_plan(heads, width, groups, n, chunk, 2) == ssd_chunk.MAX_HEADS
+    assert set(smoke.SSD_SWEEP) >= {ssd_chunk.MAX_HEADS // 2, ssd_chunk.MAX_HEADS}
+
+
+@pytest.mark.parametrize("which, forms", [(0, {"xla"}), (1, {"xla", "kernel"})])
+def test_the_mamba_row_holds_the_chunked_scan_to_the_recurrence(smoke, capsys, which, forms):
+    """The rehearsal's toy rows on the CPU: a length that is no whole
     number of chunks, bfloat16 operands against the float32 recurrence,
-    and a step over three blocks' states."""
+    and a step over the blocks' states; the second row's widths are on
+    the lane tile, so it holds the kernel (interpreted) to the
+    recurrence too, though the CPU's route stays the XLA form."""
     import jax
 
     if jax.default_backend() != "cpu":
         pytest.skip("the rehearsal's row is the CPU's")
-    assert smoke.ssd_row(True, *smoke.REHEARSAL_SSD_SHAPE)
+    shape = smoke.REHEARSAL_SSD_SHAPES[which]
+    assert smoke.ssd_row(True, *shape)
     (row,) = _result_lines(capsys.readouterr().out)
     assert row["ok"] and row["max_rel_diff_y"] < smoke.SSD_TOLERANCE
     assert row["max_rel_diff_state"] < smoke.SSD_TOLERANCE
+    assert row["route"] == "xla" and forms == {"xla", "kernel"} & set(row)
+    for form in forms:
+        assert row[form]["max_rel_diff_y"] < smoke.SSD_TOLERANCE
     assert set(row["step"]) == {"blocks", "first_call_s", "us_a_block", "gb_per_s"}
-    assert row["step"]["blocks"] == 3
+    assert row["step"]["blocks"] == shape[-1]
 
 
 def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys):

@@ -432,6 +432,42 @@ def test_kda_delta_compiles_for_v5e_at_the_prefills_shapes(
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+@pytest.mark.parametrize("label,tokens,heads,width,groups,n,chunk,block", [
+    (*shape[:-1], block) for shape in chip_smoke.SSD_SHAPES for block in chip_smoke.SSD_SWEEP
+    if block <= shape[2] // shape[4]])
+def test_ssd_chunk_compiles_for_v5e_at_the_prefills_shapes(
+        one_chip, label, tokens, heads, width, groups, n, chunk, block):
+    """A Mamba-2 layer's chunked scan over a part of the cells' prompts
+    (`ops/ssd_chunk`), at Nemotron-3-Nano's eight groups and chunks of
+    128 and at granite-4.0-h-micro's one group and chunks of 256,
+    between the `[T, H P]` array a model's convolution gives and the one
+    its gated norm takes: one kernel, no copy of u in front of it, and
+    beside it only the steps' running sums and the state turned round
+    (megabytes: no `[chunks, H, Q, Q]` array of weights, 537 MB at
+    granite's sizes). At every count of heads a grid step that
+    `chip_smoke.ssd_row` times, the plan's among them: a slice that
+    compiled at eight heads a step failed on the chip at two (PR 55)."""
+    from comfyui_distributed_tpu.ops import ssd_chunk
+
+    def call(u, b, c, step, a, state):
+        y, state = ssd_chunk.ssd_chunk(
+            u.reshape(tokens, heads, width), b.reshape(tokens, groups, n),
+            c.reshape(tokens, groups, n), step, a, state, chunk=chunk, block=block)
+        return y.reshape(tokens, heads * width), state
+
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(call).lower(
+        place((tokens, heads * width), jnp.bfloat16),
+        *(place((tokens, groups * n), jnp.bfloat16) for _ in range(2)),
+        place((tokens, heads), jnp.float32), place((heads,), jnp.float32),
+        place((heads, width, n), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%ssd_chunk" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+
+
 def test_ling_flash_prefill_walks_its_six_kda_layers_in_the_kernel(one_chip, monkeypatch):
     """Ling-3.0-flash's whole prefill at the cell's 8,192 tokens, routed
     as a TPU routes it: the delta rule of each of the six KDA layers is
@@ -558,9 +594,9 @@ def test_granite_prefill_in_parts_attends_64_wide_heads_in_the_kernel_and_its_de
     holds, for each of the four attention layers, a causal kernel call a
     possible count of keys (32 in all), 64-wide heads padded to the lane
     tile; what it holds beside its arguments is a part's working set
-    (2.0 GB: a Mamba layer's chunk weights, a SwiGLU's middle, a part's
-    folded keys), not the prompt's. The decode carries the donated tree
-    of four caches (537.9 MB) and 36 states and tails (76.4 MB), a leaf a
+    (1.8 GB: a SwiGLU's middle, a part's folded keys; a Mamba layer's
+    chunk weights stay in VMEM, `ops/ssd_chunk`), not the prompt's. The
+    decode carries the donated tree of four caches (537.9 MB) and 36 states and tails (76.4 MB), a leaf a
     layer, through its loop and copies none of them."""
     import math
     import re
@@ -578,15 +614,21 @@ def test_granite_prefill_in_parts_attends_64_wide_heads_in_the_kernel_and_its_de
             cfg, params, jax.ShapeDtypeStruct((65536,), jnp.int32, sharding=one_chip),
             cache_len=65664,
         ).compile()
-    assert routes == [
+    assert [r for r in routes if r.startswith("flash-")] == [
         f"flash-causal 8192x{8192 * k}x64/64 g4 bq512 bk1024 bf16 blocks{128 * k - 56}/{128 * k}"
         for k in range(1, 9)] * 4
+    # the 36 Mamba layers' chunked scans in the kernel of `ops/ssd_chunk` (PR 55)
+    assert {r for r in routes if not r.startswith("flash-")} == {
+        "ssd-kernel 8192x64x64 g1 n128 c256 hb8 bf16"}
     memory = prefill.memory_analysis()
-    assert memory.temp_size_in_bytes < 2.4e9      # 1.98 GB: a part's, whatever the parts' number
+    assert memory.temp_size_in_bytes < 2.0e9      # 1.78 GB: a part's, whatever the parts' number
     assert memory.output_size_in_bytes >= 65664 * 8192 + 76_437_504
     text = prefill.as_text()
-    assert " while(" in text and text.count('custom_call_target="tpu_custom_call"') == 32
+    assert " while(" in text and text.count('custom_call_target="tpu_custom_call"') == 32 + 36
     assert "%flash_attention_causal" in text
+    assert len(re.findall(r"%ssd_chunk[.\d]* = ", text)) == 36
+    # no chunk's weights in HBM: 32 x 64 x 256 x 256 entries a layer and part
+    assert "[32,1,64,256,256]" not in text and "[32,64,256,256]" not in text
 
     state = jax.tree.map(place, granite_hybrid.state_shapes(cfg, 65664, jnp.bfloat16))
     scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)

@@ -806,8 +806,10 @@ MODELS = [
         drawn_check=nemotron_drawn,
         wait_bytes=4 * (16 + 2 * 6 * 2),  # nothing of the state tree leaves the device
         # the decode's one attention block (the einsum form, one key head serving four
-        # queries), then the prefill's; the state-space blocks log no route
-        attention="decode-xla 4x8208x16, xla-causal 8192x8192x16/16 bq256 f32",
+        # queries), then the prefill's state-space blocks' chunked scan (PR 55: widths
+        # off the lane tile, never the kernel) and its attention block
+        attention=("decode-xla 4x8208x16, ssd-xla 8192x4x8 g2 n16 c32 f32, "
+                   "xla-causal 8192x8192x16/16 bq256 f32"),
         passes=lambda attrs: (8192 * 13, 16 * 13),
         widths={
             "hidden_size": 2688, "num_hidden_layers": 52, "mamba_num_heads": 64,
@@ -829,7 +831,8 @@ MODELS = [
                  "batch is 1", "house style guide", "no MTP module"),
         published=nemotron_published, entry=nemotron_entry, check_workflow=nemotron_workflow,
         metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "ssm_device_pct.lm",
-                           "expert_matvec_hbm_pct.lm", "attn_device_pct.lm"}),
+                           "ssd_device_pct.lm", "expert_matvec_hbm_pct.lm",
+                           "attn_device_pct.lm"}),
     ),
     Model(
         name="glm-5.2", served="glm-5.2-ep16-5l", tiny="tiny-glm-dsa",
@@ -910,9 +913,10 @@ MODELS = [
         drawn=frozenset(), drawn_check=lambda attrs: None,
         wait_bytes=4 * 16,  # the ids: the model reads nothing else back
         # the decode's two attention layers (the einsum form, a key head serving two
-        # queries), then a causal call a part of the prompt, each over the keys so far
+        # queries), a part's chunked scans (PR 55: the XLA form at these widths), then a
+        # causal call a part of the prompt, each over the keys so far
         attention=", ".join(sorted(
-            ["decode-xla 4x272x16"]
+            ["decode-xla 4x272x16", "ssd-xla 16x4x8 g1 n16 c8 f32"]
             + [f"xla-causal 16x{16 * (part + 1)}x16/16 bq16 f32" for part in range(16)])),
         passes=lambda attrs: (256 * 8, 16 * 8),
         widths={
@@ -932,8 +936,9 @@ MODELS = [
                  "seeded random", "drawn as a head is", "hard-wired", "stand-in",
                  "ids 0-256 of 100,352", "batch is 1", "long document", "no MTP module"),
         published=granite_published, entry=granite_entry, check_workflow=granite_workflow,
-        metrics=frozenset({"state_mb.lm", "ssm_device_pct.lm", "attn_device_pct.lm",
-                           "mlp_device_pct.lm", "flash_attention_causal_roofline_pct.lm"}),
+        metrics=frozenset({"state_mb.lm", "ssm_device_pct.lm", "ssd_device_pct.lm",
+                           "attn_device_pct.lm", "mlp_device_pct.lm",
+                           "flash_attention_causal_roofline_pct.lm"}),
     ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
