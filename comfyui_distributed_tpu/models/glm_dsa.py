@@ -29,11 +29,11 @@ The prefill is one program that reads the prompt in parts of
 and indexer keys written into the caches at its positions, its queries
 over the caches as the parts before left them, so the indexer's scores,
 the expert ladder and the activations are a part's and not the prompt's.
-The whole parts are one `lax.scan` body over caches of full length; what
-is left of the prompt is a body of its own.
+The whole parts are one scanned body over caches of full length; what
+is left of the prompt is a body of its own (`lm_common.prefill_in_parts`).
 
-The decode is K-EXAONE's: `draft_tokens` 0, `steps` one-token steps; 1, a
-`while_loop` whose step drafts with the module (its own indexer over its
+The decode is K-EXAONE's: `draft_tokens` 0, `steps` one-token steps; 1,
+`lm_common.draft_loop`, whose step drafts with the module (its own indexer over its
 own cache), runs the last token and the draft through the main model as
 two positions, each with its own S_t, and emits one or two tokens by
 `lm_common.verify`. What a dropped draft wrote in either cache the next
@@ -61,16 +61,19 @@ from .lm_common import (
     apply_rope_pairs,
     count_params,
     decode_loop,
+    draft_loop,
+    drafting_report,
+    drafts,
     head,
     init_from_shapes,
     mlp_shapes,
     mtp_input,
     nbytes,
+    parts_of,
+    prefill_in_parts,
     rms_norm,
     rope_tables,
-    sample,
     swiglu,
-    verify,
     zeros,
 )
 from .moe import decode_route, expert_layer, report_loads, sigmoid_route
@@ -396,11 +399,6 @@ class Decode(NamedTuple):
     kept: dict | None   # under `collect`: see `decode`
 
 
-def parts_of(cfg, tokens: int) -> tuple[int, int]:
-    """(whole parts of `prefill_part` positions, positions left over)."""
-    return divmod(tokens, cfg.prefill_part)
-
-
 def _part(cfg, params, cache, ids, after, start, collect: bool):
     """One part of the prompt, `ids` [P] from position `start`, `after`
     [P] the tokens that follow each, through every layer over the
@@ -432,9 +430,9 @@ def _rows_in_order(parts):
 
 @partial(jax.jit, static_argnames=("cfg", "cache_len", "collect"))
 def prefill(cfg: GlmDsaConfig, params, ids, *, cache_len: int, collect: bool = False):
-    """The prompt `ids` [T] in parts (`parts_of`): the whole parts one
-    scanned body, what is left a body of its own, each over the caches
-    as the parts before left them. Returns the logits at the last
+    """The prompt `ids` [T] in parts (`prefill_in_parts`): the whole parts
+    one scanned body, what is left a body of its own, each over the
+    caches as the parts before left them. Returns the logits at the last
     position, the request's state (allocated here, once), a part's pairs
     on each held expert and keys seen and, under `collect` (the parity
     check's), `kept`: `chosen` [sparse layers, T, k] the experts chosen
@@ -443,29 +441,17 @@ def prefill(cfg: GlmDsaConfig, params, ids, *, cache_len: int, collect: bool = F
     Position T - 1 of the MTP module's caches has no next token yet and
     is written from token 0; the decode's first step writes it again
     before anything reads it."""
-    tokens, part = ids.shape[0], cfg.prefill_part
-    whole, left = parts_of(cfg, tokens)
     after = jnp.concatenate([ids[1:], jnp.zeros((1,), ids.dtype)])
     cache = zeros(state_shapes(cfg, cache_len, params["embed"].dtype))
-    outs = []
-    if whole:
-        def body(cache, xs):
-            return _part(cfg, params, cache, *xs, collect)
 
-        cut = whole * part
-        cache, out = jax.lax.scan(body, cache, (
-            ids[:cut].reshape(whole, part), after[:cut].reshape(whole, part),
-            jnp.arange(whole) * part))
-        outs.append(out)
-    if left:
-        cache, out = _part(
-            cfg, params, cache, ids[tokens - left:], after[tokens - left:],
-            jnp.int32(tokens - left), collect)
-        outs.append(jax.tree_util.tree_map(lambda a: a[None], out))
-    h, loads, keys = (jnp.concatenate(a) for a in zip(*(out[:3] for out in outs)))
-    kept = jax.tree_util.tree_map(
-        lambda *a: jnp.concatenate([_rows_in_order(x) for x in a], axis=-2),
-        *(out[3] for out in outs)) if collect else None
+    def part(cache, cuts, start, ends):
+        cache, (*out, kept) = _part(cfg, params, cache, *cuts, start, collect)
+        # a part's outputs have one shape: what is left over keeps its rows filled up to a part's
+        fill = [(0, 0), (0, cfg.prefill_part - cuts[0].shape[0]), (0, 0)]
+        return cache, (*out, jax.tree_util.tree_map(lambda a: jnp.pad(a, fill[-a.ndim:]), kept))
+
+    cache, (h, loads, keys, kept) = prefill_in_parts(part, cache, (ids, after), cfg.prefill_part)
+    kept = jax.tree_util.tree_map(lambda a: _rows_in_order(a)[..., :ids.shape[0], :], kept)
     cache["h"] = h[-1]
     return Prefill(head(cfg, params, h[-1:])[0], cache, loads, keys, kept)
 
@@ -518,77 +504,29 @@ def _decode_plain(cfg, params, cache, logits, start, key, temperature, steps, co
 
 
 def _decode_drafting(cfg, params, cache, logits, start, key, temperature, steps, collect):
-    """The self-speculative loop, K-EXAONE's. Before a step the main
-    model's state holds positions 0 .. n - 1, x_n is the last emitted
-    token, and `waiting` of the newest confirmed positions (their
-    residual streams `h`, the tokens that follow them `after`) have not
-    been through the MTP module yet: one after a rejection, two after a
-    kept draft."""
-    layers, held = cfg.sparse_layers, len(cfg.held_experts)
-    first = sample(logits, jax.random.fold_in(key, 0), temperature)
+    """The self-speculative loop (`lm_common.draft_loop`) over this
+    model's two steps, which also say the keys they saw and, under
+    `collect`, their selections (of the module's the loop keeps the row
+    the draft was drawn from); nothing of the state waits on a draft's
+    fate."""
 
-    def body(c):
-        cache, emitted, step = c["cache"], c["emitted"], c["counts"][0]
-        n = start + emitted - 1  # x_n's position
-        key_draft, key_verify = jax.random.split(jax.random.fold_in(key, step + 1))
-        with jax.named_scope("mtp"):
-            # the second row is of no confirmed position where one waits:
-            # what it writes at n the next step writes over
-            drafts, cache, loads_mtp, selection_mtp, keys_mtp = mtp_step(
-                cfg, params, cache, c["h"], c["after"], n - c["waiting"])
-            draft_logits = drafts[c["waiting"] - 1]
-            draft = sample(draft_logits, key_draft, temperature)
-        rows, h, cache, chosen, loads_main, selections, keys_main = main_step(
-            cfg, params, cache, jnp.stack([c["last"], draft]), n)
-        with jax.named_scope("verify"):
-            accepted, one, two = verify(rows, draft_logits, draft, key_verify, temperature)
-            ids = c["ids"].at[emitted].set(one)
-            # a second token that would be one too many is not written
-            ids = ids.at[jnp.where(accepted, emitted + 1, steps)].set(two, mode="drop")
-            read = jnp.count_nonzero(loads_main) + jnp.count_nonzero(loads_mtp)
-            counts = c["counts"] + jnp.stack([1, 1, accepted, read]).astype(jnp.int32)
-        kept = c["kept"]
-        if collect:
-            now = {
-                "logits": rows, "draft_logits": draft_logits, "chosen": chosen,
-                "selections": _kept(cfg, cache, selections),
-                "draft_selection": jax.tree_util.tree_map(
-                    lambda a: a[c["waiting"] - 1], _kept(cfg, cache, [selection_mtp])[0]),
-                "position": n, "accepted": accepted,
-            }
-            kept = jax.tree_util.tree_map(lambda all_, one_: all_.at[step].set(one_), kept, now)
-        return {
-            "cache": cache, "ids": ids, "emitted": emitted + 1 + accepted,
-            "last": jnp.where(accepted, two, one), "h": h, "after": jnp.stack([one, two]),
-            "waiting": 1 + accepted.astype(jnp.int32),
-            "loads": c["loads"].at[:layers].add(loads_main).at[layers].add(loads_mtp),
-            "keys": c["keys"] + jnp.concatenate([keys_main, keys_mtp], axis=1),
-            "counts": counts, "kept": kept,
-        }
+    def drafted(cache, h, tokens, position):
+        rows, cache, loads, selection, keys = mtp_step(cfg, params, cache, h, tokens, position)
+        kept = {"draft_selection": _kept(cfg, cache, [selection])[0]} if collect else None
+        return rows, None, cache, (loads, jnp.count_nonzero(loads), keys), kept
 
-    most = max(steps - 1, 1)  # steps the loop may take: each emits at least one token
-    k, top = cfg.num_experts_per_tok, min(cfg.index_topk, cache["latents"][0].shape[0])
-    selection = (jnp.zeros((most, 2, top), jnp.int32), jnp.zeros((most, 2, top), bool))
-    kept = {
-        "logits": jnp.zeros((most, 2, cfg.vocab_held), jnp.float32),
-        "draft_logits": jnp.zeros((most, cfg.vocab_held), jnp.float32),
-        "chosen": jnp.zeros((most, layers, 2, k), jnp.int32),
-        "selections": (selection,) * cfg.full_layers,
-        "draft_selection": jax.tree_util.tree_map(lambda a: a[:, 0], selection),
-        "position": jnp.full((most,), -1, jnp.int32),
-        "accepted": jnp.zeros((most,), bool),
-    } if collect else None
-    done = jax.lax.while_loop(lambda c: c["emitted"] < steps, body, {
-        "cache": cache, "ids": jnp.zeros((steps,), jnp.int32).at[0].set(first),
-        "emitted": jnp.int32(1), "last": first,
-        "h": jnp.stack([cache["h"], jnp.zeros_like(cache["h"])]),
-        "after": jnp.stack([first, jnp.int32(0)]), "waiting": jnp.int32(1),
-        "loads": jnp.zeros((layers + 1, held), jnp.int32),
-        "keys": jnp.zeros((2, cfg.num_hidden_layers + 1), jnp.int32),
-        "counts": jnp.zeros((4,), jnp.int32), "kept": kept,
-    })
-    return Decode(
-        done["ids"], done["loads"], done["counts"], done["keys"], done["cache"], done["kept"])
+    def verified(cache, tokens, position):
+        rows, h, cache, chosen, loads, selections, keys = main_step(
+            cfg, params, cache, tokens, position)
+        kept = {"chosen": chosen, "selections": _kept(cfg, cache, selections)} if collect else None
+        return rows, h, cache, (loads, jnp.count_nonzero(loads), keys), kept
+
+    cache, ids, counts, ((loads_mtp, read_mtp, keys_mtp), (loads, read, keys)), kept = draft_loop(
+        drafted, verified, cache, logits, start, key, temperature, steps)
+    loads = jnp.concatenate([loads, loads_mtp[None]])  # the MTP module's row
+    keys = jnp.concatenate([keys, keys_mtp], axis=1)
+    counts = jnp.concatenate([counts, (read + read_mtp).astype(jnp.int32)[None]])
+    return Decode(ids, loads, counts, keys, cache, kept)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps", "collect", "draft_tokens"),
@@ -597,8 +535,8 @@ def decode(cfg: GlmDsaConfig, params, cache, logits, start, key, temperature, *,
            steps: int, collect: bool = False, draft_tokens: int = 0):
     """`steps` ids in one program, from the prefill's `logits` at
     position `start - 1`; no early stop. With `draft_tokens` 0 that is
-    `steps` one-token steps; with 1 the self-speculative loop, a
-    `while_loop` with no trip to the host. The state tree is donated,
+    `steps` one-token steps; with 1 the self-speculative loop, on
+    the device with no trip to the host. The state tree is donated,
     carried through the loop and handed back. Returns the ids, the pairs
     on each held expert, `counts`, the keys seen and, under `collect`, per
     step: the main model's logits, the experts chosen, the `full`
@@ -606,9 +544,7 @@ def decode(cfg: GlmDsaConfig, params, cache, logits, start, key, temperature, *,
     drawn from with the module's selection for it, the step's position n
     and whether its draft was kept (the logits' row 0 is position n's,
     row 1 the draft's at n + 1)."""
-    if draft_tokens not in (0, 1):
-        raise ValueError(f"this model's MTP module drafts one token a step, not {draft_tokens}")
-    run = _decode_drafting if draft_tokens else _decode_plain
+    run = _decode_drafting if drafts(draft_tokens) else _decode_plain
     return run(cfg, params, dict(cache), logits, start, key, temperature, steps, collect)
 
 
@@ -656,11 +592,9 @@ class GlmDsa(LanguageModel):
         the routing as `moe.report_loads` has it, the prefill's ladder
         read a part."""
         cfg = self.cfg
-        steps, drafted, accepted, read = (int(n) for n in counts)
-        width = 2 if drafted else 1  # positions a step runs
-        mtp = 1 if drafted else 0    # and whether the module's layer is among its bodies
-        pairs = steps * width * cfg.num_experts_per_tok * (cfg.sparse_layers + mtp)
-        whole, left = parts_of(cfg, prompt_tokens)
+        width, drafting = drafting_report(
+            counts, cfg.num_experts_per_tok, cfg.sparse_layers, cfg.num_hidden_layers)
+        whole, left = parts_of(prompt_tokens, cfg.prefill_part)
         lengths = [cfg.prefill_part] * whole + [left] * bool(left)
         by_part = [
             report_loads(
@@ -686,9 +620,6 @@ class GlmDsa(LanguageModel):
             "keys_visible": visible, "keys_selected": selected,
             "prefill_sparse_attention_form": dsa.form(min(prompt_tokens, cfg.prefill_part)),
             "decode_sparse_attention_form": dsa.form(width),
-            "decode_routed_pairs": pairs, "decode_expert_rows": pairs,
-            "decode_steps": steps, "mtp_drafted": drafted, "mtp_accepted": accepted,
             "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,
-            "decode_layer_passes": steps * width * (cfg.num_hidden_layers + mtp),
-            "decode_experts_read": read,
+            **drafting,
         }

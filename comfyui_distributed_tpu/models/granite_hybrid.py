@@ -20,8 +20,8 @@ position comes from the Mamba layers around them. No experts, no MTP
 module.
 
 **The prefill reads the prompt in parts** of `prefill_part` positions
-(`parts_of`), as `glm_dsa.prefill` does: the whole parts are one
-`lax.scan` body, what is left over a body of its own. A part goes through
+(`lm_common.prefill_in_parts`), as `glm_dsa.prefill` does: the whole parts
+are one scanned body, what is left over a body of its own. A part goes through
 all 40 layers. A Mamba layer enters it with the float32 matrix state and
 the convolution tail the part before left (`mamba2.mixer` takes both),
 so 65,536 tokens hold a part's chunk weights and SwiGLU middle at a time
@@ -68,6 +68,8 @@ from .lm_common import (
     init_from_shapes,
     mlp_shapes,
     nbytes,
+    parts_of,
+    prefill_in_parts,
     rms_norm,
     swiglu,
     zeros,
@@ -363,11 +365,6 @@ class Decode(NamedTuple):
     logits: jax.Array | None  # [steps, vocab] float32, after id i; under `collect`
 
 
-def parts_of(cfg, tokens: int) -> tuple[int, int]:
-    """(whole parts of `prefill_part` positions, positions left over)."""
-    return divmod(tokens, cfg.prefill_part)
-
-
 def _part(cfg, params, cache, ids, start, key_counts: tuple):
     """One part of the prompt, `ids` [P] from position `start`, through
     every layer over the state the parts before left. Returns (cache,
@@ -379,30 +376,21 @@ def _part(cfg, params, cache, ids, start, key_counts: tuple):
 
 @partial(jax.jit, static_argnames=("cfg", "cache_len", "collect"))
 def prefill(cfg: GraniteHybridConfig, params, ids, *, cache_len: int, collect: bool = False):
-    """The prompt `ids` [T] in parts (`parts_of`): the whole parts one
-    scanned body, what is left a body of its own, each over the state as
-    the parts before left it. Returns the logits at the last position and
+    """The prompt `ids` [T] in parts (`prefill_in_parts`): the whole parts
+    one scanned body, what is left a body of its own, each over the state
+    as the parts before left it. Returns the logits at the last position and
     the request's state (allocated here, once). `collect` keeps nothing
     more: what the parity check compares of a prefill, the states and the
     keys and values, is the state."""
     del collect
-    tokens, part = ids.shape[0], cfg.prefill_part
-    whole, left = parts_of(cfg, tokens)
     cache = zeros(state_shapes(cfg, cache_len, params["embed"].dtype))
-    if whole:
-        counts = tuple(part * (i + 1) for i in range(whole))
 
-        def body(cache, xs):
-            cache, h = _part(cfg, params, cache, *xs, counts)
-            return cache, h[-1]
+    def part(cache, cuts, start, ends):
+        cache, h = _part(cfg, params, cache, *cuts, start, ends)
+        return cache, h[-1]
 
-        cache, lasts = jax.lax.scan(
-            body, cache, (ids[:whole * part].reshape(whole, part), jnp.arange(whole) * part))
-        last = lasts[-1]
-    if left:
-        cache, h = _part(cfg, params, cache, ids[tokens - left:], tokens - left, (tokens,))
-        last = h[-1]
-    return Prefill(head(cfg, params, last[None])[0], cache)
+    cache, lasts = prefill_in_parts(part, cache, (ids,), cfg.prefill_part)
+    return Prefill(head(cfg, params, lasts[-1:])[0], cache)
 
 
 def decode_step(cfg, params, cache, token, position):
@@ -463,7 +451,7 @@ class GraniteHybrid(LanguageModel):
         Mamba layer's prefill walked over them (a part's last chunk may
         be short)."""
         cfg = self.cfg
-        whole, left = parts_of(cfg, prompt_tokens)
+        whole, left = parts_of(prompt_tokens, cfg.prefill_part)
         lengths = [cfg.prefill_part] * whole + [left] * bool(left)
         return {
             **self.describe(cache_len),

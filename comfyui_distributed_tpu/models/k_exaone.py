@@ -32,7 +32,7 @@ stream of the last position the MTP module has not seen yet. The prefill
 allocates it, the decode takes it by donation and hands it back.
 
 The decode is one program either way. `draft_tokens` 0: `steps`
-one-token steps. `draft_tokens` 1: a `while_loop` whose step drafts one
+one-token steps. `draft_tokens` 1: `lm_common.draft_loop`, whose step drafts one
 token with the MTP module, runs the last emitted token and the draft
 through the main model as two positions, keeps the draft with
 probability min(1, p / q) (else draws from the renormalised max(p - q,
@@ -65,6 +65,9 @@ from .lm_common import (
     apply_rope,
     count_params,
     decode_loop,
+    draft_loop,
+    drafting_report,
+    drafts,
     head,
     init_from_shapes,
     mlp_shapes,
@@ -72,9 +75,7 @@ from .lm_common import (
     nbytes,
     rms_norm,
     rope_tables,
-    sample,
     swiglu,
-    verify,
     zeros,
 )
 from .moe import decode_route, expert_layer, report_loads, sigmoid_route
@@ -443,68 +444,23 @@ def _decode_plain(cfg, params, cache, logits, start, key, temperature, steps, co
 
 
 def _decode_drafting(cfg, params, cache, logits, start, key, temperature, steps, collect):
-    """The self-speculative loop. Before a step the main model's state
-    holds positions 0 .. n - 1, x_n is the last emitted token, and
-    `waiting` of the newest confirmed positions (their residual streams
-    `h`, the tokens that follow them `after`) have not been through the
-    MTP module yet: one after a rejection, two after a kept draft."""
-    layers, k, held = cfg.sparse_layers, cfg.num_experts_per_tok, len(cfg.held_experts)
-    first = sample(logits, jax.random.fold_in(key, 0), temperature)
+    """The self-speculative loop (`lm_common.draft_loop`) over this
+    model's two steps; nothing of the state waits on a draft's fate."""
 
-    def body(c):
-        cache, emitted, step = c["cache"], c["emitted"], c["counts"][0]
-        n = start + emitted - 1  # x_n's position
-        key_draft, key_verify = jax.random.split(jax.random.fold_in(key, step + 1))
-        with jax.named_scope("mtp"):
-            # the second row is of no confirmed position where one waits:
-            # what it writes at n the next step writes over
-            drafts, cache, _, loads_mtp = mtp_step(
-                cfg, params, cache, c["h"], c["after"], n - c["waiting"])
-            draft_logits = drafts[c["waiting"] - 1]
-            draft = sample(draft_logits, key_draft, temperature)
-        rows, h, cache, chosen, loads_main = main_step(
-            cfg, params, cache, jnp.stack([c["last"], draft]), n)
-        with jax.named_scope("verify"):
-            accepted, one, two = verify(rows, draft_logits, draft, key_verify, temperature)
-            ids = c["ids"].at[emitted].set(one)
-            # a second token that would be one too many is not written
-            ids = ids.at[jnp.where(accepted, emitted + 1, steps)].set(two, mode="drop")
-            read = jnp.count_nonzero(loads_main) + jnp.count_nonzero(loads_mtp)
-            counts = c["counts"] + jnp.stack([1, 1, accepted, read]).astype(jnp.int32)
-        kept = c["kept"]
-        if collect:
-            kept = {
-                "logits": kept["logits"].at[step].set(rows),
-                "draft_logits": kept["draft_logits"].at[step].set(draft_logits),
-                "chosen": kept["chosen"].at[step].set(chosen),
-                "position": kept["position"].at[step].set(n),
-                "accepted": kept["accepted"].at[step].set(accepted),
-            }
-        return {
-            "cache": cache, "ids": ids, "emitted": emitted + 1 + accepted,
-            "last": jnp.where(accepted, two, one), "h": h, "after": jnp.stack([one, two]),
-            "waiting": 1 + accepted.astype(jnp.int32),
-            "loads": c["loads"].at[:layers].add(loads_main).at[layers].add(loads_mtp),
-            "counts": counts, "kept": kept,
-        }
+    def drafted(cache, h, tokens, position):
+        rows, cache, _, loads = mtp_step(cfg, params, cache, h, tokens, position)
+        return rows, None, cache, (loads, jnp.count_nonzero(loads)), None
 
-    most = max(steps - 1, 1)  # steps the loop may take: each emits at least one token
-    kept = {
-        "logits": jnp.zeros((most, 2, cfg.vocab_held), jnp.float32),
-        "draft_logits": jnp.zeros((most, cfg.vocab_held), jnp.float32),
-        "chosen": jnp.zeros((most, layers, 2, k), jnp.int32),
-        "position": jnp.full((most,), -1, jnp.int32),
-        "accepted": jnp.zeros((most,), bool),
-    } if collect else None
-    done = jax.lax.while_loop(lambda c: c["emitted"] < steps, body, {
-        "cache": cache, "ids": jnp.zeros((steps,), jnp.int32).at[0].set(first),
-        "emitted": jnp.int32(1), "last": first,
-        "h": jnp.stack([cache["h"], jnp.zeros_like(cache["h"])]),
-        "after": jnp.stack([first, jnp.int32(0)]), "waiting": jnp.int32(1),
-        "loads": jnp.zeros((layers + 1, held), jnp.int32),
-        "counts": jnp.zeros((4,), jnp.int32), "kept": kept,
-    })
-    return Decode(done["ids"], done["loads"], done["counts"], done["cache"], done["kept"])
+    def verified(cache, tokens, position):
+        rows, h, cache, chosen, loads = main_step(cfg, params, cache, tokens, position)
+        kept = {"chosen": chosen} if collect else None
+        return rows, h, cache, (loads, jnp.count_nonzero(loads)), kept
+
+    cache, ids, counts, ((loads_mtp, read_mtp), (loads, read)), kept = draft_loop(
+        drafted, verified, cache, logits, start, key, temperature, steps)
+    loads = jnp.concatenate([loads, loads_mtp[None]])  # the MTP module's row
+    counts = jnp.concatenate([counts, (read + read_mtp).astype(jnp.int32)[None]])
+    return Decode(ids, loads, counts, cache, kept)
 
 
 @partial(jax.jit, static_argnames=("cfg", "steps", "collect", "draft_tokens"),
@@ -515,17 +471,15 @@ def decode(cfg: KExaoneConfig, params, cache, logits, start, key, temperature, *
     position `start - 1`; no early stop. With `draft_tokens` 0 that is
     `steps` one-token steps (draw id i from the logits, run it through
     the model at `start + i`); with 1 the self-speculative loop, which
-    takes as many steps as its drafts' fates make it, a `while_loop`
-    with no trip to the host. The state tree is donated, carried through
+    takes as many steps as its drafts' fates make it, a loop on the
+    device with no trip to the host. The state tree is donated, carried through
     the loop and handed back. Returns the ids, the pairs on each held
     expert, `counts` and, under `collect`, per step: the main model's
     logits, the experts chosen and, when drafting, the logits each draft
     was drawn from, the step's position n and whether its draft was
     kept (the first token comes from the prefill's logits; the logits'
     row 0 is position n's, row 1 the draft's at n + 1)."""
-    if draft_tokens not in (0, 1):
-        raise ValueError(f"this model's MTP module drafts one token a step, not {draft_tokens}")
-    run = _decode_drafting if draft_tokens else _decode_plain
+    run = _decode_drafting if drafts(draft_tokens) else _decode_plain
     return run(cfg, params, dict(cache), logits, start, key, temperature, steps, collect)
 
 
@@ -569,10 +523,8 @@ class KExaone(LanguageModel):
         per phase, the routing as `moe.report_loads` has it, the decode's
         pairs counted over the positions its steps ran."""
         cfg = self.cfg
-        steps, drafted, accepted, read = (int(n) for n in counts)
-        width = 2 if drafted else 1  # positions a step runs
-        mtp = 1 if drafted else 0    # and whether the module's layer is among its bodies
-        pairs = steps * width * cfg.num_experts_per_tok * (cfg.sparse_layers + mtp)
+        width, drafting = drafting_report(
+            counts, cfg.num_experts_per_tok, cfg.sparse_layers, cfg.num_hidden_layers)
         return {
             **self.describe(cache_len),
             **report_loads(
@@ -582,9 +534,6 @@ class KExaone(LanguageModel):
                 decode_route(
                     width * cfg.num_experts_per_tok, cfg.hidden_size,
                     cfg.moe_intermediate_size, self.dtype)),
-            "decode_routed_pairs": pairs, "decode_expert_rows": pairs,
-            "decode_steps": steps, "mtp_drafted": drafted, "mtp_accepted": accepted,
             "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,
-            "decode_layer_passes": steps * width * (cfg.num_hidden_layers + mtp),
-            "decode_experts_read": read,
+            **drafting,
         }

@@ -1,8 +1,9 @@
-"""What the language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`): the blocks and helpers
-they are written from, the one initialisation rule, sampling on the
-device, the rule by which a drafted token is kept or replaced, the
-decode loop, and the stand-in tokenizer.
+"""What the eight language models share (`deepseek_v2.py`, `ouro.py`,
+`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`,
+`glm_dsa.py`, `granite_hybrid.py`): the blocks and helpers they are
+written from, the one initialisation rule, sampling on the device, the
+rule by which a drafted token is kept or replaced, the two decode loops,
+the prefill in parts, and the stand-in tokenizer.
 
 A bundle's `lm` part is an object with this contract (`LanguageModel`
 below holds what every model's class has alike), which is all that
@@ -31,6 +32,23 @@ below holds what every model's class has alike), which is all that
   `counted` answers what the node's counters take where `report` does
   not say otherwise (`decode_steps`, `prefill_layer_passes`,
   `decode_layer_passes`: a model whose step runs more than one position).
+
+What a model's two programs are written from, so that a loop is written
+once:
+
+- a `decode` is the model's one-token step handed to `decode_loop`
+  (every model);
+- where the model has an MTP module, `draft_tokens` 1 is its `mtp_step`
+  and `main_step` behind one return shape, and what it does to its own
+  state once a draft's fate is known, handed to `draft_loop` (K-EXAONE,
+  Ling-3.0-flash, GLM-5.2), which owns the keys, the draft, `verify`,
+  the ids and the first three of `counts`; `drafts` is the refusal of
+  any other number and `drafting_report` the steps' half of `report`;
+- a `prefill` that cannot take its prompt at once hands one part's body
+  and the arrays it wants cut to `prefill_in_parts` (GLM-5.2,
+  granite-4.0-h-micro), which owns the cut (`parts_of`), the scan over
+  the whole parts, the remainder and the joining of the parts' outputs;
+  the model allocates the state before it and reads the outputs after.
 """
 
 from __future__ import annotations
@@ -143,7 +161,7 @@ def sample(logits, key, temperature):
 
 def mtp_input(cfg, params, h, tokens):
     """What a multi-token-prediction module's layer takes (DeepSeek-V3's
-    form, which K-EXAONE's and Ling-3.0-flash's modules share): u [T,
+    form, which the three drafting models' modules share): u [T,
     hidden] = W_eh [rms_e(E[x_{i+1}]) ; rms_h(h_i)] of the residual
     streams h [T, hidden] after the last main layer and the tokens that
     follow each [T]; `params["mtp"]` holds `embed_norm`, `hidden_norm`
@@ -237,6 +255,154 @@ def decode_loop(step, cache, logits, start, key, temperature, steps: int):
     carry = (cache, logits, jnp.zeros((steps,), jnp.int32), zeros(tally), kept)
     cache, _, ids, tally, kept = jax.lax.fori_loop(0, steps, body, carry)
     return cache, ids, tally, kept
+
+
+def drafts(draft_tokens: int) -> bool:
+    """Whether a decode drafts, of a model whose MTP module drafts one
+    token a step; any other number is refused."""
+    if draft_tokens not in (0, 1):
+        raise ValueError(f"this model's MTP module drafts one token a step, not {draft_tokens}")
+    return bool(draft_tokens)
+
+
+def draft_loop(mtp_step, main_step, cache, logits, start, key, temperature, steps: int,
+               settle=lambda cache, accepted: cache):
+    """`steps` ids as one `while_loop` of self-speculative steps, from the
+    prefill's `logits` at position `start - 1`, of which id 0 is drawn
+    (the key folded by 0). A step (its keys: the key folded by its index +
+    1, split in two) drafts one token with the MTP module, runs the last
+    emitted token and the draft through the main model as two positions
+    and `verify` keeps the draft or replaces it, so it emits one id or two
+    and the loop takes as many steps as the drafts' fates make it. Before
+    a step the main model's state holds positions 0 .. n - 1, x_n is the
+    last emitted token, and `waiting` of the newest confirmed positions
+    (their residual streams `h`, the tokens that follow them `after`) have
+    not been through the module yet: one after a rejection, two after a
+    kept draft; before the first it is `cache["h"]`, the prompt's last.
+
+    What a model hands over: `mtp_step(cache, h [2, hidden], after [2],
+    position)` and `main_step(cache, tokens [2], position)`, which return
+    alike (logits [2, vocab], the residual streams [2, hidden] (the
+    module's: None), cache, a tree the loop sums over the steps, a dict
+    of which the loop keeps every step's or None), and
+    `settle(cache, accepted)`: what it does to its own state once a
+    draft's fate is known (nothing, unless it says otherwise). The draft
+    is drawn from the module's row `waiting` - 1, the row kept of what the
+    module keeps; the other is of no confirmed position where one waits,
+    and what it writes at n the next step writes over.
+
+    Returns (cache, ids [steps], counts [3] int32: steps taken, drafts
+    made, drafts kept, (the module's summed tree, the main model's), and
+    where the main step keeps anything the kept rows stacked, a row a
+    step the loop may take, else None: the steps' own and `logits` [2,
+    vocab] (row 0 position n's, row 1 the draft's at n + 1),
+    `draft_logits`, `position` (n; -1 where no step was taken) and
+    `accepted`)."""
+    first = sample(logits, jax.random.fold_in(key, 0), temperature)
+
+    def advance(c):
+        cache, emitted = c["cache"], c["emitted"]
+        n = start + emitted - 1  # x_n's position
+        key_draft, key_verify = jax.random.split(jax.random.fold_in(key, c["counts"][0] + 1))
+        with jax.named_scope("mtp"):
+            rows_mtp, _, cache, added_mtp, now_mtp = mtp_step(
+                cache, c["h"], c["after"], n - c["waiting"])
+            draft_logits = rows_mtp[c["waiting"] - 1]
+            draft = sample(draft_logits, key_draft, temperature)
+        rows, h, cache, added, now = main_step(cache, jnp.stack([c["last"], draft]), n)
+        with jax.named_scope("verify"):
+            accepted, one, two = verify(rows, draft_logits, draft, key_verify, temperature)
+            ids = c["ids"].at[emitted].set(one)
+            # a second token that would be one too many is not written
+            ids = ids.at[jnp.where(accepted, emitted + 1, steps)].set(two, mode="drop")
+            counts = c["counts"] + jnp.stack([1, 1, accepted]).astype(jnp.int32)
+        if now is not None:
+            now = {
+                **now, "logits": rows, "draft_logits": draft_logits, "position": n,
+                "accepted": accepted,
+                **jax.tree_util.tree_map(lambda a: a[c["waiting"] - 1], now_mtp or {}),
+            }
+        return {
+            "cache": settle(cache, accepted), "ids": ids, "emitted": emitted + 1 + accepted,
+            "last": jnp.where(accepted, two, one), "h": h, "after": jnp.stack([one, two]),
+            "waiting": 1 + accepted.astype(jnp.int32), "counts": counts,
+        }, (added_mtp, added), now
+
+    def body(carry):
+        c, tally, kept = carry
+        step = c["counts"][0]
+        c, added, now = advance(c)
+        return (c, jax.tree_util.tree_map(jnp.add, tally, added),
+                jax.tree_util.tree_map(lambda rows, row: rows.at[step].set(row), kept, now))
+
+    most = max(steps - 1, 1)  # steps the loop may take: each emits at least one token
+    c = {
+        "cache": cache, "ids": jnp.zeros((steps,), jnp.int32).at[0].set(first),
+        "emitted": jnp.int32(1), "last": first,
+        "h": jnp.stack([cache["h"], jnp.zeros_like(cache["h"])]),
+        "after": jnp.stack([first, jnp.int32(0)]), "waiting": jnp.int32(1),
+        "counts": jnp.zeros((3,), jnp.int32),
+    }
+    # what a step adds and keeps, as shapes: see `decode_loop`
+    with route_log():
+        _, tally, kept = jax.eval_shape(advance, c)
+    if kept is not None:
+        kept = zeros(jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct((most, *s.shape), s.dtype), kept))
+        kept["position"] = jnp.full((most,), -1, jnp.int32)
+    c, tally, kept = jax.lax.while_loop(
+        lambda carry: carry[0]["emitted"] < steps, body, (c, zeros(tally), kept))
+    return c["cache"], c["ids"], c["counts"], tally, kept
+
+
+def drafting_report(counts, k: int, sparse_layers: int, layers: int) -> tuple[int, dict]:
+    """What a decode's `counts` [4] (steps taken, drafts made, drafts
+    kept, held experts read) come to on `node.TextGenerate`, of a model
+    of `layers` main layers, `sparse_layers` of them sparse with `k`
+    experts a token: (the positions a step ran, the attributes). The
+    pairs and the layer bodies are counted over every position a step
+    ran, a rejected draft's and the MTP module's among them."""
+    steps, drafted, accepted, read = (int(n) for n in counts)
+    width = 2 if drafted else 1  # positions a step runs
+    mtp = 1 if drafted else 0    # and whether the module's layer is among its bodies
+    pairs = steps * width * k * (sparse_layers + mtp)
+    return width, {
+        "decode_routed_pairs": pairs, "decode_expert_rows": pairs,
+        "decode_steps": steps, "mtp_drafted": drafted, "mtp_accepted": accepted,
+        "decode_layer_passes": steps * width * (layers + mtp),
+        "decode_experts_read": read,
+    }
+
+
+def parts_of(tokens: int, part: int) -> tuple[int, int]:
+    """(whole parts of `part` positions, positions left over)."""
+    return divmod(tokens, part)
+
+
+def prefill_in_parts(body, state, arrays: tuple, part: int):
+    """A prompt read in parts of `part` positions (`parts_of`), each over
+    the state the parts before left: the whole parts one `lax.scan` body,
+    what is left over a body of its own. `arrays` are what the model
+    wants cut, each [T], an entry a position; `body(state, cuts, start,
+    ends) -> (state, out)` takes a part's entries of them, the position
+    it starts at (traced) and, statically, where the parts this body
+    serves may end (`start` // `part` is this one's place among them).
+    `out` has one shape whatever the part's length. Returns (state, the
+    parts' outs in order, joined along a leading parts axis)."""
+    tokens = arrays[0].shape[0]
+    whole, left = parts_of(tokens, part)
+    outs = []
+    if whole:
+        ends = tuple(part * (i + 1) for i in range(whole))
+        state, out = jax.lax.scan(
+            lambda state, xs: body(state, xs[:-1], xs[-1], ends), state,
+            (*(a[:whole * part].reshape(whole, part) for a in arrays), jnp.arange(whole) * part))
+        outs.append(out)
+    if left:
+        state, out = body(
+            state, tuple(a[tokens - left:] for a in arrays), jnp.int32(tokens - left), (tokens,))
+        outs.append(jax.tree_util.tree_map(lambda a: a[None], out))
+    return state, jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
 
 
 # --- parameters -----------------------------------------------------------

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from comfyui_distributed_tpu.models import dsa, mla
+from comfyui_distributed_tpu.models import dsa, lm_common, mla
 from comfyui_distributed_tpu.models import glm_dsa as glm
 from comfyui_distributed_tpu.models.lm_common import (
     apply_rope, apply_rope_pairs, rms_norm, rope_tables, swiglu)
@@ -250,7 +250,7 @@ def test_the_prefill_in_parts_is_the_prefill_in_one_pass(params):
     the same two caches, whatever the part."""
     ids = prompt_ids(TINY)
     parts = glm.prefill(TINY, params, ids, cache_len=PROMPT + 3)
-    assert glm.parts_of(TINY, PROMPT) == (3, 5)
+    assert lm_common.parts_of(PROMPT, TINY.prefill_part) == (3, 5)
     for part in (64, 53, 7):
         cfg = dataclasses.replace(TINY, prefill_part=part)
         one = glm.prefill(cfg, params, ids, cache_len=PROMPT + 3)

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from comfyui_distributed_tpu.models import granite_hybrid as gh
-from comfyui_distributed_tpu.models import mamba2
+from comfyui_distributed_tpu.models import lm_common, mamba2
 from comfyui_distributed_tpu.models.registry import create_model, get_config
 from comfyui_distributed_tpu.reference import granite_hybrid as ref
 
@@ -70,7 +70,7 @@ def test_prefill_in_parts_and_decode_through_the_state_match_the_reference(serve
     state: the logits at the last prompt position and at every decoded
     one, and every Mamba layer's state after the last token."""
     _, logits, _, final = served
-    assert gh.parts_of(TINY, PROMPT) == (2, 11)
+    assert lm_common.parts_of(PROMPT, TINY.prefill_part) == (2, 11)
     assert rel_l2(logits, wanted[0]).max() < LOGITS_TOLERANCE
     np.testing.assert_allclose(final, np.asarray(wanted[1][1]), rtol=0, atol=STATE_TOLERANCE)
 

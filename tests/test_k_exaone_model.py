@@ -402,7 +402,7 @@ def test_the_step_draws_what_the_rule_says():
 
     def one(key):
         key_draft, key_verify = jax.random.split(key)
-        draft = ke.sample(draft_logits, key_draft, temperature)
+        draft = lm_common.sample(draft_logits, key_draft, temperature)
         return lm_common.verify(logits, draft_logits, draft, key_verify, temperature)
 
     kept, first, second = jax.vmap(one)(jax.random.split(keys[2], 60000))

@@ -495,9 +495,23 @@ def test_two_drafts_a_step_are_refused():
 
 
 def test_the_drafting_rule_is_the_one_both_drafting_models_import():
-    from comfyui_distributed_tpu.models import k_exaone
+    """The rule and the loop around it are `lm_common.py`'s for the three
+    drafting models, the prompt's walk in parts for the two that cut it:
+    no model's file holds a loop of its own for either."""
+    import inspect
 
-    assert lf.verify is lm_common.verify is k_exaone.verify
+    from comfyui_distributed_tpu.models import glm_dsa, granite_hybrid, k_exaone
+
+    assert lm_common.draft_loop.__globals__["verify"] is lm_common.verify
+    for module in (lf, k_exaone, glm_dsa):
+        assert module.draft_loop is lm_common.draft_loop and module.drafts is lm_common.drafts
+        assert module.drafting_report is lm_common.drafting_report
+        assert "while_loop" not in inspect.getsource(module), module.__name__
+    for module in (glm_dsa, granite_hybrid):
+        assert module.prefill_in_parts is lm_common.prefill_in_parts
+        assert module.parts_of is lm_common.parts_of
+        assert "lax.scan" not in inspect.getsource(module.prefill.__wrapped__), module.__name__
+    assert inspect.getsource(lm_common).count("lax.while_loop(") == 1
     assert lf.kda_step is kda.kda_step and lf.kda_chunked is kda.kda_chunked
     assert lf.expert_layer is moe.expert_layer and lf.sigmoid_route is moe.sigmoid_route
 

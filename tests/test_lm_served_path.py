@@ -8,12 +8,14 @@ tree before the node stopped merging `describe` and `report` itself), what the n
 the workflow, the configuration file, the registry entry, the reference
 and the benchmark's copies. What only one model has stays a test of its
 own below. The one contract (`models/lm_common`) is held against every
-registry entry of family `lm`, and the shared decode loop against a Python
-loop over each model's own step.
+registry entry of family `lm`, and the shared loops (`decode_loop`,
+`draft_loop`, `prefill_in_parts`) against a Python loop over each model's
+own step or steps and by themselves under arithmetic ones.
 
 A `model_config` PR adds a row here, and edits no other row."""
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -1402,6 +1404,217 @@ def test_the_loop_sums_what_a_step_adds_and_keeps_what_it_hands_over(collect):
         assert rows is None
 
 
+# --- the shared drafting loop against each drafting model's own two steps --------
+
+
+@functools.lru_cache(maxsize=None)  # a model's steps are compiled once for its four cases
+def _drafting_steps_of(name):
+    """(`mtp(cfg, params, cache, h, after, position) -> (logits, cache, what
+    the decode sums of the module)`, `main(cfg, params, cache, tokens,
+    position) -> (logits, h, cache, what it sums of the main layers)`,
+    what the model does to its state once a draft's fate is known,
+    `summed(decode) -> (the module's, the main layers')`) of a tiny model."""
+    import jax
+
+    from comfyui_distributed_tpu.models import glm_dsa, k_exaone, ling_flash
+
+    module = {"k-exaone": k_exaone, "ling-flash": ling_flash, "glm-5.2": glm_dsa}[name]
+
+    def mtp(cfg, params, cache, h, after, position):
+        out = module.mtp_step(cfg, params, dict(cache), h, after, position)
+        if module is glm_dsa:
+            logits, cache, loads, _, keys = out
+            return logits, cache, (loads, keys)
+        return out[0], out[1], (out[3],)
+
+    def main(cfg, params, cache, tokens, position):
+        rows, h, cache, _, *added = module.main_step(cfg, params, dict(cache), tokens, position)
+        return rows, h, cache, (added[0], added[2]) if module is glm_dsa else (added[0],)
+
+    def settle(cache, accepted):
+        if module is ling_flash:
+            return {**cache, "slot": ling_flash.standing(cache["slot"], accepted)}
+        return cache
+
+    def summed(decode):
+        loads = decode.loads
+        if module is glm_dsa:
+            return (loads[-1], decode.keys[:, -1:]), (loads[:-1], decode.keys[:, :-1])
+        return (loads[-1],), (loads[:-1],)
+
+    return (jax.jit(mtp, static_argnums=0), jax.jit(main, static_argnums=0), jax.jit(settle),
+            summed)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+@pytest.mark.parametrize("name, steps", [  # a step count is a program: one id for one model here,
+    ("k-exaone", 1), *((m.name, 5) for m in MODELS if m.drafts)])  # for each in its own file
+def test_drafting_is_the_models_own_two_steps_walked_with_the_same_folded_keys(
+        name, steps, temperature):
+    """`lm_common.draft_loop` under each drafting model's `decode`: the ids
+    are a Python loop's over the model's own `mtp_step`, `main_step` and
+    `verify` (id 0 under the key folded by 0, step s under the key folded
+    by s + 1 and split in two), `steps` of them always, the counts are the
+    steps it took and the drafts it kept, the tallies the steps' sums, the
+    module's apart, and every kept row that step's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.lm_common import sample, verify
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    prompt, temperature = 12, jnp.float32(temperature)
+    mtp, main, settle, summed = _drafting_steps_of(name)
+    lm = create_model(BY_NAME[name].tiny)
+    cfg, key = lm.cfg, jax.random.key(steps)
+    params = lm.init(jax.random.key(1))
+    ids = jax.random.randint(jax.random.key(2), (prompt,), 0, cfg.vocab_held)
+
+    walked = lm.prefill(params, ids, prompt + 5)  # the decode below takes its own by donation
+    cache = walked.cache
+    first = sample(walked.logits, jax.random.fold_in(key, 0), temperature)
+    tokens, last, waiting, after = [first], first, 1, jnp.stack([first, jnp.int32(0)])
+    h = jnp.stack([cache["h"], jnp.zeros_like(cache["h"])])
+    rows, fates, positions, of_module, of_main = [], [], [], [], []
+    while len(tokens) < steps:
+        n = prompt + len(tokens) - 1
+        key_draft, key_verify = jax.random.split(jax.random.fold_in(key, len(rows) + 1))
+        drafted, cache, added = mtp(cfg, params, cache, h, after, jnp.int32(n - waiting))
+        of_module.append(added)
+        draft = sample(drafted[waiting - 1], key_draft, temperature)
+        logits, h, cache, added = main(cfg, params, cache, jnp.stack([last, draft]), jnp.int32(n))
+        of_main.append(added)
+        accepted, one, two = verify(logits, drafted[waiting - 1], draft, key_verify, temperature)
+        cache = settle(cache, accepted)
+        tokens += [one, two] if accepted else [one]
+        last, after, waiting = (two if accepted else one), jnp.stack([one, two]), 1 + int(accepted)
+        rows.append(logits)
+        fates.append(bool(accepted))
+        positions.append(n)
+
+    start = lm.prefill(params, ids, prompt + 5)
+    decode = lm.decode(params, start.cache, start.logits, prompt, key, steps, temperature, True, 1)
+    assert decode.ids.shape == (steps,) and decode.ids.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(decode.ids), np.asarray(jnp.stack(tokens[:steps])))
+    taken = len(rows)
+    assert np.asarray(decode.counts).tolist()[:3] == [taken, taken, sum(fates)]
+    assert np.asarray(decode.kept["position"]).tolist() == positions + [-1] * (
+        max(steps - 1, 1) - taken)
+    assert np.asarray(decode.kept["accepted"]).tolist()[:taken] == fates
+    for mine, theirs in ((of_module, summed(decode)[0]), (of_main, summed(decode)[1])):
+        for i, total in enumerate(theirs):
+            want = sum(np.asarray(added[i]) for added in mine) if mine else 0
+            np.testing.assert_array_equal(np.asarray(total), want + np.zeros_like(total))
+    for i, logits in enumerate(rows):
+        np.testing.assert_allclose(
+            np.asarray(decode.kept["logits"][i]), np.asarray(logits), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("collect", [False, True])
+@pytest.mark.parametrize("keeping", [True, False])
+@pytest.mark.parametrize("steps", [1, 6])
+def test_the_drafting_loop_emits_two_ids_for_a_kept_draft_and_one_for_a_dropped(
+        steps, keeping, collect):
+    """The loop by itself, under steps that are arithmetic at temperature
+    0 (the token at position p is p): a module that drafts position i's
+    token i + 2 has every draft kept, two ids a step, and the second id
+    that would be id `steps` is not written; one that drafts i + 3 has
+    none kept, one id a step; one id asked for is the prefill's and no
+    step. The model's hook sees each step's
+    `accepted`, the module's and the main steps' trees are summed apart
+    from zero, the kept rows are the steps' in order (of the module's the
+    row the draft was drawn from), and a main step that keeps None has
+    none."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.lm_common import draft_loop
+
+    vocab, start = 32, 3
+
+    def peaked(at):
+        return jnp.zeros((vocab,)).at[at % vocab].set(50.0)
+
+    def mtp_step(cache, h, after, position):
+        rows = jnp.stack([peaked(position + row + (2 if keeping else 3)) for row in range(2)])
+        return rows, None, cache, (position,), {"confirmed": position + jnp.arange(2)}
+
+    def main_step(cache, tokens, position):
+        rows = jnp.stack([peaked(position + 1), peaked(position + 2)])
+        h = jnp.stack([cache["h"] + position, cache["h"] + position + 1])
+        kept = {"at": position, "tokens": tokens} if collect else None
+        return rows, h, {**cache, "ran": cache["ran"] + 1}, (jnp.ones((2,), jnp.int32),), kept
+
+    def settle(cache, accepted):
+        return {**cache, "fates": cache["fates"] * 2 + accepted}
+
+    cache = {"h": jnp.zeros((4,)), "ran": jnp.int32(0), "fates": jnp.int32(1)}
+    cache, ids, counts, ((positions,), (ones,)), rows = jax.jit(lambda: draft_loop(
+        mtp_step, main_step, cache, peaked(start), jnp.int32(start), jax.random.key(0),
+        jnp.float32(0.0), steps, settle))()
+    assert np.asarray(ids).tolist() == list(range(start, start + steps))
+    taken = 0 if steps == 1 else 3 if keeping else 5
+    assert np.asarray(counts).tolist() == [taken, taken, taken if keeping else 0]
+    assert int(cache["ran"]) == taken
+    # a bit a step, after the 1
+    assert int(cache["fates"]) == (1 if steps == 1 else 0b1111 if keeping else 0b100000)
+    at = [start + (2 if keeping else 1) * step for step in range(taken)]  # each step's n
+    waiting = [1] + [2 if keeping else 1] * (taken - 1)
+    assert np.asarray(ones).tolist() == [taken, taken]
+    assert int(positions) == sum(n - w for n, w in zip(at, waiting))
+    if not collect:
+        assert rows is None
+        return
+    assert set(rows) == {"at", "tokens", "confirmed", "logits", "draft_logits", "position",
+                         "accepted"}
+    most = max(steps - 1, 1)
+    assert rows["logits"].shape == (most, 2, vocab)
+    assert np.asarray(rows["position"]).tolist() == at + [-1] * (most - taken)
+    assert np.asarray(rows["at"]).tolist()[:taken] == at
+    assert np.asarray(rows["accepted"]).tolist() == [keeping] * taken + [False] * (most - taken)
+    # the row the draft was drawn from is of the newest confirmed position, n - 1
+    assert np.asarray(rows["confirmed"]).tolist()[:taken] == [n - 1 for n in at]
+    assert np.asarray(rows["tokens"])[:taken, 0].tolist() == at
+
+
+@pytest.mark.parametrize("tokens, parts", [(12, [4, 4, 4]), (3, [3]), (14, [4, 4, 4, 2])])
+def test_the_walk_in_parts_hands_each_body_its_start_and_ends_and_joins_in_order(tokens, parts):
+    """`lm_common.prefill_in_parts` under a part that is arithmetic: whole
+    parts only, a remainder only, both. Every array is cut alike, the
+    whole parts are one body and the remainder one (each traced with the
+    ends the parts it serves may have), each part starts where the one
+    before ended over the state it left, and the outputs come back in
+    order along a leading parts axis."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models.lm_common import parts_of, prefill_in_parts
+
+    bodies = []
+
+    def body(state, cuts, start, ends):
+        ids, after = cuts
+        bodies.append((ids.shape[0], ends))
+        out = {"start": start, "before": state, "first": ids[0], "next": after[-1]}
+        return state + ids.sum(), out
+
+    ids = jnp.arange(100, 100 + tokens)
+    state, outs = jax.jit(lambda: prefill_in_parts(body, jnp.int32(0), (ids, ids + 1), 4))()
+    assert parts_of(tokens, 4) == (len([p for p in parts if p == 4]), tokens % 4)
+    whole = [(4, tuple(range(4, tokens + 1, 4)))] * (tokens >= 4)
+    assert list(dict.fromkeys(bodies)) == whole + [(tokens % 4, (tokens,))] * bool(tokens % 4)
+    starts = np.cumsum([0] + parts[:-1]).tolist()
+    assert int(state) == int(ids.sum())
+    assert np.asarray(outs["start"]).tolist() == starts
+    assert np.asarray(outs["first"]).tolist() == [100 + s for s in starts]
+    assert np.asarray(outs["next"]).tolist() == [100 + s + p for s, p in zip(starts, parts)]
+    assert np.asarray(outs["before"]).tolist() == [
+        int(ids[:s].sum()) for s in starts]
+
+
 # --- what only one model has -----------------------------------------------------
 
 
@@ -1586,7 +1799,7 @@ def test_the_seventh_model_is_written_from_the_modules_the_others_are():
     assert glm_dsa.expert_layer is moe.expert_layer is k_exaone.expert_layer
     assert glm_dsa.sigmoid_route is moe.sigmoid_route is k_exaone.sigmoid_route
     assert glm_dsa.mla is mla is ling_flash.mla is deepseek_v2.mla and dsa.mla is mla
-    assert glm_dsa.verify is lm_common.verify and glm_dsa.mtp_input is lm_common.mtp_input
+    assert glm_dsa.draft_loop is lm_common.draft_loop and glm_dsa.mtp_input is lm_common.mtp_input
     assert glm_dsa.decode_loop is lm_common.decode_loop
     assert glm_dsa.head is lm_common.head and glm_dsa.rms_norm is lm_common.rms_norm
     with open(glm_dsa.__file__, encoding="utf-8") as fh:
@@ -1652,7 +1865,7 @@ def test_the_fifth_model_is_written_from_the_modules_the_others_are():
     for module in (deepseek_v2, ling_flash):
         assert module.mla is mla
     for module in (k_exaone, ling_flash):
-        assert module.verify is lm_common.verify
+        assert module.draft_loop is lm_common.draft_loop  # which calls the rule, `verify`
     for module, gone in ((solar_open2, ("def kda_chunked", "def kda_step", "def decay_products",
                                         "def unit_lower_solve", "def _l2norm")),
                          (deepseek_v2, ("def _latents", "thc,sc->ths")),
