@@ -1465,13 +1465,17 @@ DSA_TOLERANCE = 2e-2  # one form's bfloat16 outputs against the other's, of the 
 def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, rank,
             index_heads, index_width) -> bool:
     """`models/dsa.py` at one part's shapes, bfloat16 as stored: ms of
-    the indexer's scores, of the selection in either form (`lax.top_k`;
-    the bisection), of XLA's gather of the chosen rows with nothing
+    the indexer's scores, of the selection in each form (`lax.top_k`;
+    the bisection's mask; the `dsa_select` kernel, interpreted in a
+    rehearsal, and the first and the last again at the ladder's shorter
+    rungs), of XLA's gather of the chosen rows with nothing
     behind it, and of attention in each form (`mla.absorbed` under the
     selection's mask a block of `dsa.BLOCK_ROWS` query rows at a time;
     the chosen rows gathered by XLA; gathered inside the `dsa_attend`
-    kernel, interpreted in a rehearsal); that both selections are one
-    set and the gathered forms the masked form's result (`DSA_TOLERANCE`);
+    kernel, interpreted in a rehearsal), the gathered forms from
+    `lax.top_k`'s positions and from the kernel's ascending ones; that
+    all selections are one set and the gathered forms the masked form's
+    result (`DSA_TOLERANCE`);
     then a step's two positions, scores and selection and attention
     together, in either form."""
     import jax
@@ -1504,12 +1508,23 @@ def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, ra
            "dtype": jnp.dtype(dtype).name, "block_rows": dsa.BLOCK_ROWS,
            "scores": {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}, "ok": True}
     selections = {}
-    for name, choose in (("top_k", dsa.top), ("bisection", dsa.above_threshold)):
+    chooses = {"top_k": dsa.top, "bisection": dsa.above_threshold,
+               "kernel": functools.partial(dsa.top_compacted, interpret=rehearsal)}
+    for name, choose in chooses.items():
         selections[name], first_s, ms = timed(by_blocks(lambda i: choose(i, top)), index)
         row[f"select_{name}"] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
-    same = jnp.array_equal(*(dsa.as_mask(s, rows) for s in selections.values()))
-    row["selections_equal"] = bool(same)
+    masks = [dsa.as_mask(s, rows) for s in selections.values()]
+    row["selections_equal"] = all(bool(jnp.array_equal(masks[0], m)) for m in masks[1:])
     row["ok"] &= row["selections_equal"]
+    del masks
+    # the ladder's shorter rungs, every key of them visible: the sort against the kernel
+    for length in dsa.length_ladder(rows, top)[:-1]:
+        cut, picked = index[:, :length], {}
+        for name in ("top_k", "kernel"):
+            picked[name], _, ms = timed(by_blocks(lambda i: chooses[name](i, top)), cut)
+            row[f"select_{name}"][f"ms_at_{length}"] = round(ms, 3)
+        row["ok"] &= bool(jnp.array_equal(*(dsa.as_mask(s, length) for s in picked.values())))
+    del cut, picked
 
     gathered = selections["top_k"]
     _, first_s, ms = timed(
@@ -1530,9 +1545,13 @@ def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, ra
         row[f"attend_{name}"] = {"first_call_s": round(first_s, 2), "ms": round(ms, 3)}
     want = outs["masked"].astype(jnp.float32)
     for name in ("gathered", "kernel"):
-        diff = jnp.max(jnp.abs(outs[name].astype(jnp.float32) - want)) / jnp.max(jnp.abs(want))
-        row[f"attend_{name}"]["max_rel_diff"] = round(float(diff), 6)
-        row["ok"] &= float(diff) <= DSA_TOLERANCE  # a NaN fails it too
+        # from `lax.top_k`'s positions, then from the selection kernel's ascending ones
+        ascending, _, ms = timed(forms[name], q_nope, q_rope, *selections["kernel"])
+        row[f"attend_{name}"]["ms_ascending"] = round(ms, 3)
+        for tag, out in (("max_rel_diff", outs[name]), ("max_rel_diff_ascending", ascending)):
+            diff = jnp.max(jnp.abs(out.astype(jnp.float32) - want)) / jnp.max(jnp.abs(want))
+            row[f"attend_{name}"][tag] = round(float(diff), 6)
+            row["ok"] &= float(diff) <= DSA_TOLERANCE  # a NaN fails it too
 
     # a drafting step's two positions: what `glm_dsa.attention` runs of this module, and
     # the other form at the same two

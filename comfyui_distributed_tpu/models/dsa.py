@@ -19,10 +19,16 @@ infinity, `select` S and `attend` latent attention over it. A layer that
 computes no index of its own attends by the `Selection` handed to it.
 
 S has two forms, by the number of queries alone (`form`). **gathered**
-(a part of a prompt): `lax.top_k` gives the chosen positions, a block
-of query rows at a time (`BLOCK_ROWS`), their rows of the latent cache
-are brought together and `mla.absorbed`'s products run over them. Where
-they are brought together is the backend's (`ops/dsa_attend.route`): on
+(a part of a prompt): the chosen positions a block of query rows at a
+time (`BLOCK_ROWS`), their rows of the latent cache brought together
+and `mla.absorbed`'s products run over them. How a block's positions
+are picked is the backend's (`selection_form`, `ops/dsa_select.route`):
+on a TPU by one Pallas kernel that holds the block's scores in VMEM,
+finds the k-th largest by bisection and turns the mask into ascending
+positions by a compress network (`top_compacted`; a part of 8,192
+queries over 32,768 keys 15 ms on a v5e against the sort's 110);
+elsewhere by `lax.top_k` (`top`, positions by score). Where the rows
+are brought together is the backend's too (`ops/dsa_attend.route`): on
 a TPU inside one Pallas kernel that holds the layer's cache in VMEM and
 copies a query's rows beside one another on chip (`attend_kernel`; a
 part of 8,192 queries over 32,896 rows 41 ms on a v5e against 124);
@@ -46,7 +52,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import dsa_attend
+from ..ops import dsa_attend, dsa_select
 from . import mla
 from .lm_common import apply_rope_pairs
 
@@ -149,6 +155,15 @@ def top(index: jax.Array, k: int) -> Selection:
     return Selection(chosen.astype(jnp.int32), values > -jnp.inf)
 
 
+def top_compacted(index: jax.Array, k: int, interpret: bool = False) -> Selection:
+    """`top`'s set through `ops/dsa_select`, one Pallas kernel and no
+    sort: the k-th largest by bisection, the mask under the tie rule,
+    and the mask made positions by a compress network, a block's scores
+    in VMEM throughout. A query's positions come **ascending**, not by
+    score; those that do not count point at row 0."""
+    return Selection(*dsa_select.dsa_select(index, k=k, interpret=interpret))
+
+
 def above_threshold(index: jax.Array, k: int) -> Selection:
     """The `k` largest of each row of I [T, S] (as `scores` gives it) as a
     mask: the k-th largest value by bisection on the bit patterns (32 passes of compare
@@ -185,28 +200,44 @@ def length_ladder(rows: int, k: int) -> tuple[int, ...]:
     return (*lengths, rows)
 
 
+def selection_form(queries: int, rows: int, k: int) -> str:
+    """How `select` picks the `k` best of `rows` positions for `queries`
+    queries: "bisection" (the masked form), or the gathered form's
+    "kernel" (`top_compacted`, on a TPU for a length `ops/dsa_select`
+    has a plan for) or "sort" (`top`)."""
+    if form(queries) == "masked":
+        return "bisection"
+    return dsa_select.route(min(queries, BLOCK_ROWS), rows, k)
+
+
 def select(q: jax.Array, w: jax.Array, cached: jax.Array, positions: jax.Array,
            k: int) -> Selection:
     """S_t of every query (at `positions`, one after another) in the form
-    their number gives (`form`). The gathered form scores and sorts the
-    shortest rung of `length_ladder` that holds the last query's
-    position, which the device picks (`lax.switch`): what lies past it
-    no query of the call may see."""
-    def block(cached, q, w, positions):
+    their number gives (`form`). The gathered form scores the shortest
+    rung of `length_ladder` that holds the last query's position, which
+    the device picks (`lax.switch`): what lies past it no query of the
+    call may see; and picks a rung's k best as `selection_form` says,
+    which a traced call logs a rung (`ops/dsa_select.log_route`)."""
+    def block(choose, cached, q, w, positions):
         with jax.named_scope("scores"):
             index = scores(q, w, cached, positions)
         with jax.named_scope("select"):
-            return top(index, k) if gathered else above_threshold(index, k)
+            return choose(index, k)
 
-    gathered = form(q.shape[0]) == "gathered"
-    if not gathered:
-        return block(cached, q, w, positions)
+    def rung(length):
+        how = selection_form(q.shape[0], length, k)
+        dsa_select.log_route(how, q.shape[0], length, k)
+        choose = top_compacted if how == "kernel" else top
+        return partial(by_rows, partial(block, choose, cached[:length]), BLOCK_ROWS)
+
+    if form(q.shape[0]) == "masked":
+        return block(above_threshold, cached, q, w, positions)
     ladder = length_ladder(cached.shape[0], k)
-    rungs = [partial(by_rows, partial(block, cached[:length]), BLOCK_ROWS) for length in ladder]
+    rungs = [rung(length) for length in ladder]
     if len(rungs) == 1:
         return rungs[0](q, w, positions)
-    rung = sum(positions[-1] >= length for length in ladder[:-1])
-    return jax.lax.switch(rung, rungs, q, w, positions)
+    at = sum(positions[-1] >= length for length in ladder[:-1])
+    return jax.lax.switch(at, rungs, q, w, positions)
 
 
 def attend_gathered(q_nope: jax.Array, q_rope: jax.Array, cache: jax.Array,

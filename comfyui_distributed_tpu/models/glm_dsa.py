@@ -619,6 +619,8 @@ class GlmDsa(LanguageModel):
             "prefill_parts": len(lengths),
             "keys_visible": visible, "keys_selected": selected,
             "prefill_sparse_attention_form": dsa.form(min(prompt_tokens, cfg.prefill_part)),
+            "prefill_selection_form": dsa.selection_form(
+                min(prompt_tokens, cfg.prefill_part), cache_len, cfg.index_topk),
             "decode_sparse_attention_form": dsa.form(width),
             "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,
             **drafting,

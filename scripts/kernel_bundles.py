@@ -75,6 +75,23 @@ def _dsa_attend(queries, rows, k, heads, rank, rope):
     return lower
 
 
+def _dsa_select(queries, positions, k):
+    """Lower one `ops/dsa_select` call: a block of a part's queries'
+    float32 scores over a rung of the cache, the `k` best as positions."""
+    def lower(place):
+        import functools
+
+        import jax
+        import jax.numpy as jnp
+
+        from comfyui_distributed_tpu.ops import dsa_select
+
+        scores = jax.ShapeDtypeStruct(
+            (queries, positions), jnp.float32, sharding=place((queries, positions)).sharding)
+        return functools.partial(dsa_select.dsa_select, k=k), (scores,)
+    return lower
+
+
 def _ssd_chunk(tokens, heads, width, groups, n, chunk):
     """Lower one `ops/ssd_chunk` call: a Mamba-2 layer's chunked scan
     over a part of a prompt, bf16 operands and float32 steps and state."""
@@ -110,6 +127,7 @@ CASES = {
     "deepseek-v2 mla 2048": (
         "flash_attention_causal", _attention((1, 2048, 128, 192), v_width=128, causal=True)),
     "glm-5.2 dsa": ("dsa_attend", _dsa_attend(2048, 32896, 2048, 64, 512, 64)),
+    "glm-5.2 dsa select": ("dsa_select", _dsa_select(128, 32768, 2048)),
     "granite-4.0-h-micro mamba-2": ("ssd_chunk", _ssd_chunk(8192, 64, 64, 1, 128, 256)),
     "nemotron3-nano mamba-2": ("ssd_chunk", _ssd_chunk(8192, 64, 64, 8, 128, 128)),
 }

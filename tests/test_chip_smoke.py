@@ -274,10 +274,13 @@ def test_the_mamba_row_holds_the_chunked_scan_to_the_recurrence(smoke, capsys, w
 def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys):
     """The row's shape is the cell's: the last part of the committed
     workflow's prompt over caches of the request's length, the
-    registry's widths; and the rehearsal's toy row on the CPU: both
-    selections one set, the gathered forms (XLA's, and the `dsa_attend`
-    kernel interpreted) the masked form's result, a step in either
-    form."""
+    registry's widths; and the rehearsal's toy row on the CPU: the three
+    selections (`lax.top_k`, the bisection's mask, the `dsa_select`
+    kernel interpreted) one set at the cache's length and at the
+    ladder's shorter rungs, the gathered forms (XLA's, and the
+    `dsa_attend` kernel interpreted) the masked form's result from
+    `lax.top_k`'s positions and from the kernel's ascending ones, a step
+    in either form."""
     import jax
 
     from comfyui_distributed_tpu.models.registry import get_config
@@ -297,11 +300,16 @@ def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys
     assert smoke.dsa_row(True, *smoke.REHEARSAL_DSA_SHAPE)
     (row,) = _result_lines(capsys.readouterr().out)
     assert row["ok"] and row["selections_equal"]
-    for name in ("scores", "select_top_k", "select_bisection", "gather_alone", "attend_masked"):
+    for name in ("scores", "select_bisection", "gather_alone", "attend_masked"):
         assert set(row[name]) == {"first_call_s", "ms"}, name
+    rungs = {f"ms_at_{length}" for length in (16, 32, 64)}     # under the toy cache's 72 rows
+    for name in ("select_top_k", "select_kernel"):
+        assert set(row[name]) == {"first_call_s", "ms"} | rungs, name
     for name in ("attend_gathered", "attend_kernel"):
-        assert set(row[name]) == {"first_call_s", "ms", "max_rel_diff"}, name
+        assert set(row[name]) == {
+            "first_call_s", "ms", "ms_ascending", "max_rel_diff", "max_rel_diff_ascending"}, name
         assert row[name]["max_rel_diff"] < smoke.DSA_TOLERANCE
+        assert row[name]["max_rel_diff_ascending"] < smoke.DSA_TOLERANCE
     assert set(row["step_masked"]) == set(row["step_gathered"]) == {"first_call_s", "us"}
 
 

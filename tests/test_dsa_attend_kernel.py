@@ -15,7 +15,7 @@ import pytest
 
 from comfyui_distributed_tpu.models import dsa, glm_dsa, mla
 from comfyui_distributed_tpu.models.registry import create_model, get_config
-from comfyui_distributed_tpu.ops import attention, dsa_attend
+from comfyui_distributed_tpu.ops import attention, dsa_attend, dsa_select
 
 SCALE = 0.25
 
@@ -218,15 +218,20 @@ def test_glm_dsas_prefill_on_the_tpus_route_is_its_prefill_on_the_cpus(monkeypat
     params = lm.init(jax.random.key(0))
     ids = jax.random.randint(jax.random.key(1), (40,), 0, cfg.vocab_size)
     want = lm.prefill(params, ids, 48)
-    compiled = dsa_attend.dsa_attend
+    compiled, selecting = dsa_attend.dsa_attend, dsa_select.dsa_select
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(dsa_attend, "dsa_attend", lambda *xs, scale, interpret: compiled(
         *xs, scale=scale, interpret=True))
+    # the selection takes a TPU's route with it (PR 57; `tests/test_dsa_select_kernel.py`)
+    monkeypatch.setattr(dsa_select, "dsa_select", lambda index, k, interpret: selecting(
+        index, k=k, interpret=True))
     anew = jax.jit(glm_dsa.prefill.__wrapped__, static_argnums=0, static_argnames="cache_len")
     with attention.route_log() as routes:
         got = anew(cfg, params, ids, cache_len=48)
     layers = cfg.num_hidden_layers
-    assert routes == ["dsa-kernel 16x48 k8 h4 f32"] * layers + ["dsa-masked 8x48 k48 h4 f32"] * layers
+    attending = [r for r in routes if not r.startswith("dsa-select")]
+    assert attending == (
+        ["dsa-kernel 16x48 k8 h4 f32"] * layers + ["dsa-masked 8x48 k48 h4 f32"] * layers)
     np.testing.assert_allclose(np.asarray(got.logits), np.asarray(want.logits), atol=2e-5)
     assert dsa.form(cfg.prefill_part) == "gathered" and dsa.form(8) == dsa.form(2) == "masked"
     published = get_config("glm-5.2-ep16-5l")

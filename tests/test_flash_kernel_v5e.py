@@ -6,6 +6,7 @@ this one file (one process loads the TPU's library; see the fixture)."""
 
 import functools
 import os
+import re
 
 import chip_smoke
 import jax
@@ -713,6 +714,40 @@ def test_dsa_attend_compiles_for_v5e_at_a_parts_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * block + 2 * rows * sizes.lanes * 4
 
 
+def test_dsa_select_compiles_for_v5e_at_a_parts_shape(one_chip, monkeypatch):
+    """The indexer's selection at `chip_smoke.DSA_SHAPE`, a part of
+    GLM-5.2's prompt over the cell's cache, as `dsa.select` runs it on a
+    TPU: the scores a block of `dsa.BLOCK_ROWS` queries, and their 2,048
+    best picked by the `dsa_select` kernel, one custom call a rung of the
+    ladder, a block's keys and words in VMEM (the compiler takes the
+    raised limit; the default 16 MiB would refuse it). No sort is left,
+    and what the program holds beside its arguments and its result is a
+    block's: the heads' products, the keys turned round and the
+    positions, not a part's scores."""
+    from comfyui_distributed_tpu.models import dsa
+    from comfyui_distributed_tpu.ops import dsa_select
+
+    _, queries, rows, top, *_, index_heads, index_width = chip_smoke.DSA_SHAPE
+    ladder = dsa.length_ladder(rows, top)
+    assert ladder == (4096, 8192, 16384, 32768, 32896)
+    sizes = dsa_select.plan(dsa.BLOCK_ROWS, rows, top)
+    assert 16 * 2**20 < sizes.vmem_bytes <= dsa_select.VMEM_RESIDENT_BUDGET
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        compiled = jax.jit(lambda q, w, cached, at: tuple(dsa.select(q, w, cached, at, top))).lower(
+            place((queries, index_heads, index_width), jnp.bfloat16),
+            place((queries, index_heads), jnp.float32),
+            place((rows, index_width), jnp.bfloat16), place((queries,), jnp.int32),
+        ).compile()
+    assert routes == [f"dsa-select-kernel {queries}x{length} k{top}" for length in ladder]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == len(ladder)
+    assert "%dsa_select" in text and " sort(" not in text
+    block = dsa.BLOCK_ROWS * rows * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < (index_heads + 4) * block
+
+
 def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_caches_in_place(
         one_chip, monkeypatch):
     """GLM-5.2's two programs at the cell's sizes (32,768 ids in four parts
@@ -720,8 +755,10 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
     them. The prefill is one `while` over the parts: what it holds beside
     its arguments is a part's working set (the expert ladder's top rung,
     the indexer's scores, a part's folded queries), not the prompt's;
-    each attention layer attends in the `dsa_attend` kernel and no
-    gathered row is in the program. The
+    each indexer layer picks its keys in the `dsa_select` kernel and no
+    sort over a cache's positions is in the program; each attention
+    layer attends in the `dsa_attend` kernel and no gathered row is in
+    the program. The
     drafting decode carries the donated tree of six latent caches and
     three indexer caches (252.6 MB) through its loop, a step's queries
     take the masked form, and a step's grouped products run in the
@@ -739,14 +776,20 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
             cfg, params, jax.ShapeDtypeStruct((32768,), jnp.int32, sharding=one_chip),
             cache_len=32896,
         ).compile()
-    assert routes == ["dsa-kernel 8192x32896 k2048 h64 bf16"] * cfg.num_hidden_layers
+    picks = [f"dsa-select-kernel 8192x{length} k2048" for length in (4096, 8192, 16384, 32768, 32896)]
+    assert [r for r in routes if r.startswith("dsa-select")] == picks * cfg.full_layers
+    assert routes.count("dsa-kernel 8192x32896 k2048 h64 bf16") == cfg.num_hidden_layers
+    assert len(routes) == len(picks) * cfg.full_layers + cfg.num_hidden_layers
     memory = prefill.memory_analysis()
     assert memory.temp_size_in_bytes < 4.5e9      # 4.13 GB: a part's, whatever the parts' number
     assert memory.output_size_in_bytes >= 32896 * 7680
     text = prefill.as_text()
-    # the parts' scan; `lax.top_k` at 2,048 of 32,896 is a sort
-    assert " while(" in text and [line for line in text.splitlines()
-                                  if " sort(" in line and "32896" in line]
+    # the parts' scan; the 2,048 best of up to 32,896 are picked in the `dsa_select` kernel,
+    # so no sort over a cache's positions is left (the router's top 8 of 256 is one)
+    assert " while(" in text and not [
+        line for line in text.splitlines()
+        if " sort(" in line and re.search(r"\[\d+,(4096|8192|16384|32768|32896)\]", line)]
+    assert text.count("%dsa_select") >= 1
     assert not [line for line in text.splitlines() if " gather(" in line and "2048,576" in line]
     assert text.count("%dsa_attend") >= 1
 

@@ -327,6 +327,7 @@ def test_the_keys_a_request_reports_are_their_closed_forms(params):
             said["index_shared_layers"], said["prefill_part"]) == (4, 8, 2, 3, 16)
     assert (said["prefill_sparse_attention_form"], said["decode_sparse_attention_form"]) == (
         "gathered", "masked")
+    assert said["prefill_selection_form"] == "sort"    # `lax.top_k`, off a TPU
     assert said["cache_bytes"] == total * (6 * 32 + 3 * 16) * 4
     assert said["indexer_cache_bytes"] == total * 3 * 16 * 4 and said["state_bytes"] == 0
     assert said["prefill_routed_pairs"] == PROMPT * 4 * 4

@@ -854,6 +854,7 @@ MODELS = [
             "cache_bytes": (2048 + 16) * (6 * 32 + 3 * 16) * 4,
             "indexer_cache_bytes": (2048 + 16) * 3 * 16 * 4, "state_bytes": 0,
             "prefill_sparse_attention_form": "gathered",
+            "prefill_selection_form": "sort",
             "decode_sparse_attention_form": "masked",
             "prefill_layer_passes": 2048 * 5, "prefill_routed_pairs": 2048 * 4 * 4,
             "decode_expert_route": "xla", "node_id": "6"},
@@ -865,8 +866,11 @@ MODELS = [
         # decode's of both (the MTP module's row and column) and the four counts
         wait_bytes=4 * (16 + 128 * 4 * 2 + 128 * 2 * 5 + (4 + 1) * 2 + 2 * (5 + 1) + 4),
         # a part's 16 queries over the rows a top-k chose, gathered; a step's two under
-        # the mask the bisection gave
-        attention="dsa-gathered 16x2064 k8 h4 f32, dsa-masked 2x2064 k2064 h4 f32",
+        # the mask the bisection gave; the top-k a sort at each rung of the lengths' ladder
+        attention=", ".join(sorted(
+            ["dsa-gathered 16x2064 k8 h4 f32", "dsa-masked 2x2064 k2064 h4 f32"]
+            + [f"dsa-select-sort 16x{length} k8"
+               for length in (16, 32, 64, 128, 256, 512, 1024, 2048, 2064)])),
         passes=lambda attrs: (2048 * 5, attrs["decode_steps"] * 2 * (5 + 1)),
         widths={
             "hidden_size": 6144, "num_attention_heads": 64, "num_key_value_heads": 64,
@@ -891,7 +895,8 @@ MODELS = [
         published=glm_published, entry=glm_entry, check_workflow=glm_workflow,
         metrics=frozenset({"experts_held_share_pct.lm", "mtp_accept_pct.lm",
                            "mtp_device_pct.lm", "mla_device_pct.lm", "indexer_device_pct.lm",
-                           "keys_selected_pct.lm", "dsa_attend_device_pct.lm"}),
+                           "keys_selected_pct.lm", "dsa_attend_device_pct.lm",
+                           "dsa_select_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
     ),
     Model(
