@@ -1582,7 +1582,9 @@ def dsa_row(rehearsal: bool, label, queries, rows, top, heads, nope, rope, v, ra
 # token; K-EXAONE an eighth of 128, 8 a token, two positions a drafting
 # step and one in its MTP module; Ling-3.0-flash an eighth of 512, 8 a
 # token, the narrowest (2,560 columns, 768-wide experts), two positions a
-# drafting step and one a plain step. DeepSeek's shape a second time at 8
+# drafting step and one a plain step; SDAR every one of 128, 8 a token,
+# the four positions of a block a pass: 32 rows over the union of four
+# choices, some 29 experts (PR 58). DeepSeek's shape a second time at 8
 # rows: at a row count off the sublane tile the compiler gives
 # `ragged_dot` another lowering (PERF.md §6, PR 42).
 EXPERT_SHAPES = (
@@ -1593,6 +1595,7 @@ EXPERT_SHAPES = (
     ("k-exaone mtp position", 8, 8, 16, 128, 6144, 2048),
     ("ling-flash two positions", 16, 8, 64, 512, 2560, 768),
     ("ling-flash step", 8, 8, 64, 512, 2560, 768),
+    ("sdar pass of four positions", 32, 8, 128, 128, 2048, 768),
 )
 REHEARSAL_EXPERT_SHAPES = (
     ("toy step off the sublane tile", 6, 3, 4, 16, 128, 64),

@@ -1,9 +1,10 @@
-"""What the eight language models share (`deepseek_v2.py`, `ouro.py`,
+"""What the nine language models share (`deepseek_v2.py`, `ouro.py`,
 `solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`,
-`glm_dsa.py`, `granite_hybrid.py`): the blocks and helpers they are
-written from, the one initialisation rule, sampling on the device, the
-rule by which a drafted token is kept or replaced, the two decode loops,
-the prefill in parts, and the stand-in tokenizer.
+`glm_dsa.py`, `granite_hybrid.py`, `sdar.py`): the blocks and helpers
+they are written from, the one initialisation rule, sampling on the
+device, the rule by which a drafted token is kept or replaced and the
+rule by which a block's drawn tokens are kept or masked again, the three
+decode loops, the prefill in parts, and the stand-in tokenizer.
 
 A bundle's `lm` part is an object with this contract (`LanguageModel`
 below holds what every model's class has alike), which is all that
@@ -37,7 +38,7 @@ What a model's two programs are written from, so that a loop is written
 once:
 
 - a `decode` is the model's one-token step handed to `decode_loop`
-  (every model);
+  (every model that emits its tokens in order);
 - where the model has an MTP module, `draft_tokens` 1 is its `mtp_step`
   and `main_step` behind one return shape, and what it does to its own
   state once a draft's fate is known, handed to `draft_loop` (K-EXAONE,
@@ -48,7 +49,12 @@ once:
   and the arrays it wants cut to `prefill_in_parts` (GLM-5.2,
   granite-4.0-h-micro), which owns the cut (`parts_of`), the scan over
   the whole parts, the remainder and the joining of the parts' outputs;
-  the model allocates the state before it and reads the outputs after.
+  the model allocates the state before it and reads the outputs after;
+- where the model generates by masked diffusion over blocks, a `decode`
+  is its pass over one block of positions handed to `denoise_loop`
+  (SDAR), which owns the blocks' ids and masks, the keys, the draws
+  (`sample_with_confidence`), the rule (`transfer`), the closing pass
+  and `counts`: a step's yield is a set of positions, not a suffix.
 """
 
 from __future__ import annotations
@@ -154,6 +160,147 @@ def sample(logits, key, temperature):
     traced scalar, so every value runs the one program."""
     drawn = jax.random.categorical(key, logits / jnp.where(temperature > 0, temperature, 1.0))
     return jnp.where(temperature > 0, drawn, jnp.argmax(logits)).astype(jnp.int32)
+
+
+def sample_with_confidence(logits, key, temperature):
+    """`sample`, and beside the id the probability softmax(logits /
+    temperature) gives it (at temperature 0: the largest logit's id and
+    its probability at temperature 1): what a masked-diffusion step's
+    rule (`transfer`) ranks the drawn ids by."""
+    drawn = sample(logits, key, temperature)
+    scaled = logits / jnp.where(temperature > 0, temperature, 1.0)
+    return drawn, jnp.exp(scaled[drawn] - jax.nn.logsumexp(scaled))
+
+
+# --- unmasking: which of a block's drawn ids are kept ----------------------
+
+
+def transfer(confidence, masked, n, threshold):
+    """The dynamic low-confidence rule over one block: `confidence` [B]
+    float32 of the ids drawn at each position, `masked` [B] which
+    positions are still masked, `n` how many a pass has to fill in at
+    least, `threshold` the confidence above which a drawn id is kept
+    anyway. If the masked positions above the threshold number `n` or
+    more, all of them are kept; else the `n` most confident masked ones,
+    ties to the lower index. A position that is not masked is never
+    kept (fewer than `n` may be left). Returns (kept [B] bool, whether
+    the threshold decided)."""
+    confidence = jnp.where(masked, confidence, -jnp.inf)
+    above = confidence > threshold
+    index = jnp.arange(confidence.shape[0])
+    ahead = (confidence[None, :] > confidence[:, None]) | (
+        (confidence[None, :] == confidence[:, None]) & (index[None, :] < index[:, None]))
+    by_threshold = above.sum() >= n
+    return jnp.where(by_threshold, above, (ahead.sum(axis=1) < n) & masked), by_threshold
+
+
+def blocks_most(steps: int, block: int) -> int:
+    """The blocks a `denoise_loop` of `steps` new ids may walk: one more
+    than the ids fill where the first opens with left-over prompt tokens."""
+    return -(-(steps + block - 1) // block)
+
+
+def denoise_loop(block_step, cache, opening, start, key, temperature, steps: int, block: int,
+                 passes: int, threshold: float, mask_id: int, kept_stride: int = 1):
+    """`steps` new ids by masked diffusion over blocks of `block`
+    positions: a `fori_loop` over the blocks from the one that holds
+    position `start` (the prompt's length; the `start` % `block` prompt
+    tokens past the last whole block are `opening`'s first entries and
+    open the first block unmasked) and inside it a `while_loop` of
+    denoising passes that ends with the closing pass. A block starts as
+    its known ids and `mask_id` elsewhere. A denoising pass runs the
+    block through the model, draws an id at every masked position
+    (`sample_with_confidence`, the key folded by block, then pass, then
+    split a position) and `transfer` keeps at least `block` // `passes`
+    of them (one more in the first `block` % `passes` passes), so no
+    block takes more than `passes`; once no position is masked the
+    closing pass runs the block's final ids through the model, whose
+    writes to its state are the ones that stand. What is masked is a
+    boolean of the loop's own, not read off the ids.
+
+    What a model hands over: `block_step(cache, tokens [block],
+    position, close)` -> (logits [block, vocab] float32 (a closing pass:
+    None, nothing reads them), cache, a tree the loop sums over the
+    passes, a dict of which the loop keeps every denoising pass's or
+    None), the shape `decode_loop`'s `step` has; a logit at row i is of
+    position `position` + i itself. `close` is static: two traces.
+
+    Returns (cache, ids [steps]: the first `steps` after `start`, counts
+    [4] int32: denoising passes, closing passes, positions kept by the
+    threshold, by the floor; the summed tree; and where a pass keeps
+    anything, rows [blocks the loop may walk, `passes`, ...]: the loop's
+    own `tokens` (the block as the pass saw it), `masked`, `drawn`,
+    `moved`, `position` (-1 where no pass was taken) for every pass, and
+    the step's own dict for the passes of every `kept_stride`-th block
+    [ceil(blocks / stride), `passes`, ...]; else None)."""
+    most = blocks_most(steps, block)
+    left = start % block
+    first = start - left
+    index = jnp.arange(block)
+
+    def denoise(b, s, cache, tokens, masked):
+        position = first + b * block
+        logits, cache, added, now = block_step(cache, tokens, position, False)
+        with jax.named_scope("transfer"):
+            keys = jax.random.split(jax.random.fold_in(jax.random.fold_in(key, b), s), block)
+            drawn, confidence = jax.vmap(sample_with_confidence, (0, 0, None))(
+                logits, keys, temperature)
+            n = block // passes + (s < block % passes)
+            moved, by_threshold = transfer(confidence, masked, n, threshold)
+        own = {"tokens": tokens, "masked": masked, "drawn": drawn, "moved": moved,
+               "position": position}
+        counted = jnp.stack([1, 0, 0, 0]) + moved.sum() * jnp.stack(
+            [0, 0, by_threshold, ~by_threshold])
+        return (cache, jnp.where(moved, drawn, tokens), masked & ~moved,
+                counted.astype(jnp.int32), added, own, now)
+
+    def one_block(b, carry):
+        cache, ids, counts, tally, kept = carry
+        opens = (b == 0) & (index < left)
+        tokens = jnp.where(opens, opening, mask_id).astype(jnp.int32)
+
+        def body(c):
+            s, cache, tokens, masked, counts, tally, kept = c
+            cache, tokens, masked, counted, added, own, now = denoise(b, s, cache, tokens, masked)
+            if kept is not None:
+                mine, theirs = kept
+                mine = jax.tree_util.tree_map(lambda rows, row: rows.at[b, s].set(row), mine, own)
+                row_b = jnp.where(b % kept_stride == 0, b // kept_stride, most)
+                theirs = jax.tree_util.tree_map(
+                    lambda rows, row: rows.at[row_b, s].set(row, mode="drop"), theirs, now)
+                kept = (mine, theirs)
+            return (s + 1, cache, tokens, masked, counts + counted,
+                    jax.tree_util.tree_map(jnp.add, tally, added), kept)
+
+        _, cache, tokens, _, counts, tally, kept = jax.lax.while_loop(
+            lambda c: c[3].any(), body,
+            (jnp.int32(0), cache, tokens, ~opens, counts, tally, kept))
+        with jax.named_scope("close"):
+            _, cache, added, _ = block_step(cache, tokens, first + b * block, True)
+        ids = jax.lax.dynamic_update_slice(ids, tokens, (b * block,))
+        return (cache, ids, counts.at[1].add(1),
+                jax.tree_util.tree_map(jnp.add, tally, added), kept)
+
+    # what a pass adds and keeps, as shapes: see `decode_loop`
+    with route_log():
+        *_, tally, own, kept = jax.eval_shape(
+            denoise, jnp.int32(0), jnp.int32(0), cache, jnp.zeros((block,), jnp.int32),
+            jnp.ones((block,), bool))
+    if kept is not None:
+        rows = -(-most // kept_stride)
+        kept = (
+            {**zeros(jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct((most, passes, *s.shape), s.dtype), own)),
+             "position": jnp.full((most, passes), -1, jnp.int32)},
+            zeros(jax.tree_util.tree_map(
+                lambda s: jax.ShapeDtypeStruct((rows, passes, *s.shape), s.dtype), kept)))
+    carry = (cache, jnp.zeros((most * block,), jnp.int32), jnp.zeros((4,), jnp.int32),
+             zeros(tally), kept)
+    cache, ids, counts, tally, kept = jax.lax.fori_loop(
+        0, (left + steps + block - 1) // block, one_block, carry)
+    if kept is not None:
+        kept = {**kept[0], **kept[1]}
+    return cache, jax.lax.dynamic_slice(ids, (left,), (steps,)), counts, tally, kept
 
 
 # --- drafting: the lossless rule of a self-speculative step ----------------

@@ -1,6 +1,7 @@
 """The expert layer of one chip, as the language models with a mixture
 of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`,
-`ling_flash.py`, `nemotron_h.py`), and the routing rule of those that score by sigmoids
+`ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`, `sdar.py`), and the
+routing rule of those that score by sigmoids
 (`sigmoid_route`, with or without groups chosen first).
 
 The layer is told which routed experts it holds (`held`, a contiguous
@@ -8,8 +9,9 @@ run: `parallel.sharding.expert_range` of the chip's rank), routes every
 token over all of them by the model's own rule, and computes its own
 experts' part of the result as one grouped product
 (`jax.lax.ragged_dot`) with no dropped token and no capacity factor;
-what absent experts would add is left out. The shared expert sees every
-token.
+what absent experts would add is left out. Where the layer's tree has a
+shared expert (`shared`: six of the seven models; SDAR has none) it sees
+every token.
 
 Sorted by held expert, the pairs a chip holds are the first rows and
 every row after them is an absent expert's, so the row gather, the
@@ -23,8 +25,9 @@ run, on a TPU, in the Pallas kernel of `ops/expert_matvec.py`, which
 reads each chosen held expert's weights once, out of the stacked array
 (`decode_route`); the prefill's stay `ragged_dot`.
 
-Parameters: `w_g` [hidden, experts] (the router), `experts` and `shared`
-in the expert's form, which the tree itself says (`gated`): a SwiGLU,
+Parameters: `w_g` [hidden, experts] (the router), `experts` and, where
+there is one, `shared` in the expert's form, which the tree itself says
+(`gated`): a SwiGLU,
 {`w_gate_up` [held, hidden, 2 x width], `w_down` [held, width, hidden]}
 and `shared` one such, or two matrices without a gate, relu(x W_up)^2
 W_down (Nemotron-H's): {`w_up` [held, width, hidden], stored out by in
@@ -136,8 +139,9 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
     products; an expert without a gate (`gated`) has nothing to clamp.
     With `index` (a traced scalar: a scan's body) `p["experts"]` holds
     stacks of layers `[layers, held, ...]` of which this layer is that
-    one, read where it lies. Returns (output, chosen ids [T, k], pairs on each held
-    expert [held])."""
+    one, read where it lies. A tree without `shared` is a layer without a
+    shared expert: the output is the routed part alone. Returns (output,
+    chosen ids [T, k], pairs on each held expert [held])."""
     with jax.named_scope("router"):
         logits = jnp.dot(
             x.astype(jnp.float32), p["w_g"].astype(jnp.float32),
@@ -199,6 +203,8 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
         else:
             routed = jax.lax.switch(
                 rung_index(ladder, sizes.sum()), [partial(over, rows_n) for rows_n in ladder])
+    if "shared" not in p:
+        return routed.astype(x.dtype), ids, sizes
     with jax.named_scope("shared"):
         shared = (swiglu(x, p["shared"], shared_limit) if gated(p["shared"])
                   else relu2_mlp(x, p["shared"]))

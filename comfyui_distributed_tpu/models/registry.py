@@ -25,6 +25,7 @@ from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .k_exaone import KExaone, KExaoneConfig
 from .ling_flash import LingFlash, LingFlashConfig
 from .nemotron_h import NemotronH, NemotronHConfig
+from .sdar import Sdar, SdarConfig
 from .solar_open2 import SolarOpen2, SolarOpen2Config
 from .t5_encoder import T5Encoder, T5EncoderConfig
 from .text_encoder import TextEncoder, TextEncoderConfig
@@ -656,6 +657,25 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             vocab_size=4096, prefill_part=16,
         ),
     },
+    # JetLM/SDAR-30B-A3B-Chat as stage 0 of an eight-stage pipeline, every
+    # width as published: published layers 0-5 of 48, all 128 experts of each,
+    # all 151,936 ids (4.36 B parameters, 8.72 GB in bfloat16; the benchmark's
+    # sdar-30b-a3b-chat configuration says what the cut stands for)
+    "sdar-30b-a3b-pp8-6l": {
+        "family": "lm",
+        "config": SdarConfig(num_hidden_layers=6),
+    },
+    # every mechanism at a size for the CPU: 3 layers, 4 query heads over 2
+    # key heads of 16, 8 experts of 32 columns (2 a token), 512 ids of which
+    # the last is the mask's, blocks of 4 filled in by at most 4 passes
+    "tiny-sdar": {
+        "family": "lm",
+        "config": SdarConfig(
+            hidden_size=64, num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=16, moe_intermediate_size=32, num_experts=8, num_experts_per_tok=2,
+            vocab_size=512, mask_token_id=511,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -716,6 +736,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     NemotronHConfig: NemotronH,
     GlmDsaConfig: GlmDsa,
     GraniteHybridConfig: GraniteHybrid,
+    SdarConfig: Sdar,
 }
 
 

@@ -172,34 +172,45 @@ def dot_product_attention(
     return flash_attention(q, k, v, interpret=interpret)
 
 
-def _check_causal(q: jax.Array, k: jax.Array, v: jax.Array) -> None:
+def _check_causal(q: jax.Array, k: jax.Array, v: jax.Array, window=None, block=None) -> None:
     n, m, heads, kv_heads = q.shape[1], k.shape[1], q.shape[2], k.shape[2]
     if n > m:
         raise ValueError(f"causal attention of {n} queries over {m} keys")
     if heads % kv_heads or v.shape[2] != kv_heads:
         raise ValueError(f"{heads} query heads over {kv_heads} / {v.shape[2]} key / value heads")
+    if block is not None and (
+            window is not None or block < 1 or block & (block - 1) or ROUTE_MULTIPLE % block
+            or m % block):
+        raise ValueError(
+            f"a block mask of {block} positions over {m} keys (window {window}): written for "
+            f"a power of two that divides {ROUTE_MULTIPLE} and the keys, and for no window")
 
 
 def causal_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float | None = None,
     window: int | None = None, force_flash: bool | None = None, interpret: bool = False,
+    block: int | None = None,
 ) -> jax.Array:
     """Causal attention, q/k [B, N|M, H|H_kv, Dq] and v [B, M, H_kv, Dv]:
     query i sees keys up to i + M - N, under a `window` the last `window`
-    of them. One computation on two routes, chosen from the operands and
+    of them; under a `block` (a power of two) the mask is causal over
+    blocks of that many positions and full inside one: the query whose
+    own key is p sees every key j <= p | (block - 1), the rest of its own
+    block among them (a model that fills a block in by unmasking: SDAR).
+    One computation on two routes, chosen from the operands and
     the backend alone (`causal_route`): `flash_attention` under its causal
     mask, or `causal_attention_blocked`, the form every other backend
     keeps and the kernel's tests compare with. `force_flash` and
     `interpret` are the tests', as in `dot_product_attention`. A call
     that takes the kernel logs `flash-causal NxMxDq/Dv [pad<N'>x<M'>]
     [w<window>] g<query heads a key head> bq<block_q> bk<block_k> <dtype>
-    [inplace] blocks<computed>/<square>`: the last is how much of the
-    square of blocks the grid computes."""
-    _check_causal(q, k, v)
+    [b<block>] [inplace] blocks<computed>/<square>`: the last is how much
+    of the square of blocks the grid computes."""
+    _check_causal(q, k, v, window, block)
     if force_flash is None:
         force_flash = causal_route(q, k, v, window) == "flash"
     if not force_flash:
-        return causal_attention_blocked(q, k, v, scale=scale, window=window)
+        return causal_attention_blocked(q, k, v, scale=scale, window=window, block=block)
     log = _ROUTE_LOG.get()
     if log is not None:
         n, m, d, dv = q.shape[1], k.shape[1], q.shape[3], v.shape[3]
@@ -212,12 +223,14 @@ def causal_attention(
             entry += f" pad{n_pad}x{m_pad}"
         if window is not None:
             entry += f" w{window}"
+        if block is not None:
+            entry += f" b{block}"
         entry += f" g{q.shape[2] // k.shape[2]} bq{block_q} bk{block_k}"
         entry += f" {_DTYPE_NAMES.get(q.dtype.name, q.dtype.name)}"
         entry += "" if pad and pad_v else " inplace"
         log.append(f"{entry} blocks{computed}/{n_pad // block_q * (m_pad // block_k)}")
     return flash_attention(
-        q, k, v, scale=scale, interpret=interpret, causal=True, window=window)
+        q, k, v, scale=scale, interpret=interpret, causal=True, window=window, block=block)
 
 
 # Query rows a block of `causal_attention_blocked` takes: its float32
@@ -227,7 +240,7 @@ CAUSAL_BLOCK_Q = 256
 
 def causal_attention_blocked(
     q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float | None = None,
-    window: int | None = None,
+    window: int | None = None, block: int | None = None,
 ) -> jax.Array:
     """Causal attention, q/k [B, N|M, H, Dq] and v [B, M, H, Dv] with a
     width of its own, as XLA operations over blocks of query rows: a
@@ -236,15 +249,18 @@ def causal_attention_blocked(
     score tensor is never whole in memory. Under a `window` a row sees
     only the last `window` keys up to its own (itself among them), and a
     block takes only the keys its band reaches, `window` - 1 before its
-    first row: the rest are skipped, not masked. Scores, softmax and both
-    accumulations are float32; the probabilities are rounded to v's
+    first row: the rest are skipped, not masked. Under a `block` a row
+    sees up to the end of its own key's block of that many positions, and
+    a block of rows takes the keys up to its last row's. Scores, softmax
+    and both accumulations are float32; the probabilities are rounded to v's
     dtype for the second product, as the kernel does. k and v may have
     fewer heads than q, a divisor of its count: key head j then serves
     the query heads j x group .. (j + 1) x group - 1, and is read where
     it lies, not repeated."""
-    _check_causal(q, k, v)
+    _check_causal(q, k, v, window, block)
     n, m, d = q.shape[1], k.shape[1], q.shape[3]
     heads, kv_heads = q.shape[2], k.shape[2]
+    ahead = 0 if block is None else block - 1  # `own | ahead`: the last key of own's block
     if kv_heads == heads:
         to_scores, to_out = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"
     else:  # a group axis g beside the key head h
@@ -252,23 +268,26 @@ def causal_attention_blocked(
         to_scores, to_out = "bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd"
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    block = min(CAUSAL_BLOCK_Q, n)  # the last block is short where n is no multiple
+    rows_q = min(CAUSAL_BLOCK_Q, n)  # the last block is short where n is no multiple
     log = _ROUTE_LOG.get()
     if log is not None:
         name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
         banded = "" if window is None else f" w{window}"
-        log.append(f"xla-causal {n}x{m}x{d}/{v.shape[3]}{banded} bq{block} {name}")
+        banded += "" if block is None else f" b{block}"
+        log.append(f"xla-causal {n}x{m}x{d}/{v.shape[3]}{banded} bq{rows_q} {name}")
     outs = []
-    for start in range(0, n, block):
-        stop = min(start + block, n)
-        last = stop + m - n  # keys the block's last row sees
+    for start in range(0, n, rows_q):
+        stop = min(start + rows_q, n)
+        last = (stop + m - n - 1 | ahead) + 1  # keys the block's last row sees
         # the first key the block's first row sees
         first = 0 if window is None else max(start + m - n - window + 1, 0)
         scores = scale * jnp.einsum(
             to_scores, q[:, start:stop], k[:, first:last], preferred_element_type=jnp.float32)
         rows = jnp.arange(start, stop)[:, None] + (m - n)
         cols = jnp.arange(first, last)[None, :]
-        seen = rows >= cols if window is None else (rows >= cols) & (rows - cols < window)
+        seen = (rows | ahead if ahead else rows) >= cols
+        if window is not None:
+            seen &= rows - cols < window
         scores = jnp.where(seen, scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
         outs.append(jnp.einsum(
@@ -429,11 +448,11 @@ def causal_blocks(n: int, block_q: int, block_k: int, rows: int, keys: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "scale", "causal", "window"))
+    jax.jit, static_argnames=("interpret", "scale", "causal", "window", "block"))
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     scale: float | None = None, interpret: bool = False,
-    causal: bool = False, window: int | None = None,
+    causal: bool = False, window: int | None = None, block: int | None = None,
 ) -> jax.Array:
     """Tiled online-softmax attention (Pallas).
 
@@ -502,8 +521,13 @@ def flash_attention(
     the index maps: nothing is repeated in HBM), and v a width of its
     own, which is the output's: q and k of a width off the lane tile are
     padded where they lie when v's is on it (DeepSeek-V2's 192 beside
-    128). Clamp, comparison, head map and the second width are emitted
-    for a call that has them only: every other call traces to the
+    128). Under a `block` (`causal_attention` says which: a power of two
+    that divides the lane tile, so that a block of positions never
+    straddles two k blocks) a row sees to the end of its own key's block:
+    the k blocks a q block sees (`key_block_range`) and those the diagonal
+    crosses are the causal call's own, and only the comparison in a
+    crossed block differs. Clamp, comparison, head map and the second
+    width are emitted for a call that has them only: every other call traces to the
     program it traced to before they existed (tests/test_causal_attention.py).
     """
     from jax.experimental import pallas as pl
@@ -603,6 +627,8 @@ def flash_attention(
                 if n > rows:  # a padded row sees what the last true row sees
                     row = jnp.minimum(row, rows - 1)
                 own = row + (keys - rows)  # the row's own key: padded keys lie past it
+                if block is not None:  # to the end of its block, which the keys hold whole
+                    own |= block - 1
                 cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
                 visible = cols <= own
                 if window is not None:

@@ -106,6 +106,16 @@ def position_valid(positions: jax.Array, size: int) -> jax.Array:
     return jnp.arange(size)[None, :] <= positions[:, None]
 
 
+def block_valid(position, size: int, block: int) -> jax.Array:
+    """[block, size]: which entries of a cache that grows the `block`
+    queries at `position` .. `position` + `block` - 1 (one whole block of
+    a mask that is causal over blocks and full inside one) may see: all
+    of them every entry below the block's end, their own among them, so
+    the block's keys and values are written before they are attended."""
+    seen = jnp.arange(size) < position + block
+    return jnp.broadcast_to(seen[None, :], (block, size))
+
+
 def ring_valid(positions: jax.Array, ring: int, window: int) -> jax.Array:
     """[W, ring]: which entries of a ring the queries at `positions` [W]
     (ascending, the last the newest position written) may see. Entry s

@@ -55,8 +55,13 @@ _TESTS = os.path.join(
 # what PR 52 found (`ssm_device_pct.lm` listing Nemotron-3-Nano's cell
 # alone): `test_granite_hybrid_readers.py` has the one that holds every
 # list from its start and none to its end.
-_LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_each_list_from_"
-           "its_start")
+#
+# PR 58 appended a ninth model's cell to a list that form had held to be
+# what PR 54 found (`attn_device_pct.lm` listing Nemotron-3-Nano's and
+# granite's cells alone): `test_sdar_readers.py` has the one that holds no
+# list to its end anywhere.
+_LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_no_list_held_to_"
+           "its_end")
 _MODULES = {}
 _SUPERSEDED = {
     "test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys":
@@ -73,6 +78,8 @@ _SUPERSEDED = {
     "test_the_solar_cell_is_listed_where_its_readers_find_something": _LISTED,
     "test_the_lm_cells_are_listed_where_their_readers_find_something": _LISTED,
     "test_the_lm_cells_are_listed_where_their_readers_find_something_each_after_those_before":
+        _LISTED,
+    "test_the_lm_cells_are_listed_where_their_readers_find_something_each_list_from_its_start":
         _LISTED,
 }
 

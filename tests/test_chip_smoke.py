@@ -205,6 +205,21 @@ def test_the_legs_have_the_fifth_models_rows_at_its_own_numbers(smoke):
     assert smoke.KDA_STATES[1:] == (cfg.kda_layers, cfg.num_attention_heads, cfg.head_dim)
 
 
+def test_the_experts_leg_has_the_ninth_models_row_at_its_own_numbers(smoke):
+    """SDAR's row (PR 58): a pass's four positions, 8 experts each of the
+    128 it holds all of, at 768 columns. Its prefill's call under the
+    block mask has no row among `CAUSAL_SHAPES` (a row has no block):
+    `test_flash_kernel_v5e.py` compiles it inside the model's prefill."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("sdar-30b-a3b-pp8-6l")
+    experts = {label: tuple(rest) for label, *rest in smoke.EXPERT_SHAPES}
+    assert experts["sdar pass of four positions"] == (
+        cfg.block_length * cfg.num_experts_per_tok, cfg.num_experts_per_tok,
+        len(cfg.held_experts), cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size)
+    assert len(cfg.held_experts) == cfg.num_experts == 128
+
+
 def test_the_legs_have_the_sixth_models_rows_at_its_own_numbers(smoke):
     """Nemotron-3-Nano's rows (PR 48): the `[2688, 1856]` / `[1856, 2688]`
     `expert_matvec` shapes of its experts without a gate, the 32 : 2

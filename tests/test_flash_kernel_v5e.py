@@ -812,3 +812,51 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
     assert not [line for line in text.splitlines()
                 if (" sort(" in line or " gather(" in line) and "32896" in line]
     assert text.count('custom_call_target="tpu_custom_call"') == 2 * (cfg.sparse_layers + 1)
+
+
+def test_sdar_prefill_masks_by_block_in_the_causal_kernel_and_its_decode_carries_six_leaves_in_place(
+        one_chip, monkeypatch):
+    """SDAR's two programs at the served stage's sizes (2,048 prompt
+    tokens, 512 ids over 2,560 positions) on the TPU's route: the prefill
+    attends a layer in the causal kernel under the block mask (the tiles
+    of the plain causal call, `b4` in the route's entry); the decode is
+    two nested `while` loops that carry the donated leaf a layer (31.5 MB
+    together) and write a block's four positions where they lie; a
+    denoising pass multiplies its 32 pairs in `expert_matvec` (two calls a
+    layer), a closing pass stops at the last layer's keys and values (two
+    calls fewer). What the loops need beside the tree is some 20 MB (a
+    pass's float32 logits and their draws)."""
+    from comfyui_distributed_tpu.models import sdar
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("sdar-30b-a3b-pp8-6l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: sdar.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        compiled = sdar.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((2048,), jnp.int32, sharding=one_chip),
+            cache_len=2560).compile()
+    assert routes == [
+        "flash-causal 2048x2048x128/128 b4 g8 bq512 bk1024 bf16 inplace blocks6/8"] * 6
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_attention_causal[.\d]* = ", text)) == 6
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20
+
+    state = jax.tree.map(place, sdar.state_shapes(cfg, 2560, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        compiled = sdar.decode.lower(
+            cfg, params, state,
+            jax.ShapeDtypeStruct((cfg.vocab_size,), jnp.float32, sharding=one_chip),
+            scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+            scalar(jnp.float32), steps=512).compile()
+    assert set(routes) == {"decode-xla 32x2560x128"}
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 32 * 2**20
+    # the donated tree is the output's: nothing of its size is allocated anew
+    assert memory.alias_size_in_bytes >= 31_457_280
+    text = compiled.as_text()
+    assert text.count(" while(") >= 2
+    assert len(re.findall(r"%expert_matvec[.\d]* = ", text)) == 2 * 6 + 2 * 5

@@ -530,6 +530,72 @@ def glm_entry(cfg, config):
         cfg, num_hidden_layers=78, first_layer=0, ep_size=1, vocab_shards=1)  # nothing else cut
 
 
+def sdar_drawn(attrs):
+    # every expert is held: every pair falls on one, and the prefill's ladder has one rung
+    for phase in ("prefill", "decode"):
+        pairs = attrs[f"{phase}_routed_pairs"]
+        assert attrs[f"{phase}_routed_pairs_held"] == pairs == attrs[f"{phase}_expert_rows"]
+        assert pairs / (3 * 8) <= attrs[f"{phase}_expert_load_max"] <= pairs
+    # 16 ids are four blocks of 4: a closing pass a block, and at most 4 denoising passes
+    denoise, closing = attrs["denoise_passes"], attrs["closing_passes"]
+    assert closing == 4 and closing <= denoise <= 4 * closing
+    assert attrs["decode_steps"] == denoise + closing
+    assert attrs["transferred_by_threshold"] + attrs["transferred_by_floor"] == 16
+    assert attrs["transferred_by_floor"] <= denoise
+    # four positions a pass through three layers; a closing pass stops at the last one's keys
+    assert attrs["decode_layer_passes"] == 4 * (denoise * 3 + closing * 2)
+    assert attrs["decode_routed_pairs"] == attrs["decode_layer_passes"] * 2
+    # distinct experts a pass and layer read: 2 to 8 of 8 for four positions' two each
+    bodies = denoise * 3 + closing * 2
+    assert 2 * bodies <= attrs["decode_experts_read"] <= 8 * bodies
+
+
+def sdar_workflow(mine, deepseeks):
+    assert differing(mine, deepseeks) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "max_new_tokens"),
+        ("TextGenerate", "draft_tokens"), ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["draft_tokens"], generate["temperature"]) == (
+        512, 0, 1.0)
+    # the DeepSeek cell's 2,047-byte instruction, byte for byte: 512 whole blocks of 4
+    assert generate["text"] == by_kind(deepseeks)["TextGenerate"]["text"]
+
+
+def sdar_published(config):
+    assert config["model_type"] == "sdar_moe"
+    assert (config["decoder_sparse_step"], config["mlp_only_layers"]) == (1, [])
+    assert config["published"] == {"num_hidden_layers": 48, "parameters": 30532122624}
+    assert config["held"]["parameters"] == 4361055744
+    assert config["as_run"]["parameters"] == {"lm": 4361055744}
+    assert (config["as_run"]["cache_bytes_per_token"], config["as_run"]["state_bytes"]) == (
+        12288, 0)
+    assert (config["as_run"]["block_length"], config["as_run"]["denoising_steps"],
+            config["as_run"]["confidence_threshold"], config["as_run"]["mask_token_id"]) == (
+        4, 4, 0.85, 151669)
+    assert set(config["held"]) == {
+        "layers", "experts", "vocabulary", "parameters", "bytes", "state", "bent_by_the_cut"}
+    assert "published layers 0-5 of 48" in config["held"]["layers"]
+    assert "all 128" in config["held"]["experts"]
+    assert "ids 0-151,935" in config["held"]["vocabulary"]
+    assert "8 pipeline stages of 6 layers" in config["deployment"]
+    assert "no layer is shared between chips" in config["deployment"]
+    assert "a quarter" in config["held"]["bent_by_the_cut"]
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_median"] <= limits["tolerance_rel_l2_max_unflipped"] < 0.2
+    assert 0 < limits["tolerance_expert_set_mismatch"] < 0.5
+    assert 0 <= limits["tolerance_transfer_mismatch"] < 0.5
+    # layer 0's keys are arithmetic and storage alone: far tighter than the deepest's
+    assert 0 < 2 * limits["tolerance_kv_rel_l2_first"] < limits["tolerance_kv_rel_l2_last"] < 0.1
+
+
+def sdar_entry(cfg, config):
+    assert (cfg.num_hidden_layers, len(cfg.held_experts), cfg.vocab_size) == (6, 128, 151936)
+    assert (cfg.block_length, cfg.denoising_steps, cfg.confidence_threshold,
+            cfg.mask_token_id) == tuple(config["as_run"][key] for key in (
+                "block_length", "denoising_steps", "confidence_threshold", "mask_token_id"))
+    assert type(cfg)() == dataclasses.replace(cfg, num_hidden_layers=48)  # nothing else cut
+
+
 @dataclasses.dataclass(frozen=True)
 class Model:
     """One language model's row. `attrs`: `node.TextGenerate`'s attributes
@@ -947,6 +1013,52 @@ MODELS = [
                            "attn_device_pct.lm", "mlp_device_pct.lm",
                            "flash_attention_causal_roofline_pct.lm"}),
     ),
+    Model(
+        name="sdar-30b-a3b", served="sdar-30b-a3b-pp8-6l", tiny="tiny-sdar",
+        workflow="rewrite-txt2img-sdar-30b-a3b.json", config="sdar-30b-a3b-chat.json",
+        reference="sdar.py", catalog="SDAR-30B-A3B-Chat",
+        cell="sdar_30b_a3b_rewrite_txt2img_512.closed2", prompt=2048, new_tokens=16, drafts=0,
+        # tiny-sdar: 3 layers, 4 query heads over 2 key heads of 16, 8 experts of 32 columns
+        # all held, 2 a token, 512 ids of which the last is the mask's, blocks of 4 filled in
+        # by at most 4 passes. What grows: three layers' keys and values; nothing else
+        attrs={
+            "prompt_tokens": 2048, "new_tokens": 16, "draft_tokens": 0,
+            "layers": 3, "block_length": 4, "denoising_steps": 4,
+            "experts_held": 8, "experts_total": 8,
+            "cache_bytes": 3 * 2 * 2 * (2048 + 16) * 16 * 4, "state_bytes": 0,
+            "prefill_layer_passes": 2048 * 3, "prefill_routed_pairs": 2048 * 3 * 2,
+            "prefill_routed_pairs_held": 2048 * 3 * 2, "prefill_expert_rows": 2048 * 3 * 2,
+            "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "prefill_routed_pairs_held",
+                                   "prefill_expert_rows", "decode_expert_route"}) | {
+            "decode_steps", "denoise_passes", "closing_passes", "transferred_by_threshold",
+            "transferred_by_floor", "decode_layer_passes", "decode_experts_read"},
+        drawn_check=sdar_drawn,
+        # the ids, the pairs per expert of either program and the five counts
+        wait_bytes=4 * (16 + 3 * 8 + 3 * 8 + 5),
+        # a pass's four queries over the cache, the block's own entries among what they see;
+        # the prefill under the block mask
+        attention="decode-xla 4x2064x16, xla-causal 2048x2048x16/16 b4 bq256 f32",
+        passes=lambda attrs: (2048 * 3, attrs["decode_layer_passes"]),
+        widths={
+            "hidden_size": 2048, "num_attention_heads": 32, "num_key_value_heads": 4,
+            "head_dim": 128, "intermediate_size": 6144, "moe_intermediate_size": 768,
+            "num_experts": 128, "num_experts_per_tok": 8, "norm_topk_prob": True,
+            "decoder_sparse_step": 1, "mlp_only_layers": [], "vocab_size": 151936,
+            "rms_norm_eps": 1e-6, "rope_theta": 1000000, "rope_scaling": None,
+            "attention_bias": False, "hidden_act": "silu", "sliding_window": None,
+            "use_sliding_window": False, "max_window_layers": 48,
+            "max_position_embeddings": 32768, "tie_word_embeddings": False,
+            "model_type": "sdar_moe"},
+        reduced={"num_hidden_layers": (48, 6)},
+        assumed=("generate.py", "low_confidence_dynamic", "kept as a boolean",
+                 "closing pass runs no head", "Qwen3-MoE", "seeded random", "stand-in",
+                 "batch is 1", "house style guide"),
+        published=sdar_published, entry=sdar_entry, check_workflow=sdar_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "attn_device_pct.lm",
+                           "denoise_passes_per_token.lm", "experts_device_pct.lm",
+                           "expert_union_hbm_pct.lm"}),
+    ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
 LM_ENTRIES = sorted(name for name, entry in MODEL_REGISTRY.items() if entry["family"] == "lm")
@@ -1246,6 +1358,8 @@ def test_every_language_model_meets_the_one_contract(name):
         # a part's loads and keys seen (visible, read) a layer, then the decode's
         "glm-5.2": ([[[3] * held]], [[[9], [5]]], [[1] * held], [[7], [2]]),
         "granite-4.0-h-micro": (),  # nothing is read back beside the ids
+        # the loads of either program and the decode's five counts: one block of one pass
+        "sdar-30b-a3b": ([[3] * held], [[1] * held], [1, 1, 0, 4, 9]),
     }.get(model.name, ([[3] * held], [[1] * held]))
     if model.drafts:
         read += ([4, 0, 0, 2],)
@@ -1277,7 +1391,8 @@ def test_every_language_model_meets_the_one_contract(name):
 @pytest.mark.parametrize("name, passes", [
     ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
     ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7),
-    ("nemotron3-nano-ep16-52l", 52), ("glm-5.2-ep16-5l", 5), ("granite-4.0-h-micro", 40)])
+    ("nemotron3-nano-ep16-52l", 52), ("glm-5.2-ep16-5l", 5), ("granite-4.0-h-micro", 40),
+    ("sdar-30b-a3b-pp8-6l", 6)])
 def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
     from comfyui_distributed_tpu.models.registry import create_model
 
@@ -1329,8 +1444,12 @@ def _step_of(name):
     }[name]
 
 
+# the models that emit their tokens in order; SDAR's decode is `denoise_loop`, held below
+IN_ORDER = [name for name in BY_NAME if name != "sdar-30b-a3b"]
+
+
 @pytest.mark.parametrize("steps", [1, 5])
-@pytest.mark.parametrize("name", list(BY_NAME))
+@pytest.mark.parametrize("name", IN_ORDER)
 def test_decode_is_the_models_own_step_walked_with_the_same_folded_keys(name, steps):
     """`lm_common.decode_loop` under each model's `decode`: the ids are a
     Python loop's over the model's own step (`sample` of the logits under
