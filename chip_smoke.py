@@ -62,6 +62,17 @@ Drives the main path once, through the entry points an operator uses:
                against a float32 reference; it prints us a step, GB/s
                of the chosen experts' weights, and which lowering the
                compiler gave `ragged_dot` at that row count.
+    init       only when named (`--legs init`; it builds every component
+               cold): one child that holds the chip builds SD1.5's, SDXL's
+               and FLUX's components as a start does, each with its one
+               compiled program (`models/pipeline.init_program`), the
+               compile cache off, and prints a row a component: the
+               program's `temp_size_in_bytes` beside the stored bytes
+               (a row fails above a quarter), its cold compile seconds,
+               the seconds it runs, and, where a parent commit is
+               unpacked at scratch/parent, the seconds that commit's
+               `init_params` takes cold and the share of weights whose
+               bits differ from its.
     multichip  only where the server reports two or more chips: the
                serve leg has then already run on every chip through
                the in-process mesh; this leg checks that, and runs the
@@ -108,7 +119,8 @@ import urllib.request
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 PACKAGE = "comfyui_distributed_tpu"
-LEGS = ("serve", "restart", "attention", "experts", "multichip")
+LEGS = ("serve", "restart", "attention", "experts", "multichip", "init")
+DEFAULT_LEGS = LEGS[:-1]  # `init` builds every component cold: only when named
 
 if not os.path.isdir(os.path.join(HERE, PACKAGE)):
     # a check of the program, not a stand-in for it: without the
@@ -642,8 +654,8 @@ def leg_restart(run: Run) -> None:
         server.stop()
 
 
-def leg_child(run: Run, name: str) -> None:
-    """The `attention` and `experts` legs: one child that holds the chip
+def leg_child(run: Run, name: str, limit_s: int = 900) -> None:
+    """The `attention`, `experts` and `init` legs: one child that holds the chip
     (`--<name>-child`; the servers are down by now), its JSON rows
     printed, the first one the device; a row with a `shape` that is not
     `ok`, or none at all, fails the leg."""
@@ -658,11 +670,11 @@ def leg_child(run: Run, name: str) -> None:
         )
         run.children.append(proc)
         try:
-            stdout, _ = proc.communicate(timeout=900)
+            stdout, _ = proc.communicate(timeout=limit_s)
         except subprocess.TimeoutExpired:
             os.killpg(proc.pid, signal.SIGKILL)
             proc.wait()
-            raise Failure(f"{name} child still running after 900s")
+            raise Failure(f"{name} child still running after {limit_s}s")
     rows = [
         json.loads(line) for line in stdout.decode().splitlines()
         if line.startswith("{")
@@ -1733,6 +1745,92 @@ def experts_child(rehearsal: bool) -> int:
 # --- entry -----------------------------------------------------------------
 
 
+INIT_BUNDLES = ("sd15", "sdxl", "flux-dev-5x10")
+REHEARSAL_INIT_BUNDLES = ("tiny-unet",)
+
+
+def parent_init_params():
+    """`init_params` of the commit unpacked at scratch/parent, its
+    `models/pipeline.py` loaded beside this checkout's (whose other
+    modules it imports); None where no commit is unpacked there."""
+    path = os.path.join(HERE, "scratch", "parent", PACKAGE, "models", "pipeline.py")
+    if not os.path.exists(path):
+        return None
+    name = f"{PACKAGE}.models._parent_pipeline"
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = sys.modules[name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.init_params
+
+
+def init_child(rehearsal: bool) -> int:
+    import jax
+    import numpy as np
+
+    if rehearsal:
+        # the CPU keeps float32 by itself; the leg is about the stored form
+        os.environ["CDT_PARAMS_DTYPE"] = "bfloat16"
+    if not child_device(rehearsal):
+        return 1
+    jax.config.update("jax_enable_compilation_cache", False)  # every build here is a cold one
+    from comfyui_distributed_tpu.models import pipeline as pl
+
+    parent_init, load_init = parent_init_params(), pl.init_params
+    leaves, failed = jax.tree_util.tree_leaves, 0
+
+    def bits(x):
+        x = np.asarray(x)
+        return x.view(f"u{x.dtype.itemsize}")
+
+    def measured(bundle):
+        def init_params(module, key, *args, settle=True, **kwargs):
+            nonlocal failed
+            row = {"shape": f"{bundle} {type(module).__name__}"}
+            theirs = None
+            if parent_init is not None:
+                # first, and moved to the host: the two trees never share the chip
+                started = time.perf_counter()
+                tree = jax.block_until_ready(parent_init(module, key, *args, **kwargs))
+                row["parent_s"] = round(time.perf_counter() - started, 2)
+                theirs = [bits(x) for x in leaves(tree)]
+                del tree
+            started = time.perf_counter()
+            program = pl.init_program(module, pl.params_storage_dtype(), key, *args, **kwargs)
+            compiled = program.lower(key).compile()
+            row["compile_s"] = round(time.perf_counter() - started, 2)
+            started = time.perf_counter()
+            mine = jax.block_until_ready(compiled(key))
+            row["run_s"] = round(time.perf_counter() - started, 2)
+            built = leaves(mine)
+            stored = sum(x.nbytes for x in built)
+            temp = compiled.memory_analysis().temp_size_in_bytes
+            row.update(
+                weights=len(built), values=sum(x.size for x in built),
+                stored_bytes=stored, temp_bytes=temp, temp_share=round(temp / stored, 4),
+            )
+            # the CPU does not fuse threefry's passes into the rounding: the bound is a TPU's
+            row["ok"] = rehearsal or temp <= stored / 4
+            if theirs is not None:
+                row["ok"] &= [x.shape for x in theirs] == [x.shape for x in built]
+                if row["ok"]:
+                    row["differ"] = sum(
+                        int(np.count_nonzero(bits(a) != b)) for a, b in zip(built, theirs)
+                    )
+                    row["differ_share"] = row["differ"] / row["values"]
+            failed += not row["ok"]
+            print(json.dumps(row), flush=True)
+            return mine
+        return init_params
+
+    for bundle in REHEARSAL_INIT_BUNDLES if rehearsal else INIT_BUNDLES:
+        pl.init_params = measured(bundle)
+        try:
+            pl.load_pipeline(bundle)
+        finally:
+            pl.init_params = load_init
+    return 1 if failed else 0
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument(
@@ -1741,9 +1839,10 @@ def main(argv=None) -> int:
              "N virtual devices (default 1), Pallas interpreted",
     )
     parser.add_argument(
-        "--legs", default=",".join(LEGS),
-        help=f"comma list of legs to run (default: all of {','.join(LEGS)}; "
-             "multichip runs only where there are two or more chips)",
+        "--legs", default=",".join(DEFAULT_LEGS),
+        help=f"comma list of legs to run, of {','.join(LEGS)} (default: "
+             f"{','.join(DEFAULT_LEGS)}; multichip runs only where there are "
+             "two or more chips)",
     )
     parser.add_argument(
         "--out", default=os.path.join(HERE, "chiprun_out", "chip_smoke"),
@@ -1752,12 +1851,15 @@ def main(argv=None) -> int:
     parser.add_argument("--port", type=int, default=18188)
     parser.add_argument("--attention-child", action="store_true", help=argparse.SUPPRESS)
     parser.add_argument("--experts-child", action="store_true", help=argparse.SUPPRESS)
+    parser.add_argument("--init-child", action="store_true", help=argparse.SUPPRESS)
     args = parser.parse_args(argv)
 
     if args.attention_child:
         return attention_child(bool(args.rehearsal))
     if args.experts_child:
         return experts_child(bool(args.rehearsal))
+    if args.init_child:
+        return init_child(bool(args.rehearsal))
 
     legs = [leg.strip() for leg in args.legs.split(",") if leg.strip()]
     unknown = sorted(set(legs) - set(LEGS))
@@ -1788,6 +1890,8 @@ def main(argv=None) -> int:
             run.attempt("experts", lambda: leg_child(run, "experts"))
         if "multichip" in legs and run.device and run.device["count"] >= 2:
             run.attempt("multichip", lambda: leg_multichip(run))
+        if "init" in legs:
+            run.attempt("init", lambda: leg_child(run, "init", limit_s=3000))
     finally:
         run.stop_children()
     say(f"finished in {time.monotonic() - started:.1f}s")

@@ -9,7 +9,6 @@ calls these; the distributed layers shard their inputs.
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import os
 from functools import partial
@@ -47,6 +46,11 @@ def _needs_cast(x, dt):
     )
 
 
+def _stored_bytes(x, dt):
+    """Bytes of a leaf once stored: in `dt` where it is cast to it."""
+    return x.size * (dt if _needs_cast(x, dt) else x.dtype).itemsize
+
+
 def maybe_cast_params(tree):
     """Cast floating-point weights to params_storage_dtype(). Applied
     by every model/VAE/TE/ControlNet/upscaler loader at bundle-build
@@ -73,59 +77,164 @@ def maybe_cast_params(tree):
     return jax.tree_util.tree_map(cast, tree)
 
 
-def _run_storing(closed, args, dtype):
-    """Run a traced function operation by operation, as eager code
-    runs it, and return its outputs stored in `dtype`: each output is
-    cast the moment the operation that makes it has run, and every
-    value is dropped after its last use. The operations are the ones
-    the eager function would dispatch, so their small programs are
-    shared between models and with the compile cache; what differs is
-    that an output's float32 value lives only until its cast."""
+def _bind(eqn, values):
+    subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+    out = eqn.primitive.bind(*subfuns, *values, **params)
+    return out if eqn.primitive.multiple_results else [out]
+
+
+def _hashed(value):
+    """`value` where it can key a dictionary, else what tells it from
+    every other object (a traced inner function is shared between the
+    calls that gave it the same shapes)."""
+    try:
+        hash(value)
+    except TypeError:
+        return id(value)
+    return value
+
+
+def _equal_weights_once(closed, dtype, budget):
+    """A traced initializer (key -> leaves) as a function that gives
+    the leaves stored in `dtype` and draws
+    the weights its equations make in the same way with ONE loop
+    (`lax.map`), so that the compiler builds one draw for them where
+    it would build one apiece: it shares nothing between equal fusions
+    of a program, and a draw is most of a threefry-and-erf-inv
+    fusion's 0.1-4 s of compiling. A weight's own equations are the
+    ones between the key and itself (what the forward pass leaves
+    behind on the weights is on no such path); two weights are equal
+    in this sense when those equations and their wiring are, whatever
+    their literals: the literals that differ (the hash of a weight's
+    path folded into the key, a variance) are what the loop runs over.
+    A loop's result is its weights stacked, which the program holds
+    until they are copied out: a loop takes at most `budget` bytes of
+    them, the rest of the group goes to the next loop."""
+    import numpy as np
     from jax.extend.core import Literal
 
     jaxpr = closed.jaxpr
-    outputs = {v for v in jaxpr.outvars if not isinstance(v, Literal)}
-    # only what an output depends on: `lazy_init` also leaves behind the
-    # forward pass's operations on the weights alone (a kernel cast to
-    # the compute dtype, a reshaped bias), whose results nothing reads
-    needed, eqns = set(outputs), []
-    for eqn in reversed(jaxpr.eqns):
-        if needed.intersection(eqn.outvars):
-            eqns.append(eqn)
-            needed.update(v for v in eqn.invars if not isinstance(v, Literal))
-    eqns.reverse()
-    uses = collections.Counter(
-        v for eqn in eqns for v in eqn.invars if not isinstance(v, Literal)
+    (root,) = jaxpr.invars
+    producer = {v: (i, eqn) for i, eqn in enumerate(jaxpr.eqns) for v in eqn.outvars}
+
+    def own(out):
+        """(equations, their literals in order, signature) of one leaf."""
+        found, seen, todo = {}, set(), [out]
+        while todo:
+            v = todo.pop()
+            if isinstance(v, Literal) or v in seen:
+                continue
+            seen.add(v)
+            if v in producer:
+                at, eqn = producer[v]
+                found[at] = eqn
+                todo.extend(eqn.invars)
+        eqns = [found[i] for i in sorted(found)]
+        number, literals, signature = {}, [], []
+        for eqn in eqns:
+            wiring = []
+            for v in eqn.invars:
+                if isinstance(v, Literal):
+                    literals.append(v)
+                    wiring.append(str(v.aval))
+                else:
+                    wiring.append(number.get(v, id(v)))  # an argument stands for itself
+            for v in eqn.outvars:
+                number[v] = len(number)
+            params = tuple((k, _hashed(v)) for k, v in sorted(eqn.params.items()))
+            signature.append(
+                (eqn.primitive, params, tuple(wiring), tuple(str(v.aval) for v in eqn.outvars))
+            )
+        # what is drawn from no key is a constant: its literals are its value
+        fixed = None if root in seen else tuple(repr(v.val) for v in literals)
+        return eqns, literals, (tuple(signature), number.get(out, repr(out)), fixed)
+
+    groups = {}  # signature -> (the first such leaf, its equations, [(leaf's index, its literals)])
+    for index, out in enumerate(jaxpr.outvars):
+        eqns, literals, signature = own(out)
+        groups.setdefault(signature, (out, eqns, []))[2].append((index, literals))
+
+    def build(key):
+        given = dict(zip(jaxpr.constvars, closed.consts))
+        given[root] = key
+        leaves = [None] * len(jaxpr.outvars)
+        for out, eqns, members in groups.values():
+
+            def draw(swapped, out=out, eqns=eqns):
+                """The leaf, with the literals at `swapped`'s places taken from it."""
+                local, at = dict(given), 0
+                for eqn in eqns:
+                    values = []
+                    for v in eqn.invars:
+                        if isinstance(v, Literal):
+                            values.append(swapped.get(at, v.val))
+                            at += 1
+                        else:
+                            values.append(local[v])
+                    local.update(zip(eqn.outvars, _bind(eqn, values)))
+                leaf = out.val if isinstance(out, Literal) else local[out]
+                return leaf.astype(dtype) if _needs_cast(leaf, dtype) else leaf
+
+            columns = [
+                np.asarray([literals[i].val for _, literals in members], literal.aval.dtype)
+                for i, literal in enumerate(members[0][1])
+            ]
+            differ = [i for i, column in enumerate(columns) if (column != column[0]).any()]
+            if not differ:  # one value, handed out to all
+                leaf = draw({})
+                for index, _ in members:
+                    leaves[index] = leaf
+                continue
+            step = max(1, budget // max(_stored_bytes(out.aval, dtype), 1))
+            for start in range(0, len(members), step):
+                batch = members[start:start + step]
+                if len(batch) == 1:
+                    leaves[batch[0][0]] = draw({i: columns[i][start] for i in differ})
+                    continue
+                drawn = jax.lax.map(
+                    lambda values: draw(dict(zip(differ, values))),
+                    tuple(columns[i][start:start + step] for i in differ),
+                )
+                # the next loop waits for this one's weights to be copied
+                # out: else the compiler may run every loop first and hold
+                # all their stacked results at once (SDXL's UNet: 4.4 GB)
+                given[root], copied = jax.lax.optimization_barrier(
+                    (given[root], [drawn[i] for i in range(len(batch))])
+                )
+                for member, leaf in zip(batch, copied):
+                    leaves[member[0]] = leaf
+        return leaves
+
+    return build
+
+
+def init_program(module, dtype, key, *args, **kwargs):
+    """The one compiled program that builds `module`'s seeded weights
+    stored in `dtype`, a function of the key that gives the stored
+    tree: flax's `lazy_init` and each weight's cast, traced once and
+    built with equal weights drawn by one loop (`_equal_weights_once`).
+    A weight's float32 value lives inside the fusion that rounds it;
+    what the program holds beside its result is
+    `.lower(key).compile().memory_analysis()`'s `temp_size_in_bytes`,
+    which the tests and `chip_smoke.py --legs init` read."""
+    abstract_args, abstract_kwargs = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (args, kwargs)
     )
-    env = dict(zip(jaxpr.constvars, closed.consts))
-    env.update(zip(jaxpr.invars, args))
 
-    def read(v):
-        return v.val if isinstance(v, Literal) else env[v]
+    def build(k):
+        return module.lazy_init(k, *abstract_args, **abstract_kwargs)
 
-    def stored(x):
-        if not _needs_cast(x, dtype):
-            return x
-        y = x.astype(dtype)
-        if not isinstance(y, jax.core.Tracer):
-            # dispatch runs ahead of the device: wait, so that the
-            # float32 buffers of many weights are never queued at once
-            y.block_until_ready()
-        return y
+    closed, shapes = jax.make_jaxpr(build, return_shape=True)(key)
+    leaves, tree = jax.tree_util.tree_flatten(shapes)
+    stored = sum(_stored_bytes(leaf, dtype) for leaf in leaves)
+    draw = _equal_weights_once(closed, dtype, stored // 8)
 
-    for eqn in eqns:
-        subfuns, params = eqn.primitive.get_bind_params(eqn.params)
-        out = eqn.primitive.bind(*subfuns, *map(read, eqn.invars), **params)
-        env.update(zip(eqn.outvars, out if eqn.primitive.multiple_results else [out]))
-        for v in eqn.invars:
-            if not isinstance(v, Literal):
-                uses[v] -= 1
-                if not uses[v] and v not in outputs:
-                    env.pop(v, None)
-        for v in eqn.outvars:
-            if v in outputs and not uses[v]:
-                env[v] = stored(env[v])
-    return [stored(read(v)) for v in jaxpr.outvars]
+    def init(k):
+        return tree.unflatten(draw(k))
+
+    # the name a `program.build` span carries: one a component
+    init.__name__ = f"init_{type(module).__name__}"
+    return jax.jit(init)
 
 
 def init_params(module, key, *args, settle: bool = True, **kwargs):
@@ -133,11 +242,14 @@ def init_params(module, key, *args, settle: bool = True, **kwargs):
     runs the parameter initializers and only shape-evaluates the
     forward pass; the values are bit-identical to `module.init` on the
     same dummy inputs. Where the weights are stored in another dtype
-    than float32 they are built in it, weight by weight
-    (`_run_storing`): a load never holds a float32 copy of a component
+    than float32 one compiled program builds the component in it
+    (`init_program`): a start builds or fetches one program a
+    component, not one for every distinct operation of its
+    initializers, and a load never holds a float32 copy of a component
     (a 3.2 B-parameter denoiser is 12.7 GB in float32, on a 16 GB chip
-    that also holds its text encoders). `settle=False` keeps float32
-    for a caller about to map a checkpoint onto the tree."""
+    that also holds its text encoders).
+    `settle=False` keeps float32 for a caller about to map a checkpoint
+    onto the tree."""
     abstract_args, abstract_kwargs = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (args, kwargs)
     )
@@ -148,10 +260,7 @@ def init_params(module, key, *args, settle: bool = True, **kwargs):
     dtype = params_storage_dtype() if settle else None
     if dtype is None:
         return build(key)
-    closed, shape = jax.make_jaxpr(build, return_shape=True)(key)
-    return jax.tree_util.tree_unflatten(
-        jax.tree_util.tree_structure(shape), _run_storing(closed, [key], dtype)
-    )
+    return init_program(module, dtype, key, *args, **kwargs)(key)
 
 
 @dataclasses.dataclass

@@ -114,12 +114,32 @@ def test_warm_requests_change_only_the_seed(smoke):
 
 
 def test_the_legs_by_name(smoke):
-    assert smoke.LEGS == ("serve", "restart", "attention", "experts", "multichip")
+    assert smoke.LEGS == ("serve", "restart", "attention", "experts", "multichip", "init")
+    assert smoke.DEFAULT_LEGS == smoke.LEGS[:-1]  # `init` only when named
     proc = subprocess.run(
         [sys.executable, SMOKE, "--legs", "experts,kernels"],
         capture_output=True, text=True, timeout=60,
     )
     assert proc.returncode == 2 and "unknown leg(s) ['kernels']" in proc.stderr
+
+
+def test_the_init_leg_builds_a_bundle_a_program_a_component(tmp_path):
+    """The leg at toy size, because the caller said so: a row a component
+    with the program's temporaries beside the stored bytes and its cold
+    compile seconds (no parent commit is unpacked beside a checkout)."""
+    proc = subprocess.run(
+        [sys.executable, SMOKE, "--legs", "init", "--rehearsal", "--out", str(tmp_path / "out")],
+        capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stdout[-3000:]
+    rows = [json.loads(line.split("init: ", 1)[1]) for line in proc.stdout.splitlines()
+            if "init: {" in line]
+    built = [row for row in rows if "shape" in row]
+    assert [row["shape"] for row in built] == [
+        "tiny-unet UNet", "tiny-unet VAE", "tiny-unet TextEncoder"]
+    for row in built:
+        assert row["ok"] and row["compile_s"] > 0 and row["weights"] > 30
+        assert row["stored_bytes"] == 2 * row["values"] and row["temp_bytes"] >= 0
 
 
 def test_the_experts_leg_times_the_shapes_the_three_models_decode_at(smoke):

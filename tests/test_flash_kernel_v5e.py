@@ -860,3 +860,26 @@ def test_sdar_prefill_masks_by_block_in_the_causal_kernel_and_its_decode_carries
     text = compiled.as_text()
     assert text.count(" while(") >= 2
     assert len(re.findall(r"%expert_matvec[.\d]* = ", text)) == 2 * 6 + 2 * 5
+
+
+@pytest.mark.parametrize("bundle,component", [("sd15", "TextEncoder"), ("sdxl", "UNet")])
+def test_a_components_init_program_holds_under_a_quarter_of_its_stored_bytes(
+        one_chip, bundle, component, monkeypatch):
+    """`models/pipeline.init_program` at published widths: what the one
+    program that builds a component's seeded weights holds beside them is
+    a loop's stacked results at most (an eighth of the stored bytes;
+    SDXL's UNet read 86 % before its loops were put in sequence), never a
+    float32 copy of the component. CLIP-L draws in six loops and holds
+    nothing; SDXL's UNet, 1,680 weights, in 22."""
+    from comfyui_distributed_tpu.models import pipeline as pl
+    from test_params_storage import components
+
+    monkeypatch.setenv("CDT_PARAMS_DTYPE", "bfloat16")
+    module, args, kwargs = next(
+        c for c in components(bundle, monkeypatch) if type(c[0]).__name__ == component)
+    key = jax.random.key(0)
+    program = pl.init_program(module, jnp.dtype(jnp.bfloat16), key, *args, **kwargs)
+    spec = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    memory = program.lower(spec).compile().memory_analysis()
+    assert memory.output_size_in_bytes > 2e8
+    assert memory.temp_size_in_bytes <= memory.output_size_in_bytes / 4
