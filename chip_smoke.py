@@ -25,7 +25,11 @@ Drives the main path once, through the entry points an operator uses:
                (heads folded into the batch by transpositions), each
                between the [B, N, H*D] arrays a model holds and against
                a float32 reference, and prints which route the shape
-               rule gives each shape and all three times; then the
+               rule gives each shape and all three times; where
+               `ops/short_attention.py` has a form for the shape (SDXL's
+               64-wide heads over keys that fit one block) that kernel
+               too, at every count of lane tiles a grid step, and every
+               route between a block's linears (`in_block_ms`); then the
                four causal calls of the language models' prefills
                (`CAUSAL_SHAPES`) on the kernel under its mask and on the
                XLA form, and the kernel under each pair of block caps
@@ -969,14 +973,53 @@ def timed(fn, *operands):
     return out, first_s, 1e3 * (time.perf_counter() - started) / 10
 
 
-def attention_child(rehearsal: bool) -> int:
+def in_block_ms(attends: dict, q_shape, m: int) -> dict:
+    """ms a call of each route as a UNet block holds it: q out of one
+    linear over the tokens, k and v out of two over the tokens or the
+    text, the result into a fourth and added to the residual. Standing
+    alone a call's operands are a program's parameters, which lie on
+    the device as it likes them (a `[16, 324, 1280]` array batch-minor,
+    since 324 is no multiple of 8), and a kernel pays copies a block
+    never makes: the routes' *differences* here are what a block sees."""
+    import jax
+    import jax.numpy as jnp
+
+    b, n, h, d = q_shape
+    width = h * d
+    context = width if m == n else 2048  # SDXL's text width
+
+    @jax.jit
+    def operands(key):
+        kx, kc, *kw = jax.random.split(key, 6)
+        weights = [
+            (jax.random.normal(kw[i], (rows, width)) / rows ** 0.5).astype(jnp.bfloat16)
+            for i, rows in enumerate((width, context, context, width))]
+        x = jax.random.normal(kx, (b, n, width)).astype(jnp.bfloat16)
+        text = jax.random.normal(kc, (b, m, context)).astype(jnp.bfloat16)
+        return x, (x if m == n else text), weights
+
+    x, ctx, weights = operands(jax.random.key(n + m))
+    out = {}
+    for name, attend in attends.items():
+        def block(x, ctx, weights, attend=attend):
+            wq, wk, wv, wo = weights
+            q, k, v = (
+                (y @ w).reshape(b, y.shape[1], h, d) for y, w in ((x, wq), (ctx, wk), (ctx, wv)))
+            return x + attend(q, k, v).reshape(b, n, width) @ wo
+
+        out[name] = round(timed(jax.jit(block), x, ctx, weights)[2], 3)
+    return out
+
+
+def served_row(rehearsal: bool, label, q_shape, m) -> bool:
+    """A served shape's non-causal call on every route whatever the rule
+    says, each between the [B, N, H*D] arrays a model's linears give and
+    take, against `jax.nn.dot_product_attention` in float32."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    if not child_device(rehearsal):
-        return 1
-    from comfyui_distributed_tpu.ops import attention
+    from comfyui_distributed_tpu.ops import attention, short_attention
 
     @jax.jit
     def errors(out, q, k, v):
@@ -988,61 +1031,85 @@ def attention_child(rehearsal: bool) -> int:
             jnp.max(jnp.abs(out.astype(jnp.float32) - ref)), jnp.max(jnp.abs(ref))
         )
 
-    failed = 0
-    for label, q_shape, m in REHEARSAL_SHAPES if rehearsal else SERVED_SHAPES:
-        b, n, h, d = q_shape
+    b, n, h, d = q_shape
 
-        @jax.jit
-        def operands(key, q_shape=q_shape, kv_shape=(b, m, h, d)):
-            kq, kk, kv = jax.random.split(key, 3)
-            return (
-                (2.0 * jax.random.normal(kq, q_shape)).astype(jnp.bfloat16),
-                jax.random.normal(kk, kv_shape).astype(jnp.bfloat16),
-                jax.random.normal(kv, kv_shape).astype(jnp.bfloat16),
+    @jax.jit
+    def operands(key, q_shape=q_shape, kv_shape=(b, m, h, d)):
+        kq, kk, kv = jax.random.split(key, 3)
+        return (
+            (2.0 * jax.random.normal(kq, q_shape)).astype(jnp.bfloat16),
+            jax.random.normal(kk, kv_shape).astype(jnp.bfloat16),
+            jax.random.normal(kv, kv_shape).astype(jnp.bfloat16),
+        )
+
+    q, k, v = operands(jax.random.key(n * 131 + m * 7 + d))
+    # the route the rule gives the shape on a TPU (the CPU never
+    # routes to the kernel by itself), then every route whatever the
+    # rule says: the rule rests on their times
+    route = "flash" if attention.kernel_wins(n, m) else "xla"
+    if short_attention.short_wins(n, m, h, d, q.dtype):
+        route = "short"
+    row = {
+        "shape": label, "q": list(q_shape), "keys": m, "dtype": "bfloat16",
+        "route": route, "ok": True,
+    }
+    if not rehearsal and attention.attention_route(q, k) != route:
+        row["ok"] = False
+
+    def as_served(attend, b=b, n=n, m=m, h=h, d=d):
+        """`attend` between the [B, N, H*D] a model's linears give
+        and take: what a layout costs shows only from there."""
+        def call(q, k, v):
+            out = attend(
+                q.reshape(b, n, h, d), k.reshape(b, m, h, d), v.reshape(b, m, h, d)
             )
+            return out.reshape(b, n, h * d)
+        return jax.jit(call)
 
-        q, k, v = operands(jax.random.key(n * 131 + m * 7 + d))
-        # the route the rule gives the shape on a TPU (the CPU never
-        # routes to the kernel by itself), then every route whatever the
-        # rule says: the rule rests on their times
-        route = "flash" if attention.kernel_wins(n, m) else "xla"
-        row = {
-            "shape": label, "q": list(q_shape), "keys": m, "dtype": "bfloat16",
-            "route": route, "ok": True,
+    flat = lambda x: x.reshape(*x.shape[:2], h * d)
+    attends = {
+        name: functools.partial(
+            attention.dot_product_attention, force_flash=name == "flash",
+            interpret=name == "flash" and rehearsal)
+        for name in ("flash", "xla")}
+    # `ops/short_attention.py`'s kernel wherever it has a form for the shape
+    short = d == short_attention.WIDTH and short_attention.plan(n, m, h, 2) is not None
+    if short:
+        attends["short"] = functools.partial(attention.short_attend, interpret=rehearsal)
+    for name, attend in dict(attends, transposed=transposed(attends["flash"])).items():
+        fn = as_served(attend)
+        with attention.route_log() as routes:
+            out, first_s, ms = timed(fn, flat(q), flat(k), flat(v))
+        err, ref_max = (float(x) for x in errors(out.reshape(q.shape), q, k, v))
+        scale = max(1.0, ref_max)
+        row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
+        row[name] = {
+            "entry": routes[0], "max_abs_err": round(err, 5),
+            "first_call_s": round(first_s, 2), "ms": round(ms, 3),
         }
-        if not rehearsal and attention.attention_route(q, k) != route:
-            row["ok"] = False
+    row["ref_max_abs"] = round(scale, 3)
+    if short:
+        # the kernel at every count of lane tiles a grid step (its plan takes the most
+        # that fit VMEM), then each route where a model has it: between a block's linears
+        lane_tiles = h * d // attention.ROUTE_MULTIPLE
+        block_q, m_pad, _ = short_attention.plan(n, m, h, 2)
+        row["short"]["sweep_ms"] = {
+            f"h{2 * tiles}": round(timed(as_served(functools.partial(
+                short_attention.short_attention, interpret=rehearsal, tiles=tiles,
+            )), flat(q), flat(k), flat(v))[2], 3)
+            for tiles in range(1, lane_tiles + 1) if lane_tiles % tiles == 0
+            and short_attention.vmem_bytes(block_q, m_pad, tiles, 2) <= attention.VMEM_BUDGET}
+        row["in_block_ms"] = in_block_ms(attends, q_shape, m)
+    print(json.dumps(row), flush=True)
+    return row["ok"]
 
-        def as_served(attend, b=b, n=n, m=m, h=h, d=d):
-            """`attend` between the [B, N, H*D] a model's linears give
-            and take: what a layout costs shows only from there."""
-            def call(q, k, v):
-                out = attend(
-                    q.reshape(b, n, h, d), k.reshape(b, m, h, d), v.reshape(b, m, h, d)
-                )
-                return out.reshape(b, n, h * d)
-            return jax.jit(call)
 
-        flat = lambda x: x.reshape(*x.shape[:2], h * d)
-        for name in ("flash", "xla", "transposed"):
-            flash = name != "xla"
-            attend = functools.partial(
-                attention.dot_product_attention, force_flash=flash,
-                interpret=flash and rehearsal,
-            )
-            fn = as_served(transposed(attend) if name == "transposed" else attend)
-            with attention.route_log() as routes:
-                out, first_s, ms = timed(fn, flat(q), flat(k), flat(v))
-            err, ref_max = (float(x) for x in errors(out.reshape(q.shape), q, k, v))
-            scale = max(1.0, ref_max)
-            row["ok"] &= bool(np.isfinite(err)) and err <= ATTENTION_TOLERANCE * scale
-            row[name] = {
-                "entry": routes[0], "max_abs_err": round(err, 5),
-                "first_call_s": round(first_s, 2), "ms": round(ms, 3),
-            }
-        row["ref_max_abs"] = round(scale, 3)
-        failed += not row["ok"]
-        print(json.dumps(row), flush=True)
+def attention_child(rehearsal: bool) -> int:
+    if not child_device(rehearsal):
+        return 1
+    failed = 0
+    for shape in REHEARSAL_SHAPES if rehearsal else SERVED_SHAPES:
+        failed += not served_row(rehearsal, *shape)
     for shape in REHEARSAL_CAUSAL_SHAPES if rehearsal else CAUSAL_SHAPES:
         failed += not causal_row(rehearsal, *shape)
     failed += not decode_slot_row(rehearsal)

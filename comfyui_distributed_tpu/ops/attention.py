@@ -1,12 +1,12 @@
 """Attention kernels.
 
 `dot_product_attention(q, k, v)` with [B, N, H, D] layout routes to:
-- a Pallas flash-attention kernel on TPU (tiled online-softmax — the
-  memory-bound op worth hand-writing; everything else is left to XLA);
-  lengths off the 128 multiple are padded to lengths the kernel tiles
-  and the padded keys masked inside it,
+- a Pallas flash-attention kernel on TPU (tiled online-softmax); lengths
+  off the 128 multiple are padded to lengths the kernel tiles and the
+  padded keys masked inside it; or, for the short calls at 64-wide heads
+  that `short_wins` names, `ops/short_attention.py`'s whole-key kernel,
 - `jax.nn.dot_product_attention` elsewhere (other backends, and the
-  shapes `kernel_wins` leaves to XLA).
+  shapes `short_wins` and `kernel_wins` leave to XLA).
 
 A causal call (`causal_attention`: a language model's prefill) takes the
 same kernel under a causal mask, with a band where the layer has a
@@ -63,9 +63,9 @@ ROW_MULTIPLE = 16
 # Off the multiple, the kernel takes a call from this many keys on: XLA's
 # cost is the float32 scores, 12 bytes a key for every q row, the kernel's
 # the lane padding and the copy that pads a narrow head, about the same
-# for every row. On a v5e at 64-wide heads (batch 16, PR 33) XLA wins at
-# 324 keys (0.60 ms against 0.76), 400 and 484 are ties, and the kernel
-# wins from 500 (1.25 against 1.47) and 576 (0.66 against 0.81) on.
+# for every row. On a v5e at 64-wide heads (batch 16, PR 60) this kernel
+# reads 0.541 ms at 324 keys for XLA's 0.633, and `short_attention`, which
+# `short_wins` gives that call and SDXL's other short ones first, 0.307.
 MIN_RAGGED_KEYS = 512
 # (block_q, block_k) caps of a causal call and of one under a window,
 # swept on a v5e (PERF.md §6, PR 43, on the k step as it was until PR 51:
@@ -100,10 +100,10 @@ def route_log():
     kernel chose for the shape (`pad` only where a length was padded,
     `inplace` where it reads the heads where the caller left them, a
     width that is a multiple of 128: `flash 4608x4608x128 bq512 bk1536
-    bf16 inplace`, `flash 1296x1296x64 pad1296x1408 bq432 bk1408
-    bf16`); a causal call logs `flash-causal ...` (`causal_attention`
-    has its grammar) or `xla-causal NxMxDq/Dv [w<window>] bq<rows>
-    <dtype>`. Calls happen while a
+    bf16 inplace`), or `short 324x324x64 pad336x384 h20 bq336 bf16
+    inplace` (`short_attention.entry`); a causal call logs `flash-causal
+    ...` (`causal_attention` has its grammar) or `xla-causal NxMxDq/Dv
+    [w<window>] bq<rows> <dtype>`. Calls happen while a
     program is traced, so a block around a jitted call fills only on
     the request that builds the program; the graph's sampler and
     upscale nodes read it into their spans."""
@@ -152,22 +152,22 @@ def dot_product_attention(
         raise NotImplementedError(
             "a scale or a value width of its own is implemented for causal attention only"
         )
-    use_flash = (
-        attention_route(q, k) == "flash" if force_flash is None else force_flash
-    )
+    route = attention_route(q, k) if force_flash is None else "flash" if force_flash else "xla"
+    if route == "short":  # `ops/short_attention.py`: its own kernel, its own entry in the log
+        return short_attend(q, k, v, interpret)
     n, m, d = q.shape[1], k.shape[1], q.shape[3]
     pad = -d % ROUTE_MULTIPLE
     log = _ROUTE_LOG.get()
     if log is not None:
-        entry = f"{'flash' if use_flash else 'xla'} {n}x{m}x{d}"
-        if use_flash:
+        entry = f"{route} {n}x{m}x{d}"
+        if route == "flash":
             n_pad, m_pad, block_q, block_k = flash_plan(n, m, d + pad, q.dtype.itemsize)
             name = _DTYPE_NAMES.get(q.dtype.name, q.dtype.name)
             if (n_pad, m_pad) != (n, m):
                 entry += f" pad{n_pad}x{m_pad}"
             entry += f" bq{block_q} bk{block_k} {name}" + ("" if pad else " inplace")
         log.append(entry)
-    if not use_flash:
+    if route != "flash":
         return jax.nn.dot_product_attention(q, k, v)
     return flash_attention(q, k, v, interpret=interpret)
 
@@ -299,11 +299,11 @@ def causal_attention_blocked(
 
 def attention_route(q: jax.Array, k: jax.Array) -> str:
     """The implementation `dot_product_attention` gives these operands:
-    "flash" (the Pallas kernel: a TPU backend and a shape `kernel_wins`
-    names) or "xla"."""
+    "short" or "flash" (a Pallas kernel: a TPU backend and a shape that
+    `short_wins`, asked first, or `kernel_wins` names) or "xla"."""
     if not _kernel_allowed():
         return "xla"
-    return "flash" if kernel_wins(q.shape[1], k.shape[1]) else "xla"
+    return short_route(q, k) or ("flash" if kernel_wins(q.shape[1], k.shape[1]) else "xla")
 
 
 def _kernel_allowed() -> bool:
@@ -336,8 +336,8 @@ def causal_kernel_wins(m: int, v_width: int, window: int | None = None) -> bool:
 def kernel_wins(n: int, m: int) -> bool:
     """Whether q of n rows over m keys goes to the kernel on a TPU: both
     lengths whole multiples of `ROUTE_MULTIPLE` (as since PR 28: nothing
-    is padded), or `MIN_RAGGED_KEYS` keys or more. The 77-key
-    cross-attentions and SDXL's 324-token blocks stay on XLA by this."""
+    is padded), or `MIN_RAGGED_KEYS` keys or more. SD1.5's 77-key
+    cross-attentions stay on XLA by this (SDXL's short calls: `short_wins`)."""
     if n <= 0 or m <= 0:
         return False
     return (n % ROUTE_MULTIPLE == 0 and m % ROUTE_MULTIPLE == 0) or m >= MIN_RAGGED_KEYS
@@ -708,3 +708,30 @@ def narrow_keys(v_width: int) -> float:
     """The keys from which a causal call with values `v_width` wide, off
     the lane tile, goes to the kernel on a TPU: never, but at `NARROW_WIDTH`."""
     return MIN_NARROW_KEYS if v_width == NARROW_WIDTH else math.inf
+
+
+# --- the short calls' own kernel (PR 60) ------------------------------------
+# Down here for the same reason. A non-causal call at 64-wide heads whose
+# keys fit one block goes to `ops/short_attention.py` where that kernel was
+# timed on the chip and won (`short_attention.short_wins`); the module is
+# imported at the call, since it imports this one.
+
+
+def short_route(q: jax.Array, k: jax.Array) -> str | None:
+    """ "short" where `short_attention.short_wins` names the operands'
+    shape and dtype, else None: `attention_route` then asks `kernel_wins`."""
+    from . import short_attention
+
+    n, heads, width = q.shape[1:]
+    return "short" if short_attention.short_wins(n, k.shape[1], heads, width, q.dtype) else None
+
+
+def short_attend(q: jax.Array, k: jax.Array, v: jax.Array, interpret: bool = False) -> jax.Array:
+    """`short_attention` on [B, N, H, 64] operands, its entry logged
+    (`interpret` is the tests' and the rehearsal's, as above)."""
+    from . import short_attention
+
+    log = _ROUTE_LOG.get()
+    if log is not None:
+        log.append(short_attention.entry(q.shape[1], k.shape[1], q.shape[2], q.dtype))
+    return short_attention.short_attention(q, k, v, interpret=interpret)

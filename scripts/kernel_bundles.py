@@ -53,6 +53,21 @@ def _attention(q_shape, kv_heads=None, v_width=None, window=None, causal=False):
     return lower
 
 
+def _short(q_shape, keys, tiles=None):
+    """Lower one `ops/short_attention` call: q [B, N, H, 64] over `keys`
+    keys, all of them one block, `tiles` lane tiles a grid step (the
+    plan's where None), bf16."""
+    def lower(place):
+        import functools
+
+        from comfyui_distributed_tpu.ops import short_attention
+
+        b, _, h, d = q_shape
+        return functools.partial(short_attention.short_attention, tiles=tiles), (
+            place(q_shape), place((b, keys, h, d)), place((b, keys, h, d)))
+    return lower
+
+
 def _dsa_attend(queries, rows, k, heads, rank, rope):
     """Lower one `ops/dsa_attend` call: a block of a part's queries over
     the chosen rows of a latent cache, bf16."""
@@ -119,6 +134,13 @@ CASES = {
     "sd15 self 64x64": ("flash_attention", _attention((2, 4096, 8, 40))),
     "flux joint 4608": ("flash_attention", _attention((1, 4608, 24, 128))),
     "sdxl tile self 36x36": ("flash_attention", _attention((16, 1296, 10, 64))),
+    "sdxl tile self 18x18": ("flash_attention", _attention((16, 324, 20, 64))),
+    "sdxl tile self 18x18 short": ("short_attention", _short((16, 324, 20, 64), 324)),
+    "sdxl tile self 18x18 short, one lane tile a step": (
+        "short_attention", _short((16, 324, 20, 64), 324, tiles=1)),
+    "sdxl tile cross 18x18 short": ("short_attention", _short((16, 324, 20, 64), 77)),
+    "sdxl tile cross 36x36 short": ("short_attention", _short((16, 1296, 10, 64), 77)),
+    "sdxl tile self 36x36 short": ("short_attention", _short((16, 1296, 10, 64), 1296)),
     "solar / k-exaone full 8192": (
         "flash_attention_causal", _attention((1, 8192, 64, 128), kv_heads=8, causal=True)),
     "k-exaone window 8192": (

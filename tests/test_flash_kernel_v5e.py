@@ -76,6 +76,32 @@ def test_kernel_compiles_for_v5e_under_the_tile_axis_vmap(one_chip):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+SHORT = [s for s in SHAPES if s[0].startswith("sdxl tile") and "vae" not in s[0]]
+
+
+@pytest.mark.parametrize("tiled", [False, True], ids=["batch 16", "vmap over 8 tiles of batch 2"])
+@pytest.mark.parametrize("q_shape,m", [s[1:] for s in SHORT], ids=[s[0] for s in SHORT])
+def test_short_attention_compiles_for_v5e_at_sdxl_s_tile_shapes(one_chip, q_shape, m, tiled):
+    """`ops/short_attention.py` (PR 60) at SDXL's four attention shapes,
+    the one its rule leaves to XLA too (the rule rests on both routes'
+    times), alone and as the scan tier reaches it: one kernel, and
+    nothing padded, transposed or copied around it (blocks that reach
+    past an array's end are the kernel's to mask)."""
+    from comfyui_distributed_tpu.ops import short_attention
+
+    b, n, h, d = q_shape
+    lead = (8, b // 8) if tiled else (b,)
+    q = jax.ShapeDtypeStruct((*lead, n, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((*lead, m, h, d), jnp.bfloat16, sharding=one_chip)
+    fn = attn.short_attend
+    with attn.route_log() as routes:
+        compiled = jax.jit(jax.vmap(fn) if tiled else fn).lower(q, kv, kv).compile()
+    assert routes == [short_attention.entry(n, m, h, jnp.bfloat16)]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "short_attention" in text and " pad(" not in text and " transpose(" not in text
+
+
 def test_route_log_entries():
     """What the sampler and upscale nodes write into their spans as
     `attention`: the blocks and operand dtype for a flash call, the
