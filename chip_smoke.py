@@ -33,7 +33,11 @@ Drives the main path once, through the entry points an operator uses:
                four causal calls of the language models' prefills
                (`CAUSAL_SHAPES`) on the kernel under its mask and on the
                XLA form, and the kernel under each pair of block caps
-               of the sweep; then the single-query kernel of a language model's decode
+               of the sweep, and the same for dots3-note-prev's band of
+               513 over a part's 8,192 queries and the 512 latents
+               before them (`BAND_TAIL_SHAPE`: more keys than queries,
+               what `MIN_BAND_WINDOW` rests on); then the single-query
+               kernel of a language model's decode
                (`ops/decode_attention`) against the einsum form over
                every slot of Ouro's 3.3 GB cache, a call a slot inside
                one jitted loop; then a drafting step's delta rule over
@@ -898,6 +902,12 @@ CAUSAL_SHAPES = (
     ("nemotron3-nano 32:2 8192", (1, 8192, 32, 128), 2, 128, None),
     ("granite-4.0-h 32:8 of 64 8192", (1, 8192, 32, 64), 8, 64, None),
 )
+# dots3-note-prev's sliding layers (PR 61): a part's 8,192 queries over the
+# 512 latents before the part and its own, 64 heads of 256 (192 + 64) beside
+# values of 128, under a band of 513: fewer queries than keys (the last
+# entry: the keys). What `ops/attention.MIN_BAND_WINDOW` rests on.
+BAND_TAIL_SHAPE = ("dots3 window 8192 + 512", (1, 8192, 64, 256), 64, 128, 513, 8704)
+REHEARSAL_BAND_TAIL_SHAPE = ("toy window with a tail", (1, 1280, 2, 256), 2, 128, 130, 1409)
 REHEARSAL_CAUSAL_SHAPES = (
     ("toy causal grouped", (1, 1280, 4, 128), 2, 128, None),
     ("toy causal window", (1, 1280, 2, 128), 2, 128, 100),
@@ -1112,6 +1122,8 @@ def attention_child(rehearsal: bool) -> int:
         failed += not served_row(rehearsal, *shape)
     for shape in REHEARSAL_CAUSAL_SHAPES if rehearsal else CAUSAL_SHAPES:
         failed += not causal_row(rehearsal, *shape)
+    failed += not causal_row(
+        rehearsal, *(REHEARSAL_BAND_TAIL_SHAPE if rehearsal else BAND_TAIL_SHAPE))
     failed += not decode_slot_row(rehearsal)
     failed += not kda_keep_row(rehearsal)
     for shape in REHEARSAL_KDA_DELTA_SHAPES if rehearsal else KDA_DELTA_SHAPES:
@@ -1122,11 +1134,12 @@ def attention_child(rehearsal: bool) -> int:
     return 1 if failed else 0
 
 
-def causal_row(rehearsal: bool, label, q_shape, kv_heads, v_width, window) -> bool:
+def causal_row(rehearsal: bool, label, q_shape, kv_heads, v_width, window, keys=None) -> bool:
     """A prefill's causal call on both routes, between the [B, N, H*D]
     arrays a model's projections give and take, against the XLA form in
     float32; then the kernel under each pair of block caps of the sweep
-    (ms only: the plan's caps rest on these)."""
+    (ms only: the plan's caps rest on these). `keys` where the call has
+    more keys than queries (a part of a prompt over a tail before it)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1134,8 +1147,9 @@ def causal_row(rehearsal: bool, label, q_shape, kv_heads, v_width, window) -> bo
     from comfyui_distributed_tpu.ops import attention
 
     b, n, h, d = q_shape
+    m = keys or n
     scale = 0.1147 if d != v_width else None  # DeepSeek-V2's is its own (YaRN)
-    shapes = (q_shape, (b, n, kv_heads, d), (b, n, kv_heads, v_width))
+    shapes = (q_shape, (b, m, kv_heads, d), (b, m, kv_heads, v_width))
 
     @jax.jit
     def operands(key):
@@ -1151,9 +1165,10 @@ def causal_row(rehearsal: bool, label, q_shape, kv_heads, v_width, window) -> bo
         return jax.jit(call)
 
     flat = operands(jax.random.key(n * 131 + h * 7 + d))
-    route = "flash" if attention.causal_kernel_wins(n, v_width, window) else "xla"
+    route = "flash" if attention.causal_kernel_wins(m, v_width, window) else "xla"
     row = {
-        "shape": label, "q": list(q_shape), "key_heads": kv_heads, "value_width": v_width,
+        "shape": label, "q": list(q_shape), "keys": m, "key_heads": kv_heads,
+        "value_width": v_width,
         "window": window, "dtype": "bfloat16", "route": route, "ok": True,
     }
     if not rehearsal and attention.causal_route(
@@ -1190,7 +1205,7 @@ def causal_row(rehearsal: bool, label, q_shape, kv_heads, v_width, window) -> bo
         setattr(attention, caps_name, caps)
         try:
             _, block_q, block_k = attention.flash_plan(
-                n, n, max(d + -d % 128, v_width), 2, causal=True, window=window)[1:]
+                n, m, max(d + -d % 128, v_width), 2, causal=True, window=window)[1:]
             fn = as_served(functools.partial(
                 attention.flash_attention.__wrapped__, scale=scale, causal=True, window=window))
             sweep[f"bq{block_q} bk{block_k}"] = round(timed(fn, *flat)[2], 3)

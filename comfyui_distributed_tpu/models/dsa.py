@@ -1,5 +1,5 @@
 """Learned sparse attention over a latent cache (DeepSeek sparse
-attention, DSA), as `glm_dsa.py` uses it: an **indexer** scores every
+attention, DSA), as `glm_dsa.py` and `dots3.py` use it: an **indexer** scores every
 visible position for a query, the `index_topk` best are **selected**,
 exactly, and latent attention (`models/mla.py`) reads the chosen rows of
 the cache and no others.
@@ -16,7 +16,10 @@ each rotated in pairs, and a weight a head made of the layer's input:
 `keys` makes the rows the indexer's cache holds, `queries` a part's
 queries and weights, `scores` I with what a query may not see at minus
 infinity, `select` S and `attend` latent attention over it. A layer that
-computes no index of its own attends by the `Selection` handed to it.
+computes no index of its own attends by the `Selection` handed to it
+(GLM-5.2's `shared` layers); a model may as well give every attending
+layer an index of its own, at its own count of heads (dots3-note-prev's
+full layers: 64 index heads, 128 attention heads).
 
 S has two forms, by the number of queries alone (`form`). **gathered**
 (a part of a prompt): the chosen positions a block of query rows at a

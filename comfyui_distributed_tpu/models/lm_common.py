@@ -1,6 +1,6 @@
-"""What the nine language models share (`deepseek_v2.py`, `ouro.py`,
+"""What the ten language models share (`deepseek_v2.py`, `ouro.py`,
 `solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`,
-`glm_dsa.py`, `granite_hybrid.py`, `sdar.py`): the blocks and helpers
+`glm_dsa.py`, `granite_hybrid.py`, `sdar.py`, `dots3.py`): the blocks and helpers
 they are written from, the one initialisation rule, sampling on the
 device, the rule by which a drafted token is kept or replaced and the
 rule by which a block's drawn tokens are kept or masked again, the three
@@ -47,7 +47,9 @@ once:
   any other number and `drafting_report` the steps' half of `report`;
 - a `prefill` that cannot take its prompt at once hands one part's body
   and the arrays it wants cut to `prefill_in_parts` (GLM-5.2,
-  granite-4.0-h-micro), which owns the cut (`parts_of`), the scan over
+  granite-4.0-h-micro, dots3-note-prev: the state a part hands on is a
+  tree of whatever the model needs, caches of full length, recurrent
+  states, the last latents of a window), which owns the cut (`parts_of`), the scan over
   the whole parts, the remainder and the joining of the parts' outputs;
   the model allocates the state before it and reads the outputs after;
 - where the model generates by masked diffusion over blocks, a `decode`

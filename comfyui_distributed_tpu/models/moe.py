@@ -1,6 +1,6 @@
 """The expert layer of one chip, as the language models with a mixture
 of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`,
-`ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`, `sdar.py`), and the
+`ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`, `sdar.py`, `dots3.py`), and the
 routing rule of those that score by sigmoids
 (`sigmoid_route`, with or without groups chosen first).
 
@@ -10,7 +10,7 @@ token over all of them by the model's own rule, and computes its own
 experts' part of the result as one grouped product
 (`jax.lax.ragged_dot`) with no dropped token and no capacity factor;
 what absent experts would add is left out. Where the layer's tree has a
-shared expert (`shared`: six of the seven models; SDAR has none) it sees
+shared expert (`shared`: seven of the eight models; SDAR has none) it sees
 every token.
 
 Sorted by held expert, the pairs a chip holds are the first rows and

@@ -20,6 +20,7 @@ from .dit import DiTConfig, VideoDiT
 from .mmdit import MMDiT, MMDiTConfig
 from .ouro import Ouro, OuroConfig
 from .sd3 import SD3Config, SD3MMDiT
+from .dots3 import Dots3, Dots3Config
 from .glm_dsa import GlmDsa, GlmDsaConfig
 from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .k_exaone import KExaone, KExaoneConfig
@@ -676,6 +677,35 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             vocab_size=512, mask_token_id=511,
         ),
     },
+    # dots-studio/dots3-note-prev's text path as rank 0 of the eight chips
+    # that share each layer, every width of both layer kinds as published:
+    # published layers 0-4 (the dense layer 0 and layer 1, both full with an
+    # index of their own; layers 2-4 sliding: one whole period of `full,
+    # sliding, sliding, sliding` after the dense layer), experts 0-31 of 256,
+    # the first eighth of the vocabulary (the benchmark's dots3-note-prev
+    # configuration says what the cut stands for); no tower, no MTP module
+    "dots3-note-prev-ep8-5l": {
+        "family": "lm",
+        "config": Dots3Config(num_hidden_layers=5, ep_size=8, ep_rank=0, vocab_shards=8),
+    },
+    # every mechanism at a size for the CPU: the same five layers; full
+    # layers 4 heads (8 + 8 wide, values 8) over a latent of 16 with an index
+    # of 2 heads of 16 that keeps 8 positions; sliding layers 2 heads (12 + 8
+    # wide, values 8) over a latent of 32, a window of 5 (a ring of 8); a
+    # query latent of 32 in both; 8 experts of 32 columns (2 a token) of
+    # which rank 0 of 2 holds four; 512 ids; parts of 16 positions
+    "tiny-dots3": {
+        "family": "lm",
+        "config": Dots3Config(
+            hidden_size=64, num_hidden_layers=5, num_attention_heads=4, q_lora_rank=32,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+            index_n_heads=2, index_head_dim=16, index_topk=8, sliding_window_size=5,
+            swa_num_attention_heads=2, swa_q_lora_rank=32, swa_kv_lora_rank=32,
+            swa_qk_nope_head_dim=12, swa_qk_rope_head_dim=8, swa_v_head_dim=8,
+            intermediate_size=160, moe_intermediate_size=32, n_routed_experts=8,
+            num_experts_per_tok=2, vocab_size=512, ep_size=2, ep_rank=0, prefill_part=16,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -737,6 +767,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     GlmDsaConfig: GlmDsa,
     GraniteHybridConfig: GraniteHybrid,
     SdarConfig: Sdar,
+    Dots3Config: Dots3,
 }
 
 

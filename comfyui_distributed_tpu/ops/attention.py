@@ -72,19 +72,19 @@ MIN_RAGGED_KEYS = 512
 # its stores and reductions cost per q row whatever the k block's width).
 # The widest k block wins although the triangle then computes more of the
 # square: 8,192 tokens at 64 heads take 16.9 ms as 512 x 512 (136 of 256
-# blocks), 11.5 as 512 x 1,024 (72 of 128), 30.3 as 512 x 256. A band
-# of 128 keys a row is all edge: 512 x 512 was its best (3.74 ms).
+# blocks), 11.5 as 512 x 1,024 (72 of 128), 30.3 as 512 x 256. A band is
+# best at 512 x 512: 128 keys a row 2.62 ms, 513 over a tail 3.32 (PR 61).
 CAUSAL_CAPS = (512, 1024)
 BAND_CAPS = (512, 512)
 # Under a window shorter than this a causal call stays on XLA: every
-# block the kernel computes is crossed by an edge, and of a q block's
-# 512 + window keys fetched in blocks of 512 a window of 128 sees an
-# eighth, where `causal_attention_blocked` multiplies 255 + window keys
-# a row: 2.10 ms against the kernel's 3.74 at 8,192 tokens, 64 heads
-# (PR 43). By those two rates the routes cross between 512 and 768;
-# two q blocks is where at least half of what is computed is seen. No
-# window between 128 and 8,192 has been timed.
-MIN_BAND_WINDOW = 1024
+# block the kernel computes is crossed by an edge. Two points are timed
+# on a v5e, ms a call, kernel / `causal_attention_blocked`: a window of
+# 128 over 8,192 tokens (64 heads of 128 over 8 key heads) 2.62 / 2.10
+# (3.74 / 2.10 at PR 43), of 31 blocks of 512 x 512 an eighth seen; a
+# window of 513 over 8,192 queries and 8,704 keys (64 heads of 256, v
+# 128: PR 61) 3.32 / 5.53, of 32 blocks half seen where XLA multiplies
+# 255 + 513 keys a row. Between 128 and 513 nothing has been timed.
+MIN_BAND_WINDOW = 513
 
 
 _ROUTE_LOG: contextvars.ContextVar = contextvars.ContextVar(
@@ -735,3 +735,28 @@ def short_attend(q: jax.Array, k: jax.Array, v: jax.Array, interpret: bool = Fal
     if log is not None:
         log.append(short_attention.entry(q.shape[1], k.shape[1], q.shape[2], q.dtype))
     return short_attention.short_attention(q, k, v, interpret=interpret)
+
+
+# --- what a causal call's route multiplies (PR 61) ---------------------------
+# Down here for the same reason.
+
+
+def causal_pairs_computed(route: str, n: int, m: int, d: int, dv: int, itemsize: int,
+                          window: int | None = None) -> int:
+    """The query-key pairs a head of a causal call of n queries over m
+    keys (widths d and dv) multiplies on `route` ("flash" or "xla"), from
+    the route's own blocks: the kernel's computed blocks at its plan's
+    sizes, or `causal_attention_blocked`'s blocks of rows, each over the
+    keys from its first row's first to its last row's last. What the
+    mask lets a row see is the caller's to count."""
+    if route == "flash":
+        pad, pad_v = -d % ROUTE_MULTIPLE, -dv % ROUTE_MULTIPLE
+        n_pad, _, block_q, block_k = flash_plan(
+            n, m, max(d + pad, dv + pad_v), itemsize, causal=True, window=window)
+        return causal_blocks(n_pad, block_q, block_k, n, m, window)[1] * block_q * block_k
+    rows_q, pairs = min(CAUSAL_BLOCK_Q, n), 0
+    for start in range(0, n, rows_q):
+        stop = min(start + rows_q, n)
+        first = 0 if window is None else max(start + m - n - window + 1, 0)
+        pairs += (stop - start) * (stop + m - n - first)
+    return pairs

@@ -5,7 +5,9 @@ style guide byte for byte, then a 24,576-byte manuscript, a story's chapter
 told scene by scene, ending in the line that asks for one scene's prompt.
 And `longdoc-txt2img-granite-4.0-h-micro.json` beside it (`DOCUMENTS`):
 the same graph with `granite-4.0-h-micro`, no draft, and a 65,535-byte
-text, the guide and a 57,344-byte manuscript from another seed.
+text, the guide and a 57,344-byte manuscript from another seed. And
+`longdoc-txt2img-dots3-note.json`: the first file's text, byte for byte,
+with `dots3-note-prev-ep8-5l`, no draft and 256 new tokens.
 
 The manuscript is original prose put together from the phrase lists below
 by a seeded generator (no network, no corpus): `python3
@@ -24,10 +26,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SOURCE = os.path.join(ROOT, "workflows", "rewrite-txt2img-k-exaone.json")
 NAME = "longdoc-txt2img-glm-5.2.json"
 MANUSCRIPT_BYTES = 24576
-# file name: (checkpoint, the generator's seed, the manuscript's bytes, drafts a step)
+# file name: (checkpoint, the generator's seed, the manuscript's bytes, drafts a step,
+# new tokens)
 DOCUMENTS = {
-    NAME: ("glm-5.2-ep16-5l", 52, MANUSCRIPT_BYTES, 1),
-    "longdoc-txt2img-granite-4.0-h-micro.json": ("granite-4.0-h-micro", 54, 57344, 0),
+    NAME: ("glm-5.2-ep16-5l", 52, MANUSCRIPT_BYTES, 1, 128),
+    "longdoc-txt2img-granite-4.0-h-micro.json": ("granite-4.0-h-micro", 54, 57344, 0, 128),
+    "longdoc-txt2img-dots3-note.json": ("dots3-note-prev-ep8-5l", 52, MANUSCRIPT_BYTES, 0, 256),
 }
 ASK = "\n\nIllustrate scene {scene} of the chapter above, and nothing of the other scenes.\nPrompt:"
 
@@ -94,7 +98,7 @@ def manuscript(seed: int = 52, size: int = MANUSCRIPT_BYTES) -> str:
 
 
 def main() -> None:
-    for name, (checkpoint, seed, size, drafts) in DOCUMENTS.items():
+    for name, (checkpoint, seed, size, drafts, new_tokens) in DOCUMENTS.items():
         with open(SOURCE, encoding="utf-8") as fh:
             graph = json.load(fh)
         for node in graph.values():
@@ -106,7 +110,7 @@ def main() -> None:
                 assert len(guide.encode()) == 8191, len(guide.encode())
                 body = manuscript(seed, size)
                 assert body.isascii() and len(body) == size, len(body)
-                inputs.update(text=guide + body, max_new_tokens=128, draft_tokens=drafts)
+                inputs.update(text=guide + body, max_new_tokens=new_tokens, draft_tokens=drafts)
             elif node["class_type"] == "SaveImage":
                 inputs["filename_prefix"] = name[: -len(".json")]
         data = json.dumps(graph, indent=2) + "\n"

@@ -60,10 +60,20 @@ _TESTS = os.path.join(
 # what PR 54 found (`attn_device_pct.lm` listing Nemotron-3-Nano's and
 # granite's cells alone): `test_sdar_readers.py` has the one that holds no
 # list to its end anywhere.
+#
+# PR 61 appended a second model with learned sparse attention to the two
+# DSA kernels' lists, which `test_dsa_attend_readers.py` and
+# `test_dsa_select_readers.py` had each held to be GLM-5.2's cell alone:
+# `test_dots3_readers.py` has one check for both that holds each list from
+# its start.
 _LISTED = ("test_the_lm_cells_are_listed_where_their_readers_find_something_no_list_held_to_"
            "its_end")
 _MODULES = {}
+_DSA_LISTED = "test_a_dsa_kernels_metric_keeps_its_place_and_lists_the_glm_cell_first"
 _SUPERSEDED = {
+    "test_the_metric_is_the_manifests_last_and_lists_the_glm_cell": _DSA_LISTED,
+    "test_the_selection_metric_follows_the_attention_kernels_and_lists_the_glm_cell_alone":
+        _DSA_LISTED,
     "test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys":
         "test_the_sizes_the_nemotron3_nano_counts_read_are_the_registrys_a_tree_a_block",
     "test_the_lm_cells_are_listed_where_their_readers_find_something_whoever_came_last": _LISTED,

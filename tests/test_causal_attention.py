@@ -324,6 +324,8 @@ def test_the_plan_of_a_causal_call_and_the_share_of_the_square_it_computes(
     (8192, 64, 128, False),
     (8192, 128, 128, False),   # K-EXAONE's window layers: a band that is all edge (PERF.md §6)
     (8192, 128, 1024, True),
+    (8704, 128, 513, True),    # dots3-note-prev's window over a tail: timed, the kernel's (PR 61)
+    (8704, 128, 512, False),   # under it nothing has been timed
 ])
 def test_the_shapes_a_tpu_sends_to_the_causal_kernel(monkeypatch, m, v_width, window, wins):
     assert att.causal_kernel_wins(m, v_width, window) is wins

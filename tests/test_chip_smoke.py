@@ -306,6 +306,37 @@ def test_the_mamba_row_holds_the_chunked_scan_to_the_recurrence(smoke, capsys, w
     assert row["step"]["blocks"] == shape[-1]
 
 
+def test_the_band_row_with_a_tail_is_at_the_tenth_models_sizes_and_its_routes_agree(
+        smoke, capsys):
+    """dots3-note-prev's row (PR 61): a part of the committed workflow's
+    prompt over the 512 latents before it and its own, the sliding
+    kind's heads and widths, the published window; and the rehearsal's
+    toy row on the CPU: more keys than queries under a band, the kernel
+    (interpreted) and XLA's blocks against the float32 form."""
+    import jax
+
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("dots3-note-prev-ep8-5l")
+    kind = cfg.sliding
+    label, q_shape, kv_heads, v_width, window, keys = smoke.BAND_TAIL_SHAPE
+    assert q_shape == (1, cfg.prefill_part, kind.heads, kind.width) and kv_heads == kind.heads
+    assert (v_width, window, keys) == (
+        kind.value, cfg.sliding_window_size, cfg.prefill_part + cfg.tail_positions)
+    with open(os.path.join(REPO_ROOT, "workflows", "longdoc-txt2img-dots3-note.json")) as fh:
+        (node,) = [node["inputs"] for node in json.load(fh).values()
+                   if node["class_type"] == "TextGenerate"]
+    assert (1 + len(node["text"].encode("utf-8"))) % cfg.prefill_part == 0
+    if jax.default_backend() != "cpu":
+        pytest.skip("the rehearsal's row is the CPU's")
+    assert smoke.causal_row(True, *smoke.REHEARSAL_BAND_TAIL_SHAPE)
+    (row,) = _result_lines(capsys.readouterr().out)
+    assert row["ok"] and (row["keys"], row["window"]) == (1409, 130)
+    assert row["flash"]["entry"].startswith("flash-causal 1280x1409x256/128 pad")
+    assert row["xla"]["entry"] == "xla-causal 1280x1409x256/128 w130 bq256 bf16"
+    assert "sweep_ms" not in row  # the caps are swept on the chip alone
+
+
 def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys):
     """The row's shape is the cell's: the last part of the committed
     workflow's prompt over caches of the request's length, the

@@ -840,6 +840,94 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
     assert text.count('custom_call_target="tpu_custom_call"') == 2 * (cfg.sparse_layers + 1)
 
 
+def test_the_band_kernel_compiles_for_v5e_over_a_tail_of_latents(one_chip):
+    """dots3-note-prev's sliding layers' call (`chip_smoke.BAND_TAIL_SHAPE`):
+    8,192 queries over the 512 latents before the part and its own, 64
+    heads 256 wide beside values of 128, under a band of 513, on the
+    kernel: fewer queries than keys, two blocks of 512 keys a block of
+    rows. The shape rule sends it where `MIN_BAND_WINDOW` says; it
+    compiles all the same."""
+    _, q_shape, kv_heads, v_width, window, keys = chip_smoke.BAND_TAIL_SHAPE
+    b, n, h, d = q_shape
+    place = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+    fn = jax.jit(functools.partial(attn.causal_attention, window=window, force_flash=True))
+    with attn.route_log() as routes:
+        compiled = fn.lower(
+            place(*q_shape), place(b, keys, kv_heads, d), place(b, keys, kv_heads, v_width)
+        ).compile()
+    assert routes == [
+        "flash-causal 8192x8704x256/128 w513 g1 bq512 bk512 bf16 inplace blocks32/272"]
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%flash_attention_causal" in text
+    assert attn.causal_pairs_computed("flash", n, keys, d, v_width, 2, window) == 32 * 512 * 512
+
+
+def test_dots3_prefill_in_parts_hands_tails_on_and_its_decode_carries_caches_and_rings_in_place(
+        one_chip, monkeypatch):
+    """dots3-note-prev's two programs at the cell's sizes (32,768 ids in
+    four parts of 8,192 over caches of 33,024 positions), routed as a TPU
+    routes them. The prefill is one `while` over the parts: each full
+    layer picks its keys in the `dsa_select` kernel (a rung of the
+    lengths' ladder each) and attends in the `dsa_attend` kernel at 128
+    heads; each sliding layer holds two band calls under a `lax.switch`,
+    the first part's over its own 8,192 latents and a later part's over
+    8,704; what the program holds beside its arguments is a part's
+    working set. The decode carries the donated tree of two latent
+    caches, two index caches and three rings (96.4 MB) through its
+    loop, a step's query takes the masked form in both full layers, and
+    a step's grouped products run in the `expert_matvec` kernel (two a
+    sparse layer)."""
+    from comfyui_distributed_tpu.models import dots3
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("dots3-note-prev-ep8-5l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: dots3.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        prefill = dots3.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((32768,), jnp.int32, sharding=one_chip),
+            cache_len=33024,
+        ).compile()
+    picks = [f"dsa-select-kernel 8192x{length} k2048" for length in (4096, 8192, 16384, 32768, 33024)]
+    assert [r for r in routes if r.startswith("dsa-select")] == picks * cfg.full_layers
+    assert routes.count("dsa-kernel 8192x33024 k2048 h128 bf16") == cfg.full_layers
+    route = "flash" if attn.causal_kernel_wins(8704, 128, 513) else "xla"
+    bands = [r for r in routes if " w513 " in r]
+    assert len(bands) == 2 * cfg.window_layers and all(r.startswith(route + "-causal") for r in bands)
+    assert [r.split()[1] for r in bands] == ["8192x8192x256/128", "8192x8704x256/128"] * 3
+    assert len(routes) == (len(picks) + 1) * cfg.full_layers + len(bands)
+    memory = prefill.memory_analysis()
+    assert memory.temp_size_in_bytes < 3.6e9      # 3.18 GB: a part's, whatever the parts' number
+    assert memory.output_size_in_bytes >= 33024 * 2816 + 3 * 520 * 2176
+    text = prefill.as_text()
+    assert " while(" in text and not [
+        line for line in text.splitlines()
+        if " sort(" in line and re.search(r"\[\d+,(4096|8192|16384|32768|33024)\]", line)]
+    assert text.count("%dsa_select") >= 1 and text.count("%dsa_attend") >= 1
+    assert not [line for line in text.splitlines() if " gather(" in line and "2048,576" in line]
+
+    state = jax.tree.map(place, dots3.state_shapes(cfg, 33024, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        decode = dots3.decode.lower(
+            cfg, params, state,
+            jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+            scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+            scalar(jnp.float32), steps=256,
+        ).compile()
+    assert routes == ["dsa-masked 1x33024 k33024 h128 bf16"] * cfg.full_layers
+    memory = decode.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.2e9     # 100 MB
+    assert memory.alias_size_in_bytes >= 33024 * 2816 + 3 * 520 * 2176
+    text = decode.as_text()
+    assert not [line for line in text.splitlines()
+                if (" sort(" in line or " gather(" in line) and "33024" in line]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * cfg.sparse_layers
+
+
 def test_sdar_prefill_masks_by_block_in_the_causal_kernel_and_its_decode_carries_six_leaves_in_place(
         one_chip, monkeypatch):
     """SDAR's two programs at the served stage's sizes (2,048 prompt
