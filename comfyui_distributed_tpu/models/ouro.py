@@ -196,7 +196,12 @@ def _after_attention(cfg, p, x, out):
     before it joins the residual stream."""
     x = x + rms_norm(out @ p["w_o"], p["attn_out_norm"], cfg.rms_norm_eps)
     with jax.named_scope("mlp"):
-        m = swiglu(rms_norm(x, p["ffn_norm"], cfg.rms_norm_eps).astype(out.dtype), p)
+        m = rms_norm(x, p["ffn_norm"], cfg.rms_norm_eps).astype(out.dtype)
+        # one token's products on the vector: as a [1, 2 x width] matrix the first one's
+        # result is re-tiled on a TPU (bfloat16 rows go two to a sublane, so one row is
+        # half padding) before the split reads it: 0.095 us a layer, and silu x up
+        # 0.14 us longer on the padded row (v5e, PERF.md section 5)
+        m = swiglu(m[0], p)[None] if m.shape[0] == 1 else swiglu(m, p)
         return x + rms_norm(m, p["ffn_out_norm"], cfg.rms_norm_eps)
 
 
@@ -253,18 +258,18 @@ def _loop(cfg, params, x, cache, one_layer):
     """`total_ut_steps` passes over the one stack of layers. `one_layer(
     p, x, cache, slot)` returns (x, cache). Returns (h_T, cache, every
     pass's lambda [T, tokens], every pass's h_t at the last token [T,
-    hidden])."""
-    indices = jnp.arange(cfg.num_hidden_layers)
-
+    hidden]). The layer's index is a counter in the carry: scanned
+    beside the weights it is sliced out of an array, a device operation
+    a layer, which one token's body runs 192 times."""
     def one_pass(carry, step):
-        def body(carry, xs):
-            index, p = xs
+        def body(carry, p):
+            x, cache, index = carry
             with jax.named_scope("layer"):
-                return one_layer(p, *carry, (step, index)), None
+                return (*one_layer(p, x, cache, (step, index)), index + 1), None
 
-        carry, _ = jax.lax.scan(body, carry, (indices, params["layers"]))
-        h, lam = _close_pass(cfg, params, carry[0])
-        return (h, carry[1]), (lam, h[-1])
+        (x, cache, _), _ = jax.lax.scan(body, (*carry, jnp.int32(0)), params["layers"])
+        h, lam = _close_pass(cfg, params, x)
+        return (h, cache), (lam, h[-1])
 
     with jax.named_scope("loop"):
         (h, cache), (lam, hidden) = jax.lax.scan(

@@ -159,7 +159,7 @@ def decode_attention(
     scale = d ** -0.5
     contract_last = (((1,), (1,)), ((), ()))  # q @ k.T without the transpose
 
-    def kernel(slot_ref, position_ref, q_ref, kv_ref, o_ref):
+    def kernel(pass_ref, layer_ref, position_ref, q_ref, kv_ref, o_ref):
         position = position_ref[0]
         cols = jax.lax.broadcasted_iota(jnp.int32, (1, positions), 1)
         rows = jax.lax.broadcasted_iota(jnp.int32, (positions, 1), 0)
@@ -182,23 +182,23 @@ def decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(heads // group,),
             in_specs=[
-                pl.BlockSpec((1, group, d), lambda gi, slot, position: (gi, 0, 0)),
+                pl.BlockSpec((1, group, d), lambda gi, *_: (gi, 0, 0)),
                 pl.BlockSpec(
                     (None, None, 2, group, positions, d),
-                    lambda gi, slot, position: (slot[0], slot[1], 0, gi, 0, 0)),
+                    lambda gi, pass_, layer, position: (pass_[0], layer[0], 0, gi, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, group, d), lambda gi, slot, position: (gi, 0, 0)),
+            out_specs=pl.BlockSpec((1, group, d), lambda gi, *_: (gi, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((heads // group, group, d), cache.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="decode_attention",  # the kernel's name in a device trace
     )(
-        jnp.stack([jnp.asarray(i, jnp.int32) for i in slot]),
-        jnp.asarray(position, jnp.int32).reshape(1),
+        # the slot's indices apart: stacked, they cost a device operation a call
+        *(jnp.asarray(i, jnp.int32).reshape(1) for i in (*slot, position)),
         q.astype(cache.dtype).reshape(heads // group, group, d), cache,
     )
     return out.reshape(heads, d)

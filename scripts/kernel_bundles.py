@@ -23,12 +23,13 @@ import glob
 import os
 import re
 import shutil
-import subprocess
 import sys
 import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+
+from scripts.described_v5e import one_chip, run_child  # noqa: E402
 
 UTILIZATION = "final_hlo-static-per-bundle-utilization.txt"
 
@@ -158,18 +159,12 @@ DEFAULT = ("sd15 self 64x64", "flux joint 4608", "solar / k-exaone full 8192")
 
 def child(label: str) -> None:
     """Compile the case for a described v5e; libtpu dumps as it goes."""
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    chip = one_chip()
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
-    jax.config.update("jax_enable_compilation_cache", False)
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    one_chip = SingleDeviceSharding(topo.devices[0])
     fn, args = CASES[label][1](
-        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip))
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip))
     jax.jit(fn).lower(*args).compile()
 
 
@@ -192,14 +187,9 @@ def read_bundles(dump: str, kernel: str) -> dict:
 def measure(label: str, keep: str | None) -> dict:
     kernel = CASES[label][0]
     dump = tempfile.mkdtemp(prefix="kernel_bundles_", dir=keep)
-    env = dict(
-        os.environ,
-        LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true",
-        ALLOW_MULTIPLE_LIBTPU_LOAD="1",  # a test run beside this one may hold libtpu's lock
-    )
-    done = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--child", label],
-        env=env, cwd=REPO, capture_output=True, text=True)
+    done = run_child(
+        __file__, label,
+        LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump} --xla_jf_dump_llo_text=true")
     try:
         return read_bundles(dump, kernel)
     except SystemExit:

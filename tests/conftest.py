@@ -124,3 +124,16 @@ def tmp_config_path(tmp_path, monkeypatch):
         config_mod._cache.mtime = None
         config_mod._cache.data = None
     return str(path)
+
+
+@pytest.fixture(scope="session")
+def loop_body_ops():
+    """`scripts/loop_body_ops.py` as a module: its reading of a compiled
+    program's text (the script's parent side imports no JAX)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "loop_body_ops", os.path.join(REPO_ROOT, "scripts", "loop_body_ops.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module

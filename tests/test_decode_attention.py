@@ -96,6 +96,33 @@ def test_every_slot_is_its_own():
     assert len({out.tobytes() for out in outs.values()}) == 4
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("position", [0, 41, 63])
+def test_the_slot_may_be_a_loops_carry(position, dtype):
+    """As a model's loop calls it: the pass a scanned value, the layer a
+    counter in the inner scan's carry, the position traced, each handed
+    to the kernel as a scalar of its own. Every (pass, layer) reads its
+    own slot, the bytes a call with plain integers gives."""
+    q, cache = operands(2, 64, dtype, seed=11)
+
+    @jax.jit
+    def walk(q, cache, position):
+        def one_pass(_, step):
+            def one_layer(layer, _):
+                out = da.decode_attention(q, cache, (step, layer), position, interpret=True)
+                return layer + 1, out
+            return None, jax.lax.scan(one_layer, jnp.int32(0), None, length=LAYERS)[1]
+        return jax.lax.scan(one_pass, None, jnp.arange(PASSES))[1]
+
+    got = np.asarray(walk(q, cache, jnp.int32(position)), np.float32)
+    assert got.shape == (PASSES, LAYERS, 2, D)
+    for step in range(PASSES):
+        for layer in range(LAYERS):
+            want = da.decode_attention(q, cache, (step, layer), position, interpret=True)
+            assert got[step, layer].tobytes() == np.asarray(want, np.float32).tobytes()
+    assert len({got[s, l].tobytes() for s in range(PASSES) for l in range(LAYERS)}) == 4
+
+
 @pytest.mark.parametrize("heads,positions,d,itemsize,group", [
     (16, 2112, 128, 2, 1),     # the cell: one head a step is 1.08 MB
     (16, 2112, 128, 4, 1),

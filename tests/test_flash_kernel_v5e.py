@@ -310,7 +310,7 @@ def test_decode_attention_compiles_for_v5e_over_the_whole_cache(one_chip, name, 
     ("cpu", 2, "decode-xla 16x2112x128", 0),  # the einsum form: what `SLOT_SHAPED` is there to find
 ])
 def test_ouro_decode_reads_the_slot_where_it_lies(
-        one_chip, monkeypatch, backend, steps, entry, calls):
+        one_chip, monkeypatch, loop_body_ops, backend, steps, entry, calls):
     """The whole decode at the published sizes (64 steps, the cell's),
     compiled as a TPU routes it: one kernel in the layer body, the cache
     carried in place (a cache-sized temporary would be a copy around the
@@ -339,6 +339,15 @@ def test_ouro_decode_reads_the_slot_where_it_lies(
     assert text.count('custom_call_target="tpu_custom_call"') == calls
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
     assert bool(re.findall(SLOT_SHAPED, text)) == (backend == "cpu")
+    if backend == "tpu":
+        # what the layer body launches (`scripts/loop_body_ops.py`; 26 before PR 62): the
+        # four products, nine of the norms and residual adds, silu x up and the nine that
+        # turn the qkv row into the kernel's operands and its result into w_o's
+        body = loop_body_ops.body_rows(text)
+        costed = loop_body_ops.costed(body)
+        assert len(costed) <= 23, [row["name"] for row in costed]
+        # no scanned index sliced out, no stacked slot, no one-row matrix before the split
+        assert not {"s32[1]", "s32[2]", "bf16[1,11264]"} & {row["result"] for row in body}
 
 
 def test_solar_decode_carries_its_state_tree_in_place(one_chip):

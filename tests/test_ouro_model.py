@@ -204,6 +204,36 @@ def count(jaxpr, primitive):
     return sum(eqn.primitive.name == primitive for eqn in walk(jaxpr))
 
 
+@pytest.mark.parametrize("dtype, tolerance", [(jnp.float32, 1e-6), (jnp.bfloat16, 1e-2)])
+def test_one_tokens_layer_is_a_row_of_the_whole_sequences(dtype, tolerance):
+    """A layer's second half takes one token's SwiGLU on the vector and a
+    sequence's on the matrix: the same sums, so the one token alone is
+    its row of the sequence."""
+    params = ouro.init_params(TINY, jax.random.key(2), dtype)
+    p = ouro.unstacked(params)["layers"][1]
+    x = jax.random.normal(jax.random.key(3), (5, TINY.hidden_size))
+    out = jax.random.normal(jax.random.key(4), (5, TINY.hidden_size)).astype(dtype)
+    whole = ouro._after_attention(TINY, p, x, out)
+    one = ouro._after_attention(TINY, p, x[2:3], out[2:3])
+    assert one.shape == (1, TINY.hidden_size) and one.dtype == whole.dtype == jnp.float32
+    assert rel_l2(one, whole[2:3]).max() <= tolerance
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_the_layer_index_is_a_counter_in_the_carry(program):
+    """Neither program scans an array of layer indices beside the stacked
+    weights (sliced a layer, that is a device operation in a body one
+    token runs 192 times): the inner scan scans the stack's eight leaves
+    and carries a counter."""
+    jaxpr = dict(zip(("prefill", "decode"), programs(TINY)))[program]
+    (scan,) = [e for e in walk(jaxpr) if e.primitive.name == "scan"
+               and e.params["length"] == TINY.num_hidden_layers]
+    consts, carry = scan.params["num_consts"], scan.params["num_carry"]
+    carried, scanned = scan.invars[consts:consts + carry], scan.invars[consts + carry:]
+    assert len(scanned) == 8
+    assert [v.aval.shape for v in carried if v.aval.dtype == jnp.int32] == [()]
+
+
 @pytest.mark.parametrize("layers, passes", [(6, 4), (2, 1), (6, 1)])
 def test_both_programs_hold_the_layer_body_once(layers, passes):
     """As many products at 6 layers as at 2 and at 4 passes as at 1:
