@@ -36,7 +36,10 @@ Drives the main path once, through the entry points an operator uses:
                of the sweep, and the same for dots3-note-prev's band of
                513 over a part's 8,192 queries and the 512 latents
                before them (`BAND_TAIL_SHAPE`: more keys than queries,
-               what `MIN_BAND_WINDOW` rests on); then the single-query
+               what `MIN_BAND_WINDOW` rests on) and for
+               LongCat-Flash-Chat's last part over every key so far
+               (`LATENT_PART_SHAPE`: 8,192 queries, 32,768 keys, no
+               window); then the single-query
                kernel of a language model's decode
                (`ops/decode_attention`) against the einsum form over
                every slot of Ouro's 3.3 GB cache, a call a slot inside
@@ -908,6 +911,12 @@ CAUSAL_SHAPES = (
 # entry: the keys). What `ops/attention.MIN_BAND_WINDOW` rests on.
 BAND_TAIL_SHAPE = ("dots3 window 8192 + 512", (1, 8192, 64, 256), 64, 128, 513, 8704)
 REHEARSAL_BAND_TAIL_SHAPE = ("toy window with a tail", (1, 1280, 2, 256), 2, 128, 130, 1409)
+# LongCat-Flash-Chat's attentions (PR 63): the last part's 8,192 queries
+# over the keys and values rebuilt from every latent so far, 32,768, the 16
+# heads one call takes, 192 (128 + 64) wide beside values of 128, no window:
+# a plain causal call with four times as many keys as queries.
+LATENT_PART_SHAPE = ("longcat latent part 8192 of 32768", (1, 8192, 16, 192), 16, 128, None, 32768)
+REHEARSAL_LATENT_PART_SHAPE = ("toy latent part", (1, 256, 2, 192), 2, 128, None, 1024)
 REHEARSAL_CAUSAL_SHAPES = (
     ("toy causal grouped", (1, 1280, 4, 128), 2, 128, None),
     ("toy causal window", (1, 1280, 2, 128), 2, 128, 100),
@@ -1124,6 +1133,8 @@ def attention_child(rehearsal: bool) -> int:
         failed += not causal_row(rehearsal, *shape)
     failed += not causal_row(
         rehearsal, *(REHEARSAL_BAND_TAIL_SHAPE if rehearsal else BAND_TAIL_SHAPE))
+    failed += not causal_row(
+        rehearsal, *(REHEARSAL_LATENT_PART_SHAPE if rehearsal else LATENT_PART_SHAPE))
     failed += not decode_slot_row(rehearsal)
     failed += not kda_keep_row(rehearsal)
     for shape in REHEARSAL_KDA_DELTA_SHAPES if rehearsal else KDA_DELTA_SHAPES:

@@ -1,7 +1,7 @@
-"""What the ten language models share (`deepseek_v2.py`, `ouro.py`,
-`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`,
-`glm_dsa.py`, `granite_hybrid.py`, `sdar.py`, `dots3.py`): the blocks and helpers
-they are written from, the one initialisation rule, sampling on the
+"""What the eleven language models share (`deepseek_v2.py`, `ouro.py`,
+`solar_open2.py`, `k_exaone.py`, `ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`,
+`granite_hybrid.py`, `sdar.py`, `dots3.py`, `longcat_flash.py`): the blocks and
+helpers they are written from, the one initialisation rule, sampling on the
 device, the rule by which a drafted token is kept or replaced and the
 rule by which a block's drawn tokens are kept or masked again, the three
 decode loops, the prefill in parts, and the stand-in tokenizer.
@@ -47,7 +47,7 @@ once:
   any other number and `drafting_report` the steps' half of `report`;
 - a `prefill` that cannot take its prompt at once hands one part's body
   and the arrays it wants cut to `prefill_in_parts` (GLM-5.2,
-  granite-4.0-h-micro, dots3-note-prev: the state a part hands on is a
+  granite-4.0-h-micro, dots3-note-prev, LongCat-Flash: what a part hands on is a
   tree of whatever the model needs, caches of full length, recurrent
   states, the last latents of a window), which owns the cut (`parts_of`), the scan over
   the whole parts, the remainder and the joining of the parts' outputs;

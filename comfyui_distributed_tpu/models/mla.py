@@ -1,6 +1,6 @@
 """Multi-head latent attention's two forms, as the models with such
 layers share them (`deepseek_v2.py`, `ling_flash.py`, `glm_dsa.py`,
-`dots3.py`), over a head's
+`dots3.py`, `longcat_flash.py`), over a head's
 queries in two parts (`q_nope` [T, heads, nope], and `q_rope` [T, heads,
 rope], rotated), the latents a cache holds ([S, rank + rope]: the normed
 latent c and the one rotated rope key r of all heads, side by side) and
@@ -11,8 +11,8 @@ the two halves of the up-projection, `w_uk` [rank, heads, nope] and
 
 `latents` makes what the cache holds; `expanded` builds every key and value from the latents (a whole
 sequence, causal: a prefill; under a `window` a band, and with the
-latents `before` the sequence, a part of a prompt whose first queries
-still see the part before); `absorbed` folds W_uk into the query,
+latents `before` the sequence, a part of a prompt whose queries see a
+window's tail or every position before it); `absorbed` folds W_uk into the query,
 attends over the latents themselves and applies W_uv after the weighted
 sum (a decode step's new positions over a cache, or over a ring: the
 cache is then `ring_positions` rows, position p in row p modulo that,

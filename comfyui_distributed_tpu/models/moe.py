@@ -1,17 +1,19 @@
 """The expert layer of one chip, as the language models with a mixture
 of experts share it (`deepseek_v2.py`, `solar_open2.py`, `k_exaone.py`,
-`ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`, `sdar.py`, `dots3.py`), and the
-routing rule of those that score by sigmoids
-(`sigmoid_route`, with or without groups chosen first).
+`ling_flash.py`, `nemotron_h.py`, `glm_dsa.py`, `sdar.py`, `dots3.py`,
+`longcat_flash.py`), and the routing rule of those that score by
+sigmoids (`sigmoid_route`, with or without groups chosen first).
 
 The layer is told which routed experts it holds (`held`, a contiguous
 run: `parallel.sharding.expert_range` of the chip's rank), routes every
-token over all of them by the model's own rule, and computes its own
-experts' part of the result as one grouped product
-(`jax.lax.ragged_dot`) with no dropped token and no capacity factor;
-what absent experts would add is left out. Where the layer's tree has a
-shared expert (`shared`: seven of the eight models; SDAR has none) it sees
-every token.
+token over the router's whole width by the model's own rule, and computes
+its own experts' part as one grouped product (`jax.lax.ragged_dot`) with
+no dropped token and no capacity factor. An id outside `held` is an
+absent chip's expert, whose part is left out, or, in a model whose router
+is wider than its experts (`identities`: LongCat-Flash's 768 outputs over
+512 experts), an identity: no chip's weights, its weight times the
+layer's own input, added here whole. A `shared` expert in the tree (seven
+of the nine models; SDAR and LongCat-Flash have none) sees every token.
 
 Sorted by held expert, the pairs a chip holds are the first rows and
 every row after them is an absent expert's, so the row gather, the
@@ -25,9 +27,8 @@ run, on a TPU, in the Pallas kernel of `ops/expert_matvec.py`, which
 reads each chosen held expert's weights once, out of the stacked array
 (`decode_route`); the prefill's stay `ragged_dot`.
 
-Parameters: `w_g` [hidden, experts] (the router), `experts` and, where
-there is one, `shared` in the expert's form, which the tree itself says
-(`gated`): a SwiGLU,
+Parameters: `w_g` [hidden, router width], `experts` and, where there is
+one, `shared` in the expert's form, which the tree says (`gated`): a SwiGLU,
 {`w_gate_up` [held, hidden, 2 x width], `w_down` [held, width, hidden]}
 and `shared` one such, or two matrices without a gate, relu(x W_up)^2
 W_down (Nemotron-H's): {`w_up` [held, width, hidden], stored out by in
@@ -35,8 +36,7 @@ as a checkpoint stores a projection, because the width may be off the
 lane tile and the hidden size is not (`ops/expert_matvec`), `w_down`
 [held, width, hidden]} and `shared` {`w_up` [hidden, width], `w_down`};
 whatever else the model's rule reads (a selection bias) stays with the
-rule. A model that scans over stacked layers hands the routed experts'
-stacks whole with the layer's index (`index`).
+rule. A scan over stacked layers hands the stacks whole with `index`.
 """
 
 from __future__ import annotations
@@ -62,9 +62,9 @@ def row_ladder(pairs: int, held: int, experts: int) -> tuple[int, ...]:
     """The static row counts a layer of `pairs` token-expert pairs may
     run its held experts' products over, ascending: `pairs` itself last,
     below it `pairs` halved again and again (rounded up to whole tiles)
-    down to the share `held` of `experts` take when the routing is even.
-    One rung where `pairs` is a tile or less (a decode step), or where
-    every expert is held."""
+    down to the share `held` of `experts` (the router's width, identities
+    among them) take when the routing is even. One rung where `pairs` is
+    a tile or less (a decode step), or where every expert is held."""
     rungs = [pairs]
     while True:
         rows = -(-pairs // (ROW_TILE << len(rungs))) * ROW_TILE
@@ -129,19 +129,19 @@ def decode_route(rows: int, hidden: int, width: int, dtype, with_gate: bool = Tr
     return "kernel" if routes == {"kernel"} else "xla"
 
 
-def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
-                 limit: float = 0.0, shared_limit: float = 0.0, index=None):
+def expert_layer(p: dict, x: jax.Array, held: range, route: Callable, limit: float = 0.0,
+                 shared_limit: float = 0.0, index=None, identities: int | None = None):
     """x [T, hidden] through the layer. `route(logits)` takes the
-    router's float32 logits [T, experts] and returns (ids [T, k],
-    weights [T, k] float32). `limit` and `shared_limit` clamp the routed
-    experts' and the shared expert's SwiGLU (`lm_common.
-    clamped_silu_product`; 0: none), on either route of the grouped
-    products; an expert without a gate (`gated`) has nothing to clamp.
-    With `index` (a traced scalar: a scan's body) `p["experts"]` holds
-    stacks of layers `[layers, held, ...]` of which this layer is that
-    one, read where it lies. A tree without `shared` is a layer without a
-    shared expert: the output is the routed part alone. Returns (output,
-    chosen ids [T, k], pairs on each held expert [held])."""
+    router's float32 logits [T, width] and returns (ids [T, k], weights
+    [T, k] float32). `limit` and `shared_limit` clamp the routed experts'
+    and the shared expert's SwiGLU (`lm_common.clamped_silu_product`; 0:
+    none), on either route; an expert without a gate has none. With
+    `index` (a traced scalar: a scan's body) `p["experts"]` holds stacks
+    `[layers, held, ...]` of which this layer is that one, read where it
+    lies. Ids from `identities` on are identity experts (the module's
+    docstring): never held, never rows. A tree without `shared` has no
+    shared expert. Returns (output, chosen ids [T, k], pairs on each
+    held expert [held])."""
     with jax.named_scope("router"):
         logits = jnp.dot(
             x.astype(jnp.float32), p["w_g"].astype(jnp.float32),
@@ -203,6 +203,11 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
         else:
             routed = jax.lax.switch(
                 rung_index(ladder, sizes.sum()), [partial(over, rows_n) for rows_n in ladder])
+    if identities is not None:
+        with jax.named_scope("zero_experts"):
+            # an identity's pair: its weight times the layer's input, in float32
+            kept = jnp.sum(jnp.where(ids >= identities, weights, 0.0), axis=-1, keepdims=True)
+            routed = routed + kept * x.astype(jnp.float32)
     if "shared" not in p:
         return routed.astype(x.dtype), ids, sizes
     with jax.named_scope("shared"):
@@ -212,7 +217,8 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable,
 
 
 def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
-                 prefill_loads, decode_loads, decode_expert_route: str) -> dict:
+                 prefill_loads, decode_loads, decode_expert_route: str,
+                 zero_pairs: tuple[int, int] | None = None) -> dict:
     """`node.TextGenerate`'s attributes of the routing, per phase: the
     token-expert pairs the router made (`k` a token and expert layer),
     those that fell on held experts (`loads` [expert layers, held], as
@@ -221,7 +227,9 @@ def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
     its load as the device read it; a decode step's `k` pairs are under
     a tile, a ladder of one rung, so its rows are its pairs, and
     `decode_expert_route` (the model's `decode_route` of a step's pairs)
-    says what multiplied them."""
+    says what multiplied them. `experts` is the router's width. A model
+    with identity experts hands the pairs that chose one, (the prefill's,
+    the decode's), as it read them back: `<phase>_zero_pairs`."""
     layers, held = np.shape(prefill_loads)
     attrs = {}
     for phase, tokens, loads in (
@@ -230,6 +238,8 @@ def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
         attrs[f"{phase}_routed_pairs"] = tokens * k * layers
         attrs[f"{phase}_routed_pairs_held"] = int(np.sum(loads))
         attrs[f"{phase}_expert_load_max"] = int(np.max(loads))
+    if zero_pairs is not None:
+        attrs["prefill_zero_pairs"], attrs["decode_zero_pairs"] = map(int, zero_pairs)
     ladder = row_ladder(prompt_tokens * k, held, experts)
     attrs["prefill_expert_rows"] = sum(
         ladder[rung_index(ladder, int(n))] for n in np.sum(prefill_loads, axis=1))

@@ -25,6 +25,7 @@ from .glm_dsa import GlmDsa, GlmDsaConfig
 from .granite_hybrid import GraniteHybrid, GraniteHybridConfig
 from .k_exaone import KExaone, KExaoneConfig
 from .ling_flash import LingFlash, LingFlashConfig
+from .longcat_flash import LongcatFlash, LongcatFlashConfig
 from .nemotron_h import NemotronH, NemotronHConfig
 from .sdar import Sdar, SdarConfig
 from .solar_open2 import SolarOpen2, SolarOpen2Config
@@ -706,6 +707,32 @@ MODEL_REGISTRY: dict[str, dict[str, Any]] = {
             num_experts_per_tok=2, vocab_size=512, ep_size=2, ep_rank=0, prefill_part=16,
         ),
     },
+    # meituan-longcat/LongCat-Flash-Chat as rank 0 of the 64 chips that share
+    # each layer, every width as published: layers 0-3 of 28 (a layer is two
+    # latent attentions, two dense feed-forwards and the expert layer on the
+    # shortcut), experts 0-7 of 512 beside the 256 identity experts, which are
+    # no chip's (the router keeps its 768 outputs), the first eighth of the
+    # vocabulary (the benchmark's longcat-flash-chat configuration says what
+    # the cut stands for, and why it is not the 32-way one); no MTP layer
+    "longcat-flash-chat-ep64-4l": {
+        "family": "lm",
+        "config": LongcatFlashConfig(num_layers=4, ep_size=64, ep_rank=0, vocab_shards=8),
+    },
+    # every mechanism at a size for the CPU: 2 layers (four attentions: 4
+    # heads, 8 + 8 wide, values 8, over a latent of 16, a query latent of 32,
+    # two heads a call), dense feed-forwards of 160, 8 experts of 32 columns
+    # beside 4 identities (3 a token of the router's 12) of which rank 0 of 2
+    # holds four; 512 ids; parts of 16 positions, the branch in blocks of 8
+    "tiny-longcat-flash": {
+        "family": "lm",
+        "config": LongcatFlashConfig(
+            hidden_size=64, num_layers=2, num_attention_heads=4, q_lora_rank=32,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+            ffn_hidden_size=160, expert_ffn_hidden_size=32, n_routed_experts=8,
+            zero_expert_num=4, moe_topk=3, vocab_size=512, ep_size=2, ep_rank=0,
+            prefill_part=16, expert_block=8, attention_heads_a_call=2,
+        ),
+    },
 }
 
 # Models whose conditioning comes from TWO encoders (SDXL layout):
@@ -768,6 +795,7 @@ _LANGUAGE_MODELS: dict[type, Callable[[Any], Any]] = {
     GraniteHybridConfig: GraniteHybrid,
     SdarConfig: Sdar,
     Dots3Config: Dots3,
+    LongcatFlashConfig: LongcatFlash,
 }
 
 

@@ -7,7 +7,9 @@ And `longdoc-txt2img-granite-4.0-h-micro.json` beside it (`DOCUMENTS`):
 the same graph with `granite-4.0-h-micro`, no draft, and a 65,535-byte
 text, the guide and a 57,344-byte manuscript from another seed. And
 `longdoc-txt2img-dots3-note.json`: the first file's text, byte for byte,
-with `dots3-note-prev-ep8-5l`, no draft and 256 new tokens.
+with `dots3-note-prev-ep8-5l`, no draft and 256 new tokens. And
+`longdoc-txt2img-longcat-flash.json`: the same text once more, with
+`longcat-flash-chat-ep64-4l`, no draft and 128 new tokens.
 
 The manuscript is original prose put together from the phrase lists below
 by a seeded generator (no network, no corpus): `python3
@@ -32,6 +34,8 @@ DOCUMENTS = {
     NAME: ("glm-5.2-ep16-5l", 52, MANUSCRIPT_BYTES, 1, 128),
     "longdoc-txt2img-granite-4.0-h-micro.json": ("granite-4.0-h-micro", 54, 57344, 0, 128),
     "longdoc-txt2img-dots3-note.json": ("dots3-note-prev-ep8-5l", 52, MANUSCRIPT_BYTES, 0, 256),
+    "longdoc-txt2img-longcat-flash.json": (
+        "longcat-flash-chat-ep64-4l", 52, MANUSCRIPT_BYTES, 0, 128),
 }
 ASK = "\n\nIllustrate scene {scene} of the chapter above, and nothing of the other scenes.\nPrompt:"
 

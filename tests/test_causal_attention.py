@@ -326,6 +326,7 @@ def test_the_plan_of_a_causal_call_and_the_share_of_the_square_it_computes(
     (8192, 128, 1024, True),
     (8704, 128, 513, True),    # dots3-note-prev's window over a tail: timed, the kernel's (PR 61)
     (8704, 128, 512, False),   # under it nothing has been timed
+    (32768, 128, None, True),  # LongCat-Flash-Chat's last part: 8,192 queries, timed (PR 63)
 ])
 def test_the_shapes_a_tpu_sends_to_the_causal_kernel(monkeypatch, m, v_width, window, wins):
     assert att.causal_kernel_wins(m, v_width, window) is wins

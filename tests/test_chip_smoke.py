@@ -337,6 +337,34 @@ def test_the_band_row_with_a_tail_is_at_the_tenth_models_sizes_and_its_routes_ag
     assert "sweep_ms" not in row  # the caps are swept on the chip alone
 
 
+def test_the_latent_part_row_is_at_the_eleventh_models_sizes_and_its_routes_agree(
+        smoke, capsys):
+    """LongCat-Flash-Chat's row (PR 63): the last part of the committed
+    workflow's prompt over every position so far, the heads one call
+    takes at the published widths, no window; and the rehearsal's toy
+    row on the CPU: four times the keys of the queries, the kernel
+    (interpreted) and XLA's blocks against the float32 form."""
+    import jax
+
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config("longcat-flash-chat-ep64-4l")
+    label, q_shape, kv_heads, v_width, window, keys = smoke.LATENT_PART_SHAPE
+    assert q_shape == (1, cfg.prefill_part, cfg.attention_heads_a_call, cfg.qk_head_dim)
+    assert (kv_heads, v_width, window) == (cfg.attention_heads_a_call, cfg.v_head_dim, None)
+    with open(os.path.join(REPO_ROOT, "workflows", "longdoc-txt2img-longcat-flash.json")) as fh:
+        (node,) = [node["inputs"] for node in json.load(fh).values()
+                   if node["class_type"] == "TextGenerate"]
+    assert keys == 1 + len(node["text"].encode("utf-8")) == 4 * cfg.prefill_part
+    if jax.default_backend() != "cpu":
+        pytest.skip("the rehearsal's row is the CPU's")
+    assert smoke.causal_row(True, *smoke.REHEARSAL_LATENT_PART_SHAPE)
+    (row,) = _result_lines(capsys.readouterr().out)
+    assert row["ok"] and (row["keys"], row["window"]) == (1024, None)
+    assert row["flash"]["entry"].startswith("flash-causal 256x1024x192/128 g1")
+    assert row["xla"]["entry"] == "xla-causal 256x1024x192/128 bq256 bf16"
+
+
 def test_the_dsa_row_is_at_the_glm_cells_sizes_and_its_forms_agree(smoke, capsys):
     """The row's shape is the cell's: the last part of the committed
     workflow's prompt over caches of the request's length, the

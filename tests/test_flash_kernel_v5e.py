@@ -937,6 +937,62 @@ def test_dots3_prefill_in_parts_hands_tails_on_and_its_decode_carries_caches_and
     assert text.count('custom_call_target="tpu_custom_call"') == 2 * cfg.sparse_layers
 
 
+def test_longcat_flash_prefill_rebuilds_16_heads_keys_at_a_time_and_its_decode_carries_eight_caches(
+        one_chip, monkeypatch):
+    """LongCat-Flash-Chat's two programs at the cell's sizes (32,768 ids
+    in four parts of 8,192 over eight caches of 32,896 positions), routed
+    as a TPU routes them. The prefill is one `while` over the parts: each
+    of the eight attentions holds a causal kernel call a possible count
+    of keys under a `lax.switch` (8,192 queries over 8,192 to 32,768 keys,
+    192 beside 128 wide), inside a loop over groups of 16 heads, so that
+    one group's rebuilt keys and values are alive at once; the expert
+    branch runs over blocks of 1,024 tokens, so its ladder's top rung is
+    12,288 rows. What the program holds beside its arguments and the
+    caches it hands on stays under 2.1 GB (1.89: with 32 heads a call and
+    blocks of 2,048 it held 2.5, with the groups as a row of calls and
+    not a loop 2.8, whatever the group). The decode carries the donated
+    tree of eight caches (303 MB) through its loop and a step's grouped
+    products run in the `expert_matvec` kernel (two a layer)."""
+    from comfyui_distributed_tpu.models import longcat_flash
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("longcat-flash-chat-ep64-4l")
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda: longcat_flash.init_params(cfg, jax.random.key(0), jnp.bfloat16)))
+    with attn.route_log() as routes:
+        prefill = longcat_flash.prefill.lower(
+            cfg, params, jax.ShapeDtypeStruct((32768,), jnp.int32, sharding=one_chip),
+            cache_len=32896,
+        ).compile()
+    counts = (8192, 16384, 24576, 32768)
+    assert [r.split()[:2] for r in routes] == [
+        ["flash-causal", f"8192x{keys}x192/128"] for keys in counts] * cfg.attention_sublayers
+    assert all(" g1 bq512 bk1024 bf16 inplace " in r for r in routes)
+    cache_bytes = cfg.attention_sublayers * 32896 * cfg.cache_width * 2
+    memory = prefill.memory_analysis()
+    assert memory.output_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes - cache_bytes < 2.1e9
+    text = prefill.as_text()
+    assert " while(" in text and text.count("%flash_attention_causal") >= len(counts)
+
+    state = jax.tree.map(place, longcat_flash.state_shapes(cfg, 32896, jnp.bfloat16))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+    with attn.route_log() as routes:
+        decode = longcat_flash.decode.lower(
+            cfg, params, state,
+            jax.ShapeDtypeStruct((cfg.vocab_held,), jnp.float32, sharding=one_chip),
+            scalar(jnp.int32), place(jax.eval_shape(lambda: jax.random.key(0))),
+            scalar(jnp.float32), steps=128,
+        ).compile()
+    assert routes == []  # the absorbed form: no causal call
+    memory = decode.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.5e9     # 358 MB
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert decode.as_text().count('custom_call_target="tpu_custom_call"') == 2 * cfg.num_layers
+
+
 def test_sdar_prefill_masks_by_block_in_the_causal_kernel_and_its_decode_carries_six_leaves_in_place(
         one_chip, monkeypatch):
     """SDAR's two programs at the served stage's sizes (2,048 prompt

@@ -661,6 +661,72 @@ def dots3_entry(cfg, config):
         cfg, num_hidden_layers=46, ep_size=1, vocab_shards=1)  # nothing else cut
 
 
+def longcat_drawn(attrs):
+    # two layers, 4 of 8 experts held beside 4 identities (a router of 12), 3 a token
+    for phase in ("prefill", "decode"):
+        pairs, on_held = attrs[f"{phase}_routed_pairs"], attrs[f"{phase}_routed_pairs_held"]
+        assert 0 <= on_held < pairs and 0 <= attrs[f"{phase}_zero_pairs"] < pairs
+        assert on_held / (2 * 4) <= attrs[f"{phase}_expert_load_max"] <= on_held
+    # even routing: a third of the pairs on the 4 held of 12, a third on the 4 identities
+    assert 0.2 < attrs["prefill_routed_pairs_held"] / attrs["prefill_routed_pairs"] < 0.5
+    assert 0.2 < attrs["prefill_zero_pairs"] / attrs["prefill_routed_pairs"] < 0.5
+    # a block's 24 pairs a layer are under a tile: a ladder of one rung, a block and layer
+    assert attrs["prefill_expert_rows"] == attrs["prefill_routed_pairs"]
+    assert 0 <= attrs["decode_experts_read"] <= min(attrs["decode_routed_pairs_held"], 16 * 2 * 3)
+    low, mean, high = (attrs[f"real_experts_per_token_{what}"] for what in ("min", "mean", "max"))
+    assert 0 <= low <= mean <= high <= 3
+    zero = attrs["prefill_zero_pairs"] + attrs["decode_zero_pairs"]
+    assert mean == pytest.approx(3 - zero / (2064 * 2))
+
+
+def longcat_workflow(mine, _):
+    theirs = load("workflows/longdoc-txt2img-glm-5.2.json")
+    assert differing(mine, theirs) == {
+        ("CheckpointLoaderSimple", "ckpt_name"), ("TextGenerate", "draft_tokens"),
+        ("SaveImage", "filename_prefix")}
+    generate = by_kind(mine)["TextGenerate"]
+    assert (generate["max_new_tokens"], generate["draft_tokens"], generate["temperature"]) == (
+        128, 0, 1.0)
+    # GLM-5.2's and dots3-note-prev's cells' own text, byte for byte, so the three differ by
+    # model alone; the rehearsal reads its first 2,047 bytes
+    assert generate["text"] == by_kind(theirs)["TextGenerate"]["text"]
+    edits = load("benchmark/workloads/longcat_flash_longdoc_txt2img_512.closed2.json")[
+        "rehearsal"]["set"]
+    (short,) = [e["value"] for e in edits if (e["class_type"], e["input"]) == ("TextGenerate", "text")]
+    assert short == generate["text"][:2047]
+
+
+def longcat_published(config):
+    assert (config["zero_expert_num"], config["zero_expert_type"]) == (256, "identity")
+    assert config["as_run"]["parameters"] == {"lm": 3964789760}
+    assert config["published"]["parameters"] == 560664980480
+    assert (config["as_run"]["cache_bytes_per_token"], config["as_run"]["state_bytes"]) == (
+        8 * 576 * 2, 0)
+    assert (config["as_run"]["prefill_part"], config["as_run"]["expert_block"],
+            config["as_run"]["attention_heads_a_call"]) == (8192, 1024, 16)
+    assert set(config["held"]) == {
+        "layers", "experts", "identity_experts", "vocabulary", "state", "ep_size"}
+    assert "belong to no chip's share" in config["held"]["identity_experts"]
+    assert "each layer shared by 64 chips" in config["deployment"]
+    assert "Why 64 ways and not the driver's rough 32" in config["deployment"]
+    limits = config["parity"]
+    assert 0 < limits["tolerance_rel_l2_max_unflipped"] <= limits["tolerance_rel_l2_median"] < 1
+    assert 0 < limits["tolerance_expert_set_mismatch"] < 1
+    assert 0 < limits["tolerance_cache_rel_l2"] < 1
+
+
+def longcat_entry(cfg, config):
+    assert (cfg.num_layers, len(cfg.held_experts), cfg.vocab_held) == (
+        config["num_layers"], config["n_routed_experts"], config["vocab_size"])
+    assert (cfg.n_routed_experts, cfg.zero_expert_num, cfg.router_width, cfg.vocab_size,
+            cfg.ep_size, cfg.vocab_shards) == (512, 256, 768, 131072, 64, 8)
+    assert (cfg.prefill_part, cfg.expert_block, cfg.attention_heads_a_call) == tuple(
+        config["as_run"][key] for key in ("prefill_part", "expert_block", "attention_heads_a_call"))
+    assert cfg.qk_head_dim == config["qk_head_dim"] == 192
+    assert type(cfg)() == dataclasses.replace(
+        cfg, num_layers=28, ep_size=1, vocab_shards=1)  # nothing else cut
+
+
 @dataclasses.dataclass(frozen=True)
 class Model:
     """One language model's row. `attrs`: `node.TextGenerate`'s attributes
@@ -1203,6 +1269,57 @@ MODELS = [
                            "band_keys_seen_pct.lm", "flash_attention_band_roofline_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
     ),
+    Model(
+        name="longcat-flash-chat", served="longcat-flash-chat-ep64-4l", tiny="tiny-longcat-flash",
+        workflow="longdoc-txt2img-longcat-flash.json", config="longcat-flash-chat.json",
+        reference="longcat_flash.py", catalog="LongCat-Flash-Chat",
+        cell="longcat_flash_longdoc_txt2img_512.closed2", prompt=2048, cell_prompt=32768,
+        new_tokens=16, drafts=0, trace=(5, 20),
+        # tiny-longcat-flash: 2 layers of two latent attentions (4 heads 8 + 8 wide, latents
+        # of 16 + 8, two heads a call), two dense feed-forwards and the expert branch: a
+        # router of 12 over 8 experts (4 held) and 4 identities, 3 a token; parts of 16
+        # positions, the branch in blocks of 8. What grows: four latent caches
+        attrs={
+            "prompt_tokens": 2048, "new_tokens": 16, "draft_tokens": 0, "decode_steps": 16,
+            "layers": 2, "attention_sublayers": 4, "prefill_part": 16, "expert_block": 8,
+            "prefill_parts": 128, "experts_held": 4, "experts_total": 8, "zero_experts": 4,
+            "cache_bytes": (2048 + 16) * 4 * 24 * 4, "state_bytes": 0,
+            "prefill_routed_pairs": 2048 * 2 * 3, "decode_routed_pairs": 16 * 2 * 3,
+            "decode_expert_rows": 16 * 2 * 3, "decode_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
+                                   "decode_expert_rows", "decode_expert_route"}) | {
+            "decode_experts_read", "prefill_zero_pairs", "decode_zero_pairs",
+            "real_experts_per_token_mean", "real_experts_per_token_min",
+            "real_experts_per_token_max"},
+        drawn_check=longcat_drawn,
+        # the ids; a part's pairs per held expert a layer and block and its histogram of real
+        # experts a token (128 parts); the decode's of both and the experts it read
+        wait_bytes=4 * (16 + 128 * 2 * 2 * 4 + 128 * 4 + 2 * 4 + 4 + 1),
+        # a part's 16 queries, two heads a call, over the rows so far: a call a count of keys
+        attention=", ".join(sorted(
+            f"xla-causal 16x{keys}x16/8 bq16 f32" for keys in range(16, 2049, 16))),
+        passes=lambda attrs: (2048 * 2, 16 * 2),
+        widths={
+            "hidden_size": 6144, "num_attention_heads": 64, "q_lora_rank": 1536,
+            "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+            "v_head_dim": 128, "ffn_hidden_size": 12288, "expert_ffn_hidden_size": 2048,
+            "moe_topk": 12, "zero_expert_num": 256, "zero_expert_type": "identity",
+            "routed_scaling_factor": 6, "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+            "rope_theta": 10000000, "rms_norm_eps": 1e-5, "attention_bias": False,
+            "attention_method": "MLA", "max_position_embeddings": 131072, "hidden_act": "silu",
+            "tie_word_embeddings": False},
+        reduced={"num_layers": (28, 4), "n_routed_experts": (512, 8),
+                 "vocab_size": (131072, 16384)},
+        assumed=("modeling_longcat_flash.py", "lines 418-480", "default 1e-6",
+                 "apply_rotary_pos_emb_interleave", "e_score_correction_bias", "nn.Identity",
+                 "no multi-token-prediction layer", "seeded random", "stand-in", "batch is 1",
+                 "style guide"),
+        published=longcat_published, entry=longcat_entry, check_workflow=longcat_workflow,
+        metrics=frozenset({"experts_held_share_pct.lm", "mla_device_pct.lm", "mlp_device_pct.lm",
+                           "experts_device_pct.lm", "zero_expert_pairs_pct.lm",
+                           "shortcut_device_pct.lm", "flash_attention_latent_roofline_pct.lm"}),
+        imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
+    ),
 ]
 BY_NAME = {m.name: m for m in MODELS}
 LM_ENTRIES = sorted(name for name, entry in MODEL_REGISTRY.items() if entry["family"] == "lm")
@@ -1438,7 +1555,8 @@ def test_the_registry_entry_is_the_configuration_file(model):
     for key in model.widths:
         if hasattr(cfg, key):
             assert getattr(cfg, key) == config[key], key
-    assert cfg.num_hidden_layers == config["num_hidden_layers"]
+    depth = "num_hidden_layers" if "num_hidden_layers" in config else "num_layers"
+    assert getattr(cfg, depth) == config[depth]
     model.entry(cfg, config)
 
 
@@ -1496,7 +1614,9 @@ def test_every_language_model_meets_the_one_contract(name):
     with pytest.raises(ValueError, match="draft_tokens"):
         lm.decode(None, None, None, 0, None, 4, 1.0, draft_tokens=model.drafts + 1)
 
-    held = len(getattr(lm.cfg, "held_experts", ()))
+    held, top = len(getattr(lm.cfg, "held_experts", ())), getattr(lm.cfg, "moe_topk", 0)
+    # the blocks the expert branch cuts a part of the 100 tokens below into
+    blocks = -(-min(100, getattr(lm.cfg, "prefill_part", 100)) // getattr(lm.cfg, "expert_block", 100))
     read = {
         "ouro": ([1.0] * 4, [0.5] * 4),
         # a part's loads and keys seen (visible, read) a layer, then the decode's
@@ -1506,6 +1626,10 @@ def test_every_language_model_meets_the_one_contract(name):
         "sdar-30b-a3b": ([[3] * held], [[1] * held], [1, 1, 0, 4, 9]),
         # a part's loads and keys seen (visible, read) a full layer, the decode's, its reads
         "dots3-note-prev": ([[[3] * held]], [[[9, 9], [5, 5]]], [[1] * held], [[7, 7], [2, 2]], 2),
+        # a part's loads a layer and block and its histogram of real experts a token, the
+        # decode's of both, its reads
+        "longcat-flash-chat": (
+            [[[[3] * held] * blocks]], [[1] * (top + 1)], [[1] * held], [1] * (top + 1), 2),
     }.get(model.name, ([[3] * held], [[1] * held]))
     if model.drafts:
         read += ([4, 0, 0, 2],)
@@ -1514,7 +1638,8 @@ def test_every_language_model_meets_the_one_contract(name):
         return lm.report(100, 4, cache_len, *read)
 
     said = report(128)
-    assert said["layers"] == lm.cfg.num_hidden_layers
+    assert said["layers"] == (
+        lm.cfg.num_layers if model.name == "longcat-flash-chat" else lm.cfg.num_hidden_layers)
     assert said["cache_bytes"] > 0 and isinstance(said["cache_bytes"], int)
     assert said["state_bytes"] >= 0 and isinstance(said["state_bytes"], int)
     assert report(256)["cache_bytes"] == 2 * said["cache_bytes"]
@@ -1538,7 +1663,8 @@ def test_every_language_model_meets_the_one_contract(name):
     ("tiny-deepseek-v2", 3), ("deepseek-v2-ep4-5l", 5), ("ouro-2.6b", 192),
     ("solar-open2-ep8-4l", 4), ("k-exaone-ep8-5l", 5), ("ling-flash-ep8-7l", 7),
     ("nemotron3-nano-ep16-52l", 52), ("glm-5.2-ep16-5l", 5), ("granite-4.0-h-micro", 40),
-    ("sdar-30b-a3b-pp8-6l", 6), ("dots3-note-prev-ep8-5l", 5)])
+    ("sdar-30b-a3b-pp8-6l", 6), ("dots3-note-prev-ep8-5l", 5),
+    ("longcat-flash-chat-ep64-4l", 4)])
 def test_a_token_walks_its_layers_once_for_each_pass_of_the_loop(name, passes):
     from comfyui_distributed_tpu.models.registry import create_model
 
@@ -1552,8 +1678,8 @@ def _step_of(name):
     """(module, `step(cfg, params, cache, token, position) -> (logits,
     cache, what the decode sums over its steps)`) of a tiny model."""
     from comfyui_distributed_tpu.models import (
-        deepseek_v2, dots3, glm_dsa, granite_hybrid, k_exaone, ling_flash, nemotron_h, ouro,
-        solar_open2)
+        deepseek_v2, dots3, glm_dsa, granite_hybrid, k_exaone, ling_flash, longcat_flash,
+        nemotron_h, ouro, solar_open2)
 
     def dots3_step(cfg, params, cache, token, position):
         row, cache, _, loads, _, _ = dots3.decode_step(cfg, params, dict(cache), token, position)
@@ -1593,6 +1719,7 @@ def _step_of(name):
         "glm-5.2": (glm_dsa, glm_step),
         "granite-4.0-h-micro": (granite_hybrid, granite_step),
         "dots3-note-prev": (dots3, dots3_step),
+        "longcat-flash-chat": (longcat_flash, with_loads(longcat_flash)),
     }[name]
 
 
@@ -1649,7 +1776,8 @@ def test_decode_is_the_models_own_step_walked_with_the_same_folded_keys(name, st
         assert np.asarray(decode.counts).tolist()[:3] == [steps, 0, 0]
         loads = loads[:-1]
     np.testing.assert_array_equal(loads, tally)
-    assert loads.sum() <= steps * loads.shape[0] * cfg.num_experts_per_tok
+    assert loads.sum() <= steps * loads.shape[0] * (
+        cfg.moe_topk if name == "longcat-flash-chat" else cfg.num_experts_per_tok)
 
 
 @pytest.mark.parametrize("collect", [False, True])

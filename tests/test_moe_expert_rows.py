@@ -7,6 +7,8 @@ kept here as the reference), the top rung and a ladder of one rung are
 that computation itself, and the rung the host reports is the rung the
 device took."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -127,6 +129,10 @@ def rows_run(monkeypatch):
     (256, 1, 64, (256,)),                                # a tile or less: one rung
     (257, 1, 64, (256, 257)),
     (384, 4, 16, (256, 384)),                            # tiny-deepseek-v2's 128-token prompt
+    # LongCat-Flash's block of 1,024 tokens, 12 pairs each, 8 held of a router 768 wide (512
+    # experts and 256 identities): the even share is 128 rows, under the lowest rung
+    (1024 * 12, 8, 768, (256, 512, 768, 1536, 3072, 6144, 12288)),
+    (12, 8, 768, (12,)),                                 # its decode step
 ])
 def test_the_ladder_of_a_shape(pairs, held, experts, want):
     assert moe.row_ladder(pairs, held, experts) == want
@@ -300,3 +306,164 @@ def test_the_lowest_rung_forms_no_array_of_more_rows_than_it_has():
     assert any(
         var.aval.shape[0] == TOKENS * K for eqn in top.eqns for var in eqn.outvars
         if len(var.aval.shape) >= 2)
+
+
+# --- a router wider than its experts: identities (PR 63) -----------------------
+
+
+def softmax_route(logits, k=K, scale=6.0):
+    """LongCat-Flash's rule: softmax over the whole width, the k largest,
+    the weights the chosen scores times `scale`, not renormalised."""
+    scores = jax.nn.softmax(logits, axis=-1)
+    weights, ids = jax.lax.top_k(scores, k)
+    return ids, weights * scale
+
+
+@pytest.mark.parametrize("count", [16, TOKENS])
+def test_an_identity_adds_its_weight_times_the_input_and_is_never_a_row(count):
+    """A router of 16 over 12 experts (ids 12-15 identities), 2 held: the
+    layer is the layer without the argument plus, a token, the chosen
+    identities' weights' sum times the token; the loads and the rung are
+    those of the layer without it."""
+    p, x = params(), tokens(count)
+    p = {name: value for name, value in p.items() if name != "shared"}
+    plain, ids, sizes = moe.expert_layer(p, x, HELD, softmax_route)
+    got, ids_2, sizes_2 = moe.expert_layer(p, x, HELD, softmax_route, identities=12)
+    np.testing.assert_array_equal(ids, ids_2)
+    np.testing.assert_array_equal(sizes, sizes_2)
+    _, weights = softmax_route(x @ p["w_g"])
+    kept = jnp.sum(jnp.where(ids >= 12, weights, 0.0), axis=-1, keepdims=True)
+    assert float(jnp.max(kept)) > 0 and int(jnp.sum(ids >= 12)) > 0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(plain + kept * x), rtol=1e-5, atol=1e-6)
+    # an identity is no absent chip's expert either: with every expert held it is still added
+    everyone = params(held=range(12), experts=EXPERTS)
+    everyone.pop("shared")
+    whole_layer, ids_3, _ = moe.expert_layer(everyone, x, range(12), softmax_route, identities=12)
+    without, _, _ = moe.expert_layer(everyone, x, range(12), softmax_route)
+    _, weights = softmax_route(x @ everyone["w_g"])
+    kept = jnp.sum(jnp.where(ids_3 >= 12, weights, 0.0), axis=-1, keepdims=True)
+    np.testing.assert_allclose(
+        np.asarray(whole_layer), np.asarray(without + kept * x), rtol=1e-5, atol=1e-6)
+
+
+def test_report_loads_counts_the_pairs_that_chose_an_identity_a_phase():
+    loads = np.array([[3, 1], [0, 2]])
+    said = moe.report_loads(12, 768, 64, 4, loads, loads // 2, "xla", (500, 31))
+    assert (said["prefill_zero_pairs"], said["decode_zero_pairs"]) == (500, 31)
+    assert said["prefill_routed_pairs"] == 64 * 12 * 2
+    assert "prefill_zero_pairs" not in moe.report_loads(12, 768, 64, 4, loads, loads // 2, "xla")
+
+
+def as_it_was(p: dict, x: jax.Array, held: range, route,
+              limit: float = 0.0, shared_limit: float = 0.0, index=None):
+    """`moe.expert_layer` as it stood before it learnt of identities (PR
+    62), line for line."""
+    with jax.named_scope("router"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), p["w_g"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        ids, weights = route(logits)
+    with jax.named_scope("experts"):
+        tokens, k = ids.shape
+        local = ids.reshape(-1) - held.start
+        here = (local >= 0) & (local < len(held))
+        # sort the token-expert pairs by held expert, the pairs of absent
+        # experts last: each held expert's rows are then one segment, and
+        # the held pairs are the first `sizes.sum()` rows
+        slot = jnp.where(here, local, len(held))
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.zeros((len(held),), jnp.int32).at[slot].add(1, mode="drop")
+
+        # a decode step's few rows: each chosen held expert's weights
+        # read once where they lie (`ops/moe.expert_matvec`)
+        experts = p["experts"]
+        w_down = experts["w_down"]
+        how = moe.decode_route(
+            tokens * k, w_down.shape[-1], w_down.shape[-2], w_down.dtype, moe.gated(experts))
+        grouped = moe.expert_matvec if how == "kernel" else moe.grouped_xla
+
+        def over(rows_n: int):
+            """The held experts' part [T, hidden] float32 from the first
+            `rows_n` sorted pairs, which has to cover every held one."""
+            top = order[:rows_n]
+            token = top // k
+            rows = x[token]
+            if moe.gated(experts):
+                gate, up = jnp.split(
+                    grouped(rows, experts["w_gate_up"], sizes, index), 2, axis=-1)
+                middle = moe.clamped_silu_product(gate, up, limit)
+            else:
+                middle = jnp.square(jax.nn.relu(
+                    grouped(rows, experts["w_up"], sizes, index, out_major=True)))
+            out = grouped(middle, w_down, sizes, index)
+            # rows past the last segment are absent experts' pairs: weight 0
+            out = jnp.where(here[top][:, None], out, 0).astype(jnp.float32)
+            out = out * weights.reshape(-1)[top][:, None]
+            if rows_n == tokens * k:
+                # every pair: back to the pairs' own order, then the sum
+                # over a token's experts
+                return out[jnp.argsort(order)].reshape(tokens, k, -1).sum(axis=1)
+            # a prefix: each row is added to its token's, a block of
+            # columns at a time (4,608 float32 rows of 5,120 cost a v5e
+            # 4.2 ms whole and 0.7 ms in four blocks: PERF.md §6, PR 40)
+            edges = list(range(moe.SCATTER_LANES, out.shape[1], moe.SCATTER_LANES))
+            return jnp.concatenate([
+                jnp.zeros((tokens, part.shape[1]), jnp.float32).at[token].add(part)
+                for part in jnp.split(out, edges, axis=1)
+            ], axis=1)
+
+        ladder = moe.row_ladder(tokens * k, len(held), p["w_g"].shape[1])
+        if len(ladder) == 1:
+            routed = over(ladder[0])
+        else:
+            routed = jax.lax.switch(
+                moe.rung_index(ladder, sizes.sum()), [partial(over, rows_n) for rows_n in ladder])
+    if "shared" not in p:
+        return routed.astype(x.dtype), ids, sizes
+    with jax.named_scope("shared"):
+        shared = (moe.swiglu(x, p["shared"], shared_limit) if moe.gated(p["shared"])
+                  else moe.relu2_mlp(x, p["shared"]))
+    return shared + routed.astype(x.dtype), ids, sizes
+
+
+OTHERS = ["deepseek_v2", "solar_open2", "k_exaone", "ling_flash", "nemotron_h", "glm_dsa", "sdar",
+          "dots3"]
+TINY = {"deepseek_v2": "tiny-deepseek-v2", "solar_open2": "tiny-solar-open2",
+        "k_exaone": "tiny-k-exaone", "ling_flash": "tiny-ling-flash",
+        "nemotron_h": "tiny-nemotron3-nano", "glm_dsa": "tiny-glm-dsa", "sdar": "tiny-sdar",
+        "dots3": "tiny-dots3"}
+
+
+@pytest.mark.parametrize("name", OTHERS)
+def test_the_other_expert_models_programs_are_what_they_were_before_the_argument(
+        name, monkeypatch):
+    """The eight models that never hand `identities`: their prefill and
+    their decode trace to the same jaxprs, text for text, with the
+    layer as it stands and with the layer as it stood (`as_it_was`, put
+    in the module's own name for the second trace)."""
+    import importlib
+
+    from comfyui_distributed_tpu.models.registry import create_model
+
+    module = importlib.import_module(f"comfyui_distributed_tpu.models.{name}")
+    lm = create_model(TINY[name])
+    cfg = lm.cfg
+    weights = jax.eval_shape(lambda: lm.init(jax.random.key(0)))
+    ids = jax.ShapeDtypeStruct((40,), jnp.int32)
+
+    def programs():
+        prefill = jax.make_jaxpr(
+            lambda w, i: module.prefill.__wrapped__(cfg, w, i, cache_len=48))(weights, ids)
+        made = jax.eval_shape(
+            lambda w, i: module.prefill.__wrapped__(cfg, w, i, cache_len=48), weights, ids)
+        decode = jax.make_jaxpr(
+            lambda w, cache, logits: module.decode.__wrapped__(
+                cfg, w, cache, logits, jnp.int32(40), jax.random.key(1), jnp.float32(1.0),
+                steps=4))(weights, made.cache, made.logits)
+        return str(prefill), str(decode)
+
+    now = programs()
+    monkeypatch.setattr(module, "expert_layer", as_it_was)
+    assert programs() == now
+    assert "zero_experts" not in now[0] + now[1]
