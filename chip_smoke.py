@@ -72,7 +72,15 @@ Drives the main path once, through the entry points an operator uses:
                a step's routing drawn anew inside one jitted loop, both
                against a float32 reference; it prints us a step, GB/s
                of the chosen experts' weights, and which lowering the
-               compiler gave `ragged_dot` at that row count.
+               compiler gave `ragged_dot` at that row count. Then a
+               row a model and rung for the nine models' prefills
+               (`PREFILL_EXPERT_SHAPES`, the ladder's lowest two rungs,
+               of which the layer gives the kernel the lowest):
+               the row gather, the two products and the weighted way
+               back on `ragged_dot` and on the kernel of
+               `ops/grouped_matmul`, ms a call of each, their ratio and
+               the largest difference between them: what
+               `grouped_matmul.route`'s rule rests on.
     init       only when named (`--legs init`; it builds every component
                cold): one child that holds the chip builds SD1.5's, SDXL's
                and FLUX's components as a start does, each with its one
@@ -1832,7 +1840,148 @@ def experts_child(rehearsal: bool) -> int:
         row["ref_max_abs"] = round(scale, 3)
         failed += not row["ok"]
         print(json.dumps(row), flush=True)
+    failed += prefill_expert_rows(rehearsal)
     return 1 if failed else 0
+
+
+# (label, tokens a call of `moe.expert_layer`, experts a token, held
+# experts, the router's width, hidden, width, whether the expert has a
+# gate, whether its weights are a layer of a stack): a prefill (or a
+# prefill's part or block) of each model with a mixture of experts as its
+# benchmark cell runs it (PR 64). DeepSeek-V2 and SDAR prefill 2,048
+# tokens, Solar, K-EXAONE, Ling and Nemotron 8,192, GLM-5.2 and dots3
+# parts of 8,192, LongCat-Flash blocks of 1,024 under a router of 768
+# outputs; Nemotron's experts have no gate, an up-projection stored out by
+# in, and stand in a stack a scanned run.
+PREFILL_EXPERT_SHAPES = (
+    ("deepseek-v2 prefill", 2048, 6, 40, 160, 5120, 1536, True, False),
+    ("solar-open2 prefill", 8192, 8, 40, 320, 4096, 1280, True, False),
+    ("k-exaone prefill", 8192, 8, 16, 128, 6144, 2048, True, False),
+    ("ling-flash prefill", 8192, 8, 64, 512, 2560, 768, True, False),
+    ("nemotron3-nano prefill", 8192, 6, 8, 128, 2688, 1856, False, True),
+    ("glm-5.2 part", 8192, 8, 16, 256, 6144, 2048, True, False),
+    ("sdar prefill", 2048, 8, 128, 128, 2048, 768, True, False),
+    ("dots3-note-prev part", 8192, 8, 32, 256, 5120, 1536, True, False),
+    ("longcat-flash block", 1024, 12, 8, 768, 6144, 2048, True, False),
+)
+REHEARSAL_PREFILL_EXPERT_SHAPES = (
+    ("toy prefill", 512, 3, 4, 16, 128, 64, True, False),
+    ("toy prefill without a gate, stacked", 512, 3, 4, 16, 128, 48, False, True),
+    ("toy block whose lowest rung is a tile", 256, 3, 4, 16, 128, 64, True, False),
+)
+PREFILL_EXPERT_STEPS = 8
+# what a rehearsal cuts the kernel's tiles to, so that a toy rung has several
+REHEARSAL_GMM_CAPS = (128, 32, 2**19)
+
+
+def prefill_routings(seed: int, steps: int, tokens: int, k: int, held: int, experts: int,
+                     most: int):
+    """`steps` routings of `tokens` tokens, each choosing `k` distinct
+    experts of `experts` evenly, whose pairs on the held experts (the ids
+    below `held`) are at most `most`: ids [steps, tokens, k]."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    kept = []
+    while len(kept) < steps:
+        ids = np.argsort(rng.random((tokens, experts)), axis=1)[:, :k]
+        if np.count_nonzero(ids < held) <= most:
+            kept.append(ids)
+    return np.stack(kept)
+
+
+def prefill_expert_rows(rehearsal: bool) -> int:
+    """A row a model and rung (the ladder's lowest two; the layer gives
+    the kernel the lowest): the rung's row gather, two grouped products and weighted way
+    back as `moe.expert_layer` runs them, a routing an iteration of one
+    jitted loop, on `jax.lax.ragged_dot` and on `ops/grouped_matmul`:
+    ms a call of each, their ratio, and the largest difference between
+    their results over the largest result. `route` is what the layer's
+    lowest rung takes on this backend (`moe.prefill_route`). Returns
+    the rows that failed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from comfyui_distributed_tpu.models import moe
+    from comfyui_distributed_tpu.ops import grouped_matmul as gmm
+    from comfyui_distributed_tpu.ops.expert_matvec import grouped_xla
+
+    def calls_of(grouped, k, gated, stacked, tokens):
+        def loop(x, w_first, w_down, top, sizes, scale):
+            index = jnp.int32(1) if stacked else None
+
+            def body(_, step):
+                top_i, sizes_i, scale_i = step
+                token = top_i // k
+                rows = x[token]
+                if gated:
+                    gate, up = jnp.split(grouped(rows, w_first, sizes_i, index), 2, axis=-1)
+                    middle = jax.nn.silu(gate) * up
+                else:
+                    middle = jnp.square(jax.nn.relu(
+                        grouped(rows, w_first, sizes_i, index, out_major=True)))
+                out = grouped(middle, w_down, sizes_i, index)
+                # a row past the held pairs has scale 0, and is picked out, not multiplied
+                out = jnp.where(scale_i[:, None] > 0, out, 0).astype(jnp.float32) * scale_i[:, None]
+                edges = list(range(moe.SCATTER_LANES, out.shape[1], moe.SCATTER_LANES))
+                return None, jnp.concatenate([
+                    jnp.zeros((tokens, part.shape[1]), jnp.float32).at[token].add(part)
+                    for part in jnp.split(out, edges, axis=1)], axis=1)
+            return jax.lax.scan(body, None, (top, sizes, scale))[1]
+        return jax.jit(loop)
+
+    failed = 0
+    steps = 2 if rehearsal else PREFILL_EXPERT_STEPS
+    if rehearsal:  # this child runs the leg and nothing else: small tiles for the interpreter
+        gmm.TILE_ROWS, gmm.BLOCK_ROWS, gmm.VMEM_BLOCK_BUDGET = REHEARSAL_GMM_CAPS
+    kernel = functools.partial(gmm.grouped_matmul, interpret=rehearsal)
+    for label, tokens, k, held, experts, hidden, width, gated, stacked in (
+            REHEARSAL_PREFILL_EXPERT_SHAPES if rehearsal else PREFILL_EXPERT_SHAPES):
+        keys = jax.random.split(jax.random.key(hidden + tokens), 3)
+        x = jax.random.normal(keys[0], (tokens, hidden), jnp.bfloat16)
+        lead = (2,) if stacked else ()
+        first = (*lead, held, hidden, 2 * width) if gated else (*lead, held, width, hidden)
+        w_first = jax.jit(lambda key: hidden ** -0.5 * jax.random.normal(
+            key, first, jnp.bfloat16))(keys[1])
+        w_down = jax.jit(lambda key: width ** -0.5 * jax.random.normal(
+            key, (*lead, held, width, hidden), jnp.bfloat16))(keys[2])
+        ladder = moe.row_ladder(tokens * k, held, experts)
+        for rung in ladder[:2]:
+            ids = prefill_routings(tokens + rung, steps, tokens, k, held, experts, rung)
+            slot = np.where(ids < held, ids, held).reshape(steps, -1)
+            order = np.argsort(slot, axis=1, kind="stable")
+            top = order[:, :rung]
+            sizes = np.stack([np.bincount(s, minlength=held + 1)[:held] for s in slot])
+            scale = np.take_along_axis(slot, top, axis=1) < held
+            scale = scale * np.random.default_rng(rung).uniform(0.05, 0.2, scale.shape)
+            row = {
+                "shape": f"{label}, rung {rung}", "tokens": tokens, "rows": rung, "held": held,
+                "hidden": hidden, "width": width, "gated": gated, "stacked": stacked,
+                "held_pairs_a_call": round(float(sizes.sum(axis=1).mean()), 1),
+                "route": moe.prefill_route(
+                    tokens, k, held, experts, hidden, width, jnp.bfloat16, with_gate=gated),
+            }
+            operands = (x, w_first, w_down, jnp.asarray(top, jnp.int32),
+                        jnp.asarray(sizes, jnp.int32), jnp.asarray(scale, jnp.float32))
+            outs = {}
+            for name, grouped in (("xla", grouped_xla), ("kernel", kernel)):
+                outs[name], first_s, ms = timed(calls_of(grouped, k, gated, stacked, tokens), *operands)
+                row[name] = {"first_call_s": round(first_s, 2), "ms_a_call": round(ms / steps, 4)}
+                if rung <= gmm.MAX_ROWS:  # a tile or fewer rows (LongCat-Flash's lowest): no plan
+                    row.update(kernel=None, ok=True)
+                    break
+            if row.get("ok"):
+                print(json.dumps(row), flush=True)
+                continue
+            row["xla_over_kernel"] = round(row["xla"]["ms_a_call"] / row["kernel"]["ms_a_call"], 3)
+            want = np.asarray(outs["xla"], np.float32)
+            diff = float(np.abs(np.asarray(outs["kernel"], np.float32) - want).max())
+            row["max_rel_diff"] = round(diff / max(float(np.abs(want).max()), 1e-30), 6)
+            row["ok"] = bool(np.isfinite(diff)) and row["max_rel_diff"] <= ATTENTION_TOLERANCE
+            failed += not row["ok"]
+            print(json.dumps(row), flush=True)
+    return failed
 
 
 # --- entry -----------------------------------------------------------------

@@ -57,7 +57,7 @@ from .lm_common import (
     rms_norm,
     swiglu,
 )
-from .moe import decode_route, expert_layer, report_loads
+from .moe import decode_route, expert_layer, prefill_route, report_loads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,7 +311,7 @@ class Prefill(NamedTuple):
     logits: jax.Array   # [vocab_held] float32, at the prompt's last position
     cache: jax.Array    # [layers, cache_len, kv_lora + rope]
     loads: jax.Array    # [moe layers, held] pairs on each held expert
-    chosen: jax.Array | None  # [moe layers, T, k] experts chosen; under `collect`
+    chosen: jax.Array | None  # [moe layers, T, k] experts chosen (the parity check reads it)
 
 
 class Decode(NamedTuple):
@@ -326,9 +326,9 @@ def prefill(cfg: DeepSeekV2Config, params, ids, *, cache_len: int, collect: bool
     """The whole prompt `ids` [T] at once. Returns the logits at its last
     position [vocab_held] (float32), the latent cache [layers, cache_len,
     kv_lora + rope] with the first T positions written, the pairs that
-    fell on each held expert [moe layers, held] and, under `collect`
-    (the parity check's; a served request needs none of it), the experts
-    chosen [moe layers, T, k]."""
+    fell on each held expert [moe layers, held] and the experts chosen
+    [moe layers, T, k], whatever `collect` (the parity check's): its
+    prefill is the served program itself (PERF.md §6, PR 64)."""
     tokens = ids.shape[0]
     rope = rope_tables(cfg, jnp.arange(tokens))
     h = params["embed"][ids]
@@ -342,7 +342,7 @@ def prefill(cfg: DeepSeekV2Config, params, ids, *, cache_len: int, collect: bool
             loads.append(sizes)
     return Prefill(
         head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
-        jnp.stack(chosen) if collect else None,
+        jnp.stack(chosen),  # served too: one program, whatever `collect`
     )
 
 
@@ -430,5 +430,8 @@ class DeepSeekV2(LanguageModel):
                 prompt_tokens, new_tokens, prefill_loads, decode_loads,
                 decode_route(
                     cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size,
-                    self.dtype)),
+                    self.dtype),
+                prefill_expert_route=prefill_route(
+                    prompt_tokens, cfg.num_experts_per_tok, len(cfg.held_experts),
+                    cfg.n_routed_experts, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype)),
         }

@@ -86,7 +86,7 @@ from .lm_common import (
     swiglu,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, prefill_route, report_loads, sigmoid_route
 
 # A ring's length is a whole number of these (`k_exaone.RING_MULTIPLE`:
 # the sublane tile of a 32-bit array).
@@ -697,9 +697,12 @@ class Dots3(LanguageModel):
                 decode_loads,
                 decode_route(
                     cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size,
-                    self.dtype))
+                    self.dtype),
+                prefill_expert_route=prefill_route(
+                    length, cfg.num_experts_per_tok, len(cfg.held_experts), cfg.n_routed_experts,
+                    cfg.hidden_size, cfg.moe_intermediate_size, self.dtype))
             for length, loads in zip(lengths, np.asarray(prefill_loads))]
-        routing = dict(by_part[-1])
+        routing = {**by_part[-1], "prefill_expert_route": by_part[0]["prefill_expert_route"]}
         for name in ("prefill_routed_pairs", "prefill_routed_pairs_held", "prefill_expert_rows"):
             routing[name] = sum(part[name] for part in by_part)
         routing["prefill_expert_load_max"] = int(np.max(np.sum(prefill_loads, axis=0)))

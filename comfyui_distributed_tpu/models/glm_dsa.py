@@ -76,7 +76,7 @@ from .lm_common import (
     swiglu,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, prefill_route, report_loads, sigmoid_route
 
 
 @dataclasses.dataclass(frozen=True)
@@ -603,9 +603,12 @@ class GlmDsa(LanguageModel):
                 # a step's positions in a main layer; the module's one takes the same route
                 decode_route(
                     width * cfg.num_experts_per_tok, cfg.hidden_size,
-                    cfg.moe_intermediate_size, self.dtype))
+                    cfg.moe_intermediate_size, self.dtype),
+                prefill_expert_route=prefill_route(
+                    length, cfg.num_experts_per_tok, len(cfg.held_experts), cfg.n_routed_experts,
+                    cfg.hidden_size, cfg.moe_intermediate_size, self.dtype))
             for length, loads in zip(lengths, np.asarray(prefill_loads))]
-        routing = dict(by_part[-1])
+        routing = {**by_part[-1], "prefill_expert_route": by_part[0]["prefill_expert_route"]}
         for name in ("prefill_routed_pairs", "prefill_routed_pairs_held", "prefill_expert_rows"):
             routing[name] = sum(part[name] for part in by_part)
         routing["prefill_expert_load_max"] = int(np.max(np.sum(prefill_loads, axis=0)))

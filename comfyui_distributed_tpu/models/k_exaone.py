@@ -78,7 +78,7 @@ from .lm_common import (
     swiglu,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, prefill_route, report_loads, sigmoid_route
 
 # A ring's length is a whole number of these (the sublane tile of a
 # 32-bit layout; a 16-bit one pads to twice it by itself).
@@ -336,7 +336,7 @@ class Prefill(NamedTuple):
     logits: jax.Array   # [vocab_held] float32, at the prompt's last position
     cache: dict         # `state_shapes`: the request's state after the prompt
     loads: jax.Array    # [sparse layers, held] pairs on each held expert
-    chosen: jax.Array | None  # [sparse layers, T, k] experts chosen; under `collect`
+    chosen: jax.Array | None  # [sparse layers, T, k] experts chosen (the parity check reads it)
 
 
 class Decode(NamedTuple):
@@ -353,8 +353,8 @@ def prefill(cfg: KExaoneConfig, params, ids, *, cache_len: int, collect: bool = 
     position, the request's state (allocated here, once: each ring with
     the prompt's last positions, each growing slot's first T positions
     written, `h` the last position's residual stream), the pairs that
-    fell on each held expert and, under `collect` (the parity check's),
-    the experts chosen.
+    fell on each held expert and the experts chosen, whatever `collect`
+    (the parity check's): one program (`deepseek_v2.prefill`).
 
     Of the MTP module the prompt needs the keys and values only (nothing
     reads its output before the decode's first draft), so that is what
@@ -390,7 +390,7 @@ def prefill(cfg: KExaoneConfig, params, ids, *, cache_len: int, collect: bool = 
     cache["h"] = h[-1]
     return Prefill(
         head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
-        jnp.stack(chosen) if collect else None,
+        jnp.stack(chosen),  # served too: one program, whatever `collect`
     )
 
 
@@ -533,7 +533,10 @@ class KExaone(LanguageModel):
                 # a step's positions in a main layer; the module's one takes the same route
                 decode_route(
                     width * cfg.num_experts_per_tok, cfg.hidden_size,
-                    cfg.moe_intermediate_size, self.dtype)),
+                    cfg.moe_intermediate_size, self.dtype),
+                prefill_expert_route=prefill_route(
+                    prompt_tokens, cfg.num_experts_per_tok, len(cfg.held_experts),
+                    cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype)),
             "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,
             **drafting,
         }

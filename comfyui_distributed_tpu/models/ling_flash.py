@@ -99,7 +99,7 @@ from .lm_common import (
     swiglu,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, prefill_route, report_loads, sigmoid_route
 
 
 @dataclasses.dataclass(frozen=True)
@@ -480,7 +480,7 @@ class Prefill(NamedTuple):
     logits: jax.Array   # [vocab_held] float32, at the prompt's last position
     cache: dict         # `state_shapes`: the request's state after the prompt
     loads: jax.Array    # [sparse layers, held] pairs on each held expert
-    chosen: jax.Array | None  # [sparse layers, T, k] experts chosen; under `collect`
+    chosen: jax.Array | None  # [sparse layers, T, k] experts chosen (the parity check reads it)
 
 
 class Decode(NamedTuple):
@@ -497,8 +497,8 @@ def prefill(cfg: LingFlashConfig, params, ids, *, cache_len: int, collect: bool 
     position, the request's state (allocated here, once: each MLA slot's
     first T positions written, each KDA layer's state and tail as the
     last token left them in slot 0, which stands, `h` the last position's
-    residual stream), the pairs that fell on each held expert and, under
-    `collect` (the parity check's), the experts chosen.
+    residual stream), the pairs that fell on each held expert and the experts
+    chosen, whatever `collect`: one program (`deepseek_v2.prefill`).
 
     Of the MTP module the prompt needs the latents only (nothing reads
     its output before the decode's first draft), so that is what runs:
@@ -547,7 +547,7 @@ def prefill(cfg: LingFlashConfig, params, ids, *, cache_len: int, collect: bool 
     cache["h"] = h[-1]
     return Prefill(
         head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
-        jnp.stack(chosen) if collect else None,
+        jnp.stack(chosen),  # served too: one program, whatever `collect`
     )
 
 
@@ -702,7 +702,10 @@ class LingFlash(LanguageModel):
                 # a step's positions in a main layer; the module's one takes the same route
                 decode_route(
                     width * cfg.num_experts_per_tok, cfg.hidden_size,
-                    cfg.moe_intermediate_size, self.dtype)),
+                    cfg.moe_intermediate_size, self.dtype),
+                prefill_expert_route=prefill_route(
+                    prompt_tokens, cfg.num_experts_per_tok, len(cfg.held_experts),
+                    cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype)),
             "prefill_layer_passes": prompt_tokens * cfg.num_hidden_layers,
             **drafting,
         }

@@ -70,7 +70,7 @@ from .lm_common import (
     swiglu,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, row_ladder, rung_index
+from .moe import decode_route, expert_layer, prefill_route, report_loads, row_ladder, rung_index
 
 # `LongcatFlashRMSNorm`'s default, which the two norms inside an attention
 # keep (modeling_longcat_flash.py:312, 321); the layer norms take `rms_norm_eps`.
@@ -495,7 +495,10 @@ class LongcatFlash(LanguageModel):
         routing = report_loads(
             k, cfg.router_width, prompt_tokens, new_tokens, np.sum(prefill_loads, axis=(0, 2)),
             decode_loads,
-            decode_route(k, cfg.hidden_size, cfg.expert_ffn_hidden_size, self.dtype), zero)
+            decode_route(k, cfg.hidden_size, cfg.expert_ffn_hidden_size, self.dtype), zero,
+            prefill_expert_route=prefill_route(
+                min(prompt_tokens, cfg.expert_block), k, len(cfg.held_experts), cfg.router_width,
+                cfg.hidden_size, cfg.expert_ffn_hidden_size, self.dtype))
         whole, left = parts_of(prompt_tokens, cfg.prefill_part)
         rows = 0
         lengths = [cfg.prefill_part] * whole + [left] * bool(left)

@@ -25,7 +25,7 @@ so no routing, however skewed, drops a pair. Where the pairs are a tile
 or less (a decode step: a ladder of one rung) the two grouped products
 run, on a TPU, in the Pallas kernel of `ops/expert_matvec.py`, which
 reads each chosen held expert's weights once, out of the stacked array
-(`decode_route`); the prefill's stay `ragged_dot`.
+(`decode_route`); a prefill's lowest rung: `ops/grouped_matmul.py`.
 
 Parameters: `w_g` [hidden, router width], `experts` and, where there is
 one, `shared` in the expert's form, which the tree says (`gated`): a SwiGLU,
@@ -48,9 +48,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.expert_matvec import expert_matvec, expert_matvec_route, grouped_xla
+from ..ops.expert_matvec import expert_matvec, expert_matvec_route, grouped_xla  # noqa: F401
+from ..ops.grouped_matmul import grouped_rows, rung_route
 from .lm_common import clamped_silu_product, relu2_mlp, swiglu
-
 
 # A rung of the ladder below the top is a whole number of these rows.
 ROW_TILE = 256
@@ -159,13 +159,13 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable, limit: flo
         order = jnp.argsort(slot, stable=True)
         sizes = jnp.zeros((len(held),), jnp.int32).at[slot].add(1, mode="drop")
 
-        # a decode step's few rows: each chosen held expert's weights
-        # read once where they lie (`ops/expert_matvec`)
-        experts = p["experts"]
-        w_down = experts["w_down"]
+        # a decode step's few rows: each chosen held expert's weights read once
+        # where they lie (`ops/expert_matvec`); a prefill's: `ops/grouped_matmul`
+        experts, w_down = p["experts"], p["experts"]["w_down"]
+        ladder = row_ladder(tokens * k, len(held), p["w_g"].shape[1])
         how = decode_route(
             tokens * k, w_down.shape[-1], w_down.shape[-2], w_down.dtype, gated(experts))
-        grouped = expert_matvec if how == "kernel" else grouped_xla
+        grouped = expert_matvec if how == "kernel" else grouped_rows(ladder[0])
 
         def over(rows_n: int):
             """The held experts' part [T, hidden] float32 from the first
@@ -197,7 +197,7 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable, limit: flo
                 for part in jnp.split(out, edges, axis=1)
             ], axis=1)
 
-        ladder = row_ladder(tokens * k, len(held), p["w_g"].shape[1])
+        # the device picks the rung from the routing's own count
         if len(ladder) == 1:
             routed = over(ladder[0])
         else:
@@ -216,9 +216,27 @@ def expert_layer(p: dict, x: jax.Array, held: range, route: Callable, limit: flo
     return shared + routed.astype(x.dtype), ids, sizes
 
 
+def prefill_route(tokens: int, k: int, held: int, experts: int, hidden: int, width: int, dtype,
+                  with_gate: bool = True) -> str:
+    """How a layer over `tokens` tokens (a prefill, a part, a block: more
+    than a tile of pairs) runs the two grouped products of its ladder's
+    lowest rung, which is where an evenly routed request lands or just over:
+    "kernel" where `ops/grouped_matmul.rung_route` takes both shapes of
+    the lowest rung on this backend, else "xla" (`jax.lax.ragged_dot`,
+    which the rungs above keep whatever this says). `experts` is the
+    router's width. The layer's `grouped_rows` asks the same function
+    while it is traced, a model's `report` afterwards."""
+    rows = row_ladder(tokens * k, held, experts)[0]
+    up = (rung_route(rows, rows, hidden, 2 * width, held, dtype) if with_gate
+          else rung_route(rows, rows, hidden, width, held, dtype, out_major=True))
+    return "kernel" if {up, rung_route(rows, rows, width, hidden, held, dtype)} == {
+        "kernel"} else "xla"
+
+
 def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
                  prefill_loads, decode_loads, decode_expert_route: str,
-                 zero_pairs: tuple[int, int] | None = None) -> dict:
+                 zero_pairs: tuple[int, int] | None = None, *,
+                 prefill_expert_route: str = "xla") -> dict:
     """`node.TextGenerate`'s attributes of the routing, per phase: the
     token-expert pairs the router made (`k` a token and expert layer),
     those that fell on held experts (`loads` [expert layers, held], as
@@ -227,9 +245,11 @@ def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
     its load as the device read it; a decode step's `k` pairs are under
     a tile, a ladder of one rung, so its rows are its pairs, and
     `decode_expert_route` (the model's `decode_route` of a step's pairs)
-    says what multiplied them. `experts` is the router's width. A model
-    with identity experts hands the pairs that chose one, (the prefill's,
-    the decode's), as it read them back: `<phase>_zero_pairs`."""
+    says what multiplied them, `prefill_expert_route` (its `prefill_route`
+    of a call's tokens) the prefill's lowest rung. `experts` is the
+    router's width. A model with identity experts hands the pairs that
+    chose one, (the prefill's, the decode's), as it read them back:
+    `<phase>_zero_pairs`."""
     layers, held = np.shape(prefill_loads)
     attrs = {}
     for phase, tokens, loads in (
@@ -245,4 +265,5 @@ def report_loads(k: int, experts: int, prompt_tokens: int, new_tokens: int,
         ladder[rung_index(ladder, int(n))] for n in np.sum(prefill_loads, axis=1))
     attrs["decode_expert_rows"] = attrs["decode_routed_pairs"]
     attrs["decode_expert_route"] = decode_expert_route
+    attrs["prefill_expert_route"] = prefill_expert_route
     return attrs

@@ -85,7 +85,7 @@ from .lm_common import (
     rms_norm,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, prefill_route, report_loads, sigmoid_route
 
 PUBLISHED_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
@@ -467,7 +467,7 @@ class Prefill(NamedTuple):
     logits: jax.Array   # [vocab_held] float32, at the prompt's last position
     cache: dict         # `state_shapes`: the request's state after the prompt
     loads: jax.Array    # [E blocks, held] pairs on each held expert
-    chosen: jax.Array | None  # [E blocks, T, k] experts chosen; under `collect`
+    chosen: jax.Array | None  # [E blocks, T, k] experts chosen (the parity check reads it)
 
 
 class Decode(NamedTuple):
@@ -483,12 +483,12 @@ def prefill(cfg: NemotronHConfig, params, ids, *, cache_len: int, collect: bool 
     """The whole prompt `ids` [T] at once. Returns the logits at its last
     position, the request's state (allocated here, once: the first T
     positions of every `kv` written, `ssm` and `conv` as the last token
-    left them), the pairs that fell on each held expert and, under
-    `collect` (the parity check's), the experts chosen."""
+    left them), the pairs that fell on each held expert and the experts
+    chosen, whatever `collect`: one program (`deepseek_v2.prefill`)."""
     h = params["embed"][ids]
     cache = zeros(state_shapes(cfg, cache_len, h.dtype))
     h, cache, chosen, loads = walk(cfg, params["blocks"], h, cache, partial(attn_whole, cfg))
-    return Prefill(head(cfg, params, h[-1:])[0], cache, loads, chosen if collect else None)
+    return Prefill(head(cfg, params, h[-1:])[0], cache, loads, chosen)  # whatever `collect`
 
 
 def decode_step(cfg, params, cache, token, position):
@@ -563,6 +563,10 @@ class NemotronH(LanguageModel):
                 prompt_tokens, new_tokens, prefill_loads, decode_loads,
                 decode_route(
                     cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size,
-                    self.dtype, with_gate=False)),
+                    self.dtype, with_gate=False),
+                prefill_expert_route=prefill_route(
+                    prompt_tokens, cfg.num_experts_per_tok, len(cfg.held_experts),
+                    cfg.n_routed_experts, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype,
+                    with_gate=False)),
         }
         return {**attrs, "decode_experts_read": attrs["decode_routed_pairs_held"]}

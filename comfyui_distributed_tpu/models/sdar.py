@@ -68,7 +68,7 @@ from .lm_common import (
     rope_tables,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads
+from .moe import decode_route, expert_layer, prefill_route, report_loads
 
 # Under `collect` the denoising passes' float32 logits are kept for about
 # this many blocks, evenly spread (every eighth of the cell's 128: 160 MB
@@ -254,7 +254,7 @@ class Prefill(NamedTuple):
     logits: jax.Array   # [vocab] float32, at the last prefilled position (zeros: none)
     cache: dict         # `state_shapes`: the request's state after the prompt's whole blocks
     loads: jax.Array    # [layers, experts] pairs on each expert
-    chosen: jax.Array | None  # [layers, P, k] experts chosen; under `collect`
+    chosen: jax.Array | None  # [layers, P, k] experts chosen (the parity check reads it)
 
 
 class Decode(NamedTuple):
@@ -271,9 +271,9 @@ def prefill(cfg: SdarConfig, params, ids, *, cache_len: int, collect: bool = Fal
     under the block mask. Returns the logits at position P - 1 (of token
     P - 1 itself; the decode does not read them), the request's state
     (allocated here, once: each layer's first P entries written, the
-    left-over ids pending), the pairs on each expert and, under
-    `collect`, the experts chosen. A prompt shorter than one block runs
-    no layer."""
+    left-over ids pending), the pairs on each expert and the experts
+    chosen, whatever `collect`: one program (`deepseek_v2.prefill`). A
+    prompt shorter than one block runs no layer."""
     whole = ids.shape[0] - ids.shape[0] % cfg.block_length
     cache = zeros(state_shapes(cfg, cache_len, params["embed"].dtype))
     cache["pending"] = jnp.zeros_like(cache["pending"]).at[:ids.shape[0] - whole].set(ids[whole:])
@@ -282,7 +282,7 @@ def prefill(cfg: SdarConfig, params, ids, *, cache_len: int, collect: bool = Fal
         return Prefill(
             jnp.zeros((cfg.vocab_size,), jnp.float32), cache,
             jnp.zeros((cfg.num_hidden_layers, experts), jnp.int32),
-            jnp.zeros((cfg.num_hidden_layers, 0, k), jnp.int32) if collect else None)
+            jnp.zeros((cfg.num_hidden_layers, 0, k), jnp.int32))  # whatever `collect`
     h = params["embed"][ids[:whole]]
     kv, chosen, loads = [], [], []
     for layer, (block, held) in enumerate(zip(params["layers"], cache["kv"])):
@@ -294,7 +294,7 @@ def prefill(cfg: SdarConfig, params, ids, *, cache_len: int, collect: bool = Fal
     cache["kv"] = tuple(kv)
     return Prefill(
         head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
-        jnp.stack(chosen) if collect else None)
+        jnp.stack(chosen))  # served too: one program, whatever `collect`
 
 
 def block_pass(cfg, params, cache, tokens, position, close: bool):
@@ -407,7 +407,10 @@ class Sdar(LanguageModel):
             cfg.num_experts_per_tok, cfg.num_experts, whole, new_tokens, prefill_loads,
             decode_loads, decode_route(
                 cfg.block_length * cfg.num_experts_per_tok, cfg.hidden_size,
-                cfg.moe_intermediate_size, self.dtype))
+                cfg.moe_intermediate_size, self.dtype),
+            prefill_expert_route=prefill_route(
+                whole, cfg.num_experts_per_tok, len(cfg.held_experts), cfg.num_experts,
+                cfg.hidden_size, cfg.moe_intermediate_size, self.dtype))
         pairs = bodies * cfg.num_experts_per_tok
         return {
             **self.describe(cache_len), **report,

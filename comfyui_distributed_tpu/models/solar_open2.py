@@ -65,7 +65,7 @@ from .lm_common import (
     rms_norm,
     zeros,
 )
-from .moe import decode_route, expert_layer, report_loads, sigmoid_route
+from .moe import decode_route, expert_layer, prefill_route, report_loads, sigmoid_route
 
 
 @dataclasses.dataclass(frozen=True)
@@ -368,7 +368,7 @@ class Prefill(NamedTuple):
     logits: jax.Array   # [vocab_held] float32, at the prompt's last position
     cache: dict         # `state_shapes`: the request's state after the prompt
     loads: jax.Array    # [layers, held] pairs on each held expert
-    chosen: jax.Array | None  # [layers, T, k] experts chosen; under `collect`
+    chosen: jax.Array | None  # [layers, T, k] experts chosen (the parity check reads it)
 
 
 class Decode(NamedTuple):
@@ -384,8 +384,8 @@ def prefill(cfg: SolarOpen2Config, params, ids, *, cache_len: int, collect: bool
     """The whole prompt `ids` [T] at once. Returns the logits at its last
     position, the request's state (allocated here, once: the first T
     positions of `kv` written, `state` and `conv` as the last token left
-    them), the pairs that fell on each held expert and, under `collect`
-    (the parity check's), the experts chosen."""
+    them), the pairs that fell on each held expert and the experts chosen,
+    whatever `collect`: one program (`deepseek_v2.prefill`)."""
     tokens = ids.shape[0]
     h = params["embed"][ids]
     cache = zeros(state_shapes(cfg, cache_len, h.dtype))
@@ -402,7 +402,7 @@ def prefill(cfg: SolarOpen2Config, params, ids, *, cache_len: int, collect: bool
         loads.append(sizes)
     return Prefill(
         head(cfg, params, h[-1:])[0], cache, jnp.stack(loads),
-        jnp.stack(chosen) if collect else None,
+        jnp.stack(chosen),  # served too: one program, whatever `collect`
     )
 
 
@@ -486,5 +486,8 @@ class SolarOpen2(LanguageModel):
                 prompt_tokens, new_tokens, prefill_loads, decode_loads,
                 decode_route(
                     cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size,
-                    self.dtype)),
+                    self.dtype),
+                prefill_expert_route=prefill_route(
+                    prompt_tokens, cfg.num_experts_per_tok, len(cfg.held_experts),
+                    cfg.n_routed_experts, cfg.hidden_size, cfg.moe_intermediate_size, self.dtype)),
         }

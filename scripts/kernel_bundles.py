@@ -128,6 +128,20 @@ def _ssd_chunk(tokens, heads, width, groups, n, chunk):
     return lower
 
 
+def _grouped_matmul(rows, k, n, groups):
+    """Lower one `ops/grouped_matmul` call: a rung's rows against the
+    held experts' stacked weights [groups, k, n]."""
+    def lower(place):
+        import jax
+        import jax.numpy as jnp
+
+        from comfyui_distributed_tpu.ops import grouped_matmul
+
+        sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=place((groups,)).sharding)
+        return grouped_matmul.grouped_matmul, (place((rows, k)), place((groups, k, n)), sizes)
+    return lower
+
+
 # label -> (kernel's name in the compiled program, lowering). The labels
 # are `chip_smoke.SERVED_SHAPES` / `CAUSAL_SHAPES`', so a bundle count and
 # the chip's time of `chip_smoke.py --legs attention` read side by side.
@@ -153,6 +167,8 @@ CASES = {
     "glm-5.2 dsa select": ("dsa_select", _dsa_select(128, 32768, 2048)),
     "granite-4.0-h-micro mamba-2": ("ssd_chunk", _ssd_chunk(8192, 64, 64, 1, 128, 256)),
     "nemotron3-nano mamba-2": ("ssd_chunk", _ssd_chunk(8192, 64, 64, 8, 128, 128)),
+    "dots3-note-prev part, gate-up": ("grouped_matmul", _grouped_matmul(8192, 5120, 3072, 32)),
+    "dots3-note-prev part, down": ("grouped_matmul", _grouped_matmul(8192, 1536, 5120, 32)),
 }
 DEFAULT = ("sd15 self 64x64", "flux joint 4608", "solar / k-exaone full 8192")
 

@@ -457,3 +457,43 @@ def test_a_steps_routing_is_k_distinct_experts_a_token(smoke):
     # an eighth of the experts held: two of a step's 16 pairs on average
     assert 1.5 < sizes.sum(axis=1).mean() < 2.5
     assert (sizes.sum(axis=1) == 0).any()
+
+
+@pytest.mark.parametrize("label, registry, tokens", [
+    ("deepseek-v2 prefill", "deepseek-v2-ep4-5l", 2048),
+    ("solar-open2 prefill", "solar-open2-ep8-4l", 8192),
+    ("k-exaone prefill", "k-exaone-ep8-5l", 8192),
+    ("ling-flash prefill", "ling-flash-ep8-7l", 8192),
+    ("nemotron3-nano prefill", "nemotron3-nano-ep16-52l", 8192),
+    ("glm-5.2 part", "glm-5.2-ep16-5l", None),
+    ("sdar prefill", "sdar-30b-a3b-pp8-6l", 2048),
+    ("dots3-note-prev part", "dots3-note-prev-ep8-5l", None),
+    ("longcat-flash block", "longcat-flash-chat-ep64-4l", None),
+])
+def test_the_experts_leg_times_the_nine_models_prefill_shapes(smoke, label, registry, tokens):
+    """A `PREFILL_EXPERT_SHAPES` row (PR 64) is a registered
+    configuration's own numbers: the tokens a call of `expert_layer`
+    sees (the cell's prompt, a part of it, or LongCat-Flash's block),
+    the experts a token, those held, the router's width, the hidden size
+    and the expert's width, whether it has a gate (Nemotron-H's has
+    none, and stands in a scanned run's stack)."""
+    from comfyui_distributed_tpu.models.registry import get_config
+
+    cfg = get_config(registry)
+    first = lambda *names: next(getattr(cfg, n) for n in names if hasattr(cfg, n))
+    if tokens is None:
+        tokens = first("expert_block", "prefill_part")
+    no_gate = registry.startswith("nemotron")
+    (row,) = [rest for name, *rest in smoke.PREFILL_EXPERT_SHAPES if name == label]
+    assert tuple(row) == (
+        tokens, first("num_experts_per_tok", "moe_topk"), len(cfg.held_experts),
+        first("router_width", "n_routed_experts", "num_experts"), cfg.hidden_size,
+        first("moe_intermediate_size", "expert_ffn_hidden_size"), not no_gate, no_gate)
+
+
+def test_the_prefill_rows_routings_hold_the_rung_and_choose_k_distinct_experts(smoke):
+    ids = smoke.prefill_routings(3, steps=4, tokens=512, k=3, held=4, experts=16, most=400)
+    assert ids.shape == (4, 512, 3)
+    assert all(len(set(token)) == 3 for token in ids[0])
+    held = (ids < 4).sum(axis=(1, 2))
+    assert (held <= 400).all() and held.min() > 300  # a quarter of 1,536 pairs is 384

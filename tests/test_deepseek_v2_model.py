@@ -219,7 +219,8 @@ def test_a_served_request_collects_nothing_and_draws_the_same_ids():
         TINY, params, prefill.cache, prefill.logits, jnp.int32(PROMPT), jax.random.key(3),
         jnp.float32(1.0), steps=STEPS,
     )
-    assert prefill.chosen is None and decode.logits is None and decode.chosen is None
+    # the served prefill is the collecting one (PR 64): the decode is what collects nothing
+    assert prefill.chosen is not None and decode.logits is None and decode.chosen is None
     assert decode.ids.tolist() == full[PROMPT:].tolist()
     np.testing.assert_array_equal(np.asarray(prefill.loads), loads[0])
     np.testing.assert_array_equal(np.asarray(decode.loads), loads[1])

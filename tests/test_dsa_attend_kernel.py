@@ -225,7 +225,13 @@ def test_glm_dsas_prefill_on_the_tpus_route_is_its_prefill_on_the_cpus(monkeypat
     # the selection takes a TPU's route with it (PR 57; `tests/test_dsa_select_kernel.py`)
     monkeypatch.setattr(dsa_select, "dsa_select", lambda index, k, interpret: selecting(
         index, k=k, interpret=True))
-    anew = jax.jit(glm_dsa.prefill.__wrapped__, static_argnums=0, static_argnames="cache_len")
+    # under a function of this test's own: `jit` keeps a trace by the function it
+    # wraps, so two tests that wrap `prefill.__wrapped__` itself share one trace in a
+    # worker, and the second meets the first's routes and an empty log (PR 64)
+    anew = jax.jit(
+        lambda cfg, *operands, cache_len: glm_dsa.prefill.__wrapped__(
+            cfg, *operands, cache_len=cache_len),
+        static_argnums=0, static_argnames="cache_len")
     with attention.route_log() as routes:
         got = anew(cfg, params, ids, cache_len=48)
     layers = cfg.num_hidden_layers

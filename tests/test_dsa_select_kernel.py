@@ -231,7 +231,13 @@ def test_glm_dsas_prefill_on_the_tpus_route_is_its_prefill_on_the_cpus(monkeypat
     ids = jax.random.randint(jax.random.key(1), (40,), 0, cfg.vocab_size)
     want = lm.prefill(params, ids, 48)
     interpreted(monkeypatch)
-    anew = jax.jit(glm_dsa.prefill.__wrapped__, static_argnums=0, static_argnames="cache_len")
+    # under a function of this test's own: `jit` keeps a trace by the function it
+    # wraps, so two tests that wrap `prefill.__wrapped__` itself share one trace in a
+    # worker, and the second meets the first's routes and an empty log (PR 64)
+    anew = jax.jit(
+        lambda cfg, *operands, cache_len: glm_dsa.prefill.__wrapped__(
+            cfg, *operands, cache_len=cache_len),
+        static_argnums=0, static_argnames="cache_len")
     with attention.route_log() as routes:
         got = anew(cfg, params, ids, cache_len=48)
     full = sum(cfg.is_full(i) for i in cfg.layers)

@@ -36,6 +36,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def grouped_entries(tokens, k, held, experts, hidden, width, gate=True):
+    """An expert layer's entries in the route log on a TPU, a rung of its
+    ladder after the other (PR 64): the lowest rung's two grouped
+    products in `ops/grouped_matmul` where the shape rule takes them and
+    the lowest is more than a tile (a tile or fewer rows leave no entry:
+    `expert_matvec`'s or XLA's), the rungs above on `ragged_dot`."""
+    from comfyui_distributed_tpu.models import moe
+    from comfyui_distributed_tpu.ops import grouped_matmul as gmm
+
+    ladder = moe.row_ladder(tokens * k, held, experts)
+    first = (hidden, 2 * width if gate else width)
+    entries = []
+    for rung in ladder:
+        if rung <= moe.ROW_TILE:
+            continue
+        for (rows_k, n), out_major in ((first, not gate), ((width, hidden), False)):
+            mine = moe.ROW_TILE < ladder[0] == rung
+            form = gmm.route(rung, rows_k, n, held, jnp.bfloat16, out_major) if mine else "xla"
+            entries.append(f"gmm-{form} {rung}x{rows_k}x{n} g{held} bf16"
+                           + " out-major" * out_major)
+    return entries
+
+
 def test_the_served_shapes_that_reach_the_kernel():
     assert [
         label for label, q_shape, m in SHAPES if attn.kernel_wins(q_shape[1], m)
@@ -532,6 +555,56 @@ def test_ling_flash_prefill_walks_its_six_kda_layers_in_the_kernel(one_chip, mon
     assert "%flash_attention_causal" in text
 
 
+@pytest.mark.parametrize(
+    "label,tokens,k,held,experts,hidden,width,gated,stacked",
+    [s for s in chip_smoke.PREFILL_EXPERT_SHAPES if s[0].split()[0] in ("deepseek-v2", "dots3-note-prev")],
+    ids=["deepseek-v2", "dots3-note-prev"])
+def test_an_expert_layers_lowest_rung_compiles_in_the_grouped_kernel_for_v5e(
+        one_chip, monkeypatch, label, tokens, k, held, experts, hidden, width, gated, stacked):
+    """`moe.expert_layer` over a prefill (DeepSeek-V2's 2,048 tokens, a
+    part of dots3-note-prev's 8,192) at the published widths, routed as
+    a TPU routes it (PR 64): under the ladder's `lax.switch` the lowest
+    rung holds two `grouped_matmul` calls, the rungs above two
+    `ragged-dot`s each, and the log says so; what a call's blocks take
+    of VMEM twice over (the pipeline's two buffers) is under what its
+    plan counts, and that under the budget the plan fits."""
+    from comfyui_distributed_tpu.models import moe
+    from comfyui_distributed_tpu.ops import grouped_matmul as gmm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    place = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+    p = {"w_g": place(hidden, experts), "experts": {
+        "w_gate_up": place(held, hidden, 2 * width), "w_down": place(held, width, hidden)}}
+
+    def route(logits):
+        weights, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return ids, weights
+
+    with attn.route_log() as routes:
+        compiled = jax.jit(
+            lambda p, x: moe.expert_layer(p, x, range(held), route)[0]
+        ).lower(p, place(tokens, hidden)).compile()
+    ladder = moe.row_ladder(tokens * k, held, experts)
+    assert len(ladder) >= 3 and ladder[1] == 2 * ladder[0]
+    assert routes == grouped_entries(tokens, k, held, experts, hidden, width)
+    assert [r.split()[0] for r in routes] == ["gmm-kernel"] * 2 + ["gmm-xla"] * 2 * (len(ladder) - 1)
+    text = compiled.as_text()
+    assert len(re.findall(r"%grouped_matmul[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 2 * (len(ladder) - 1)
+    for rung in ladder[:1]:
+        for rows_k, n in ((hidden, 2 * width), (width, hidden)):
+            taken = gmm.plan(rung, rows_k, n, held, 2)
+            (call,) = [e for e in _eqns(jax.make_jaxpr(gmm.grouped_matmul)(
+                jax.ShapeDtypeStruct((rung, rows_k), jnp.bfloat16),
+                jax.ShapeDtypeStruct((held, rows_k, n), jnp.bfloat16),
+                jax.ShapeDtypeStruct((held,), jnp.int32)).jaxpr) if e.primitive.name == "pallas_call"]
+            blocks = [v.aval for v in call.params["jaxpr"].invars if len(v.aval.shape) == 2]
+            assert [b.shape for b in blocks] == [
+                (taken.tile, rows_k), (rows_k, taken.columns), (taken.tile, taken.columns)]
+            held_bytes = 2 * sum(b.size * b.dtype.itemsize for b in blocks)
+            assert held_bytes <= taken.vmem_bytes <= gmm.VMEM_BLOCK_BUDGET, (rung, rows_k, n)
+
+
 @pytest.mark.parametrize("label,rows,k,held,experts,hidden,width", chip_smoke.EXPERT_SHAPES)
 def test_expert_matvec_compiles_for_v5e_at_the_decode_shapes(
         one_chip, label, rows, k, held, experts, hidden, width):
@@ -814,7 +887,12 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
     picks = [f"dsa-select-kernel 8192x{length} k2048" for length in (4096, 8192, 16384, 32768, 32896)]
     assert [r for r in routes if r.startswith("dsa-select")] == picks * cfg.full_layers
     assert routes.count("dsa-kernel 8192x32896 k2048 h64 bf16") == cfg.num_hidden_layers
-    assert len(routes) == len(picks) * cfg.full_layers + cfg.num_hidden_layers
+    # a sparse layer's ladder (4,096 ... 65,536): the lowest rung in the grouped kernel
+    grouped = grouped_entries(8192, 8, 16, 256, 6144, 2048)
+    assert [r.split()[0] for r in grouped] == ["gmm-kernel"] * 2 + ["gmm-xla"] * 8
+    assert [r for r in routes if r.startswith("gmm-")] == grouped * cfg.sparse_layers
+    assert len(routes) == (
+        len(picks) * cfg.full_layers + cfg.num_hidden_layers + len(grouped) * cfg.sparse_layers)
     memory = prefill.memory_analysis()
     assert memory.temp_size_in_bytes < 4.5e9      # 4.13 GB: a part's, whatever the parts' number
     assert memory.output_size_in_bytes >= 32896 * 7680
@@ -827,6 +905,8 @@ def test_glm_prefill_in_parts_is_one_scanned_body_and_its_decode_carries_two_cac
     assert text.count("%dsa_select") >= 1
     assert not [line for line in text.splitlines() if " gather(" in line and "2048,576" in line]
     assert text.count("%dsa_attend") >= 1
+    # a call a product and rung in each of the two bodies that hold a sparse layer's switch
+    assert len(re.findall(r"%grouped_matmul[.\d]* = ", text)) >= 4 and "ragged-dot" in text
 
     state = jax.tree.map(place, glm_dsa.state_shapes(cfg, 32896, jnp.bfloat16))
     scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
@@ -907,7 +987,12 @@ def test_dots3_prefill_in_parts_hands_tails_on_and_its_decode_carries_caches_and
     bands = [r for r in routes if " w513 " in r]
     assert len(bands) == 2 * cfg.window_layers and all(r.startswith(route + "-causal") for r in bands)
     assert [r.split()[1] for r in bands] == ["8192x8192x256/128", "8192x8704x256/128"] * 3
-    assert len(routes) == (len(picks) + 1) * cfg.full_layers + len(bands)
+    # a sparse layer's ladder (8,192 ... 65,536): the lowest rung in the grouped kernel
+    grouped = grouped_entries(8192, 8, 32, 256, 5120, 1536)
+    assert [r.split()[0] for r in grouped] == ["gmm-kernel"] * 2 + ["gmm-xla"] * 6
+    assert [r for r in routes if r.startswith("gmm-")] == grouped * cfg.sparse_layers
+    assert len(routes) == (
+        (len(picks) + 1) * cfg.full_layers + len(bands) + len(grouped) * cfg.sparse_layers)
     memory = prefill.memory_analysis()
     assert memory.temp_size_in_bytes < 3.6e9      # 3.18 GB: a part's, whatever the parts' number
     assert memory.output_size_in_bytes >= 33024 * 2816 + 3 * 520 * 2176
@@ -917,6 +1002,7 @@ def test_dots3_prefill_in_parts_hands_tails_on_and_its_decode_carries_caches_and
         if " sort(" in line and re.search(r"\[\d+,(4096|8192|16384|32768|33024)\]", line)]
     assert text.count("%dsa_select") >= 1 and text.count("%dsa_attend") >= 1
     assert not [line for line in text.splitlines() if " gather(" in line and "2048,576" in line]
+    assert len(re.findall(r"%grouped_matmul[.\d]* = ", text)) >= 4 and "ragged-dot" in text
 
     state = jax.tree.map(place, dots3.state_shapes(cfg, 33024, jnp.bfloat16))
     scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
@@ -966,6 +1052,11 @@ def test_longcat_flash_prefill_rebuilds_16_heads_keys_at_a_time_and_its_decode_c
             cfg, params, jax.ShapeDtypeStruct((32768,), jnp.int32, sharding=one_chip),
             cache_len=32896,
         ).compile()
+    # a block's ladder starts at a tile (256 ... 12,288): every rung keeps `ragged_dot` (PR 64)
+    grouped = grouped_entries(1024, 12, 8, 768, 6144, 2048)
+    assert len(grouped) == 12 and all(r.startswith("gmm-xla ") for r in grouped)
+    assert [r for r in routes if r.startswith("gmm-")] == grouped * cfg.num_layers
+    routes = [r for r in routes if not r.startswith("gmm-")]
     counts = (8192, 16384, 24576, 32768)
     assert [r.split()[:2] for r in routes] == [
         ["flash-causal", f"8192x{keys}x192/128"] for keys in counts] * cfg.attention_sublayers
@@ -1017,10 +1108,14 @@ def test_sdar_prefill_masks_by_block_in_the_causal_kernel_and_its_decode_carries
         compiled = sdar.prefill.lower(
             cfg, params, jax.ShapeDtypeStruct((2048,), jnp.int32, sharding=one_chip),
             cache_len=2560).compile()
-    assert routes == [
-        "flash-causal 2048x2048x128/128 b4 g8 bq512 bk1024 bf16 inplace blocks6/8"] * 6
+    # every expert held: a ladder of one rung, its two products in the grouped kernel (PR 64)
+    grouped = grouped_entries(2048, 8, 128, 128, 2048, 768)
+    assert grouped == ["gmm-kernel 16384x2048x1536 g128 bf16", "gmm-kernel 16384x768x2048 g128 bf16"]
+    assert routes == ([
+        "flash-causal 2048x2048x128/128 b4 g8 bq512 bk1024 bf16 inplace blocks6/8"] + grouped) * 6
     text = compiled.as_text()
     assert len(re.findall(r"%flash_attention_causal[.\d]* = ", text)) == 6
+    assert len(re.findall(r"%grouped_matmul[.\d]* = ", text)) == 12 and "ragged-dot" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 400 * 2**20
 
     state = jax.tree.map(place, sdar.state_shapes(cfg, 2560, jnp.bfloat16))

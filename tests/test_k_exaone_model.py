@@ -444,7 +444,8 @@ def test_at_temperature_zero_drafting_changes_no_id():
 def test_a_served_request_collects_nothing_draws_the_same_ids_and_gets_its_state_back(drafting):
     params, ids, _, collected = drafting
     pre = ke.prefill(TINY, params, ids, cache_len=PROMPT + NEW)
-    assert pre.chosen is None
+    # the served prefill is the collecting one (PR 64): the decode is what collects nothing
+    assert pre.chosen.shape[1:] == (PROMPT, TINY.num_experts_per_tok)
     shapes = ke.state_shapes(TINY, PROMPT + NEW, jnp.float32)
     assert {k: (v.shape, v.dtype) for k, v in pre.cache.items()} == {
         k: (v.shape, v.dtype) for k, v in shapes.items()}

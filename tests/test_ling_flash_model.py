@@ -473,7 +473,8 @@ def test_exactly_as_many_ids_as_asked_for_whatever_was_kept(steps):
 def test_a_served_request_collects_nothing_draws_the_same_ids_and_gets_its_state_back(drafting):
     params, ids, _, collected = drafting
     pre = lf.prefill(TINY, params, ids, cache_len=PROMPT + NEW)
-    assert pre.chosen is None
+    # the served prefill is the collecting one (PR 64): the decode is what collects nothing
+    assert pre.chosen.shape[1:] == (PROMPT, TINY.num_experts_per_tok)
     shapes = lf.state_shapes(TINY, PROMPT + NEW, jnp.float32)
     of = lambda tree: jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), tree)
     assert of(pre.cache) == of(shapes)

@@ -33,8 +33,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUILD_TALLIES = {"compiles", "compile_s", "cache_hits", "cache_misses", "trace_s", "lower_s",
                  "cache_fetch_s"}
 ROUTING = {f"{phase}_{what}" for phase in ("prefill", "decode") for what in (
-    "routed_pairs", "routed_pairs_held", "expert_load_max", "expert_rows")} | {
-    "decode_expert_route"}
+    "routed_pairs", "routed_pairs_held", "expert_load_max", "expert_rows", "expert_route")}
+# the kernel metric of the cells whose prefill's grouped products take `ops/grouped_matmul` (PR 64)
+GROUPED = "grouped_matmul_device_pct.lm"
 # the metrics every language-model cell lists in BENCHMARK.json
 LM_METRICS = {
     "images_per_s", "execute_ms.txt2img", "host_ms.txt2img", "device_idle_pct.txt2img",
@@ -761,9 +762,27 @@ class Model:
     imports: tuple = tuple(PLAIN_IMPORTS)
     cell_prompt: int = 0        # tokens of the committed workflow, where the rehearsal cuts it
     trace: tuple = (5, 12)      # the cell's traced slice: (start_s, slice_s)
+    # (tokens, experts a token, held, experts, hidden, width[, no gate]) of the tiny
+    # preset's expert layer where a prefill's rungs are more than a tile: their entries
+    grouped: tuple = ()
 
     def __str__(self):
         return self.name
+
+    def traced_routes(self) -> str:
+        """`attention` on the request that traced the programs: the row's
+        entries and, a rung and product of the expert layer's ladder,
+        `gmm-xla ...` (the CPU's route; PR 64)."""
+        entries = set(self.attention.split(", "))
+        if self.grouped:
+            from comfyui_distributed_tpu.models import moe
+
+            tokens, k, held, experts, hidden, width, *no_gate = self.grouped
+            first = (hidden, width, " out-major") if no_gate else (hidden, 2 * width, "")
+            for rung in moe.row_ladder(tokens * k, held, experts):
+                entries |= {f"gmm-xla {rung}x{first[0]}x{first[1]} g{held} f32{first[2]}",
+                            f"gmm-xla {rung}x{width}x{hidden} g{held} f32"}
+        return ", ".join(sorted(entries))
 
 
 MODELS = [
@@ -779,12 +798,15 @@ MODELS = [
             "layers": 3, "experts_held": 4, "experts_total": 16,
             "cache_bytes": 3 * (2048 + 16) * 32 * 4, "state_bytes": 0,
             "prefill_routed_pairs": 2048 * 2 * 3, "decode_routed_pairs": 16 * 2 * 3,
-            "decode_expert_rows": 16 * 2 * 3, "decode_expert_route": "xla", "node_id": "6"},
+            "decode_expert_rows": 16 * 2 * 3, "decode_expert_route": "xla",
+            "prefill_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
-                                   "decode_expert_rows", "decode_expert_route"}),
+                                   "decode_expert_rows", "decode_expert_route",
+                                   "prefill_expert_route"}),
         drawn_check=deepseek_drawn,
         wait_bytes=4 * (16 + 2 * 2 * 4),  # the ids, the pairs per held expert of either program
         attention="xla-causal 2048x2048x24/16 bq256 f32",
+        grouped=(2048, 3, 4, 16, 64, 32),
         passes=lambda attrs: (2048 * 3, 16 * 3),
         widths={
             "hidden_size": 5120, "intermediate_size": 12288, "moe_intermediate_size": 1536,
@@ -800,7 +822,7 @@ MODELS = [
         assumed=("seeded random", "stand-in", "batch is 1"),
         published=deepseek_published, entry=deepseek_entry, check_workflow=deepseek_workflow,
         # `mla_device_pct.lm` since PR 45, whose reader finds this cell's `mla` scope too
-        metrics=frozenset({"experts_held_share_pct.lm", "mla_device_pct.lm"}),
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "mla_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS + ["import math\n"]),
     ),
     Model(
@@ -851,9 +873,10 @@ MODELS = [
             "prefill_chunks": 8192 // 32, "kda_form": "scan",
             "prefill_routed_pairs": 8192 * 4 * 4, "decode_routed_pairs": 16 * 4 * 4,
             "decode_expert_rows": 16 * 4 * 4,
-            "decode_expert_route": "xla", "node_id": "6"},
+            "decode_expert_route": "xla", "prefill_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
-                                   "decode_expert_rows", "decode_expert_route"}),
+                                   "decode_expert_rows", "decode_expert_route",
+                                   "prefill_expert_route"}),
         drawn_check=solar_drawn,
         wait_bytes=4 * (16 + 2 * 4 * 2),  # nothing of the state tree leaves the device
         # the decode's one softmax layer in four (the einsum form, a key head
@@ -861,6 +884,7 @@ MODELS = [
         # form on any backend), then its softmax layer
         attention=("decode-xla 4x8208x16, kda-scan 8192x4x16 c32 f32, "
                    "xla-causal 8192x8192x16/16 bq256 f32"),
+        grouped=(8192, 4, 2, 16, 64, 32),
         passes=lambda attrs: (8192 * 4, 16 * 4),
         widths={
             "hidden_size": 4096, "num_attention_heads": 64, "num_key_value_heads": 8,
@@ -875,7 +899,7 @@ MODELS = [
         assumed=("low-rank", "element-wise", "sigmoids", "seeded random", "stand-in",
                  "batch is 1", "dt_bias", "house style guide"),
         published=solar_published, entry=solar_entry, check_workflow=solar_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "state_mb.lm",
                            "linear_attention_device_pct.lm"}),
     ),
     Model(
@@ -893,8 +917,9 @@ MODELS = [
             "ring_positions": 16, "experts_held": 2, "experts_total": 16,
             "cache_bytes": 2 * 2 * 2 * (8192 + 16) * 16 * 4, "state_bytes": 4 * 2 * 2 * 16 * 16 * 4,
             "prefill_layer_passes": 8192 * 5, "prefill_routed_pairs": 8192 * 4 * 4,
-            "decode_expert_route": "xla", "node_id": "6"},
-        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
+            "decode_expert_route": "xla", "prefill_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route",
+        "prefill_expert_route"}) | {
             "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
             "decode_experts_read"},
         drawn_check=k_exaone_drawn,
@@ -906,6 +931,7 @@ MODELS = [
         attention=("decode-xla 4x16x16, decode-xla 4x8208x16, "
                    "xla-causal 8192x8192x16/16 bq256 f32, "
                    "xla-causal 8192x8192x16/16 w12 bq256 f32"),
+        grouped=(8192, 4, 2, 16, 64, 32),
         passes=lambda attrs: (8192 * 5, attrs["decode_steps"] * 2 * (5 + 1)),
         widths={
             "hidden_size": 6144, "num_attention_heads": 64, "num_key_value_heads": 8,
@@ -921,7 +947,7 @@ MODELS = [
                  "selection bias", "DeepSeek-V3's", "before the final norm", "seeded random",
                  "stand-in", "batch is 1", "share of drafts kept", "house style guide"),
         published=k_exaone_published, entry=k_exaone_entry, check_workflow=k_exaone_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
                            "mtp_device_pct.lm"}),
     ),
     Model(
@@ -940,8 +966,10 @@ MODELS = [
             "experts_total": 32, "cache_bytes": 2 * (8192 + 16) * 32 * 4,
             "state_bytes": 6 * 2 * (4 * 16 * 16 * 4 + 3 * 3 * 4 * 16 * 4),
             "prefill_chunks": 8192 // 32, "kda_form": "scan", "prefill_layer_passes": 8192 * 7,
-            "prefill_routed_pairs": 8192 * 6 * 4, "decode_expert_route": "xla", "node_id": "6"},
-        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
+            "prefill_routed_pairs": 8192 * 6 * 4, "decode_expert_route": "xla",
+            "prefill_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route",
+        "prefill_expert_route"}) | {
             "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
             "decode_experts_read"},
         drawn_check=ling_flash_drawn,
@@ -952,6 +980,7 @@ MODELS = [
         # attention (24-wide queries and keys, 16-wide values); the decode's absorbed
         # form is plain einsums and logs no route
         attention="kda-scan 8192x4x16 c32 f32, xla-causal 8192x8192x24/16 bq256 f32",
+        grouped=(8192, 4, 4, 32, 64, 32),
         passes=lambda attrs: (8192 * 7, attrs["decode_steps"] * 2 * (7 + 1)),
         widths={
             "hidden_size": 2560, "num_attention_heads": 32, "num_key_value_heads": 32,
@@ -975,7 +1004,7 @@ MODELS = [
                  "batch is 1", "share of drafts kept", "house style guide"),
         published=ling_flash_published, entry=ling_flash_entry,
         check_workflow=ling_flash_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "state_mb.lm", "mtp_accept_pct.lm",
                            "mtp_device_pct.lm", "linear_attention_device_pct.lm",
                            "state_keep_device_pct.lm", "mla_device_pct.lm"}),
         imports=tuple(PLAIN_IMPORTS[:4]),  # no numpy of its own
@@ -998,9 +1027,10 @@ MODELS = [
             "prefill_chunks": 8192 // 32,
             "prefill_routed_pairs": 8192 * 6 * 3, "decode_routed_pairs": 16 * 6 * 3,
             "decode_expert_rows": 16 * 6 * 3,
-            "decode_expert_route": "xla", "node_id": "6"},
+            "decode_expert_route": "xla", "prefill_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
-                                   "decode_expert_rows", "decode_expert_route"}) | {
+                                   "decode_expert_rows", "decode_expert_route",
+                                   "prefill_expert_route"}) | {
             "decode_experts_read"},
         drawn_check=nemotron_drawn,
         wait_bytes=4 * (16 + 2 * 6 * 2),  # nothing of the state tree leaves the device
@@ -1009,6 +1039,7 @@ MODELS = [
         # off the lane tile, never the kernel) and its attention block
         attention=("decode-xla 4x8208x16, ssd-xla 8192x4x8 g2 n16 c32 f32, "
                    "xla-causal 8192x8192x16/16 bq256 f32"),
+        grouped=(8192, 3, 2, 16, 64, 24, True),
         passes=lambda attrs: (8192 * 13, 16 * 13),
         widths={
             "hidden_size": 2688, "num_hidden_layers": 52, "mamba_num_heads": 64,
@@ -1029,7 +1060,7 @@ MODELS = [
                  "no group step", "seeded random", "no bias is shifted", "stand-in",
                  "batch is 1", "house style guide", "no MTP module"),
         published=nemotron_published, entry=nemotron_entry, check_workflow=nemotron_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "ssm_device_pct.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "state_mb.lm", "ssm_device_pct.lm",
                            "ssd_device_pct.lm", "expert_matvec_hbm_pct.lm",
                            "attn_device_pct.lm"}),
     ),
@@ -1054,8 +1085,9 @@ MODELS = [
             "prefill_selection_form": "sort",
             "decode_sparse_attention_form": "masked",
             "prefill_layer_passes": 2048 * 5, "prefill_routed_pairs": 2048 * 4 * 4,
-            "decode_expert_route": "xla", "node_id": "6"},
-        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route"}) | {
+            "decode_expert_route": "xla", "prefill_expert_route": "xla", "node_id": "6"},
+        drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_expert_route",
+        "prefill_expert_route"}) | {
             "decode_steps", "mtp_drafted", "mtp_accepted", "decode_layer_passes",
             "decode_experts_read", "keys_visible", "keys_selected"},
         drawn_check=glm_drawn,
@@ -1090,7 +1122,7 @@ MODELS = [
                  "before the final norm", "seeded random", "stand-in", "batch is 1",
                  "share of drafts kept", "style guide"),
         published=glm_published, entry=glm_entry, check_workflow=glm_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "mtp_accept_pct.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "mtp_accept_pct.lm",
                            "mtp_device_pct.lm", "mla_device_pct.lm", "indexer_device_pct.lm",
                            "keys_selected_pct.lm", "dsa_attend_device_pct.lm",
                            "dsa_select_device_pct.lm"}),
@@ -1159,9 +1191,10 @@ MODELS = [
             "cache_bytes": 3 * 2 * 2 * (2048 + 16) * 16 * 4, "state_bytes": 0,
             "prefill_layer_passes": 2048 * 3, "prefill_routed_pairs": 2048 * 3 * 2,
             "prefill_routed_pairs_held": 2048 * 3 * 2, "prefill_expert_rows": 2048 * 3 * 2,
-            "decode_expert_route": "xla", "node_id": "6"},
+            "decode_expert_route": "xla", "prefill_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "prefill_routed_pairs_held",
-                                   "prefill_expert_rows", "decode_expert_route"}) | {
+                                   "prefill_expert_rows", "decode_expert_route",
+                                   "prefill_expert_route"}) | {
             "decode_steps", "denoise_passes", "closing_passes", "transferred_by_threshold",
             "transferred_by_floor", "decode_layer_passes", "decode_experts_read"},
         drawn_check=sdar_drawn,
@@ -1170,6 +1203,7 @@ MODELS = [
         # a pass's four queries over the cache, the block's own entries among what they see;
         # the prefill under the block mask
         attention="decode-xla 4x2064x16, xla-causal 2048x2048x16/16 b4 bq256 f32",
+        grouped=(2048, 2, 8, 8, 64, 32),
         passes=lambda attrs: (2048 * 3, attrs["decode_layer_passes"]),
         widths={
             "hidden_size": 2048, "num_attention_heads": 32, "num_key_value_heads": 4,
@@ -1186,7 +1220,7 @@ MODELS = [
                  "closing pass runs no head", "Qwen3-MoE", "seeded random", "stand-in",
                  "batch is 1", "house style guide"),
         published=sdar_published, entry=sdar_entry, check_workflow=sdar_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "attn_device_pct.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "attn_device_pct.lm",
                            "denoise_passes_per_token.lm", "experts_device_pct.lm",
                            "expert_union_hbm_pct.lm"}),
     ),
@@ -1221,9 +1255,10 @@ MODELS = [
             "prefill_layer_passes": 2048 * 5, "decode_layer_passes": 16 * 5,
             "prefill_routed_pairs": 2048 * 4 * 2, "decode_routed_pairs": 16 * 4 * 2,
             "decode_expert_rows": 16 * 4 * 2,
-            "decode_expert_route": "xla", "node_id": "6"},
+            "decode_expert_route": "xla", "prefill_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
-                                   "decode_expert_rows", "decode_expert_route"}) | {
+                                   "decode_expert_rows", "decode_expert_route",
+                                   "prefill_expert_route"}) | {
             "decode_experts_read"},
         drawn_check=dots3_drawn,
         # the ids; a part's pairs per held expert and keys seen a full layer (128 parts);
@@ -1262,7 +1297,7 @@ MODELS = [
                  "no MTP module and no tower", "seeded random", "stand-in", "batch is 1",
                  "style guide"),
         published=dots3_published, entry=dots3_entry, check_workflow=dots3_workflow,
-        metrics=frozenset({"experts_held_share_pct.lm", "state_mb.lm", "mla_device_pct.lm",
+        metrics=frozenset({GROUPED, "experts_held_share_pct.lm", "state_mb.lm", "mla_device_pct.lm",
                            "indexer_device_pct.lm", "keys_selected_pct.lm",
                            "dsa_attend_device_pct.lm", "dsa_select_device_pct.lm",
                            "experts_device_pct.lm", "window_latent_device_pct.lm",
@@ -1285,9 +1320,11 @@ MODELS = [
             "prefill_parts": 128, "experts_held": 4, "experts_total": 8, "zero_experts": 4,
             "cache_bytes": (2048 + 16) * 4 * 24 * 4, "state_bytes": 0,
             "prefill_routed_pairs": 2048 * 2 * 3, "decode_routed_pairs": 16 * 2 * 3,
-            "decode_expert_rows": 16 * 2 * 3, "decode_expert_route": "xla", "node_id": "6"},
+            "decode_expert_rows": 16 * 2 * 3, "decode_expert_route": "xla",
+            "prefill_expert_route": "xla", "node_id": "6"},
         drawn=frozenset(ROUTING - {"prefill_routed_pairs", "decode_routed_pairs",
-                                   "decode_expert_rows", "decode_expert_route"}) | {
+                                   "decode_expert_rows", "decode_expert_route",
+                                   "prefill_expert_route"}) | {
             "decode_experts_read", "prefill_zero_pairs", "decode_zero_pairs",
             "real_experts_per_token_mean", "real_experts_per_token_min",
             "real_experts_per_token_max"},
@@ -1457,7 +1494,7 @@ def test_the_spans_under_the_node_are_dispatch_one_wait_and_detokenize(model, se
 
 def test_only_the_request_that_traced_the_programs_says_which_attention(model, served):
     (first,) = spans_named(served[0][1], "node.TextGenerate")
-    assert first["attrs"]["attention"] == model.attention
+    assert first["attrs"]["attention"] == model.traced_routes()
     (second,) = spans_named(served[1][1], "node.TextGenerate")
     assert "attention" not in second["attrs"]
 
@@ -2303,7 +2340,9 @@ def test_solars_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeyp
     with attention.route_log() as routes:
         want = solar_open2.prefill(cfg, params, ids, cache_len=672, collect=True)
     delta_rule = ["kda-scan 640x4x16 c32 f32"] * cfg.linear_layers  # d = 16: never the kernel
-    assert sorted(routes) == delta_rule + ["xla-causal 640x640x16/16 bq256 f32"]
+    grouped = sorted(r for r in routes if r.startswith("gmm-"))  # the expert layers' rungs (PR 64)
+    assert grouped and all(r.startswith("gmm-xla ") for r in grouped)
+    assert sorted(routes) == grouped + delta_rule + ["xla-causal 640x640x16/16 bq256 f32"]
 
     monkeypatch.setattr(attention, "causal_route", lambda *operands: "flash")
     kernel = attention.flash_attention
@@ -2313,7 +2352,7 @@ def test_solars_prefill_on_the_kernels_route_gives_the_xla_routes_logits(monkeyp
     with attention.route_log() as routes:  # another cache length: traced anew
         got = solar_open2.prefill(cfg, params, ids, cache_len=704, collect=True)
     assert sorted(routes) == (
-        ["flash-causal 640x640x16/16 g2 bq128 bk640 f32 blocks5/5"] + delta_rule)
+        ["flash-causal 640x640x16/16 g2 bq128 bk640 f32 blocks5/5"] + grouped + delta_rule)
     # another order of summation moves a score in its last digit, and a router
     # over seeded weights then gives a few (token, layer) pairs another expert
     # (the XLA form in blocks of 128 rows for 256: 5 of 10,240 choices; the

@@ -207,7 +207,8 @@ def served(params, collect, steps=STEPS):
 
 def test_a_served_request_collects_nothing_draws_the_same_ids_and_gets_its_state_back(params):
     prefill, decode = served(params, False)
-    assert prefill.chosen is None and decode.logits is None and decode.chosen is None
+    # the served prefill is the collecting one (PR 64): the decode is what collects nothing
+    assert prefill.chosen is not None and decode.logits is None and decode.chosen is None
     assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(prefill.cache))  # donated
     assert jax.tree_util.tree_structure(decode.cache) == jax.tree_util.tree_structure(
         nh.state_shapes(TINY, PROMPT + STEPS, jnp.float32))

@@ -199,7 +199,8 @@ def test_a_served_request_collects_nothing_draws_the_same_ids_and_gets_its_state
     ids = jax.random.randint(jax.random.key(5), (PROMPT,), 0, TINY.vocab_held)
     _, _, _, _, collected = generate(TINY, params)
     prefill = so.prefill(TINY, params, ids, cache_len=PROMPT + STEPS)
-    assert prefill.chosen is None
+    # the served prefill is the collecting one (PR 64): the decode is what collects nothing
+    assert prefill.chosen.shape[1:] == (PROMPT, TINY.num_experts_per_tok)
     given = prefill.cache
     decode = so.decode(
         TINY, params, given, prefill.logits, jnp.int32(PROMPT), jax.random.key(1),
