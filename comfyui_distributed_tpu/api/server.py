@@ -668,17 +668,24 @@ class DistributedServer:
 
     def _work_ended(self, job: PromptJob) -> None:
         """One piece of the job's work ended, on either thread; the last
-        one ends `execute_prompt` and sets `done`."""
+        one stamps the job's record on `execute_prompt`, ends it and
+        sets `done`."""
         from ..telemetry import get_tracer
+        from ..telemetry.job_record import stamp_job
 
         with self._jobs_lock:
             job.open_work -= 1
             if job.open_work:
                 return
-        span = job.execute_span
+        tracer, span = get_tracer(), job.execute_span
         if job.error is not None:
             span.attrs.setdefault("error", job.error)
-        get_tracer().end_span(span, status="ok" if job.error is None else "error")
+        end = tracer.now()
+        try:
+            stamp_job(tracer, span, end)
+        except Exception as exc:  # noqa: BLE001 - telemetry must not fail the job
+            debug_log(f"job record for {job.prompt_id} failed: {exc}")
+        tracer.end_span(span, status="ok" if job.error is None else "error", end=end)
         self._export_trace(job.trace_id)
         with self._jobs_lock:
             self._unfinished -= 1

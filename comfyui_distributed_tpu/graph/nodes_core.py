@@ -1411,9 +1411,10 @@ def _save_pngs(images, paths, overlapped=lambda: False, landed=lambda: None) -> 
     """Read `images` back and write one PNG per image. The thread parks
     in the read-back until the device has finished everything the
     images depend on, then calls `landed()`; `overlapped()` says whether
-    the executor has taken another prompt since the hand-off."""
+    the executor has taken another prompt since the hand-off (what
+    `png.encode` says of it). From the read-back's end on this is the
+    `tail_s` of the job's record (telemetry/job_record.py)."""
     from ..telemetry import get_tracer
-    from ..telemetry.instruments import saves_total
 
     tracer = get_tracer()
     with tracer.device_wait() as wait:
@@ -1427,9 +1428,8 @@ def _save_pngs(images, paths, overlapped=lambda: False, landed=lambda: None) -> 
         with tracer.span("file.write", bytes=len(png)):
             with open(path, "wb") as fh:
                 fh.write(png)
-        hidden = int(overlapped())
-        encode.attrs["overlapped"] = hidden
-        saves_total().inc(overlapped=str(hidden))
+        # read once the file is there: taken meanwhile, it was hidden
+        encode.attrs["overlapped"] = int(overlapped())
 
 
 @register_node

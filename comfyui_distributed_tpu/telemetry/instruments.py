@@ -841,23 +841,6 @@ def prompt_queue_depth() -> Gauge:
     )
 
 
-def saves_pending() -> Gauge:
-    return get_metrics_registry().gauge(
-        "cdt_saves_pending",
-        "Image saves handed to the saver thread and not yet on disk, per server",
-        ("server",),
-    )
-
-
-def saves_total() -> Counter:
-    return get_metrics_registry().counter(
-        "cdt_saves_total",
-        "Images saved; overlapped=1 when the executor had taken another "
-        "prompt before the file was written",
-        ("overlapped",),
-    )
-
-
 def walks_total() -> Counter:
     return get_metrics_registry().counter(
         "cdt_walks_total",
@@ -884,6 +867,30 @@ def device_busy_seconds_total() -> Counter:
         "(the busy_s of the device.run spans); its rate is the chip's "
         "utilisation by program",
         ("program",),
+    )
+
+
+def job_seconds_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_job_seconds_total",
+        "Seconds of finished jobs, from arrival to the last byte on "
+        "disk, by where the job stood: waiting (for the chip, behind "
+        "earlier jobs), device (its own programs on the chip), starved "
+        "(the chip idle until its next launch), tail (read-back, encode, "
+        "write); the four attributes of execute_prompt",
+        ("part",),
+    )
+
+
+def device_idle_seconds_total() -> Counter:
+    return get_metrics_registry().counter(
+        "cdt_device_idle_seconds_total",
+        "Seconds the device had nothing before a launch (the "
+        "idle_before_s of the device.run spans), by cause: no_job "
+        "(before the job arrived), between_jobs (before its first "
+        "launch), within_job (between two of its own); beside "
+        "cdt_device_busy_seconds_total, the utilisation's loss by cause",
+        ("cause",),
     )
 
 
@@ -942,7 +949,6 @@ def collector_jobs_active() -> Gauge:
 
 _LIVE_GAUGES = (
     prompt_queue_depth,
-    saves_pending,
     tile_jobs_active,
     tile_queue_depth,
     tiles_in_flight,
@@ -1048,7 +1054,6 @@ def bind_server_collectors(server) -> Callable[[], None]:
 
     def collect() -> None:
         prompt_queue_depth().set(server.queue_remaining, server=label)
-        saves_pending().set(getattr(server, "saves_pending", 0), server=label)
         stats = server.job_store.stats_unlocked()
         tile_jobs_active().set(stats["tile_jobs"], server=label)
         tile_queue_depth().set(stats["queue_depth"], server=label)

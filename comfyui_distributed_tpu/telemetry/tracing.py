@@ -315,9 +315,13 @@ class Tracer:
         _notify_span("open", span)
         return span
 
-    def end_span(self, span: Span, status: str = "ok") -> None:
+    def end_span(
+        self, span: Span, status: str = "ok", end: Optional[float] = None
+    ) -> None:
+        """`end` is an earlier reading of `now()`, for a span that is
+        stamped with what happened up to its end before it is closed."""
         if span.end is None:
-            span.end = self._clock()
+            span.end = self._clock() if end is None else end
             # preserve a status the body set explicitly (e.g. a span
             # whose failure is swallowed by a best-effort except arm)
             if span.status == "ok":
@@ -378,8 +382,10 @@ class Tracer:
         under the active span and hands it to the watcher thread, which
         ends it when `ready` is: `begin` (when the device could start
         it: the later of this call and the previous launch's end),
-        `queued_s`, `busy_s`. Waits for nothing here; outside a trace
-        it does nothing."""
+        `queued_s` (how long it stood behind that launch),
+        `idle_before_s` (how long the device had had nothing when this
+        call came; one of the two is 0), `busy_s`. Waits for nothing
+        here; outside a trace it does nothing."""
         if _current.get() is None:
             return None
         span = self.start_span("device.run", attrs={"program": program, **attrs})
@@ -423,9 +429,13 @@ class Tracer:
                 launch.ready = None
             if error is None:
                 begin = span.start if last_end is None else max(span.start, last_end)
+                idle_before_s = 0.0 if last_end is None else begin - last_end
                 last_end = span.end
                 busy_s = span.end - begin
-                span.attrs.update(begin=begin, queued_s=begin - span.start, busy_s=busy_s)
+                span.attrs.update(
+                    begin=begin, queued_s=begin - span.start,
+                    idle_before_s=idle_before_s, busy_s=busy_s,
+                )
                 device_busy_seconds_total().inc(max(0.0, busy_s), program=str(program))
             else:
                 # says nothing of when the device was free: the next

@@ -147,6 +147,45 @@ def test_a_deleted_or_failed_output_ends_its_span_in_error_and_the_next_is_serve
     assert tracer._watch_thread.is_alive()
 
 
+@pytest.mark.parametrize("second_start, queued_s, idle_before_s", [
+    (2.0, 3.0, 0.0),   # launched behind a program still running: it stood, the device did not
+    (5.0, 0.0, 0.0),   # launched the moment the device came free
+    (6.5, 0.0, 1.5),   # launched on a device that had had nothing for 1.5 s
+])
+def test_idle_before_is_the_complement_of_queued_and_one_of_the_two_is_zero(
+    tracer, clock, second_start, queued_s, idle_before_s
+):
+    with tracer.span("execute_prompt", trace_id="earlier"):
+        first, out_first = launch(tracer, clock, 1.0, "vae_decode")
+    with tracer.span("execute_prompt", trace_id="t"):  # the previous launch is any trace's
+        if second_start < 5.0:
+            second, out_second = launch(tracer, clock, second_start)
+            finish(clock, 5.0, first, out_first)
+        else:
+            finish(clock, 5.0, first, out_first)
+            second, out_second = launch(tracer, clock, second_start)
+    finish(clock, 8.0, second, out_second)
+    assert first.attrs["idle_before_s"] == 0.0  # nothing before it says when the device was free
+    assert (second.attrs["queued_s"], second.attrs["idle_before_s"]) == (queued_s, idle_before_s)
+    assert second.attrs["idle_before_s"] == second.attrs["begin"] - first.end
+    # from the previous end to this one the device was idle, then busy
+    assert second.attrs["idle_before_s"] + second.attrs["busy_s"] == second.end - first.end
+
+
+def test_a_failed_program_bears_no_idle_before_and_the_next_counts_from_the_last_good_end(
+    tracer, clock
+):
+    with tracer.span("execute_prompt", trace_id="t"):
+        good, out_good = launch(tracer, clock, 1.0)
+        finish(clock, 2.0, good, out_good)
+        bad, out_bad = launch(tracer, clock, 3.0, "lost", Output(fail=ValueError("failed")))
+        finish(clock, 4.0, bad, out_bad)
+        after, out_after = launch(tracer, clock, 4.5)
+        finish(clock, 6.0, after, out_after)
+    assert bad.status == "error" and not {"begin", "queued_s", "idle_before_s"} & set(bad.attrs)
+    assert after.attrs["idle_before_s"] == 2.5 and after.attrs["queued_s"] == 0.0
+
+
 def test_outside_a_trace_nothing_is_queued_and_no_thread_starts(tracer, clock):
     output = Output()
     assert tracer.device_span("sampler", output) is None

@@ -43,7 +43,9 @@ LM_METRICS = {
     "lm_share_pct.rewrite", "cache_gb.lm", "layer_passes_per_token.lm",
     "sampler_device_ms.txt2img", "vae_device_ms.txt2img", "prefill_device_ms.lm",
     "decode_device_ms_per_token.lm", "decode_hbm_roofline_pct.lm", "prefill_mxu_peak_pct.lm",
-    "device_idle_in_pct.txt2img", "between_jobs_ms.txt2img"}
+    "device_idle_in_pct.txt2img", "between_jobs_ms.txt2img",
+    # the job's record (telemetry/job_record.py), every cell with a served job
+    "job_waiting_ms.txt2img", "job_tail_ms.txt2img", "device_starved_pct.txt2img"}
 PLAIN_IMPORTS = ["from __future__ import annotations\n", "import dataclasses\n", "import jax\n",
                  "import jax.numpy as jnp\n", "import numpy as np\n"]
 

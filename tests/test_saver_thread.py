@@ -20,7 +20,8 @@ from comfyui_distributed_tpu.graph.executor import ExecutionContext, GraphExecut
 from comfyui_distributed_tpu.graph.registry import NODE_REGISTRY
 from comfyui_distributed_tpu.resilience.chaos import FakeClock
 from comfyui_distributed_tpu.telemetry import Tracer, set_tracer
-from comfyui_distributed_tpu.telemetry.instruments import saves_total, walks_total
+from comfyui_distributed_tpu.telemetry.instruments import job_seconds_total, walks_total
+from comfyui_distributed_tpu.telemetry.job_record import PARTS
 from comfyui_distributed_tpu.telemetry.metrics import get_metrics_registry
 from comfyui_distributed_tpu.utils import image as img_utils
 from comfyui_distributed_tpu.workers.startup import drain_worker
@@ -248,11 +249,18 @@ def test_the_overlap_counter_and_attribute_read_one_and_zero(
     # p2 was taken while p1's save was held; nothing was taken during p2's
     assert spans_named(tracer, "p1", "png.encode")[0]["attrs"]["overlapped"] == 1
     assert spans_named(tracer, "p2", "png.encode")[0]["attrs"]["overlapped"] == 0
-    assert saves_total().value(overlapped="1") == 1
-    assert saves_total().value(overlapped="0") == 1
+    # the save's place in a job is the record's `tail_s`: each job launched
+    # nothing, so all of it is the tail, the save included
+    records = [spans_named(tracer, p, "execute_prompt")[0] for p in ("p1", "p2")]
+    for record in records:
+        attrs = record["attrs"]
+        assert attrs["tail_s"] > 0 and "starved_in" not in attrs
+        assert (attrs["waiting_s"], attrs["device_s"], attrs["starved_s"]) == (0.0, 0.0, 0.0)
+    for part in PARTS:
+        assert job_seconds_total().value(part=part) == sum(
+            r["attrs"][f"{part}_s"] for r in records)
     text = get_metrics_registry().render()
-    assert 'cdt_saves_total{overlapped="1"} 1' in text
-    assert 'cdt_saves_total{overlapped="0"} 1' in text
+    assert f'cdt_job_seconds_total{{part="tail"}} {job_seconds_total().value(part="tail"):g}' in text
 
 
 @pytest.fixture()
@@ -345,10 +353,13 @@ def test_the_pending_gauge_is_in_the_scrape(two_in_flight, server, encoder):
 
     unbind = bind_server_collectors(server)
     try:
-        assert 'cdt_saves_pending{server="worker:0"} 2' in get_metrics_registry().render()
+        # the server's own field, and the queue depth that counts a pending save
+        assert server.saves_pending == 2
+        assert 'cdt_prompt_queue_depth{server="worker:0"} 2' in get_metrics_registry().render()
         release(encoder, *two_in_flight)
         wait_until(lambda: server.saves_pending == 0, "the saves have ended")
-        assert 'cdt_saves_pending{server="worker:0"} 0' in get_metrics_registry().render()
+        text = get_metrics_registry().render()
+        assert 'cdt_prompt_queue_depth{server="worker:0"} 0' in text
     finally:
         unbind()
 
@@ -485,7 +496,8 @@ def test_without_a_server_the_node_saves_inline(out_dir, tracer, encoder):
     assert encoder.threads == [threading.current_thread().name]
     (encode,) = [s for ids in tracer.trace_ids() for s in spans_named(tracer, ids, "png.encode")]
     assert encode["attrs"]["overlapped"] == 0
-    assert saves_total().value(overlapped="0") == 1 and saves_total().value(overlapped="1") == 0
+    # no server, no job: nothing is stamped or counted
+    assert all(job_seconds_total().value(part=part) == 0 for part in PARTS)
 
 
 def test_a_context_with_a_server_but_no_defer_saves_inline(out_dir, tracer, encoder):

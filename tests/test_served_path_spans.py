@@ -419,6 +419,76 @@ def test_a_generate_request_has_the_prefill_then_the_decode_back_to_back(
     assert "after_ready_s" in below[4]["attrs"]
 
 
+class Ready:
+    """Stands for a launched program's output that is there already."""
+
+    def block_until_ready(self):
+        return self
+
+    def is_ready(self):
+        return True
+
+
+class LaunchingImage(SpanTestImage):
+    """A source node that launches a program, as far as the tracer can
+    tell: a `device.run` under its span, ended by the watcher thread."""
+
+    def make(self, value):
+        time.sleep(0.02)  # the host's work before the launch: the chip starves meanwhile
+        get_tracer().device_span("sampler", Ready())
+        return (np.full((1, 8, 8, 3), float(value), np.float32),)
+
+
+def test_a_served_prompt_ends_with_its_four_parts_and_both_counters_move(
+    tmp_config_path, wall_tracer, tmp_path, monkeypatch
+):
+    """Two prompts through the server's own loop, each launching one
+    program: every finished `execute_prompt` bears the record, the four
+    sum to the span from arrival to its end, and the counters hold the
+    sums."""
+    from comfyui_distributed_tpu.telemetry.instruments import (
+        device_idle_seconds_total, job_seconds_total)
+    from comfyui_distributed_tpu.telemetry.job_record import CAUSES, PARTS
+
+    monkeypatch.setitem(NODE_REGISTRY, "LaunchingImage", LaunchingImage)
+    monkeypatch.setenv("CDT_OUTPUT_DIR", str(tmp_path / "out"))
+    server = DistributedServer(port=0, is_worker=True)
+    prompts = [graph(value) for value in (0.25, 0.75)]
+    for prompt in prompts:
+        prompt["1"]["class_type"] = "LaunchingImage"
+    jobs = [server.queue_prompt(prompt, f"p{i}") for i, prompt in enumerate(prompts)]
+    run_queued(server)
+    wall_tracer.stop_device_watch(timeout=30)
+    assert all(job.done.is_set() and job.error is None for job in jobs)
+    records = []
+    for job in jobs:
+        spans = by_name(wall_tracer, job.trace_id)
+        (execute,) = spans["execute_prompt"]
+        attrs = execute["attrs"]
+        parts = [attrs[f"{part}_s"] for part in PARTS]
+        assert all(seconds >= 0.0 for seconds in parts)
+        arrived = spans["prompt_queue.wait"][0]["start"]
+        assert sum(parts) == pytest.approx(execute["end"] - arrived, abs=1e-6)
+        # its program ran, and the save came after it
+        (run,) = spans["device.run"]
+        assert attrs["device_s"] > 0.0 and attrs["tail_s"] > 0.0
+        assert attrs["tail_s"] == pytest.approx(execute["end"] - run["end"], abs=1e-6)
+        records.append(attrs)
+    for part in PARTS:
+        assert job_seconds_total().value(part=part) == pytest.approx(
+            sum(r[f"{part}_s"] for r in records))
+    idle = {cause: device_idle_seconds_total().value(cause=cause) for cause in CAUSES}
+    assert idle["between_jobs"] + idle["within_job"] == pytest.approx(
+        sum(r["starved_s"] for r in records))
+    # the second prompt's launch came after the first's had ended: the chip
+    # starved while the host walked to it, inside the node that launched
+    assert records[1]["starved_s"] >= 0.02 and records[1]["starved_in"] == "node.LaunchingImage"
+    assert idle["between_jobs"] > 0.0 and idle["no_job"] == 0.0  # both were queued at the start
+    text = get_metrics_registry().render()
+    assert 'cdt_job_seconds_total{part="device"}' in text
+    assert 'cdt_device_idle_seconds_total{cause="between_jobs"}' in text
+
+
 def test_two_requests_back_to_back_have_one_between_jobs_span_between_them(server, tracer):
     server.queue_prompt(graph(0.25), "p1")
     server.queue_prompt(graph(0.75), "p2")
